@@ -44,6 +44,7 @@ from typing import Dict, List, Optional, Sequence
 
 from dynamo_tpu.planner.metrics import count_metric, set_replicas
 from dynamo_tpu.utils.aio import decorrelated_jitter, reap_task
+from dynamo_tpu.utils.platform import single_chip_env
 from dynamo_tpu.worker.drain import drain_timeout_s
 
 logger = logging.getLogger(__name__)
@@ -69,6 +70,7 @@ class WorkerHandle:
     role: str
     gen: int                      # spawn ordinal (log file name)
     port: int = 0                 # per-worker system-server port (0 = none)
+    chip: Optional[int] = None    # the host chip this worker was given
     log_path: Optional[str] = None
     log_file: Optional[object] = None
     spawned_at: float = 0.0
@@ -109,7 +111,14 @@ class LocalConnector:
                  supervise_interval_s: float = 0.2,
                  probe_interval_s: float = 0.1,
                  log_dir: Optional[str] = None,
-                 extra_env: Optional[Dict[str, str]] = None):
+                 extra_env: Optional[Dict[str, str]] = None,
+                 chips: int = 0):
+        """``chips``: TPU chips on this host to hand out, ONE per worker,
+        through the worker's environment (``utils/platform.single_chip_env``
+        — a chip belongs to one process at a time). A worker is never
+        started on a chip another still holds: with every chip taken the
+        spawn is refused. 0 leaves the environment alone (mockers, CPU
+        workers, a worker that is meant to take every chip of the host)."""
         self.prefill_cmd = list(prefill_cmd)
         self.decode_cmd = list(decode_cmd)
         self.term_grace_s = term_grace_s
@@ -123,8 +132,10 @@ class LocalConnector:
         self.crash_loop_hold_s = crash_loop_hold_s
         self.supervise_interval_s = supervise_interval_s
         self.probe_interval_s = probe_interval_s
+        self.chips = chips
         self.log_dir = log_dir or tempfile.mkdtemp(prefix="dyn-planner-")
         self.extra_env = dict(extra_env or {})
+        self._free_chips: List[int] = list(range(chips))
         self.desired: Dict[str, int] = {r: 0 for r in ROLES}
         self._fleets: Dict[str, List[WorkerHandle]] = {r: [] for r in ROLES}
         self._gen = 0
@@ -184,6 +195,17 @@ class LocalConnector:
         gen = self._gen
         env = dict(os.environ)
         env.update(self.extra_env)
+        chip = None
+        if self.chips:
+            if not self._free_chips:
+                raise RuntimeError(
+                    f"all {self.chips} chips of this host are held by "
+                    f"workers; not starting a {role} worker on a chip in "
+                    "use")
+            # reserved before the first await: concurrent spawns never
+            # draw the same chip
+            chip = self._free_chips.pop(0)
+            env.update(single_chip_env(chip))
         port = 0
         if self.probe_ready:
             # every worker gets its own system server: the readiness gate,
@@ -199,11 +221,13 @@ class LocalConnector:
                 *cmd, stdout=log_file, stderr=asyncio.subprocess.STDOUT,
                 env=env)
             h = WorkerHandle(proc=proc, role=role, gen=gen, port=port,
-                             log_path=log_path, log_file=log_file,
+                             chip=chip, log_path=log_path,
+                             log_file=log_file,
                              spawned_at=time.monotonic())
             self._fleets[role].append(h)
         except BaseException:
             log_file.close()
+            self._release_chip(chip)
             raise
         finally:
             self._pending[role] -= 1
@@ -213,9 +237,14 @@ class LocalConnector:
         else:
             h.ready = True
             self._update_gauge(role)
-        logger.info("spawned %s worker pid=%d port=%d log=%s",
-                    role, proc.pid, port, log_path)
+        logger.info("spawned %s worker pid=%d port=%d chip=%s log=%s",
+                    role, proc.pid, port, chip, log_path)
         return h
+
+    def _release_chip(self, chip: Optional[int]) -> None:
+        if chip is not None:
+            self._free_chips.append(chip)
+            self._free_chips.sort()
 
     async def _probe_ready(self, h: WorkerHandle) -> None:
         import aiohttp
@@ -255,6 +284,7 @@ class LocalConnector:
         fleet = self._fleets[h.role]
         if h in fleet:
             fleet.remove(h)
+            self._release_chip(h.chip)   # the process is gone: chip free
         was_ready = h.ready
         h.ready = False
         self._update_gauge(h.role)

@@ -50,6 +50,10 @@ def build_parser() -> argparse.ArgumentParser:
                    help="crashes inside the window that trip hold-down")
     p.add_argument("--crash-loop-window-s", type=float, default=60.0)
     p.add_argument("--crash-loop-hold-s", type=float, default=60.0)
+    p.add_argument("--worker-chips", type=int, default=0,
+                   help="TPU chips on this host to hand out, one per "
+                        "spawned worker, each in its own process (0: "
+                        "leave worker environments alone — mockers, CPU)")
     p.add_argument("--worker-log-dir", default=None,
                    help="directory for per-worker log files (default: "
                         "a fresh temp dir)")
@@ -70,7 +74,7 @@ async def amain(args: argparse.Namespace) -> None:
             crash_loop_threshold=args.crash_loop_threshold,
             crash_loop_window_s=args.crash_loop_window_s,
             crash_loop_hold_s=args.crash_loop_hold_s,
-            log_dir=args.worker_log_dir)
+            log_dir=args.worker_log_dir, chips=args.worker_chips)
     else:
         from dynamo_tpu.planner.metrics_source import QueueAwareSource
         from dynamo_tpu.runtime.runtime import DistributedRuntime

@@ -67,8 +67,12 @@ class SystemServer:
                  registry: Optional[CollectorRegistry] = None,
                  extra_metrics: Optional[Callable[[], bytes]] = None,
                  host: str = "0.0.0.0", port: int = 0,
-                 tracer=None, steptrace=None):
+                 tracer=None, steptrace=None,
+                 info: Optional[Dict[str, object]] = None):
         self.health = health or SystemHealth()
+        # static facts about this process for the /health body (a worker
+        # reports its platform, devices and attention path here)
+        self.info = dict(info or {})
         self.registry = registry
         self.extra_metrics = extra_metrics
         self.tracer = tracer
@@ -134,7 +138,7 @@ class SystemServer:
         ok = self.health.healthy
         return web.json_response(
             {"status": "healthy" if ok else "unhealthy",
-             "subsystems": self.health.snapshot()},
+             "subsystems": self.health.snapshot(), **self.info},
             status=200 if ok else 503)
 
     async def handle_live(self, request: web.Request) -> web.Response:

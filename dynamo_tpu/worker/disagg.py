@@ -108,19 +108,15 @@ def get_kv_bandwidth_book() -> KvBandwidthBook:
 
 def make_device_transfer_plane(engine: JaxEngine):
     """A ``DeviceTransferPlane`` for this engine, or None when the
-    device-direct path does not apply: the jax transfer API is missing,
-    or the engine's cache is sharded over a mesh (a cross-process pull
-    onto a NamedSharding needs a shared global mesh). Mesh-sharded
+    device-direct path does not apply: the engine's cache is sharded over
+    a mesh (a cross-process pull onto a NamedSharding needs a shared
+    global mesh). Mesh-sharded
     deployments are NOT stuck on a host gather though: their bulk/RPC
     pulls negotiate the wire-v5 per-shard frame schema
     (``transfer.kv_shard_payload``), so each prefill shard's slice
     streams straight to its decode shard's device."""
     from jax.sharding import SingleDeviceSharding
 
-    try:
-        from jax.experimental import transfer  # noqa: F401
-    except ImportError:
-        return None
     ref = engine.pages[0] if isinstance(engine.pages, list) else engine.pages
     if not isinstance(ref.sharding, SingleDeviceSharding) \
             and len(ref.sharding.device_set) > 1:

@@ -247,6 +247,59 @@ class TestShardedMixedDispatch:
             await eng.stop()
 
 
+class TestPallasPerShard:
+    """On a mesh the GQA Pallas kernels run once per tp shard under
+    shard_map (``JaxEngine._per_shard``): the TPU compiler refuses a Mosaic
+    call left to GSPMD ("Mosaic kernels cannot be automatically
+    partitioned"). Here the kernels run in interpret mode on the CPU mesh;
+    the native compile is chip_smoke.py's tp variant."""
+
+    async def test_tp_and_dp_tp_match_single_device_scan(self):
+        cfg = ModelConfig.tiny(num_heads=4, num_kv_heads=2, head_dim=128)
+        kw = dict(ENGINE_KW, page_size=8)
+        prompts = [list(range(1 + i, 30 + 3 * i)) for i in range(3)]
+
+        async def serve(engine):
+            try:
+                return await asyncio.gather(*[
+                    run_tokens(engine, p, f"r{i}", max_tokens=12)
+                    for i, p in enumerate(prompts)])
+            finally:
+                await engine.stop()
+
+        want = await serve(JaxEngine.random_init(
+            cfg, JaxEngineConfig(attn_impl="scan", **kw)))
+        from dynamo_tpu.parallel.mesh import MeshSpec, make_mesh
+        from dynamo_tpu.parallel.sharding import ModelSharding
+        for spec in (MeshSpec(tp=2), MeshSpec(dp=2, tp=2)):
+            shard = ModelSharding(cfg, make_mesh(
+                spec, devices=jax.devices()[:spec.size]))
+            engine = build_tp2(cfg, shard, attn_impl="pallas", **kw)
+            assert engine.attn_impl == "pallas"
+            got = await serve(engine)
+            assert engine.multistep_blocks > 0 and engine.mixed_steps > 0
+            assert got == want, spec
+
+    def test_an_attn_impl_asked_for_by_name_is_honoured_or_refused(self):
+        # head_dim 16: the kernels cannot run; "auto" would settle on scan
+        cfg = ModelConfig.tiny()
+        with pytest.raises(ValueError, match="head_dim%128"):
+            JaxEngine.random_init(cfg, JaxEngineConfig(
+                attn_impl="pallas", **ENGINE_KW))
+        # the MLA kernels have no shard_map wrapper: refused on a mesh
+        mla = ModelConfig.tiny(
+            model_type="deepseek_v2", num_heads=4, num_kv_heads=1,
+            head_dim=128, kv_lora_rank=128, qk_rope_head_dim=16,
+            qk_nope_head_dim=32, v_head_dim=32, first_k_dense_replace=2)
+        shard = tp_sharding(mla, 2)
+        with pytest.raises(ValueError, match="cannot run on a mesh"):
+            JaxEngine.random_init(mla, JaxEngineConfig(
+                attn_impl="pallas", mesh=shard.mesh,
+                shard_params_fn=shard.shard_params,
+                shard_pages_fn=shard.shard_pages,
+                **dict(ENGINE_KW, page_size=8)))
+
+
 class TestShardAwareHandoff:
     """Per-shard KV wire frames (wire v5) between two sharded engines."""
 

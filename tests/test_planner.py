@@ -353,6 +353,40 @@ class TestFleetSupervisor:
         finally:
             await c.close(force=True)
 
+    async def test_each_worker_gets_its_own_chip_or_no_spawn(
+            self, monkeypatch, tmp_path):
+        """``chips=N``: every worker's environment shows it ONE chip of
+        the host (a chip belongs to one process), no two live workers
+        share one, an N+1th spawn is refused instead of landing on a chip
+        in use, and a chip comes back when its worker is gone."""
+        monkeypatch.setenv("DYN_DRAIN_TIMEOUT_S", "0.2")
+        show_env = [sys.executable, "-c",
+                    "import os, time; print('CHIP', "
+                    "os.environ['TPU_VISIBLE_CHIPS'], "
+                    "os.environ['TPU_PROCESS_BOUNDS'], "
+                    "os.environ['JAX_PLATFORMS'], flush=True); "
+                    "time.sleep(60)"]
+        c = fast_connector(show_env, show_env, chips=2, probe_ready=False,
+                           heal=False, log_dir=str(tmp_path))
+        try:
+            await c.scale(1, 1)
+            handles = c._fleets["prefill"] + c._fleets["decode"]
+            assert sorted(h.chip for h in handles) == [0, 1]
+            for h in handles:
+                await poll_until(lambda h=h: "CHIP" in h.log_tail(),
+                                 msg="worker printed its environment")
+                assert f"CHIP {h.chip} 1,1,1 tpu" in h.log_tail()
+            with pytest.raises(RuntimeError, match="chips of this host"):
+                await c.scale(1, 2)
+            await c.scale(0, 1)
+            await c.quiesce()
+            await poll_until(lambda: c.alive_counts()["prefill"] == 0,
+                             msg="prefill worker gone")
+            await c.scale(0, 2)      # the freed chip is handed out again
+            assert sorted(h.chip for h in c._fleets["decode"]) == [0, 1]
+        finally:
+            await c.close(force=True)
+
     async def test_term_grace_default_tracks_drain_budget(self, monkeypatch):
         monkeypatch.setenv("DYN_DRAIN_TIMEOUT_S", "7.5")
         c = LocalConnector(["x"], ["y"])  # default margin 5s
