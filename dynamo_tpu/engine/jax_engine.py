@@ -972,47 +972,48 @@ class JaxEngine(ScheduledEngineBase):
             new = alive.astype(jnp.int32)
             logits, pages, aux = self._decode_forward(
                 params, pages, tok, pos, table, total, new)
-            logits = logits.astype(jnp.float32)
-            key = jax.random.fold_in(rng, step0 + j)
-            if pw is not None:
-                # dynamic window ∪ prompt-reproduction entries, one
-                # scatter-add (excluded/pad entries carry a zero delta)
-                from dynamo_tpu.ops.sampling import (apply_penalties,
-                                                     penalty_window_entries)
-                inc = penalty_window_entries(
-                    pw["prompt_ids"], pw["prompt_valid"], pids, pn)
-                zs = jnp.zeros(inc.shape, jnp.float32)
-                logits = apply_penalties(
-                    logits,
-                    jnp.concatenate([pids, pw["prompt_ids"]], axis=1),
-                    jnp.concatenate([pcnt, zs], axis=1),
-                    jnp.concatenate([pctx, inc.astype(jnp.float32)],
-                                    axis=1),
-                    pw["fp"], pw["pp"], pw["rp"],
-                    pen_bias=jnp.concatenate([pbias, zs], axis=1))
-            if gt is not None:
-                # grammar allow-mask LAST: a penalty/bias can reweight
-                # inside the grammar but never resurrect an illegal token
-                from dynamo_tpu.ops.sampling import apply_vocab_mask
-                logits = apply_vocab_mask(logits, gt["masks"][gstate])
-            if pen is not None:
-                sampled, logprobs = sample_tokens(
-                    logits, key, temperature, top_k, top_p,
-                    seeds=pen["seeds"], seed_rng=rng, seed_pos=total,
-                    min_p=pen["min_p"])
-            else:
-                sampled, logprobs = sample_tokens(logits, key, temperature,
-                                                  top_k, top_p)
-            cols = [sampled[:, None],
-                    jax.lax.bitcast_convert_type(logprobs,
-                                                 jnp.int32)[:, None]]
-            if self.cfg.num_top_logprobs > 0:
-                # from the PENALIZED/MASKED logits — the distribution
-                # actually sampled from, as _sample_tail reports
-                ids, lp_bits = self._topk_cols(logits)
-                cols.append(ids)
-                cols.append(lp_bits)
-            packed = jnp.concatenate(cols, axis=1)
+            with jax.named_scope("sample"):
+                logits = logits.astype(jnp.float32)
+                key = jax.random.fold_in(rng, step0 + j)
+                if pw is not None:
+                    # dynamic window ∪ prompt-reproduction entries, one
+                    # scatter-add (excluded/pad entries carry a zero delta)
+                    from dynamo_tpu.ops.sampling import (
+                        apply_penalties, penalty_window_entries)
+                    inc = penalty_window_entries(
+                        pw["prompt_ids"], pw["prompt_valid"], pids, pn)
+                    zs = jnp.zeros(inc.shape, jnp.float32)
+                    logits = apply_penalties(
+                        logits,
+                        jnp.concatenate([pids, pw["prompt_ids"]], axis=1),
+                        jnp.concatenate([pcnt, zs], axis=1),
+                        jnp.concatenate([pctx, inc.astype(jnp.float32)],
+                                        axis=1),
+                        pw["fp"], pw["pp"], pw["rp"],
+                        pen_bias=jnp.concatenate([pbias, zs], axis=1))
+                if gt is not None:
+                    # grammar allow-mask LAST: a penalty/bias can reweight
+                    # inside the grammar but never resurrect an illegal token
+                    from dynamo_tpu.ops.sampling import apply_vocab_mask
+                    logits = apply_vocab_mask(logits, gt["masks"][gstate])
+                if pen is not None:
+                    sampled, logprobs = sample_tokens(
+                        logits, key, temperature, top_k, top_p,
+                        seeds=pen["seeds"], seed_rng=rng, seed_pos=total,
+                        min_p=pen["min_p"])
+                else:
+                    sampled, logprobs = sample_tokens(logits, key, temperature,
+                                                      top_k, top_p)
+                cols = [sampled[:, None],
+                        jax.lax.bitcast_convert_type(logprobs,
+                                                     jnp.int32)[:, None]]
+                if self.cfg.num_top_logprobs > 0:
+                    # from the PENALIZED/MASKED logits — the distribution
+                    # actually sampled from, as _sample_tail reports
+                    ids, lp_bits = self._topk_cols(logits)
+                    cols.append(ids)
+                    cols.append(lp_bits)
+                packed = jnp.concatenate(cols, axis=1)
             hit = jnp.any(stop_ids == sampled[:, None], axis=1)
             min_ok = (j + 1) >= min_gate
             stopped = (hit & min_ok) | ((j + 1) >= budget)
@@ -1175,9 +1176,11 @@ class JaxEngine(ScheduledEngineBase):
                                           total_lens)
         return pages, packed, {}
 
+    @functools.partial(jax.named_call, name="sample")
     def _sample_tail(self, logits, pages, rng, step, temperature, top_k,
                      top_p, pen=None, total_lens=None):
-        """Shared sampling epilogue of every step family (chunked + ring).
+        """Shared sampling epilogue of every step family (chunked + ring),
+        traced under the ``sample`` scope.
 
         Everything the host needs is PACKED into one int32 buffer
         ``[B, 2 + 2K]`` (token id, logprob bits, K alternative ids, K
@@ -1967,6 +1970,7 @@ class JaxEngine(ScheduledEngineBase):
         self.decode_dispatches += 1
         self.multistep_blocks += 1
         self.last_padded = (B, w)
+        self.last_program = f"multistep{w}[{B}]"
         if _fresh:
             self._mark_compile(_ckey, "multistep", B, w,
                                time.perf_counter() - _t0)
@@ -2145,6 +2149,8 @@ class JaxEngine(ScheduledEngineBase):
                 # still be in flight; everything older has long completed)
                 self._drain_moe_drops(keep_last=8)
         self.last_padded = (_B, _S)
+        # the step program and its bucket, as the ring names it
+        self.last_program = f"{kind}[{_B},{_S}]"
         if _fresh:
             self._mark_compile(_ckey, kind, _B, _S,
                                time.perf_counter() - _t0)

@@ -129,11 +129,14 @@ class ScheduledEngineBase(EngineBase):
         self._drain_leases: List[int] = []
         # step flight recorder: every dispatch stamps one StepRecord into
         # the process-wide ring (engine/steptrace.py); subclasses report
-        # their padded shapes via ``last_padded`` and first-call jit
-        # compiles via ``drain_compile_events`` so both occupancy and
-        # mid-run compiles are attributable from GET /v1/steptrace
+        # their padded shapes via ``last_padded``, the step program's
+        # name and bucket via ``last_program`` and first-call jit
+        # compiles via ``drain_compile_events`` so occupancy, per-program
+        # device time and mid-run compiles are attributable from
+        # GET /v1/steptrace
         self.steptrace = get_step_recorder()
         self.last_padded: Optional[Tuple[int, int]] = None
+        self.last_program = ""
         self._last_dispatch_end: Optional[float] = None
 
     # -- subclass hook -----------------------------------------------------
@@ -200,23 +203,25 @@ class ScheduledEngineBase(EngineBase):
 
     # -- step flight recorder ----------------------------------------------
 
-    def _stamp_dispatch(self, kind: str, plan, t_d0: float,
+    def _stamp_dispatch(self, kind: str, plan, dispatch,
                         plan_ms: float = 0.0, fallback: str = "",
                         chained: bool = False):
-        """Stamp one dispatch into the step ring: queue/pool pressure at
-        plan time, real-vs-padded tokens (``last_padded`` from the
-        subclass), the gap since the previous dispatch returned (host
-        overhead between dispatches), and any compile events the engine
-        buffered during this dispatch — those also land on every live
-        request the step served (``Sequence.compile_ms``), so a mid-run
-        compile shows up in the request's own trace. Returns the live
-        ring record (or None when disabled)."""
+        """Stamp one dispatch into the step ring, from its finished
+        ``dispatch`` phase: queue/pool pressure at plan time,
+        real-vs-padded tokens and the program's name (``last_padded`` /
+        ``last_program`` from the subclass), the gap since the previous
+        dispatch returned (host overhead between dispatches), and any
+        compile events the engine buffered during this dispatch — those
+        also land on every live request the step served
+        (``Sequence.compile_ms``), so a mid-run compile shows up in the
+        request's own trace. Returns the live ring record (or None when
+        disabled)."""
         st = self.steptrace
-        t_d1 = time.perf_counter()
         gap_ms = 0.0
         if self._last_dispatch_end is not None:
-            gap_ms = max(0.0, (t_d0 - self._last_dispatch_end) * 1000.0)
-        self._last_dispatch_end = t_d1
+            gap_ms = max(0.0,
+                         (dispatch.t0 - self._last_dispatch_end) * 1000.0)
+        self._last_dispatch_end = dispatch.t1
         seqs = getattr(plan, "seqs", ()) if plan is not None else ()
         rec = None
         if st.enabled:
@@ -244,15 +249,18 @@ class ScheduledEngineBase(EngineBase):
                 tokens_padded = tokens_real
             mgr = getattr(self, "_export_leases", None)
             rec = st.record(
-                kind, width=width, rows=rows, batch=batch,
-                tokens_real=tokens_real, tokens_padded=tokens_padded,
+                kind, program=self.last_program, width=width, rows=rows,
+                batch=batch, tokens_real=tokens_real,
+                tokens_padded=tokens_padded,
                 queue_depth=len(self.scheduler.waiting),
                 running=len(self.scheduler.active),
                 pool_free=self.allocator.num_free,
                 pool_pinned=mgr.pinned_pages if mgr is not None else 0,
-                plan_ms=plan_ms, dispatch_ms=(t_d1 - t_d0) * 1000.0,
-                gap_ms=gap_ms, fallback=fallback, chained=chained)
+                plan_ms=plan_ms, dispatch_ms=dispatch.ms,
+                gap_ms=gap_ms, fallback=fallback, chained=chained,
+                enqueue=dispatch.t0)
         self.last_padded = None
+        self.last_program = ""
         for ev in self.drain_compile_events():
             st.note_compile(ev.get("kind", kind), ev["seconds"], rec)
             for seq in seqs:
@@ -260,6 +268,9 @@ class ScheduledEngineBase(EngineBase):
                 seq.compile_events += 1
         if plan is not None:
             plan._steprec = rec
+            # what the later phases of this dispatch (fetch, process) are
+            # annotated with, whether or not the ring is on
+            plan._stepid = (dispatch.seq, kind)
         return rec
 
     def _consume_fallback(self) -> str:
@@ -633,9 +644,10 @@ class ScheduledEngineBase(EngineBase):
             fn, args, fut = self._exclusive.popleft()
             if fut.done():
                 continue
-            t_d0 = time.perf_counter()
+            gather = self.steptrace.phase("dispatch", self.steptrace.total,
+                                          "gather")
             try:
-                res = await asyncio.to_thread(fn, *args)
+                res = await gather.in_thread(fn, *args)
             except asyncio.CancelledError:
                 # loop task cancelled mid-drain (stop()): the item is already
                 # popped, so fail its future here or the caller hangs forever
@@ -651,7 +663,8 @@ class ScheduledEngineBase(EngineBase):
             # exclusive-window work (KV export gathers, tier offload,
             # drain freezes) shows up on the step timeline as its own
             # kind, so a stalled KV pull is visible as the gap's cause
-            self._stamp_dispatch("gather", None, t_d0)
+            rec = self._stamp_dispatch("gather", None, gather)
+            self.steptrace.note_ready(rec, gather.ready, gather.ready_unix)
 
     # -- the engine loop ---------------------------------------------------
 
@@ -721,165 +734,149 @@ class ScheduledEngineBase(EngineBase):
         # host then fetches the pending step's results while the chained
         # step executes — the device->host readback is fully hidden in
         # steady-state decode (VERDICT r2 item 2).
+        #
+        # Every phase of the loop runs under ``st.phase`` (the one
+        # stamping helper, engine/steptrace.py): host-clock stamps for the
+        # ring, and a ``loop.<phase>`` annotation carrying the dispatch's
+        # ring number for whatever profile is running.
         pending: Optional[Tuple[StepPlan, Any]] = None
+        st = self.steptrace
 
-        def fetch_fn(plan):
-            return (self.fetch_packed_block
-                    if isinstance(plan, MultiStepBatch) else self.fetch_packed)
-
-        def process_fn(plan):
-            return (self._process_multistep
-                    if isinstance(plan, MultiStepBatch) else self._process)
-
-        async def flush() -> None:
-            nonlocal pending
-            if pending is None:
-                return
-            plan, handle = pending
-            pending = None
-            t_u0 = time.perf_counter()
+        async def finish(plan, handle) -> None:
+            """Fetch a dispatched step's result and stream it out."""
+            multi = isinstance(plan, MultiStepBatch)
+            rec = plan._steprec
+            fetch = st.phase("fetch", *plan._stepid)
             try:
-                result = await asyncio.to_thread(fetch_fn(plan), handle)
+                result = await fetch.in_thread(
+                    self.fetch_packed_block if multi else self.fetch_packed,
+                    handle)
             except Exception as e:  # noqa: BLE001
                 self._fail_plan(plan, e)
                 return
-            process_fn(plan)(plan, *result)
-            self.steptrace.note_unpack(
-                getattr(plan, "_steprec", None),
-                (time.perf_counter() - t_u0) * 1000.0)
+            st.note_ready(rec, fetch.ready, fetch.ready_unix)
+            with st.phase("process", *plan._stepid) as process:
+                (self._process_multistep if multi
+                 else self._process)(plan, *result)
+            st.note_unpack(rec, fetch.ms, process.ms)
+
+        async def flush() -> None:
+            nonlocal pending
+            if pending is not None:
+                plan, handle = pending
+                pending = None
+                await finish(plan, handle)
 
         while not self._stopping:
             if self._exclusive:
                 await flush()
                 await self._drain_exclusive()
+            # the number the ring gives the next dispatch: drawn before
+            # planning, so all phases of one dispatch carry it
+            seq = st.total
             if pending is not None:
                 prev_plan, prev_handle = pending
-                t_p0 = time.perf_counter()
-                if isinstance(prev_plan, MultiStepBatch):
-                    chained = (self.scheduler.plan_multistep_chained(prev_plan)
-                               if self.supports_multistep else None)
-                else:
-                    chained = (self.scheduler.plan_chained(prev_plan)
-                               if self.supports_pipelining else None)
-                plan_ms = (time.perf_counter() - t_p0) * 1000.0
+                with st.phase("plan", seq, "chained") as planning:
+                    if isinstance(prev_plan, MultiStepBatch):
+                        chained = (
+                            self.scheduler.plan_multistep_chained(prev_plan)
+                            if self.supports_multistep else None)
+                    else:
+                        chained = (self.scheduler.plan_chained(prev_plan)
+                                   if self.supports_pipelining else None)
                 if chained is not None:
                     pending = None
-                    t_d0 = time.perf_counter()
+                    if isinstance(chained, MultiStepBatch):
+                        kind, fn = "multistep", self.dispatch_multistep
+                    else:
+                        kind, fn = "chained", self.dispatch_chained
+                    dispatch = st.phase("dispatch", seq, kind)
                     try:
-                        if isinstance(chained, MultiStepBatch):
-                            kind = "multistep"
-                            handle = await asyncio.to_thread(
-                                self.dispatch_multistep, chained, prev_handle)
-                        else:
-                            kind = "chained"
-                            handle = await asyncio.to_thread(
-                                self.dispatch_chained, chained, prev_handle)
+                        handle = await dispatch.in_thread(
+                            fn, chained, prev_handle)
                     except Exception as e:  # noqa: BLE001
                         # finish step/block N first so survivors' state is
                         # consistent, then fail the chained victims
                         try:
-                            result = await asyncio.to_thread(
-                                fetch_fn(prev_plan), prev_handle)
-                            process_fn(prev_plan)(prev_plan, *result)
+                            await finish(prev_plan, prev_handle)
                         except Exception as e2:  # noqa: BLE001
                             self._fail_plan(prev_plan, e2)
                         self._fail_plan(chained, e)
                         continue
-                    self._stamp_dispatch(kind, chained, t_d0,
-                                         plan_ms=plan_ms, chained=True)
+                    self._stamp_dispatch(kind, chained, dispatch,
+                                         plan_ms=planning.ms, chained=True)
                     pending = (chained, handle)
                     # overlap: unpack step/block N (streaming its tokens
                     # out) while N+1 runs on device
-                    t_u0 = time.perf_counter()
-                    try:
-                        result = await asyncio.to_thread(
-                            fetch_fn(prev_plan), prev_handle)
-                    except Exception as e:  # noqa: BLE001
-                        self._fail_plan(prev_plan, e)
-                        continue
-                    process_fn(prev_plan)(prev_plan, *result)
-                    self.steptrace.note_unpack(
-                        getattr(prev_plan, "_steprec", None),
-                        (time.perf_counter() - t_u0) * 1000.0)
+                    await finish(prev_plan, prev_handle)
                     continue
                 await flush()
-            t_p0 = time.perf_counter()
-            plan = self.scheduler.schedule()
-            self._drain_reaped()
+            with st.phase("plan", seq) as planning:
+                plan = self.scheduler.schedule()
+                self._drain_reaped()
+                ms = None
+                if isinstance(plan, DecodeBatch):
+                    if self.supports_multistep:
+                        ms = self.scheduler.plan_multistep(plan)
+                    else:
+                        reason = self.multistep_unsupported_reason
+                        if reason is not None:
+                            self.scheduler.record_fallback(reason, plan.seqs)
             if plan is None:
                 self._work.clear()
                 if self.scheduler.waiting:
                     if not self.scheduler.active:
                         # nothing running and the head request still cannot be
                         # admitted: it can never fit — fail it
-                        seq = self.scheduler.waiting.popleft()
-                        self._emit(seq, LLMEngineOutput(
+                        head = self.scheduler.waiting.popleft()
+                        self._emit(head, LLMEngineOutput(
                             finish_reason=FinishReason.ERROR,
                             error="request cannot fit in KV cache"))
                         continue
                     # cache full; yield to let running streams drain, retry
-                    await asyncio.sleep(0.005)
+                    with st.phase("blocked", seq):
+                        await asyncio.sleep(0.005)
                     self._last_dispatch_end = None  # idle, not a stall
                     continue
-                await self._work.wait()
+                with st.phase("idle", seq):
+                    await self._work.wait()
                 self._last_dispatch_end = None      # idle, not a stall
                 continue
-            if isinstance(plan, DecodeBatch):
-                ms = None
-                if self.supports_multistep:
-                    ms = self.scheduler.plan_multistep(plan)
-                else:
-                    reason = self.multistep_unsupported_reason
-                    if reason is not None:
-                        self.scheduler.record_fallback(reason, plan.seqs)
-                plan_ms = (time.perf_counter() - t_p0) * 1000.0
-                if ms is not None:
-                    t_d0 = time.perf_counter()
-                    try:
-                        handle = await asyncio.to_thread(
-                            self.dispatch_multistep, ms, None)
-                    except Exception as e:  # noqa: BLE001
-                        self._fail_plan(ms, e)
-                        continue
-                    self._stamp_dispatch("multistep", ms, t_d0,
-                                         plan_ms=plan_ms)
-                    pending = (ms, handle)
-                    continue
-                if self.supports_pipelining:
-                    t_d0 = time.perf_counter()
-                    try:
-                        handle = await asyncio.to_thread(
-                            self.dispatch_decode, plan)
-                    except Exception as e:  # noqa: BLE001
-                        self._fail_plan(plan, e)
-                        continue
-                    self._stamp_dispatch("decode", plan, t_d0,
-                                         plan_ms=plan_ms,
-                                         fallback=self._consume_fallback())
-                    pending = (plan, handle)
-                    continue
-            plan_ms = (time.perf_counter() - t_p0) * 1000.0
-            if isinstance(plan, SpecDecodeBatch):
-                kind = "spec"
-            elif isinstance(plan, MixedStepBatch):
-                kind = "mixed"
-            elif isinstance(plan, PrefillBatch):
-                kind = "prefill"
+            # asynchronous kinds return a handle and leave the result on
+            # the device (``pending``); the others return the result
+            asynchronous = True
+            if ms is not None:
+                plan, kind, fn, args = (ms, "multistep",
+                                        self.dispatch_multistep, (ms, None))
+            elif isinstance(plan, DecodeBatch) and self.supports_pipelining:
+                kind, fn, args = "decode", self.dispatch_decode, (plan,)
             else:
-                kind = "decode"
-            t_d0 = time.perf_counter()
+                asynchronous = False
+                if isinstance(plan, SpecDecodeBatch):
+                    kind = "spec"
+                elif isinstance(plan, MixedStepBatch):
+                    kind = "mixed"
+                elif isinstance(plan, PrefillBatch):
+                    kind = "prefill"
+                else:
+                    kind = "decode"
+                fn, args = self._execute_plan, (plan,)
+            dispatch = st.phase("dispatch", seq, kind)
             try:
-                result = await asyncio.to_thread(self._execute_plan, plan)
+                out = await dispatch.in_thread(fn, *args)
             except Exception as e:  # noqa: BLE001 — engine must not die silently
                 self._fail_plan(plan, e)
                 continue
-            rec = self._stamp_dispatch(kind, plan, t_d0, plan_ms=plan_ms,
-                                       fallback=self._consume_fallback())
-            sampled, logprobs, extras = result
-            t_u0 = time.perf_counter()
-            self._process(plan, sampled, logprobs, extras)
-            self.steptrace.note_unpack(
-                rec, (time.perf_counter() - t_u0) * 1000.0)
+            rec = self._stamp_dispatch(
+                kind, plan, dispatch, plan_ms=planning.ms,
+                fallback="" if ms is not None else self._consume_fallback())
+            if asynchronous:
+                pending = (plan, out)
+                continue
+            st.note_ready(rec, dispatch.ready, dispatch.ready_unix)
+            with st.phase("process", seq, kind) as process:
+                self._process(plan, *out)
+            st.note_unpack(rec, 0.0, process.ms)
 
     async def start(self) -> None:
         if self._loop_task is None:

@@ -6,10 +6,21 @@ The request-level flight recorder (utils/tracing.py) answers "what
 happened to THIS request"; this module answers "what was the engine
 doing" — per-dispatch kind, fused width, batch occupancy vs padding
 waste, queue depth and page-pool pressure at plan time, plan/dispatch/
-host-unpack wall time, and the step GAP since the previous dispatch
-(host overhead and exclusive-window stalls made visible). XLA compiles
-detected on a fresh jit bucket land here too, so a mid-run compile is
-attributable instead of masquerading as a throughput regression.
+fetch/process wall time on the host, the program's DEVICE time as the
+host can see it (``device_ms``: from the later of this dispatch's enqueue
+and the previous result's arrival, to this result's arrival), and the
+step GAP since the previous dispatch (host overhead and exclusive-window
+stalls made visible). XLA compiles detected on a fresh jit bucket land
+here too, so a mid-run compile is attributable instead of masquerading
+as a throughput regression.
+
+The loop's phases (``plan``/``dispatch``/``fetch``/``process``/``idle``/
+``blocked``) are stamped by one helper, ``StepRecorder.phase``: it takes
+the host-clock stamps the ring keeps AND opens a
+``jax.profiler.TraceAnnotation`` named ``loop.<phase>`` with the
+dispatch's ``seq``, so any profile of the process carries the same
+phases on the device trace's clock (a TraceMe that checks one flag when
+no profile runs).
 
 Design constraints, in order:
 
@@ -29,14 +40,18 @@ Design constraints, in order:
 
 from __future__ import annotations
 
+import asyncio
+import contextlib
 import os
+import sys
 import threading
 import time
 from bisect import bisect_left
 from typing import Any, Dict, List, Optional
 
 __all__ = [
-    "StepRecord", "StepRecorder", "get_step_recorder", "set_step_recorder",
+    "StepRecord", "StepRecorder", "Phase", "get_step_recorder",
+    "set_step_recorder",
 ]
 
 
@@ -87,15 +102,20 @@ class StepRecord:
     allocation-free in steady state; ``seq`` is the monotonic dispatch
     index (survives ring wrap, anchors pagination)."""
 
-    __slots__ = ("seq", "t_unix", "kind", "width", "rows", "batch",
-                 "tokens_real", "tokens_padded", "queue_depth", "running",
-                 "pool_free", "pool_pinned", "plan_ms", "dispatch_ms",
-                 "unpack_ms", "gap_ms", "compile_ms", "fallback", "chained")
+    FIELDS = ("seq", "t_unix", "kind", "program", "width", "rows", "batch",
+              "tokens_real", "tokens_padded", "queue_depth", "running",
+              "pool_free", "pool_pinned", "plan_ms", "dispatch_ms",
+              "fetch_ms", "process_ms", "unpack_ms", "device_ms",
+              "ready_unix", "gap_ms", "compile_ms", "fallback", "chained")
+    # _enqueue: perf_counter at the start of the enqueue, kept until the
+    # result arrives and device_ms can be taken; not exported
+    __slots__ = FIELDS + ("_enqueue",)
 
     def __init__(self) -> None:
         self.seq = -1
         self.t_unix = 0.0
         self.kind = ""
+        self.program = ""
         self.width = 0
         self.rows = 0
         self.batch = 0
@@ -107,22 +127,99 @@ class StepRecord:
         self.pool_pinned = 0
         self.plan_ms = 0.0
         self.dispatch_ms = 0.0
+        self.fetch_ms = 0.0
+        self.process_ms = 0.0
         self.unpack_ms = 0.0
+        self.device_ms = 0.0
+        self.ready_unix = 0.0
         self.gap_ms = 0.0
         self.compile_ms = 0.0
         self.fallback = ""
         self.chained = False
+        self._enqueue = 0.0
 
     def to_dict(self) -> Dict[str, Any]:
-        return {s: getattr(self, s) for s in self.__slots__}
+        return {s: getattr(self, s) for s in self.FIELDS}
+
+
+_annotation = None
+
+
+def _trace_annotation(name: str, seq: int, kind: str):
+    """``jax.profiler.TraceAnnotation(name, seq=, kind=)`` in a process
+    that has imported jax; a null context in one that has not (the
+    mocker), where no profile can run either."""
+    global _annotation
+    if _annotation is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return contextlib.nullcontext()
+        _annotation = jax.profiler.TraceAnnotation
+    return _annotation(name, seq=seq, kind=kind)
+
+
+class Phase:
+    """One phase of the step loop for one dispatch, on both clocks: the
+    host-clock stamps the ring keeps (``t0``/``t1``, ``ms``) and a
+    ``loop.<name>`` annotation in whatever profile is running. A ``with``
+    block for the phases the loop runs itself; ``in_thread`` for the two
+    it hands to a worker thread. There the loop's side (the hand-over to
+    the thread and back included: what ``ms`` measures) is annotated on
+    the loop's thread, and the call itself once more, under the same name
+    and ``seq``, on the thread that does the work; ``ready``/
+    ``ready_unix`` are taken the moment the call returns, before the
+    event loop gets round to resuming."""
+
+    __slots__ = ("_recorder", "name", "seq", "kind", "t0", "t1", "ready",
+                 "ready_unix", "_ann")
+
+    def __init__(self, recorder: "StepRecorder", name: str, seq: int,
+                 kind: str) -> None:
+        self._recorder = recorder
+        self.name = name
+        self.seq = seq
+        self.kind = kind
+        self.t0 = self.t1 = self.ready = self.ready_unix = 0.0
+
+    @property
+    def ms(self) -> float:
+        return (self.t1 - self.t0) * 1000.0
+
+    def _annotation(self):
+        return _trace_annotation("loop." + self.name, self.seq, self.kind)
+
+    def __enter__(self) -> "Phase":
+        self._ann = self._annotation()
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        waits = self._recorder.loop_wait_s
+        if self.name in waits:
+            waits[self.name] += self.t1 - self.t0
+
+    def _call(self, fn, args):
+        with self._annotation():
+            out = fn(*args)
+            self.ready = time.perf_counter()
+            self.ready_unix = time.time()
+        return out
+
+    async def in_thread(self, fn, *args):
+        with self:
+            return await asyncio.to_thread(self._call, fn, args)
 
 
 class StepRecorder:
     """Process-wide step ring + inline fleet aggregates.
 
     The loop calls ``record()`` once per dispatch (cheap), then patches
-    host-side costs in as they become known: ``note_unpack()`` when the
-    overlapped fetch+process completes, ``note_compile()`` when the
+    host-side costs in as they become known: ``note_ready()`` when the
+    result is on the host, ``note_unpack()`` when the overlapped
+    fetch+process completes, ``note_compile()`` when the
     engine reports a fresh-jit-bucket compile attributed to that
     dispatch. Aggregate reads (``aggregates()``/``snapshot()``) take the
     same lock — scrape-time only, never on the hot path.
@@ -148,18 +245,31 @@ class StepRecorder:
         self.compile_seconds: Dict[str, float] = {}
         self.pool_free = 0
         self.pool_pinned = 0
+        # perf_counter when the newest result arrived: where the next
+        # program's device time starts if it was enqueued before that
+        self._last_ready = 0.0
+        # seconds the loop spent waiting, process-wide: ``idle`` for a
+        # request, ``blocked`` on a full cache (written by the loop's
+        # thread alone, through ``phase``)
+        self.loop_wait_s: Dict[str, float] = {"idle": 0.0, "blocked": 0.0}
 
     # -- hot path ----------------------------------------------------------
 
-    def record(self, kind: str, *, width: int = 0, rows: int = 0,
+    def phase(self, name: str, seq: int, kind: str = "") -> Phase:
+        """The stamping helper for one loop phase (see ``Phase``)."""
+        return Phase(self, name, seq, kind)
+
+    def record(self, kind: str, *, program: str = "", width: int = 0,
+               rows: int = 0,
                batch: int = 0, tokens_real: int = 0, tokens_padded: int = 0,
                queue_depth: int = 0, running: int = 0, pool_free: int = 0,
                pool_pinned: int = 0, plan_ms: float = 0.0,
                dispatch_ms: float = 0.0, gap_ms: float = 0.0,
-               fallback: str = "", chained: bool = False
-               ) -> Optional[StepRecord]:
+               fallback: str = "", chained: bool = False,
+               enqueue: float = 0.0) -> Optional[StepRecord]:
         """Stamp one dispatch; returns the live ring slot (later patched
-        by note_unpack/note_compile) or None when disabled."""
+        by note_ready/note_unpack/note_compile) or None when disabled.
+        ``enqueue`` is the perf_counter at the start of the enqueue."""
         if not self.enabled:
             return None
         now = time.time()
@@ -169,6 +279,7 @@ class StepRecorder:
             rec.seq = self._n - 1
             rec.t_unix = now
             rec.kind = kind
+            rec.program = program
             rec.width = width
             rec.rows = rows
             rec.batch = batch
@@ -180,15 +291,16 @@ class StepRecorder:
             rec.pool_pinned = pool_pinned
             rec.plan_ms = plan_ms
             rec.dispatch_ms = dispatch_ms
+            rec.fetch_ms = 0.0
+            rec.process_ms = 0.0
             rec.unpack_ms = 0.0
+            rec.device_ms = 0.0
+            rec.ready_unix = 0.0
             rec.gap_ms = gap_ms
             rec.compile_ms = 0.0
             rec.fallback = fallback
             rec.chained = chained
-            h = self._dur.get(kind)
-            if h is None:
-                h = self._dur[kind] = _Hist(_DUR_BOUNDS)
-            h.observe(dispatch_ms / 1000.0)
+            rec._enqueue = enqueue
             if tokens_padded > 0:
                 o = self._occ.get(kind)
                 if o is None:
@@ -200,14 +312,42 @@ class StepRecorder:
             self.pool_pinned = pool_pinned
             return rec
 
-    def note_unpack(self, rec: Optional[StepRecord], ms: float) -> None:
-        """Patch host fetch+unpack wall time into a dispatch's record
+    def note_ready(self, rec: Optional[StepRecord], ready: float,
+                   ready_unix: float) -> None:
+        """The dispatch's result is on the host (``ready``: perf_counter,
+        ``ready_unix``: wall clock). The device runs programs in the
+        order they were enqueued, so this one had the device from the
+        later of its own enqueue and the previous result's arrival:
+        ``device_ms`` is that span, for synchronous and asynchronous
+        kinds alike, and under the chained overlap (N+1 enqueued before
+        N is fetched) two programs never count the same time. It is the
+        host's estimate: the enqueue's own host work and the copy back
+        are inside it. The per-kind duration histogram observes it."""
+        if rec is None or not self.enabled:
+            return
+        with self._lock:
+            device_s = max(0.0, ready - max(rec._enqueue, self._last_ready))
+            self._last_ready = ready
+            rec.ready_unix = ready_unix
+            rec.device_ms = device_s * 1000.0
+            h = self._dur.get(rec.kind)
+            if h is None:
+                h = self._dur[rec.kind] = _Hist(_DUR_BOUNDS)
+            h.observe(device_s)
+
+    def note_unpack(self, rec: Optional[StepRecord], fetch_ms: float,
+                    process_ms: float) -> None:
+        """Patch the host's time after the dispatch into its record:
+        blocked in the fetch (waiting for the device and the copy back),
+        and in its own unpack-and-emit work; ``unpack_ms`` is their sum
         (known only when the overlapped fetch completes, often after
         the NEXT dispatch has been stamped)."""
         if rec is None or not self.enabled:
             return
         with self._lock:
-            rec.unpack_ms = ms
+            rec.fetch_ms = fetch_ms
+            rec.process_ms = process_ms
+            rec.unpack_ms = fetch_ms + process_ms
 
     def note_compile(self, kind: str, seconds: float,
                      rec: Optional[StepRecord] = None) -> None:
@@ -257,6 +397,7 @@ class StepRecorder:
                 "compile_seconds": dict(self.compile_seconds),
                 "pool_free": self.pool_free,
                 "pool_pinned": self.pool_pinned,
+                "loop_wait_s": dict(self.loop_wait_s),
             }
 
 

@@ -471,24 +471,14 @@ def _dense_mlp(lp: Dict[str, jnp.ndarray], x: jnp.ndarray) -> jnp.ndarray:
 
 # ----------------------------------------------------------------- forward
 
-def _layer_step(cfg: ModelConfig, lp, h, positions, total_lens, new_lens,
-                page_table, pages, lidx, *, moe: bool, layered: bool,
-                use_pallas: bool = False, ep_mesh=None):
-    """One decoder layer against the paged latent cache. ``layered`` means
-    ``pages`` is the per-layer buffer (unrolled path) instead of the
-    stacked cache. ``use_pallas`` routes S==1 through the MLA Pallas
-    decode kernel (``ops/pallas/mla_decode.py``) when the geometry
-    supports it. Returns ``(h, pages, dropped_assignments)``."""
+def _attend(cfg: ModelConfig, lp, h, q_lat, q_pe, w_uv, positions,
+            total_lens, page_table, pages, lidx, *, layered: bool,
+            use_pallas: bool):
+    """The attention stage of ``_layer_step`` (latent attention over the
+    paged cache plus the out-projection residual), by the path the
+    geometry picks. Returns the new ``h``."""
     from dynamo_tpu.ops.attention import _pad_table
 
-    q_lat, q_pe, c_kv, k_pe, w_uv = _mla_qkv(cfg, lp, h, positions)
-    k_new, v_new = _cache_rows(cfg, c_kv, k_pe)
-    if layered:
-        pages = write_kv_layer(pages, k_new, v_new, page_table, positions,
-                               new_lens)
-    else:
-        pages = write_kv(pages, lidx, k_new, v_new, page_table, positions,
-                         new_lens)
     S = h.shape[1]
     P = page_table.shape[1]
     ps = pages.shape[-2]
@@ -531,11 +521,38 @@ def _layer_step(cfg: ModelConfig, lp, h, positions, total_lens, new_lens,
         ckv_ctx, kpe_ctx = _gather_ctx(cfg, gathered)
         h = _mla_attend(cfg, lp, h, q_lat, q_pe, w_uv, ckv_ctx, kpe_ctx,
                         positions, total_lens)
-    x = _rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
-    if moe:
-        mlp, dropped = _moe_mlp(cfg, lp, x, ep_mesh=ep_mesh)
-    else:
-        mlp, dropped = _dense_mlp(lp, x), jnp.zeros((), jnp.int32)
+    return h
+
+
+def _layer_step(cfg: ModelConfig, lp, h, positions, total_lens, new_lens,
+                page_table, pages, lidx, *, moe: bool, layered: bool,
+                use_pallas: bool = False, ep_mesh=None):
+    """One decoder layer against the paged latent cache. ``layered`` means
+    ``pages`` is the per-layer buffer (unrolled path) instead of the
+    stacked cache. ``use_pallas`` routes S==1 through the MLA Pallas
+    decode kernel (``ops/pallas/mla_decode.py``) when the geometry
+    supports it. Returns ``(h, pages, dropped_assignments)``."""
+    # stage names for the device trace, as in models/llama.py
+    with jax.named_scope("layer.attn_in"):
+        q_lat, q_pe, c_kv, k_pe, w_uv = _mla_qkv(cfg, lp, h, positions)
+        k_new, v_new = _cache_rows(cfg, c_kv, k_pe)
+    with jax.named_scope("layer.kv_write"):
+        if layered:
+            pages = write_kv_layer(pages, k_new, v_new, page_table,
+                                   positions, new_lens)
+        else:
+            pages = write_kv(pages, lidx, k_new, v_new, page_table,
+                             positions, new_lens)
+    with jax.named_scope("layer.attn"):
+        h = _attend(cfg, lp, h, q_lat, q_pe, w_uv, positions, total_lens,
+                    page_table, pages, lidx, layered=layered,
+                    use_pallas=use_pallas)
+    with jax.named_scope("layer.moe" if moe else "layer.ffn"):
+        x = _rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
+        if moe:
+            mlp, dropped = _moe_mlp(cfg, lp, x, ep_mesh=ep_mesh)
+        else:
+            mlp, dropped = _dense_mlp(lp, x), jnp.zeros((), jnp.int32)
     h = h + mlp
     return h, pages, dropped
 
@@ -562,7 +579,8 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     use_pallas = (getattr(attn_impl, "pallas_paged_kernel", False)
                   and mla_supports(cfg.kv_lora_rank, pages.shape[-2]))
     K = cfg.first_k_dense_replace
-    h = params["embed"][tokens]
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens]
     total_dropped = jnp.zeros((), jnp.int32)
 
     def body(moe):
@@ -586,8 +604,9 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             (params["moe_layers"], K + jnp.arange(cfg.num_layers - K)))
         total_dropped = jnp.sum(drops)
     aux = {"moe_dropped_assignments": total_dropped}
-    return (_logits(cfg, params, h, new_lens, window=logits_window),
-            pages, aux)
+    with jax.named_scope("logits"):
+        logits = _logits(cfg, params, h, new_lens, window=logits_window)
+    return logits, pages, aux
 
 
 def forward_unrolled(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -606,7 +625,8 @@ def forward_unrolled(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                   and mla_supports(cfg.kv_lora_rank,
                                    pages_list[0].shape[-2]))
     K = cfg.first_k_dense_replace
-    h = params["embed"][tokens]
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens]
     out_pages: List[jnp.ndarray] = []
     total_dropped = jnp.zeros((), jnp.int32)
     for l in range(cfg.num_layers):
@@ -621,8 +641,9 @@ def forward_unrolled(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         total_dropped = total_dropped + dropped
         out_pages.append(kv)
     aux = {"moe_dropped_assignments": total_dropped}
-    return (_logits(cfg, params, h, new_lens, window=logits_window),
-            out_pages, aux)
+    with jax.named_scope("logits"):
+        logits = _logits(cfg, params, h, new_lens, window=logits_window)
+    return logits, out_pages, aux
 
 
 # ------------------------------------------------------------------ loader

@@ -220,22 +220,32 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     """
     sm_scale = cfg.head_dim ** -0.5
     attn_impl = attn_impl or paged_attention
-    h = params["embed"][tokens]  # [B, S, H]
+    # the named scopes are the stage names a device trace shows for the
+    # operations traced under them (docs/observability.md)
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens]  # [B, S, H]
 
     def body(carry, xs):
         h, pages = carry
         lp, lidx = xs
-        q, k, v = _project_qkv(cfg, lp, h, positions)
-        pages = write_kv(pages, lidx, k, v, page_table, positions, new_lens)
-        attn = attn_impl(q, pages, lidx, page_table, positions,
-                         total_lens, sm_scale)
-        h = _finish_layer(cfg, lp, h, attn)
+        with jax.named_scope("layer.attn_in"):
+            q, k, v = _project_qkv(cfg, lp, h, positions)
+        with jax.named_scope("layer.kv_write"):
+            pages = write_kv(pages, lidx, k, v, page_table, positions,
+                             new_lens)
+        with jax.named_scope("layer.attn"):
+            attn = attn_impl(q, pages, lidx, page_table, positions,
+                             total_lens, sm_scale)
+        with jax.named_scope("layer.ffn"):
+            h = _finish_layer(cfg, lp, h, attn)
         return (h, pages), None
 
     (h, pages), _ = jax.lax.scan(
         body, (h, pages),
         (params["layers"], jnp.arange(cfg.num_layers)))
-    return _logits(cfg, params, h, new_lens, window=logits_window), pages
+    with jax.named_scope("logits"):
+        logits = _logits(cfg, params, h, new_lens, window=logits_window)
+    return logits, pages
 
 
 def _dense_hidden(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -360,17 +370,25 @@ def forward_unrolled(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     """
     sm_scale = cfg.head_dim ** -0.5
     attn_impl = attn_impl or paged_attention_layer
-    h = params["embed"][tokens]
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens]
     out_pages: List[jnp.ndarray] = []
     for l in range(cfg.num_layers):
         lp = {k: v[l] for k, v in params["layers"].items()}
-        q, k, v = _project_qkv(cfg, lp, h, positions)
-        kv = write_kv_layer(pages_list[l], k, v, page_table, positions,
-                            new_lens)
-        attn = attn_impl(q, kv, page_table, positions, total_lens, sm_scale)
-        h = _finish_layer(cfg, lp, h, attn)
+        with jax.named_scope("layer.attn_in"):
+            q, k, v = _project_qkv(cfg, lp, h, positions)
+        with jax.named_scope("layer.kv_write"):
+            kv = write_kv_layer(pages_list[l], k, v, page_table, positions,
+                                new_lens)
+        with jax.named_scope("layer.attn"):
+            attn = attn_impl(q, kv, page_table, positions, total_lens,
+                             sm_scale)
+        with jax.named_scope("layer.ffn"):
+            h = _finish_layer(cfg, lp, h, attn)
         out_pages.append(kv)
-    return _logits(cfg, params, h, new_lens, window=logits_window), out_pages
+    with jax.named_scope("logits"):
+        logits = _logits(cfg, params, h, new_lens, window=logits_window)
+    return logits, out_pages
 
 
 __all__ = ["init_params", "forward", "forward_unrolled", "encode", "score",

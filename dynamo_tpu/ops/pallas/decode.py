@@ -213,6 +213,7 @@ def _paged_decode(q, kv_pages, layer_idx, window, page_table, total_lens,
         ],
         out_shape=jax.ShapeDtypeStruct((B, Hq, Dh), q.dtype),
         interpret=interpret,
+        name="paged_decode",
     )((q * sm_scale).astype(q.dtype), kv_pages, layer_idx, window,
       page_table, total_lens)
 

@@ -177,6 +177,7 @@ def _mla_paged_decode(q2, kv_pages, layer_idx, page_table, total_lens,
         ],
         out_shape=jax.ShapeDtypeStruct((B, nh, dkv), jnp.float32),
         interpret=interpret,
+        name="mla_decode",
     )((q2 * sm_scale).astype(kv_pages.dtype), kv_pages, layer_idx,
       page_table, total_lens)
 

@@ -198,6 +198,7 @@ def _mla_paged_prefill(q2, kv_pages, layer_idx, page_table, q_start,
         ],
         out_shape=jax.ShapeDtypeStruct((B, S, nh, dkv), jnp.float32),
         interpret=interpret,
+        name="mla_prefill",
     )((q2 * sm_scale).astype(kv_pages.dtype), kv_pages, layer_idx,
       page_table, q_start, total_lens)
 

@@ -256,6 +256,7 @@ def _paged_prefill(q, kv_pages, layer_idx, window, page_table, q_start,
         ],
         out_shape=jax.ShapeDtypeStruct((B, S, Hq, Dh), q.dtype),
         interpret=interpret,
+        name="paged_prefill",
     )((q * sm_scale).astype(q.dtype), kv_pages, layer_idx, window,
       page_table, q_start, total_lens)
 

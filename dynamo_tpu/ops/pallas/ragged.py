@@ -206,6 +206,7 @@ def _ragged_mixed(q, kv_pages, layer_idx, window, page_table, q_start,
         ],
         out_shape=jax.ShapeDtypeStruct((B, S, Hq, Dh), q.dtype),
         interpret=interpret,
+        name="ragged_mixed",
     )((q * sm_scale).astype(q.dtype), kv_pages, layer_idx, window,
       page_table, q_start, total_lens)
 

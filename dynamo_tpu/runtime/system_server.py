@@ -12,18 +12,53 @@ first, ``?limit=&offset=&request_id=`` pagination/lookup) and
 ``GET /v1/traces/{trace_id}`` (the full span tree); with a ``steptrace``
 (``engine/steptrace.StepRecorder``) it exposes the engine step timeline
 on ``GET /v1/steptrace`` — see ``docs/observability.md``.
+
+``POST /v1/profile`` (``{"seconds": s}``) takes a ``jax.profiler`` trace
+of this process for ``s`` seconds and names the directory it wrote: in a
+worker the device's operations (under the scopes the step programs name)
+and the step loop's ``loop.*`` annotations, in one file on one clock.
 """
 
 from __future__ import annotations
 
+import asyncio
 import logging
 import os
+import tempfile
+import time
 from typing import Callable, Dict, Optional
 
 from aiohttp import web
 from prometheus_client import CollectorRegistry, generate_latest
 
 logger = logging.getLogger(__name__)
+
+PROFILE_MAX_S = 60.0    # the longest trace POST /v1/profile takes
+PROFILE_SLICE = "profile_slice"
+
+
+def take_profile(seconds: float) -> dict:
+    """A ``jax.profiler`` trace of this process for ``seconds``, written
+    under a fresh directory. The Python tracer is off: it records every
+    call of the step loop's thread and slows the host it is there to
+    observe. The ``profile_slice`` annotation spans the sleep on the
+    trace's own clock; ``start_unix``/``stop_unix`` are the same two
+    moments on the wall clock. Blocks: call it from a worker thread."""
+    import jax
+
+    out = tempfile.mkdtemp(prefix="dynamo_profile_")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(out, profiler_options=options)
+    try:
+        with jax.profiler.TraceAnnotation(PROFILE_SLICE):
+            t0 = time.time()
+            time.sleep(seconds)
+            t1 = time.time()
+    finally:
+        jax.profiler.stop_trace()
+    return {"dir": out, "seconds": seconds, "start_unix": t0,
+            "stop_unix": t1}
 
 
 def coord_ready_reasons(coord) -> list:
@@ -89,6 +124,8 @@ class SystemServer:
         self.app.router.add_get("/v1/traces/{trace_id}", self.handle_trace)
         self.app.router.add_get("/v1/steptrace", self.handle_steptrace)
         self.app.router.add_post("/drain", self.handle_drain)
+        self.app.router.add_post("/v1/profile", self.handle_profile)
+        self._profiling = False   # one profile at a time
         # graceful-drain hook (worker/drain.DrainController): POST /drain
         # triggers it; absent on processes with nothing to drain
         self._drain = None
@@ -181,6 +218,33 @@ class SystemServer:
         self._drain.trigger("POST /drain")
         return web.json_response({"state": self._drain.state,
                                   "counts": self._drain.counts})
+
+    async def handle_profile(self, request: web.Request) -> web.Response:
+        """``POST /v1/profile`` ``{"seconds": s}``: answers when the trace
+        is written, with the directory that holds it; 409 while another
+        one runs, 400 for a length outside (0, PROFILE_MAX_S]."""
+        try:
+            body = await request.json() if request.can_read_body else {}
+            seconds = float(body.get("seconds", 2.0))
+        except (ValueError, TypeError, AttributeError):
+            return web.json_response(
+                {"error": 'the body is {"seconds": <number>}'}, status=400)
+        if not 0.0 < seconds <= PROFILE_MAX_S:
+            return web.json_response(
+                {"error": f"seconds must be in (0, {PROFILE_MAX_S:g}]"},
+                status=400)
+        if self._profiling:
+            return web.json_response(
+                {"error": "a profile is being taken"}, status=409)
+        self._profiling = True
+        try:
+            return web.json_response(
+                await asyncio.to_thread(take_profile, seconds))
+        except ImportError:
+            return web.json_response(
+                {"error": "this process has no jax to profile"}, status=404)
+        finally:
+            self._profiling = False
 
     async def handle_traces(self, request: web.Request) -> web.Response:
         return trace_list_response(self.tracer, request)

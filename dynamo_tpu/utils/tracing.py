@@ -225,11 +225,11 @@ class Tracer:
         # finished traces, oldest first (OrderedDict as a ring)
         self._ring: "OrderedDict[str, Dict[str, Any]]" = OrderedDict()
         self.dropped_traces = 0     # sampled out or buffer-evicted
-        self._last_finalized: Optional[Dict[str, Any]] = None
-        # keep-last-K side ring: the most recent finalized traces, kept
-        # even when slow-trace sampling (DYN_TRACE_SLOW_S) drops them from
-        # the main ring — "the request I JUST sent" stays findable via
-        # /v1/traces?request_id= without turning sampling off fleet-wide
+        # keep-last-K side ring: the most recent traces that slow-trace
+        # sampling (DYN_TRACE_SLOW_S) dropped from the main ring and that
+        # carry a request id — "the request I JUST sent" stays findable
+        # via /v1/traces?request_id= without turning sampling off
+        # fleet-wide. It holds nothing the main ring kept or evicted.
         self.keep_last = max(0, _env_int("DYN_TRACE_KEEP_LAST", 64))
         self._keep_last: "OrderedDict[str, Dict[str, Any]]" = OrderedDict()
         self._listeners: List[Callable[[Span], None]] = []
@@ -377,14 +377,14 @@ class Tracer:
         its final response frame (``SPANS_FRAME_KEY``)."""
         if isinstance(span, _NoopSpan):
             return []
-        trace_id = span.trace_id
+        # taken before the finish pops it: even when the local SAMPLING
+        # drops the fragment, the caller still gets the full span set —
+        # its sampling decision is its own
+        spans = list(self._live.get(span.trace_id, ()))
         span.finish()  # finalizes the local fragment (ring per sampling)
-        rec = self._last_finalized
-        if rec is not None and rec["trace_id"] == trace_id:
-            # even when the local SAMPLING dropped the fragment, the caller
-            # still gets the full span set — its sampling decision is its own
-            return list(rec["spans"])
-        return [span.to_dict()]
+        spans.append(span.to_dict())
+        spans.sort(key=lambda s: s.get("start_unix") or 0.0)
+        return spans
 
     def _buffer(self, d: Dict[str, Any]) -> None:
         self._live.setdefault(d["trace_id"], []).append(d)
@@ -409,16 +409,16 @@ class Tracer:
             "error": errored,
             "spans": spans,
         }
-        self._last_finalized = record
-        if self.keep_last:
-            # before the sampling decision: fast traces stay findable
-            self._keep_last.pop(root.trace_id, None)
-            self._keep_last[root.trace_id] = record
-            while len(self._keep_last) > self.keep_last:
-                self._keep_last.popitem(last=False)
         if self.slow_s > 0 and root.duration_s < self.slow_s and not errored:
             self.dropped_traces += 1
+            if self.keep_last and record["request_id"]:
+                # sampled out, but findable by its request id for a while
+                self._keep_last.pop(root.trace_id, None)
+                self._keep_last[root.trace_id] = record
+                while len(self._keep_last) > self.keep_last:
+                    self._keep_last.popitem(last=False)
             return
+        self._keep_last.pop(root.trace_id, None)
         # re-finalizing the same trace id (two hops of one trace through
         # the same process) merges into one record
         prev = self._ring.pop(root.trace_id, None)
@@ -478,11 +478,6 @@ class Tracer:
         rec = self._ring.get(trace_id)
         return rec if rec is not None else self._keep_last.get(trace_id)
 
-    def clear(self) -> None:
-        self._ring.clear()
-        self._keep_last.clear()
-        self._live.clear()
-
 
 def _env_int(name: str, default: int) -> int:
     try:
@@ -500,6 +495,71 @@ def _env_float(name: str, default: float) -> float:
         logger.warning("malformed %s=%r; using %s", name,
                        os.environ.get(name), default)
         return default
+
+
+def process_start_unix() -> float:
+    """When the OS started this process, on the wall clock — so a span
+    that begins here counts the interpreter's start and the imports. From
+    ``/proc`` (start time in clock ticks since boot, against the uptime:
+    good to about 10 ms); the time of the call where there is no
+    ``/proc``."""
+    now = time.time()
+    try:
+        with open("/proc/self/stat") as f:
+            # fields after the parenthesised command name, which may
+            # itself hold spaces: starttime is field 22 of the line
+            ticks = float(f.read().rpartition(")")[2].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        age = uptime - ticks / os.sysconf("SC_CLK_TCK")
+        return now - age if 0.0 <= age < 7 * 86400.0 else now
+    except (OSError, ValueError, IndexError):
+        return now
+
+
+class StartupTrace:
+    """A process's ``startup`` trace: one root that runs from the
+    process's own start to the moment it reports ready, with a child per
+    stage of the way (``with startup.stage("startup.weights"): ...``).
+    Nothing reaches the tracer until ``finish``: the tracer's service
+    name and export path are often settled late in start-up."""
+
+    def __init__(self) -> None:
+        self.start_unix = process_start_unix()
+        self.stages: List[tuple] = []
+        self._last: Optional[tuple] = None
+
+    @contextlib.contextmanager
+    def stage(self, name: str) -> Iterator[None]:
+        t0 = time.time()
+        try:
+            yield
+        finally:
+            self.stages.append((name, t0, time.time()))
+
+    def stage_since_start(self, name: str) -> None:
+        """A stage that began with the process (the imports)."""
+        self.stages.append((name, self.start_unix, time.time()))
+
+    def stage_until_ready(self, name: str) -> None:
+        """The last stage: from now until ``finish``."""
+        self._last = (name, time.time())
+
+    def finish(self, tracer: "Tracer",
+               attrs: Optional[Dict[str, Any]] = None) -> None:
+        """Ready: finalize the trace into ``tracer`` (its ring,
+        ``/v1/traces`` and ``DYN_TRACE_EXPORT`` like any other)."""
+        root = tracer.start_span("startup", attrs=attrs, current=False)
+        if root is NOOP_SPAN:
+            return
+        root.start_unix = self.start_unix
+        end = time.time()
+        if self._last is not None:
+            self.stages.append(self._last + (end,))
+            self._last = None
+        for name, t0, t1 in self.stages:
+            tracer.record(name, t0, t1, parent=root)
+        root.finish(end_unix=end)
 
 
 # ---------------------------------------------------------------------------
@@ -598,6 +658,8 @@ __all__ = [
     "Span",
     "Tracer",
     "StageStitcher",
+    "StartupTrace",
+    "process_start_unix",
     "get_tracer",
     "set_tracer",
     "TRACE_ID_HEADER",

@@ -175,7 +175,20 @@ def arm_guided(engine, card) -> None:
             "guided decoding disabled: token_bytes extraction failed")
 
 
-def build_engine(args: argparse.Namespace) -> JaxEngine:
+def build_engine(args: argparse.Namespace, startup=None) -> JaxEngine:
+    """``startup`` (a ``utils/tracing.StartupTrace``) gets the two stages
+    of the build: ``startup.weights`` (configuration, mesh, parameters)
+    and ``startup.engine`` (page pools, jit wrappers). Callers that keep
+    no startup trace (run.py, step followers) pass none."""
+    from dynamo_tpu.utils.tracing import StartupTrace
+    startup = startup or StartupTrace()
+    with startup.stage("startup.weights"):
+        cfg, engine_cfg, forward_fn, params = _build_weights(args)
+    with startup.stage("startup.engine"):
+        return JaxEngine(cfg, params, engine_cfg, forward_fn=forward_fn)
+
+
+def _build_weights(args: argparse.Namespace):
     # every process that compiles serving programs (this worker, run.py)
     # builds its engine here: hold it to its platform and open the
     # persistent compile cache before the first computation
@@ -271,7 +284,7 @@ def build_engine(args: argparse.Namespace) -> JaxEngine:
         params = load_gguf_params(cfg, args.model_path)
     else:
         params = load_hf_params(cfg, args.model_path)
-    return JaxEngine(cfg, params, engine_cfg, forward_fn=forward_fn)
+    return cfg, engine_cfg, forward_fn, params
 
 
 def engine_placement(engine: JaxEngine) -> dict:
@@ -290,6 +303,11 @@ def engine_placement(engine: JaxEngine) -> dict:
 
 
 async def amain(args: argparse.Namespace) -> None:
+    # the ``startup`` trace: from the process's own start (so the imports
+    # count) to the ready line, a child per stage (docs/observability.md)
+    from dynamo_tpu.utils.tracing import StartupTrace
+    startup = StartupTrace()
+    startup.stage_since_start("startup.imports")
     # accept HF repo ids as well as local dirs/.gguf (reference: hub.rs)
     from dynamo_tpu.models.hub import resolve_model_path
     args.model_path = resolve_model_path(args.model_path)
@@ -316,13 +334,17 @@ async def amain(args: argparse.Namespace) -> None:
     card.num_top_logprobs = args.num_top_logprobs
     endpoint = (drt.namespace(args.namespace).component(args.component)
                 .endpoint(args.endpoint))
-    engine = build_engine(args)
+    engine = build_engine(args, startup)
     # advertise the engine's sparse penalty/logit_bias window so the
     # frontend preprocessor rejects requests the device would truncate
     card.penalty_window = engine.cfg.penalty_window
-    # arm guided decoding (response_format): the engine needs the
-    # tokenizer's byte view of the vocabulary to walk grammar masks
-    arm_guided(engine, card)
+    # what the worker warms before it reports ready: today the guided
+    # decoder's byte vocabulary (response_format: the engine needs the
+    # tokenizer's byte view to walk grammar masks) and no step program
+    with startup.stage("startup.prime"):
+        arm_guided(engine, card)
+    # endpoints, model registration, system server: until the ready line
+    startup.stage_until_ready("startup.register")
 
     # a dead engine loop takes the worker's registration down with it, so
     # routers stop sending to a zombie (reference: task.rs critical tasks)
@@ -600,6 +622,8 @@ async def amain(args: argparse.Namespace) -> None:
     install_signal_drain(drain)
     if system is not None:
         system.register_drain(drain)
+    startup.finish(tracer, attrs={"model": card.name,
+                                  "platform": placement["platform"]})
     print(f"jax worker serving model {card.name} "
           f"platform={placement['platform']} "
           f"device_kind={placement['device_kind']!r} "

@@ -239,9 +239,11 @@ class StepTraceCollector:
                                                  _OCC_BOUNDS)
         dur = HistogramMetricFamily(
             "dynamo_worker_step_duration_seconds",
-            "Engine dispatch wall time by kind (prefill/decode/chained/"
-            "multistep/mixed/spec/gather) — the host-side dispatch call, "
-            "which includes compile time on a fresh jit bucket",
+            "Device time of one dispatch by kind (prefill/decode/chained/"
+            "multistep/mixed/spec/gather), as the host sees it: from the "
+            "later of its enqueue and the previous result's arrival to "
+            "its own result's arrival (StepRecord.device_ms) — includes "
+            "compile time on a fresh jit bucket",
             labels=["kind"])
         occ = HistogramMetricFamily(
             "dynamo_worker_step_occupancy",
@@ -293,6 +295,16 @@ class StepTraceCollector:
             secs.add_metric([kind], float(csec.get(kind, 0.0)))
         yield ev
         yield secs
+        wait = CounterMetricFamily(
+            "dynamo_worker_loop_wait_seconds",
+            "Seconds the engine loop spent with nothing to dispatch: idle "
+            "(no request queued or running) or blocked (requests waiting, "
+            "KV cache full)",
+            labels=["state"])
+        waits = dict(agg.get("loop_wait_s") or {})
+        for state in ("idle", "blocked"):
+            wait.add_metric([state], float(waits.get(state, 0.0)))
+        yield wait
 
 
 def engine_dispatch_stats(engine) -> Dict[str, object]:

@@ -30,11 +30,17 @@ def fresh_recorder():
     set_step_recorder(None)
 
 
-def stamp(rec, kind="decode", **kw):
+def stamp(rec, kind="decode", device_ms=5.0, **kw):
+    """One dispatch whose result arrives ``device_ms`` after its enqueue
+    began (the duration histogram observes device time, at ready)."""
     defaults = dict(rows=2, batch=4, tokens_real=2, tokens_padded=4,
                     dispatch_ms=5.0)
     defaults.update(kw)
-    return rec.record(kind, **defaults)
+    # enqueued once the previous result is in, so it has the device alone
+    t0 = max(time.perf_counter(), rec._last_ready)
+    r = rec.record(kind, enqueue=t0, **defaults)
+    rec.note_ready(r, t0 + device_ms / 1e3, time.time())
+    return r
 
 
 # -- unit: the ring ---------------------------------------------------------
@@ -77,11 +83,15 @@ class TestRing:
     def test_unpack_and_compile_patching(self):
         rec = StepRecorder(capacity=8, enabled=True)
         r = stamp(rec, kind="multistep", width=8, gap_ms=2.0)
-        rec.note_unpack(r, 0.7)
+        rec.note_unpack(r, 0.5, 0.2)
         rec.note_compile("multistep", 2.5, r)
         d = rec.snapshot(limit=1)["records"][0]
-        assert d["unpack_ms"] == 0.7 and d["compile_ms"] == 2500.0
-        rec.note_unpack(None, 1.0)  # disabled/absent record is a no-op
+        assert d["fetch_ms"] == 0.5 and d["process_ms"] == 0.2
+        assert d["unpack_ms"] == d["fetch_ms"] + d["process_ms"]
+        assert d["compile_ms"] == 2500.0
+        assert "_enqueue" not in d  # the private stamp is not exported
+        rec.note_unpack(None, 1.0, 1.0)  # absent record is a no-op
+        rec.note_ready(None, 1.0, 1.0)
 
     def test_aggregates_shape(self):
         rec = StepRecorder(capacity=8, enabled=True)
@@ -386,7 +396,8 @@ def test_perfetto_steptrace_merge(tmp_path, fresh_recorder):
     src = tmp_path / "traces.jsonl"
     src.write_text(json.dumps(tracer.get_trace(root.trace_id)) + "\n")
 
-    r1 = stamp(fresh_recorder, kind="multistep", width=8, gap_ms=0.4)
+    r1 = stamp(fresh_recorder, kind="multistep", width=8, gap_ms=0.4,
+               dispatch_ms=5.0)
     fresh_recorder.note_compile("multistep", 1.5, r1)
     stamp(fresh_recorder, kind="decode", fallback="guided")
     steps = tmp_path / "steps.json"
@@ -413,9 +424,292 @@ def test_perfetto_steptrace_merge(tmp_path, fresh_recorder):
     assert "compile" in comp["cat"] and comp["args"]["compile_ms"] == 1500.0
     fb = by_name["decode"]
     assert "fallback" in fb["cat"] and fb["args"]["fallback"] == "guided"
-    # step events share the wall-clock timeline with the request spans
+    # step events share the wall-clock timeline with the request spans,
+    # and span the DEVICE time: they end when the result was on the host
     rec = fresh_recorder.snapshot(limit=10)["records"][0]
-    assert any(e["ts"] == pytest.approx(rec["t_unix"] * 1e6)
+    assert any(e["ts"] + e["dur"] == pytest.approx(rec["ready_unix"] * 1e6)
                for e in steps_x)
-    # newest-first record maps dur = dispatch_ms in microseconds
+    # dur = device_ms in microseconds, not the enqueue (dispatch_ms)
     assert all(e["dur"] == pytest.approx(5.0 * 1e3) for e in steps_x)
+    assert comp["args"]["dispatch_ms"] == 5.0
+
+
+# -- device time per dispatch, the loop's phases, the profile hook ----------
+
+
+class TestDeviceTime:
+    async def test_chained_overlap_splits_the_wall_time(self, fresh_recorder):
+        """An engine whose device runs fused blocks of 60 ms in order and
+        whose fetch blocks until the block is done: the loop enqueues block
+        N+1 before it fetches block N, and each block's ``device_ms`` is
+        its own 60 ms - the two sum to the wall time from the first
+        enqueue to the second result, neither counts the other."""
+        from dynamo_tpu.mocker.engine import MockEngineArgs, MockerEngine
+        block_s = 0.06
+
+        class QueuedDevice(MockerEngine):
+            free_at = 0.0           # when the device finishes what it has
+
+            def dispatch_multistep(self, plan, prev_handle=None):
+                self.args.speedup_ratio = 0      # no sleep in the enqueue
+                handle = super().dispatch_multistep(plan, prev_handle)
+                self.free_at = max(time.perf_counter(),
+                                   self.free_at) + block_s
+                return handle + (self.free_at,)
+
+            def fetch_packed_block(self, handle):
+                time.sleep(max(0.0, handle[2] - time.perf_counter()))
+                return super().fetch_packed_block(handle[:2])
+
+        eng = QueuedDevice(MockEngineArgs(
+            num_pages=64, page_size=4, max_num_seqs=4, max_context=256,
+            decode_multistep=8, speedup_ratio=100.0))
+        try:
+            await collect(eng, make_req([1, 2, 3, 4, 5], "ov", max_tokens=40))
+        finally:
+            await eng.stop()
+        recs = fresh_recorder.snapshot(limit=256)["records"][::-1]
+        blocks = [r for r in recs if r["kind"] == "multistep"]
+        chained = [r for r in blocks if r["chained"]]
+        assert len(blocks) >= 3 and chained
+        for r in blocks:
+            # the enqueue returns at once; the device time is the block's
+            assert r["dispatch_ms"] < 0.5 * block_s * 1e3
+            assert r["device_ms"] == pytest.approx(block_s * 1e3, rel=0.25)
+            assert r["ready_unix"] >= r["t_unix"]
+            assert r["unpack_ms"] == r["fetch_ms"] + r["process_ms"]
+            assert r["fetch_ms"] > 0 and r["process_ms"] > 0
+        # a chained block was enqueued before its predecessor's result came
+        i = blocks.index(chained[0])
+        prev, nxt = blocks[i - 1], blocks[i]
+        assert nxt["t_unix"] < prev["ready_unix"]
+        wall_ms = (nxt["ready_unix"] - prev["ready_unix"]) * 1e3 \
+            + prev["device_ms"]
+        assert prev["device_ms"] + nxt["device_ms"] == \
+            pytest.approx(wall_ms, abs=2.0)
+        # synchronous kinds: the result is on the host when the dispatch
+        # returns, and there is nothing to fetch
+        sync = [r for r in recs if r["kind"] in ("prefill", "mixed")]
+        assert sync and all(r["fetch_ms"] == 0.0 and r["device_ms"] > 0
+                            and r["unpack_ms"] == r["process_ms"]
+                            for r in sync)
+        # the duration histogram holds device time, observed at ready
+        agg = fresh_recorder.aggregates()
+        _cum, total, n = agg["duration"]["multistep"]
+        assert n == len(blocks)
+        assert total == pytest.approx(
+            sum(r["device_ms"] for r in blocks) / 1e3)
+        assert set(agg["loop_wait_s"]) == {"idle", "blocked"}
+
+    async def test_idle_wait_is_counted(self, fresh_recorder):
+        from dynamo_tpu.mocker.engine import MockEngineArgs, MockerEngine
+        eng = MockerEngine(MockEngineArgs(num_pages=64, page_size=4,
+                                          max_num_seqs=4, max_context=256,
+                                          speedup_ratio=100.0))
+        try:
+            await collect(eng, make_req([1, 2, 3], "i1", max_tokens=4))
+            await asyncio.sleep(0.15)     # the loop waits for a request
+            await collect(eng, make_req([4, 5, 6], "i2", max_tokens=4))
+        finally:
+            await eng.stop()
+        assert fresh_recorder.loop_wait_s["idle"] >= 0.1
+        assert fresh_recorder.loop_wait_s["blocked"] == 0.0
+        from prometheus_client import generate_latest
+
+        from dynamo_tpu.worker.metrics import WorkerMetrics
+        wm = WorkerMetrics()
+        wm.steptrace.attach(fresh_recorder.aggregates)
+        out = generate_latest(wm.registry).decode()
+        assert 'dynamo_worker_loop_wait_seconds_total{state="blocked"} 0.0' \
+            in out
+        assert 'dynamo_worker_loop_wait_seconds_total{state="idle"}' in out
+
+    async def test_records_name_their_program(self, fresh_recorder):
+        from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
+        from dynamo_tpu.models.config import ModelConfig
+        eng = JaxEngine.random_init(
+            ModelConfig.tiny(),
+            JaxEngineConfig(num_pages=64, page_size=4, max_num_seqs=4,
+                            max_prefill_chunk=16, max_context=64,
+                            min_prefill_bucket=4, decode_multistep=8))
+        try:
+            await collect(eng, make_req([1, 2, 3, 4, 5], "p1", max_tokens=12))
+        finally:
+            await eng.stop()
+        recs = fresh_recorder.snapshot(limit=256)["records"]
+        programs = {r["kind"]: r["program"] for r in recs}
+        # the step program and its bucket, from the key the engine builds
+        assert programs["prefill"].startswith("step[")
+        assert programs["multistep"].startswith("multistep")
+        assert all(r["program"].endswith("]") for r in recs)
+
+
+def test_module_names_the_benchmark_matches_on():
+    """``benchmarks/layer_metrics/step.decode_hbm_share.py`` finds the
+    decode programs on the trace's ``XLA Modules`` line by name: the
+    one-step program, the mixed program and the fused block (a closure:
+    ``unknown``) keep the names they had when the benchmark was accepted,
+    whatever scopes and kernel names are added inside them."""
+    import numpy as np
+
+    from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
+    from dynamo_tpu.models.config import ModelConfig
+
+    class Lowered(Exception):
+        pass
+
+    # the Pallas kernels' geometry (head_dim % 128, page_size % 8); only
+    # lowered here, in interpret mode, never run
+    eng = JaxEngine.random_init(
+        ModelConfig.tiny(head_dim=128, num_heads=2, num_kv_heads=1,
+                         hidden_size=128),
+        JaxEngineConfig(num_pages=16, page_size=8, max_num_seqs=4,
+                        max_prefill_chunk=16, max_context=64,
+                        attn_impl="pallas", decode_multistep=8))
+    names = {}
+
+    def spy(label, fn):
+        def call(*args, **kw):
+            # "module @jit__step_impl attributes {...": lowered, never run
+            names[label] = fn.lower(*args, **kw).as_text().split(None, 2)[1]
+            raise Lowered
+        return call
+
+    assert eng._jit_mixed is not eng._jit_step     # the Pallas path's own
+    eng._jit_step = spy("step", eng._jit_step)
+    eng._jit_mixed = spy("mixed", eng._jit_mixed)
+    eng._jit_ms[8] = spy("multistep", eng._get_jit_multistep(8))
+    B, S = 4, 8
+    arrays = {"toks": np.zeros((B, S), np.int32),
+              "pos": np.tile(np.arange(S, dtype=np.int32), (B, 1)),
+              "table": np.zeros((B, eng.table_width), np.int32),
+              "total": np.full(B, S, np.int32),
+              "new": np.full(B, S, np.int32),
+              "temp": np.zeros(B, np.float32),
+              "top_k": np.zeros(B, np.int32),
+              "top_p": np.ones(B, np.float32)}
+    for kind in ("step", "mixed"):
+        with pytest.raises(Lowered):
+            eng.execute_arrays(kind, arrays, 0)
+    with pytest.raises(Lowered):
+        eng.prime_multistep(B, widths=[8])
+    assert names == {"step": "@jit__step_impl",
+                     "mixed": "@jit__mixed_step_impl",
+                     "multistep": "@jit__unknown"}
+
+
+class TestProfileHook:
+    async def test_profile_carries_the_loops_phases_by_seq(
+            self, fresh_recorder):
+        """``POST /v1/profile`` on the worker's system server: the profile
+        it writes holds ``loop.plan``/``loop.dispatch``/``loop.fetch``
+        events whose ``seq`` are the ring's, each dispatch under its
+        kind; one profile at a time."""
+        import glob
+
+        from jax.profiler import ProfileData
+
+        from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
+        from dynamo_tpu.models.config import ModelConfig
+        from dynamo_tpu.runtime.system_server import SystemServer
+        eng = JaxEngine.random_init(
+            ModelConfig.tiny(),
+            JaxEngineConfig(num_pages=64, page_size=4, max_num_seqs=4,
+                            max_prefill_chunk=16, max_context=64,
+                            min_prefill_bucket=4, decode_multistep=8))
+        server = await SystemServer(host="127.0.0.1", port=0,
+                                    steptrace=eng.steptrace).start()
+        url = f"http://127.0.0.1:{server.port}/v1/profile"
+        try:
+            # warm the buckets, so the profile holds steady-state steps
+            await collect(eng, make_req([1, 2, 3, 4, 5], "w", max_tokens=20))
+            first_seq = fresh_recorder.total
+            async with aiohttp.ClientSession() as http:
+                async def post(body):
+                    async with http.post(url, json=body) as r:
+                        return r.status, await r.json()
+                for bad in ({"seconds": 0}, {"seconds": 1e9},
+                            {"seconds": "x"}):
+                    assert (await post(bad))[0] == 400
+                taking = asyncio.ensure_future(post({"seconds": 1.0}))
+                await asyncio.sleep(0.3)
+                assert (await post({"seconds": 0.1}))[0] == 409
+                await collect(eng, make_req([9, 8, 7, 6, 5], "p",
+                                            max_tokens=20))
+                status, reply = await taking
+            assert status == 200 and reply["seconds"] == 1.0
+            assert reply["stop_unix"] - reply["start_unix"] >= 1.0
+        finally:
+            await server.stop()
+            await eng.stop()
+        import shutil
+        paths = glob.glob(os.path.join(reply["dir"], "**", "*.xplane.pb"),
+                          recursive=True)
+        assert paths, reply
+        events = []
+        for plane in ProfileData.from_file(paths[0]).planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("loop."):
+                        stats = dict(ev.stats)
+                        # an empty kind (a plan, a wait) leaves no stat
+                        events.append((ev.name, stats["seq"],
+                                       stats.get("kind", "")))
+        shutil.rmtree(reply["dir"], ignore_errors=True)
+        ring = {r["seq"]: r["kind"]
+                for r in fresh_recorder.snapshot(limit=256)["records"]
+                if r["seq"] >= first_seq}
+        assert ring
+        for phase in ("loop.plan", "loop.dispatch", "loop.fetch",
+                      "loop.process"):
+            seqs = {seq for name, seq, _k in events if name == phase}
+            assert seqs & set(ring), phase
+        # every dispatch and fetch annotation of a recorded dispatch
+        # carries that record's kind
+        for name, seq, kind in events:
+            if name in ("loop.dispatch", "loop.fetch") and seq in ring:
+                assert kind == ring[seq], (name, seq)
+        # the wait for the second request shows as loop.idle
+        assert any(name == "loop.idle" for name, _s, _k in events)
+
+
+def test_xplane_scopes_reads_the_scope_from_event_metadata(tmp_path):
+    """``tools/xplane_scopes.py`` on a small synthetic trace: the scope is
+    the ``tf_op`` stat of the operation's event METADATA on the device
+    plane's ``XLA Ops`` line (where a TPU profile keeps it); loops are
+    left out, operations are summed and sorted by time."""
+    xplane_pb2 = pytest.importorskip(
+        "tensorflow.tsl.profiler.protobuf.xplane_pb2")
+    space = xplane_pb2.XSpace()
+    plane = space.planes.add(name="/device:TPU:0")
+    for i, name in enumerate(("hlo_category", "tf_op", "source"), start=1):
+        plane.stat_metadata[i].name = name
+
+    def op(mid, display, category, scope="", source=""):
+        md = plane.event_metadata[mid]
+        md.name, md.display_name = f"%{display} = ...", display
+        for stat_id, value in ((1, category), (2, scope), (3, source)):
+            if value:
+                md.stats.add(metadata_id=stat_id, str_value=value)
+    op(1, "copy.124", "data formatting",
+       "jit(<unknown>)/while/body/layer.kv_write/scatter:", "attention.py:77")
+    op(2, "fusion.181", "convolution fusion",
+       "jit(_mixed_step_impl)/while/body/layer.ffn/dot_general:")
+    op(3, "while.38", "while")
+    line = plane.lines.add(name="XLA Ops")
+    for mid, ps in ((3, 9_000_000), (1, 4_000_000), (2, 3_000_000),
+                    (1, 4_000_000)):
+        line.events.add(metadata_id=mid, duration_ps=ps)
+    space.planes.add(name="/host:CPU").lines.add(name="python3")
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(space.SerializeToString())
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                    "tools"))
+    import xplane_scopes
+    rows = xplane_scopes.top_ops(str(tmp_path))
+    assert [(r[2], r[1]) for r in rows] == [("copy.124", 2),
+                                            ("fusion.181", 1)]
+    assert rows[0][0] == pytest.approx(8e-6)
+    assert rows[0][4].endswith("layer.kv_write/scatter")
+    assert rows[0][5] == "attention.py:77" and rows[1][5] == ""
+    assert xplane_scopes.main([str(path), "--top", "1"]) == 0

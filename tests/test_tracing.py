@@ -541,3 +541,85 @@ def test_log_records_carry_trace_context(fresh_tracer, capsys):
         assert out["request_id"] == "rid-9"
     finally:
         root.finish()
+
+
+# -- the worker's startup trace ----------------------------------------------
+
+STARTUP_STAGES = ["startup.imports", "startup.weights", "startup.engine",
+                  "startup.prime", "startup.register"]
+
+
+def test_startup_trace_runs_from_process_start_to_ready():
+    import time
+
+    from dynamo_tpu.utils.tracing import StartupTrace, process_start_unix
+    # the OS's start time of this process: before now, and the same twice
+    born = process_start_unix()
+    assert 0.0 < time.time() - born < 7 * 86400
+    assert abs(process_start_unix() - born) < 0.05
+    startup = StartupTrace()
+    startup.stage_since_start("startup.imports")
+    for name in STARTUP_STAGES[1:4]:
+        with startup.stage(name):
+            time.sleep(0.002)
+    startup.stage_until_ready("startup.register")
+    time.sleep(0.002)
+    t = Tracer(service="worker", capacity=4)
+    startup.finish(t, attrs={"model": "m"})
+    summary = t.traces()["traces"][0]
+    assert summary["name"] == "startup" and summary["num_spans"] == 6
+    rec = t.get_trace(summary["trace_id"])
+    root = next(s for s in rec["spans"] if s["name"] == "startup")
+    assert root["kind"] == "root" and root["attrs"] == {"model": "m"}
+    assert root["start_unix"] == pytest.approx(born, abs=0.05)
+    kids = [s for s in rec["spans"] if s["name"] != "startup"]
+    assert [s["name"] for s in kids] == STARTUP_STAGES     # in order
+    assert all(s["parent_span_id"] == root["span_id"] for s in kids)
+    assert kids[0]["start_unix"] == root["start_unix"]
+    for a, b in zip(kids, kids[1:]):
+        assert a["end_unix"] <= b["start_unix"]
+    # the last stage and the root end together, at ready
+    assert kids[-1]["end_unix"] == root["end_unix"]
+    # a disabled tracer takes nothing and does not mind
+    StartupTrace().finish(Tracer(enabled=False))
+
+
+@pytest.mark.async_timeout(240)
+async def test_worker_writes_its_startup_trace_when_ready(tmp_path):
+    """A real ``worker.main`` process: by its ready line the ``startup``
+    trace is in ``DYN_TRACE_EXPORT``, its five children in order, starting
+    with the process and ending at ready."""
+    import time
+
+    from dynamo_tpu.runtime.coordinator import Coordinator
+    from dynamo_tpu.utils.testing import make_test_model_dir
+    from tests.procutils import ManagedProcess
+    model_dir = make_test_model_dir(str(tmp_path / "m"), vocab_size=512)
+    export = tmp_path / "worker.traces.jsonl"
+    coord = await Coordinator(port=0).start()
+    t_spawn = time.time()
+    worker = ManagedProcess(
+        ["dynamo_tpu.worker.main", "--coordinator",
+         f"127.0.0.1:{coord.port}", "--model-path", model_dir,
+         "--model-name", "s-model", "--random-weights",
+         "--page-size", "4", "--num-pages", "64", "--max-num-seqs", "4",
+         "--max-prefill-chunk", "32", "--max-context", "256"],
+        name="startup-worker", ready_line="jax worker serving",
+        timeout=180.0, env_overrides={"DYN_TRACE_EXPORT": str(export)})
+    try:
+        async with worker:
+            t_ready = time.time()
+            with open(export) as f:
+                records = [json.loads(line) for line in f]
+    finally:
+        await coord.stop()
+    rec = next(r for r in records if r["name"] == "startup")
+    assert rec["service"] == "worker"
+    kids = [s for s in rec["spans"] if s["name"] != "startup"]
+    assert [s["name"] for s in kids] == STARTUP_STAGES
+    assert t_spawn - 0.1 <= rec["start_unix"] <= t_spawn + 2.0
+    end = rec["start_unix"] + rec["duration_s"]
+    assert end <= t_ready + 0.1 and kids[-1]["end_unix"] == \
+        pytest.approx(end, abs=1e-3)
+    # the stages cover the start-up: imports and the engine build dominate
+    assert sum(s["duration_s"] for s in kids) >= 0.8 * rec["duration_s"]

@@ -13,8 +13,10 @@ With ``--steptrace`` the engine's step flight recorder (a saved
 ``GET /v1/steptrace`` body, see ``dynamo_tpu/engine/steptrace.py``) merges
 onto the same timeline as an ``engine-steps`` process track: every dispatch
 (prefill/decode/chained/multistep/mixed/spec/gather) renders as a complete
-event whose args carry rows/tokens/queue-depth/page-pool state, with compile
-time and fallback demotions flagged — so a TTFT spike in the request flame
+event spanning its DEVICE time (``device_ms`` up to ``ready_unix``, not the
+enqueue call) whose args carry the program's name, rows/tokens/queue-depth/
+page-pool state and the host's phase times, with compile time and fallback
+demotions flagged — so a TTFT spike in the request flame
 chart lines up against the exact engine step (and compile, and pool
 pressure) that caused it.
 
@@ -61,7 +63,11 @@ def step_events(records, pid: int) -> list:
     """StepRecords -> complete events on one ``engine-steps`` process
     track, one thread per dispatch kind (dispatches of one kind never
     overlap — the engine loop serialises them — so time containment
-    cannot mis-stack)."""
+    cannot mis-stack). An event spans the record's device time: it ends
+    when the result was on the host (``ready_unix``) and lasts
+    ``device_ms``. A record without them (a body saved before the ring
+    had them, a dispatch whose result never came) falls back to the
+    dispatch call."""
     events = [{"name": "process_name", "ph": "M", "pid": pid, "tid": 0,
                "args": {"name": "engine-steps"}}]
     kinds = {}
@@ -77,16 +83,23 @@ def step_events(records, pid: int) -> list:
         if r.get("fallback"):
             cat += ",fallback"
         args = {k: r[k] for k in
-                ("seq", "width", "rows", "batch", "tokens_real",
+                ("seq", "program", "width", "rows", "batch", "tokens_real",
                  "tokens_padded", "queue_depth", "running", "pool_free",
-                 "pool_pinned", "plan_ms", "unpack_ms", "gap_ms",
-                 "compile_ms", "fallback", "chained") if r.get(k)}
+                 "pool_pinned", "plan_ms", "dispatch_ms", "fetch_ms",
+                 "process_ms", "unpack_ms", "gap_ms", "compile_ms",
+                 "fallback", "chained") if r.get(k)}
+        if r.get("ready_unix"):
+            dur_ms = float(r.get("device_ms", 0.0))
+            ts = float(r["ready_unix"]) - dur_ms / 1e3
+        else:
+            dur_ms = float(r.get("dispatch_ms", 0.0))
+            ts = float(r.get("t_unix", 0.0))
         events.append({
             "name": (f"{kind}x{r['width']}" if r.get("width")
                      else kind),
             "cat": cat, "ph": "X",
-            "ts": float(r.get("t_unix", 0.0)) * 1e6,
-            "dur": max(0.0, float(r.get("dispatch_ms", 0.0))) * 1e3,
+            "ts": ts * 1e6,
+            "dur": max(0.0, dur_ms) * 1e3,
             "pid": pid, "tid": kinds[kind],
             "args": args,
         })
