@@ -8,7 +8,9 @@ the chip and ``dynamo_tpu.frontend.main``, three separate processes — sends
 a few OpenAI requests that make every hot-path program compile and run, and
 checks what comes back. Before the servers start, a child of its own
 compiles each of the five Pallas kernels natively at serving widths and
-compares it with the XLA path it replaces.
+compares it with the XLA path it replaces, then compiles the decode, fused
+and mixed step programs at the serving geometry and reads their HLO: none
+may copy the page pool or hold a temporary of its size.
 
     python3 chip_smoke.py                            one chip (the contract)
     python3 chip_smoke.py --replicas 4               four one-chip workers
@@ -295,6 +297,12 @@ class Smoke:
                 f"{k['seconds']:.1f}s  max|kernel-xla|={k['max_abs_err']:.2e}"
                 f" = {k['share_of_bound']:.2f} of the bound "
                 f"{KERNEL_TOL:g}*(1+|xla|)")
+        for r in report.get("programs", []):
+            say(f"program {r['program']:<7} pool "
+                f"{'x'.join(map(str, r['pool_shape']))} "
+                f"({r['pool_bytes'] / 1e9:.2f} GB): no pool-sized copy, "
+                f"temporaries {r['temp_bytes'] / 1e9:.3f} GB "
+                f"(compiled in {r['seconds']:.0f}s)")
         need = max(self.args.replicas, self.args.tensor_parallel_size)
         if not self.dry and device["count"] < need:
             raise Failed(f"this variant needs {need} chips, jax sees "
@@ -694,7 +702,52 @@ def device_child(kernels: bool, dry: bool) -> None:
                          "kind": devs[0].device_kind, "count": len(devs)}}
     if kernels:
         report["kernels"] = run_kernels(dry)
+        report["programs"] = check_programs(dry)
     print("DEVICE_CHILD " + json.dumps(report), flush=True)
+
+
+def check_programs(dry: bool) -> list:
+    """Compile the decode step, the fused block and the mixed step at the
+    geometry ``start_servers`` gives the worker (from shapes: no weights,
+    no pool on the device) and fail on a pool-sized copy in the HLO or a
+    temporary as large as the pool (``engine/program_check.py``)."""
+    import jax
+
+    from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
+    from dynamo_tpu.engine.program_check import check_step_programs
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import ModelConfig
+
+    if dry:
+        # f32 (the CPU backend widens a bf16 scatter's operand, a copy no
+        # chip makes) and a pool several times the context the XLA
+        # attention path gathers for a full batch
+        cfg = ModelConfig.tiny(vocab_size=512, head_dim=128)
+        geometry = dict(page_size=8, max_num_seqs=8, max_prefill_chunk=64,
+                        max_context=512, attn_impl="scan")
+        num_pages, batch, chunk = 4096, 8, 64
+    else:
+        cfg = ModelConfig.llama32_3b()
+        geometry = dict(page_size=16, max_num_seqs=32, max_prefill_chunk=512,
+                        max_context=8192, attn_impl="pallas")
+        num_pages, batch, chunk = 2048, 32, 512
+    params = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    # the engine allocates its own pool: a token one, the check's pool is
+    # a shape
+    engine = JaxEngine(cfg, params, JaxEngineConfig(num_pages=16, **geometry))
+    t0 = time.monotonic()
+    reports = check_step_programs(engine, batch, chunk, width=8,
+                                  num_pages=num_pages)
+    for r in reports:
+        r["seconds"] = round((time.monotonic() - t0) / len(reports), 1)
+        if not r["ok"]:
+            raise Failed(
+                f"step program {r['program']}: {len(r['pool_copies'])} "
+                f"pool-sized copies, temporaries {r['temp_bytes']} B beside "
+                f"a pool of {r['pool_bytes']} B:\n"
+                + "\n".join(c[:300] for c in r["pool_copies"]))
+    return reports
 
 
 def run_kernels(dry: bool) -> list:

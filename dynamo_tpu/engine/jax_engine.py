@@ -10,7 +10,13 @@ the framework and designed for XLA:
   programs is small and fixed. The page-table width is static
   (``max_context / page_size``), so no shape depends on sequence length.
 - The paged KV cache is ONE device array, donated through every step
-  (``donate_argnums``), so XLA updates it in place — zero cache copies.
+  (``donate_argnums``), and every step program updates it in place: the
+  cache write scatters whole pages (``ops/attention._write_pages``), the
+  window that the pool's row-major layout — the one the kernels'
+  ``pl.ANY`` operand pins — holds contiguously. A write the compiler has
+  to re-lay the pool for costs a copy of all of it per layer and shows
+  nowhere in the source; ``engine/program_check.py`` reads the compiled
+  programs for one (tests, ``chip_smoke.py``).
 - Sampling runs on device in the same program as the forward pass
   (``ops/sampling.sample_tokens``): one host round-trip per step (the sampled
   token ids), nothing else.

@@ -54,8 +54,8 @@ class PageAllocator:
     """Tracks ownership of the physical KV pages of one device cache.
 
     Page ids run ``1..num_pages-1`` — page 0 is the reserved garbage page that
-    padded token positions write to (see ``ops/attention.write_kv``) and is
-    never allocated.
+    padded rows address (see ``ops/attention.write_kv``) and is never
+    allocated.
 
     Lifecycle of a page:
       free -> allocated (refcount 1, no hash) -> committed (hash registered)

@@ -13,7 +13,19 @@ Design is TPU-first:
   decode) move whole pages with unit-stride copies. (A head-major layout
   fragments every page into per-head 4 KB strips — measured ~10× worse on
   both the DMA and the XLA-gather paths.)
-- Page 0 is a reserved garbage page: padded token positions write there, which
+- New K/V are written a PAGE at a time (``_write_pages``): the pages a
+  row's new tokens fall in are gathered, the tokens laid over them, and
+  the pages scattered back. A whole page is the window that is contiguous
+  in this layout, so the scatter asks for the row-major pool the Pallas
+  kernels pin (``memory_space=pl.ANY``) and updates the donated pool in
+  place. A window per token (``[2, Hkv, Dh]``: the slot axis lies between
+  ``Hkv`` and ``Dh``) makes XLA's TPU scatter ask for a token-major pool,
+  and layout assignment copies the whole pool there and back every layer
+  (8.3 ms each for 2.7 GB on a v5e); a window per ``[Dh]`` row is in place
+  but costs ~70 ns an index, 18 ms a layer for a [32, 512] step (PERF.md
+  section 6, PR 25).
+- Page 0 is a reserved garbage page: a slab that takes no real token
+  (a padded row, the pages past a short row's end) addresses it, which
   makes every scatter shape-static and mask-free.
 - One code path serves prefill (S = chunk length) and decode (S = 1): new K/V
   is scattered into the cache first, then the full context is gathered from the
@@ -34,6 +46,55 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
+def _write_pages(pool: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
+                 v_new: jnp.ndarray, page_table: jnp.ndarray,
+                 positions: jnp.ndarray, new_lens: jnp.ndarray) -> jnp.ndarray:
+    """The cache write behind ``write_kv`` (``layer_idx`` a scalar, pool
+    ``[L, N, 2, Hkv, ps, Dh]``) and ``write_kv_layer`` (``layer_idx``
+    None, pool ``[N, 2, Hkv, ps, Dh]``), a page at a time: gather the pages
+    each row's new tokens fall in, lay the tokens over them, scatter the
+    pages back.
+
+    A row's real tokens are CONSECUTIVE positions from ``positions[b, 0]``
+    (what a prefill chunk, a decode step and a verify window are; the ring
+    path reads the prefix length off ``positions[:, 0]`` too), so they fall
+    in at most ``J`` pages in table order. Why pages and no smaller
+    window: the module docstring.
+    """
+    Hkv, page_size, Dh = pool.shape[-3:]
+    B, S = positions.shape
+    J = (S + page_size - 2) // page_size + 1
+    start = positions[:, 0]
+    first, off = start // page_size, start % page_size    # [B]
+    # pages that take a real token; every other slab is the garbage page 0
+    # written back to itself (pads land nowhere)
+    n_live = jnp.where(new_lens > 0,
+                       (off + new_lens + page_size - 1) // page_size, 0)
+    j = jnp.arange(J, dtype=start.dtype)[None, :]
+    logical = jnp.minimum(first[:, None] + j, page_table.shape[1] - 1)
+    phys = jnp.where(j < n_live[:, None],
+                     jnp.take_along_axis(page_table, logical, axis=1), 0)
+    at = pool.at[phys] if layer_idx is None else pool.at[layer_idx, phys]
+    old = at.get(mode="clip")                        # [B, J, 2, Hkv, ps, Dh]
+    # the new tokens shifted to their slots, token-major, then page-major
+    new = jnp.stack([k_new, v_new], axis=2).astype(pool.dtype)
+    if S == 1:
+        # one token: whichever slot the mask below picks holds it (XLA runs
+        # the shift as a loop over rows, 57 us a decode layer on a v5e)
+        laid = jnp.broadcast_to(new, (B, page_size, 2, Hkv, Dh))
+    else:
+        laid = jax.vmap(
+            lambda buf, row, o: jax.lax.dynamic_update_slice_in_dim(
+                buf, row, o, axis=0))(
+            jnp.zeros((B, J * page_size, 2, Hkv, Dh), pool.dtype), new, off)
+    laid = laid.reshape(B, J, page_size, 2, Hkv, Dh).transpose(
+        0, 1, 3, 4, 2, 5)
+    t = jnp.arange(J * page_size, dtype=start.dtype)[None, :]
+    real = (t >= off[:, None]) & (t < (off + new_lens)[:, None])
+    real = real.reshape(B, J, 1, 1, page_size, 1)
+    return at.set(jnp.where(real, laid, old), mode="drop")
+
+
 def write_kv_layer(kv_layer: jnp.ndarray, k_new: jnp.ndarray,
                    v_new: jnp.ndarray, page_table: jnp.ndarray,
                    positions: jnp.ndarray, new_lens: jnp.ndarray) -> jnp.ndarray:
@@ -45,37 +106,16 @@ def write_kv_layer(kv_layer: jnp.ndarray, k_new: jnp.ndarray,
     positions:  [B, S] absolute token positions of the new tokens
     new_lens:   [B] number of real (non-pad) new tokens per sequence
     """
-    page_size = kv_layer.shape[3]
-    B, S = positions.shape
-    logical = positions // page_size                       # [B, S]
-    slot = positions % page_size                           # [B, S]
-    phys = jnp.take_along_axis(page_table, logical, axis=1)  # [B, S]
-    # Padded tokens (s >= new_lens[b]) go to the reserved garbage page 0.
-    pad = jnp.arange(S)[None, :] >= new_lens[:, None]
-    phys = jnp.where(pad, 0, phys)
-    slot = jnp.where(pad, 0, slot)
-    # advanced indices (phys, slot) are separated by slices, so their
-    # broadcast dims move to the FRONT: the scatter value is [B, S, 2, Hkv, Dh]
-    new = jnp.stack([k_new, v_new], axis=2)
-    return kv_layer.at[phys, :, :, slot].set(new.astype(kv_layer.dtype),
-                                             mode="drop")
+    return _write_pages(kv_layer, None, k_new, v_new, page_table, positions,
+                       new_lens)
 
 
 def write_kv(pages: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
              v_new: jnp.ndarray, page_table: jnp.ndarray,
              positions: jnp.ndarray, new_lens: jnp.ndarray) -> jnp.ndarray:
     """Scatter new K/V into the stacked cache ``[L, N, 2, Hkv, ps, Dh]``."""
-    page_size = pages.shape[4]
-    B, S = positions.shape
-    logical = positions // page_size
-    slot = positions % page_size
-    phys = jnp.take_along_axis(page_table, logical, axis=1)
-    pad = jnp.arange(S)[None, :] >= new_lens[:, None]
-    phys = jnp.where(pad, 0, phys)
-    slot = jnp.where(pad, 0, slot)
-    new = jnp.stack([k_new, v_new], axis=2)                # [B, S, 2, Hkv, Dh]
-    return pages.at[layer_idx, phys, :, :, slot].set(
-        new.astype(pages.dtype), mode="drop")
+    return _write_pages(pages, layer_idx, k_new, v_new, page_table, positions,
+                       new_lens)
 
 
 def _softcap(scores: jnp.ndarray, cap) -> jnp.ndarray:

@@ -33,6 +33,10 @@ def test_cpu_dry_run_passes_and_says_where_it_ran():
     for kernel in ("decode", "prefill", "ragged", "mla_decode",
                    "mla_prefill"):
         assert f"kernel {kernel} " in r.stdout, r.stdout
+    # the compiled step programs were read for copies of the page pool
+    for program in ("decode", "fused", "mixed"):
+        assert f"program {program} " in r.stdout, r.stdout
+    assert r.stdout.count("no pool-sized copy") == 3
     last = json.loads(r.stdout.strip().splitlines()[-1])
     assert last == {"ok": True, "device": {"platform": "cpu", "kind": "cpu",
                                            "count": last["device"]["count"]}}

@@ -226,6 +226,7 @@ def test_tp_sharded_steps_compile_in_the_tpu_compiler():
     from jax.sharding import NamedSharding, PartitionSpec as P_
 
     from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
+    from dynamo_tpu.engine.program_check import pool_copies
     from dynamo_tpu.models import llama
     from dynamo_tpu.models.config import ModelConfig
     from dynamo_tpu.parallel.mesh import MeshSpec, make_mesh
@@ -257,7 +258,11 @@ def test_tp_sharded_steps_compile_in_the_tpu_compiler():
         return sds(leaf.shape, leaf.dtype, node)
 
     params = jax.tree_util.tree_map_with_path(param_sds, abs_params)
-    pages = sds(eng.pages.shape, eng.pages.dtype,
+    # the serving default's page count, as a shape: a pool of 16 pages the
+    # compiler would prefetch whole into faster memory
+    L_, _n, two, Hkv_, ps_, Dh_ = eng.pages.shape
+    N_ = 2048
+    pages = sds((L_, N_, two, Hkv_, ps_, Dh_), eng.pages.dtype,
                 P_(None, None, None, "tp", None, None))
     for impl, B_, S_ in ((eng._step_impl, 4, 1),
                          (eng._mixed_step_impl, 2, 128)):
@@ -271,6 +276,10 @@ def test_tp_sharded_steps_compile_in_the_tpu_compiler():
         hlo = compiled.as_text()
         assert "tpu_custom_call" in hlo
         assert "all-gather" not in hlo, "the sharded cache was gathered"
+        # nor is a chip's slab of it copied or re-laid around the cache
+        # write (engine/program_check.py)
+        assert pool_copies(hlo, (L_, N_, two, Hkv_ // 2, ps_, Dh_),
+                           eng.pages.dtype) == []
 
 
 def test_deepseek_mla_forward_lowers_for_tpu():
