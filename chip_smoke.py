@@ -626,6 +626,9 @@ class Smoke:
                 text, "dynamo_worker_compile_events_total").values())),
             "compile_seconds": sum(metric_samples(
                 text, "dynamo_worker_compile_seconds_total").values()),
+            # prefill-carrying dispatches by form: packed, padded:<why>
+            "forms": {k.split('"')[1]: int(v) for k, v in metric_samples(
+                text, "dynamo_worker_prefill_steps_total").items() if v},
         }
 
     def every_worker_served(self) -> bool:
@@ -642,6 +645,11 @@ class Smoke:
                 "programs]")
             if c["steps"].get("prefill", 0) == 0:
                 raise Failed(f"{w.name} served no request")
+            say(f"{w.name} prefill-carrying steps by form: {c['forms']}")
+            if not self.dry and set(c["forms"]) != {"packed"}:
+                # on the chip every variant here packs (the kernels, no
+                # dp, no speculation): a padded step is a silent fallback
+                raise Failed(f"{w.name} served padded steps: {c['forms']}")
             if cold and c["compile_events"] == 0:
                 raise Failed(f"{w.name} counted no compile event on a "
                              "cold start")
@@ -707,10 +715,13 @@ def device_child(kernels: bool, dry: bool) -> None:
 
 
 def check_programs(dry: bool) -> list:
-    """Compile the decode step, the fused block and the mixed step at the
-    geometry ``start_servers`` gives the worker (from shapes: no weights,
-    no pool on the device) and fail on a pool-sized copy in the HLO or a
-    temporary as large as the pool (``engine/program_check.py``)."""
+    """Compile the decode step, the fused block, the padded mixed step
+    and the token-packed step (an engine on the kernels serves its
+    prefill-carrying steps with that one) at the geometry
+    ``start_servers`` gives the worker (from shapes: no weights, no pool
+    on the device) and fail on a pool-sized copy in the HLO or a temporary
+    as large as the pool (``engine/program_check.py``). The packed program
+    RUNS in the served phase: ``check_workers`` reads its count."""
     import jax
 
     from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
@@ -725,12 +736,13 @@ def check_programs(dry: bool) -> list:
         cfg = ModelConfig.tiny(vocab_size=512, head_dim=128)
         geometry = dict(page_size=8, max_num_seqs=8, max_prefill_chunk=64,
                         max_context=512, attn_impl="scan")
-        num_pages, batch, chunk = 4096, 8, 64
+        num_pages, batch, chunk, tokens = 4096, 8, 64, None
     else:
         cfg = ModelConfig.llama32_3b()
         geometry = dict(page_size=16, max_num_seqs=32, max_prefill_chunk=512,
                         max_context=8192, attn_impl="pallas")
-        num_pages, batch, chunk = 2048, 32, 512
+        # the top of the packed ladder: a full chunk beside 32 decode rows
+        num_pages, batch, chunk, tokens = 2048, 32, 512, 640
     params = jax.eval_shape(
         lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
     # the engine allocates its own pool: a token one, the check's pool is
@@ -738,7 +750,7 @@ def check_programs(dry: bool) -> list:
     engine = JaxEngine(cfg, params, JaxEngineConfig(num_pages=16, **geometry))
     t0 = time.monotonic()
     reports = check_step_programs(engine, batch, chunk, width=8,
-                                  num_pages=num_pages)
+                                  num_pages=num_pages, tokens=tokens)
     for r in reports:
         r["seconds"] = round((time.monotonic() - t0) / len(reports), 1)
         if not r["ok"]:
@@ -757,13 +769,14 @@ def run_kernels(dry: bool) -> list:
 
     from dynamo_tpu.models import deepseek
     from dynamo_tpu.models.config import ModelConfig
-    from dynamo_tpu.ops.attention import _pad_table, paged_attention
+    from dynamo_tpu.ops.attention import (_pad_table, paged_attention,
+                                          ragged_paged_attention)
     from dynamo_tpu.ops.pallas.decode import paged_decode_attention_stacked
     from dynamo_tpu.ops.pallas.mla_decode import mla_paged_decode_stacked
     from dynamo_tpu.ops.pallas.mla_prefill import mla_paged_prefill_stacked
     from dynamo_tpu.ops.pallas.prefill import (
         paged_prefill_attention_stacked)
-    from dynamo_tpu.ops.pallas.ragged import ragged_mixed_attention_stacked
+    from dynamo_tpu.ops.pallas.ragged import ragged_mixed_attention_packed
 
     interpret = dry     # native Mosaic everywhere but the CPU dry run
     dtype = jnp.float32 if dry else jnp.bfloat16
@@ -829,16 +842,22 @@ def run_kernels(dry: bool) -> list:
               qs, pages, 1, table, pos, total, sm, interpret=interpret),
           lambda: paged_attention(qs, pages, 1, table, pos, total, sm),
           new)
-    # a mixed batch: prefill chunks and decode rows (one real query)
-    new_mixed = ([S, 1, S // 2 + 1, 1] * B)[:B]
-    total_mixed = start + jnp.asarray(new_mixed, jnp.int32)
+    # a token-packed step: prefill chunks and decode rows (one query
+    # each) back to back on one axis, pad slots behind them, against the
+    # XLA path over the same layout
+    new_mixed = jnp.asarray(([S, 1, S // 2 + 1, 1] * B)[:B], jnp.int32)
+    total_mixed = start + new_mixed
+    cu = jnp.cumsum(new_mixed) - new_mixed
+    n_real = int(new_mixed.sum())
+    qp = jax.random.normal(next(key), (-(-(n_real + 5) // 128) * 128, Hq,
+                                       Dh)).astype(dtype)
     check("ragged",
-          lambda: ragged_mixed_attention_stacked(
-              qs, pages, 1, table, pos, total_mixed, sm,
-              interpret=interpret),
-          lambda: paged_attention(qs, pages, 1, table, pos, total_mixed,
-                                  sm),
-          new_mixed)
+          lambda: ragged_mixed_attention_packed(
+              qp, pages, 1, table, cu, new_mixed, total_mixed, sm,
+              interpret=interpret)[None],
+          lambda: ragged_paged_attention(
+              qp, pages, 1, table, cu, new_mixed, total_mixed, sm)[None],
+          [n_real])
 
     # --- MLA: ops/pallas/mla_{decode,prefill} against the latent XLA path
     # of models/deepseek.py (_mla_attend for a decode step,

@@ -233,6 +233,17 @@ def _bucket(n: int, lo: int, hi: int) -> int:
     return min(b, hi)
 
 
+def _token_bucket(n: int, lo: int) -> int:
+    """The packed step's token axis ``T``: powers of two from ``lo`` up to
+    512, then steps of 128 (1,152 for the worker's default 1,024-token
+    prefill budget beside up to 64 decode rows). A ladder over the step's
+    TOKENS, so a step of three chunks and twenty decode rows pays for
+    1,152 slots and not for ``rows x longest chunk``."""
+    if n <= 512:
+        return _bucket(n, lo, 512)
+    return -(-n // 128) * 128
+
+
 class JaxEngine(ScheduledEngineBase):
     """Continuous-batching paged-KV engine over a jax Llama-family model."""
 
@@ -390,15 +401,15 @@ class JaxEngine(ScheduledEngineBase):
             from dynamo_tpu.ops.pallas.prefill import (
                 paged_prefill_attention_stacked)
             from dynamo_tpu.ops.pallas.ragged import (
-                ragged_mixed_attention_stacked)
-            # the attention op of each step shape: S == 1, a prefill
-            # chunk batch, a mixed (ragged) batch
+                ragged_mixed_attention_packed)
+            # the attention op of each step shape: S == 1, a padded
+            # prefill chunk batch, a token-packed (ragged) step
             self._attn_decode = self._per_shard(
                 paged_decode_attention_stacked, forward_fn)
             self._attn_prefill = self._per_shard(
                 paged_prefill_attention_stacked, forward_fn)
-            self._attn_mixed = self._per_shard(
-                ragged_mixed_attention_stacked, forward_fn)
+            self._attn_packed = self._per_shard(
+                ragged_mixed_attention_packed, forward_fn)
         if impl in ("scan", "pallas"):
             self.pages = llama.make_pages(model_cfg, self.cfg.num_pages,
                                           self.cfg.page_size)
@@ -446,15 +457,19 @@ class JaxEngine(ScheduledEngineBase):
         self._jit_chained = jax.jit(self._chained_step_impl,
                                     donate_argnums=(1,))
         self._jit_spec = jax.jit(self._spec_step_impl, donate_argnums=(1,))
-        # the MIXED step program (prefill chunks + decode rows in one
-        # [B, S] dispatch): on the Pallas path it swaps the S>1 attention
-        # for the ragged mixed kernel (ops/pallas/ragged.py) so decode
-        # rows skip the padded query blocks; everywhere else the program
-        # IS the plain step program (same trace — zero extra compiles)
-        self._jit_mixed = (jax.jit(self._mixed_step_impl,
-                                   donate_argnums=(1,))
-                           if self.attn_impl == "pallas"
-                           else self._jit_step)
+        # the prefill-carrying steps (a MixedStepBatch, a non-ring
+        # PrefillBatch) run TOKEN-PACKED where the engine can tell, from
+        # what it is, that they may: one [T] program for prompt chunks and
+        # decode rows (_packed_step_impl). Everywhere else they are the
+        # plain [B, S] step program, and the counter says why.
+        self.padded_reason = self._why_padded(forward_fn, family)
+        self._jit_packed = (jax.jit(self._packed_step_impl,
+                                    donate_argnums=(1,))
+                            if self.padded_reason is None else None)
+        # prefill-carrying dispatches by form: "packed", "padded:<reason>"
+        # (dynamo_worker_prefill_steps_total; the collector pre-seeds the
+        # labels, worker/metrics.py PREFILL_FORMS)
+        self.prefill_steps: Dict[str, int] = {}
         self._last_packed = None  # most recent packed output (device)
         self.ring_steps = 0  # diagnostics: sequence-parallel prefills run
         self.chained_steps = 0  # diagnostics: pipelined decode steps run
@@ -493,8 +508,8 @@ class JaxEngine(ScheduledEngineBase):
         # compile-event detection (engine/steptrace.py): the first call on
         # a fresh (jit program, B, S) bucket ALWAYS traces+compiles, so
         # its dispatch wall IS the compile cost — no threshold guessing.
-        # Seen keys use id(fn) (not the kind name) so the mixed-step alias
-        # of _jit_step shares its buckets (same trace, zero extra
+        # Seen keys use id(fn) (not the kind name) so a padded mixed step
+        # shares the plain step program's buckets (same trace, zero extra
         # compiles). Appends happen on the step worker thread, the loop
         # drains on the event-loop thread (the _moe_drops idiom).
         self._jit_seen: set = set()
@@ -542,24 +557,33 @@ class JaxEngine(ScheduledEngineBase):
             return kernel
         from jax.sharding import PartitionSpec as P
 
-        def per_shard(q, pages, layer_idx, page_table, positions,
-                      total_lens, sm_scale, window=None, softcap=None):
-            rows = ("dp" if self._dp > 1 and q.shape[0] % self._dp == 0
-                    else None)
-            heads = P(rows, None, "tp", None)
+        def per_shard(q, pages, layer_idx, page_table, *rest, window=None,
+                      softcap=None):
+            # rest: the kernel's row arrays, then sm_scale — a padded
+            # kernel's (positions, total_lens), the packed kernel's
+            # (q_starts, q_lens, kv_lens) over q [T, Hq, Dh], whose token
+            # axis has no rows to split
+            *row_arrays, sm_scale = rest
+            packed = q.ndim == 3
+            rows = ("dp" if not packed and self._dp > 1
+                    and q.shape[0] % self._dp == 0 else None)
+            heads = (P(None, "tp", None) if packed
+                     else P(rows, None, "tp", None))
 
-            def local(q, pages, layer, win, table, pos, total):
-                return kernel(q, pages, layer, table, pos, total, sm_scale,
+            def local(q, pages, layer, win, table, *row_arrays):
+                return kernel(q, pages, layer, table, *row_arrays, sm_scale,
                               window=win, softcap=softcap)
 
             return jax.shard_map(
                 local, mesh=mesh,
                 in_specs=(heads, P(None, None, None, "tp", None, None),
-                          P(), P(), P(rows, None), P(rows, None), P(rows)),
+                          P(), P(), P(rows, None),
+                          *(P(rows, *([None] * (a.ndim - 1)))
+                            for a in row_arrays)),
                 out_specs=heads, check_vma=False)(
                 q, pages, jnp.asarray(layer_idx, jnp.int32),
                 jnp.asarray(0 if window is None else window, jnp.int32),
-                page_table, positions, total_lens)
+                page_table, *row_arrays)
 
         # the markers the gemma and deepseek forwards look for
         per_shard.supports_window_softcap = True
@@ -834,26 +858,43 @@ class JaxEngine(ScheduledEngineBase):
                                           total_lens)
         return pages, packed, aux
 
-    def _mixed_step_impl(self, params, pages, tokens, positions, page_table,
-                         total_lens, new_lens, rng, step, temperature,
-                         top_k, top_p, pen=None):
-        """The MIXED step program (prefill chunks + decode rows, one
-        ragged [B, S] batch): ``_step_impl`` with the S>1 attention swapped
-        for the ragged mixed kernel, which derives each row's real query
-        count from the descriptors already in flight
-        (``total_lens - positions[:, 0]``) and skips the query blocks a
-        decode row's padding would otherwise pay. Only traced on the
-        Pallas path — every other attn_impl's mixed program IS the plain
-        step program (``__init__`` aliases the jit)."""
-        (tokens, positions, page_table, total_lens, new_lens, temperature,
-         top_k, top_p) = self._shard_batch(
-            tokens, positions, page_table, total_lens, new_lens, temperature,
-            top_k, top_p)
-        attn = (self._attn_decode if tokens.shape[1] == 1
-                else self._attn_mixed)
+    def _why_padded(self, forward_fn, family) -> Optional[str]:
+        """None where the prefill-carrying steps run token-packed, else
+        the first reason they cannot: a custom
+        ``forward_fn`` (pipeline stages), a family forward that does not
+        declare the packed form (MLA: its kernels are row-padded), a mesh
+        with ``dp > 1`` (``_shard_batch`` splits rows, a packed axis has
+        none), speculation (the verify window is ``[B, K+1]``), and the
+        XLA ``scan``/``unrolled`` paths (the CPU's). Read off what the
+        engine is — no flag, no model name."""
+        if forward_fn is not None:
+            return "forward"
+        if not getattr(family.forward, "supports_packed", False):
+            return "family"
+        if self._dp > 1:
+            return "dp"
+        if self.spec_K:
+            return "spec"
+        if self.attn_impl != "pallas":
+            return "attn_impl"
+        return None
+
+    def _packed_step_impl(self, params, pages, tokens, positions,
+                          page_table, total_lens, new_lens, rng, step,
+                          temperature, top_k, top_p, pen=None):
+        """The TOKEN-PACKED step program (prompt chunks + decode rows in
+        one ``[T]`` dispatch): ``tokens``/``positions`` ``[1, T]`` hold
+        every row's new tokens back to back, the row arrays are ``[R]``
+        as in ``_step_impl``, and row ``r`` owns slots ``cu[r] .. cu[r] +
+        new_lens[r]`` with ``cu`` the exclusive cumulative sum the forward
+        computes on the device (``models/llama.packed_rows``). Everything
+        per token runs on ``[1, T, H]``; the cache write, the ragged
+        attention kernel and the last-token select take the rows as
+        descriptors. Sampling sees the same ``[R]`` rows in the same order
+        as the padded step."""
         out = self._forward(
-            params, self.model_cfg, tokens, positions, pages,
-            page_table, total_lens, new_lens, attn_impl=attn)
+            params, self.model_cfg, tokens, positions, pages, page_table,
+            total_lens, new_lens, attn_impl=self._attn_packed, packed=True)
         logits, pages = out[0], out[1]
         aux = out[2] if len(out) > 2 else {}
         pages, packed = self._sample_tail(logits, pages, rng, step,
@@ -1436,6 +1477,11 @@ class JaxEngine(ScheduledEngineBase):
                 chunks += [PrefillChunk(seq=s, start=len(s) - 1, length=1,
                                         is_last=True)
                            for s in plan.decode_seqs]
+            # the form of this step: token-packed, or padded and why
+            reason = "ring" if ring else self.padded_reason
+            pack = reason is None
+            form = "packed" if pack else f"padded:{reason}"
+            self.prefill_steps[form] = self.prefill_steps.get(form, 0) + 1
             if ring:
                 # whole-prompt sequence-parallel step: B=1, S may exceed the
                 # chunk budget; pad S to a power of two (bounded compile
@@ -1447,27 +1493,38 @@ class JaxEngine(ScheduledEngineBase):
             else:
                 B = _bucket(len(chunks), self.cfg.min_prefill_seqs_bucket,
                             self.cfg.max_num_seqs)
-                S = _bucket(max(c.length for c in chunks),
-                            self.cfg.min_prefill_bucket,
-                            self.cfg.max_prefill_chunk)
-            toks = np.zeros((B, S), np.int32)
-            pos = np.zeros((B, S), np.int32)
+                S = (_token_bucket(sum(c.length for c in chunks),
+                                   self.cfg.min_prefill_bucket)
+                     if pack else
+                     _bucket(max(c.length for c in chunks),
+                             self.cfg.min_prefill_bucket,
+                             self.cfg.max_prefill_chunk))
+            # packed: every row's new tokens back to back on one [1, T]
+            # axis, chunk rows then decode rows; the row arrays stay [B]
+            toks = np.zeros((1 if pack else B, S), np.int32)
+            pos = np.zeros_like(toks)
             table = np.zeros((B, P), np.int32)
             total = np.ones(B, np.int32)   # pad rows: 1 garbage-page token
             new = np.zeros(B, np.int32)    # pad rows: write nothing
             temp = np.zeros(B, np.float32)
             top_k = np.zeros(B, np.int32)
             top_p = np.ones(B, np.float32)
+            at = 0                         # a packed row's first slot
             for i, c in enumerate(chunks):
                 seq = c.seq
+                # where the row's new tokens go: its own padded row, or
+                # its slots of the packed axis
+                row, lo = (0, at) if pack else (i, 0)
+                at += c.length
                 if c.length == 1 and c.start == len(seq) - 1:
                     # decode row: skip the O(context) token-list build
-                    toks[i, 0] = seq.tokens.last_token()
+                    toks[row, lo] = seq.tokens.last_token()
                 else:
                     all_tokens = seq.tokens.tokens()
-                    toks[i, :c.length] = all_tokens[c.start:c.start
-                                                    + c.length]
-                pos[i, :c.length] = np.arange(c.start, c.start + c.length)
+                    toks[row, lo:lo + c.length] = all_tokens[
+                        c.start:c.start + c.length]
+                pos[row, lo:lo + c.length] = np.arange(c.start,
+                                                       c.start + c.length)
                 table[i, :len(seq.page_ids)] = seq.page_ids
                 total[i] = c.start + c.length
                 new[i] = c.length
@@ -1484,6 +1541,9 @@ class JaxEngine(ScheduledEngineBase):
             kind = "mixed"
             self.decode_dispatches += 1
             self.mixed_steps += 1
+        if pack:
+            # the program, not the plan: followers replay it by this name
+            kind = "packed"
         elif ring:
             kind = "ring"
             self.ring_steps += 1
@@ -1508,7 +1568,7 @@ class JaxEngine(ScheduledEngineBase):
             # prompt's own later chunks); and on MULTI-HOST we never skip,
             # because the leader's step_outcome broadcast must reflect a
             # real sync or a symmetric failure would read as divergence.
-            B = arrays["toks"].shape[0]
+            B = arrays["total"].shape[0]
             return np.zeros(B, np.int64), np.zeros(B, np.float32), None
         return self.fetch_packed(packed)
 
@@ -2099,18 +2159,17 @@ class JaxEngine(ScheduledEngineBase):
             self.pages = self._jit_scatter_pages(
                 self.pages, jnp.asarray(a["ids"]), jnp.asarray(a["vals"]))
             return None
+        # rows, and the width of the token arrays (a packed step's T)
         _shape = (a["toks"] if "toks" in a else a["pos"]).shape
-        _B, _S = int(_shape[0]), int(_shape[1]) if len(_shape) > 1 else 1
-        if kind == "spec":
-            _fn = self._jit_spec
-        elif kind == "chained":
-            _fn = self._jit_chained
-        else:
-            _fn = {"ring": self._jit_ring_step,
-                   "mixed": self._jit_mixed}.get(kind, self._jit_step)
+        _B = int(a["total"].shape[0])
+        _S = int(_shape[1]) if len(_shape) > 1 else 1
+        # a padded mixed step IS the plain step program (same trace)
+        step_fn = {"spec": self._jit_spec, "chained": self._jit_chained,
+                   "ring": self._jit_ring_step,
+                   "packed": self._jit_packed}.get(kind, self._jit_step)
         # the with-mask and without-mask pen pytrees are distinct traces
         # (see _pen_arg) — a bucket per variant, like the jit cache itself
-        _ckey = (id(_fn), _B, _S, a.get("mask_words") is not None)
+        _ckey = (id(step_fn), _B, _S, a.get("mask_words") is not None)
         _fresh = _ckey not in self._jit_seen
         _t0 = time.perf_counter() if _fresh else 0.0
         if kind == "spec":
@@ -2134,9 +2193,7 @@ class JaxEngine(ScheduledEngineBase):
                 jnp.asarray(a["total"]), jnp.asarray(a["new"]),
                 self._rng, np.int32(step), temp, top_k, top_p, pen)
         else:
-            step_fn = {"ring": self._jit_ring_step,
-                       "mixed": self._jit_mixed}.get(kind, self._jit_step)
-            pen = self._pen_arg(a, a["toks"].shape[0])
+            pen = self._pen_arg(a, _B)
             temp, top_k, top_p = self._step_sampling(a, kind, seqs)
             self.pages, packed, aux = step_fn(
                 self.params, self.pages, jnp.asarray(a["toks"]),
@@ -2154,9 +2211,12 @@ class JaxEngine(ScheduledEngineBase):
                 # bounded memory: drain all but the freshest few (those may
                 # still be in flight; everything older has long completed)
                 self._drain_moe_drops(keep_last=8)
-        self.last_padded = (_B, _S)
-        # the step program and its bucket, as the ring names it
-        self.last_program = f"{kind}[{_B},{_S}]"
+        # the slots the device computed and the step program with its
+        # bucket, as the ring names them: a packed step pays for its T
+        # slots whatever its rows
+        self.last_padded = (1, _S) if kind == "packed" else (_B, _S)
+        self.last_program = (f"packed[{_S},{_B}]" if kind == "packed"
+                             else f"{kind}[{_B},{_S}]")
         if _fresh:
             self._mark_compile(_ckey, kind, _B, _S,
                                time.perf_counter() - _t0)

@@ -10,7 +10,9 @@ of the chip's time). So the check reads the compiled program:
 ``pool_copies`` lists the instructions of an optimised HLO text that write
 an array as large as the pool, and ``check_step_programs`` compiles the
 decode, fused and mixed programs of an engine from shapes alone and reports
-those beside ``memory_analysis()``'s temporary bytes. Used by
+those beside ``memory_analysis()``'s temporary bytes; an engine that
+serves its prefill-carrying steps token-packed has a fourth, ``packed``.
+Used by
 ``tests/test_kv_write.py`` (toy model, CPU),
 ``tests/test_pallas_tpu_lowering.py`` (a tp=2 mesh, the TPU compiler) and
 the kernel child of ``chip_smoke.py`` (serving geometry, on the chip).
@@ -80,13 +82,17 @@ def _pool_shape(engine, num_pages: Optional[int]):
 
 
 def lower_step_programs(engine, batch: int, chunk: int, width: int = 8,
-                        sharding=None, num_pages: Optional[int] = None
+                        sharding=None, num_pages: Optional[int] = None,
+                        tokens: Optional[int] = None
                         ) -> Dict[str, "jax.stages.Lowered"]:
     """The decode step ``[batch, 1]``, the fused block of ``width`` decode
-    steps and the mixed step ``[batch, chunk]`` of a stacked-pool engine,
-    lowered from shapes alone (``engine.params`` may be abstract, nothing
-    is placed on a device). ``sharding`` places every argument, for a
-    described device; ``num_pages`` overrides the pool's page count."""
+    steps, the padded prefill-carrying step ``[batch, chunk]`` and, where
+    the engine packs (``engine.padded_reason`` is None), the token-packed
+    step of ``tokens`` slots (default ``chunk``) over ``batch`` rows, of a
+    stacked-pool engine, lowered from shapes alone (``engine.params`` may
+    be abstract, nothing is placed on a device). ``sharding`` places every
+    argument, for a described device; ``num_pages`` overrides the pool's
+    page count."""
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
 
@@ -95,8 +101,9 @@ def lower_step_programs(engine, batch: int, chunk: int, width: int = 8,
     pages = sds(_pool_shape(engine, num_pages), engine.pages.dtype)
     i32, f32 = jnp.int32, jnp.float32
 
-    def step_args(B, S):
-        return (params, pages, sds((B, S), i32), sds((B, S), i32),
+    def step_args(B, S, lead=None):
+        lead = B if lead is None else lead
+        return (params, pages, sds((lead, S), i32), sds((lead, S), i32),
                 sds((B, engine.table_width), i32), sds((B,), i32),
                 sds((B,), i32), sds((2,), jnp.uint32), sds((), i32),
                 sds((B,), f32), sds((B,), i32), sds((B,), f32))
@@ -104,7 +111,7 @@ def lower_step_programs(engine, batch: int, chunk: int, width: int = 8,
     B = batch
     # the engine's own jitted programs: the names a device trace shows and
     # the donation are the served ones
-    return {
+    out = {
         "decode": engine._jit_step.lower(*step_args(B, 1)),
         "fused": engine._get_jit_multistep(width).lower(
             params, pages, sds((B, 1), i32), sds((B, 1), i32),
@@ -112,18 +119,22 @@ def lower_step_programs(engine, batch: int, chunk: int, width: int = 8,
             sds((B,), jnp.bool_), sds((B,), i32), sds((B,), i32),
             sds((2,), jnp.uint32), sds((), i32), sds((B,), f32),
             sds((B,), i32), sds((B,), f32), sds((B, 1), i32), None, None),
-        "mixed": engine._jit_mixed.lower(*step_args(B, chunk)),
+        "mixed": engine._jit_step.lower(*step_args(B, chunk)),
     }
+    if engine.padded_reason is None:
+        out["packed"] = engine._jit_packed.lower(
+            *step_args(B, tokens or chunk, lead=1))
+    return out
 
 
 def check_step_programs(engine, batch: int, chunk: int, width: int = 8,
-                        sharding=None, num_pages: Optional[int] = None
-                        ) -> List[dict]:
-    """Compile the three programs and report, for each, the pool-sized
+                        sharding=None, num_pages: Optional[int] = None,
+                        tokens: Optional[int] = None) -> List[dict]:
+    """Compile the programs and report, for each, the pool-sized
     copies in its HLO and its temporary bytes beside the pool's bytes. A
     program is ``ok`` with no such copy and temporaries under one pool."""
     lowered = lower_step_programs(engine, batch, chunk, width, sharding,
-                                  num_pages)
+                                  num_pages, tokens)
     shape, dtype = _pool_shape(engine, num_pages), engine.pages.dtype
     pool_bytes = math.prod(shape) * jnp.dtype(dtype).itemsize
     out = []

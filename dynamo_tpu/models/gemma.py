@@ -31,13 +31,15 @@ import jax
 import jax.numpy as jnp
 
 from dynamo_tpu.models.config import ModelConfig
-from dynamo_tpu.models.llama import make_pages, make_pages_list
-from dynamo_tpu.ops.attention import (
-    paged_attention,
-    paged_attention_layer,
-    write_kv,
-    write_kv_layer,
+from dynamo_tpu.models.llama import (
+    _select_last,
+    attend_rows,
+    make_pages,
+    make_pages_list,
+    packed_rows,
+    write_rows,
 )
+from dynamo_tpu.ops.attention import paged_attention_layer, write_kv_layer
 from dynamo_tpu.ops.rope import apply_rope
 from dynamo_tpu.ops import quant
 
@@ -127,14 +129,13 @@ def _finish_layer(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
 
 
 def _logits(cfg: ModelConfig, params: Params, h: jnp.ndarray,
-            new_lens: jnp.ndarray, window: int = 1) -> jnp.ndarray:
+            new_lens: jnp.ndarray, window: int = 1,
+            starts=None) -> jnp.ndarray:
     """Logits at each row's last ``window`` real new positions ([B, V], or
     [B, W, V] for the speculative-verify step — see llama._logits)."""
     h = _rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
     if window == 1:
-        last = jnp.maximum(new_lens - 1, 0)
-        h_sel = jnp.take_along_axis(
-            h, last[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+        h_sel = _select_last(h, new_lens, starts)
     else:
         offs = jnp.arange(window, dtype=jnp.int32)[None, :]
         idx = jnp.maximum(new_lens[:, None] - window + offs, 0)
@@ -170,15 +171,17 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             page_table: jnp.ndarray, total_lens: jnp.ndarray,
             new_lens: jnp.ndarray,
             attn_impl: Optional[Callable] = None,
-            logits_window: int = 1
+            logits_window: int = 1, packed: bool = False
             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Scan-over-layers forward. ``attn_impl`` is honored only when it
-    advertises ``supports_window_softcap`` (both stacked Pallas kernels —
-    decode and prefill — carry gemma's per-layer sliding window + logit
-    soft-capping) — otherwise the XLA paths serve, with identical math."""
+    """Scan-over-layers forward, padded or token-packed
+    (``llama.packed_rows``). ``attn_impl`` is honored only when it
+    advertises ``supports_window_softcap`` (the stacked Pallas kernels —
+    decode, prefill and ragged — carry gemma's per-layer sliding window +
+    logit soft-capping) — otherwise the XLA paths serve, with identical
+    math."""
     if not getattr(attn_impl, "supports_window_softcap", False):
         attn_impl = None
-    attn_impl = attn_impl or paged_attention
+    starts = packed_rows(packed, new_lens)
     sm_scale = _sm_scale(cfg)
     softcap = cfg.attn_logit_softcap or None  # static: both paths accept
     windows = layer_windows(cfg)
@@ -188,17 +191,22 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         h, pages = carry
         lp, lidx, win = xs
         q, k, v = _project_qkv(cfg, lp, h, positions)
-        pages = write_kv(pages, lidx, k, v, page_table, positions, new_lens)
-        attn = attn_impl(q, pages, lidx, page_table, positions,
-                         total_lens, sm_scale, window=win,
-                         softcap=softcap)
+        pages = write_rows(pages, lidx, k, v, page_table, positions,
+                           total_lens, new_lens, starts)
+        attn = attend_rows(attn_impl, q, pages, lidx, page_table, positions,
+                           total_lens, new_lens, sm_scale, starts,
+                           window=win, softcap=softcap)
         h = _finish_layer(cfg, lp, h, attn)
         return (h, pages), None
 
     (h, pages), _ = jax.lax.scan(
         body, (h, pages),
         (params["layers"], jnp.arange(cfg.num_layers), windows))
-    return _logits(cfg, params, h, new_lens, window=logits_window), pages
+    return _logits(cfg, params, h, new_lens, window=logits_window,
+                   starts=starts), pages
+
+
+forward.supports_packed = True
 
 
 def forward_unrolled(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,

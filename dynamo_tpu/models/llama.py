@@ -35,8 +35,10 @@ from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.ops.attention import (
     paged_attention,
     paged_attention_layer,
+    ragged_paged_attention,
     write_kv,
     write_kv_layer,
+    write_kv_packed,
 )
 from dynamo_tpu.ops.rope import apply_rope
 from dynamo_tpu.ops import quant
@@ -163,20 +165,32 @@ def _finish_layer(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
     return h + quant.mm(lp, "w_down", act)
 
 
+def _select_last(h: jnp.ndarray, new_lens: jnp.ndarray,
+                 starts: Optional[jnp.ndarray]) -> jnp.ndarray:
+    """Hidden state of each row's last real new token, ``[R, H]``: column
+    ``new_lens - 1`` of a padded ``[B, S, H]``, or slot ``starts +
+    new_lens - 1`` of a token-packed ``[1, T, H]``."""
+    last = jnp.maximum(new_lens - 1, 0).astype(jnp.int32)
+    if starts is not None:
+        return h[0][starts + last]
+    return jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
+
+
 def _logits(cfg: ModelConfig, params: Params, h: jnp.ndarray,
-            new_lens: jnp.ndarray, window: int = 1) -> jnp.ndarray:
+            new_lens: jnp.ndarray, window: int = 1,
+            starts: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Logits at each row's last ``window`` real new positions.
 
     window == 1 (every normal step) returns [B, V]; window = W > 1 (the
     speculative-verify step, which samples at all K+1 chunk slots) returns
     [B, W, V]. Only W rows of hidden state hit the lm_head either way —
-    full [B, S, V] materialization stays off the table.
+    full [B, S, V] materialization stays off the table. ``starts`` (a
+    token-packed step, ``packed_rows``) is each row's first slot on the
+    packed axis of ``h [1, T, H]``.
     """
     h = _rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
     if window == 1:
-        last = jnp.maximum(new_lens - 1, 0)                # [B]
-        h_sel = jnp.take_along_axis(
-            h, last[:, None, None].astype(jnp.int32), axis=1)[:, 0]  # [B, H]
+        h_sel = _select_last(h, new_lens, starts)
     else:
         offs = jnp.arange(window, dtype=jnp.int32)[None, :]          # [1, W]
         idx = jnp.maximum(new_lens[:, None] - window + offs, 0)      # [B, W]
@@ -194,12 +208,53 @@ def _logits(cfg: ModelConfig, params: Params, h: jnp.ndarray,
     return jnp.dot(h_sel, lm_head, preferred_element_type=jnp.float32)
 
 
+def packed_rows(packed: bool, new_lens: jnp.ndarray
+                ) -> Optional[jnp.ndarray]:
+    """Each row's first slot on the packed axis of a TOKEN-PACKED step (the
+    exclusive cumulative sum of ``new_lens``), or None for a padded step.
+
+    A packed step carries ``tokens``/``positions`` ``[1, T]`` — every
+    row's new tokens back to back, chunk rows then decode rows, pads at the
+    end — beside the per-row ``page_table [R, P]``, ``total_lens`` and
+    ``new_lens [R]``. Everything per token (embed, norms, projections,
+    rope, FFN) runs on ``[1, T, H]`` as it would on any batch of one; the
+    three operations that know rows take the row descriptors:
+    ``write_rows``, ``attend_rows`` and ``_logits``. A forward that
+    handles both forms says so with ``forward.supports_packed = True``
+    (``engine/jax_engine.py`` serves every other forward padded)."""
+    if not packed:
+        return None
+    return (jnp.cumsum(new_lens) - new_lens).astype(jnp.int32)
+
+
+def write_rows(pages, lidx, k, v, page_table, positions, total_lens,
+               new_lens, starts):
+    """The cache write of either step form (``starts``: ``packed_rows``)."""
+    if starts is None:
+        return write_kv(pages, lidx, k, v, page_table, positions, new_lens)
+    return write_kv_packed(pages, lidx, k[0], v[0], page_table, starts,
+                           new_lens, total_lens)
+
+
+def attend_rows(attn_impl, q, pages, lidx, page_table, positions,
+                total_lens, new_lens, sm_scale, starts, **kw):
+    """Attention of either step form. A packed step's ``attn_impl`` has
+    ``ops.attention.ragged_paged_attention``'s signature (the default)."""
+    if starts is None:
+        return (attn_impl or paged_attention)(
+            q, pages, lidx, page_table, positions, total_lens, sm_scale,
+            **kw)
+    return (attn_impl or ragged_paged_attention)(
+        q[0], pages, lidx, page_table, starts, new_lens, total_lens,
+        sm_scale, **kw)[None]
+
+
 def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             positions: jnp.ndarray, pages: jnp.ndarray,
             page_table: jnp.ndarray, total_lens: jnp.ndarray,
             new_lens: jnp.ndarray,
             attn_impl: Optional[Callable] = None,
-            logits_window: int = 1
+            logits_window: int = 1, packed: bool = False
             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Scan-over-layers forward against the stacked paged cache.
 
@@ -215,11 +270,13 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 for S == 1 steps on TPU; the traced scan index selects the
                 layer inside the kernel's DMA, so decode keeps the
                 single-compiled-layer-body scan.
+    packed:     the step is token-packed (``packed_rows``): tokens and
+                positions are [1, T], the row arrays [R].
 
     Returns (logits [B, vocab] at each sequence's last real new token, pages).
     """
     sm_scale = cfg.head_dim ** -0.5
-    attn_impl = attn_impl or paged_attention
+    starts = packed_rows(packed, new_lens)
     # the named scopes are the stage names a device trace shows for the
     # operations traced under them (docs/observability.md)
     with jax.named_scope("embed"):
@@ -231,11 +288,12 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         with jax.named_scope("layer.attn_in"):
             q, k, v = _project_qkv(cfg, lp, h, positions)
         with jax.named_scope("layer.kv_write"):
-            pages = write_kv(pages, lidx, k, v, page_table, positions,
-                             new_lens)
+            pages = write_rows(pages, lidx, k, v, page_table, positions,
+                               total_lens, new_lens, starts)
         with jax.named_scope("layer.attn"):
-            attn = attn_impl(q, pages, lidx, page_table, positions,
-                             total_lens, sm_scale)
+            attn = attend_rows(attn_impl, q, pages, lidx, page_table,
+                               positions, total_lens, new_lens, sm_scale,
+                               starts)
         with jax.named_scope("layer.ffn"):
             h = _finish_layer(cfg, lp, h, attn)
         return (h, pages), None
@@ -244,8 +302,12 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         body, (h, pages),
         (params["layers"], jnp.arange(cfg.num_layers)))
     with jax.named_scope("logits"):
-        logits = _logits(cfg, params, h, new_lens, window=logits_window)
+        logits = _logits(cfg, params, h, new_lens, window=logits_window,
+                         starts=starts)
     return logits, pages
+
+
+forward.supports_packed = True
 
 
 def _dense_hidden(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,

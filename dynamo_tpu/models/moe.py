@@ -34,12 +34,13 @@ from dynamo_tpu.models.llama import (
     _logits,
     _project_qkv,
     _rms_norm,
+    attend_rows,
+    packed_rows,
+    write_rows,
 )
 from dynamo_tpu.models import llama
 from dynamo_tpu.ops.attention import (
-    paged_attention,
     paged_attention_layer,
-    write_kv,
     write_kv_layer,
 )
 
@@ -216,30 +217,35 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             page_table: jnp.ndarray, total_lens: jnp.ndarray,
             new_lens: jnp.ndarray,
             attn_impl: Optional[Callable] = None, ep_mesh=None,
-            logits_window: int = 1
+            logits_window: int = 1, packed: bool = False
             ) -> Tuple[jnp.ndarray, jnp.ndarray, dict]:
-    """Scan-over-layers MoE forward (llama.forward contract plus a third
-    ``aux`` return: ``{"moe_dropped_assignments": scalar}`` summed over
-    layers — the engine forwards it to worker stats)."""
+    """Scan-over-layers MoE forward (llama.forward contract, the
+    token-packed form included, plus a third ``aux`` return:
+    ``{"moe_dropped_assignments": scalar}`` summed over layers — the
+    engine forwards it to worker stats)."""
     sm_scale = cfg.head_dim ** -0.5
-    attn_impl = attn_impl or paged_attention
+    starts = packed_rows(packed, new_lens)
     h = params["embed"][tokens]
 
     def body(carry, xs):
         h, pages = carry
         lp, lidx = xs
         q, k, v = _project_qkv(cfg, lp, h, positions)
-        pages = write_kv(pages, lidx, k, v, page_table, positions, new_lens)
-        attn = attn_impl(q, pages, lidx, page_table, positions,
-                         total_lens, sm_scale)
+        pages = write_rows(pages, lidx, k, v, page_table, positions,
+                           total_lens, new_lens, starts)
+        attn = attend_rows(attn_impl, q, pages, lidx, page_table, positions,
+                           total_lens, new_lens, sm_scale, starts)
         h, dropped = _moe_layer_tail(cfg, lp, h, attn, ep_mesh=ep_mesh)
         return (h, pages), dropped
 
     (h, pages), drops = jax.lax.scan(
         body, (h, pages), (params["layers"], jnp.arange(cfg.num_layers)))
     aux = {"moe_dropped_assignments": jnp.sum(drops)}
-    return (_logits(cfg, params, h, new_lens, window=logits_window),
-            pages, aux)
+    return (_logits(cfg, params, h, new_lens, window=logits_window,
+                    starts=starts), pages, aux)
+
+
+forward.supports_packed = True
 
 
 def forward_unrolled(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,

@@ -75,7 +75,6 @@ def _write_pages(pool: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
     phys = jnp.where(j < n_live[:, None],
                      jnp.take_along_axis(page_table, logical, axis=1), 0)
     at = pool.at[phys] if layer_idx is None else pool.at[layer_idx, phys]
-    old = at.get(mode="clip")                        # [B, J, 2, Hkv, ps, Dh]
     # the new tokens shifted to their slots, token-major, then page-major
     new = jnp.stack([k_new, v_new], axis=2).astype(pool.dtype)
     if S == 1:
@@ -87,12 +86,64 @@ def _write_pages(pool: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
             lambda buf, row, o: jax.lax.dynamic_update_slice_in_dim(
                 buf, row, o, axis=0))(
             jnp.zeros((B, J * page_size, 2, Hkv, Dh), pool.dtype), new, off)
-    laid = laid.reshape(B, J, page_size, 2, Hkv, Dh).transpose(
-        0, 1, 3, 4, 2, 5)
     t = jnp.arange(J * page_size, dtype=start.dtype)[None, :]
     real = (t >= off[:, None]) & (t < (off + new_lens)[:, None])
-    real = real.reshape(B, J, 1, 1, page_size, 1)
-    return at.set(jnp.where(real, laid, old), mode="drop")
+    return _commit_pages(at, laid.reshape(B, J, page_size, 2, Hkv, Dh),
+                         real.reshape(B, J, page_size))
+
+
+def _commit_pages(at, laid: jnp.ndarray, real: jnp.ndarray) -> jnp.ndarray:
+    """The page gather / select / scatter both forms of the write end in.
+    ``at`` indexes the pool by the physical page of every slab ``[..., ]``,
+    ``laid [..., ps, 2, Hkv, Dh]`` holds the new tokens at their slots,
+    token-major, and ``real [..., ps]`` says which slots take one; every
+    other slot keeps what the page held."""
+    old = at.get(mode="clip")                        # [..., 2, Hkv, ps, Dh]
+    laid = jnp.moveaxis(laid, -4, -2)
+    return at.set(jnp.where(real[..., None, None, :, None], laid, old),
+                  mode="drop")
+
+
+def write_kv_packed(pool: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
+                    v_new: jnp.ndarray, page_table: jnp.ndarray,
+                    starts: jnp.ndarray, new_lens: jnp.ndarray,
+                    total_lens: jnp.ndarray) -> jnp.ndarray:
+    """``_write_pages`` for a TOKEN-PACKED step into the stacked pool: ``k_new``/``v_new``
+    ``[T, Hkv, Dh]`` hold every row's new tokens back to back, row ``r``
+    at slots ``starts[r] .. starts[r] + new_lens[r]`` and at positions
+    ``total_lens[r] - new_lens[r] ..``. Same pages, same select, same
+    scatter; what differs is how the slabs are found. The rows' live pages
+    are numbered back to back too (at most ``T // ps + 2 R`` of them: a row
+    of ``n`` tokens at any offset spans at most ``(n - 1) // ps + 2``), and
+    slab ``m`` takes the ``ps`` packed tokens from ``starts[r] + j * ps -
+    off[r]`` on: one slice a page, no per-row padded buffer."""
+    Hkv, page_size, Dh = pool.shape[-3:]
+    T = k_new.shape[0]
+    R = page_table.shape[0]
+    M = T // page_size + 2 * R
+    begin = total_lens - new_lens                   # first new position [R]
+    first, off = begin // page_size, begin % page_size
+    n_live = jnp.where(new_lens > 0,
+                       (off + new_lens + page_size - 1) // page_size, 0)
+    ends = jnp.cumsum(n_live)
+    m = jnp.arange(M, dtype=begin.dtype)
+    # slab m -> (row, page of the row); slabs past the last live page are
+    # the garbage page 0 written back to itself
+    row = jnp.minimum(jnp.sum(m[:, None] >= ends[None, :], axis=1), R - 1)
+    j = m - (ends - n_live)[row]
+    logical = jnp.minimum(first[row] + j, page_table.shape[1] - 1)
+    phys = jnp.where(m < ends[-1], page_table[row, logical], 0)
+    at = pool.at[layer_idx, phys]
+    # the slab's tokens: slot s of slab m is token j*ps + s - off of the row
+    tok0 = j * page_size - off[row]                               # [M]
+    new = jnp.stack([k_new, v_new], axis=1).astype(pool.dtype)
+    new = jnp.pad(new, ((page_size, page_size), (0, 0), (0, 0), (0, 0)))
+    laid = jax.vmap(lambda s: jax.lax.dynamic_slice_in_dim(
+        new, s, page_size, axis=0))(page_size + starts[row] + tok0)
+    tok = tok0[:, None] + jnp.arange(page_size, dtype=begin.dtype)[None, :]
+    real = ((m < ends[-1])[:, None] & (tok >= 0)
+            & (tok < new_lens[row][:, None]))
+    return _commit_pages(at, laid, real)
 
 
 def write_kv_layer(kv_layer: jnp.ndarray, k_new: jnp.ndarray,
@@ -411,7 +462,8 @@ def paged_attention(q: jnp.ndarray, pages: jnp.ndarray, layer_idx,
                    window=window, softcap=softcap).astype(q.dtype)
 
 
-__all__ = ["write_kv", "write_kv_layer", "paged_attention",
+__all__ = ["write_kv", "write_kv_layer", "write_kv_packed",
+           "paged_attention",
            "paged_attention_layer", "ragged_paged_attention",
            "merge_softmax_partials", "normalize_softmax_partials",
            "NEG_INF"]

@@ -20,8 +20,8 @@ from dynamo_tpu.ops.pallas.mla_decode import (
     mla_paged_decode_stacked,
 )
 from dynamo_tpu.ops.pallas.mla_prefill import mla_paged_prefill_stacked
-from dynamo_tpu.ops.pallas.ragged import ragged_mixed_attention_stacked
+from dynamo_tpu.ops.pallas.ragged import ragged_mixed_attention_packed
 
 __all__ = ["paged_decode_attention", "paged_decode_attention_stacked",
            "mla_paged_decode_layer", "mla_paged_decode_stacked",
-           "mla_paged_prefill_stacked", "ragged_mixed_attention_stacked"]
+           "mla_paged_prefill_stacked", "ragged_mixed_attention_packed"]

@@ -1,31 +1,32 @@
-"""Ragged mixed-batch paged attention on TPU — one kernel for prefill
-chunks AND decode steps (the Ragged Paged Attention kernel shape,
-PAPERS.md).
+"""Ragged mixed-batch paged attention on TPU over a TOKEN-PACKED step — one
+kernel for prefill chunks AND decode rows (the Ragged Paged Attention
+kernel shape, PAPERS.md).
 
-The engine's mixed step packs prefill chunks and single-token decode rows
-into one ``[B, S]`` dispatch (``engine/scheduler.MixedStepBatch``). The
-prefill kernel (``ops/pallas/prefill.py``) already computes such a batch
-correctly — pad rows mask out causally — but it pays the FULL query-block
-grid for every row: a decode row (1 real query token) costs the same
-``ceil(S/SB)`` programs as a 512-token chunk, each streaming the row's
-whole paged context. This kernel is the prefill kernel plus the ragged
-row descriptors:
+The engine's prefill-carrying step lays every row's new tokens back to back
+on one ``[T]`` axis (``engine/jax_engine._packed_step_impl``): chunk rows
+first, then the decode rows, one token each; row ``r`` owns slots
+``q_starts[r] .. q_starts[r] + q_lens[r]``. Nothing around this kernel is
+padded to ``[rows, longest chunk]`` any more, so the kernel reads the
+queries where they lie:
 
-- Per row, ``q_len = ctx - q_start`` (positions are row-contiguous and end
-  at ``ctx - 1``, so the descriptor rides the arrays the engine already
-  ships — no new operands).
-- Grid programs wholly past their row's real queries
-  (``j*SB >= q_len``) SKIP everything — no page DMAs, no matmuls. On the
-  sequential TPU grid a decode row costs ONE program streaming its own
-  context instead of ``ceil(S/SB)``; mixed batches run at ~ragged cost,
-  not padded cost.
-- Everything else (page-streaming double buffer, SMEM layer index for the
-  ``lax.scan`` forward, causal online softmax in f32, window/softcap) is
-  the prefill kernel's machinery unchanged.
+- The grid runs over ALIGNED blocks of ``SB`` packed slots (plain
+  ``BlockSpec``s on ``q`` and the output: no row is aligned to anything,
+  a decode row takes one slot). A block loops over the rows that have
+  slots in it (first and last row per block arrive as scalars) and, per
+  row, streams the pages those slots can SEE, masking the block's other
+  slots out. Every slot belongs to one row, so one running softmax state
+  per slot serves the whole loop: a row's pass leaves the other rows'
+  slots as they were.
+- A block wholly past the packed tokens loops over no live row and writes
+  zeros. A decode row costs its own context once, in the one block that
+  holds its slot.
+- The page-streaming double buffer, the SMEM layer index for the
+  ``lax.scan`` forward, the causal online softmax in f32 and window /
+  softcap are the prefill kernel's (``ops/pallas/prefill.py``).
 
-The pure-JAX flattened-layout reference lives in
-``ops.attention.ragged_paged_attention`` (the CPU-test oracle); CPU tests
-of this kernel run in interpreter mode.
+The pure-JAX reference over the same layout, and the CPU-test oracle, is
+``ops.attention.ragged_paged_attention``; CPU tests of this kernel run in
+interpreter mode.
 """
 
 from __future__ import annotations
@@ -47,190 +48,215 @@ NEG_INF = -1e30
 
 
 def _ragged_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
-                   qstart_ref, lens_ref, out_ref, buf, sem, *,
+                   rows_ref, qstart_ref, qlen_ref, lens_ref, out_ref,
+                   buf, sem, m_ref, l_ref, acc_ref, *,
                    page_size: int, n_kv: int, chunk: int, q_block: int,
                    softcap: float):
-    """One program per (row, query-block); blocks wholly past the row's
-    ragged ``q_len`` degenerate to near no-ops: the chunk loop's trip
-    count collapses to ZERO (so no page DMAs are armed — nothing for the
-    next program's semaphores to trip over — and no matmuls run), leaving
-    only the cheap vector-unit epilogue writing zeros into the pad block.
-    Mosaic cannot lower the layout transposes inside a ``pl.when``
-    branch, so the skip is expressed through the loop bounds instead of a
-    guarded body."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
+    """One program per block of ``SB`` packed slots.
+
+    q_ref/out_ref: [SB, Hq, Dh]; rows_ref [2, n_blocks]: the first row
+    with a slot in the block and one past the last; qstart/qlen/lens [R]:
+    a row's first slot, its slots, its context including them.
+    buf: [2, 2, Hkv, chunk*page_size, Dh] double-buffered kv slabs;
+    m/l [Hkv, G*SB, 1], acc [Hkv, G*SB, Dh]: the running softmax state
+    of the block's slots.
+
+    A row without slots in the block (a pad row, ``q_len`` 0) runs its
+    chunk loop zero times, so no page DMA is armed and no matmul runs.
+    Mosaic cannot lower the layout transposes inside a ``pl.when`` branch,
+    so that skip is expressed through the loop bounds."""
+    i = pl.program_id(0)
     layer = layer_ref[0]
     win = window_ref[0]
-    ctx = lens_ref[b]
-    q_start = qstart_ref[b]
-    # the ragged descriptor: row b contributes q_len real query tokens at
-    # positions q_start .. ctx-1 (a decode row is q_len == 1)
-    q_len = ctx - q_start
-    active = j * q_block < q_len
-
     SB = q_block
-    Hq, Dh = q_ref.shape[2], q_ref.shape[3]
+    Hq, Dh = q_ref.shape[1], q_ref.shape[2]
     G = Hq // n_kv
     span = chunk * page_size
-
-    # kv this block can see: causal bound clamped to the live context
-    block_last = q_start + (j + 1) * SB - 1
-    visible = jnp.minimum(ctx, block_last + 1)
-    num_chunks = jnp.maximum(jax.lax.div(visible + span - 1, span), 1)
-    block_first = q_start + j * SB
-    first_pos = jnp.where(win > 0,
-                          jnp.maximum(block_first - win + 1, 0), 0)
-
     P = table_ref.shape[1]
+    t0 = i * SB
 
-    def page_dma(slot, i, c):
-        jj = jnp.minimum(c * chunk + i, P - 1)
-        return pltpu.make_async_copy(
-            kv_hbm.at[layer, table_ref[b, jj]],
-            buf.at[slot, :, :, pl.ds(i * page_size, page_size)],
-            sem.at[slot, i])
-
-    def start_chunk(slot, c):
-        def start_one(i, _):
-            page_dma(slot, i, c).start()
-            return 0
-
-        jax.lax.fori_loop(0, chunk, start_one, 0, unroll=True)
-
-    def wait_chunk(slot, c):
-        def wait_one(i, _):
-            page_dma(slot, i, c).wait()
-            return 0
-
-        jax.lax.fori_loop(0, chunk, wait_one, 0, unroll=True)
-
-    c0 = jnp.minimum(jax.lax.div(first_pos, span), num_chunks - 1)
-    # THE ragged skip: an inactive block runs the chunk loop zero times
-    n_end = jnp.where(active, num_chunks, c0)
-
-    @pl.when(active)
-    def _():
-        start_chunk(jax.lax.rem(c0, 2), c0)
-
-    q = q_ref[0].reshape(SB, n_kv, G, Dh).transpose(1, 2, 0, 3) \
+    q = q_ref[...].reshape(SB, n_kv, G, Dh).transpose(1, 2, 0, 3) \
         .reshape(n_kv, G * SB, Dh)
-    qpos = q_start + j * SB + jax.lax.broadcasted_iota(
-        jnp.int32, (1, G, SB, 1), 2)                       # [1, G, SB, 1]
+    # the packed slot of each query row of the block
+    slot_t = t0 + jax.lax.broadcasted_iota(jnp.int32, (1, G, SB, 1), 2)
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    def body(c, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(c, 2)
+    def one_row(r, _):
+        ctx = lens_ref[r]
+        q_start = qstart_ref[r]
+        q_len = qlen_ref[r]
+        # the row's slots inside this block, and their positions: slot t
+        # sits at absolute position pos0 + t
+        lo = jnp.maximum(q_start, t0)
+        hi = jnp.minimum(q_start + q_len, t0 + SB)
+        active = hi > lo
+        pos0 = ctx - q_len - q_start
+        # kv the row's slots of this block can see: causal bound, inside
+        # the live context by construction (hi <= q_start + q_len)
+        visible = pos0 + hi
+        num_chunks = jnp.maximum(jax.lax.div(visible + span - 1, span), 1)
+        first_pos = jnp.where(win > 0,
+                              jnp.maximum(pos0 + lo - win + 1, 0), 0)
+        c0 = jnp.minimum(jax.lax.div(first_pos, span), num_chunks - 1)
+        n_end = jnp.where(active, num_chunks, c0)
 
-        @pl.when(c + 1 < n_end)
+        def page_dma(slot, k, c):
+            jj = jnp.minimum(c * chunk + k, P - 1)
+            return pltpu.make_async_copy(
+                kv_hbm.at[layer, table_ref[r, jj]],
+                buf.at[slot, :, :, pl.ds(k * page_size, page_size)],
+                sem.at[slot, k])
+
+        def start_chunk(slot, c):
+            def start_one(k, _):
+                page_dma(slot, k, c).start()
+                return 0
+
+            jax.lax.fori_loop(0, chunk, start_one, 0, unroll=True)
+
+        def wait_chunk(slot, c):
+            def wait_one(k, _):
+                page_dma(slot, k, c).wait()
+                return 0
+
+            jax.lax.fori_loop(0, chunk, wait_one, 0, unroll=True)
+
+        @pl.when(active)
         def _():
-            start_chunk(jax.lax.rem(c + 1, 2), c + 1)
+            start_chunk(jax.lax.rem(c0, 2), c0)
 
-        wait_chunk(slot, c)
-        k = buf[slot, 0]                                   # [Hkv, span, Dh]
-        v = buf[slot, 1]
+        qpos = pos0 + slot_t                               # [1, G, SB, 1]
+        in_row = (slot_t >= q_start) & (slot_t < q_start + q_len)
 
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)            # [Hkv, G*SB, span]
-        if softcap:
-            s = jnp.tanh(s / softcap) * softcap
-        s4 = s.reshape(n_kv, G, SB, span)
-        t_pos = c * span + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, 1, span), 3)
-        # pad rows of the block (local row >= q_len - j*SB) carry
-        # qpos >= ctx; the `t_pos < ctx` bound keeps their work finite
-        # and their outputs are never read downstream (the engine
-        # samples at each row's last REAL token only)
-        mask = (t_pos <= qpos) & (t_pos < ctx)             # [1, G, SB, span]
-        mask &= (win <= 0) | (t_pos > qpos - win)
-        s4 = jnp.where(mask, s4, NEG_INF)
-        s = s4.reshape(n_kv, G * SB, span)
+        def body(c, _):
+            slot = jax.lax.rem(c, 2)
 
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))        # [Hkv, G*SB]
-        p = jnp.exp(s - m_new[..., None])
-        p = jnp.where((m_new > NEG_INF / 2)[..., None], p, 0.0)
-        scale = jnp.where(m > NEG_INF / 2, jnp.exp(m - m_new), 0.0)
-        l_new = l * scale + jnp.sum(p, axis=-1)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)            # [Hkv, G*SB, Dh]
-        acc = acc * scale[..., None] + pv
-        return m_new, l_new, acc
+            @pl.when(c + 1 < n_end)
+            def _():
+                start_chunk(jax.lax.rem(c + 1, 2), c + 1)
 
-    m0 = jnp.full((n_kv, G * SB), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((n_kv, G * SB), jnp.float32)
-    acc0 = jnp.zeros((n_kv, G * SB, Dh), jnp.float32)
-    _m, l, acc = jax.lax.fori_loop(c0, n_end, body, (m0, l0, acc0))
-    # inactive blocks kept acc == 0, l == 0: the epilogue writes zeros
-    # into the pad block — deterministic output for the parity oracle
-    out = acc / jnp.maximum(l, 1e-20)[..., None]           # [Hkv, G*SB, Dh]
+            wait_chunk(slot, c)
+            k = buf[slot, 0]                               # [Hkv, span, Dh]
+            v = buf[slot, 1]
+
+            s = jax.lax.dot_general(
+                q, k, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)        # [Hkv, G*SB, span]
+            if softcap:
+                s = jnp.tanh(s / softcap) * softcap
+            s4 = s.reshape(n_kv, G, SB, span)
+            t_pos = c * span + jax.lax.broadcasted_iota(
+                jnp.int32, (1, 1, 1, span), 3)
+            mask = in_row & (t_pos <= qpos)                # [1, G, SB, span]
+            mask &= (win <= 0) | (t_pos > qpos - win)
+            s4 = jnp.where(mask, s4, NEG_INF)
+            s = s4.reshape(n_kv, G * SB, span)
+
+            # slots of other rows see nothing here: their max stays, their
+            # p is 0 (or masked below while they have seen nothing at all)
+            m = m_ref[...]                                 # [Hkv, G*SB, 1]
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            p = jnp.where(m_new > NEG_INF / 2, p, 0.0)
+            scale = jnp.where(m > NEG_INF / 2, jnp.exp(m - m_new), 0.0)
+            l_ref[...] = l_ref[...] * scale + jnp.sum(p, axis=-1,
+                                                      keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)        # [Hkv, G*SB, Dh]
+            acc_ref[...] = acc_ref[...] * scale + pv
+            m_ref[...] = m_new
+            return 0
+
+        jax.lax.fori_loop(c0, n_end, body, 0)
+        return 0
+
+    jax.lax.fori_loop(rows_ref[0, i], rows_ref[1, i], one_row, 0)
+    # slots of no row kept acc == 0, l == 0: zeros, a deterministic output
+    # for the parity oracle
+    out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)
     out = out.reshape(n_kv, G, SB, Dh).transpose(2, 0, 1, 3) \
         .reshape(SB, Hq, Dh)
-    out_ref[0] = out.astype(out_ref.dtype)
+    out_ref[...] = out.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("sm_scale", "softcap", "interpret"))
-def _ragged_mixed(q, kv_pages, layer_idx, window, page_table, q_start,
-                  total_lens, sm_scale: float, softcap: float = 0.0,
+def _ragged_mixed(q, kv_pages, layer_idx, window, page_table, q_starts,
+                  q_lens, kv_lens, sm_scale: float, softcap: float = 0.0,
                   interpret: bool = False):
-    B, S, Hq, Dh = q.shape
+    T, Hq, Dh = q.shape
     _L, _N, _two, Hkv, page_size, _ = kv_pages.shape
     P = page_table.shape[1]
     chunk = min(PAGES_PER_CHUNK, P)
     span = chunk * page_size
     slab_bytes = 2 * 2 * Hkv * span * Dh * kv_pages.dtype.itemsize
-    SB = _fit_query_block(S, Hq, Dh, span, slab_bytes)
-    n_q_blocks = -(-S // SB)
+    SB = _fit_query_block(T, Hq, Dh, span, slab_bytes)
+    n_blocks = -(-T // SB)
+    # sm_scale rides the packed q (the kernel's matmuls see it once)
+    qs = (q * sm_scale).astype(q.dtype)
+    if n_blocks * SB != T:
+        qs = jnp.pad(qs, ((0, n_blocks * SB - T), (0, 0), (0, 0)))
+    # the rows with slots in each block: rows are packed in order, so
+    # those whose end lies past the block's start and whose start lies
+    # before its end
+    t0 = jnp.arange(n_blocks, dtype=jnp.int32)[:, None] * SB
+    rows = jnp.stack([
+        jnp.sum(((q_starts + q_lens)[None, :] <= t0), axis=1),
+        jnp.sum((q_starts[None, :] < t0 + SB), axis=1)]).astype(jnp.int32)
 
     kernel = functools.partial(_ragged_kernel, page_size=page_size,
                                n_kv=Hkv, chunk=chunk, q_block=SB,
                                softcap=softcap)
-    return pl.pallas_call(
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    G = Hq // Hkv
+    out = pl.pallas_call(
         kernel,
-        grid=(B, n_q_blocks),
+        grid=(n_blocks,),
         in_specs=[
-            pl.BlockSpec((1, SB, Hq, Dh), lambda b, j: (b, j, 0, 0)),
+            pl.BlockSpec((SB, Hq, Dh), lambda i: (i, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
+            smem, smem, smem, smem, smem, smem, smem,
         ],
-        out_specs=pl.BlockSpec((1, SB, Hq, Dh), lambda b, j: (b, j, 0, 0)),
+        out_specs=pl.BlockSpec((SB, Hq, Dh), lambda i: (i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, 2, Hkv, chunk * page_size, Dh), kv_pages.dtype),
             pltpu.SemaphoreType.DMA((2, chunk)),
+            pltpu.VMEM((Hkv, G * SB, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G * SB, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G * SB, Dh), jnp.float32),
         ],
-        out_shape=jax.ShapeDtypeStruct((B, S, Hq, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_blocks * SB, Hq, Dh), q.dtype),
         interpret=interpret,
         name="ragged_mixed",
-    )((q * sm_scale).astype(q.dtype), kv_pages, layer_idx, window,
-      page_table, q_start, total_lens)
+    )(qs, kv_pages, layer_idx, window, page_table, rows, q_starts, q_lens,
+      kv_lens)
+    return out[:T]
 
 
-def ragged_mixed_attention_stacked(q: jnp.ndarray, pages: jnp.ndarray,
-                                   layer_idx, page_table: jnp.ndarray,
-                                   positions: jnp.ndarray,
-                                   total_lens: jnp.ndarray, sm_scale: float,
-                                   window=None, softcap=None,
-                                   interpret: bool | None = None
-                                   ) -> jnp.ndarray:
-    """Drop-in for ``ops.attention.paged_attention`` on MIXED steps
-    (S > 1, rows ragged: each row's real query tokens are its leading
-    ``total_lens[b] - positions[b, 0]`` slots — a prefill chunk, or a
-    single decode token).
+def ragged_mixed_attention_packed(q: jnp.ndarray, pages: jnp.ndarray,
+                                  layer_idx, page_table: jnp.ndarray,
+                                  q_starts: jnp.ndarray,
+                                  q_lens: jnp.ndarray,
+                                  kv_lens: jnp.ndarray, sm_scale: float,
+                                  window=None, softcap=None,
+                                  interpret: bool | None = None
+                                  ) -> jnp.ndarray:
+    """Drop-in for ``ops.attention.ragged_paged_attention`` on a
+    token-packed step.
 
-    q:          [B, S, Hq, Dh] (S = padded widest chunk in the batch)
+    q:          [T, Hq, Dh] every row's query tokens back to back; slots of
+                no row are pad (their output is zero)
     pages:      [L, N, 2, Hkv, page_size, Dh]
     layer_idx:  scalar int (python int or traced scan index)
-    page_table: [B, P]
-    positions:  [B, S] absolute positions (row-contiguous; only column 0
-                enters the kernel — the ragged length is derived as
-                ``total_lens - positions[:, 0]``)
-    total_lens: [B] context length including the new tokens
+    page_table: [R, P]
+    q_starts:   [R] a row's first slot (ascending, packed: the exclusive
+                cumulative sum of ``q_lens``)
+    q_lens:     [R] real query tokens per row (a decode row is 1, a pad
+                row 0)
+    kv_lens:    [R] context per row including its new tokens
     window:     optional scalar (python int or traced, 0 = unlimited)
     softcap:    optional STATIC float (gemma logit soft-capping)
     """
@@ -239,16 +265,17 @@ def ragged_mixed_attention_stacked(q: jnp.ndarray, pages: jnp.ndarray,
            else jnp.asarray(window, jnp.int32).reshape(1))
     return _ragged_mixed(q, pages, layer, win,
                          page_table.astype(jnp.int32),
-                         positions[:, 0].astype(jnp.int32),
-                         total_lens.astype(jnp.int32), sm_scale,
+                         q_starts.astype(jnp.int32),
+                         q_lens.astype(jnp.int32),
+                         kv_lens.astype(jnp.int32), sm_scale,
                          softcap=float(softcap or 0.0),
                          interpret=_resolve_interpret(interpret))
 
 
 # the family forwards consult these markers before handing an impl their
 # per-layer window/softcap kwargs (see ops/pallas/prefill.py)
-ragged_mixed_attention_stacked.supports_window_softcap = True
-ragged_mixed_attention_stacked.pallas_paged_kernel = True
+ragged_mixed_attention_packed.supports_window_softcap = True
+ragged_mixed_attention_packed.pallas_paged_kernel = True
 
 
-__all__ = ["ragged_mixed_attention_stacked", "supports"]
+__all__ = ["ragged_mixed_attention_packed", "supports"]

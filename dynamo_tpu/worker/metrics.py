@@ -160,6 +160,13 @@ class EngineDispatchCollector:
                         "penalty_window", "guided", "guided_table",
                         "spec", "budget", "pages", "multihost")
 
+    # the forms a prefill-carrying step can take (engine/jax_engine.py
+    # _why_padded, and "ring" per plan), pre-seeded like the fallback
+    # reasons
+    PREFILL_FORMS = ("packed", "padded:forward", "padded:family",
+                     "padded:dp", "padded:spec", "padded:attn_impl",
+                     "padded:ring")
+
     def __init__(self, registry: CollectorRegistry):
         self._source: Optional[Callable[[], Dict[str, float]]] = None
         registry.register(self)
@@ -194,6 +201,21 @@ class EngineDispatchCollector:
         for reason, value in sorted(reasons.items()):
             fb.add_metric([str(reason)], float(value))
         yield fb
+        # prefill-carrying steps by the form they ran in, so a model that
+        # silently serves padded shows on the scrape
+        pf = CounterMetricFamily(
+            "dynamo_worker_prefill_steps",
+            "Prefill-carrying dispatches (mixed steps and chunked-prefill "
+            "steps) by form: 'packed' (one [T] token axis for prompt "
+            "chunks and decode rows) or 'padded:<reason>' ([rows x longest "
+            "chunk]; reason forward/family/dp/spec/attn_impl for the "
+            "engine, ring for a sequence-parallel whole-prompt step)",
+            labels=["form"])
+        forms = dict.fromkeys(self.PREFILL_FORMS, 0.0)
+        forms.update(stats.get("prefill_steps") or {})
+        for form, value in sorted(forms.items()):
+            pf.add_metric([str(form)], float(value))
+        yield pf
 
 
 class StepTraceCollector:
@@ -310,8 +332,9 @@ class StepTraceCollector:
 def engine_dispatch_stats(engine) -> Dict[str, object]:
     """The ``EngineDispatchCollector.attach`` source for a
     ``ScheduledEngineBase`` engine (JaxEngine and the mocker both carry
-    the counters). Values are floats, except ``multistep_fallbacks``:
-    a per-reason count dict the collector renders as a labeled family."""
+    the counters). Values are floats, except ``multistep_fallbacks`` and
+    ``prefill_steps``: per-label count dicts the collector renders as
+    labeled families."""
     sched = getattr(engine, "scheduler", None)
     return {
         "decode_dispatches": float(getattr(engine, "decode_dispatches", 0)),
@@ -322,6 +345,7 @@ def engine_dispatch_stats(engine) -> Dict[str, object]:
             getattr(engine, "guided_parity_mismatches", 0)),
         "multistep_fallbacks": dict(
             getattr(sched, "multistep_fallbacks", None) or {}),
+        "prefill_steps": dict(getattr(engine, "prefill_steps", None) or {}),
     }
 
 

@@ -1,10 +1,12 @@
-"""The cache write (``ops/attention.write_kv`` / ``write_kv_layer``).
+"""The cache write (``ops/attention.write_kv`` / ``write_kv_layer``, and
+``write_kv_packed`` for a token-packed step).
 
 Parity, bit for bit, of the page-at-a-time write against the plain
 ``.at[layer, phys, :, :, slot].set`` it replaced, over both pool forms, the
 three step shapes, the GQA and MLA page geometries, both cache dtypes, and
-page sizes the TPU's tile rows divide and do not (one path serves all).
-Then the guard on the compiled step programs
+page sizes the TPU's tile rows divide and do not (one path serves all);
+the packed entry against the same scatter on the same rows laid back to
+back. Then the guard on the compiled step programs
 (``engine/program_check.py``): no pool-sized copy, no pool-sized
 temporary.
 """
@@ -14,7 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dynamo_tpu.ops.attention import write_kv, write_kv_layer
+from dynamo_tpu.ops.attention import (write_kv, write_kv_layer,
+                                      write_kv_packed)
 
 L, LAYER = 3, 1
 GEOMETRY = {"gqa": (8, 128), "mla": (1, 512)}      # Hkv, Dh
@@ -121,6 +124,59 @@ def test_write_matches_the_plain_scatter_bit_for_bit(pool_form, step,
     np.testing.assert_array_equal(got[idle], before[idle])
 
 
+def _packed_rows(ps: int):
+    """(start positions, new_lens) of five rows of a packed step: a fresh
+    chunk, a decode row deep in its context, a dead row, a resumed chunk
+    that ends off a page boundary, and two tokens across a page edge."""
+    return [0, 9 * ps + 1, 5, 2 * ps, 4 * ps - 1], [24, 1, 0, 13, 2]
+
+
+@pytest.mark.parametrize("ps", [16, 4])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("geometry", ["gqa", "mla"])
+def test_packed_write_matches_the_plain_scatter_bit_for_bit(geometry, dtype,
+                                                            ps):
+    """The same rows with their tokens back to back on one ``[T]`` axis
+    (pads behind them): the pool ends as the plain per-token scatter of
+    each row leaves it, page 0 and every page no token named untouched,
+    and the scatter still takes whole pages."""
+    Hkv, Dh = GEOMETRY[geometry]
+    start, new_lens = _packed_rows(ps)
+    R, P = len(start), 16
+    N = R * P + 3
+    T = sum(new_lens) + 7
+    rng = np.random.default_rng(11)
+    shape = (N, 2, Hkv, ps, Dh)
+    pool = jnp.asarray(rng.standard_normal((L,) + shape), dtype)
+    k_new = jnp.asarray(rng.standard_normal((T, Hkv, Dh)), dtype)
+    v_new = jnp.asarray(rng.standard_normal((T, Hkv, Dh)), dtype)
+    table = jnp.asarray(rng.permutation(np.arange(1, 1 + R * P))
+                        .reshape(R, P), jnp.int32)
+    lens = jnp.asarray(new_lens, jnp.int32)
+    cu = jnp.cumsum(lens) - lens
+    # a dead row reads total 1, new 0, as the engine's pad rows do
+    total = jnp.where(lens > 0, jnp.asarray(start, jnp.int32) + lens, 1)
+    fn = lambda *a: write_kv_packed(a[0], LAYER, *a[1:])       # noqa: E731
+    args = (pool, k_new, v_new, table, cu, lens, total)
+    assert _pool_scatter_window(fn, *args) == (2, Hkv, ps, Dh)
+    got = jax.jit(fn)(*args)
+    # the oracle: each row alone through the plain scatter
+    want = pool
+    for r in range(R):
+        n, s0 = new_lens[r], int(cu[r])
+        if n:
+            pos = jnp.arange(start[r], start[r] + n, dtype=jnp.int32)[None]
+            want = _old_write(want, LAYER, k_new[None, s0:s0 + n],
+                              v_new[None, s0:s0 + n], table[r:r + 1], pos,
+                              jnp.asarray([n], jnp.int32))
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.float32)))
+    # pads land nowhere: page 0 is as it was, in every layer
+    np.testing.assert_array_equal(
+        np.asarray(got[:, 0].astype(jnp.float32)),
+        np.asarray(pool[:, 0].astype(jnp.float32)))
+
+
 def test_pool_copies_reads_an_optimised_hlo_text():
     """The reader on a hand-written module: a ``copy`` of the pool, a copy
     of its 2-D view and a fusion that ends in one are listed; the in-place
@@ -155,7 +211,7 @@ ENTRY %main (pages: bf16[2,3,16,128]) -> bf16[2,3,16,128] {
         "ROOT %copy.9", "%copy.1", "%copy.2", "%fusion.2"]
 
 
-def _toy_engine():
+def _toy_engine(attn_impl: str = "scan"):
     from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
     from dynamo_tpu.models import llama
     from dynamo_tpu.models.config import ModelConfig
@@ -170,7 +226,7 @@ def _toy_engine():
     # a pool (16.8 MB) that dwarfs every other temporary of a toy step
     return JaxEngine(cfg, params, JaxEngineConfig(
         num_pages=512, page_size=8, max_num_seqs=4, max_prefill_chunk=64,
-        max_context=256, attn_impl="scan"))
+        max_context=256, attn_impl=attn_impl))
 
 
 @pytest.fixture(scope="module")
@@ -180,6 +236,36 @@ def toy_reports():
     return {r["program"]: r
             for r in check_step_programs(_toy_engine(), batch=4, chunk=32,
                                          width=4)}
+
+
+@pytest.fixture(scope="module")
+def toy_reports_packing():
+    """The same engine on the kernels (interpreted here): it serves its
+    prefill-carrying steps token-packed, so the check has a fourth
+    program."""
+    from dynamo_tpu.engine.program_check import check_step_programs
+
+    return {r["program"]: r
+            for r in check_step_programs(_toy_engine("pallas"), batch=4,
+                                         chunk=32, width=4, tokens=64)}
+
+
+@pytest.mark.parametrize("program", ["decode", "fused", "mixed", "packed"])
+def test_a_packing_engines_programs_hold_no_pool_sized_copy(
+        toy_reports_packing, program):
+    """The packed program beside the three others of an engine that packs:
+    the packed cache write gathers and scatters whole pages of the donated
+    pool in place, like the padded one."""
+    assert list(toy_reports_packing) == ["decode", "fused", "mixed",
+                                         "packed"]
+    r = toy_reports_packing[program]
+    assert r["pool_copies"] == []
+    assert r["temp_bytes"] < r["pool_bytes"], r
+    assert r["ok"]
+
+
+def test_an_engine_that_does_not_pack_has_no_packed_program(toy_reports):
+    assert list(toy_reports) == ["decode", "fused", "mixed"]
 
 
 @pytest.mark.parametrize("program", ["decode", "fused", "mixed"])

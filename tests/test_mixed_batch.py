@@ -7,8 +7,8 @@ running while arrivals onboard (the PR 8 "no waiters/prefills" gate is
 lifted), and the token streams stay BIT-IDENTICAL to the legacy
 prefill-XOR-decode alternation under greedy and fixed-seed sampling.
 The ragged attention op (``ops.attention.ragged_paged_attention`` flat
-reference + ``ops/pallas/ragged.py`` kernel) matches the dense per-row
-oracle on ragged row shapes.
+reference + ``ops/pallas/ragged.py`` kernel over the same packed layout)
+matches the dense per-row oracle on ragged row shapes.
 """
 
 import asyncio
@@ -104,39 +104,64 @@ class TestRaggedOp:
         # pad slots are zeroed, not garbage
         assert float(jnp.max(jnp.abs(out[int(q_lens.sum()):]))) == 0.0
 
-    def test_pallas_ragged_kernel_matches_xla_reference(self):
+    @pytest.mark.parametrize("window,softcap", [
+        (None, None), (6, None), (None, 30.0), (17, 50.0)])
+    def test_pallas_packed_kernel_matches_xla_reference(self, window,
+                                                        softcap):
+        """The packed kernel (interpreted) against the flat reference on
+        the same packed layout: no row is aligned to a query block, the
+        decode row takes one slot, a chunk straddles two blocks."""
         import jax.numpy as jnp
 
-        from dynamo_tpu.ops.attention import paged_attention
+        from dynamo_tpu.ops.attention import ragged_paged_attention
         from dynamo_tpu.ops.pallas.ragged import (
-            ragged_mixed_attention_stacked)
+            ragged_mixed_attention_packed)
         pages, table, q, q_starts, q_lens, kv_lens = self._setup()
         pages = pages.astype(jnp.bfloat16)
-        # S wider than the 128-row query block so the decode row's tail
-        # blocks are genuinely SKIPPED (the ragged win under test)
-        B, S = 3, 256
-        Hq, Dh = q.shape[1], q.shape[2]
-        qb = jnp.zeros((B, S, Hq, Dh), jnp.bfloat16)
-        positions = np.zeros((B, S), np.int32)
-        for i in range(B):
-            s, ln, kv = int(q_starts[i]), int(q_lens[i]), int(kv_lens[i])
-            qb = qb.at[i, :ln].set(q[s:s + ln].astype(jnp.bfloat16))
-            positions[i, :ln] = np.arange(kv - ln, kv)
-        out = ragged_mixed_attention_stacked(
-            qb, pages, 1, table, jnp.asarray(positions),
-            jnp.asarray(kv_lens), 0.09, interpret=True)
-        ref = paged_attention(qb, pages, 1, table, jnp.asarray(positions),
-                              jnp.asarray(kv_lens), 0.09)
-        for i in range(B):
-            ln = int(q_lens[i])
-            err = float(jnp.max(jnp.abs(
-                out[i, :ln].astype(jnp.float32)
-                - ref[i, :ln].astype(jnp.float32))))
-            assert err < 0.05, (i, err)
-        # blocks wholly past a row's q_len are skipped and write zeros
-        # (within-block pad slots compute masked garbage — never read)
-        assert float(jnp.max(jnp.abs(
-            out[1, 128:].astype(jnp.float32)))) == 0.0
+        q = q.astype(jnp.bfloat16)
+        args = (q, pages, 1, table, jnp.asarray(q_starts),
+                jnp.asarray(q_lens), jnp.asarray(kv_lens), 0.09)
+        out = ragged_mixed_attention_packed(
+            *args, window=window, softcap=softcap, interpret=True)
+        ref = ragged_paged_attention(
+            *args, window=None if window is None else jnp.asarray(window),
+            softcap=softcap)
+        assert out.shape == q.shape and out.dtype == q.dtype
+        n = int(q_lens.sum())
+        err = float(jnp.max(jnp.abs(out[:n].astype(jnp.float32)
+                                    - ref[:n].astype(jnp.float32))))
+        assert err < 0.05, err
+        # slots of no row write zeros
+        assert float(jnp.max(jnp.abs(out[n:].astype(jnp.float32)))) == 0.0
+
+    def test_packed_kernel_over_many_blocks_and_pad_rows(self):
+        """A packed axis of several query blocks: chunks that straddle
+        block edges, decode rows packed behind them, pad rows (no tokens)
+        and whole blocks past the last real token."""
+        import jax.numpy as jnp
+
+        from dynamo_tpu.ops.attention import ragged_paged_attention
+        from dynamo_tpu.ops.pallas.ragged import (
+            ragged_mixed_attention_packed)
+        rng = np.random.default_rng(3)
+        L, N, Hkv, ps, Dh, Hq, P = 2, 64, 2, 8, 128, 4, 24
+        pages = jnp.asarray(rng.normal(size=(L, N, 2, Hkv, ps, Dh))
+                            .astype(np.float32)).astype(jnp.bfloat16)
+        q_lens = np.array([70, 150, 1, 1, 1, 0, 0], np.int32)
+        kv_lens = np.array([133, 150, 9, 77, 1, 1, 1], np.int32)
+        table = jnp.asarray(rng.integers(1, N, size=(len(q_lens), P))
+                            .astype(np.int32))
+        q_starts = (np.cumsum(q_lens) - q_lens).astype(np.int32)
+        for T in (512, 230):       # 230: not a multiple of the block
+            q = jnp.asarray(rng.normal(size=(T, Hq, Dh))
+                            .astype(np.float32)).astype(jnp.bfloat16)
+            args = (q, pages, 1, table, jnp.asarray(q_starts),
+                    jnp.asarray(q_lens), jnp.asarray(kv_lens), 0.09)
+            out = ragged_mixed_attention_packed(*args, interpret=True)
+            ref = ragged_paged_attention(*args)
+            err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
+                                        - ref.astype(jnp.float32))))
+            assert out.shape == (T, Hq, Dh) and err < 0.05, (T, err)
 
 
 # -- engine parity: mixed dispatch vs legacy alternation ------------------

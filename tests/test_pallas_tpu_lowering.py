@@ -102,26 +102,29 @@ def test_gqa_prefill_kernel_lowers(window, softcap):
     _assert_mosaic(exp)
 
 
-def test_ragged_mixed_kernel_lowers():
-    """The ragged mixed-batch kernel (one dispatch for prefill chunks +
-    decode rows, `ops/pallas/ragged.py`) lowers at the same Llama-3-class
-    geometry as the prefill kernel it extends — the program the engine's
-    mixed step runs on chip with DYN_MIXED_BATCH on."""
-    from dynamo_tpu.ops.pallas.ragged import ragged_mixed_attention_stacked
+@pytest.mark.parametrize("window,softcap", [(None, None), (4096, 50.0)])
+def test_packed_ragged_kernel_lowers(window, softcap):
+    """The packed ragged kernel (one dispatch for prefill chunks + decode
+    rows on one token axis, `ops/pallas/ragged.py`) lowers at Qwen3-4B
+    widths and the batch cell's step: 32/8 heads of 128, page 16, 1,152
+    slots, 32 rows — the program the engine's packed step runs on chip."""
+    from dynamo_tpu.ops.pallas.ragged import ragged_mixed_attention_packed
 
-    Hq, Hkv, Dh, S = 24, 8, 128, 512
+    Hq, Hkv, Dh, T, R = 32, 8, 128, 1152, 32
 
-    def fn(q, pages, table, positions, total):
-        return ragged_mixed_attention_stacked(
-            q, pages, 1, table, positions, total, 0.088, interpret=False)
+    def fn(q, pages, table, starts, q_lens, kv_lens):
+        return ragged_mixed_attention_packed(
+            q, pages, 1, table, starts, q_lens, kv_lens, 0.088,
+            window=window, softcap=softcap, interpret=False)
 
     exp = _export_tpu(
         fn,
-        jax.ShapeDtypeStruct((B, S, Hq, Dh), jnp.bfloat16),
+        jax.ShapeDtypeStruct((T, Hq, Dh), jnp.bfloat16),
         jax.ShapeDtypeStruct((L, N, 2, Hkv, PS, Dh), jnp.bfloat16),
-        jax.ShapeDtypeStruct((B, P * 4), jnp.int32),
-        jax.ShapeDtypeStruct((B, S), jnp.int32),
-        jax.ShapeDtypeStruct((B,), jnp.int32))
+        jax.ShapeDtypeStruct((R, P * 8), jnp.int32),
+        jax.ShapeDtypeStruct((R,), jnp.int32),
+        jax.ShapeDtypeStruct((R,), jnp.int32),
+        jax.ShapeDtypeStruct((R,), jnp.int32))
     _assert_mosaic(exp)
 
 
@@ -217,9 +220,10 @@ def test_tp_sharded_steps_compile_in_the_tpu_compiler():
     ("Mosaic kernels cannot be automatically partitioned") — what every
     tp>1 worker would have hit on the chip before ``JaxEngine._per_shard``
     ran the kernels per shard. libtpu compiles without a chip against a
-    topology description, so the refusal is catchable here: the decode and
-    the mixed step of a tp=2 engine at Llama-3.2-3B widths must compile,
-    with no all-gather (the cache stays sharded on Hkv)."""
+    topology description, so the refusal is catchable here: the decode, the
+    padded prefill and the token-packed step of a tp=2 engine at
+    Llama-3.2-3B widths must compile, with no all-gather (the cache stays
+    sharded on Hkv) and no copy of a chip's slab of the pool."""
     import dataclasses
 
     from jax.experimental import topologies
@@ -264,11 +268,14 @@ def test_tp_sharded_steps_compile_in_the_tpu_compiler():
     N_ = 2048
     pages = sds((L_, N_, two, Hkv_, ps_, Dh_), eng.pages.dtype,
                 P_(None, None, None, "tp", None, None))
-    for impl, B_, S_ in ((eng._step_impl, 4, 1),
-                         (eng._mixed_step_impl, 2, 128)):
+    assert eng.padded_reason is None        # a tp mesh packs
+    # (program, rows, width of the token arrays, their leading axis)
+    for impl, B_, S_, lead in ((eng._step_impl, 4, 1, 4),
+                               (eng._step_impl, 2, 128, 2),
+                               (eng._packed_step_impl, 4, 256, 1)):
         compiled = jax.jit(impl, donate_argnums=(1,)).lower(
-            params, pages, sds((B_, S_), jnp.int32),
-            sds((B_, S_), jnp.int32),
+            params, pages, sds((lead, S_), jnp.int32),
+            sds((lead, S_), jnp.int32),
             sds((B_, eng.table_width), jnp.int32), sds((B_,), jnp.int32),
             sds((B_,), jnp.int32), sds((2,), jnp.uint32),
             sds((), jnp.int32), sds((B_,), jnp.float32),

@@ -547,9 +547,11 @@ class TestDeviceTime:
 def test_module_names_the_benchmark_matches_on():
     """``benchmarks/layer_metrics/step.decode_hbm_share.py`` finds the
     decode programs on the trace's ``XLA Modules`` line by name: the
-    one-step program, the mixed program and the fused block (a closure:
-    ``unknown``) keep the names they had when the benchmark was accepted,
-    whatever scopes and kernel names are added inside them."""
+    one-step program and the fused block (a closure: ``unknown``) keep the
+    names they had when the benchmark was accepted, whatever scopes and
+    kernel names are added inside them; the token-packed prefill-carrying
+    program has a name the decode reader does NOT match (it starts with
+    neither), so a prefill step is never counted as decode."""
     import numpy as np
 
     from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
@@ -575,9 +577,9 @@ def test_module_names_the_benchmark_matches_on():
             raise Lowered
         return call
 
-    assert eng._jit_mixed is not eng._jit_step     # the Pallas path's own
+    assert eng.padded_reason is None               # the Pallas path packs
     eng._jit_step = spy("step", eng._jit_step)
-    eng._jit_mixed = spy("mixed", eng._jit_mixed)
+    eng._jit_packed = spy("packed", eng._jit_packed)
     eng._jit_ms[8] = spy("multistep", eng._get_jit_multistep(8))
     B, S = 4, 8
     arrays = {"toks": np.zeros((B, S), np.int32),
@@ -588,14 +590,22 @@ def test_module_names_the_benchmark_matches_on():
               "temp": np.zeros(B, np.float32),
               "top_k": np.zeros(B, np.int32),
               "top_p": np.ones(B, np.float32)}
-    for kind in ("step", "mixed"):
+    packed = dict(arrays, toks=np.zeros((1, 32), np.int32),
+                  pos=np.zeros((1, 32), np.int32))
+    # a padded mixed step IS the one-step program
+    for kind, a in (("step", arrays), ("mixed", arrays),
+                    ("packed", packed)):
         with pytest.raises(Lowered):
-            eng.execute_arrays(kind, arrays, 0)
+            eng.execute_arrays(kind, a, 0)
     with pytest.raises(Lowered):
         eng.prime_multistep(B, widths=[8])
     assert names == {"step": "@jit__step_impl",
-                     "mixed": "@jit__mixed_step_impl",
+                     "packed": "@jit__packed_step_impl",
                      "multistep": "@jit__unknown"}
+    # what the decode reader matches (DECODE_PROGRAMS in
+    # benchmarks/layer_metrics/step.decode_hbm_share.py) leaves it out
+    assert not names["packed"][1:].startswith(("jit__step_impl",
+                                               "jit__unknown"))
 
 
 class TestProfileHook:
@@ -694,7 +704,7 @@ def test_xplane_scopes_reads_the_scope_from_event_metadata(tmp_path):
     op(1, "copy.124", "data formatting",
        "jit(<unknown>)/while/body/layer.kv_write/scatter:", "attention.py:77")
     op(2, "fusion.181", "convolution fusion",
-       "jit(_mixed_step_impl)/while/body/layer.ffn/dot_general:")
+       "jit(_packed_step_impl)/while/body/layer.ffn/dot_general:")
     op(3, "while.38", "while")
     line = plane.lines.add(name="XLA Ops")
     for mid, ps in ((3, 9_000_000), (1, 4_000_000), (2, 3_000_000),

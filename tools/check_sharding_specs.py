@@ -155,8 +155,10 @@ def main() -> int:
             np.int32(0), jnp.zeros(B, jnp.float32),
             jnp.zeros(B, jnp.int32), jnp.ones(B, jnp.float32), None)
 
+    # a padded mixed step is the one-step program at S > 1 (this engine
+    # runs the XLA path on the CPU, so it does not pack)
     for name, fn2, S in (("decode", eng._jit_step, 1),
-                         ("mixed", eng._jit_mixed, 4)):
+                         ("mixed", eng._jit_step, 4)):
         comp = fn2.lower(*step_args(S)).compile()
         pg, packed, _aux = comp.output_shardings
         check(f"{name}.pages(out)", pg, pages_sharding, pages_ndim)
