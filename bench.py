@@ -20,8 +20,8 @@ Legs: ``engine`` (closed-batch decode/prefill on one engine, the same-run
 fused-vs-per-step and mixed-vs-legacy A/Bs, the KV transport planes),
 ``longctx``, ``mesh_sharded``, ``constrained_decode``, ``drain``,
 ``coord_failover``, ``fleet``, ``routing``, ``steptrace``,
-``shared_prefix``, and three that build further full-size engines and run
-only when named: ``attn_ab``, ``quant``, ``spec``.
+``shared_prefix``, and two that build further full-size engines and run
+only when named: ``quant``, ``spec``.
 """
 
 from __future__ import annotations
@@ -2597,17 +2597,6 @@ async def _measure_variant(args, label: str, **build_kw):
     return m, param_bytes, engine.attn_impl
 
 
-async def _leg_attn_ab(args, result: dict) -> None:
-    """scan+pallas against pallas_unrolled (ROADMAP Queue 1 item 6)."""
-    m, _bytes, impl = await _measure_variant(args, "ab",
-                                             attn_impl=args.ab)
-    result["ab"] = {"attn_impl": impl,
-                    "decode_tok_s": round(m["tok_per_s"], 1),
-                    "prefill_tok_s": round(m["prefill_tok_s"], 1),
-                    "ttft_p50_s": round(m["ttft_p50"], 3),
-                    "warmup_s": round(m["warmup_s"], 1)}
-
-
 async def _leg_quant(args, result: dict) -> None:
     """int8 W8A8-dynamic weights (ops/quant.py): decode is bound by the
     parameter stream, so halving it is the lever this leg prices."""
@@ -2669,13 +2658,11 @@ LEGS = {
     "steptrace": _result_leg("steptrace", _measure_steptrace),
     "shared_prefix": _result_leg("shared_prefix",
                                  _measure_shared_prefix),
-    "attn_ab": _leg_attn_ab,
     "quant": _leg_quant,
     "spec": _leg_spec,
 }
-# the three legs that build further full-size engines run only when named
-DEFAULT_LEGS = [leg for leg in LEGS
-                if leg not in ("attn_ab", "quant", "spec")]
+# the two legs that build further full-size engines run only when named
+DEFAULT_LEGS = [leg for leg in LEGS if leg not in ("quant", "spec")]
 
 
 async def run_legs(args) -> dict:
@@ -2698,10 +2685,7 @@ def _parse_args(argv=None):
                         "JAX_PLATFORMS names (how the tests run the legs "
                         "on the CPU); without it the run needs a TPU")
     p.add_argument("--attn-impl", default="auto",
-                   help="engine attn_impl (auto/pallas/pallas_unrolled/"
-                        "scan/unrolled)")
-    p.add_argument("--ab", default="pallas_unrolled",
-                   help="the attn_impl the attn_ab leg measures")
+                   help="engine attn_impl (auto/pallas/scan)")
     args = p.parse_args(argv)
     args.legs = [leg for leg in args.legs.split(",") if leg]
     unknown = [leg for leg in args.legs if leg not in LEGS]

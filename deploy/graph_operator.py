@@ -27,7 +27,7 @@ Reconcile semantics per CR:
 The planner's runtime scale decisions still flow through
 ``deploy/reconciler.py`` (coordinator-KV -> replica patches); this
 controller owns the declarative shape. Run:
-``python deploy/operator.py --kube-namespace dynamo``.
+``python deploy/graph_operator.py --kube-namespace dynamo``.
 
 SCOPE (also stated in docs/deployment.md): poll-based (no watches — next
 ``--interval`` pass picks up changes; kubectl failures requeue after

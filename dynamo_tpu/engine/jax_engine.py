@@ -52,6 +52,9 @@ from dynamo_tpu.ops.sampling import sample_tokens
 
 logger = logging.getLogger(__name__)
 
+# what JaxEngineConfig.attn_impl (and the worker's --attn-impl) may say
+ATTN_IMPLS = ("auto", "pallas", "scan")
+
 
 @dataclass
 class JaxEngineConfig:
@@ -88,20 +91,15 @@ class JaxEngineConfig:
     # per-row to per-step decode with fallback reason "guided_table".
     guided_table_bytes: int = 8 << 20
     seed: int = 0
-    # attention implementation:
-    #   "scan"     — lax.scan over layers, stacked cache, XLA attention
-    #                (portable; CPU tests)
-    #   "pallas"   — scan + stacked cache, with the layer-indexed Pallas
-    #                decode kernel inside the scan body for S == 1 steps
-    #                (TPU default: one compiled layer body — ~L× cheaper
-    #                cold compile than the unrolled families — with the
-    #                kernel's page-streaming DMAs)
-    #   "unrolled" — python loop over layers, per-layer cache buffers, XLA
-    #                gather attention (CPU-testable)
-    #   "pallas_unrolled" — unrolled + per-layer Pallas decode kernel
-    #                (round-3 TPU path; kept for on-chip A/B against the
-    #                scan+pallas path)
-    #   "auto"     — pallas on TPU, scan elsewhere
+    # attention implementation (one forward per family — lax.scan over
+    # layers, one compiled layer body — over one stacked page pool; what
+    # varies is the attention op the scan body calls):
+    #   "scan"     — XLA attention (portable; the CPU's path and the
+    #                reference the kernel tests compare with)
+    #   "pallas"   — the layer-indexed Pallas kernels of each step form
+    #                (decode, padded prefill chunks, token-packed) with
+    #                their page-streaming DMAs (TPU default)
+    #   "auto"     — pallas on TPU where the kernels can run, scan elsewhere
     attn_impl: str = "auto"
     # weight quantization applied at load time: "" (serve the checkpoint
     # dtype) or "int8" (W8A8-dynamic, ops/quant.py — halves the per-step
@@ -332,7 +330,6 @@ class JaxEngine(ScheduledEngineBase):
             from dynamo_tpu.ops.quant import quantize_params
             self.params = quantize_params(self.params)
         self._forward = forward_fn or family.forward
-        self._forward_unrolled = family.forward_unrolled
         if (forward_fn is None and self.cfg.mesh is not None
                 and self.cfg.mesh.shape.get("ep", 1) > 1):
             # EP active: hand the MoE families the mesh so their dispatch
@@ -342,9 +339,11 @@ class JaxEngine(ScheduledEngineBase):
             if "ep_mesh" in inspect.signature(family.forward).parameters:
                 self._forward = functools.partial(
                     family.forward, ep_mesh=self.cfg.mesh)
-                self._forward_unrolled = functools.partial(
-                    family.forward_unrolled, ep_mesh=self.cfg.mesh)
         impl = self.cfg.attn_impl
+        if impl not in ATTN_IMPLS:
+            raise ValueError(
+                f"unknown attn_impl {impl!r}: one of "
+                + ", ".join(repr(v) for v in ATTN_IMPLS))
         # "auto" may settle on the XLA path where the kernels cannot run;
         # an attn_impl asked for by name is honoured or is an error
         auto = impl == "auto"
@@ -369,7 +368,7 @@ class JaxEngine(ScheduledEngineBase):
                 logger.info("custom forward_fn without attn_impl support: "
                             "using the XLA scan path")
                 impl = "scan"
-        if impl in ("pallas", "pallas_unrolled"):
+        if impl == "pallas":
             from dynamo_tpu.ops.pallas.decode import supports
             if not supports(model_cfg.head_dim, self.cfg.page_size):
                 why = ("the Pallas kernels need head_dim%128==0 and "
@@ -381,20 +380,23 @@ class JaxEngine(ScheduledEngineBase):
                 logger.info("%s; using the XLA path", why)
                 impl = "scan"
         if (forward_fn is None and self.cfg.mesh is not None
-                and (impl == "pallas_unrolled"
-                     or (impl == "pallas" and model_cfg.kv_lora_rank))):
+                and impl == "pallas" and model_cfg.kv_lora_rank):
             # GSPMD cannot partition a Mosaic call ("Mosaic kernels cannot
             # be automatically partitioned"): on a mesh the GQA stacked
-            # kernels run per shard (_per_shard below); the per-layer and
-            # the MLA kernels have no such wrapper
-            why = ("the per-layer and the MLA Pallas kernels are not "
-                   "wrapped in shard_map, so they cannot run on a mesh")
+            # kernels run per shard (_per_shard below); the MLA kernels
+            # have no such wrapper
+            why = ("the MLA Pallas kernels are not wrapped in shard_map, "
+                   "so they cannot run on a mesh")
             if not auto:
                 raise ValueError(
                     f"attn_impl={impl!r} was asked for, but {why}")
             logger.info("%s; using the XLA path", why)
             impl = "scan"
         self.attn_impl = impl
+        # the attention op of each step form — S == 1, a padded prefill
+        # chunk batch, a token-packed (ragged) step — or None: the
+        # family's own XLA attention
+        self._attn_decode = self._attn_prefill = self._attn_packed = None
         if impl == "pallas":
             from dynamo_tpu.ops.pallas.decode import (
                 paged_decode_attention_stacked)
@@ -402,22 +404,14 @@ class JaxEngine(ScheduledEngineBase):
                 paged_prefill_attention_stacked)
             from dynamo_tpu.ops.pallas.ragged import (
                 ragged_mixed_attention_packed)
-            # the attention op of each step shape: S == 1, a padded
-            # prefill chunk batch, a token-packed (ragged) step
             self._attn_decode = self._per_shard(
                 paged_decode_attention_stacked, forward_fn)
             self._attn_prefill = self._per_shard(
                 paged_prefill_attention_stacked, forward_fn)
             self._attn_packed = self._per_shard(
                 ragged_mixed_attention_packed, forward_fn)
-        if impl in ("scan", "pallas"):
-            self.pages = llama.make_pages(model_cfg, self.cfg.num_pages,
-                                          self.cfg.page_size)
-        elif impl in ("unrolled", "pallas_unrolled"):
-            self.pages = llama.make_pages_list(model_cfg, self.cfg.num_pages,
-                                               self.cfg.page_size)
-        else:
-            raise ValueError(f"unknown attn_impl {impl!r}")
+        self.pages = llama.make_pages(model_cfg, self.cfg.num_pages,
+                                      self.cfg.page_size)
         if self.cfg.shard_params_fn is not None:
             self.params = self.cfg.shard_params_fn(self.params)
         if self.cfg.shard_pages_fn is not None:
@@ -820,6 +814,20 @@ class JaxEngine(ScheduledEngineBase):
                 c(total_lens, row), c(new_lens, row), c(temperature, row),
                 c(top_k, row), c(top_p, row))
 
+    def _run_forward(self, attn, params, tokens, positions, pages,
+                     page_table, total_lens, new_lens, **kw):
+        """The family forward with the step form's attention op ``attn``
+        (``_attn_decode`` / ``_attn_prefill`` / ``_attn_packed``), or with
+        none where the XLA path serves: a custom ``forward_fn``
+        (``pipeline_forward``) may implement the base signature only.
+        Returns (logits, pages, aux); MoE families return the aux dict
+        (dispatch drop counts), dense ones the plain pair."""
+        if attn is not None:
+            kw["attn_impl"] = attn
+        out = self._forward(params, self.model_cfg, tokens, positions,
+                            pages, page_table, total_lens, new_lens, **kw)
+        return out[0], out[1], (out[2] if len(out) > 2 else {})
+
     def _step_impl(self, params, pages, tokens, positions, page_table,
                    total_lens, new_lens, rng, step, temperature, top_k,
                    top_p, pen=None):
@@ -827,32 +835,11 @@ class JaxEngine(ScheduledEngineBase):
          top_k, top_p) = self._shard_batch(
             tokens, positions, page_table, total_lens, new_lens, temperature,
             top_k, top_p)
-        if self.attn_impl in ("scan", "pallas"):
-            if self.attn_impl == "pallas":
-                attn = (self._attn_decode if tokens.shape[1] == 1
-                        else self._attn_prefill)
-                out = self._forward(
-                    params, self.model_cfg, tokens, positions, pages,
-                    page_table, total_lens, new_lens, attn_impl=attn)
-            else:
-                # no attn_impl kwarg: custom forward_fns (pipeline_forward)
-                # only implement the base signature
-                out = self._forward(params, self.model_cfg, tokens,
-                                    positions, pages, page_table,
-                                    total_lens, new_lens)
-        else:
-            attn = None
-            if (self.attn_impl == "pallas_unrolled"
-                    and tokens.shape[1] == 1):
-                from dynamo_tpu.ops.pallas import paged_decode_attention
-                attn = paged_decode_attention
-            out = self._forward_unrolled(
-                params, self.model_cfg, tokens, positions, pages,
-                page_table, total_lens, new_lens, attn_impl=attn)
-        # MoE families return a third aux dict (dispatch drop counts);
-        # dense families return the plain (logits, pages) pair
-        logits, pages = out[0], out[1]
-        aux = out[2] if len(out) > 2 else {}
+        attn = (self._attn_decode if tokens.shape[1] == 1
+                else self._attn_prefill)
+        logits, pages, aux = self._run_forward(
+            attn, params, tokens, positions, pages, page_table, total_lens,
+            new_lens)
         pages, packed = self._sample_tail(logits, pages, rng, step,
                                           temperature, top_k, top_p, pen,
                                           total_lens)
@@ -865,7 +852,7 @@ class JaxEngine(ScheduledEngineBase):
         declare the packed form (MLA: its kernels are row-padded), a mesh
         with ``dp > 1`` (``_shard_batch`` splits rows, a packed axis has
         none), speculation (the verify window is ``[B, K+1]``), and the
-        XLA ``scan``/``unrolled`` paths (the CPU's). Read off what the
+        XLA ``scan`` path (the CPU's). Read off what the
         engine is — no flag, no model name."""
         if forward_fn is not None:
             return "forward"
@@ -892,11 +879,9 @@ class JaxEngine(ScheduledEngineBase):
         attention kernel and the last-token select take the rows as
         descriptors. Sampling sees the same ``[R]`` rows in the same order
         as the padded step."""
-        out = self._forward(
-            params, self.model_cfg, tokens, positions, pages, page_table,
-            total_lens, new_lens, attn_impl=self._attn_packed, packed=True)
-        logits, pages = out[0], out[1]
-        aux = out[2] if len(out) > 2 else {}
+        logits, pages, aux = self._run_forward(
+            self._attn_packed, params, tokens, positions, pages, page_table,
+            total_lens, new_lens, packed=True)
         pages, packed = self._sample_tail(logits, pages, rng, step,
                                           temperature, top_k, top_p, pen,
                                           total_lens)
@@ -914,26 +899,10 @@ class JaxEngine(ScheduledEngineBase):
                                top_k, top_p, pen)
 
     def _decode_forward(self, params, pages, tok, pos, table, total, new):
-        """One S==1 decode forward (the scan body of the fused block);
-        mirrors ``_step_impl``'s attn selection for tokens.shape[1] == 1.
+        """One S==1 decode forward (the scan body of the fused block).
         Returns (logits [B, V], pages, aux)."""
-        if self.attn_impl in ("scan", "pallas"):
-            if self.attn_impl == "pallas":
-                out = self._forward(params, self.model_cfg, tok, pos, pages,
-                                    table, total, new,
-                                    attn_impl=self._attn_decode)
-            else:
-                out = self._forward(params, self.model_cfg, tok, pos, pages,
-                                    table, total, new)
-        else:
-            attn = None
-            if self.attn_impl == "pallas_unrolled":
-                from dynamo_tpu.ops.pallas import paged_decode_attention
-                attn = paged_decode_attention
-            out = self._forward_unrolled(params, self.model_cfg, tok, pos,
-                                         pages, table, total, new,
-                                         attn_impl=attn)
-        return out[0], out[1], (out[2] if len(out) > 2 else {})
+        return self._run_forward(self._attn_decode, params, tok, pos, pages,
+                                 table, total, new)
 
     def _multistep_impl(self, params, pages, tok, pos, table, total, alive,
                         budget, min_gate, rng, step0, temperature, top_k,
@@ -1115,12 +1084,9 @@ class JaxEngine(ScheduledEngineBase):
             kw = {}
             if self.cfg.mesh is not None:
                 from jax.sharding import NamedSharding, PartitionSpec
-                ref = (self.pages[0] if isinstance(self.pages, list)
-                       else self.pages)
-                if isinstance(ref.sharding, NamedSharding):
+                if isinstance(self.pages.sharding, NamedSharding):
                     rep = NamedSharding(self.cfg.mesh, PartitionSpec())
-                    pages_sh = jax.tree_util.tree_map(
-                        lambda x: x.sharding, self.pages)
+                    pages_sh = self.pages.sharding
                     carry_sh = {k: rep for k in ("tok", "pos", "total",
                                                  "alive", "budget",
                                                  "min_gate", "pids",
@@ -1161,22 +1127,9 @@ class JaxEngine(ScheduledEngineBase):
          top_k, top_p) = self._shard_batch(
             tokens, positions, page_table, total_lens, new_lens, temperature,
             top_k, top_p)
-        attn = self._attn_prefill if self.attn_impl == "pallas" else None
-        if self.attn_impl in ("scan", "pallas"):
-            out = self._forward(
-                params, self.model_cfg, tokens, positions, pages,
-                page_table, total_lens, new_lens,
-                **({"attn_impl": attn} if attn is not None else {}),
-                logits_window=tokens.shape[1])
-        else:
-            # unrolled paths: S > 1, so no decode kernel — XLA attention
-            out = self._forward_unrolled(
-                params, self.model_cfg, tokens, positions, pages,
-                page_table, total_lens, new_lens,
-                logits_window=tokens.shape[1])
-        # MoE families return a third aux dict (dispatch drop counts)
-        logits, pages = out[0], out[1]
-        aux = out[2] if len(out) > 2 else {}
+        logits, pages, aux = self._run_forward(
+            self._attn_prefill, params, tokens, positions, pages,
+            page_table, total_lens, new_lens, logits_window=tokens.shape[1])
         if gmask is not None:
             # mask ONCE here so the packed top alternatives below see the
             # same constrained distribution the verifier samples from —
@@ -2320,15 +2273,9 @@ class JaxEngine(ScheduledEngineBase):
         if self.cfg.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
             rep = NamedSharding(self.cfg.mesh, PartitionSpec())
-        if isinstance(self.pages, list):
-            gather = lambda pages, ids: jnp.stack([p[ids] for p in pages])  # noqa: E731
-            scatter = lambda pages, ids, vals: [  # noqa: E731
-                p.at[ids].set(vals[l].astype(p.dtype))
-                for l, p in enumerate(pages)]
-        else:
-            gather = lambda pages, ids: pages[:, ids]  # noqa: E731
-            scatter = lambda pages, ids, vals: pages.at[:, ids].set(  # noqa: E731
-                vals.astype(pages.dtype))
+        gather = lambda pages, ids: pages[:, ids]  # noqa: E731
+        scatter = lambda pages, ids, vals: pages.at[:, ids].set(  # noqa: E731
+            vals.astype(pages.dtype))
         self._jit_gather_pages = jax.jit(
             gather, out_shardings=rep) if rep is not None else jax.jit(gather)
         # sharded gather: the transport array KEEPS the cache's placement
@@ -2594,11 +2541,6 @@ class JaxEngine(ScheduledEngineBase):
         top_n = max(1, min(self.cfg.num_top_logprobs or 1,
                            cfg.vocab_size))
 
-        # same chunked-prefill kernel the serving prefill and spec-verify
-        # steps run (S > 1)
-        attn_kw = ({"attn_impl": self._attn_prefill}
-                   if self.attn_impl == "pallas" else {})
-
         def body(pages, xs):
             tc, gc, ci = xs
             pos = (ci * chunk
@@ -2606,10 +2548,11 @@ class JaxEngine(ScheduledEngineBase):
             pos = jnp.tile(pos, (B, 1))
             total = jnp.full((B,), (ci + 1) * chunk, jnp.int32)
             new = jnp.full((B,), chunk, jnp.int32)
-            out = self._forward(params, cfg, tc, pos, pages, table,
-                                total, new, logits_window=chunk,
-                                **attn_kw)
-            logits, pages = out[0], out[1]          # [B, chunk, V]
+            # same chunked-prefill kernel the serving prefill and
+            # spec-verify steps run (S > 1)
+            logits, pages, _ = self._run_forward(
+                self._attn_prefill, params, tc, pos, pages, table, total,
+                new, logits_window=chunk)           # [B, chunk, V]
             lsm = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
             # gather INSIDE the scan: only [B, chunk(, top_n)] leaves each
             # step — the full [B, S, V] logits never materialize
@@ -2671,6 +2614,7 @@ class JaxEngine(ScheduledEngineBase):
         return cls(model_cfg, params, config)
 
 
-__all__ = ["JaxEngine", "JaxEngineConfig", "decode_multistep_default",
+__all__ = ["JaxEngine", "JaxEngineConfig", "ATTN_IMPLS",
+           "decode_multistep_default",
            "mixed_batch_default", "decode_progress_default",
            "DECODE_MULTISTEP", "MIXED_BATCH", "DECODE_PROGRESS_EVERY"]

@@ -550,11 +550,6 @@ def inject_frame(engine: JaxEngine, meta: Dict[str, Any]) -> int:
     return _inject_data(engine, metas, arr.copy())
 
 
-def _pages_ref(engine: JaxEngine):
-    return engine.pages[0] if isinstance(engine.pages, list) \
-        else engine.pages
-
-
 def _commit_staged(engine: JaxEngine, metas, data, inner) -> int:
     """One batched commit inside the exclusive window. The caller refills
     the staging buffer the moment this resolves, so wait for the scatter
@@ -567,7 +562,7 @@ def _commit_staged(engine: JaxEngine, metas, data, inner) -> int:
     n = inner(engine, metas, data)
     if (not isinstance(data, jax.Array)
             or jax.default_backend() == "cpu"):
-        jax.block_until_ready(_pages_ref(engine))
+        jax.block_until_ready(engine.pages)
     return n
 
 

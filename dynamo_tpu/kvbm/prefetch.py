@@ -95,11 +95,9 @@ def prefetch_depth_bytes() -> int:
 
 def _block_bytes(engine) -> int:
     """Bytes of one KV block in this engine's cache geometry."""
-    ref = engine.pages[0] if isinstance(engine.pages, list) else engine.pages
-    L = (len(engine.pages) if isinstance(engine.pages, list)
-         else engine.pages.shape[0])
-    shape = (L,) + tuple(ref.shape[-4:])  # [L, 2, Hkv, ps, Dh]
-    return int(np.prod(shape)) * np.dtype(ref.dtype).itemsize
+    pages = engine.pages                  # [L, N, 2, Hkv, ps, Dh]
+    shape = pages.shape[:1] + pages.shape[2:]
+    return int(np.prod(shape)) * np.dtype(pages.dtype).itemsize
 
 
 class PrefetchScheduler:

@@ -1,11 +1,13 @@
 """Model family implementations (pure-functional jax).
 
-Each family exposes ``init_params(cfg, rng)``, a scan ``forward`` and an
-unrolled ``forward_unrolled`` over the paged KV cache. ``get_family(cfg)``
-maps a config to its implementation: MoE configs (``num_experts > 0``,
-covering mixtral / qwen3_moe / deepseek-style routing) use
-``models.moe``; everything else in the Llama tree (llama 2/3, mistral,
-qwen2/qwen3) uses ``models.llama``.
+Each family exposes ``init_params(cfg, rng)``, ``make_pages`` (the stacked
+paged KV cache ``[L, N, 2, Hkv, page_size, Dh]``) and ONE ``forward``: a
+``lax.scan`` over the layers against that cache, whose attention op is an
+argument (``attn_impl``). ``get_family(cfg)`` maps a config to its
+implementation: MLA configs (``kv_lora_rank > 0``) use ``models.deepseek``,
+other MoE configs (``num_experts > 0``: mixtral / qwen3_moe routing)
+``models.moe``, gemma-2 ``models.gemma``; everything else in the Llama tree
+(llama 2/3, mistral, qwen2/qwen3) uses ``models.llama``.
 """
 
 from dynamo_tpu.models.config import ModelConfig

@@ -40,7 +40,7 @@ assembles the two layer stacks from safetensors.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,9 +51,8 @@ from dynamo_tpu.models.llama import (
     _logits,
     _rms_norm,
     make_pages,
-    make_pages_list,
 )
-from dynamo_tpu.ops.attention import NEG_INF, write_kv, write_kv_layer
+from dynamo_tpu.ops.attention import NEG_INF, write_kv
 
 Params = Dict[str, Any]
 
@@ -472,8 +471,7 @@ def _dense_mlp(lp: Dict[str, jnp.ndarray], x: jnp.ndarray) -> jnp.ndarray:
 # ----------------------------------------------------------------- forward
 
 def _attend(cfg: ModelConfig, lp, h, q_lat, q_pe, w_uv, positions,
-            total_lens, page_table, pages, lidx, *, layered: bool,
-            use_pallas: bool):
+            total_lens, page_table, pages, lidx, *, use_pallas: bool):
     """The attention stage of ``_layer_step`` (latent attention over the
     paged cache plus the out-projection residual), by the path the
     geometry picks. Returns the new ``h``."""
@@ -484,17 +482,13 @@ def _attend(cfg: ModelConfig, lp, h, q_lat, q_pe, w_uv, positions,
     ps = pages.shape[-2]
     if use_pallas and S == 1:
         from dynamo_tpu.ops.pallas.mla_decode import (
-            mla_paged_decode_layer, mla_paged_decode_stacked)
+            mla_paged_decode_stacked)
 
-        if layered:
-            lat = mla_paged_decode_layer(q_lat, q_pe, pages, page_table,
-                                         total_lens, _mla_scale(cfg))
-        else:
-            lat = mla_paged_decode_stacked(q_lat, q_pe, pages, lidx,
-                                           page_table, total_lens,
-                                           _mla_scale(cfg))
+        lat = mla_paged_decode_stacked(q_lat, q_pe, pages, lidx,
+                                       page_table, total_lens,
+                                       _mla_scale(cfg))
         h = _expand_and_project(cfg, lp, h, lat, w_uv)
-    elif use_pallas and not layered:
+    elif use_pallas:
         from dynamo_tpu.ops.pallas.mla_prefill import (
             mla_paged_prefill_stacked)
 
@@ -509,44 +503,36 @@ def _attend(cfg: ModelConfig, lp, h, q_lat, q_pe, w_uv, positions,
             tbl = jax.lax.dynamic_slice(
                 table, (0, c * PAGES_PER_CHUNK),
                 (table.shape[0], PAGES_PER_CHUNK))
-            g = pages[tbl] if layered else pages[lidx, tbl]
-            return _gather_ctx(cfg, g)
+            return _gather_ctx(cfg, pages[lidx, tbl])
 
         h = _mla_attend_blockwise(cfg, lp, h, q_lat, q_pe, w_uv,
                                   gather_chunk, P, ps, positions,
                                   total_lens)
     else:
-        gathered = (pages[page_table] if layered
-                    else pages[lidx, page_table])
-        ckv_ctx, kpe_ctx = _gather_ctx(cfg, gathered)
+        ckv_ctx, kpe_ctx = _gather_ctx(cfg, pages[lidx, page_table])
         h = _mla_attend(cfg, lp, h, q_lat, q_pe, w_uv, ckv_ctx, kpe_ctx,
                         positions, total_lens)
     return h
 
 
 def _layer_step(cfg: ModelConfig, lp, h, positions, total_lens, new_lens,
-                page_table, pages, lidx, *, moe: bool, layered: bool,
+                page_table, pages, lidx, *, moe: bool,
                 use_pallas: bool = False, ep_mesh=None):
-    """One decoder layer against the paged latent cache. ``layered`` means
-    ``pages`` is the per-layer buffer (unrolled path) instead of the
-    stacked cache. ``use_pallas`` routes S==1 through the MLA Pallas
-    decode kernel (``ops/pallas/mla_decode.py``) when the geometry
-    supports it. Returns ``(h, pages, dropped_assignments)``."""
+    """One decoder layer against the stacked paged latent cache.
+    ``use_pallas`` routes S==1 through the MLA Pallas decode kernel
+    (``ops/pallas/mla_decode.py``) and S>1 through the prefill kernel
+    when the geometry supports them. Returns ``(h, pages,
+    dropped_assignments)``."""
     # stage names for the device trace, as in models/llama.py
     with jax.named_scope("layer.attn_in"):
         q_lat, q_pe, c_kv, k_pe, w_uv = _mla_qkv(cfg, lp, h, positions)
         k_new, v_new = _cache_rows(cfg, c_kv, k_pe)
     with jax.named_scope("layer.kv_write"):
-        if layered:
-            pages = write_kv_layer(pages, k_new, v_new, page_table,
-                                   positions, new_lens)
-        else:
-            pages = write_kv(pages, lidx, k_new, v_new, page_table,
-                             positions, new_lens)
+        pages = write_kv(pages, lidx, k_new, v_new, page_table,
+                         positions, new_lens)
     with jax.named_scope("layer.attn"):
         h = _attend(cfg, lp, h, q_lat, q_pe, w_uv, positions, total_lens,
-                    page_table, pages, lidx, layered=layered,
-                    use_pallas=use_pallas)
+                    page_table, pages, lidx, use_pallas=use_pallas)
     with jax.named_scope("layer.moe" if moe else "layer.ffn"):
         x = _rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
         if moe:
@@ -589,8 +575,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             lp, lidx = xs
             h, pages, dropped = _layer_step(
                 cfg, lp, h, positions, total_lens, new_lens, page_table,
-                pages, lidx, moe=moe, layered=False, use_pallas=use_pallas,
-                ep_mesh=ep_mesh)
+                pages, lidx, moe=moe, use_pallas=use_pallas, ep_mesh=ep_mesh)
             return (h, pages), dropped
         return step
 
@@ -607,43 +592,6 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     with jax.named_scope("logits"):
         logits = _logits(cfg, params, h, new_lens, window=logits_window)
     return logits, pages, aux
-
-
-def forward_unrolled(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
-                     positions: jnp.ndarray, pages_list: List[jnp.ndarray],
-                     page_table: jnp.ndarray, total_lens: jnp.ndarray,
-                     new_lens: jnp.ndarray,
-                     attn_impl: Optional[Callable] = None, ep_mesh=None,
-                     logits_window: int = 1
-                     ) -> Tuple[jnp.ndarray, List[jnp.ndarray], dict]:
-    """Python-unrolled forward over per-layer latent buffers. An
-    ``attn_impl`` carrying the ``pallas_paged_kernel`` marker opts S==1
-    steps into the per-layer MLA Pallas kernel (see ``forward``)."""
-    from dynamo_tpu.ops.pallas.mla_decode import supports as mla_supports
-
-    use_pallas = (getattr(attn_impl, "pallas_paged_kernel", False)
-                  and mla_supports(cfg.kv_lora_rank,
-                                   pages_list[0].shape[-2]))
-    K = cfg.first_k_dense_replace
-    with jax.named_scope("embed"):
-        h = params["embed"][tokens]
-    out_pages: List[jnp.ndarray] = []
-    total_dropped = jnp.zeros((), jnp.int32)
-    for l in range(cfg.num_layers):
-        moe = l >= K
-        stack = params["moe_layers"] if moe else params["dense_layers"]
-        li = l - K if moe else l
-        lp = {k: v[li] for k, v in stack.items()}
-        h, kv, dropped = _layer_step(
-            cfg, lp, h, positions, total_lens, new_lens, page_table,
-            pages_list[l], 0, moe=moe, layered=True,
-            use_pallas=use_pallas, ep_mesh=ep_mesh)
-        total_dropped = total_dropped + dropped
-        out_pages.append(kv)
-    aux = {"moe_dropped_assignments": total_dropped}
-    with jax.named_scope("logits"):
-        logits = _logits(cfg, params, h, new_lens, window=logits_window)
-    return logits, out_pages, aux
 
 
 # ------------------------------------------------------------------ loader
@@ -779,5 +727,5 @@ def load_params(cfg: ModelConfig, path: str,
     return params
 
 
-__all__ = ["init_params", "forward", "forward_unrolled", "load_params",
-           "rope_interleaved", "make_pages", "make_pages_list"]
+__all__ = ["init_params", "forward", "load_params", "rope_interleaved",
+           "make_pages"]

@@ -1,9 +1,8 @@
 """Gemma-2 family decoder — pure-functional jax over the paged KV cache.
 
-Same serving contract as ``models/llama.py`` (``init_params`` /
-``forward`` scan / ``forward_unrolled``), covering the gemma-2
-architecture differences (verified against transformers'
-``Gemma2ForCausalLM`` in tests):
+Same serving contract as ``models/llama.py`` (``init_params`` / the
+``forward`` scan), covering the gemma-2 architecture differences
+(verified against transformers' ``Gemma2ForCausalLM`` in tests):
 
 - GeGLU MLP: ``gelu_tanh(x@gate) * (x@up) @ down``;
 - sandwich norms: pre+post norms around BOTH attention and the MLP
@@ -17,15 +16,13 @@ architecture differences (verified against transformers'
 
 Both stacked Pallas kernels (decode AND prefill, ``ops/pallas/``) carry
 the per-layer window + softcap operands, so the scan forward serves this
-family fully on kernels under ``attn_impl="pallas"``; ``forward_unrolled``
-still ignores the override (the per-layer decode kernel variant has no
-window/softcap) and runs the XLA paths.
+family fully on kernels under ``attn_impl="pallas"``.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,11 +32,9 @@ from dynamo_tpu.models.llama import (
     _select_last,
     attend_rows,
     make_pages,
-    make_pages_list,
     packed_rows,
     write_rows,
 )
-from dynamo_tpu.ops.attention import paged_attention_layer, write_kv_layer
 from dynamo_tpu.ops.rope import apply_rope
 from dynamo_tpu.ops import quant
 
@@ -209,35 +204,4 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 forward.supports_packed = True
 
 
-def forward_unrolled(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
-                     positions: jnp.ndarray, pages_list: List[jnp.ndarray],
-                     page_table: jnp.ndarray, total_lens: jnp.ndarray,
-                     new_lens: jnp.ndarray,
-                     attn_impl: Optional[Callable] = None,
-                     logits_window: int = 1
-                     ) -> Tuple[jnp.ndarray, List[jnp.ndarray]]:
-    """Unrolled forward. ``attn_impl`` is IGNORED: the Pallas decode kernel
-    implements neither soft-capping nor sliding windows, so gemma always
-    takes the XLA attention paths."""
-    del attn_impl
-    sm_scale = _sm_scale(cfg)
-    softcap = (jnp.asarray(cfg.attn_logit_softcap, jnp.float32)
-               if cfg.attn_logit_softcap else None)
-    windows = layer_windows(cfg)
-    h = _embed(cfg, params, tokens)
-    out_pages: List[jnp.ndarray] = []
-    for l in range(cfg.num_layers):
-        lp = {k: v[l] for k, v in params["layers"].items()}
-        q, k, v = _project_qkv(cfg, lp, h, positions)
-        kv = write_kv_layer(pages_list[l], k, v, page_table, positions,
-                            new_lens)
-        attn = paged_attention_layer(q, kv, page_table, positions,
-                                     total_lens, sm_scale,
-                                     window=windows[l], softcap=softcap)
-        h = _finish_layer(cfg, lp, h, attn)
-        out_pages.append(kv)
-    return _logits(cfg, params, h, new_lens, window=logits_window), out_pages
-
-
-__all__ = ["init_params", "forward", "forward_unrolled", "make_pages",
-           "make_pages_list", "layer_windows"]
+__all__ = ["init_params", "forward", "make_pages", "layer_windows"]

@@ -1,21 +1,15 @@
 """Llama-family decoder (llama 2/3, mistral, qwen2/qwen3) — pure-functional jax.
 
 The reference framework never implements a model; it shells out to vLLM/SGLang
-on CUDA (SURVEY §2.5). Here the model loop is native and TPU-first, with two
-interchangeable forwards over the same weights:
+on CUDA (SURVEY §2.5). Here the model loop is native and TPU-first, with
+one forward over the weights:
 
 - ``forward`` — ONE ``lax.scan`` over stacked per-layer params: a single
   compiled layer body, fast compiles, XLA while-loop buffer aliasing keeps the
-  stacked paged KV cache (scan carry) updated in place. This is the portable
-  path (CPU tests, prefill-heavy work).
-- ``forward_unrolled`` — python loop over layers with a *list* of per-layer
-  KV buffers. Exists for the Pallas decode kernel, which wants a concrete
-  per-layer HBM ref (a traced layer-slice of a stacked cache forces XLA to
-  defensively copy the whole cache around the opaque custom call —
-  measured 10x worse than the list, aliasing declarations included).
-  Longer compile, fastest decode; the serving engine uses it on TPU.
-
-Both share the exact same math (``_layer_step``); equivalence is tested.
+  stacked paged KV cache (scan carry) updated in place. The attention op of a
+  step is an argument (``attn_impl``): the XLA gather path by default (the
+  CPU's, and the reference of the kernel tests), a Pallas kernel that takes
+  the stacked cache and the traced layer index on the chip.
 
 Only the last real token's logits are computed ([B, V]); full [B, S, V]
 logit materialization would waste HBM on long prefill chunks.
@@ -26,7 +20,7 @@ Weight layout matches HF checkpoints after transpose (torch Linear stores
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,10 +28,8 @@ import jax.numpy as jnp
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.ops.attention import (
     paged_attention,
-    paged_attention_layer,
     ragged_paged_attention,
     write_kv,
-    write_kv_layer,
     write_kv_packed,
 )
 from dynamo_tpu.ops.rope import apply_rope
@@ -61,7 +53,7 @@ def _head_rms_norm(x: jnp.ndarray, w: jnp.ndarray, eps: float) -> jnp.ndarray:
 
 def make_pages(cfg: ModelConfig, num_pages: int, page_size: int,
                dtype=None) -> jnp.ndarray:
-    """Stacked paged KV cache: [L, N, 2, Hkv, page_size, Dh] (scan path).
+    """Stacked paged KV cache: [L, N, 2, Hkv, page_size, Dh].
 
     Page-major: one page is a contiguous slab carrying K AND V for all kv
     heads, so page-granular DMAs (Pallas decode kernel, disagg block
@@ -73,15 +65,6 @@ def make_pages(cfg: ModelConfig, num_pages: int, page_size: int,
     dtype = dtype or jnp.dtype(cfg.dtype)
     return jnp.zeros((cfg.num_layers, num_pages, 2, cfg.num_kv_heads,
                       page_size, cfg.head_dim), dtype=dtype)
-
-
-def make_pages_list(cfg: ModelConfig, num_pages: int, page_size: int,
-                    dtype=None) -> List[jnp.ndarray]:
-    """Per-layer KV buffers [N, 2, Hkv, page_size, Dh] (unrolled path)."""
-    dtype = dtype or jnp.dtype(cfg.dtype)
-    return [jnp.zeros((num_pages, 2, cfg.num_kv_heads, page_size,
-                       cfg.head_dim), dtype=dtype)
-            for _ in range(cfg.num_layers)]
 
 
 def init_params(cfg: ModelConfig, rng: jax.Array, scale: float = 0.02) -> Params:
@@ -417,41 +400,4 @@ def score(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     return target_lps, top_ids, top_lps
 
 
-def forward_unrolled(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
-                     positions: jnp.ndarray, pages_list: List[jnp.ndarray],
-                     page_table: jnp.ndarray, total_lens: jnp.ndarray,
-                     new_lens: jnp.ndarray,
-                     attn_impl: Optional[Callable] = None,
-                     logits_window: int = 1
-                     ) -> Tuple[jnp.ndarray, List[jnp.ndarray]]:
-    """Unrolled forward over per-layer KV buffers (Pallas-kernel path).
-
-    ``attn_impl(q, kv_layer, page_table, positions, total_lens, sm_scale)``
-    defaults to the XLA gather path; the engine passes the Pallas decode
-    kernel for S == 1 steps on TPU.
-    """
-    sm_scale = cfg.head_dim ** -0.5
-    attn_impl = attn_impl or paged_attention_layer
-    with jax.named_scope("embed"):
-        h = params["embed"][tokens]
-    out_pages: List[jnp.ndarray] = []
-    for l in range(cfg.num_layers):
-        lp = {k: v[l] for k, v in params["layers"].items()}
-        with jax.named_scope("layer.attn_in"):
-            q, k, v = _project_qkv(cfg, lp, h, positions)
-        with jax.named_scope("layer.kv_write"):
-            kv = write_kv_layer(pages_list[l], k, v, page_table, positions,
-                                new_lens)
-        with jax.named_scope("layer.attn"):
-            attn = attn_impl(q, kv, page_table, positions, total_lens,
-                             sm_scale)
-        with jax.named_scope("layer.ffn"):
-            h = _finish_layer(cfg, lp, h, attn)
-        out_pages.append(kv)
-    with jax.named_scope("logits"):
-        logits = _logits(cfg, params, h, new_lens, window=logits_window)
-    return logits, out_pages
-
-
-__all__ = ["init_params", "forward", "forward_unrolled", "encode", "score",
-           "make_pages", "make_pages_list"]
+__all__ = ["init_params", "forward", "encode", "score", "make_pages"]

@@ -22,7 +22,7 @@ Weight layout (stacked for scan): ``w_router [L, H, E]``,
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,14 +35,11 @@ from dynamo_tpu.models.llama import (
     _project_qkv,
     _rms_norm,
     attend_rows,
+    make_pages,
     packed_rows,
     write_rows,
 )
 from dynamo_tpu.models import llama
-from dynamo_tpu.ops.attention import (
-    paged_attention_layer,
-    write_kv_layer,
-)
 
 
 def moe_mlp(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
@@ -248,33 +245,5 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 forward.supports_packed = True
 
 
-def forward_unrolled(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
-                     positions: jnp.ndarray, pages_list: List[jnp.ndarray],
-                     page_table: jnp.ndarray, total_lens: jnp.ndarray,
-                     new_lens: jnp.ndarray,
-                     attn_impl: Optional[Callable] = None, ep_mesh=None,
-                     logits_window: int = 1
-                     ) -> Tuple[jnp.ndarray, List[jnp.ndarray], dict]:
-    """Unrolled MoE forward (llama.forward_unrolled contract plus the
-    ``aux`` drop-count return, see ``forward``)."""
-    sm_scale = cfg.head_dim ** -0.5
-    attn_impl = attn_impl or paged_attention_layer
-    h = params["embed"][tokens]
-    out_pages: List[jnp.ndarray] = []
-    total_dropped = jnp.zeros((), jnp.int32)
-    for l in range(cfg.num_layers):
-        lp = {k: v[l] for k, v in params["layers"].items()}
-        q, k, v = _project_qkv(cfg, lp, h, positions)
-        kv = write_kv_layer(pages_list[l], k, v, page_table, positions,
-                            new_lens)
-        attn = attn_impl(q, kv, page_table, positions, total_lens, sm_scale)
-        h, dropped = _moe_layer_tail(cfg, lp, h, attn, ep_mesh=ep_mesh)
-        total_dropped = total_dropped + dropped
-        out_pages.append(kv)
-    aux = {"moe_dropped_assignments": total_dropped}
-    return (_logits(cfg, params, h, new_lens, window=logits_window),
-            out_pages, aux)
-
-
-__all__ = ["forward", "forward_unrolled", "init_params", "moe_mlp",
+__all__ = ["forward", "init_params", "make_pages", "moe_mlp",
            "moe_mlp_dispatch", "expert_dispatch"]

@@ -50,10 +50,9 @@ def _write_pages(pool: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
                  v_new: jnp.ndarray, page_table: jnp.ndarray,
                  positions: jnp.ndarray, new_lens: jnp.ndarray) -> jnp.ndarray:
     """The cache write behind ``write_kv`` (``layer_idx`` a scalar, pool
-    ``[L, N, 2, Hkv, ps, Dh]``) and ``write_kv_layer`` (``layer_idx``
-    None, pool ``[N, 2, Hkv, ps, Dh]``), a page at a time: gather the pages
-    each row's new tokens fall in, lay the tokens over them, scatter the
-    pages back.
+    ``[L, N, 2, Hkv, ps, Dh]``), a page at a time: gather the pages each
+    row's new tokens fall in, lay the tokens over them, scatter the pages
+    back.
 
     A row's real tokens are CONSECUTIVE positions from ``positions[b, 0]``
     (what a prefill chunk, a decode step and a verify window are; the ring
@@ -74,7 +73,7 @@ def _write_pages(pool: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
     logical = jnp.minimum(first[:, None] + j, page_table.shape[1] - 1)
     phys = jnp.where(j < n_live[:, None],
                      jnp.take_along_axis(page_table, logical, axis=1), 0)
-    at = pool.at[phys] if layer_idx is None else pool.at[layer_idx, phys]
+    at = pool.at[layer_idx, phys]
     # the new tokens shifted to their slots, token-major, then page-major
     new = jnp.stack([k_new, v_new], axis=2).astype(pool.dtype)
     if S == 1:
@@ -146,25 +145,17 @@ def write_kv_packed(pool: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
     return _commit_pages(at, laid, real)
 
 
-def write_kv_layer(kv_layer: jnp.ndarray, k_new: jnp.ndarray,
-                   v_new: jnp.ndarray, page_table: jnp.ndarray,
-                   positions: jnp.ndarray, new_lens: jnp.ndarray) -> jnp.ndarray:
-    """Scatter new K/V into one layer's paged cache.
+def write_kv(pages: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
+             v_new: jnp.ndarray, page_table: jnp.ndarray,
+             positions: jnp.ndarray, new_lens: jnp.ndarray) -> jnp.ndarray:
+    """Scatter new K/V into layer ``layer_idx`` of the stacked cache.
 
-    kv_layer:   [N, 2, Hkv, page_size, Dh]
+    pages:      [L, N, 2, Hkv, page_size, Dh]
     k_new/v_new:[B, S, Hkv, Dh]
     page_table: [B, P] logical-page -> physical-page map (int32)
     positions:  [B, S] absolute token positions of the new tokens
     new_lens:   [B] number of real (non-pad) new tokens per sequence
     """
-    return _write_pages(kv_layer, None, k_new, v_new, page_table, positions,
-                       new_lens)
-
-
-def write_kv(pages: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
-             v_new: jnp.ndarray, page_table: jnp.ndarray,
-             positions: jnp.ndarray, new_lens: jnp.ndarray) -> jnp.ndarray:
-    """Scatter new K/V into the stacked cache ``[L, N, 2, Hkv, ps, Dh]``."""
     return _write_pages(pages, layer_idx, k_new, v_new, page_table, positions,
                        new_lens)
 
@@ -383,42 +374,6 @@ def ragged_paged_attention(q: jnp.ndarray, pages: jnp.ndarray, layer_idx,
     return jnp.where(valid[:, None, None], out, 0.0).astype(q.dtype)
 
 
-def paged_attention_layer(q: jnp.ndarray, kv_layer: jnp.ndarray,
-                          page_table: jnp.ndarray, positions: jnp.ndarray,
-                          total_lens: jnp.ndarray, sm_scale: float,
-                          window=None, softcap=None) -> jnp.ndarray:
-    """XLA-path attention against one layer's cache.
-
-    q: [B, S, Hq, Dh]; kv_layer: [N, 2, Hkv, ps, Dh] -> [B, S, Hq, Dh]
-
-    Prefill steps (S > 1) with a context wider than one chunk take the
-    blockwise online-softmax path; small shapes keep the direct gather.
-    """
-    B, S, Hq, Dh = q.shape
-    Hkv = kv_layer.shape[2]
-    ps = kv_layer.shape[3]
-    P = page_table.shape[1]
-    qg = q.reshape(B, S, Hkv, Hq // Hkv, Dh)
-    if S > 1 and P > PAGES_PER_CHUNK:
-        table = _pad_table(page_table, PAGES_PER_CHUNK)
-
-        def gather_chunk(c):
-            tbl = jax.lax.dynamic_slice(
-                table, (0, c * PAGES_PER_CHUNK), (B, PAGES_PER_CHUNK))
-            g = kv_layer[tbl]              # [B, C, 2, Hkv, ps, Dh]
-            return _gathered_to_bhtd(g[:, :, 0]), _gathered_to_bhtd(g[:, :, 1])
-
-        return _attend_blockwise(qg, gather_chunk, P, ps, PAGES_PER_CHUNK,
-                                 positions, total_lens, sm_scale,
-                                 window=window,
-                                 softcap=softcap).astype(q.dtype)
-    gathered = kv_layer[page_table]        # [B, P, 2, Hkv, ps, Dh]
-    k = _gathered_to_bhtd(gathered[:, :, 0])
-    v = _gathered_to_bhtd(gathered[:, :, 1])
-    return _attend(qg, k, v, positions, total_lens, sm_scale,
-                   window=window, softcap=softcap).astype(q.dtype)
-
-
 def paged_attention(q: jnp.ndarray, pages: jnp.ndarray, layer_idx,
                     page_table: jnp.ndarray, positions: jnp.ndarray,
                     total_lens: jnp.ndarray, sm_scale: float,
@@ -462,8 +417,7 @@ def paged_attention(q: jnp.ndarray, pages: jnp.ndarray, layer_idx,
                    window=window, softcap=softcap).astype(q.dtype)
 
 
-__all__ = ["write_kv", "write_kv_layer", "write_kv_packed",
-           "paged_attention",
-           "paged_attention_layer", "ragged_paged_attention",
+__all__ = ["write_kv", "write_kv_packed", "paged_attention",
+           "ragged_paged_attention",
            "merge_softmax_partials", "normalize_softmax_partials",
            "NEG_INF"]

@@ -13,12 +13,12 @@ Design (one grid program per sequence, chunked page streaming):
   grid machinery costs ~1.7 ms per invocation (measured 80x slowdown on an
   otherwise identical kernel); plain SMEM inputs issue dynamic-index DMAs
   at sub-microsecond cost.
-- K/V pages stay in HBM (``memory_space=ANY``) in the page-major per-layer
-  layout ``[N, 2, Hkv, ps, Dh]`` — one page is one contiguous slab with K
-  and V for all heads, so each page is fetched by ONE DMA descriptor.
-  (Per-layer buffers, not a layer-slice of a stacked cache: XLA
-  defensively copies a stacked cache around the opaque custom call, ~10x.)
-  Pages are
+- K/V pages stay in HBM (``memory_space=ANY``) in the page-major stacked
+  layout ``[L, N, 2, Hkv, ps, Dh]`` — one page is one contiguous slab with
+  K and V for all heads, so each page is fetched by ONE DMA descriptor.
+  The WHOLE pool enters the kernel and the layer index rides in SMEM (a
+  layer-slice taken outside would make XLA copy the layer around the
+  opaque custom call). Pages are
   streamed in chunks of ``PAGES_PER_CHUNK`` into a double-buffered VMEM
   slab, the next chunk's burst issued while the current chunk computes.
 - Flash-style online softmax in f32 over a ``lax.fori_loop`` whose trip
@@ -218,29 +218,6 @@ def _paged_decode(q, kv_pages, layer_idx, window, page_table, total_lens,
       page_table, total_lens)
 
 
-def paged_decode_attention(q: jnp.ndarray, kv_layer: jnp.ndarray,
-                           page_table: jnp.ndarray, positions: jnp.ndarray,
-                           total_lens: jnp.ndarray, sm_scale: float,
-                           interpret: bool | None = None) -> jnp.ndarray:
-    """Drop-in for ``ops.attention.paged_attention_layer`` when S == 1.
-
-    q:          [B, 1, Hq, Dh]
-    kv_layer:   [N, 2, Hkv, page_size, Dh] (page-major slabs)
-    page_table: [B, P]
-    total_lens: [B] context length including the query token
-    """
-    B, S, Hq, Dh = q.shape
-    if S != 1:
-        raise ValueError(f"decode kernel requires S=1, got S={S}")
-    out = _paged_decode(q[:, 0], kv_layer[None],
-                        jnp.zeros((1,), jnp.int32),
-                        jnp.zeros((1,), jnp.int32),
-                        page_table.astype(jnp.int32),
-                        total_lens.astype(jnp.int32), sm_scale,
-                        interpret=_resolve_interpret(interpret))
-    return out[:, None]                                    # [B, 1, Hq, Dh]
-
-
 def paged_decode_attention_stacked(q: jnp.ndarray, pages: jnp.ndarray,
                                    layer_idx, page_table: jnp.ndarray,
                                    positions: jnp.ndarray,
@@ -282,9 +259,7 @@ paged_decode_attention_stacked.supports_window_softcap = True
 # marker for families whose attention the GQA kernels cannot run directly
 # (deepseek MLA): a passed impl carrying it opts the family into its own
 # Pallas kernels (ops/pallas/mla_decode.py) instead of being called
-paged_decode_attention.pallas_paged_kernel = True
 paged_decode_attention_stacked.pallas_paged_kernel = True
 
 
-__all__ = ["paged_decode_attention", "paged_decode_attention_stacked",
-           "supports"]
+__all__ = ["paged_decode_attention_stacked", "supports"]

@@ -213,15 +213,4 @@ def mla_paged_decode_stacked(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
     return out[:, None]                                    # [B, 1, nh, dkv]
 
 
-def mla_paged_decode_layer(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
-                           kv_layer: jnp.ndarray, page_table: jnp.ndarray,
-                           total_lens: jnp.ndarray, sm_scale: float,
-                           interpret: bool | None = None) -> jnp.ndarray:
-    """Per-layer-buffer variant (the ``pallas_unrolled`` engine path):
-    ``kv_layer`` is one layer's ``[N, 2, 1, ps, dkv]`` buffer."""
-    return mla_paged_decode_stacked(q_lat, q_pe, kv_layer[None], 0,
-                                    page_table, total_lens, sm_scale,
-                                    interpret=interpret)
-
-
-__all__ = ["mla_paged_decode_stacked", "mla_paged_decode_layer", "supports"]
+__all__ = ["mla_paged_decode_stacked", "supports"]

@@ -23,16 +23,15 @@ blockwise loop has a zero trip count — the novel-prompt path costs
 nothing extra. The reference has no sequence parallelism anywhere
 (SURVEY §5) — net-new capability.
 
-Writes either cache layout (stacked ``[L, N, 2, Hkv, ps, Dh]`` for the scan
-forward; per-layer page-major list for the unrolled/Pallas forward) and
-composes
-with tensor parallelism: the head axis stays sharded over ``tp`` inside the
+Writes the stacked cache ``[L, N, 2, Hkv, ps, Dh]`` under one ``lax.scan``
+over the layers, like ``llama.forward``, and composes with tensor
+parallelism: the head axis stays sharded over ``tp`` inside the
 ring (attention is head-local), so a ``(sp, tp)`` mesh uses both.
 """
 
 from __future__ import annotations
 
-from typing import List, Tuple, Union
+from typing import Tuple
 
 import jax.numpy as jnp
 from jax import lax
@@ -52,19 +51,16 @@ from dynamo_tpu.ops.attention import (
     merge_softmax_partials,
     normalize_softmax_partials,
     write_kv,
-    write_kv_layer,
 )
 from dynamo_tpu.parallel.ring_attention import ring_self_attention
 
-Pages = Union[jnp.ndarray, List[jnp.ndarray]]
-
 
 def ring_prefill(params, cfg: ModelConfig, tokens: jnp.ndarray,
-                 positions: jnp.ndarray, pages: Pages,
+                 positions: jnp.ndarray, pages: jnp.ndarray,
                  page_table: jnp.ndarray, total_lens: jnp.ndarray,
                  new_lens: jnp.ndarray, *, mesh: Mesh,
                  sp_axis: str = "sp", tp_axis: str = "tp",
-                 ) -> Tuple[jnp.ndarray, Pages]:
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Full-prompt prefill with the sequence axis sharded over ``sp``.
 
     tokens/positions: [B, S] with S a multiple of the ``sp`` axis size;
@@ -90,9 +86,11 @@ def ring_prefill(params, cfg: ModelConfig, tokens: jnp.ndarray,
     h = params["embed"][tokens]                             # [B, S, H]
     h = lax.with_sharding_constraint(h, seq_sharded)
 
-    def layer(h, pages, lp, write, gather_layer):
+    def body(carry, xs):
+        h, pages = carry
+        lp, lidx = xs
         q, k, v = _project_qkv(cfg, lp, h, positions)
-        pages = write(pages, k, v)
+        pages = write_kv(pages, lidx, k, v, page_table, positions, new_lens)
         ring_parts = ring_self_attention(
             mesh, q, k, v, positions, kv_valid=kv_valid, sm_scale=sm_scale,
             axis_name=sp_axis, head_axis=tp_axis, return_partials=True)
@@ -100,7 +98,7 @@ def ring_prefill(params, cfg: ModelConfig, tokens: jnp.ndarray,
         def gather_chunk(c):
             tbl = lax.dynamic_slice(
                 table_pad, (0, c * PAGES_PER_CHUNK), (B, PAGES_PER_CHUNK))
-            g = gather_layer(pages, tbl)   # [B, C, 2, Hkv, ps, Dh]
+            g = pages[lidx, tbl]           # [B, C, 2, Hkv, ps, Dh]
             return _gathered_to_bhtd(g[:, :, 0]), _gathered_to_bhtd(g[:, :, 1])
 
         # cached-context partials: new-token queries vs positions < start
@@ -114,27 +112,7 @@ def ring_prefill(params, cfg: ModelConfig, tokens: jnp.ndarray,
         out = normalize_softmax_partials(num, den)          # [B,Hq,S,D]
         attn = out.transpose(0, 2, 1, 3).astype(q.dtype)    # [B,S,Hq,D]
         h = _finish_layer(cfg, lp, h, attn)
-        return lax.with_sharding_constraint(h, seq_sharded), pages
-
-    if isinstance(pages, list):
-        out_pages: List[jnp.ndarray] = []
-        for l in range(cfg.num_layers):
-            lp = {k: v[l] for k, v in params["layers"].items()}
-            h, kv = layer(h, pages[l], lp,
-                          lambda pg, k, v: write_kv_layer(
-                              pg, k, v, page_table, positions, new_lens),
-                          gather_layer=lambda pg, tbl: pg[tbl])
-            out_pages.append(kv)
-        return _logits(cfg, params, h, new_lens), out_pages
-
-    def body(carry, xs):
-        h, pages = carry
-        lp, lidx = xs
-        h, pages = layer(h, pages, lp,
-                         lambda pg, k, v: write_kv(
-                             pg, lidx, k, v, page_table, positions, new_lens),
-                         gather_layer=lambda pg, tbl: pg[lidx, tbl])
-        return (h, pages), None
+        return (lax.with_sharding_constraint(h, seq_sharded), pages), None
 
     (h, pages), _ = lax.scan(
         body, (h, pages), (params["layers"], jnp.arange(cfg.num_layers)))

@@ -191,12 +191,6 @@ class ModelSharding:
             return P()
         return P(None, None, None, "tp", None, None)
 
-    def pages_layer_spec(self) -> P:
-        """Per-layer cache [N, 2, Hkv, page, Dh]: Hkv over tp."""
-        if self.cfg.kv_lora_rank:
-            return P()
-        return P(None, None, "tp", None, None)
-
     # -- application -------------------------------------------------------
 
     def _named(self, spec: P) -> NamedSharding:
@@ -214,9 +208,6 @@ class ModelSharding:
         return jax.tree_util.tree_map_with_path(place, params)
 
     def shard_pages(self, pages):
-        if isinstance(pages, list):
-            spec = self._named(self.pages_layer_spec())
-            return [jax.device_put(p, spec) for p in pages]
         return jax.device_put(pages, self._named(self.pages_spec()))
 
     def replicate(self, x):
@@ -233,22 +224,16 @@ def tp_sharding(cfg: ModelConfig, tp_size: int,
 
 # -- transport-array sharding helpers ---------------------------------------
 # The KV transfer paths move blocks as a STACKED rank-6 array
-# [L, n, 2, Hkv, ps, Dh] regardless of whether the cache itself is the
-# stacked array or a per-layer list; these helpers are the one place the
-# cache placement -> transport placement mapping lives (engine/transfer.py
-# and the engine's sharded gather both use them).
+# [L, n, 2, Hkv, ps, Dh], the cache's own rank; these helpers are the one
+# place the cache placement -> transport placement mapping lives
+# (engine/transfer.py and the engine's sharded gather both use them).
 
 
 def transport_sharding(pages):
     """Sharding of the stacked ``[L, n, ...]`` transport array matching the
-    cache's placement. For a per-layer list cache (rank-5 refs) the layer
-    axis is prepended to the spec; any non-Named sharding (single device)
-    passes through unchanged."""
-    ref = pages[0] if isinstance(pages, list) else pages
-    sharding = ref.sharding
-    if isinstance(pages, list) and isinstance(sharding, NamedSharding):
-        sharding = NamedSharding(sharding.mesh, P(None, *sharding.spec))
-    return sharding
+    cache's placement: the cache's own (block indexing runs along the
+    unsharded page axis)."""
+    return pages.sharding
 
 
 def shard_layout(sharding) -> tuple:

@@ -117,9 +117,9 @@ def make_device_transfer_plane(engine: JaxEngine):
     streams straight to its decode shard's device."""
     from jax.sharding import SingleDeviceSharding
 
-    ref = engine.pages[0] if isinstance(engine.pages, list) else engine.pages
-    if not isinstance(ref.sharding, SingleDeviceSharding) \
-            and len(ref.sharding.device_set) > 1:
+    sharding = engine.pages.sharding
+    if not isinstance(sharding, SingleDeviceSharding) \
+            and len(sharding.device_set) > 1:
         logger.info("device-direct KV plane disabled for the mesh-sharded "
                     "cache; shard-to-shard pulls ride the wire-v5 "
                     "per-shard frames on the bulk/RPC planes")

@@ -142,28 +142,6 @@ class TestForward:
         np.testing.assert_allclose(np.asarray(l1), np.asarray(l2),
                                    rtol=2e-4, atol=2e-4)
 
-    def test_unrolled_matches_scan(self):
-        from dynamo_tpu.models.llama import make_pages_list
-        cfg = ds_cfg()
-        params = deepseek.init_params(cfg, jax.random.PRNGKey(3))
-        table = _alloc(2, 3)
-        B, S = 2, 8
-        toks = jnp.asarray(np.random.RandomState(2).randint(
-            1, 255, size=(B, S)), jnp.int32)
-        pos = jnp.tile(jnp.arange(S, dtype=jnp.int32)[None], (B, 1))
-        lens = jnp.full((B,), S, jnp.int32)
-        l1, p1, _ = deepseek.forward(
-            params, cfg, toks, pos, make_pages(cfg, 8, 4, jnp.float32),
-            table, lens, lens)
-        l2, p2, _ = deepseek.forward_unrolled(
-            params, cfg, toks, pos,
-            make_pages_list(cfg, 8, 4, jnp.float32), table, lens, lens)
-        np.testing.assert_allclose(np.asarray(l1), np.asarray(l2),
-                                   rtol=2e-5, atol=2e-5)
-        for l in range(cfg.num_layers):
-            np.testing.assert_allclose(np.asarray(p1[l]), np.asarray(p2[l]),
-                                       rtol=1e-6, atol=1e-6)
-
 
 class TestGate:
     def test_group_limited_restricts_to_top_groups(self):
@@ -402,15 +380,6 @@ class TestMlaPallasDecode:
             np.testing.assert_allclose(np.asarray(ref),
                                        np.asarray(outs[layer]),
                                        rtol=2e-4, atol=2e-4)
-
-    def test_layer_variant_matches(self):
-        from dynamo_tpu.ops.pallas.mla_decode import mla_paged_decode_layer
-        pages, q_lat, q_pe, table, total = self._mk(seed=9)
-        ref = self._ref(q_lat, q_pe, pages, 1, table, total, 0.1)
-        out = mla_paged_decode_layer(q_lat, q_pe, pages[1], table, total,
-                                     0.1, interpret=True)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
-                                   rtol=2e-4, atol=2e-4)
 
     def test_forward_pallas_matches_xla_decode(self):
         """deepseek.forward no longer ignores attn_impl: with a supported

@@ -155,19 +155,3 @@ async def test_engine_pallas_matches_scan():
     assert len(outs["pallas"]) == 5
 
 
-def test_unrolled_matches_scan():
-    cfg = ModelConfig.tiny(model_type="gemma2", num_layers=4,
-                           sliding_window=6, attn_logit_softcap=40.0)
-    params = gemma.init_params(cfg, jax.random.PRNGKey(3))
-    prompt = list(range(1, 12))
-    pages = gemma.make_pages(cfg, 8, 8, dtype=jnp.float32)
-    ref, _ = _prefill(params, cfg, prompt, pages, _alloc(1, 4))
-
-    pages_list = gemma.make_pages_list(cfg, 8, 8, dtype=jnp.float32)
-    toks = jnp.asarray([prompt], jnp.int32)
-    pos = jnp.asarray([list(range(len(prompt)))], jnp.int32)
-    lens = jnp.asarray([len(prompt)], jnp.int32)
-    got, _ = gemma.forward_unrolled(params, cfg, toks, pos, pages_list,
-                                    _alloc(1, 4), lens, lens)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-4, atol=2e-4)

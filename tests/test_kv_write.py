@@ -1,9 +1,10 @@
-"""The cache write (``ops/attention.write_kv`` / ``write_kv_layer``, and
-``write_kv_packed`` for a token-packed step).
+"""The cache write (``ops/attention.write_kv``, and ``write_kv_packed``
+for a token-packed step).
 
 Parity, bit for bit, of the page-at-a-time write against the plain
-``.at[layer, phys, :, :, slot].set`` it replaced, over both pool forms, the
-three step shapes, the GQA and MLA page geometries, both cache dtypes, and
+``.at[layer, phys, :, :, slot].set`` it replaced, at the first and the last
+layer of the stacked pool (every other layer untouched), the three step
+shapes, the GQA and MLA page geometries, both cache dtypes, and
 page sizes the TPU's tile rows divide and do not (one path serves all);
 the packed entry against the same scatter on the same rows laid back to
 back. Then the guard on the compiled step programs
@@ -16,8 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dynamo_tpu.ops.attention import (write_kv, write_kv_layer,
-                                      write_kv_packed)
+from dynamo_tpu.ops.attention import write_kv, write_kv_packed
 
 L, LAYER = 3, 1
 GEOMETRY = {"gqa": (8, 128), "mla": (1, 512)}      # Hkv, Dh
@@ -42,8 +42,6 @@ def _old_write(pool, layer, k_new, v_new, table, positions, new_lens):
     phys = jnp.where(pad, 0, phys)
     slot = jnp.where(pad, 0, positions % ps)
     new = jnp.stack([k_new, v_new], axis=2).astype(pool.dtype)
-    if layer is None:
-        return pool.at[phys, :, :, slot].set(new, mode="drop")
     return pool.at[layer, phys, :, :, slot].set(new, mode="drop")
 
 
@@ -62,8 +60,8 @@ def _pool_scatter_window(fn, *args) -> tuple:
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 @pytest.mark.parametrize("geometry", ["gqa", "mla"])
 @pytest.mark.parametrize("step", ["decode", "prefill", "mixed"])
-@pytest.mark.parametrize("pool_form", ["stacked", "per_layer"])
-def test_write_matches_the_plain_scatter_bit_for_bit(pool_form, step,
+@pytest.mark.parametrize("layer", [0, L - 1])
+def test_write_matches_the_plain_scatter_bit_for_bit(layer, step,
                                                      geometry, dtype, ps):
     Hkv, Dh = GEOMETRY[geometry]
     B, P = 4, 16
@@ -71,9 +69,7 @@ def test_write_matches_the_plain_scatter_bit_for_bit(pool_form, step,
     start, new_lens, S = _batch(step, ps)
     rng = np.random.default_rng(7)
     shape = (N, 2, Hkv, ps, Dh)
-    stacked = pool_form == "stacked"
-    pool = jnp.asarray(rng.standard_normal(((L,) if stacked else ()) + shape),
-                       dtype)
+    pool = jnp.asarray(rng.standard_normal((L,) + shape), dtype)
     k_new = jnp.asarray(rng.standard_normal((B, S, Hkv, Dh)), dtype)
     v_new = jnp.asarray(rng.standard_normal((B, S, Hkv, Dh)), dtype)
     table = jnp.asarray(rng.permutation(np.arange(1, 1 + B * P))
@@ -82,12 +78,8 @@ def test_write_matches_the_plain_scatter_bit_for_bit(pool_form, step,
                  + jnp.arange(S, dtype=jnp.int32)[None, :])
     lens = jnp.asarray(new_lens, jnp.int32)
 
-    if stacked:
-        new_fn = lambda *a: write_kv(a[0], LAYER, *a[1:])      # noqa: E731
-        old_fn = lambda *a: _old_write(a[0], LAYER, *a[1:])    # noqa: E731
-    else:
-        new_fn = write_kv_layer
-        old_fn = lambda *a: _old_write(a[0], None, *a[1:])     # noqa: E731
+    new_fn = lambda *a: write_kv(a[0], layer, *a[1:])          # noqa: E731
+    old_fn = lambda *a: _old_write(a[0], layer, *a[1:])        # noqa: E731
     args = (pool, k_new, v_new, table, positions, lens)
     # whole pages, the window that is contiguous in the page-major pool
     # and few enough to scatter; a token's window is neither
@@ -97,11 +89,10 @@ def test_write_matches_the_plain_scatter_bit_for_bit(pool_form, step,
     got = np.asarray(jax.jit(new_fn)(*args).astype(jnp.float32))
     want = np.asarray(jax.jit(old_fn)(*args).astype(jnp.float32))
     before = np.asarray(pool.astype(jnp.float32))
-    if stacked:
-        # the other layers: untouched, their page 0 included
-        np.testing.assert_array_equal(np.delete(got, LAYER, 0),
-                                      np.delete(before, LAYER, 0))
-        got, want, before = got[LAYER], want[LAYER], before[LAYER]
+    # the other layers: untouched, their page 0 included
+    np.testing.assert_array_equal(np.delete(got, layer, 0),
+                                  np.delete(before, layer, 0))
+    got, want, before = got[layer], want[layer], before[layer]
     # every page but the garbage page, bit for bit
     np.testing.assert_array_equal(got[1:], want[1:])
     # the real tokens landed where the table says (not merely where the

@@ -9,7 +9,6 @@ import jax
 import jax.numpy as jnp
 
 from dynamo_tpu.models.config import ModelConfig
-from dynamo_tpu.models import llama
 from dynamo_tpu.models.llama import forward, init_params, make_pages
 from dynamo_tpu.ops.sampling import sample_tokens
 
@@ -146,95 +145,11 @@ def test_sampling_greedy_and_topk():
     assert np.all((np.asarray(t3a) >= 0) & (np.asarray(t3a) < 50))
 
 
-class TestUnrolledForward:
-    def test_unrolled_matches_scan(self):
-        """forward_unrolled (per-layer buffers) must produce identical
-        logits and cache contents to the scan forward."""
-        import numpy as np
-        cfg = ModelConfig.tiny()
-        params = llama.init_params(cfg, jax.random.PRNGKey(0))
-        stacked = llama.make_pages(cfg, 8, 4)
-        layered = llama.make_pages_list(cfg, 8, 4)
-        B, S = 2, 8
-        tokens = jnp.arange(B * S, dtype=jnp.int32).reshape(B, S) % 100
-        positions = jnp.tile(jnp.arange(S, dtype=jnp.int32)[None], (B, 1))
-        table = jnp.array([[1, 2, 0], [3, 4, 0]], jnp.int32)
-        total = jnp.full((B,), S, jnp.int32)
-        new = jnp.full((B,), S, jnp.int32)
-
-        l1, p1 = llama.forward(params, cfg, tokens, positions, stacked,
-                               table, total, new)
-        l2, p2 = llama.forward_unrolled(params, cfg, tokens, positions,
-                                        layered, table, total, new)
-        np.testing.assert_allclose(np.asarray(l1), np.asarray(l2),
-                                   rtol=2e-5, atol=2e-5)
-        for l in range(cfg.num_layers):
-            np.testing.assert_allclose(np.asarray(p1[l]), np.asarray(p2[l]),
-                                       rtol=1e-6, atol=1e-6)
-
-    async def test_engine_unrolled_matches_scan_tokens(self):
-        from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
-        from dynamo_tpu.protocols.common import (
-            PreprocessedRequest, SamplingOptions, StopConditions)
-
-        def req(rid):
-            return PreprocessedRequest(
-                token_ids=list(range(1, 11)), request_id=rid,
-                stop_conditions=StopConditions(max_tokens=6),
-                sampling_options=SamplingOptions(temperature=0.0))
-
-        outs = {}
-        for impl in ("scan", "unrolled"):
-            eng = JaxEngine.random_init(ModelConfig.tiny(), JaxEngineConfig(
-                num_pages=32, page_size=4, max_num_seqs=2,
-                max_prefill_chunk=8, max_context=64, min_prefill_bucket=4,
-                attn_impl=impl))
-            try:
-                toks = []
-                async for f in eng.generate(req(impl)):
-                    toks.extend(f.token_ids)
-                outs[impl] = toks
-            finally:
-                await eng.stop()
-        assert outs["scan"] == outs["unrolled"]
-        assert len(outs["scan"]) == 6
-
-
-class TestPallasDecode:
-    """The kernel runs in interpreter mode on CPU (same jaxpr, no Mosaic),
-    and natively when a real TPU is attached — one test body for both."""
-
-    def _run(self, interpret: bool):
-        import numpy as np
-        from dynamo_tpu.ops.attention import paged_attention_layer
-        from dynamo_tpu.ops.pallas import paged_decode_attention
-        # page-major layer cache [N, 2, Hkv, ps, Dh]
-        kv = jnp.asarray(
-            jax.random.normal(jax.random.PRNGKey(0), (16, 2, 2, 8, 128)),
-            dtype=jnp.bfloat16)
-        B, P = 4, 6
-        table = jnp.arange(1, 1 + B * P, dtype=jnp.int32).reshape(B, P) % 15 + 1
-        q = jnp.asarray(jax.random.normal(jax.random.PRNGKey(1), (B, 1, 4, 128)),
-                        dtype=jnp.bfloat16)
-        # mixed lengths incl. a single-token and a full-table sequence
-        total = jnp.array([9, 17, 1, 48], jnp.int32)
-        positions = (total - 1)[:, None]
-        ref = paged_attention_layer(q, kv, table, positions, total, 0.088)
-        out = paged_decode_attention(q, kv, table, positions, total, 0.088,
-                                     interpret=interpret)
-        np.testing.assert_allclose(np.asarray(ref, np.float32),
-                                   np.asarray(out, np.float32),
-                                   rtol=2e-2, atol=2e-2)
-
-    def test_kernel_interpret_matches_xla_path(self):
-        self._run(interpret=True)
-
-
 class TestPallasDecodeStacked:
-    """The layer-indexed stacked-cache kernel variant: same math as the
-    per-layer kernel, but the whole [L, N, ...] cache enters the kernel and
-    an SMEM scalar picks the layer — including with a TRACED index inside a
-    ``lax.scan`` (the engine's scan+pallas decode path)."""
+    """The decode kernel against the XLA path, in interpreter mode on the
+    CPU (same jaxpr, no Mosaic): the whole [L, N, ...] cache enters the
+    kernel and an SMEM scalar picks the layer — including with a TRACED
+    index inside a ``lax.scan`` (the engine's scan+pallas decode path)."""
 
     def _mk(self, seed=0):
         L, N, Hkv, ps, Dh = 3, 16, 2, 8, 128
@@ -251,13 +166,13 @@ class TestPallasDecodeStacked:
         return pages, q, table, total
 
     def test_static_layer_matches_xla(self):
-        from dynamo_tpu.ops.attention import paged_attention_layer
+        from dynamo_tpu.ops.attention import paged_attention
         from dynamo_tpu.ops.pallas import paged_decode_attention_stacked
         pages, q, table, total = self._mk()
         positions = (total - 1)[:, None]
         for layer in range(pages.shape[0]):
-            ref = paged_attention_layer(q, pages[layer], table, positions,
-                                        total, 0.088)
+            ref = paged_attention(q, pages, layer, table, positions,
+                                  total, 0.088)
             out = paged_decode_attention_stacked(
                 q, pages, layer, table, positions, total, 0.088,
                 interpret=True)
@@ -266,7 +181,7 @@ class TestPallasDecodeStacked:
                                        rtol=2e-2, atol=2e-2)
 
     def test_traced_layer_inside_scan(self):
-        from dynamo_tpu.ops.attention import paged_attention_layer
+        from dynamo_tpu.ops.attention import paged_attention
         from dynamo_tpu.ops.pallas import paged_decode_attention_stacked
         pages, q, table, total = self._mk(seed=4)
         positions = (total - 1)[:, None]
@@ -280,8 +195,8 @@ class TestPallasDecodeStacked:
 
         _, outs = jax.lax.scan(body, 0, jnp.arange(L))
         for layer in range(L):
-            ref = paged_attention_layer(q, pages[layer], table, positions,
-                                        total, 0.088)
+            ref = paged_attention(q, pages, layer, table, positions,
+                                  total, 0.088)
             np.testing.assert_allclose(np.asarray(ref, np.float32),
                                        np.asarray(outs[layer], np.float32),
                                        rtol=2e-2, atol=2e-2)
@@ -491,7 +406,8 @@ class TestBlockwisePrefillAttention:
         new = jnp.array([16, 16, 9], jnp.int32)
         positions = start[:, None] + jnp.arange(S)[None, :]
         total = start + new
-        out = A.paged_attention_layer(q, kv, table, positions, total, 0.17)
+        out = A.paged_attention(q, kv[None], 0, table, positions, total,
+                                0.17)
         # direct reference
         g = kv[table]
         k = A._gathered_to_bhtd(g[:, :, 0])
@@ -513,8 +429,9 @@ class TestBlockwisePrefillAttention:
         positions = jnp.tile(jnp.arange(S)[None], (B, 1)) + 20
         total = jnp.array([28, 23], jnp.int32)
         out = A.paged_attention(q, pages, 1, table, positions, total, 0.2)
-        ref = A.paged_attention_layer(q, pages[1], table, positions, total,
-                                      0.2)
+        # the same layer as a pool of its own
+        ref = A.paged_attention(q, pages[1][None], 0, table, positions,
+                                total, 0.2)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
 
@@ -524,7 +441,7 @@ class TestBlockwisePrefillAttention:
         q, kv, table = self._mk(B, S, P, Hq, Hkv, ps, Dh, jnp.float32, seed=7)
         positions = jnp.tile(jnp.arange(S)[None], (B, 1))
         total = jnp.array([4, 3], jnp.int32)
-        out = A.paged_attention_layer(q, kv, table, positions, total, 0.3)
+        out = A.paged_attention(q, kv[None], 0, table, positions, total, 0.3)
         g = kv[table]
         k = A._gathered_to_bhtd(g[:, :, 0])
         v = A._gathered_to_bhtd(g[:, :, 1])

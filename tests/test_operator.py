@@ -17,7 +17,7 @@ import pytest
 
 _spec = importlib.util.spec_from_file_location(
     "graph_operator", os.path.join(os.path.dirname(__file__), "..",
-                                   "deploy", "operator.py"))
+                                   "deploy", "graph_operator.py"))
 operator = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(operator)
 
@@ -134,7 +134,7 @@ class TestReconcileLoop:
         env["FAKE_CRS"] = str(crs)
         r = subprocess.run(
             [sys.executable, os.path.join(os.path.dirname(__file__), "..",
-                                          "deploy", "operator.py"),
+                                          "deploy", "graph_operator.py"),
              "--once", "--kube-namespace", "ns1"],
             env=env, capture_output=True, timeout=60)
         assert r.returncode == 0, r.stderr.decode()
@@ -185,7 +185,7 @@ class TestReconcileLoop:
         env["FAKE_APPLY_FAILS"] = "1"
         r = subprocess.run(
             [sys.executable, os.path.join(os.path.dirname(__file__), "..",
-                                          "deploy", "operator.py"),
+                                          "deploy", "graph_operator.py"),
              "--once", "--kube-namespace", "ns1"],
             env=env, capture_output=True, timeout=60)
         assert r.returncode == 0, r.stderr.decode()
@@ -238,7 +238,7 @@ class TestReconcileLoop:
         env["FAKE_CRS"] = str(crs)
         r = subprocess.run(
             [sys.executable, os.path.join(os.path.dirname(__file__), "..",
-                                          "deploy", "operator.py"),
+                                          "deploy", "graph_operator.py"),
              "--once", "--kube-namespace", "ns1"],
             env=env, capture_output=True, timeout=60)
         assert r.returncode == 0, r.stderr.decode()
