@@ -301,6 +301,9 @@ class Smoke:
             say(f"program {r['program']:<7} pool "
                 f"{'x'.join(map(str, r['pool_shape']))} "
                 f"({r['pool_bytes'] / 1e9:.2f} GB): no pool-sized copy, "
+                + ("selection direct (a toy vocabulary), "
+                   if r["selection"] == "direct"
+                   else "no sort over the vocabulary, ") +
                 f"temporaries {r['temp_bytes'] / 1e9:.3f} GB "
                 f"(compiled in {r['seconds']:.0f}s)")
         need = max(self.args.replicas, self.args.tensor_parallel_size)
@@ -719,8 +722,9 @@ def check_programs(dry: bool) -> list:
     and the token-packed step (an engine on the kernels serves its
     prefill-carrying steps with that one) at the geometry
     ``start_servers`` gives the worker (from shapes: no weights, no pool
-    on the device) and fail on a pool-sized copy in the HLO or a temporary
-    as large as the pool (``engine/program_check.py``). The packed program
+    on the device) and fail on a pool-sized copy in the HLO, a temporary
+    as large as the pool or a sort over the vocabulary
+    (``engine/program_check.py``). The packed program
     RUNS in the served phase: ``check_workers`` reads its count."""
     import jax
 
@@ -756,9 +760,11 @@ def check_programs(dry: bool) -> list:
         if not r["ok"]:
             raise Failed(
                 f"step program {r['program']}: {len(r['pool_copies'])} "
-                f"pool-sized copies, temporaries {r['temp_bytes']} B beside "
+                f"pool-sized copies, {len(r['vocab_sorts'])} sorts over "
+                f"the vocabulary, temporaries {r['temp_bytes']} B beside "
                 f"a pool of {r['pool_bytes']} B:\n"
-                + "\n".join(c[:300] for c in r["pool_copies"]))
+                + "\n".join(c[:300] for c in
+                            r["pool_copies"] + r["vocab_sorts"]))
     return reports
 
 

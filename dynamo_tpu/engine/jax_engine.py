@@ -48,7 +48,8 @@ from dynamo_tpu.engine.loop import ScheduledEngineBase
 from dynamo_tpu.engine.scheduler import PrefillBatch, StepPlan
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models import llama
-from dynamo_tpu.ops.sampling import sample_tokens
+from dynamo_tpu.ops.sampling import (TOPK_MAX, sample_tokens,
+                                     top_candidates)
 
 logger = logging.getLogger(__name__)
 
@@ -1104,7 +1105,12 @@ class JaxEngine(ScheduledEngineBase):
         tail and the spec verify step pack (K clamps to the vocab; the
         host unpack mirrors the same clamp)."""
         kt = min(self.cfg.num_top_logprobs, lf.shape[-1])
-        vals, ids = jax.lax.top_k(lf, kt)
+        # the first kt of the sampler's own candidates: the same call on
+        # the same logits, so a step program holds ONE selection over
+        # the vocabulary (asked for apart, kt and TOPK_MAX columns would
+        # be two)
+        vals, ids = top_candidates(lf, max(kt, min(TOPK_MAX, lf.shape[-1])))
+        vals, ids = vals[..., :kt], ids[..., :kt]
         lps = vals - jax.nn.logsumexp(lf, axis=-1, keepdims=True)
         return (ids.astype(jnp.int32),
                 jax.lax.bitcast_convert_type(lps, jnp.int32))
@@ -2557,7 +2563,7 @@ class JaxEngine(ScheduledEngineBase):
             # gather INSIDE the scan: only [B, chunk(, top_n)] leaves each
             # step — the full [B, S, V] logits never materialize
             t_lp = jnp.take_along_axis(lsm, gc[..., None], axis=-1)[..., 0]
-            top_lp, top_id = jax.lax.top_k(lsm, top_n)
+            top_lp, top_id = top_candidates(lsm, top_n)
             return pages, (t_lp, top_id.astype(jnp.int32), top_lp)
 
         _, (t_lp, top_id, top_lp) = jax.lax.scan(

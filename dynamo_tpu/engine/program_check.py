@@ -1,4 +1,4 @@
-"""What a compiled step program does to the page pool.
+"""What a compiled step program does to the page pool, and to the logits.
 
 The pool is donated through every step program and the new K/V rows are
 scattered into it in place (``ops/attention._write_pages``). Whether that
@@ -12,8 +12,16 @@ an array as large as the pool, and ``check_step_programs`` compiles the
 decode, fused and mixed programs of an engine from shapes alone and reports
 those beside ``memory_analysis()``'s temporary bytes; an engine that
 serves its prefill-carrying steps token-packed has a fourth, ``packed``.
+
+The second line it holds is the sampler's: ``lax.top_k`` over the
+vocabulary is, on the chip, a sort of every column of every row (PERF.md
+section 6, PR 30: 19 % of the cell's time), which
+``ops/sampling.top_candidates`` replaces where the vocabulary is large
+enough to pay for it. ``vocab_sorts`` lists the instructions of an
+optimised HLO text that order an axis as long as the vocabulary.
 Used by
-``tests/test_kv_write.py`` (toy model, CPU),
+``tests/test_kv_write.py`` and ``tests/test_sampling_topk.py`` (toy
+models, CPU),
 ``tests/test_pallas_tpu_lowering.py`` (a tp=2 mesh, the TPU compiler) and
 the kernel child of ``chip_smoke.py`` (serving geometry, on the chip).
 """
@@ -74,6 +82,29 @@ def pool_copies(hlo_text: str, pool_shape, dtype) -> List[str]:
     return found
 
 
+_ORDERING = re.compile(r" (sort|custom-call)\(([^)]*)\)")
+
+
+def vocab_sorts(hlo_text: str, vocab: int) -> List[str]:
+    """Instructions of an optimised HLO module that order an axis of
+    ``vocab`` columns: a ``sort``, or XLA's ``TopK`` custom call (what a
+    lone ``lax.top_k`` becomes), on an operand that carries that axis.
+    One line each, as printed."""
+    want = str(vocab)
+    wide = set()        # names of the arrays that carry the axis
+    found = []
+    for line in hlo_text.splitlines():
+        m = _INSTR.match(line)
+        if m and want in m.group(4).split(","):
+            wide.add(m.group(2))
+        m = _ORDERING.search(line)
+        if (m and (m.group(1) == "sort"
+                   or 'custom_call_target="TopK"' in line)
+                and any(a.strip() in wide for a in m.group(2).split(","))):
+            found.append(line.strip())
+    return found
+
+
 def _pool_shape(engine, num_pages: Optional[int]):
     shape = engine.pages.shape
     if num_pages is not None:
@@ -81,18 +112,18 @@ def _pool_shape(engine, num_pages: Optional[int]):
     return tuple(shape)
 
 
-def lower_step_programs(engine, batch: int, chunk: int, width: int = 8,
-                        sharding=None, num_pages: Optional[int] = None,
-                        tokens: Optional[int] = None
-                        ) -> Dict[str, "jax.stages.Lowered"]:
-    """The decode step ``[batch, 1]``, the fused block of ``width`` decode
-    steps, the padded prefill-carrying step ``[batch, chunk]`` and, where
-    the engine packs (``engine.padded_reason`` is None), the token-packed
-    step of ``tokens`` slots (default ``chunk``) over ``batch`` rows, of a
-    stacked-pool engine, lowered from shapes alone (``engine.params`` may
-    be abstract, nothing is placed on a device). ``sharding`` places every
-    argument, for a described device; ``num_pages`` overrides the pool's
-    page count."""
+def step_programs(engine, batch: int, chunk: int, width: int = 8,
+                  sharding=None, num_pages: Optional[int] = None,
+                  tokens: Optional[int] = None) -> Dict[str, tuple]:
+    """``name -> (jitted program, its arguments as shapes)`` for the decode
+    step ``[batch, 1]``, the fused block of ``width`` decode steps, the
+    padded prefill-carrying step ``[batch, chunk]``, where the engine
+    packs (``engine.padded_reason`` is None) the token-packed step of
+    ``tokens`` slots (default ``chunk``) over ``batch`` rows, and where
+    it speculates the verify step ``[batch, spec_K + 1]``
+    (``engine.params`` may be abstract, nothing is placed on a device).
+    ``sharding`` places every argument, for a described device;
+    ``num_pages`` overrides the pool's page count."""
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
 
@@ -112,18 +143,20 @@ def lower_step_programs(engine, batch: int, chunk: int, width: int = 8,
     # the engine's own jitted programs: the names a device trace shows and
     # the donation are the served ones
     out = {
-        "decode": engine._jit_step.lower(*step_args(B, 1)),
-        "fused": engine._get_jit_multistep(width).lower(
+        "decode": (engine._jit_step, step_args(B, 1)),
+        "fused": (engine._get_jit_multistep(width), (
             params, pages, sds((B, 1), i32), sds((B, 1), i32),
             sds((B, engine.table_width), i32), sds((B,), i32),
             sds((B,), jnp.bool_), sds((B,), i32), sds((B,), i32),
             sds((2,), jnp.uint32), sds((), i32), sds((B,), f32),
-            sds((B,), i32), sds((B,), f32), sds((B, 1), i32), None, None),
-        "mixed": engine._jit_step.lower(*step_args(B, chunk)),
+            sds((B,), i32), sds((B,), f32), sds((B, 1), i32), None, None)),
+        "mixed": (engine._jit_step, step_args(B, chunk)),
     }
     if engine.padded_reason is None:
-        out["packed"] = engine._jit_packed.lower(
-            *step_args(B, tokens or chunk, lead=1))
+        out["packed"] = (engine._jit_packed,
+                         step_args(B, tokens or chunk, lead=1))
+    if engine.spec_K > 0:
+        out["spec"] = (engine._jit_spec, step_args(B, engine.spec_K + 1))
     return out
 
 
@@ -131,22 +164,33 @@ def check_step_programs(engine, batch: int, chunk: int, width: int = 8,
                         sharding=None, num_pages: Optional[int] = None,
                         tokens: Optional[int] = None) -> List[dict]:
     """Compile the programs and report, for each, the pool-sized
-    copies in its HLO and its temporary bytes beside the pool's bytes. A
-    program is ``ok`` with no such copy and temporaries under one pool."""
-    lowered = lower_step_programs(engine, batch, chunk, width, sharding,
-                                  num_pages, tokens)
+    copies in its HLO, its temporary bytes beside the pool's bytes and
+    the sorts over the vocabulary. A program is ``ok`` with no such copy,
+    temporaries under one pool and, where the sampler's selection is the
+    grouped one (``ops/sampling.candidate_form``; a toy vocabulary takes
+    ``lax.top_k`` by design), no such sort; ``selection`` is that form."""
+    from dynamo_tpu.ops.sampling import candidate_form
+
+    programs = step_programs(engine, batch, chunk, width, sharding,
+                             num_pages, tokens)
     shape, dtype = _pool_shape(engine, num_pages), engine.pages.dtype
     pool_bytes = math.prod(shape) * jnp.dtype(dtype).itemsize
+    vocab = engine.model_cfg.vocab_size
+    selection = candidate_form(vocab)
     out = []
-    for name, low in lowered.items():
-        compiled = low.compile()
-        copies = pool_copies(compiled.as_text(), shape, dtype)
+    for name, (fn, args) in programs.items():
+        compiled = fn.lower(*args).compile()
+        text = compiled.as_text()
+        copies = pool_copies(text, shape, dtype)
+        sorts = vocab_sorts(text, vocab) if selection != "direct" else []
         temp = int(compiled.memory_analysis().temp_size_in_bytes)
         out.append({"program": name, "pool_shape": list(shape),
                     "pool_bytes": pool_bytes, "temp_bytes": temp,
-                    "pool_copies": copies,
-                    "ok": not copies and temp < pool_bytes})
+                    "pool_copies": copies, "selection": selection,
+                    "vocab_sorts": sorts,
+                    "ok": not copies and not sorts and temp < pool_bytes})
     return out
 
 
-__all__ = ["pool_copies", "lower_step_programs", "check_step_programs"]
+__all__ = ["pool_copies", "vocab_sorts", "step_programs",
+           "check_step_programs"]

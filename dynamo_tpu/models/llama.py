@@ -34,6 +34,7 @@ from dynamo_tpu.ops.attention import (
 )
 from dynamo_tpu.ops.rope import apply_rope
 from dynamo_tpu.ops import quant
+from dynamo_tpu.ops.sampling import top_candidates
 
 Params = Dict[str, Any]
 
@@ -383,7 +384,7 @@ def score(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                              preferred_element_type=jnp.float32)  # [B,c,V]
         lsm = jax.nn.log_softmax(logits, axis=-1)
         t_lp = jnp.take_along_axis(lsm, tc[..., None], axis=-1)[..., 0]
-        top_lp, top_id = jax.lax.top_k(lsm, top_n)    # [B, c, top_n]
+        top_lp, top_id = top_candidates(lsm, top_n)   # [B, c, top_n]
         return None, (t_lp, top_id.astype(jnp.int32), top_lp)
 
     _, (t_lp, top_id, top_lp) = jax.lax.scan(head_chunk, None, (h_c, t_c))

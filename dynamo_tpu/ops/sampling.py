@@ -4,12 +4,26 @@ One jittable ``sample_tokens`` handles a whole decode batch with *per-request*
 temperature / top-k / top-p (the reference forwards these to vLLM's sampler;
 here they run natively on TPU).
 
-Strategy: gather the static ``TOPK_MAX`` highest logits once (``lax.top_k``),
-then apply per-request top-k and top-p masks inside that candidate set and draw
-via Gumbel-max. Greedy requests (temperature == 0) take candidate 0. Restricting
-sampling to the top ``TOPK_MAX=64`` candidates is exact for any top_k <= 64 and
-an excellent approximation otherwise (tail mass beyond the top 64 is noise for
-served models); it keeps the sampler free of full-vocab sorts.
+Strategy: take the static ``TOPK_MAX`` highest logits of each row once
+(``top_candidates``), then apply per-request top-k and top-p masks inside that
+candidate set and draw via Gumbel-max. Greedy requests (temperature == 0) take
+candidate 0. Restricting sampling to the top ``TOPK_MAX=64`` candidates is
+exact for any top_k <= 64 and an excellent approximation otherwise (tail mass
+beyond the top 64 is noise for served models); a request's ``top_k`` above
+``TOPK_MAX`` is clamped to it.
+
+``top_candidates`` returns what ``jax.lax.top_k`` returns, bit for bit, but
+never orders an axis as long as the vocabulary: at a real vocabulary XLA's
+TPU ``top_k`` is a key+index sort of all ``V`` columns (6.2 ms a step at
+``[32, 151936]``, the largest device operation of a decode step; PERF.md
+section 6, PR 30). It takes the maximum of each group of ``g`` contiguous
+columns, the ``k`` best groups, and the ``k`` best of those groups' columns.
+``g`` follows from the static ``(V, k)`` (``_group_width``); a small
+vocabulary takes ``lax.top_k`` itself. Every selection over the vocabulary in
+a step program goes through it: the sampler's candidates, the speculative
+verifier's, and the engine's top-logprob alternatives (the first columns of
+the same candidates), so a step holds one selection and no full-vocabulary
+sort (``engine/program_check.vocab_sorts`` holds that line).
 """
 
 from __future__ import annotations
@@ -162,6 +176,68 @@ def penalty_window_entries(prompt_ids: jnp.ndarray, prompt_valid: jnp.ndarray,
     return eligible & (pen_n[:, None] + rank < W)
 
 
+# columns per group of the two-stage selection: one lane tile. Timed on a
+# v5e in the sampling tail at k = 64 (PERF.md section 6, PR 30): at
+# [32, 151936] widths 32 / 64 / 128 / 256 / 512 / 1024 take 0.50 / 0.33 /
+# 0.31 / 0.48 / 0.91 / 1.81 ms where lax.top_k's sort takes 6.09; 64 and
+# 128 stay within 0.07 ms of each other down to V = 32,000 and up to 128
+# rows, so one width serves every (V, k)
+GROUP_WIDTH = 128
+
+
+def _group_width(V: int, k: int) -> int:
+    """Columns per group for ``top_candidates`` at a vocabulary of ``V``
+    and ``k`` wanted, or 0 where the selection is ``lax.top_k`` itself:
+    the second stage orders ``k * g`` candidates, so grouping pays only
+    while that is at most half of ``V`` (0.24 against 0.86 ms at
+    V = 32,000; the tests' toy vocabularies stay direct)."""
+    g = GROUP_WIDTH
+    return g if 2 * k * g <= V else 0
+
+
+def candidate_form(V: int, k: int = TOPK_MAX) -> str:
+    """Which form ``top_candidates`` takes at ``(V, k)`` — by default the
+    sampler's own selection at a vocabulary of ``V`` — for a start-up
+    span: ``grouped[G=1187,g=128]`` or ``direct``."""
+    g = _group_width(V, min(k, V))
+    return f"grouped[G={-(-V // g)},g={g}]" if g else "direct"
+
+
+def top_candidates(logits: jnp.ndarray, k: int):
+    """``jax.lax.top_k(logits, k)`` over the last axis — same values, same
+    indices, same order, ties to the lower index — without ordering an
+    axis of the vocabulary's length.
+
+    Exact, not approximate: under the total order (value descending,
+    index ascending) each of the ``k`` best columns lies in a group whose
+    best column is itself among the ``k`` best, so at most ``k`` groups
+    matter; contiguous groups order by their best column exactly as
+    ``top_k`` orders their maxima (a tie between maxima falls to the
+    lower group, which holds the lower indices). The chosen groups are
+    gathered in ascending order, so a tie between candidates again falls
+    to the lower vocabulary index. Pad columns (``-inf``, where ``g`` does
+    not divide ``V``) sit behind every real column of the last group and
+    are never returned.
+    """
+    *lead, V = logits.shape
+    g = _group_width(V, k)
+    if not g:
+        return jax.lax.top_k(logits, k)
+    with jax.named_scope("top_candidates"):
+        G = -(-V // g)
+        x = logits.reshape(-1, V)
+        if G * g != V:
+            x = jnp.pad(x, ((0, 0), (0, G * g - V)),
+                        constant_values=-jnp.inf)
+        x = x.reshape(-1, G, g)
+        _, groups = jax.lax.top_k(jnp.max(x, axis=-1), k)     # [R, k]
+        groups = jnp.sort(groups, axis=-1)
+        cand = jnp.take_along_axis(x, groups[:, :, None], axis=1)
+        vals, pos = jax.lax.top_k(cand.reshape(-1, k * g), k)
+        idx = jnp.take_along_axis(groups, pos // g, axis=1) * g + pos % g
+        return vals.reshape(*lead, k), idx.reshape(*lead, k)
+
+
 def _masked_candidates(logits: jnp.ndarray, temperature: jnp.ndarray,
                        top_k: jnp.ndarray, top_p: jnp.ndarray,
                        min_p: Optional[jnp.ndarray] = None):
@@ -176,7 +252,7 @@ def _masked_candidates(logits: jnp.ndarray, temperature: jnp.ndarray,
     """
     R, V = logits.shape
     k = min(TOPK_MAX, V)
-    top_vals, top_idx = jax.lax.top_k(logits, k)          # [R, k]
+    top_vals, top_idx = top_candidates(logits, k)         # [R, k]
 
     ranks = jnp.arange(k)[None, :]                        # [1, k]
     eff_k = jnp.where(top_k > 0, jnp.minimum(top_k, k), k)  # [R]
@@ -368,4 +444,5 @@ def spec_verify(logits: jnp.ndarray, tokens: jnp.ndarray, rng: jax.Array,
 
 __all__ = ["SamplingParamsBatch", "sample_tokens", "apply_penalties",
            "apply_vocab_mask", "update_penalty_window",
-           "penalty_window_entries", "spec_verify", "TOPK_MAX"]
+           "penalty_window_entries", "spec_verify", "top_candidates",
+           "candidate_form", "TOPK_MAX"]

@@ -530,16 +530,17 @@ class StartupTrace:
         self._last: Optional[tuple] = None
 
     @contextlib.contextmanager
-    def stage(self, name: str) -> Iterator[None]:
-        t0 = time.time()
+    def stage(self, name: str) -> Iterator[Dict[str, Any]]:
+        """Yields the stage's attributes, for the block to fill in."""
+        t0, attrs = time.time(), {}
         try:
-            yield
+            yield attrs
         finally:
-            self.stages.append((name, t0, time.time()))
+            self.stages.append((name, t0, time.time(), attrs))
 
     def stage_since_start(self, name: str) -> None:
         """A stage that began with the process (the imports)."""
-        self.stages.append((name, self.start_unix, time.time()))
+        self.stages.append((name, self.start_unix, time.time(), {}))
 
     def stage_until_ready(self, name: str) -> None:
         """The last stage: from now until ``finish``."""
@@ -555,10 +556,11 @@ class StartupTrace:
         root.start_unix = self.start_unix
         end = time.time()
         if self._last is not None:
-            self.stages.append(self._last + (end,))
+            self.stages.append(self._last + (end, {}))
             self._last = None
-        for name, t0, t1 in self.stages:
-            tracer.record(name, t0, t1, parent=root)
+        for name, t0, t1, stage_attrs in self.stages:
+            tracer.record(name, t0, t1, parent=root,
+                          attrs=stage_attrs or None)
         root.finish(end_unix=end)
 
 

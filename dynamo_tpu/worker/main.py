@@ -178,13 +178,17 @@ def arm_guided(engine, card) -> None:
 def build_engine(args: argparse.Namespace, startup=None) -> JaxEngine:
     """``startup`` (a ``utils/tracing.StartupTrace``) gets the two stages
     of the build: ``startup.weights`` (configuration, mesh, parameters)
-    and ``startup.engine`` (page pools, jit wrappers). Callers that keep
-    no startup trace (run.py, step followers) pass none."""
+    and ``startup.engine`` (page pools, jit wrappers; its attribute
+    ``sample.top_candidates`` says which form the sampler's selection
+    takes at this vocabulary). Callers that keep no startup trace (run.py,
+    step followers) pass none."""
+    from dynamo_tpu.ops.sampling import candidate_form
     from dynamo_tpu.utils.tracing import StartupTrace
     startup = startup or StartupTrace()
     with startup.stage("startup.weights"):
         cfg, engine_cfg, forward_fn, params = _build_weights(args)
-    with startup.stage("startup.engine"):
+    with startup.stage("startup.engine") as attrs:
+        attrs["sample.top_candidates"] = candidate_form(cfg.vocab_size)
         return JaxEngine(cfg, params, engine_cfg, forward_fn=forward_fn)
 
 

@@ -223,16 +223,19 @@ def test_tp_sharded_steps_compile_in_the_tpu_compiler():
     topology description, so the refusal is catchable here: the decode, the
     padded prefill and the token-packed step of a tp=2 engine at
     Llama-3.2-3B widths must compile, with no all-gather (the cache stays
-    sharded on Hkv) and no copy of a chip's slab of the pool."""
+    sharded on Hkv; nor may the sampler's grouped selection, which a
+    vocabulary of 32,768 engages, gather what the tail holds) and no copy
+    of a chip's slab of the pool."""
     import dataclasses
 
     from jax.experimental import topologies
     from jax.sharding import NamedSharding, PartitionSpec as P_
 
     from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
-    from dynamo_tpu.engine.program_check import pool_copies
+    from dynamo_tpu.engine.program_check import pool_copies, vocab_sorts
     from dynamo_tpu.models import llama
     from dynamo_tpu.models.config import ModelConfig
+    from dynamo_tpu.ops.sampling import candidate_form
     from dynamo_tpu.parallel.mesh import MeshSpec, make_mesh
     from dynamo_tpu.parallel.sharding import ModelSharding
 
@@ -242,7 +245,8 @@ def test_tp_sharded_steps_compile_in_the_tpu_compiler():
     except Exception as e:  # noqa: BLE001 — no libtpu in this install
         pytest.skip(f"no compile-only TPU topology: {e}")
     cfg = dataclasses.replace(ModelConfig.llama32_3b(), num_layers=2,
-                              vocab_size=1024)
+                              vocab_size=32768)
+    assert candidate_form(cfg.vocab_size).startswith("grouped")
     mesh = make_mesh(MeshSpec(tp=2), devices=topo.devices[:2])
     specs = ModelSharding(cfg, mesh).param_specs()
     abs_params = jax.eval_shape(
@@ -287,6 +291,66 @@ def test_tp_sharded_steps_compile_in_the_tpu_compiler():
         # write (engine/program_check.py)
         assert pool_copies(hlo, (L_, N_, two, Hkv_ // 2, ps_, Dh_),
                            eng.pages.dtype) == []
+        assert vocab_sorts(hlo, cfg.vocab_size) == []
+
+
+def test_the_sampling_tail_sorts_no_axis_of_the_vocabulary(monkeypatch):
+    """Every step program ends in ``JaxEngine._sample_tail`` (the fused
+    block in the same calls under its own scope). At Qwen3's ``[32,
+    151936]`` XLA's TPU pipeline turns the tail's two ``lax.top_k`` into
+    one key+index sort of all 151,936 columns (6.2 ms a step on the chip:
+    PERF.md section 6, PR 30); on ``ops/sampling.top_candidates`` the
+    compiled tail holds the sorts of the group maxima and of the gathered
+    candidates and nothing as long as the vocabulary."""
+    import dataclasses
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from dynamo_tpu.engine import jax_engine
+    from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
+    from dynamo_tpu.engine.program_check import vocab_sorts
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import ModelConfig
+    from dynamo_tpu.ops import sampling
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu in this install
+        pytest.skip(f"no compile-only TPU topology: {e}")
+    R, V = 32, 151936
+    cfg = dataclasses.replace(ModelConfig.tiny(), vocab_size=V)
+    eng = JaxEngine(cfg, jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0))),
+        JaxEngineConfig(num_pages=8, page_size=4, max_num_seqs=4,
+                        max_prefill_chunk=16, max_context=64))
+    assert eng.cfg.num_top_logprobs > 0      # the alternatives ride along
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def compiled_tail():
+        def tail(logits, rng, step, temperature, top_k, top_p):
+            return eng._sample_tail(logits, None, rng, step, temperature,
+                                    top_k, top_p)[1]
+        return jax.jit(tail).lower(
+            sds((R, V), jnp.float32), sds((2,), jnp.uint32),
+            sds((), jnp.int32), sds((R,), jnp.float32),
+            sds((R,), jnp.int32), sds((R,), jnp.float32)).compile().as_text()
+
+    hlo = compiled_tail()
+    assert vocab_sorts(hlo, V) == []
+    G = -(-V // sampling.GROUP_WIDTH)
+    assert vocab_sorts(hlo, G), "the sort of the group maxima is gone too"
+    # one selection serves the sampler and the alternatives: XLA merges
+    # the two calls (a second one would order the maxima twice)
+    assert len(vocab_sorts(hlo, G)) == 1
+    # the parent's tail, for the reader's sake: it has to see that sort
+    monkeypatch.setattr(sampling, "top_candidates", jax.lax.top_k)
+    monkeypatch.setattr(jax_engine, "top_candidates", jax.lax.top_k)
+    assert vocab_sorts(compiled_tail(), V)
 
 
 def test_deepseek_mla_forward_lowers_for_tpu():

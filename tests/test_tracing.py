@@ -560,8 +560,10 @@ def test_startup_trace_runs_from_process_start_to_ready():
     startup = StartupTrace()
     startup.stage_since_start("startup.imports")
     for name in STARTUP_STAGES[1:4]:
-        with startup.stage(name):
+        with startup.stage(name) as stage_attrs:
             time.sleep(0.002)
+            if name == "startup.engine":
+                stage_attrs["sample.top_candidates"] = "direct"
     startup.stage_until_ready("startup.register")
     time.sleep(0.002)
     t = Tracer(service="worker", capacity=4)
@@ -575,6 +577,9 @@ def test_startup_trace_runs_from_process_start_to_ready():
     kids = [s for s in rec["spans"] if s["name"] != "startup"]
     assert [s["name"] for s in kids] == STARTUP_STAGES     # in order
     assert all(s["parent_span_id"] == root["span_id"] for s in kids)
+    # what a stage's block wrote into its attributes is on its span
+    assert [s.get("attrs") or {} for s in kids] == [
+        {}, {}, {"sample.top_candidates": "direct"}, {}, {}]
     assert kids[0]["start_unix"] == root["start_unix"]
     for a, b in zip(kids, kids[1:]):
         assert a["end_unix"] <= b["start_unix"]
@@ -621,5 +626,7 @@ async def test_worker_writes_its_startup_trace_when_ready(tmp_path):
     end = rec["start_unix"] + rec["duration_s"]
     assert end <= t_ready + 0.1 and kids[-1]["end_unix"] == \
         pytest.approx(end, abs=1e-3)
+    # which form the sampler's selection takes at this vocabulary (512)
+    assert kids[2]["attrs"] == {"sample.top_candidates": "direct"}
     # the stages cover the start-up: imports and the engine build dominate
     assert sum(s["duration_s"] for s in kids) >= 0.8 * rec["duration_s"]
