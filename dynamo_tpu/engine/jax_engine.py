@@ -246,6 +246,10 @@ def _token_bucket(n: int, lo: int) -> int:
 class JaxEngine(ScheduledEngineBase):
     """Continuous-batching paged-KV engine over a jax Llama-family model."""
 
+    # jax dispatch is asynchronous: dispatch_decode / dispatch_multistep
+    # return once the program is enqueued (engine/loop.py)
+    dispatch_head_start_s = 0.02
+
     def __init__(self, model_cfg: ModelConfig, params,
                  config: Optional[JaxEngineConfig] = None,
                  forward_fn: Optional[Callable] = None):
@@ -489,17 +493,26 @@ class JaxEngine(ScheduledEngineBase):
         # change instead of rebuilding + re-uploading the padding every
         # step — (key, versions, np table, device table)
         self._table_cache: Optional[Tuple] = None
-        # MoE dispatch overflow accounting (VERDICT r4 weak 5): per-step
-        # device scalars queue here; stats() drains them into the total.
-        # Only the dispatch backend can drop — dense configs emit a
-        # constant-zero aux we never enqueue.
-        self._pending_moe_drops: list = []
-        self._moe_dropped_total = 0
+        # expert-layer accounting: every dispatch of a MoE family returns
+        # its counts as device scalars through ``aux`` — experts touched
+        # and assignments from the grouped layer, dropped assignments from
+        # the dispatch backend (VERDICT r4 weak 5). They queue here;
+        # stats() and the metrics scrape drain them into the totals, so
+        # the hot loop never pays a host round trip for them.
+        self._pending_moe_aux: list = []
+        self.moe_totals: Dict[str, int] = {
+            "moe_dropped_assignments": 0, "moe_experts_touched": 0,
+            "moe_assignments": 0}
         # appends happen on the step worker thread, drains on either that
         # thread (the >512 cap) or the event-loop thread (stats scrape)
-        self._moe_drops_lock = threading.Lock()
-        self._moe_dispatch_active = (
-            getattr(model_cfg, "moe_backend", "") == "dispatch")
+        self._moe_aux_lock = threading.Lock()
+        # what "every expert of every expert layer" counts to in one
+        # forward pass: the denominator of the touched share
+        self._moe_slots_per_step = int(
+            getattr(model_cfg, "num_experts", 0)
+            * (model_cfg.num_layers
+               - getattr(model_cfg, "first_k_dense_replace", 0)))
+        self.moe_expert_slots = 0
         # compile-event detection (engine/steptrace.py): the first call on
         # a fresh (jit program, B, S) bucket ALWAYS traces+compiles, so
         # its dispatch wall IS the compile cost — no threshold guessing.
@@ -583,6 +596,9 @@ class JaxEngine(ScheduledEngineBase):
         # the markers the gemma and deepseek forwards look for
         per_shard.supports_window_softcap = True
         per_shard.pallas_paged_kernel = True
+        # ... and the one that keeps the expert layer's own Mosaic kernel
+        # off a mesh (models/moe.grouped_on_chip)
+        per_shard.per_shard = True
         return per_shard
 
     # -- guided decoding ---------------------------------------------------
@@ -1051,13 +1067,11 @@ class JaxEngine(ScheduledEngineBase):
                 # no-ops EOS); dead rows freeze
                 gstate = jnp.where(alive, gt["trans"][gstate, sampled],
                                    gstate)
-            drops = aux.get("moe_dropped_assignments",
-                            jnp.zeros((), jnp.int32))
             return ((pages, tok, pos, total, new_alive,
-                     pids, pcnt, pctx, pbias, pn, gstate), (packed, drops))
+                     pids, pcnt, pctx, pbias, pn, gstate), (packed, aux))
 
         (pages, tok, pos, total, alive, pids, pcnt, pctx, pbias, pn,
-         gstate), (steps, drops) = jax.lax.scan(
+         gstate), (steps, aux) = jax.lax.scan(
             body, (pages, tok, pos, total, alive,
                    pids0, pcnt0, pctx0, pbias0, pn0, gstate0),
             jnp.arange(n_steps, dtype=jnp.int32))
@@ -1067,7 +1081,7 @@ class JaxEngine(ScheduledEngineBase):
                  "pids": pids, "pcnt": pcnt, "pctx": pctx, "pbias": pbias,
                  "pn": pn, "gstate": gstate}
         return (pages, jnp.moveaxis(steps, 0, 1), carry,
-                jnp.sum(drops.astype(jnp.int32)))
+                {k: jnp.sum(v.astype(jnp.int32)) for k, v in aux.items()})
 
     def _get_jit_multistep(self, w: int):
         fn = self._jit_ms.get(w)
@@ -1977,18 +1991,13 @@ class JaxEngine(ScheduledEngineBase):
         _ckey = (id(fn), B, w, pcarry is not None)
         _fresh = _ckey not in self._jit_seen
         _t0 = time.perf_counter() if _fresh else 0.0
-        self.pages, packed_block, carry, drops = fn(
+        self.pages, packed_block, carry, aux = fn(
             self.params, self.pages, jnp.asarray(tok), jnp.asarray(pos),
             jnp.asarray(table), jnp.asarray(total), jnp.asarray(alive),
             jnp.asarray(budget), jnp.asarray(min_gate), self._rng,
             np.int32(self._step_counter), samp["temp"], samp["top_k"],
             samp["top_p"], samp["stop_ids"], samp["pen"], pcarry)
-        if self._moe_dispatch_active:
-            with self._moe_drops_lock:
-                self._pending_moe_drops.append(drops)
-                overflow = len(self._pending_moe_drops) > 512
-            if overflow:
-                self._drain_moe_drops(keep_last=8)
+        self._queue_moe_aux(aux, steps=w)
         # one rng-fold key per fused step: the counter advances by the
         # block width so fused and per-step runs consume the same keys
         self._step_counter += w
@@ -2024,7 +2033,7 @@ class JaxEngine(ScheduledEngineBase):
             # first dispatch at this (B, w) is not misreported as a
             # mid-run compile event
             self._jit_seen.add((id(fn), B, w, False))
-            self.pages, out, _carry, _drops = fn(
+            self.pages, out, _carry, _aux = fn(
                 self.params, self.pages,
                 jnp.zeros((B, 1), jnp.int32), jnp.zeros((B, 1), jnp.int32),
                 jnp.zeros((B, P), jnp.int32), jnp.ones(B, jnp.int32),
@@ -2159,17 +2168,7 @@ class JaxEngine(ScheduledEngineBase):
                 jnp.asarray(a["pos"]), self._step_table(a, kind, seqs),
                 jnp.asarray(a["total"]), jnp.asarray(a["new"]),
                 self._rng, np.int32(step), temp, top_k, top_p, pen)
-        if self._moe_dispatch_active and "moe_dropped_assignments" in aux:
-            # device scalar; fetched lazily at stats-scrape time so the hot
-            # loop never pays an extra host round trip
-            with self._moe_drops_lock:
-                self._pending_moe_drops.append(
-                    aux["moe_dropped_assignments"])
-                overflow = len(self._pending_moe_drops) > 512
-            if overflow:
-                # bounded memory: drain all but the freshest few (those may
-                # still be in flight; everything older has long completed)
-                self._drain_moe_drops(keep_last=8)
+        self._queue_moe_aux(aux)
         # the slots the device computed and the step program with its
         # bucket, as the ring names them: a packed step pays for its T
         # slots whatever its rows
@@ -2234,33 +2233,58 @@ class JaxEngine(ScheduledEngineBase):
             return self._table_arrays(seqs, a["pos"].shape[0])[1]
         return jnp.asarray(a["table"])
 
-    def _drain_moe_drops(self, keep_last: int = 0) -> None:
+    def _queue_moe_aux(self, aux: dict, steps: int = 1) -> None:
+        """One dispatch's expert-layer counts (device scalars; nothing
+        for a dense family), of ``steps`` forward passes."""
+        if not aux:
+            return
+        if "moe_experts_touched" in aux:
+            self.moe_expert_slots += steps * self._moe_slots_per_step
+            # for this dispatch's ring record (loop._stamp_dispatch)
+            self.last_experts_touched = aux["moe_experts_touched"]
+        with self._moe_aux_lock:
+            self._pending_moe_aux.append(aux)
+            overflow = len(self._pending_moe_aux) > 512
+        if overflow:
+            # bounded memory: drain all but the freshest few (those may
+            # still be in flight; everything older has long completed)
+            self._drain_moe_aux(keep_last=8)
+
+    def _drain_moe_aux(self, keep_last: int = 0) -> None:
         # swap the list out under the lock (appends race from the step
         # worker thread, scrapes from the event loop); the device transfer
         # runs OUTSIDE it so a slow fetch never blocks the step thread
-        with self._moe_drops_lock:
-            if len(self._pending_moe_drops) <= keep_last:
+        with self._moe_aux_lock:
+            if len(self._pending_moe_aux) <= keep_last:
                 return
-            split = len(self._pending_moe_drops) - keep_last
-            done = self._pending_moe_drops[:split]
-            self._pending_moe_drops = self._pending_moe_drops[split:]
+            split = len(self._pending_moe_aux) - keep_last
+            done = self._pending_moe_aux[:split]
+            self._pending_moe_aux = self._pending_moe_aux[split:]
         # ONE batched transfer, not a device_get per scalar
-        total = int(sum(int(x) for x in jax.device_get(done)))
-        with self._moe_drops_lock:
-            self._moe_dropped_total += total
+        sums: Dict[str, int] = {}
+        for aux in jax.device_get(done):
+            for k, v in aux.items():
+                sums[k] = sums.get(k, 0) + int(v)
+        with self._moe_aux_lock:
+            for k, v in sums.items():
+                self.moe_totals[k] = self.moe_totals.get(k, 0) + v
 
-    def moe_dropped_total(self) -> int:
-        """Cumulative MoE dispatch overflow count (token-expert assignments
-        whose combine weight was zeroed). Drains every pending per-step
-        scalar — called from the stats scrape path, where blocking on at
-        most the one in-flight step is acceptable."""
-        self._drain_moe_drops(keep_last=0)
-        with self._moe_drops_lock:
-            return self._moe_dropped_total
+    def moe_counts(self) -> Dict[str, int]:
+        """Cumulative expert-layer counts: assignments routed, experts
+        touched and the expert slots they were touched out of (grouped
+        layer), assignments dropped past capacity (dispatch backend).
+        Drains every pending per-step scalar — called from the stats and
+        metrics scrape paths, where blocking on at most the one in-flight
+        step is acceptable."""
+        self._drain_moe_aux(keep_last=0)
+        with self._moe_aux_lock:
+            return dict(self.moe_totals,
+                        moe_expert_slots=self.moe_expert_slots)
 
     def stats(self):
         m = super().stats()
-        m.worker_stats.moe_dropped_tokens = self.moe_dropped_total()
+        m.worker_stats.moe_dropped_tokens = self.moe_counts()[
+            "moe_dropped_assignments"]
         return m
 
     # -- page IO (KV transfer / KVBM tier moves) ---------------------------

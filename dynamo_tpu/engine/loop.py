@@ -62,6 +62,7 @@ logger = logging.getLogger(__name__)
 MIGRATION_KEY = "migration"
 
 
+
 def migration_token(out: "LLMEngineOutput") -> Optional[dict]:
     """The migration/resume token on a frame, or None for ordinary
     frames — the one place the frame shape is interpreted (engine loop,
@@ -137,6 +138,9 @@ class ScheduledEngineBase(EngineBase):
         self.steptrace = get_step_recorder()
         self.last_padded: Optional[Tuple[int, int]] = None
         self.last_program = ""
+        # ... and, from a MoE family's step programs, the experts the
+        # dispatch touched: a device scalar until the result is fetched
+        self.last_experts_touched: Any = None
         self._last_dispatch_end: Optional[float] = None
 
     # -- subclass hook -----------------------------------------------------
@@ -179,6 +183,13 @@ class ScheduledEngineBase(EngineBase):
     # carry. fetch_packed_block blocks on a handle and returns
     # (sampled [B, w], logprobs [B, w], extras) aligned with plan.seqs.
     supports_multistep = False
+
+    # how long the loop's thread waits for an asynchronous dispatch to
+    # return before it does anything else (``steptrace.Phase.in_thread``).
+    # For an engine whose dispatch only ENQUEUES a program (JaxEngine: 3-4
+    # ms for one called before; a first call compiles and is awaited past
+    # this); 0 where the dispatch itself takes the step's time (mocker)
+    dispatch_head_start_s = 0.0
 
     @property
     def multistep_unsupported_reason(self) -> Optional[str]:
@@ -258,9 +269,10 @@ class ScheduledEngineBase(EngineBase):
                 pool_pinned=mgr.pinned_pages if mgr is not None else 0,
                 plan_ms=plan_ms, dispatch_ms=dispatch.ms,
                 gap_ms=gap_ms, fallback=fallback, chained=chained,
-                enqueue=dispatch.t0)
+                enqueue=dispatch.t0, experts=self.last_experts_touched)
         self.last_padded = None
         self.last_program = ""
+        self.last_experts_touched = None
         for ev in self.drain_compile_events():
             st.note_compile(ev.get("kind", kind), ev["seconds"], rec)
             for seq in seqs:
@@ -863,7 +875,13 @@ class ScheduledEngineBase(EngineBase):
                 fn, args = self._execute_plan, (plan,)
             dispatch = st.phase("dispatch", seq, kind)
             try:
-                out = await dispatch.in_thread(fn, *args)
+                # the device idles until this program is enqueued: an
+                # asynchronous dispatch gets a head start over the frames
+                # the last result put out (``Phase.in_thread``)
+                out = await dispatch.in_thread(
+                    fn, *args,
+                    head_start=(self.dispatch_head_start_s
+                                if asynchronous else 0.0))
             except Exception as e:  # noqa: BLE001 — engine must not die silently
                 self._fail_plan(plan, e)
                 continue

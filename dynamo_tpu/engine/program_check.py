@@ -19,6 +19,11 @@ section 6, PR 30: 19 % of the cell's time), which
 ``ops/sampling.top_candidates`` replaces where the vocabulary is large
 enough to pay for it. ``vocab_sorts`` lists the instructions of an
 optimised HLO text that order an axis as long as the vocabulary.
+
+The third is the expert layer's: ``expert_temporaries`` lists the
+instructions that write a ``[tokens, experts, expert width]`` array, which
+the mask form of a mixture layer does and the grouped one must not
+(``tests/test_moe_grouped.py``).
 Used by
 ``tests/test_kv_write.py`` and ``tests/test_sampling_topk.py`` (toy
 models, CPU),
@@ -101,6 +106,24 @@ def vocab_sorts(hlo_text: str, vocab: int) -> List[str]:
         if (m and (m.group(1) == "sort"
                    or 'custom_call_target="TopK"' in line)
                 and any(a.strip() in wide for a in m.group(2).split(","))):
+            found.append(line.strip())
+    return found
+
+
+def expert_temporaries(hlo_text: str, tokens: int, experts: int,
+                       width: int) -> List[str]:
+    """Instructions of an optimised HLO module that write an array with a
+    token, an expert and an expert-width axis at once - ``[T, E, I]`` in
+    any order: what computing every expert on every token materialises
+    (13 GB at 64 experts over ``[64, 1024]`` slots; PERF.md section 4) and
+    the grouped expert layer (``models/moe.grouped_experts``) never does.
+    One line each, as printed."""
+    want = sorted((tokens, experts, width))
+    found = []
+    for line in hlo_text.splitlines():
+        m = _INSTR.match(line)
+        if m and m.group(4) and sorted(
+                int(d) for d in m.group(4).split(",")) == want:
             found.append(line.strip())
     return found
 
@@ -192,5 +215,5 @@ def check_step_programs(engine, batch: int, chunk: int, width: int = 8,
     return out
 
 
-__all__ = ["pool_copies", "vocab_sorts", "step_programs",
-           "check_step_programs"]
+__all__ = ["pool_copies", "vocab_sorts", "expert_temporaries",
+           "step_programs", "check_step_programs"]

@@ -302,6 +302,14 @@ class SchedulerConfig:
     guided_fuse_check: Optional[Callable] = None
 
 
+def _penalized(so) -> bool:
+    """Does the row keep a device penalty window (penalties or a bias)?"""
+    return bool(so.frequency_penalty or so.presence_penalty or so.logit_bias
+                or (so.repetition_penalty is not None
+                    and so.repetition_penalty > 0
+                    and so.repetition_penalty != 1.0))
+
+
 class Scheduler:
     """Chunked-prefill continuous batching over a :class:`PageAllocator`."""
 
@@ -903,12 +911,8 @@ class Scheduler:
         and ship to the device (seeded draws key on token position, not
         step)."""
         so = seq.request.sampling_options
-        rep_on = (so.repetition_penalty is not None
-                  and so.repetition_penalty > 0
-                  and so.repetition_penalty != 1.0)
         cap = 1 << 20
-        if so.frequency_penalty or so.presence_penalty or rep_on \
-                or so.logit_bias:
+        if _penalized(so):
             W = self.cfg.penalty_window
             if W <= 0:
                 return "penalties", cap
@@ -933,14 +937,17 @@ class Scheduler:
         return None, cap
 
     def _grow_for_block(self, seqs: List[Sequence], start_lens: List[int],
-                        width: int) -> bool:
-        """Allocate every page a ``width``-step block will write
-        (positions ``sl-1 .. sl+width-2`` per row) up front. No preemption
-        on this path — the caller narrows the width instead; pages
-        allocated before a failure stay with their sequences (they are the
-        very next pages those rows use anyway, as ``_spec_plan``)."""
-        for seq, sl in zip(seqs, start_lens):
-            need = self._pages_needed(sl + width - 1) - len(seq.page_ids)
+                        writes: List[int]) -> bool:
+        """Allocate every page the block will write (row i writes
+        ``writes[i]`` positions from ``start_lens[i] - 1``; a row that is
+        dead from block start writes none) up front. No preemption on this
+        path — the caller narrows the width instead; pages allocated
+        before a failure stay with their sequences (they are the very next
+        pages those rows use anyway, as ``_spec_plan``)."""
+        for seq, sl, n in zip(seqs, start_lens, writes):
+            if n <= 0:
+                continue
+            need = self._pages_needed(sl + n - 1) - len(seq.page_ids)
             if need > 0:
                 try:
                     seq.page_ids.extend(self.alloc.allocate(need))
@@ -949,19 +956,47 @@ class Scheduler:
                     return False
         return True
 
+    def _max_new(self, seq: Sequence) -> Optional[int]:
+        sc = seq.request.stop_conditions
+        if sc.max_tokens is not None:
+            return sc.max_tokens
+        return (self.max_context_hint - seq.num_prompt
+                if self.max_context_hint else None)
+
+    def _spent(self, seq: Sequence) -> bool:
+        """Did this finished row end because its token budget ran out? Then
+        the device's budget carry ran out at the same token (one rule on
+        both sides), and a chained block may keep the row as a dead one.
+        Rows the host alone ended (cancelled, stop strings, errors) are
+        alive on the device and never ride; constrained rows keep
+        per-request device state that ``release_request`` drops."""
+        so = seq.request.sampling_options
+        if so.guided or _penalized(so):
+            return False
+        max_new = self._max_new(seq)
+        return ((max_new is not None and len(seq.generated) >= max_new)
+                or (self.max_context_hint is not None
+                    and len(seq) >= self.max_context_hint))
+
     def _plan_block(self, seqs: List[Sequence], start_lens: List[int],
                     chained: bool) -> Optional[MultiStepBatch]:
-        """Compute the safe fuse width for one block over ``seqs`` and
-        allocate its pages, or None to fall back to the per-step path.
+        """Compute the fuse width for one block over ``seqs`` and allocate
+        its pages, or None to fall back to the per-step path.
 
-        The width is the min over rows of: the configured cap
-        (``decode_multistep``), the row's remaining token budget
-        (max_tokens / max_context — a row that deterministically finishes
-        in <2 steps isn't worth a block), and the stop-string lookback for
-        rows with detokenizer-level stop strings; then rounded DOWN to a
-        power of two (bounded compile count), then narrowed further if
-        page pressure refuses the up-front allocation — so the fused
-        program never needs mid-block page allocation. Penalized / biased
+        The width is the configured cap (``decode_multistep``), narrowed
+        to what the row with the MOST tokens left can still use
+        (max_tokens / max_context: a batch that ends in <2 steps isn't
+        worth a block) and, for rows with detokenizer-level stop strings,
+        to the stop-string lookback; then rounded DOWN to a power of two
+        (bounded compile count), then narrowed further if page pressure
+        refuses the up-front allocation — so the fused program never
+        needs mid-block page allocation. A row with fewer tokens left
+        than the width does NOT narrow it: the device stops the row at
+        its budget and masks it for the rest of the block, so a stream
+        that ends takes no other row through narrower, unchained blocks.
+        In a chained block a row that ends inside the block still in
+        flight (or ended by its budget, ``_spent``) rides as a dead row:
+        the device carry has it dead from block start. Penalized / biased
         rows additionally cap the width by their remaining device
         penalty-window capacity (``_fuse_gate``); spec-decode mode and
         rows the gate cannot admit (no penalty window configured,
@@ -973,10 +1008,15 @@ class Scheduler:
         if self.cfg.spec_tokens > 0:
             self.record_fallback("spec", seqs)
             return None
-        w = cap
+        w, most = cap, 0
         budgets: List[int] = []
         min_gates: List[int] = []
         for seq, sl in zip(seqs, start_lens):
+            if seq.phase is not Phase.RUNNING:
+                # chained only (``plan_multistep_chained`` let it in)
+                budgets.append(0)
+                min_gates.append(0)
+                continue
             reason, row_cap = self._fuse_gate(seq, sl)
             if reason is not None:
                 self.record_fallback(reason, seqs)
@@ -984,22 +1024,23 @@ class Scheduler:
             w = min(w, row_cap)
             sc = seq.request.stop_conditions
             gen_eff = len(seq.generated) + (sl - len(seq))
-            max_new = sc.max_tokens if sc.max_tokens is not None else (
-                self.max_context_hint - seq.num_prompt
-                if self.max_context_hint else None)
+            max_new = self._max_new(seq)
             rem = (max_new - gen_eff) if max_new is not None else 1 << 20
             if self.max_context_hint is not None:
                 rem = min(rem, self.max_context_hint - sl)
-            if rem < 2:
-                self.record_fallback("budget", seqs)
-                return None
-            w = min(w, rem)
-            if sc.stop:
+            rem = max(rem, 0)   # chained: it ends inside the block in flight
+            most = max(most, rem)
+            if sc.stop and rem:
                 w = min(w, max(1, self.cfg.stop_str_lookback))
             budgets.append(min(rem, 1 << 20))  # int32-safe device budget
             min_gates.append(max(0, (sc.min_tokens or 0) - gen_eff))
+        if most < 2:
+            self.record_fallback("budget", seqs)
+            return None
+        w = min(w, most)
         w = 1 << (w.bit_length() - 1)
-        while w >= 2 and not self._grow_for_block(seqs, start_lens, w):
+        while w >= 2 and not self._grow_for_block(
+                seqs, start_lens, [min(w, b) for b in budgets]):
             w //= 2
         if w < 2:
             self.record_fallback("pages", seqs)
@@ -1037,17 +1078,29 @@ class Scheduler:
         effective row length is ``len(seq) + prev.width`` — positions and
         budgets are computed from that offset, and the device carry
         supplies the actual first token / liveness. Refused when the batch
-        may change (waiting/prefilling arrivals, any row finished or
-        cancelled per host knowledge). Unlike ``plan_multistep``, the
-        waiting/prefilling refusals survive the mixed-batch gate lift ON
-        PURPOSE: a chain break here is the block boundary where arrivals
-        get their admission/prefill (mixed) step — it is not a fallback
-        to per-step decode and is not counted as one."""
+        may change (waiting/prefilling arrivals, any row cancelled or
+        ended by the host alone). A row that ended because its budget ran
+        out (``_spent``) is dead in the device carry too and stays in the
+        chain as a dead row, while more than half of the rows live: the
+        end of one stream does not stall the others, and the chain breaks
+        where the next arrival is admitted anyway. Unlike
+        ``plan_multistep``, the waiting/prefilling refusals survive the
+        mixed-batch gate lift ON PURPOSE: a chain break here is the block
+        boundary where arrivals get their admission/prefill (mixed) step
+        — it is not a fallback to per-step decode and is not counted as
+        one."""
         if self.waiting:
             return None
+        live = 0
         for seq in prev.seqs:
-            if seq.phase is not Phase.RUNNING or seq.cancelled:
+            if seq.phase is Phase.RUNNING:
+                if seq.cancelled:
+                    return None
+                live += 1
+            elif not self._spent(seq):
                 return None
+        if 2 * live <= len(prev.seqs):
+            return None
         if any(s.phase is Phase.PREFILL for s in self.active.values()):
             return None
         return self._plan_block(prev.seqs,

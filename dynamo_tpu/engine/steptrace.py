@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
+import contextvars
 import os
 import sys
 import threading
@@ -106,10 +107,13 @@ class StepRecord:
               "tokens_real", "tokens_padded", "queue_depth", "running",
               "pool_free", "pool_pinned", "plan_ms", "dispatch_ms",
               "fetch_ms", "process_ms", "unpack_ms", "device_ms",
-              "ready_unix", "gap_ms", "compile_ms", "fallback", "chained")
+              "ready_unix", "gap_ms", "compile_ms", "fallback", "chained",
+              "experts_touched")
     # _enqueue: perf_counter at the start of the enqueue, kept until the
-    # result arrives and device_ms can be taken; not exported
-    __slots__ = FIELDS + ("_enqueue",)
+    # result arrives and device_ms can be taken; _experts: the dispatch's
+    # count of experts touched while it is still a device scalar; neither
+    # is exported
+    __slots__ = FIELDS + ("_enqueue", "_experts")
 
     def __init__(self) -> None:
         self.seq = -1
@@ -136,7 +140,11 @@ class StepRecord:
         self.compile_ms = 0.0
         self.fallback = ""
         self.chained = False
+        # experts the dispatch read, summed over its expert layers and
+        # steps (MoE families' grouped layer; 0 elsewhere)
+        self.experts_touched = 0
         self._enqueue = 0.0
+        self._experts = None
 
     def to_dict(self) -> Dict[str, Any]:
         return {s: getattr(self, s) for s in self.FIELDS}
@@ -208,9 +216,31 @@ class Phase:
             self.ready_unix = time.time()
         return out
 
-    async def in_thread(self, fn, *args):
+    async def in_thread(self, fn, *args, head_start: float = 0.0):
+        """``head_start`` seconds the loop's thread first WAITS for the
+        call (a dispatch that only enqueues a program returns in a few ms)
+        before it goes on to other work: otherwise the frames the loop has
+        just put out for its streams are serialised on this thread at the
+        same moment, the two take turns at the interpreter lock, and the
+        device waits for its next program twice as long. A call that
+        outlasts the head start (a first call compiles) is awaited as
+        without one."""
         with self:
-            return await asyncio.to_thread(self._call, fn, args)
+            if head_start <= 0.0:
+                return await asyncio.to_thread(self._call, fn, args)
+            returned = threading.Event()
+
+            def call():
+                try:
+                    return self._call(fn, args)
+                finally:
+                    returned.set()
+            # submitted here and now (``to_thread`` would start it only
+            # when this coroutine next yields)
+            pending = asyncio.get_running_loop().run_in_executor(
+                None, contextvars.copy_context().run, call)
+            returned.wait(head_start)
+            return await pending
 
 
 class StepRecorder:
@@ -266,10 +296,13 @@ class StepRecorder:
                pool_pinned: int = 0, plan_ms: float = 0.0,
                dispatch_ms: float = 0.0, gap_ms: float = 0.0,
                fallback: str = "", chained: bool = False,
-               enqueue: float = 0.0) -> Optional[StepRecord]:
+               enqueue: float = 0.0, experts: Any = None
+               ) -> Optional[StepRecord]:
         """Stamp one dispatch; returns the live ring slot (later patched
         by note_ready/note_unpack/note_compile) or None when disabled.
-        ``enqueue`` is the perf_counter at the start of the enqueue."""
+        ``enqueue`` is the perf_counter at the start of the enqueue;
+        ``experts`` the experts its expert layers touched, a device scalar
+        that ``note_ready`` reads once the result is on the host."""
         if not self.enabled:
             return None
         now = time.time()
@@ -300,7 +333,9 @@ class StepRecorder:
             rec.compile_ms = 0.0
             rec.fallback = fallback
             rec.chained = chained
+            rec.experts_touched = 0
             rec._enqueue = enqueue
+            rec._experts = experts
             if tokens_padded > 0:
                 o = self._occ.get(kind)
                 if o is None:
@@ -334,6 +369,9 @@ class StepRecorder:
             if h is None:
                 h = self._dur[rec.kind] = _Hist(_DUR_BOUNDS)
             h.observe(device_s)
+        if rec._experts is not None:
+            # the program has run, so the scalar is there to be read
+            rec.experts_touched, rec._experts = int(rec._experts), None
 
     def note_unpack(self, rec: Optional[StepRecord], fetch_ms: float,
                     process_ms: float) -> None:

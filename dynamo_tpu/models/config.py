@@ -19,6 +19,25 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, Optional
 
 
+def _topk_method(hf: Dict[str, Any], model_type: str) -> str:
+    """The MLA family's gate, from the config's own keys first:
+    ``topk_method``, else ``scoring_func`` (sigmoid scores are the
+    aux-loss-free ``noaux_tc`` gate's). Only a config that says neither
+    falls back on its name: HF's DeepseekV3Config serialises no
+    ``topk_method``. A pair the gates do not implement is an error, not a
+    silent softmax."""
+    scoring = hf.get("scoring_func")
+    method = hf.get("topk_method") or (
+        "noaux_tc" if scoring == "sigmoid" or (
+            scoring is None and model_type == "deepseek_v3") else "greedy")
+    want = "sigmoid" if method == "noaux_tc" else "softmax"
+    if scoring not in (None, want):
+        raise NotImplementedError(
+            f"topk_method {method!r} with scoring_func {scoring!r} (the "
+            f"{method} gate scores with {want})")
+    return method
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     vocab_size: int
@@ -41,11 +60,12 @@ class ModelConfig:
     num_experts_per_tok: int = 2
     moe_intermediate_size: int = 0
     norm_topk_prob: bool = True
-    # expert compute: "dense" runs every expert on every token (static
-    # shapes, fine at decode batch sizes); "dispatch" gathers each expert's
-    # routed tokens into a fixed-capacity buffer first, cutting expert
-    # FLOPs from E to ~k x capacity_factor per token (the wide-EP path)
-    moe_backend: str = "dense"
+    # expert compute: "grouped" sorts the assignments by expert and runs
+    # one grouped matmul over the groups that exist — exact, no capacity,
+    # no drop (models/moe.py grouped_experts); "dispatch" gathers each
+    # expert's routed tokens into a fixed-capacity buffer pinned to the ep
+    # axis and drops past capacity (the wide-EP path)
+    moe_backend: str = "grouped"
     # dispatch capacity per expert = ceil(T * k / E * this); tokens routed
     # past capacity are dropped (their combine weight is zero) — the
     # standard GShard/Switch overflow semantics
@@ -90,13 +110,22 @@ class ModelConfig:
     def kv_size(self) -> int:
         return self.num_kv_heads * self.head_dim
 
+    def __post_init__(self):
+        if self.moe_backend not in ("grouped", "dispatch"):
+            raise ValueError(
+                f"moe_backend {self.moe_backend!r}: 'grouped' (the exact "
+                "expert layer) or 'dispatch' (capacity-factor, wide-EP)")
+
     @classmethod
     def from_hf(cls, hf: Dict[str, Any], dtype: str = "bfloat16") -> "ModelConfig":
         heads = hf["num_attention_heads"]
         mt = hf.get("model_type", "llama")
         num_experts = hf.get("num_local_experts", hf.get("num_experts", 0)) or 0
         extra: Dict[str, Any] = {}
-        if mt.startswith("deepseek"):
+        # the MLA family is read off the keys that make it one, never off
+        # a model_type string: a public model with these keys under
+        # another name (joyai_llm_flash, ...) is the same architecture
+        if hf.get("kv_lora_rank"):
             num_experts = hf.get("n_routed_experts", 0) or 0
             extra = dict(
                 q_lora_rank=int(hf.get("q_lora_rank") or 0),
@@ -109,12 +138,7 @@ class ModelConfig:
                     hf.get("first_k_dense_replace") or 0),
                 routed_scaling_factor=float(
                     hf.get("routed_scaling_factor") or 1.0),
-                # V3 checkpoints route with the aux-loss-free sigmoid gate;
-                # HF's DeepseekV3Config does not serialize topk_method, so
-                # the model type implies it
-                topk_method=hf.get(
-                    "topk_method",
-                    "noaux_tc" if mt == "deepseek_v3" else "greedy"),
+                topk_method=_topk_method(hf, mt),
                 n_group=int(hf.get("n_group") or 1),
                 topk_group=int(hf.get("topk_group") or 1),
             )
@@ -135,7 +159,7 @@ class ModelConfig:
                 )
             elif rtype is not None:
                 raise NotImplementedError(
-                    f"deepseek rope_scaling type {rtype!r} (only yarn is "
+                    f"MLA rope_scaling type {rtype!r} (only yarn is "
                     "implemented)")
             extra["rope_interleave"] = bool(
                 hf.get("rope_interleave", True))

@@ -30,9 +30,10 @@ Attention in its **absorbed** inference form:
   with ``greedy`` / ``group_limited_greedy`` top-k (no renorm), and V3's
   aux-loss-free ``noaux_tc`` gate (sigmoid scores, e_score_correction_bias
   group selection, renormalized weights) — both scaled by
-  ``routed_scaling_factor``; routed experts compute densely or via the
-  capacity dispatch (``cfg.moe_backend``), plus the always-on shared
-  experts.
+  ``routed_scaling_factor``; routed experts run the one exact grouped
+  layer (``models/moe.grouped_experts``: sorted by expert, one grouped
+  matmul over the groups that exist, no drop) or, by ``cfg.moe_backend``,
+  the capacity dispatch, plus the always-on shared experts.
 
 Weight layout matches HF checkpoints after transpose; ``load_params``
 assembles the two layer stacks from safetensors.
@@ -139,6 +140,21 @@ def rope_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
 
 # ------------------------------------------------------------------- params
 
+def _randn_stack(key, n: int, shape: tuple, scale: float,
+                 dtype) -> jnp.ndarray:
+    """``[n, *shape]`` normal weights drawn a layer at a time inside one
+    program, each from its own split key, straight into ``dtype``: no
+    float32 copy of the whole stack ever exists (a stacked expert matrix
+    of 4 x 256 x 2048 x 768 is 6.4 GB in float32, twice over if drawn in
+    one call, beside the 11 GB the finished weights take)."""
+    @jax.jit
+    def draw(keys):
+        return jax.lax.map(
+            lambda k: (jax.random.normal(k, shape, jnp.float32)
+                       * scale).astype(dtype), keys)
+    return draw(jax.random.split(key, n))
+
+
 def _attn_leaves(cfg: ModelConfig, key, scale: float,
                  n: int) -> Dict[str, jnp.ndarray]:
     dtype = jnp.dtype(cfg.dtype)
@@ -147,8 +163,7 @@ def _attn_leaves(cfg: ModelConfig, key, scale: float,
     keys = iter(jax.random.split(key, 8))
 
     def randn(shape):
-        return (jax.random.normal(next(keys), (n,) + shape, jnp.float32)
-                * scale).astype(dtype)
+        return _randn_stack(next(keys), n, shape, scale, dtype)
 
     leaves = {
         "attn_norm": jnp.ones((n, H), dtype),
@@ -169,9 +184,34 @@ def _attn_leaves(cfg: ModelConfig, key, scale: float,
     return leaves
 
 
+# Standard deviation of the random weights, times sqrt(hidden): 0.012 at
+# the 2,048-wide models of this family, kept per fan-in so that toy widths
+# see activations of the same size. Chosen by measurement, not taken from
+# a paper (the DeepSeek papers' 0.006 and the other families' 0.02 are
+# the two ends that were tried first): see ``init_params``.
+INIT_GAIN = 0.012 * 2048 ** 0.5
+
+
 def init_params(cfg: ModelConfig, rng: jax.Array,
-                scale: float = 0.02) -> Params:
-    """Random init with the two-stack layer layout (tests/benchmarks)."""
+                scale: Optional[float] = None) -> Params:
+    """Random init with the two-stack layer layout (tests/benchmarks; the
+    benchmark's worker and its reference child both call this, so both
+    hold the same weights). Every stacked tensor is drawn a layer at a
+    time (``_randn_stack``), with standard deviation ``scale`` (default
+    ``INIT_GAIN / sqrt(hidden)``).
+
+    Why 0.012: renormalised sigmoid scores weigh the 8 chosen of 256
+    experts 1/8 each, and bfloat16 rounding swaps one at the top-k
+    boundary in some tokens against a float32 run. Swaps and real faults
+    both move log-probabilities as the square of the scale, so the scale
+    only places the benchmark's fixed 0.3 nats between them. On the chip
+    (PERF.md section 6, PR 34): at 0.02 a clean run reads 0.41 (refused);
+    at 0.006 a clean run 0.043, but every expert swapped for its neighbour
+    only 0.18 (passes); at 0.012 clean 0.145, one expert layer's routed
+    output dropped 0.37, experts swapped 0.78 (both refused). Experts in
+    3-bit mantissas read 0.22 there: the check does not see that."""
+    if scale is None:
+        scale = INIT_GAIN / cfg.hidden_size ** 0.5
     dtype = jnp.dtype(cfg.dtype)
     H, E = cfg.hidden_size, cfg.num_experts
     Im = cfg.moe_intermediate_size or cfg.intermediate_size
@@ -179,37 +219,36 @@ def init_params(cfg: ModelConfig, rng: jax.Array,
     M = cfg.num_layers - K
     k_dense, k_moe, k_embed, k_head = jax.random.split(rng, 4)
 
-    def randn(key, shape):
-        return (jax.random.normal(key, shape, jnp.float32) * scale) \
-            .astype(dtype)
-
     params: Params = {
-        "embed": randn(k_embed, (cfg.vocab_size, H)),
+        "embed": _randn_stack(k_embed, 1, (cfg.vocab_size, H), scale,
+                              dtype)[0],
         "final_norm": jnp.ones((H,), dtype),
     }
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = randn(k_head, (H, cfg.vocab_size))
+        params["lm_head"] = _randn_stack(
+            k_head, 1, (H, cfg.vocab_size), scale, dtype)[0]
     if K:
         dl = _attn_leaves(cfg, k_dense, scale, K)
         ks = iter(jax.random.split(jax.random.fold_in(k_dense, 1), 3))
-        dl["w_gate"] = randn(next(ks), (K, H, cfg.intermediate_size))
-        dl["w_up"] = randn(next(ks), (K, H, cfg.intermediate_size))
-        dl["w_down"] = randn(next(ks), (K, cfg.intermediate_size, H))
+        for leaf, shape in (("w_gate", (H, cfg.intermediate_size)),
+                            ("w_up", (H, cfg.intermediate_size)),
+                            ("w_down", (cfg.intermediate_size, H))):
+            dl[leaf] = _randn_stack(next(ks), K, shape, scale, dtype)
         params["dense_layers"] = dl
     if M:
         ml = _attn_leaves(cfg, k_moe, scale, M)
         ks = iter(jax.random.split(jax.random.fold_in(k_moe, 1), 8))
-        ml["w_router"] = randn(next(ks), (M, H, E))
+        ml["w_router"] = _randn_stack(next(ks), M, (H, E), scale, dtype)
         if cfg.topk_method == "noaux_tc":
             ml["router_bias"] = jnp.zeros((M, E), jnp.float32)
-        ml["w_gate"] = randn(next(ks), (M, E, H, Im))
-        ml["w_up"] = randn(next(ks), (M, E, H, Im))
-        ml["w_down"] = randn(next(ks), (M, E, Im, H))
+        for leaf, shape in (("w_gate", (E, H, Im)), ("w_up", (E, H, Im)),
+                            ("w_down", (E, Im, H))):
+            ml[leaf] = _randn_stack(next(ks), M, shape, scale, dtype)
         if cfg.n_shared_experts:
             Is = Im * cfg.n_shared_experts
-            ml["ws_gate"] = randn(next(ks), (M, H, Is))
-            ml["ws_up"] = randn(next(ks), (M, H, Is))
-            ml["ws_down"] = randn(next(ks), (M, Is, H))
+            for leaf, shape in (("ws_gate", (H, Is)), ("ws_up", (H, Is)),
+                                ("ws_down", (Is, H))):
+                ml[leaf] = _randn_stack(next(ks), M, shape, scale, dtype)
         params["moe_layers"] = ml
     return params
 
@@ -228,7 +267,8 @@ def _mla_qkv(cfg: ModelConfig, lp: Dict[str, jnp.ndarray], h: jnp.ndarray,
     eps = cfg.rms_norm_eps
     x = _rms_norm(h, lp["attn_norm"], eps)
     if cfg.q_lora_rank:
-        q = _rms_norm(x @ lp["wq_a"], lp["q_a_norm"], eps) @ lp["wq_b"]
+        with jax.named_scope("q_compress"):
+            q = _rms_norm(x @ lp["wq_a"], lp["q_a_norm"], eps) @ lp["wq_b"]
     else:
         q = x @ lp["wq"]
     q = q.reshape(B, S, nh, dn + dr)
@@ -414,12 +454,14 @@ def _gate_noaux(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
     sfc = scores + lp["router_bias"].astype(jnp.float32)   # [B,S,E]
     B, S, E = scores.shape
     g, k = cfg.n_group, cfg.num_experts_per_tok
-    group_scores = jnp.sum(
-        jax.lax.top_k(sfc.reshape(B, S, g, E // g), 2)[0], axis=-1)
-    _gv, gi = jax.lax.top_k(group_scores, cfg.topk_group)
-    group_mask = jnp.sum(jax.nn.one_hot(gi, g, dtype=sfc.dtype), axis=2)
-    score_mask = jnp.repeat(group_mask, E // g, axis=-1)
-    masked = jnp.where(score_mask > 0, sfc, 0.0)
+    masked = sfc
+    if g > 1:       # one group is no limit
+        group_scores = jnp.sum(
+            jax.lax.top_k(sfc.reshape(B, S, g, E // g), 2)[0], axis=-1)
+        _gv, gi = jax.lax.top_k(group_scores, cfg.topk_group)
+        group_mask = jnp.sum(jax.nn.one_hot(gi, g, dtype=sfc.dtype), axis=2)
+        score_mask = jnp.repeat(group_mask, E // g, axis=-1)
+        masked = jnp.where(score_mask > 0, sfc, 0.0)
     _w, top_i = jax.lax.top_k(masked, k)
     top_w = jnp.take_along_axis(scores, top_i, axis=-1)
     if cfg.norm_topk_prob:
@@ -428,40 +470,36 @@ def _gate_noaux(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
 
 
 def _moe_mlp(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
-             x: jnp.ndarray, ep_mesh=None
-             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Routed experts + shared experts. ``cfg.moe_backend`` picks the
-    routed compute: dense-mask (every expert, decode-batch default) or the
-    capacity-factor token dispatch (``models/moe.py expert_dispatch`` —
-    the wide-EP path that makes 256-expert DeepSeek-V3 credible).
-    Returns ``(out, dropped_assignments)`` (dropped is a static 0 on the
-    dense backend); ``ep_mesh`` pins dispatch buffers to the ep axis."""
-    top_w, top_i = _gate(cfg, lp, x)
-    dropped = jnp.zeros((), jnp.int32)
+             x: jnp.ndarray, ep_mesh=None, **kw
+             ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """Routed experts + shared experts. The routed part is the one exact
+    grouped layer (``models/moe.grouped_experts``; ``kw`` — the layer
+    index into stacked experts, the valid-slot mask, the kernel switch —
+    goes to it) unless ``cfg.moe_backend`` asks for the capacity-factor
+    token dispatch (``expert_dispatch``, the wide-EP path; ``ep_mesh``
+    pins its buffers to the ep axis). Returns ``(out, aux)``: the grouped
+    layer's counts or the dispatch's dropped assignments."""
+    from dynamo_tpu.models.moe import expert_dispatch, grouped_experts
+
+    B, S, H = x.shape
+    xt = x.reshape(B * S, H)
+    with jax.named_scope("route"):
+        top_w, top_i = _gate(cfg, lp, x)
+        top_w, top_i = top_w.reshape(B * S, -1), top_i.reshape(B * S, -1)
     if cfg.moe_backend == "dispatch":
-        from dynamo_tpu.models.moe import expert_dispatch
-        B, S, H = x.shape
         routed, dropped = expert_dispatch(
-            x.reshape(B * S, H), top_w.reshape(B * S, -1),
-            top_i.reshape(B * S, -1), lp["w_gate"], lp["w_up"],
-            lp["w_down"], cfg.num_experts,
-            cfg.moe_capacity_factor, ep_mesh=ep_mesh)
-        routed = routed.reshape(B, S, H).astype(x.dtype)
+            xt, top_w, top_i, lp["w_gate"], lp["w_up"], lp["w_down"],
+            cfg.num_experts, cfg.moe_capacity_factor, ep_mesh=ep_mesh)
+        aux = {"moe_dropped_assignments": dropped}
     else:
-        weights = jnp.sum(
-            jax.nn.one_hot(top_i, cfg.num_experts, dtype=jnp.float32)
-            * top_w[..., None], axis=2)                    # [B,S,E]
-        gate = jnp.einsum("bsh,ehi->bsei", x, lp["w_gate"])
-        up = jnp.einsum("bsh,ehi->bsei", x, lp["w_up"])
-        act = jax.nn.silu(gate) * up
-        routed = jnp.einsum("bse,bseh->bsh", weights.astype(x.dtype),
-                            jnp.einsum("bsei,eih->bseh", act,
-                                       lp["w_down"]))
+        routed, aux = grouped_experts(
+            xt, top_w, top_i, lp["w_gate"], lp["w_up"], lp["w_down"], **kw)
+    routed = routed.reshape(B, S, H).astype(x.dtype)
     if cfg.n_shared_experts:
-        shared = (jax.nn.silu(x @ lp["ws_gate"])
-                  * (x @ lp["ws_up"])) @ lp["ws_down"]
-        routed = routed + shared
-    return routed, dropped
+        with jax.named_scope("shared"):
+            routed = routed + (jax.nn.silu(x @ lp["ws_gate"])
+                               * (x @ lp["ws_up"])) @ lp["ws_down"]
+    return routed, aux
 
 
 def _dense_mlp(lp: Dict[str, jnp.ndarray], x: jnp.ndarray) -> jnp.ndarray:
@@ -517,12 +555,13 @@ def _attend(cfg: ModelConfig, lp, h, q_lat, q_pe, w_uv, positions,
 
 def _layer_step(cfg: ModelConfig, lp, h, positions, total_lens, new_lens,
                 page_table, pages, lidx, *, moe: bool,
-                use_pallas: bool = False, ep_mesh=None):
+                use_pallas: bool = False, ep_mesh=None, moe_kw=None):
     """One decoder layer against the stacked paged latent cache.
     ``use_pallas`` routes S==1 through the MLA Pallas decode kernel
     (``ops/pallas/mla_decode.py``) and S>1 through the prefill kernel
-    when the geometry supports them. Returns ``(h, pages,
-    dropped_assignments)``."""
+    when the geometry supports them; ``moe_kw`` goes to the grouped
+    expert layer. Returns ``(h, pages, aux)``, ``aux`` the expert layer's
+    counts (empty for a dense layer)."""
     # stage names for the device trace, as in models/llama.py
     with jax.named_scope("layer.attn_in"):
         q_lat, q_pe, c_kv, k_pe, w_uv = _mla_qkv(cfg, lp, h, positions)
@@ -536,11 +575,12 @@ def _layer_step(cfg: ModelConfig, lp, h, positions, total_lens, new_lens,
     with jax.named_scope("layer.moe" if moe else "layer.ffn"):
         x = _rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
         if moe:
-            mlp, dropped = _moe_mlp(cfg, lp, x, ep_mesh=ep_mesh)
+            mlp, aux = _moe_mlp(cfg, lp, x, ep_mesh=ep_mesh,
+                                **(moe_kw or {}))
         else:
-            mlp, dropped = _dense_mlp(lp, x), jnp.zeros((), jnp.int32)
+            mlp, aux = _dense_mlp(lp, x), {}
     h = h + mlp
-    return h, pages, dropped
+    return h, pages, aux
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -551,15 +591,20 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             logits_window: int = 1
             ) -> Tuple[jnp.ndarray, jnp.ndarray, dict]:
     """Scan forward (llama.forward contract plus the ``aux`` third return
-    carrying ``moe_dropped_assignments``, like models/moe.py). The GQA
+    carrying the expert layer's counts summed over layers, like
+    models/moe.py: ``moe_experts_touched`` and ``moe_assignments``, or the
+    dispatch backend's ``moe_dropped_assignments``). The GQA
     Pallas kernels the engine passes as ``attn_impl`` cannot run latent
     attention, so they are never CALLED here — but an impl carrying the
     ``pallas_paged_kernel`` marker (both stacked kernels set it) opts
     the family into its OWN latent kernels when the geometry supports it
     (kv_lora_rank % 128 == 0 — true for real V2/V3 checkpoints): S==1
     steps ride ``ops/pallas/mla_decode.py``, S>1 chunks
-    ``ops/pallas/mla_prefill.py``. Any other non-None impl is ignored
+    ``ops/pallas/mla_prefill.py``, and the expert layer its
+    ``moe_grouped`` kernel. Any other non-None impl is ignored
     (the XLA paths serve), matching gemma's marker pattern."""
+    from dynamo_tpu.models.moe import (grouped_on_chip, split_experts,
+                                       sum_aux)
     from dynamo_tpu.ops.pallas.mla_decode import supports as mla_supports
 
     use_pallas = (getattr(attn_impl, "pallas_paged_kernel", False)
@@ -567,16 +612,21 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     K = cfg.first_k_dense_replace
     with jax.named_scope("embed"):
         h = params["embed"][tokens]
-    total_dropped = jnp.zeros((), jnp.int32)
+    B, S = tokens.shape
+    aux = {}
 
-    def body(moe):
+    def body(moe, experts=None, **moe_kw):
         def step(carry, xs):
             h, pages = carry
             lp, lidx = xs
-            h, pages, dropped = _layer_step(
+            kw = {}
+            if experts:     # stacked, indexed by the MoE layer's number
+                lp, kw = {**lp, **experts}, dict(moe_kw, layer=lidx - K)
+            h, pages, aux = _layer_step(
                 cfg, lp, h, positions, total_lens, new_lens, page_table,
-                pages, lidx, moe=moe, use_pallas=use_pallas, ep_mesh=ep_mesh)
-            return (h, pages), dropped
+                pages, lidx, moe=moe, use_pallas=use_pallas, ep_mesh=ep_mesh,
+                moe_kw=kw)
+            return (h, pages), aux
         return step
 
     if K and "dense_layers" in params:
@@ -584,11 +634,15 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             body(False), (h, pages),
             (params["dense_layers"], jnp.arange(K)))
     if "moe_layers" in params:
-        (h, pages), drops = jax.lax.scan(
-            body(True), (h, pages),
-            (params["moe_layers"], K + jnp.arange(cfg.num_layers - K)))
-        total_dropped = jnp.sum(drops)
-    aux = {"moe_dropped_assignments": total_dropped}
+        scanned, experts = split_experts(cfg, params["moe_layers"])
+        # slots that hold no token (padding of a [B, S] step, dead rows
+        # of a fused block) route to no expert
+        valid = (jnp.arange(S)[None, :] < new_lens[:, None]).reshape(B * S)
+        (h, pages), aux = jax.lax.scan(
+            body(True, experts, valid=valid,
+                 use_pallas=grouped_on_chip(attn_impl)), (h, pages),
+            (scanned, K + jnp.arange(cfg.num_layers - K)))
+        aux = sum_aux(aux)
     with jax.named_scope("logits"):
         logits = _logits(cfg, params, h, new_lens, window=logits_window)
     return logits, pages, aux

@@ -7,14 +7,13 @@ swapping the dense MLP for routed experts:
 
 - router: softmax over expert logits, top-k selection, optional
   renormalization (``norm_topk_prob``).
-- two expert-compute backends, selected by ``cfg.moe_backend``:
-  "dense" computes every expert over every token with routing weights as a
-  mask — simple, fully static shapes, the right trade at decode batch
-  sizes (tens of tokens); "dispatch" (``moe_mlp_dispatch``) gathers each
-  expert's routed tokens into a fixed-capacity buffer first, cutting
-  expert FLOPs from E to ~k x capacity_factor per token — the wide-EP
-  path for large expert counts. Under GSPMD both shard the expert axis
-  over ``ep`` so each chip computes only its local experts.
+- one exact expert layer, ``grouped_experts``: assignments sorted by
+  expert, one grouped matmul over the groups that exist (the
+  ``moe_grouped`` Mosaic kernel on the chip, ``lax.ragged_dot`` elsewhere),
+  no capacity, no drop — what runs unless ``cfg.moe_backend`` says
+  "dispatch" (``moe_mlp_dispatch``), the capacity-factor wide-EP path that
+  pins fixed ``[E, C, H]`` buffers to the ``ep`` axis and drops past
+  capacity.
 
 Weight layout (stacked for scan): ``w_router [L, H, E]``,
 ``w_gate/w_up [L, E, H, I]``, ``w_down [L, E, I, H]``.
@@ -42,19 +41,145 @@ from dynamo_tpu.models.llama import (
 from dynamo_tpu.models import llama
 
 
+def _unsort(order: jnp.ndarray, values: jnp.ndarray) -> jnp.ndarray:
+    """``values`` (in sorted order) back in the order ``order`` sorted:
+    ``out[order[i]] = values[i]``, as one more sort - XLA's scatter costs
+    the TPU 70-80 ns an index (PERF.md section 6, PR 25), a sort of 65,536
+    keys far less."""
+    return jax.lax.sort((order, values), num_keys=1)[1]
+
+
+def grouped_experts(xt: jnp.ndarray, top_w: jnp.ndarray,
+                    top_i: jnp.ndarray, w_gate, w_up, w_down, *,
+                    layer=None, valid=None, use_pallas: bool = False
+                    ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """The exact expert layer every MoE family runs (routing-agnostic: the
+    caller brings its gate's ``top_w``/``top_i``). The ``T * k``
+    assignments are sorted by expert (stable), the tokens gathered into
+    that order, ONE grouped matmul over the groups that exist computes
+    gate/up and one the down projection, and each token sums its ``k``
+    expert outputs times the routing weights in float32. No capacity and
+    no drop: the result equals the every-expert-on-every-token mask form
+    up to summation order, at ``k / E`` of its FLOPs and without its
+    ``[T, E, I]`` temporaries. Sorts, searches and gathers only: no
+    scatter.
+
+    ``xt [T, H]``; ``top_w``/``top_i [T, k]``; weights ``[E, H, I]`` /
+    ``[E, I, H]``, or stacked ``[L, E, ...]`` with ``layer`` the (traced)
+    index — the stacked form is what a scan over layers hands over, so no
+    per-layer slice of the expert weights is ever materialised. ``valid
+    [T]`` bool masks slots that hold no token (padding of a ``[B, S]``
+    step, dead rows of a fused block): they route nowhere, cost nothing and
+    come back zero. The stages are named for the device trace (``sort``,
+    ``experts``, ``combine``, under the caller's ``layer.moe``).
+    ``use_pallas`` runs the grouped matmuls in the
+    ``moe_grouped`` Mosaic kernel (``ops/pallas/moe_grouped.py``), which
+    reads only the experts that own a row; otherwise ``lax.ragged_dot``,
+    the plain form (the CPU, meshes, widths the kernel cannot tile).
+
+    Returns ``(out [T, H] float32, aux)``; ``aux`` holds the counts the
+    step programs hand on: ``moe_experts_touched`` (experts with at least
+    one row) and ``moe_assignments`` (routed assignments)."""
+    T, H = xt.shape
+    k = top_i.shape[1]
+    E = w_gate.shape[-3]
+    A = T * k
+    i32 = jnp.int32
+    flat_e = top_i.reshape(A).astype(i32)
+    if valid is not None:
+        # an unrouted assignment sorts behind every expert's
+        flat_e = jnp.where(jnp.repeat(valid, k), flat_e, E)
+    with jax.named_scope("sort"):
+        order = jnp.argsort(flat_e, stable=True).astype(i32)
+        sorted_e = flat_e[order]
+        sorted_t = order // k
+        # first[e]: assignments of experts below e; first[E]: all routed
+        first = jnp.searchsorted(sorted_e, jnp.arange(E + 1, dtype=i32)
+                                 ).astype(i32)
+        counts = first[1:] - first[:-1]                     # [E]
+    aux = {"moe_experts_touched": jnp.sum(counts > 0).astype(i32),
+           "moe_assignments": first[E]}
+    if w_gate.ndim == 3:        # one layer's experts: a stack of one
+        w_gate, w_up, w_down = w_gate[None], w_up[None], w_down[None]
+        layer = 0
+    if use_pallas:
+        from dynamo_tpu.ops.pallas.moe_grouped import moe_grouped, supports
+        use_pallas = supports(H, w_gate.shape[-1])
+    if use_pallas:
+        # row tiles: a group starts on a tile boundary, so a tile has one
+        # expert. 16 rows (one bf16 sublane tile) while the assignments
+        # are few and most groups hold a row or two; 128 (the MXU's edge)
+        # once groups are long enough to fill them
+        tm = 16 if A <= 2048 else 128
+        n_tiles = -(-A // tm) + min(E, A)       # bound on sum ceil(c / tm)
+        M = n_tiles * tm
+        with jax.named_scope("sort"):
+            tiles = -(-counts // tm)                        # [E]
+            tile_end = jnp.cumsum(tiles)
+            num_tiles = tile_end[-1]
+            row_first = (tile_end - tiles) * tm             # [E]
+            tile_expert = jnp.minimum(jnp.searchsorted(
+                tile_end, jnp.arange(n_tiles, dtype=i32),
+                side="right"), E - 1).astype(i32)
+            # each row's token, from the row's side: the tile's expert,
+            # the row's rank in its group, the sorted assignment there
+            row = jnp.arange(M, dtype=i32)
+            row_e = tile_expert[row // tm]
+            row_rank = row - row_first[row_e]
+            live = (row // tm < num_tiles) & (row_rank < counts[row_e])
+            row_tok = jnp.where(live, sorted_t[jnp.minimum(
+                first[row_e] + row_rank, A - 1)], 0)
+            # tiles past the last keep its expert: nothing is fetched
+            tile_expert = jnp.where(
+                jnp.arange(n_tiles) < num_tiles, tile_expert,
+                tile_expert[jnp.maximum(num_tiles - 1, 0)])
+            # each assignment's row, back in token-major order
+            safe_e = jnp.minimum(sorted_e, E - 1)
+            pos = _unsort(order, row_first[safe_e]
+                          + jnp.arange(A, dtype=i32) - first[safe_e])
+        with jax.named_scope("experts"):
+            ys = moe_grouped(
+                xt[row_tok], tile_expert, num_tiles.reshape(1),
+                jnp.asarray(layer, i32).reshape(1), w_gate, w_up, w_down,
+                tm=tm)
+    else:
+        w_gate, w_up, w_down = w_gate[layer], w_up[layer], w_down[layer]
+        with jax.named_scope("experts"):
+            xs = xt[sorted_t]                              # [A, H]
+            act = (jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, counts))
+                   * jax.lax.ragged_dot(xs, w_up, counts))
+            ys = jax.lax.ragged_dot(
+                act.astype(xt.dtype), w_down, counts,
+                preferred_element_type=jnp.float32)        # [A, H]
+        with jax.named_scope("sort"):
+            pos = _unsort(order, jnp.arange(A, dtype=i32))
+    with jax.named_scope("combine"):
+        routed = (flat_e < E).reshape(T, k, 1)
+        # rows no group owns are never written: select, do not multiply
+        y = jnp.where(routed, ys[jnp.minimum(pos, ys.shape[0] - 1)]
+                      .reshape(T, k, H), 0.0)
+        out = jnp.sum(y * top_w.astype(jnp.float32)[..., None], axis=1)
+    return out, aux
+
+
 def moe_mlp(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
             x: jnp.ndarray) -> jnp.ndarray:
     """Routed expert MLP. x: [B, S, H] (already normed) -> [B, S, H]."""
-    top_w, top_i = _router_topk(cfg, lp, x)         # [B, S, k]
-    # dense per-expert weights [B, S, E] (zero for unrouted experts)
-    weights = jnp.sum(
-        jax.nn.one_hot(top_i, cfg.num_experts, dtype=jnp.float32)
-        * top_w[..., None], axis=2)                 # [B, S, E]
-    gate = jnp.einsum("bsh,ehi->bsei", x, lp["w_gate"])
-    up = jnp.einsum("bsh,ehi->bsei", x, lp["w_up"])
-    act = jax.nn.silu(gate) * up
-    out = jnp.einsum("bsei,eih->bseh", act, lp["w_down"])  # [B, S, E, H]
-    return jnp.einsum("bse,bseh->bsh", weights.astype(out.dtype), out)
+    return moe_mlp_counted(cfg, lp, x)[0]
+
+
+def moe_mlp_counted(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
+                    x: jnp.ndarray, **kw
+                    ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """``moe_mlp`` with the grouped layer's counts: ``([B, S, H], aux)``;
+    ``kw`` goes to ``grouped_experts``."""
+    B, S, H = x.shape
+    xt = x.reshape(B * S, H)
+    with jax.named_scope("route"):
+        top_w, top_i = _router_topk(cfg, lp, xt)           # [T, k]
+    out, aux = grouped_experts(
+        xt, top_w, top_i, lp["w_gate"], lp["w_up"], lp["w_down"], **kw)
+    return out.reshape(B, S, H).astype(x.dtype), aux
 
 
 # decode-size batches get their dispatch capacity padded to 4x the
@@ -173,17 +298,49 @@ def expert_dispatch(xt: jnp.ndarray, top_w: jnp.ndarray,
 
 
 def _moe_layer_tail(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
-                    h: jnp.ndarray, attn: jnp.ndarray, ep_mesh=None
-                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (h, dropped_assignments) — dropped is a static 0 on the
-    dense backend (it computes every expert; nothing can drop)."""
+                    h: jnp.ndarray, attn: jnp.ndarray, ep_mesh=None, **kw
+                    ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """Returns ``(h, aux)``: the grouped layer's counts (experts touched,
+    assignments), or the dispatch backend's dropped assignments. ``kw``
+    goes to ``grouped_experts``."""
     h = _finish_attn(cfg, lp, h, attn)
-    x = _rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
+    # stage names for the device trace, as in models/deepseek.py
+    with jax.named_scope("layer.moe"):
+        x = _rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
+        if cfg.moe_backend == "dispatch":
+            mlp, dropped = moe_mlp_dispatch(cfg, lp, x, ep_mesh=ep_mesh)
+            aux = {"moe_dropped_assignments": dropped}
+        else:
+            mlp, aux = moe_mlp_counted(cfg, lp, x, **kw)
+    return h + mlp, aux
+
+
+EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def split_experts(cfg: ModelConfig, layers: Dict[str, jnp.ndarray]):
+    """``(scanned leaves, stacked expert leaves)`` of a layer stack: the
+    grouped layer takes the experts whole, indexed by the layer (see
+    ``grouped_experts``); the dispatch backend scans them like the
+    rest."""
     if cfg.moe_backend == "dispatch":
-        mlp, dropped = moe_mlp_dispatch(cfg, lp, x, ep_mesh=ep_mesh)
-    else:
-        mlp, dropped = moe_mlp(cfg, lp, x), jnp.zeros((), jnp.int32)
-    return h + mlp, dropped
+        return layers, {}
+    return ({k: v for k, v in layers.items() if k not in EXPERT_LEAVES},
+            {k: layers[k] for k in EXPERT_LEAVES})
+
+
+def sum_aux(aux: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
+    """Per-layer (or per-step) counts stacked by a scan -> their sums."""
+    return {k: jnp.sum(v.astype(jnp.int32)) for k, v in aux.items()}
+
+
+def grouped_on_chip(attn_impl) -> bool:
+    """Whether the expert layer may run its Mosaic kernel: where the
+    engine handed the family its own Pallas attention kernels, unwrapped
+    (on a mesh they come wrapped per shard, and GSPMD cannot partition a
+    Mosaic call over sharded experts)."""
+    return (getattr(attn_impl, "pallas_paged_kernel", False)
+            and not getattr(attn_impl, "per_shard", False))
 
 
 def init_params(cfg: ModelConfig, rng: jax.Array,
@@ -217,12 +374,21 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             logits_window: int = 1, packed: bool = False
             ) -> Tuple[jnp.ndarray, jnp.ndarray, dict]:
     """Scan-over-layers MoE forward (llama.forward contract, the
-    token-packed form included, plus a third ``aux`` return:
-    ``{"moe_dropped_assignments": scalar}`` summed over layers — the
-    engine forwards it to worker stats)."""
+    token-packed form included, plus a third ``aux`` return: the expert
+    layer's counts summed over layers — ``moe_experts_touched`` and
+    ``moe_assignments``, or the dispatch backend's
+    ``moe_dropped_assignments`` — which the engine forwards to worker
+    stats)."""
     sm_scale = cfg.head_dim ** -0.5
     starts = packed_rows(packed, new_lens)
     h = params["embed"][tokens]
+    B, S = tokens.shape
+    # slots that hold no token route to no expert
+    valid = (jnp.arange(S) < jnp.sum(new_lens) if packed
+             else (jnp.arange(S)[None, :] < new_lens[:, None]).reshape(B * S))
+    scanned, experts = split_experts(cfg, params["layers"])
+    kw = (dict(valid=valid, use_pallas=grouped_on_chip(attn_impl))
+          if experts else {})
 
     def body(carry, xs):
         h, pages = carry
@@ -232,18 +398,19 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                            total_lens, new_lens, starts)
         attn = attend_rows(attn_impl, q, pages, lidx, page_table, positions,
                            total_lens, new_lens, sm_scale, starts)
-        h, dropped = _moe_layer_tail(cfg, lp, h, attn, ep_mesh=ep_mesh)
-        return (h, pages), dropped
+        grouped = dict(kw, layer=lidx) if experts else {}
+        h, aux = _moe_layer_tail(cfg, {**lp, **experts}, h, attn,
+                                 ep_mesh=ep_mesh, **grouped)
+        return (h, pages), aux
 
-    (h, pages), drops = jax.lax.scan(
-        body, (h, pages), (params["layers"], jnp.arange(cfg.num_layers)))
-    aux = {"moe_dropped_assignments": jnp.sum(drops)}
+    (h, pages), aux = jax.lax.scan(
+        body, (h, pages), (scanned, jnp.arange(cfg.num_layers)))
     return (_logits(cfg, params, h, new_lens, window=logits_window,
-                    starts=starts), pages, aux)
+                    starts=starts), pages, sum_aux(aux))
 
 
 forward.supports_packed = True
 
 
 __all__ = ["forward", "init_params", "make_pages", "moe_mlp",
-           "moe_mlp_dispatch", "expert_dispatch"]
+           "moe_mlp_dispatch", "expert_dispatch", "grouped_experts"]

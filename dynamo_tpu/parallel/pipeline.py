@@ -158,8 +158,8 @@ class _MoeStage(_LlamaStage):
     is LINEAR in the expert outputs, so ONE psum after the routed result
     completes the partial down-products — same two all-reduce points per
     layer as the dense family. The dispatch backend works too (its
-    scatter/combine is also linear); its drop counter is discarded here
-    (the pipeline returns the llama 2-tuple contract)."""
+    scatter/combine is also linear); the expert layer's counts are
+    discarded here (the pipeline returns the llama 2-tuple contract)."""
 
     TP_TAILS = _TP_TAILS_MOE
 
@@ -168,7 +168,7 @@ class _MoeStage(_LlamaStage):
 
         cfg = self.cfg
         if psum is None:
-            h, _dropped = _moe._moe_layer_tail(cfg, lp, h, attn)
+            h, _aux = _moe._moe_layer_tail(cfg, lp, h, attn)
             return h
         Bm_, S_ = h.shape[0], h.shape[1]
         h = h + psum(attn.reshape(Bm_, S_, -1) @ lp["wo"])

@@ -79,11 +79,12 @@ def build_parser() -> argparse.ArgumentParser:
                    help="load-time weight quantization: int8 = W8A8 "
                         "dynamic (halves the decode-step parameter "
                         "stream; llama-family dense models)")
-    p.add_argument("--moe-backend", choices=["dense", "dispatch"],
+    p.add_argument("--moe-backend", choices=["dispatch"],
                    default=None,
-                   help="MoE expert compute: dense (every expert, every "
-                        "token — decode-batch default) or dispatch "
-                        "(capacity-factor token gather — wide-EP)")
+                   help="MoE expert compute, when not the exact grouped "
+                        "layer (sorted by expert, one grouped matmul, no "
+                        "drop): dispatch (capacity-factor token gather "
+                        "into fixed ep-pinned buffers — wide-EP)")
     p.add_argument("--host-cache-bytes", type=int, default=0,
                    help="KVBM G2 host-RAM KV tier budget (0 disables)")
     p.add_argument("--disk-cache-bytes", type=int, default=0,
@@ -189,6 +190,10 @@ def build_engine(args: argparse.Namespace, startup=None) -> JaxEngine:
         cfg, engine_cfg, forward_fn, params = _build_weights(args)
     with startup.stage("startup.engine") as attrs:
         attrs["sample.top_candidates"] = candidate_form(cfg.vocab_size)
+        if cfg.num_experts:
+            attrs["moe.experts"] = (
+                cfg.moe_backend if cfg.moe_backend != "grouped" else
+                f"grouped[E={cfg.num_experts},k={cfg.num_experts_per_tok}]")
         return JaxEngine(cfg, params, engine_cfg, forward_fn=forward_fn)
 
 

@@ -142,6 +142,16 @@ class EngineDispatchCollector:
                                     "transition table after a fused block "
                                     "(logged once per row; any nonzero "
                                     "value is a device/host lowering bug)",
+        "moe_assignments": "Token-to-expert assignments the grouped expert "
+                           "layer computed (tokens x experts per token x "
+                           "expert layers; slots that hold no token route "
+                           "nowhere and are not counted)",
+        "moe_experts_touched": "Experts with at least one assignment, "
+                               "summed over expert layers and forward "
+                               "passes: what the grouped layer read",
+        "moe_expert_slots": "Experts the grouped layer could have read: "
+                            "forward passes x expert layers x experts (the "
+                            "denominator of the touched share)",
     }
 
     # the known fallback reasons, pre-seeded so every label shows on the
@@ -336,7 +346,11 @@ def engine_dispatch_stats(engine) -> Dict[str, object]:
     ``prefill_steps``: per-label count dicts the collector renders as
     labeled families."""
     sched = getattr(engine, "scheduler", None)
+    moe = engine.moe_counts() if hasattr(engine, "moe_counts") else {}
     return {
+        "moe_assignments": float(moe.get("moe_assignments", 0)),
+        "moe_experts_touched": float(moe.get("moe_experts_touched", 0)),
+        "moe_expert_slots": float(moe.get("moe_expert_slots", 0)),
         "decode_dispatches": float(getattr(engine, "decode_dispatches", 0)),
         "decode_multistep_blocks": float(
             getattr(engine, "multistep_blocks", 0)),

@@ -178,7 +178,7 @@ class TestMoeDispatch:
         # must dominate for the comparison to be meaningful (real MoEs have
         # I >> H; at the toy I=32 the one-hot dispatch einsums would drown
         # the signal), so widen the expert FFN here.
-        cfg_d = moe_cfg(num_experts=8, moe_backend="dense",
+        cfg_d = moe_cfg(num_experts=8, moe_backend="grouped",
                         moe_intermediate_size=256)
         cfg_s = moe_cfg(num_experts=8, moe_backend="dispatch",
                         moe_intermediate_size=256, moe_capacity_factor=1.5)

@@ -228,6 +228,36 @@ class TestTokenParity:
             await eng.stop()
 
 
+class TestStreamEnds:
+    async def test_a_streams_end_keeps_the_chain_and_the_width(self):
+        """Four rows, one much shorter: its end neither narrows the other
+        rows' blocks nor breaks their chain (the ring shows every block at
+        the full width, only the one behind the last admission unchained),
+        and every row's tokens equal the per-step run's."""
+        def mk():
+            return reqs_staggered(lens=(7, 41, 41, 41))
+
+        step_t, step_r, _ = await run_many(mk(), decode_multistep=1)
+        eng = tiny_engine(decode_multistep=4)
+        try:
+            results = await asyncio.gather(*[collect(eng, r) for r in mk()])
+            assert [toks_of(f) for f in results] == step_t
+            assert [f[-1].finish_reason for f in results] == step_r
+            recs = sorted(eng.steptrace.snapshot(limit=256)["records"],
+                          key=lambda r: r["seq"])
+            last_admission = max(i for i, r in enumerate(recs)
+                                 if r["kind"] != "multistep")
+            recs = recs[last_admission + 1:]
+            assert len(recs) >= 9
+            assert all(r["width"] == 4 and r["rows"] == 4 for r in recs)
+            assert [r["chained"] for r in recs] == [False] + [True] * (
+                len(recs) - 1)
+            assert recs[-1]["running"] == 3
+        finally:
+            await eng.stop()
+        assert eng.allocator.num_free == eng.allocator.num_pages - 1
+
+
 class TestDispatchCount:
     async def test_m_tokens_cost_m_over_n_plus_c_dispatches(self):
         """The regression guard of the fused path: M decoded tokens must
@@ -322,6 +352,67 @@ class TestSchedulerWidth:
         sched, _ = self.make()
         self.to_running(sched, make_req(range(1, 6), "a", max_tokens=2))
         assert sched.plan_multistep(sched.schedule()) is None
+
+    def running(self, sched, *max_tokens):
+        for i, n in enumerate(max_tokens):
+            sched.add_request(make_req(range(1, 6), "abc"[i], max_tokens=n))
+        plan = sched.schedule()
+        assert isinstance(plan, PrefillBatch)
+        assert len(plan.chunks) == len(max_tokens)
+        sched.on_step_done(plan)
+        seqs = [c.seq for c in plan.chunks]
+        for seq in seqs:
+            seq.tokens.append(9)
+            seq.generated.append(9)
+        return seqs
+
+    def test_short_row_does_not_narrow_the_block(self):
+        # the row with the most left sets the width; the other stops at
+        # its budget on the device and gets pages for what it writes only
+        sched, _ = self.make()
+        a, b = self.running(sched, 4, 32)
+        ms = sched.plan_multistep(sched.schedule())
+        assert ms is not None and ms.width == 8
+        assert ms.budgets == [3, 31]
+        assert len(a.page_ids) * sched.page_size >= len(a) + 3 - 1
+        assert len(a.page_ids) < len(b.page_ids)
+        assert sched.multistep_fallbacks == {}
+
+    def test_row_ending_in_flight_rides_the_chain_dead(self):
+        sched, _ = self.make()
+        a, b, _c = self.running(sched, 4, 64, 64)
+        ms = sched.plan_multistep(sched.schedule())
+        assert ms.width == 8 and len(ms.seqs) == 3
+        # "a" ends inside the block in flight: dead row, no pages, and the
+        # chain goes on at full width
+        pages_a = list(a.page_ids)
+        nxt = sched.plan_multistep_chained(ms)
+        assert nxt is not None and nxt.width == 8 and nxt.chained
+        assert nxt.budgets[0] == 0 and nxt.budgets[1] == 63 - 8
+        assert a.page_ids == pages_a
+        # the host learns of it (tokens appended, row finished): a row
+        # that spent its budget stays in the chain while most rows live
+        for seq in ms.seqs:
+            for _ in range(3 if seq is a else 8):
+                seq.tokens.append(7)
+                seq.generated.append(7)
+        sched.finish(a)
+        after = sched.plan_multistep_chained(nxt)
+        assert after is not None and after.seqs == nxt.seqs
+        assert after.budgets[0] == 0
+        # but not a row the host alone ended: the device has it alive
+        sched.finish(b)
+        assert sched.plan_multistep_chained(after) is None
+
+    def test_chain_breaks_when_half_the_rows_are_dead(self):
+        sched, _ = self.make()
+        a, b = self.running(sched, 4, 64)
+        ms = sched.plan_multistep(sched.schedule())
+        for _ in range(3):
+            a.tokens.append(7)
+            a.generated.append(7)
+        sched.finish(a)
+        assert sched.plan_multistep_chained(ms) is None
 
     def test_stop_string_lookback_caps_width(self):
         sched, _ = self.make()

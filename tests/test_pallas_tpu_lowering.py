@@ -24,9 +24,10 @@ def _native_kernels(monkeypatch):
     off ``jax.default_backend()`` (cpu here), but these tests lower for
     the TPU platform — the kernels must take their native path."""
     from dynamo_tpu.ops.pallas import (decode, mla_decode, mla_prefill,
-                                       prefill, ragged)
+                                       moe_grouped, prefill, ragged)
 
-    for mod in (decode, prefill, mla_decode, mla_prefill, ragged):
+    for mod in (decode, prefill, mla_decode, mla_prefill, ragged,
+                moe_grouped):
         monkeypatch.setattr(mod, "_resolve_interpret",
                             lambda interpret: False)
 
@@ -453,3 +454,48 @@ class TestVmemStackClamp:
                 est = Hq * sb * (14 * span + 24 * Dh) + slab
                 assert sb >= 8
                 assert est <= VMEM_STACK_BUDGET or sb == 8, (Hq, Dh, span)
+
+
+@pytest.mark.parametrize("tokens", [16, 8192])
+def test_grouped_expert_layer_compiles_in_the_tpu_compiler(tokens):
+    """``models/moe.grouped_experts`` with the ``moe_grouped`` kernel at the
+    widths of the benchmark's MLA + MoE cell (256 experts of 2,048 x 768,
+    top-8, four layers stacked) and its two shapes: 16 decode rows (16-row
+    tiles) and the 8,192 slots of a padded ``[16,512]`` step (128-row
+    tiles). The TPU compiler has to take the kernel's VMEM (three weight
+    blocks of 3.1 MB, double-buffered), keep the stacked experts where they
+    are (a per-layer slice handed to the custom call would be a 2.4 GB
+    copy) and scatter nothing (70-80 ns an index on the chip)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from dynamo_tpu.models.moe import grouped_experts
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu in this install
+        pytest.skip(f"no compile-only TPU topology: {e}")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    E, H, I, k, layers = 256, 2048, 768, 8, 4
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer_of_a_stack(xt, top_w, top_i, wg, wu, wd, layer, valid):
+        return grouped_experts(xt, top_w, top_i, wg, wu, wd, layer=layer,
+                               valid=valid, use_pallas=True)
+
+    text = jax.jit(layer_of_a_stack).lower(
+        sds((tokens, H), jnp.bfloat16), sds((tokens, k), jnp.float32),
+        sds((tokens, k), jnp.int32), sds((layers, E, H, I), jnp.bfloat16),
+        sds((layers, E, H, I), jnp.bfloat16),
+        sds((layers, E, I, H), jnp.bfloat16), sds((), jnp.int32),
+        sds((tokens,), jnp.bool_)).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "%moe_grouped" in calls[0]
+    # the kernel's weight operands are the stacks themselves
+    assert calls[0].count(f"bf16[{layers},{E},") == 3
+    assert not [ln for ln in text.splitlines()
+                if " scatter(" in ln or f"bf16[{E},{H},{I}]" in ln
+                or f"bf16[{E},{I},{H}]" in ln]

@@ -199,8 +199,8 @@ def test_rejects_families_without_stage_adapter():
                          mesh=mesh)
 
 
-@pytest.mark.parametrize("pp,tp,backend", [(2, 1, "dense"),
-                                           (2, 2, "dense"),
+@pytest.mark.parametrize("pp,tp,backend", [(2, 1, "grouped"),
+                                           (2, 2, "grouped"),
                                            (2, 2, "dispatch")])
 def test_pipeline_moe_matches_plain_forward(pp, tp, backend):
     """Mixtral/Qwen3-MoE through the MoE stage adapter: routed experts

@@ -544,6 +544,56 @@ class TestDeviceTime:
         assert all(r["program"].endswith("]") for r in recs)
 
 
+class TestHeadStart:
+    """``Phase.in_thread(..., head_start=s)``: the call is on its thread
+    before the loop does anything else, for at most ``s`` seconds."""
+
+    async def test_the_call_returns_before_the_loops_other_work(self):
+        rec = StepRecorder(capacity=8)
+        order = []
+
+        async def frames():
+            order.append("frames")
+
+        other = asyncio.ensure_future(frames())   # ready to run already
+        ph = rec.phase("dispatch", 0, "multistep")
+        out = await ph.in_thread(lambda x: order.append("dispatch") or x, 7,
+                                 head_start=1.0)
+        await other
+        assert out == 7 and order == ["dispatch", "frames"]
+        assert ph.ready > 0.0 and ph.ms >= 0.0
+
+    async def test_a_call_that_outlasts_it_is_awaited(self):
+        rec = StepRecorder(capacity=8)
+        ran = []
+
+        async def frames():
+            ran.append(time.perf_counter())
+
+        other = asyncio.ensure_future(frames())
+
+        def slow():
+            time.sleep(0.2)
+            return time.perf_counter()
+
+        ph = rec.phase("dispatch", 0, "multistep")
+        t0 = time.perf_counter()
+        done = await ph.in_thread(slow, head_start=0.02)
+        await other
+        # the loop was held for the head start, not for the call
+        assert 0.015 <= ran[0] - t0 < 0.15 and done - t0 >= 0.2
+
+    async def test_an_error_in_the_call_is_raised(self):
+        rec = StepRecorder(capacity=8)
+
+        def boom():
+            raise ValueError("no")
+
+        with pytest.raises(ValueError):
+            await rec.phase("dispatch", 0, "multistep").in_thread(
+                boom, head_start=0.5)
+
+
 def test_module_names_the_benchmark_matches_on():
     """``benchmarks/layer_metrics/step.decode_hbm_share.py`` finds the
     decode programs on the trace's ``XLA Modules`` line by name: the

@@ -86,14 +86,16 @@ def main() -> int:
     CARRY_2D = ("tok", "pos", "pids", "pcnt", "pctx", "pbias")
 
     def check_multistep(tag: str, ms) -> None:
-        out_pages, out_packed, out_carry, out_drops = ms.output_shardings
+        out_pages, out_packed, out_carry, out_aux = ms.output_shardings
         check(f"multistep{tag}.pages(out)", out_pages, pages_sharding,
               pages_ndim)
         check(f"multistep{tag}.packed(out)", out_packed, rep, 3)
         for key, s in out_carry.items():
             nd = 2 if key in CARRY_2D else 1
             check(f"multistep{tag}.carry[{key}](out)", s, rep, nd)
-        check(f"multistep{tag}.drops(out)", out_drops, rep, 0)
+        # the expert layer's counts: scalars, none for a dense family
+        for key, s in out_aux.items():
+            check(f"multistep{tag}.aux[{key}](out)", s, rep, 0)
         in_shardings, _in_kw = ms.input_shardings
         # donated pages: argument 1 must come in on the sharding it goes
         # out with, or XLA falls back to copy-and-reshard and the
