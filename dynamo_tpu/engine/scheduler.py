@@ -302,6 +302,14 @@ class SchedulerConfig:
     guided_fuse_check: Optional[Callable] = None
 
 
+# what bounds a run of consecutive prefill-carrying (mixed) steps: what
+# its admission pass left waiting, and why. Nothing ("queue"); or requests
+# the row limit kept out ("rows"), or the page pool ("pages"), or neither:
+# the pass took the prompts it may (``max_prefill_seqs``) and what is left
+# of them in the end does not fill a step ("partial")
+RUN_ENDS = ("queue", "rows", "pages", "partial")
+
+
 def _penalized(so) -> bool:
     """Does the row keep a device penalty window (penalties or a bias)?"""
     return bool(so.frequency_penalty or so.presence_penalty or so.logit_bias
@@ -356,6 +364,15 @@ class Scheduler:
         self._steps_since_decode = 0
         # mixed-dispatch diagnostics (the engine also counts dispatches)
         self.mixed_plans = 0
+        # admission runs: consecutive prefill-carrying (mixed) steps, by
+        # what ended each (``RUN_ENDS``), and the steps they held; the
+        # ratio is a run's mean length (dynamo_worker_sched_admission_*)
+        self.admission_runs: Dict[str, int] = dict.fromkeys(RUN_ENDS, 0)
+        self.admission_run_steps = 0
+        self._run_steps = 0     # mixed steps planned in the run under way
+        # why the last admission pass stopped (one of RUN_ENDS), and the
+        # pass of the run under way
+        self._admit_stop = self._run_stop = "queue"
 
     def record_fallback(self, reason: str, seqs=()) -> None:
         """Count one fused-path refusal; also stamp the sequences it
@@ -396,12 +413,45 @@ class Scheduler:
     def _watermark_pages(self) -> int:
         return max(1, int(self.alloc.num_pages * self.cfg.watermark))
 
-    def _try_admit(self) -> Optional[Sequence]:
+    def _ahead(self, seq: Sequence) -> int:
+        """Pages ``seq`` will ask for: through the prefill-carrying step
+        that takes it along and the fused block behind it (the step feeds
+        position ``len - 1``, the block writes ``decode_multistep``
+        positions from ``len``), and beyond that as far as it is sure to
+        go: to ``min_tokens``, or to the end of its budget where nothing
+        but the budget can end it (``ignore_eos`` and no stop set)."""
+        sc = seq.request.stop_conditions
+        done = len(seq.generated)
+        left = self._max_new(seq)
+        left = (1 << 30) if left is None else left - done
+        if self.max_context_hint is not None:
+            left = min(left, self.max_context_hint - len(seq))
+        sure = (sc.min_tokens or 0) - done
+        if sc.ignore_eos and not sc.stop and not sc.stop_token_ids:
+            sure = left
+        return self._pages_needed(
+            len(seq) + max(sure, min(left, self.cfg.decode_multistep), 0))
+
+    def _block_reserve(self) -> int:
+        """Pages the admitted rows do not hold yet and will ask for
+        (``_ahead``). Admission leaves them in the pool: a prompt that
+        takes them is paid for by a narrower block (a program nobody has
+        called), single steps or a preemption whose prompt is then
+        computed twice."""
+        return sum(max(0, self._ahead(s) - len(s.page_ids))
+                   for s in self.active.values())
+
+    def _try_admit(self, reserve: int) -> Optional[Sequence]:
+        """Admit the head of the queue if a row is free and the pool holds
+        its prompt, what it will ask for (``_ahead``) and ``reserve``:
+        what the rows admitted before it will."""
         while self.waiting and self.waiting[0].cancelled:
             self.reaped.append(self.waiting.popleft())
         if not self.waiting:
+            self._admit_stop = "queue"
             return None
         if len(self.active) >= self.cfg.max_num_seqs:
+            self._admit_stop = "rows"
             return None
         seq = self.waiting[0]
         hashes = seq.tokens.block_hashes()
@@ -417,13 +467,19 @@ class Scheduler:
             match.page_ids = match.page_ids[:full_cached_pages]
         cached = full_cached_pages * self.page_size
         need = self._pages_needed(len(seq)) - len(match.page_ids)
-        if need > self.alloc.num_free - self._watermark_pages():
+        spare = self.alloc.num_free - self._watermark_pages()
+        # beside other rows the pool has to hold what they and this one
+        # will ask for; alone, a prompt that fits runs as far as it gets
+        if (need > spare or self.active and self._ahead(seq)
+                - len(match.page_ids) > spare - reserve):
             self.alloc.release(match.page_ids)
+            self._admit_stop = "pages"
             return None
         try:
             fresh = self.alloc.allocate(need) if need else []
         except OutOfPages:
             self.alloc.release(match.page_ids)
+            self._admit_stop = "pages"
             return None
         self.alloc.count_lookup(hits=full_cached_pages,
                                 misses=len(hashes) - full_cached_pages)
@@ -542,10 +598,11 @@ class Scheduler:
 
     # -- the step ----------------------------------------------------------
 
-    def _prefill_plan(self) -> Optional[PrefillBatch]:
+    def _prefill_plan(self, admit: bool = True) -> Optional[PrefillBatch]:
         """Admit waiting sequences (bounded by slots, pages, and batch
-        width), then pack up to ``max_prefill_seqs`` chunks into one step
-        under the ``max_prefill_chunk`` token budget, oldest first."""
+        width; none with ``admit`` off), then pack up to
+        ``max_prefill_seqs`` chunks into one step under the
+        ``max_prefill_chunk`` token budget, oldest first."""
         # adopt blocks that became resident since admission (prefetch or
         # disagg injects, concurrent requests committing a shared prefix)
         # so each chunk starts where residency ends
@@ -572,8 +629,11 @@ class Scheduler:
                         if s.phase == Phase.PREFILL and not ring_eligible(s))
         n_ring = sum(1 for s in self.active.values()
                      if s.phase == Phase.PREFILL and ring_eligible(s))
-        while (n_prefill < self.cfg.max_prefill_seqs
-               and len(self.active) < self.cfg.max_num_seqs):
+        # why the pass stops: until ``_try_admit`` refuses for another
+        # reason, by its own cap (or a ring prompt held back)
+        self._admit_stop = "partial"
+        reserve = self._block_reserve() if admit else 0
+        while admit and n_prefill < self.cfg.max_prefill_seqs:
             while self.waiting and self.waiting[0].cancelled:
                 self.reaped.append(self.waiting.popleft())
             if rt is not None and self.waiting and n_ring >= self.cfg.max_ring_seqs:
@@ -585,9 +645,10 @@ class Scheduler:
                     # after any prefix hit exceed the threshold); hold it —
                     # FIFO order forbids skipping ahead to shorter prompts
                     break
-            seq = self._try_admit()
+            seq = self._try_admit(reserve)
             if seq is None:
                 break
+            reserve += max(0, self._ahead(seq) - len(seq.page_ids))
             if ring_eligible(seq):
                 n_ring += 1
             else:
@@ -641,16 +702,31 @@ class Scheduler:
         """Pick the next engine step, or None if there is nothing to run.
 
         With ``mixed_batch`` on (the default), prefill steps carry the
-        decode rows along as length-1 ragged chunks (MixedStepBatch) and
-        the ``_prefer_prefill`` alternation becomes mixed-vs-pure-decode —
-        the pure-decode half is what the loop upgrades to a fused
-        multi-step block, so fused decode stays active while arrivals
-        onboard. With it off, the legacy prefill-XOR-decode alternation
-        applies, except that a deep waiting queue may take up to
-        ``decode_progress_every - 1`` consecutive prefill steps (burst
-        TTFT) before a decode step is forced — the decode-progress
-        guarantee that bounds decode tail latency under sustained
-        arrivals."""
+        decode rows along as length-1 ragged chunks (MixedStepBatch).
+        A run of them starts with an admission pass: the prompts that
+        wait, as far as rows, ``max_prefill_seqs`` and the pool allow
+        (``_try_admit`` leaves in the pool what the admitted rows will
+        ask for, ``_block_reserve``). After a mixed step comes another
+        one while a queue stands and what the pass admitted still fills
+        a whole step; every step of a run takes every decode row one
+        token on. Then comes the pure-decode plan, which the loop
+        upgrades to a fused multi-step block, and completions free rows
+        and pages for the next run. Where no queue stands behind a mixed
+        step the run is one step long and the plans alternate mixed /
+        pure-decode. With ``mixed_batch`` off, the
+        legacy prefill-XOR-decode alternation applies, except that a deep
+        waiting queue may take up to ``decode_progress_every - 1``
+        consecutive prefill steps (burst TTFT) before a decode step is
+        forced: the decode-progress guarantee that bounds decode tail
+        latency under sustained arrivals."""
+        plan = self._next_plan()
+        if self._run_steps and not isinstance(plan, MixedStepBatch):
+            # the run of mixed steps is over: count it by what bounded it
+            self.admission_runs[self._run_stop] += 1
+            self._run_steps = 0
+        return plan
+
+    def _next_plan(self) -> Optional[StepPlan]:
         self._chain_run = 0
         # drop cancelled active sequences
         for seq in [s for s in self.active.values() if s.cancelled]:
@@ -662,8 +738,27 @@ class Scheduler:
         K = self.cfg.decode_progress_every
         force_decode = bool(decodable and K > 0
                             and self._steps_since_decode >= K - 1)
-        if not force_decode and (self._prefer_prefill or not decodable):
-            batch = self._prefill_plan()
+        # a run of mixed steps is under way, and it goes on while a queue
+        # stands (more requests wait than the next pass could admit: a
+        # burst that one pass absorbs is served as it always was) and what
+        # the run's first step admitted still fills a whole step. Only
+        # that first step admits, ``max_prefill_seqs`` prompts at most: a
+        # pool filled in one long run empties in one (rows admitted
+        # together end together), and then neither the pool nor the
+        # window of a measurement sees a steady state
+        go_on = bool(self._run_steps and decodable
+                     and len(self.waiting) >= self.cfg.max_prefill_seqs)
+        if not force_decode and (self._prefer_prefill or not decodable
+                                 or go_on):
+            batch = self._prefill_plan(admit=not go_on)
+            if (go_on and batch is not None
+                    and sum(c.length for c in batch.chunks)
+                    < self.cfg.max_prefill_chunk):
+                # a part-filled step costs every decode row a long token
+                # for few prompt tokens, in a program (``T`` follows the
+                # tokens) the steady state does not call; what is left
+                # rides the next run's first step, as it always has
+                batch = None
             if batch is not None:
                 if (self.cfg.mixed_batch and not batch.ring
                         and self.cfg.spec_tokens == 0 and decodable):
@@ -676,6 +771,13 @@ class Scheduler:
                         self._prefer_prefill = False
                         self._steps_since_decode = 0
                         self.mixed_plans += 1
+                        if not go_on:
+                            # a run starts: what its pass left waiting,
+                            # and why, names it
+                            self._run_stop = (self._admit_stop
+                                              if self.waiting else "queue")
+                        self._run_steps += 1
+                        self.admission_run_steps += 1
                         return MixedStepBatch(chunks=chunks,
                                               decode_seqs=ready)
                     if not chunks and not ready:

@@ -142,6 +142,16 @@ class EngineDispatchCollector:
                                     "transition table after a fused block "
                                     "(logged once per row; any nonzero "
                                     "value is a device/host lowering bug)",
+        "sched_admission_run_steps": "Prefill-carrying (mixed) steps in the "
+                                     "admission runs counted by "
+                                     "dynamo_worker_sched_admission_runs_"
+                                     "total: steps / runs is a run's mean "
+                                     "length (1.0 where no queue stands "
+                                     "behind an admission)",
+        "preemptions": "Running sequences evicted back to the waiting "
+                       "queue under page pressure (their prompt is computed "
+                       "again on re-admission, less what the prefix cache "
+                       "kept)",
         "moe_assignments": "Token-to-expert assignments the grouped expert "
                            "layer computed (tokens x experts per token x "
                            "expert layers; slots that hold no token route "
@@ -169,6 +179,10 @@ class EngineDispatchCollector:
     FALLBACK_REASONS = ("waiters", "prefill", "penalties",
                         "penalty_window", "guided", "guided_table",
                         "spec", "budget", "pages", "multihost")
+
+    # what can end a run of prefill-carrying steps (engine/scheduler.py
+    # RUN_ENDS), pre-seeded likewise
+    RUN_ENDS = ("queue", "rows", "pages", "partial")
 
     # the forms a prefill-carrying step can take (engine/jax_engine.py
     # _why_padded, and "ring" per plan), pre-seeded like the fallback
@@ -211,6 +225,21 @@ class EngineDispatchCollector:
         for reason, value in sorted(reasons.items()):
             fb.add_metric([str(reason)], float(value))
         yield fb
+        runs = CounterMetricFamily(
+            "dynamo_worker_sched_admission_runs",
+            "Runs of consecutive prefill-carrying (mixed) steps, by what "
+            "bounded the run: 'queue' (nothing waits any more), or, with "
+            "requests still waiting, what stopped its admission short of "
+            "them: 'rows' (max_num_seqs), 'pages' (the pool, less what "
+            "the admitted rows will ask for) or 'partial' (the run took "
+            "the prompts one admission pass may and the rest of them "
+            "does not fill a step)",
+            labels=["ended_by"])
+        ends = dict.fromkeys(self.RUN_ENDS, 0.0)
+        ends.update(stats.get("admission_runs") or {})
+        for ended_by, value in sorted(ends.items()):
+            runs.add_metric([str(ended_by)], float(value))
+        yield runs
         # prefill-carrying steps by the form they ran in, so a model that
         # silently serves padded shows on the scrape
         pf = CounterMetricFamily(
@@ -342,8 +371,8 @@ class StepTraceCollector:
 def engine_dispatch_stats(engine) -> Dict[str, object]:
     """The ``EngineDispatchCollector.attach`` source for a
     ``ScheduledEngineBase`` engine (JaxEngine and the mocker both carry
-    the counters). Values are floats, except ``multistep_fallbacks`` and
-    ``prefill_steps``: per-label count dicts the collector renders as
+    the counters). Values are floats, except ``multistep_fallbacks``,
+    ``admission_runs`` and ``prefill_steps``: per-label count dicts the collector renders as
     labeled families."""
     sched = getattr(engine, "scheduler", None)
     moe = engine.moe_counts() if hasattr(engine, "moe_counts") else {}
@@ -359,6 +388,10 @@ def engine_dispatch_stats(engine) -> Dict[str, object]:
             getattr(engine, "guided_parity_mismatches", 0)),
         "multistep_fallbacks": dict(
             getattr(sched, "multistep_fallbacks", None) or {}),
+        "admission_runs": dict(getattr(sched, "admission_runs", None) or {}),
+        "sched_admission_run_steps": float(
+            getattr(sched, "admission_run_steps", 0)),
+        "preemptions": float(getattr(sched, "num_preemptions", 0)),
         "prefill_steps": dict(getattr(engine, "prefill_steps", None) or {}),
     }
 

@@ -238,12 +238,17 @@ class TestScheduler:
 
     def test_preemption_on_page_pressure(self):
         sched, alloc = self.make(num_pages=4, page_size=4)  # 3 usable pages
-        # two 4-token prompts (1 page each), then both need a 2nd page
-        sched.add_request(make_req(range(1, 5), "a", max_tokens=16))
-        sched.add_request(make_req(range(11, 15), "b", max_tokens=16))
+        # two 3-token prompts (1 page each; admission looks one step
+        # ahead, and that step still fits the page), then both need a
+        # 2nd page
+        sched.add_request(make_req(range(1, 4), "a", max_tokens=16))
+        sched.add_request(make_req(range(11, 14), "b", max_tokens=16))
         plan = sched.schedule()
         assert isinstance(plan, PrefillBatch) and len(plan.chunks) == 2
-        advance(sched, plan)  # both RUNNING at len 5 -> need page 2
+        advance(sched, plan)  # both RUNNING at len 4
+        plan = sched.schedule()
+        assert isinstance(plan, DecodeBatch) and len(plan.seqs) == 2
+        advance(sched, plan)  # both at len 5 -> need page 2
         # decode: one free page left; "a" (older) gets it, "b" is preempted
         plan = sched.schedule()
         assert isinstance(plan, DecodeBatch)

@@ -21,6 +21,7 @@ from dynamo_tpu.engine.pages import PageAllocator
 from dynamo_tpu.engine.scheduler import (
     DecodeBatch,
     MixedStepBatch,
+    MultiStepBatch,
     Phase,
     PrefillBatch,
     Scheduler,
@@ -445,6 +446,201 @@ class TestMixedScheduling:
         assert sched2.multistep_fallbacks == {"guided": 1}
 
 
+# -- admission runs (ISSUE 35) ---------------------------------------------
+
+
+def _sched(num_pages=257, page_size=4, **cfg):
+    alloc = PageAllocator(num_pages, page_size)
+    base = dict(max_num_seqs=16, max_prefill_chunk=8, decode_multistep=8)
+    base.update(cfg)
+    s = Scheduler(alloc, SchedulerConfig(**base))
+    s.max_context_hint = 512
+    return s
+
+
+def _resolve(sched, plan, finished=None):
+    """What the engine loop does with a plan's result, counts only: the
+    accounting, one token for every row that sampled (a block: as many
+    as the device would write), and the end of a row at its budget."""
+    def accept(seq):
+        seq.tokens.append(9)
+        seq.generated.append(9)
+        if len(seq.generated) >= seq.request.stop_conditions.max_tokens:
+            sched.finish(seq)
+            if finished is not None:
+                finished.append(seq)
+
+    if isinstance(plan, MultiStepBatch):
+        adv = [min(plan.width, b) if q.phase is Phase.RUNNING else 0
+               for q, b in zip(plan.seqs, plan.budgets)]
+        sched.on_multistep_done(plan, adv)
+        for q, a in zip(plan.seqs, adv):
+            for _ in range(a):
+                accept(q)
+        sched.commit_block(plan)
+        return
+    sched.on_step_done(plan)
+    if isinstance(plan, (PrefillBatch, MixedStepBatch)):
+        rows = [c.seq for c in plan.chunks if c.is_last]
+        rows += getattr(plan, "decode_seqs", [])
+    else:
+        rows = plan.seqs
+    for q in rows:
+        if q.phase is Phase.RUNNING:
+            accept(q)
+
+
+def _next_plan(sched):
+    """The loop's planning, without its chained blocks: schedule, and
+    upgrade a pure-decode plan to a fused block."""
+    plan = sched.schedule()
+    if isinstance(plan, DecodeBatch):
+        plan = sched.plan_multistep(plan) or plan
+    return plan
+
+
+def _closed_loop(sched, clients, steps, prompt, max_tokens, seed=0):
+    """``clients`` callers, each sending its next prompt when the last is
+    answered. Returns the plans in order, as comparable tuples."""
+    rng = np.random.default_rng(seed)
+    n = [0]
+
+    def send():
+        n[0] += 1
+        lo, hi = prompt
+        out = (max_tokens if isinstance(max_tokens, int)
+               else int(rng.integers(max_tokens[0], max_tokens[1] + 1)))
+        sched.add_request(make_req(
+            rng.integers(1, 400, int(rng.integers(lo, hi + 1))).tolist(),
+            f"r{n[0]}", max_tokens=out, ignore_eos=True))
+
+    for _ in range(clients):
+        send()
+    log, blocks = [], []
+    for _ in range(steps):
+        plan = _next_plan(sched)
+        assert plan is not None
+        ids = [q.request.request_id for q in plan.seqs]
+        log.append((type(plan).__name__, getattr(plan, "width", 0),
+                    tuple(c.length for c in getattr(plan, "chunks", ())),
+                    tuple(ids)))
+        if isinstance(plan, MultiStepBatch):
+            blocks.append(plan)
+        done = []
+        _resolve(sched, plan, done)
+        for _ in done:
+            send()
+    return log, blocks
+
+
+class TestAdmissionRuns:
+    def _with_a_running_row(self, **cfg):
+        sched = _sched(**cfg)
+        sched.add_request(make_req(range(1, 6), "a", max_tokens=64))
+        _resolve(sched, sched.schedule())       # the prompt, alone
+        _resolve(sched, sched.schedule())       # the decode half
+        return sched, sched.active["a"]
+
+    @pytest.mark.parametrize("ended_by,cfg,waiting,kinds", [
+        # one prompt of three chunks and nobody behind it: no queue
+        # stands, and its chunks alternate with the rows' decode half
+        # as they always did
+        ("queue", {}, [20], "MD"),
+        # a standing queue, rows and pages to spare: the pass takes the
+        # prompts it may (3 x 6 tokens), two full steps compute 16 of
+        # them, and 2 tokens are not worth a third
+        ("partial", dict(max_prefill_seqs=3), [6] * 10, "MMD"),
+        # two rows free: both prompts are computed in three full steps
+        ("rows", dict(max_num_seqs=3, max_prefill_seqs=3), [12] * 6, "MMMD"),
+        # 13 usable pages: beside what "a" and the first prompt will ask
+        # for through their next block, the second prompt does not fit
+        ("pages", dict(num_pages=14, max_prefill_seqs=3), [16] * 4, "MMD"),
+    ])
+    def test_what_ends_a_run(self, ended_by, cfg, waiting, kinds):
+        sched, a = self._with_a_running_row(**cfg)
+        for i, n in enumerate(waiting):
+            # unique prompts: no prefix cache shortens a chunk
+            sched.add_request(make_req(range(100 * i + 100, 100 * i + 100 + n),
+                                       f"w{i}", max_tokens=64))
+        assert sum(sched.admission_runs.values()) == 0
+        seen = ""
+        for _ in kinds:
+            plan = sched.schedule()
+            seen += {MixedStepBatch: "M", DecodeBatch: "D"}[type(plan)]
+            n0, g0 = a.num_computed, len(a.generated)
+            if isinstance(plan, MixedStepBatch):
+                # every step of a run takes every decode row one token on
+                assert a in plan.decode_seqs
+                # ... and carries the whole budget
+                assert sum(c.length for c in plan.chunks) == 8
+            _resolve(sched, plan)
+            assert (a.num_computed, len(a.generated)) == (n0 + 1, g0 + 1)
+        assert seen == kinds
+        want = dict.fromkeys(("queue", "rows", "pages", "partial"), 0)
+        want[ended_by] = 1
+        assert sched.admission_runs == want
+        assert sched.admission_run_steps == kinds.count("M")
+        assert sched.num_preemptions == 0
+
+    def test_pool_bound_closed_loop_never_preempts_or_narrows(self):
+        # 32 callers on 16 rows and a pool that holds fewer than 16 of
+        # them to the end of their 24 tokens: the pool bounds the batch, and
+        # because admission leaves what the rows are sure to ask for,
+        # no row is ever evicted and every block runs at full width
+        sched = _sched(num_pages=161, max_prefill_chunk=64)
+        log, blocks = _closed_loop(sched, clients=32, steps=1500,
+                                   prompt=(8, 32), max_tokens=24)
+        assert sched.num_preemptions == 0
+        assert sched.multistep_fallbacks.get("pages", 0) == 0
+        assert len(blocks) > 300
+        for b in blocks:
+            most = min(8, max(b.budgets))
+            assert b.width == 1 << (most.bit_length() - 1)
+        # (the first prompts go alone: no decode row yet)
+        assert {k for k, *_ in log[1:]} == {"MixedStepBatch",
+                                            "MultiStepBatch"}
+        runs = sched.admission_runs
+        assert runs["pages"] > runs["partial"] > runs["rows"] == 0
+        assert runs["queue"] == 0
+        # some runs are longer than one step
+        assert sched.admission_run_steps > 1.2 * sum(runs.values())
+        assert max(len(b.seqs) for b in blocks) < 16
+
+    def test_as_many_callers_as_rows_plans_as_before(self):
+        # the reasoning cell's shape, ramp included (16 callers at once,
+        # a pass of 8, two or three prompts a step): no queue stands,
+        # every run is one step long, and the plans are the strict
+        # alternation's, plan for plan
+        class Alternating(Scheduler):
+            def schedule(self):
+                self._run_steps = 0     # every mixed step ends its run
+                return super().schedule()
+
+        logs = []
+        for cls in (Scheduler, Alternating):
+            sched = cls(PageAllocator(2049, 4), SchedulerConfig(
+                max_num_seqs=16, max_prefill_chunk=32, decode_multistep=4))
+            sched.max_context_hint = 512
+            log, _ = _closed_loop(sched, clients=16, steps=600,
+                                  prompt=(8, 16), max_tokens=(24, 56),
+                                  seed=3)
+            logs.append(log)
+            if cls is Scheduler:
+                runs = sched.admission_runs
+                # (the run under way when the loop stops is not counted)
+                assert 20 < sched.admission_run_steps <= sum(runs.values()) + 1
+                assert runs["rows"] == runs["pages"] == 0
+                assert runs["partial"] <= 3 < runs["queue"]     # the ramp's
+        assert logs[0] == logs[1]
+
+    def test_a_lone_prompt_is_admitted_whatever_it_will_ask_for(self):
+        # 3 usable pages: the prompt fits, its next block does not; with
+        # no row to protect it runs as far as it gets
+        sched = _sched(num_pages=4)
+        sched.add_request(make_req(range(1, 6), "a", max_tokens=32))
+        assert isinstance(sched.schedule(), PrefillBatch)
+
+
 # -- metrics surface ------------------------------------------------------
 
 
@@ -497,6 +693,38 @@ class TestMetricsSurface:
         # longer a reason at all — sharded engines fuse (PR 10)
         assert by_reason["waiters"] == 0.0 and by_reason["multihost"] == 0.0
         assert "mesh" not in by_reason
+
+    def test_worker_registry_renders_admission_runs(self):
+        from prometheus_client import CollectorRegistry
+
+        from dynamo_tpu.worker.metrics import (WorkerMetrics,
+                                               engine_dispatch_stats)
+        wm = WorkerMetrics(CollectorRegistry())
+        from dynamo_tpu.engine.scheduler import RUN_ENDS
+        assert type(wm.engine).RUN_ENDS == RUN_ENDS
+
+        def value(name, **labels):
+            return wm.registry.get_sample_value(name, labels or None)
+
+        # on the scrape at 0 before an engine is attached, every label
+        for ended_by in ("queue", "rows", "pages", "partial"):
+            assert value("dynamo_worker_sched_admission_runs_total",
+                         ended_by=ended_by) == 0.0
+        assert value("dynamo_worker_sched_admission_run_steps_total") == 0.0
+        assert value("dynamo_worker_preemptions_total") == 0.0
+
+        class Eng:
+            scheduler = _sched()
+        Eng.scheduler.admission_runs["pages"] = 3
+        Eng.scheduler.admission_run_steps = 7
+        Eng.scheduler.num_preemptions = 2
+        wm.engine.attach(lambda: engine_dispatch_stats(Eng))
+        assert value("dynamo_worker_sched_admission_runs_total",
+                     ended_by="pages") == 3.0
+        assert value("dynamo_worker_sched_admission_runs_total",
+                     ended_by="queue") == 0.0
+        assert value("dynamo_worker_sched_admission_run_steps_total") == 7.0
+        assert value("dynamo_worker_preemptions_total") == 2.0
 
 
 # -- engine-internal caches ----------------------------------------------
