@@ -3,14 +3,16 @@ the kernel's name. ``xplane.py`` names an operation ``%<name>.<n> <kind>
 <shape>`` and marks a Mosaic custom call; a Pallas kernel's ``name`` is its
 instruction's."""
 
+from fnmatch import fnmatchcase
+
 
 def mosaic_ops(trace: dict, names: tuple) -> list:
     """``[op name, seconds, calls]`` of the slice's Mosaic calls whose
-    kernel is one of ``names``."""
+    kernel is one of ``names`` (a name, or a pattern such as ``mla_*``)."""
     def kernel(op_name: str) -> str:
         return op_name.split(" ", 1)[0].lstrip("%").split(".", 1)[0]
     return [op for op in trace["ops"] if op[0].endswith("[mosaic]")
-            and kernel(op[0]) in names]
+            and any(fnmatchcase(kernel(op[0]), n) for n in names)]
 
 
 def time_share(run, names: tuple):

@@ -1,0 +1,6 @@
+"""``step.mfu`` in the cells judged on ``out_tok_per_s`` (a per-layer
+metric names one end-to-end metric and lists its cells, so the quantity is split)."""
+
+from layer_metrics import reader
+
+compute = reader("step.mfu").compute

@@ -31,6 +31,19 @@ def expert_slots(hf: dict) -> int:
     return expert_layers(hf) * hf["n_routed_experts"]
 
 
+def grouped_rows(hf: dict, assignments: int) -> int:
+    """Rows of the ``moe_grouped`` call that computes ``assignments``
+    token-expert pairs (a step's token slots times the experts per token):
+    ``models/moe.py`` sorts the pairs by expert and starts each expert's
+    group on a tile boundary, so the call has the pairs' tiles and one more
+    for every expert that can own a group; a tile is 16 rows up to 2,048
+    pairs and 128 beyond. 16 rows x 8 experts of 256: 136 tiles, 2,176
+    rows."""
+    tile = 16 if assignments <= 2048 else 128
+    return (-(-assignments // tile)
+            + min(hf["n_routed_experts"], assignments)) * tile
+
+
 def grouped_cost(hf: dict, dtype: str, touched: float,
                  assignments: float) -> tuple:
     """(FLOPs, bytes) of grouped-matmul calls that touched ``touched``

@@ -40,12 +40,39 @@ def weight_bytes(hf: dict, dtype: str) -> int:
         k = hf.get("first_k_dense_replace", 0)
         params = L * attn + k * dense + (L - k) * moe
     else:
-        dh = hf.get("head_dim") or H // n
-        nkv = hf.get("num_key_value_heads", n)
-        attn = H * n * dh + 2 * H * nkv * dh + n * dh * H
-        params = L * (attn + 3 * H * hf["intermediate_size"])
+        params = L * _dense_layer_params(hf)
     params += V * H            # the vocabulary projection (tied or not)
     return params * _ITEMSIZE[dtype]
+
+
+def _dense_layer_params(hf: dict) -> int:
+    """One llama-family layer: query, key, value and output projections
+    and the three feed-forward matrices."""
+    H, n = hf["hidden_size"], hf["num_attention_heads"]
+    dh = hf.get("head_dim") or H // n
+    nkv = hf.get("num_key_value_heads", n)
+    return (2 * H * n * dh + 2 * H * nkv * dh
+            + 3 * H * hf["intermediate_size"])
+
+
+def active_params(hf: dict) -> tuple:
+    """(parameters one token is multiplied with on its way through the
+    layers, parameters of the vocabulary projection): every matrix of a
+    dense layer; of an expert layer the attention, the router, the shared
+    experts and the ``num_experts_per_tok`` routed experts a token is sent
+    to. Two FLOPs each a token; attention's scores against the context are
+    not in it."""
+    H, L = hf["hidden_size"], hf["num_hidden_layers"]
+    if hf.get("kv_lora_rank"):
+        import moe_cost
+        k = hf.get("first_k_dense_replace", 0)
+        moe = (H * hf["n_routed_experts"] + moe_cost.expert_params(hf)
+               * (hf["num_experts_per_tok"] + hf.get("n_shared_experts", 0)))
+        layers = (L * moe_cost.attention_params(hf)
+                  + k * 3 * H * hf["intermediate_size"] + (L - k) * moe)
+    else:
+        layers = L * _dense_layer_params(hf)
+    return layers, hf["vocab_size"] * H
 
 
 def kv_bytes_per_token(hf: dict, dtype: str) -> int:

@@ -4,10 +4,22 @@ the configuration's plain float32 reference, and exits.
 
     python3 benchmarks/reference/score.py <in.json> <out.json>
 
-Input: ``{"config", "tiny", "sequences": [{"prompt", "continuation"}]}``.
-Output, per sequence and per continuation position: ``{token id:
-log-probability}`` for the served token and the reference's own top 32, the
-model teacher-forced on prompt + continuation.
+Input: ``{"config", "tiny", "sequences": [{"prompt", "continuation",
+"carried"}]}``. Output, per sequence and per continuation position: ``{token
+id: log-probability}`` for the served token and the reference's own top 32.
+
+How a served sequence is scored is the reference module's: it may export
+
+    score(hf, params, layer_fns, prompt, continuation, carried)
+
+which returns, for each continuation position, the float32 log-probability
+vector ``[len(continuation), vocabulary]`` that the configuration's rule
+assigns to what was served there (``carried``: what the configuration's
+``probe.carry`` kept beside each token, ``{key: [per position]}``). Such a
+configuration says so in its file with a ``probe`` block under ``benchmark``
+(``correctness.py``), an empty one where its rule needs nothing carried. A
+module that exports none is scored by ``next_token_rule``: the model
+teacher-forced on prompt + continuation.
 
 Weights are data, taken as the worker takes them: the program's
 ``init_params(cfg, PRNGKey(0))`` in the served dtype. The reference streams
@@ -34,6 +46,31 @@ def load_family(name: str):
     return mod
 
 
+def next_token_rule(ref, hf, params):
+    """The rule of a reference module that exports no ``score``: one clean
+    pass over prompt + continuation, and position ``len(prompt) - 1 + j``
+    predicts continuation token ``j``."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def logp_of(h_last):
+        return jax.nn.log_softmax(ref.head(hf, params, h_last), axis=-1)
+
+    def score(hf, params, layer_fns, prompt, continuation, carried):
+        tokens = jnp.asarray(prompt + continuation, jnp.int32)
+        h = params["embed"][tokens].astype(jnp.float32)
+        for kind, stack, n in ref.layers(params):
+            for i in range(n):
+                w = jax.tree_util.tree_map(
+                    lambda a, i=i: a[i].astype(jnp.float32), stack)
+                h = layer_fns[kind](w, h)
+        lo = len(prompt) - 1
+        return logp_of(h[lo:lo + len(continuation)])
+
+    return score
+
+
 def main() -> int:
     import modeldir
     with open(sys.argv[1]) as f:
@@ -53,14 +90,20 @@ def main() -> int:
     cfg = ModelConfig.from_hf(hf, dtype=bench["dtype"])
     params = get_family(cfg).init_params(cfg, jax.random.PRNGKey(0))
     ref = load_family(bench["reference"])
-    f32 = jnp.float32
+    if hasattr(ref, "score") != ("probe" in bench):
+        # the file's data says whose rule scores the configuration
+        raise SystemExit(
+            f"{ask['config']}.json has "
+            f"{'a' if 'probe' in bench else 'no'} probe block under "
+            f"benchmark, and reference/{bench['reference']}.py exports "
+            f"{'a' if hasattr(ref, 'score') else 'no'} score: a "
+            "configuration with a rule of its own has both")
+    rule = getattr(ref, "score", None) or next_token_rule(ref, hf, params)
     layer_fns = {kind: jax.jit(lambda w, h, fn=fn: fn(hf, w, h))
                  for kind, fn in ref.LAYER_FNS.items()}
 
     @jax.jit
-    def tail(h_last, targets):
-        logits = ref.head(hf, params, h_last)
-        logp = jax.nn.log_softmax(logits, axis=-1)
+    def pick(logp, targets):
         top_lp, top_id = jax.lax.top_k(logp, KEEP_TOP)
         chosen = jnp.take_along_axis(logp, targets[:, None], axis=-1)[:, 0]
         return chosen, top_lp, top_id
@@ -69,17 +112,14 @@ def main() -> int:
     with jax.default_matmul_precision("highest"):
         for seq in ask["sequences"]:
             prompt, cont = seq["prompt"], seq["continuation"]
-            tokens = jnp.asarray(prompt + cont, jnp.int32)
-            h = params["embed"][tokens].astype(f32)
-            for kind, stack, n in ref.layers(params):
-                for i in range(n):
-                    w = jax.tree_util.tree_map(
-                        lambda a, i=i: a[i].astype(f32), stack)
-                    h = layer_fns[kind](w, h)
-            # position len(prompt)-1+j predicts continuation token j
-            lo = len(prompt) - 1
-            chosen, top_lp, top_id = jax.device_get(tail(
-                h[lo:lo + len(cont)], jnp.asarray(cont, jnp.int32)))
+            logp = jnp.asarray(rule(hf, params, layer_fns, prompt, cont,
+                                    seq.get("carried", {})), jnp.float32)
+            if logp.ndim != 2 or logp.shape[0] != len(cont):
+                raise SystemExit(
+                    f"the rule scored {len(cont)} served tokens with an "
+                    f"array of shape {logp.shape}")
+            chosen, top_lp, top_id = jax.device_get(pick(
+                logp, jnp.asarray(cont, jnp.int32)))
             scored = []
             for j, tok in enumerate(cont):
                 row = {int(i): float(v)

@@ -531,7 +531,6 @@ class Run:
         line = {"correct": correct if correct is None else bool(correct),
                 "attempted": len(self.requests),
                 "failed": failed, "metrics": metrics, "device": device,
-                "probes": self.probe_result,
                 "compiles_in_window": self.compiles_in_window}
         if self.traced and self.device_traces:
             n = len(self.device_traces)
@@ -539,6 +538,8 @@ class Run:
             device["window_s"] = sum(t["window_s"]
                                      for t in self.device_traces) / n
             line["breakdown"] = breakdown.build(self)
+        # what was compared, each number beside its limit: last in the line
+        line["probes"] = self.probe_result
         return line
 
     def end_to_end(self, done: list) -> dict:
@@ -592,6 +593,17 @@ def main() -> int:
     except Failed as e:
         say(f"FAILED: {e}")
         return 1
+    probes = line["probes"]
+    if probes:
+        # ... and last on standard error
+        say(f"correct {line['correct']}: served_vs_reference_max_nats "
+            f"{probes['served_vs_reference_max_nats']} (limit "
+            f"{probes['reference_tol']}), served_vs_reference_mean_nats "
+            f"{probes['served_vs_reference_mean_nats']} (limit "
+            f"{probes['reference_mean_tol']}), cold_vs_cached_max_nats "
+            f"{probes['cold_vs_cached_max_nats']} (limit "
+            f"{probes['repeat_tol']}), {probes['logprobs_compared']} "
+            "log-probabilities compared")
     print(json.dumps(line), flush=True)
     return 0
 

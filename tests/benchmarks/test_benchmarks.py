@@ -30,6 +30,17 @@ CELLS = [w["name"] for w in BENCHMARK["workloads"]]
 BUILT_CELLS = sorted(f[:-5] for f in os.listdir(os.path.join(BENCH, "cells")))
 BUILT_CONFIGS = sorted(f[:-5] for f in os.listdir(
     os.path.join(BENCH, "configs")))
+
+
+def _has_own_rule(config: str) -> bool:
+    """Told from the file's data: a configuration whose served tokens are
+    scored by a rule of its reference module's own has a ``probe`` block."""
+    with open(os.path.join(BENCH, "configs", config + ".json")) as f:
+        return "probe" in json.load(f)["benchmark"]
+
+
+# the configurations the next-token rule scores (``reference/score.py``)
+DEFAULT_RULE_CONFIGS = [c for c in BUILT_CONFIGS if not _has_own_rule(c)]
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
@@ -349,7 +360,71 @@ def _program_logprobs(hf, tokens):
     return params, out
 
 
-@pytest.mark.parametrize("config", BUILT_CONFIGS)
+def _load(name, path):
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_the_shipped_configurations_are_scored_by_the_next_token_rule():
+    """The seam's default: none of the three shipped reference modules
+    exports a ``score``, none of their configurations has a ``probe`` block
+    (so the cache keys and the request bodies are the parent's), and the
+    agreement test below still holds its three cases."""
+    assert DEFAULT_RULE_CONFIGS == ["dsv2lite", "joyai-llm-flash",
+                                    "qwen3-4b"]
+    for family in ("llama", "deepseek", "joyai"):
+        with open(os.path.join(BENCH, "reference", family + ".py")) as f:
+            assert not re.search(r"^(def score\b|score\s*=)", f.read(), re.M)
+    assert {modeldir.load_config(c)["bench"]["reference"]
+            for c in DEFAULT_RULE_CONFIGS} == {"llama", "deepseek", "joyai"}
+
+
+@pytest.mark.parametrize("config", DEFAULT_RULE_CONFIGS)
+def test_the_default_rule_is_the_next_token_rule(config):
+    """``next_token_rule``, fed a toy sequence as the child feeds it, returns
+    for continuation token j the reference's row at position
+    ``len(prompt) - 1 + j`` of one clean pass over prompt + continuation:
+    what the child wrote before the rule had a name."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from dynamo_tpu.models import get_family
+    from dynamo_tpu.models.config import ModelConfig
+
+    c = modeldir.load_config(config, tiny=True)
+    hf = c["hf"]
+    ref = _load("ref_rule_" + config.replace("-", "_"), os.path.join(
+        BENCH, "reference", c["bench"]["reference"] + ".py"))
+    score_py = _load("reference_score", os.path.join(BENCH, "reference",
+                                                     "score.py"))
+    cfg = ModelConfig.from_hf(hf, dtype="float32")
+    params = get_family(cfg).init_params(cfg, jax.random.PRNGKey(0))
+    tokens = np.random.default_rng(5).integers(0, hf["vocab_size"],
+                                               size=29).tolist()
+    prompt, cont = tokens[:21], tokens[21:]
+    layer_fns = {kind: jax.jit(lambda w, h, fn=fn: fn(hf, w, h))
+                 for kind, fn in ref.LAYER_FNS.items()}
+    with jax.default_matmul_precision("highest"):
+        got = score_py.next_token_rule(ref, hf, params)(
+            hf, params, layer_fns, prompt, cont, {})
+        h = params["embed"][jnp.asarray(tokens)].astype(jnp.float32)
+        for kind, stack, n in ref.layers(params):
+            for i in range(n):
+                w = jax.tree_util.tree_map(lambda a, i=i: a[i], stack)
+                h = ref.LAYER_FNS[kind](hf, w, h)
+        want = jax.nn.log_softmax(ref.head(hf, params, h), axis=-1)
+    assert got.shape == (len(cont), hf["vocab_size"])
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want[20:28]),
+                               atol=1e-5, rtol=0)
+    # one position off is another answer: the rows are told apart
+    assert float(jnp.max(jnp.abs(got - want[21:29]))) > 1e-2
+
+
+@pytest.mark.parametrize("config", DEFAULT_RULE_CONFIGS)
 def test_reference_agrees_with_the_programs_family_at_toy_size(config):
     import importlib.util
     import jax
@@ -381,3 +456,176 @@ def test_reference_agrees_with_the_programs_family_at_toy_size(config):
     kind, stack, n = ref.layers(params)[-1]
     w = jax.tree_util.tree_map(lambda a: a[n - 1], stack)
     assert float(jnp.max(jnp.abs(ref.LAYER_FNS[kind](hf, w, h) - h))) > 1e-2
+
+
+# ------------------------------------------------- whose rule decides correct
+
+
+def _chunk(ids, lps, **more):
+    return dict({"tokens": [f"<{i}>" for i in ids], "token_logprobs": lps,
+                 "top_logprobs": [{f"<{i}>": v} for i, v in zip(ids, lps)]},
+                **more)
+
+
+def test_served_keeps_what_the_configuration_carries_per_token():
+    import correctness
+    from served import Failed
+    ids = list(range(100, 116))
+    chunks = [_chunk(ids[:10], [-1.0] * 10, text_offset=list(range(10)),
+                     revealed_in=[0] * 10),
+              _chunk(ids[10:], [-2.0] * 6, text_offset=list(range(10, 16)),
+                     revealed_in=[1] * 6)]
+    plain = correctness._served(chunks)
+    assert plain["ids"] == ids and plain["carried"] == {}
+    got = correctness._served(chunks, ["revealed_in"])
+    assert (got["ids"], got["lps"], got["top"]) == (
+        plain["ids"], plain["lps"], plain["top"])
+    assert got["carried"] == {"revealed_in": [0] * 10 + [1] * 6}
+    # one entry for each token of its chunk, or the probe has failed
+    chunks[1]["revealed_in"] = [1] * 5
+    with pytest.raises(Failed, match="revealed_in"):
+        correctness._served(chunks, ["revealed_in"])
+    with pytest.raises(Failed, match="no_such_key"):
+        correctness._served(chunks, ["no_such_key"])
+
+
+def test_a_configuration_without_the_block_keeps_the_parents_cache_keys():
+    import hashlib
+    import numpy as np
+    import correctness
+    tokens = [5, 151935, 0, 77]
+    parents = hashlib.sha256(np.asarray(tokens, np.int64).tobytes()
+                             ).hexdigest()
+    assert correctness._key(tokens) == parents
+    assert correctness._key(tokens, {}) == parents
+    carried = correctness._key(tokens, {"revealed_in": [0, 0, 1, 1]})
+    assert carried != parents
+    assert carried != correctness._key(tokens, {"revealed_in": [0, 1, 1, 1]})
+    assert carried == correctness._key(tokens, {"revealed_in": [0, 0, 1, 1]})
+
+
+def test_the_probes_ask_what_the_configurations_file_says():
+    import asyncio
+    import types
+    import correctness
+
+    class Client:
+        def __init__(self):
+            self.extras = []
+
+        def now(self):
+            return 0.0
+
+        async def send(self, r, extra=None):
+            self.extras.append(extra)
+            r.ok = True
+            return [_chunk(list(range(16)), [-1.0] * 16,
+                           text_offset=list(range(16)))]
+
+    config = modeldir.load_config("qwen3-4b", tiny=True)
+    run = types.SimpleNamespace(config=config)
+    client = Client()
+    asyncio.run(correctness.send_probes(run, client))
+    assert client.extras == [{"logprobs": 5}] * 8        # the parent's body
+    assert all(p[w]["carried"] == {} for p in run.probes
+               for w in ("cold", "cached"))
+    config["bench"]["probe"] = {"extra": {"nvext": {"passes": 3},
+                                          "logprobs": 1},
+                                "carry": ["text_offset"]}
+    client = Client()
+    asyncio.run(correctness.send_probes(run, client))
+    assert client.extras == [{"nvext": {"passes": 3}, "logprobs": 5}] * 8
+    assert run.probes[0]["cold"]["carried"] == {
+        "text_offset": list(range(16))}
+
+
+def _judged(monkeypatch, cold_carried, cached_carried, cached_lps):
+    """``judge`` on one stub probe whose reference agrees with the cold
+    pass to the digit; returns (correct, the result block, the sequences the
+    reference was asked for)."""
+    import types
+    import correctness
+    ids = list(range(200, 216))
+    cold_lps = [-1.0 - 0.01 * i for i in range(16)]
+
+    def served(lps, carried):
+        return {"ids": ids, "lps": lps, "top": [{} for _ in ids],
+                "carried": carried}
+    run = types.SimpleNamespace(
+        config={"bench": {"dtype": "float32"}},
+        probes=[{"prompt": [1, 2, 3], "cold": served(cold_lps, cold_carried),
+                 "cached": served(cached_lps, cached_carried)}])
+    asked = []
+
+    def scores(_run, sequences):
+        asked.extend(sequences)
+        out = {}
+        for p, c, k in sequences:
+            lps = cold_lps if k == cold_carried else cached_lps
+            out[correctness._key(p + c, k)] = [
+                {str(i): v} for i, v in zip(c, lps)]
+        return out
+    monkeypatch.setattr(correctness, "reference_scores", scores)
+    return correctness.judge(run), run.probe_result, asked
+
+
+def test_judge_compares_cold_and_cached_only_while_they_carried_the_same(
+        monkeypatch):
+    cold_lps = [-1.0 - 0.01 * i for i in range(16)]
+    # position 9 on, the cached pass says something else (0.5 nats off)
+    moved = cold_lps[:9] + [v - 0.5 for v in cold_lps[9:]]
+    # nothing carried: the same tokens are the same context, and 0.5 fails
+    ok, result, asked = _judged(monkeypatch, {}, {}, moved)
+    assert not ok and result["cold_vs_cached_max_nats"] == pytest.approx(0.5)
+    assert len(asked) == 1               # one sequence, scored once
+    # the same values carried: nothing changes
+    same = {"revealed_in": [0] * 8 + [1] * 8}
+    ok, result, asked = _judged(monkeypatch, same, dict(same), moved)
+    assert not ok and result["cold_vs_cached_max_nats"] == pytest.approx(0.5)
+    assert len(asked) == 1
+    # the cached pass revealed position 9 in another pass: from there on
+    # the two conditioned on different inputs, and the comparison ends
+    other = {"revealed_in": [0] * 8 + [1] + [2] * 7}
+    ok, result, asked = _judged(monkeypatch, same, other, moved)
+    assert ok and result["cold_vs_cached_max_nats"] == 0.0
+    assert result["served_vs_reference_max_nats"] == 0.0
+    assert len(asked) == 2               # each scored under what it carried
+    assert result["logprobs_compared"] == 32
+
+
+def test_judge_holds_the_mean_gap_to_the_configurations_own_limit(
+        monkeypatch):
+    """Every served log-probability 0.05 nats off its reference: the widest
+    gap is far inside bfloat16's 0.3, and only a configuration that states
+    a limit for the mean (between a clean run's and the int8 control's,
+    PERF.md PR 36) sees it."""
+    import types
+    import correctness
+    ids = list(range(300, 316))
+    served = {"ids": ids, "lps": [-1.0] * 16, "top": [{} for _ in ids],
+              "carried": {}}
+    monkeypatch.setattr(
+        correctness, "reference_scores", lambda _run, seqs: {
+            correctness._key(p + c, k): [{str(i): -1.05} for i in c]
+            for p, c, k in seqs})
+
+    def judged(bench):
+        run = types.SimpleNamespace(config={"bench": bench}, probes=[
+            {"prompt": [7, 8], "cold": served, "cached": served}])
+        return correctness.judge(run), run.probe_result
+    ok, result = judged({"dtype": "bfloat16"})
+    assert ok and result["reference_mean_tol"] is None
+    assert result["served_vs_reference_mean_nats"] == pytest.approx(0.05)
+    ok, result = judged({"dtype": "bfloat16",
+                         "reference_mean_tol": {"bfloat16": 0.03}})
+    assert not ok and result["reference_mean_tol"] == 0.03
+    assert result["served_vs_reference_max_nats"] == pytest.approx(0.05)
+    ok, _ = judged({"dtype": "bfloat16",
+                    "reference_mean_tol": {"bfloat16": 0.06}})
+    assert ok
+    # the limit is the stated dtype's: the tiny float32 overlay has none
+    for config in ("qwen3-4b", "joyai-llm-flash"):
+        stated = modeldir.load_config(config)["bench"]
+        assert 0 < stated["reference_mean_tol"]["bfloat16"] < 0.1
+        tiny = modeldir.load_config(config, tiny=True)["bench"]
+        assert tiny["dtype"] not in tiny["reference_mean_tol"]

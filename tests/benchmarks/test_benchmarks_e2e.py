@@ -1,8 +1,9 @@
 """End-to-end CPU tests of the benchmark: each traffic mix's cell at toy
 widths through the served path (``--tiny``: coordinator, worker and frontend
 as processes on the CPU backend), the contract's last line checked; what a
-run does without a TPU or without the program; and a later PR's cell and
-metric added with files alone."""
+run does without a TPU or without the program; a later PR's cell and
+metric added with files alone; and a later PR's configuration whose served
+tokens its own reference module scores, added with files alone."""
 
 import copy
 import json
@@ -155,3 +156,145 @@ def test_a_later_pr_adds_a_cell_and_a_metric_with_files_alone(tmp_path):
     line = json.loads(out.stdout.strip().splitlines()[-1])
     assert line["metrics"]["dummy.requests_ok"]["value"] == \
         line["attempted"] > 0
+
+
+OWN_RULE = '''"""A scratch configuration's rule, in its own words: the
+model reads prompt and continuation once, clean and causal, and the token
+at position t is scored by the logits that came out at position t - 1. It
+wants ``text_offset`` carried: one entry a served token, each the characters
+streamed before it."""
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+
+_spec = importlib.util.spec_from_file_location(
+    "ownrule_llama", os.path.join(os.path.dirname(os.path.abspath(
+        __file__)), "llama.py"))
+_llama = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_llama)
+LAYER_FNS, layers, head = _llama.LAYER_FNS, _llama.layers, _llama.head
+READ_AT = -1          # the position before the token's own
+
+
+def score(hf, params, layer_fns, prompt, continuation, carried):
+    offsets = carried["text_offset"]
+    assert len(offsets) == len(continuation), (offsets, continuation)
+    assert offsets[0] == 0 and all(
+        b - a >= len(f"<{t}>")
+        for a, b, t in zip(offsets, offsets[1:], continuation)), offsets
+    tokens = jnp.asarray(prompt + continuation, jnp.int32)
+    h = params["embed"][tokens].astype(jnp.float32)
+    for kind, stack, n in layers(params):
+        for i in range(n):
+            w = jax.tree_util.tree_map(
+                lambda a, i=i: a[i].astype(jnp.float32), stack)
+            h = layer_fns[kind](w, h)
+    logp = jax.nn.log_softmax(head(hf, params, h), axis=-1)
+    at = [len(prompt) + j + READ_AT for j in range(len(continuation))]
+    return logp[jnp.asarray(at)]
+'''
+
+
+@pytest.mark.parametrize("read_at, correct", [(-1, True), (0, False)])
+def test_a_later_pr_adds_a_configuration_with_a_rule_of_its_own(
+        tmp_path, read_at, correct):
+    """A temp copy of the repo's benchmark gains a configuration whose file
+    carries a key of the streamed ``logprobs`` per token (``probe.carry``), a
+    reference module that exports ``score``, a cell and their BENCHMARK.json
+    entries - new files only - and the tiny run is correct. The same with a
+    rule that reads one position off is not: the seam cannot pass a wrong
+    rule."""
+    root = tmp_path / "repo"
+    root.mkdir()
+    shutil.copytree(BENCH, root / "benchmarks", ignore=shutil.
+                    ignore_patterns(".runs", ".cache", "__pycache__"))
+    os.symlink(os.path.join(REPO, "dynamo_tpu"), root / "dynamo_tpu")
+    before = {str(p.relative_to(root)): p.read_bytes()
+              for p in (root / "benchmarks").rglob("*") if p.is_file()}
+    with open(os.path.join(BENCH, "configs", "qwen3-4b.json")) as f:
+        config = json.load(f)
+    config["benchmark"].update(
+        reference="ownrule", served_name="toy-own-rule",
+        probe={"extra": {"echo": False}, "carry": ["text_offset"]})
+    (root / "benchmarks/configs/toy-own-rule.json").write_text(
+        json.dumps(config))
+    (root / "benchmarks/reference/ownrule.py").write_text(
+        OWN_RULE.replace("READ_AT = -1", f"READ_AT = {read_at}"))
+    shutil.copy(os.path.join(BENCH, "cells", "qwen3-4b.batch.json"),
+                root / "benchmarks/cells/toy-own-rule.batch.json")
+    b = copy.deepcopy(BENCHMARK)
+    b["configs"].append({
+        "name": "toy-own-rule", "source": "a test's configuration",
+        "file": "benchmarks/configs/toy-own-rule.json", "reduced": [],
+        "why": "served tokens scored by its reference module's own rule"})
+    b["workloads"].append({
+        "name": "toy-own-rule.batch", "config": "toy-own-rule",
+        "traffic": "batch", "chips": 1, "why": "a test's cell"})
+    (root / "BENCHMARK.json").write_text(json.dumps(b))
+    out = _run(["--workload", "toy-own-rule.batch", "--seed", "2400000011",
+                "--seconds", "3", "--trace", "0", "--tiny"], cwd=str(root))
+    assert out.returncode == 0, out.stderr[-3000:]
+    line = json.loads(out.stdout.strip().splitlines()[-1])
+    assert line["correct"] is correct and line["failed"] == 0, line
+    assert line["probes"]["logprobs_compared"] == 768
+    # float32 at toy widths: rounding against a wrong row's 0.05 nats
+    worst = line["probes"]["served_vs_reference_max_nats"]
+    assert (worst <= 2e-3) if correct else (worst > 0.02), line["probes"]
+    # the child ran the module's rule and kept its scores under keys that
+    # hold what was carried; no file that was there was touched
+    with open(root / "benchmarks/.cache/reference/toy-own-rule-tiny.json"
+              ) as f:
+        assert len(json.load(f)) >= 4
+    assert {k: (root / k).read_bytes() for k in before} == before
+
+
+def test_the_control_in_the_next_precision_down_is_not_correct(tmp_path):
+    """The control of ``correct``, at a size a test can hold: the program's
+    own lower-precision path in the program's place. The tiny configuration
+    states float32 (reference and limit, 2e-3 nats); served in bfloat16, the
+    nearest precision below, the same run is not correct."""
+    root = tmp_path / "repo"
+    root.mkdir()
+    shutil.copytree(BENCH, root / "benchmarks", ignore=shutil.
+                    ignore_patterns(".runs", ".cache", "__pycache__"))
+    os.symlink(os.path.join(REPO, "dynamo_tpu"), root / "dynamo_tpu")
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), root)
+    path = root / "benchmarks/configs/qwen3-4b.json"
+    config = json.loads(path.read_text())
+    # the worker takes the last --dtype it is given
+    config["benchmark"]["tiny"]["worker_args"] += ["--dtype", "bfloat16"]
+    path.write_text(json.dumps(config))
+    out = _run(["--workload", "qwen3-4b.batch", "--seed", "2600000017",
+                "--seconds", "3", "--trace", "0", "--tiny"], cwd=str(root))
+    assert out.returncode == 0, out.stderr[-3000:]
+    line = json.loads(out.stdout.strip().splitlines()[-1])
+    assert line["correct"] is False and line["failed"] == 0, line
+    probes = line["probes"]
+    assert probes["reference_tol"] == 2e-3
+    # the clean run reads 2e-6 (summation order): the limit lies between
+    assert probes["served_vs_reference_max_nats"] > 2e-3, probes
+    # each number compared stands beside its limit, last on standard error
+    assert "served_vs_reference_max_nats" in out.stderr.splitlines()[-1]
+    assert list(line)[-1] == "probes"
+
+
+def test_the_reference_side_control_reads_a_gap_at_toy_size():
+    """``reference/control.py``, the builder's tool that puts the reference
+    in the next precision down in the program's place: at toy size it runs,
+    names what it compared, and reads a gap far above a clean run's 2e-6."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    out = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "reference", "control.py"),
+         "qwen3-4b", "--tiny", "--seeds", "1"], cwd=REPO,
+        env=dict(env, JAX_PLATFORMS="cpu", PYTHONPATH=REPO),
+        capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    line = json.loads(out.stdout.strip().splitlines()[-1])
+    assert (line["stated"], line["control"]) == ("float32", "bfloat16")
+    assert line["limit"] == 2e-3 and len(line["readings"]) == 1
+    assert line["readings"][0] > 1e-4
+    assert line["control_fails"] is (line["readings"][0] > 2e-3)

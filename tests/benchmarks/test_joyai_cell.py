@@ -20,6 +20,7 @@ sys.path.insert(0, BENCH)
 
 import modeldir  # noqa: E402
 import moe_cost  # noqa: E402
+import peaks  # noqa: E402
 import traffic  # noqa: E402
 from layer_metrics import reader  # noqa: E402
 
@@ -57,7 +58,7 @@ def test_the_cells_files_carry_the_parameters_it_was_defined_with():
 
 
 def test_only_this_pr_lists_the_cell_and_no_metric_is_left_without_a_list():
-    assert len(METRICS) == 14
+    assert len(METRICS) == 15
     assert all(m.get("workloads") for m in BENCHMARK["per_layer"])
     moved = {m["moves"] for m in BENCHMARK["per_layer"]
              if CELL in m["workloads"]}
@@ -137,6 +138,80 @@ def test_readers_read_the_ring_and_the_trace():
                  + 3200 * moe_cost.expert_params(hf) * 2)
     assert hbm == pytest.approx(100 * 2 * per_block / 819e9 / 0.128)
     assert 0 < hbm <= 100
+
+
+def test_the_whole_steps_share_of_the_peak_stands_beside_the_rooflines():
+    """``step.mfu``: two FLOPs for every parameter a real token of the window
+    meets (8 of 256 experts, not all), the projection for the tokens a decode
+    dispatch samples, over the peak and the dispatches' device time."""
+    hf = modeldir.load_config("joyai-llm-flash")["hf"]
+    layers, head = peaks.active_params(hf)
+    assert layers == (5 * moe_cost.attention_params(hf) + 3 * 2048 * 7168
+                      + 4 * (2048 * 256 + 9 * 3 * 2048 * 768))
+    assert head == 129280 * 2048
+    qwen = modeldir.load_config("qwen3-4b")["hf"]
+    assert sum(peaks.active_params(qwen)) * 2 == peaks.weight_bytes(
+        qwen, "bfloat16")                  # dense: every matrix, every token
+    ring = [_record(), _record(t_unix=120.0),
+            _record(kind="mixed", width=0, tokens_real=330,
+                    tokens_padded=16 * 512, device_ms=40.0),
+            _record(t_unix=10.0)]                         # before the window
+    for cell in ("reason", "batch"):
+        mfu = reader(f"step.mfu.{cell}").compute(_run_stub(ring))
+        assert mfu == pytest.approx(
+            100 * (2 * layers * 586 + 2 * head * 256) / 197e12 / 0.168)
+    assert 0 < mfu < 100
+    assert reader("step.mfu.reason").compute(
+        _run_stub(ring, platform="cpu")) is None
+    assert reader("step.mfu.reason").compute(_run_stub([])) is None
+    assert [m["moves"] for m in BENCHMARK["per_layer"]
+            if m["name"].startswith("step.mfu.")] == ["out_tok_per_s"] * 2
+
+
+def test_kernel_readers_survive_a_token_packed_step():
+    """The slice above, its admission computed as a token-packed step of 256
+    slots (a short prompt and 15 decode rows: 2,048 assignments, 6,144 rows
+    at the kernel's tile, where a decode step's call has 2,176) by a packed
+    latent-attention kernel: both readers still return a value, and the
+    value they return on the padded slice."""
+    assert moe_cost.grouped_rows(
+        modeldir.load_config("joyai-llm-flash")["hf"], 16 * 8) == 2176
+    ops = [["%moe_grouped.3 custom-call f32[2176,2048]{1,0} [mosaic]",
+            0.10, 64],
+           ["%mla_decode.1 custom-call f32[16,32,512]{2,1,0} [mosaic]",
+            0.004, 80],
+           ["%fusion.9 fusion bf16[16,2048]", 0.05, 900]]
+    mark = {"start_unix": 105.0, "stop_unix": 125.0}
+    padded = {"mark": mark, "busy_s": 0.20, "ops": ops + [
+        ["%moe_grouped.7 custom-call f32[98304,2048]{1,0} [mosaic]",
+         0.01, 4],
+        ["%mla_prefill.2 custom-call f32[16,512,32,512] [mosaic]",
+         0.002, 5]]}
+    packed = {"mark": mark, "busy_s": 0.20, "ops": ops + [
+        ["%moe_grouped.7 custom-call f32[6144,2048]{1,0} [mosaic]",
+         0.01, 4],
+        ["%mla_ragged.2 custom-call f32[256,32,512] [mosaic]", 0.002, 5]]}
+    blocks = [_record(), _record(t_unix=120.0)]
+    readings = []
+    for trace, step in (
+            (padded, _record(kind="mixed", width=0, experts_touched=900,
+                             tokens_real=330, tokens_padded=16 * 512,
+                             device_ms=40.0)),
+            (packed, _record(kind="mixed", width=0, experts_touched=900,
+                             tokens_real=143, tokens_padded=256,
+                             device_ms=12.0))):
+        run = _run_stub(blocks + [step], [trace])
+        readings.append((
+            reader("kernel.moe_roofline_share.reason").compute(run),
+            reader("kernel.mla_time_share.reason").compute(run)))
+    nbytes = 6400 * 3 * 2048 * 768 * 2 + 2 * 8 * 4 * 128 * 2048 * 6
+    assert readings[0] == readings[1] == (
+        pytest.approx(100 * nbytes / 819e9 / 0.10), pytest.approx(3.0))
+    # a prefill-carrying step of as many slots as a decode step has rows
+    # could not be told from one: nothing, rather than a share of both
+    same = _run_stub(blocks + [_record(kind="prefill", width=0,
+                                       tokens_padded=16)], [packed])
+    assert reader("kernel.moe_roofline_share.reason").compute(same) is None
 
 
 @pytest.mark.parametrize("metric", [
