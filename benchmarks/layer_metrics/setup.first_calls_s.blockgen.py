@@ -1,0 +1,6 @@
+"""``setup.first_calls_s`` in the block-generation cells, which are judged on ``setup_s`` (a per-layer
+metric names one end-to-end metric and lists its cells, so the quantity is split)."""
+
+from layer_metrics import reader
+
+compute = reader("setup.first_calls_s").compute

@@ -87,7 +87,8 @@ class Backend:
         self.tokenizer = tokenizer if tokenizer is not None else card.load_tokenizer()
 
     def _logprob_entry(self, piece: str, logprob: Optional[float],
-                       top: Optional[dict], num_top: int) -> dict:
+                       top: Optional[dict], num_top: int,
+                       reveal_pass: Optional[int] = None) -> dict:
         """One OpenAI ``logprobs.content[]`` element (chat format; the
         completions route reshapes these into the legacy arrays).
 
@@ -104,6 +105,11 @@ class Backend:
         top-K step outputs feed them directly."""
         entry = {"token": piece, "logprob": logprob,
                  "bytes": list(piece.encode("utf-8"))}
+        if reveal_pass is not None:
+            # generation by diffusion over blocks: the pass of its block
+            # that revealed the token (its log-probabilities are that
+            # pass's, at the token's own position)
+            entry["reveal_pass"] = reveal_pass
         if top:
             ranked = sorted(top.items(), key=lambda kv: -kv[1])[:num_top]
             entry["top_logprobs"] = [
@@ -161,7 +167,9 @@ class Backend:
                                if out.top_logprobs
                                and j < len(out.top_logprobs) else None)
                         lp_content.append(self._logprob_entry(
-                            piece, lp, top, want_logprobs))
+                            piece, lp, top, want_logprobs,
+                            out.reveal_pass[j] if out.reveal_pass
+                            and j < len(out.reveal_pass) else None))
                 text = jail.push("".join(pieces)) if pieces else ""
                 if jail.matched is not None:
                     finish = FinishReason.STOP

@@ -44,11 +44,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dynamo_tpu.engine.loop import ScheduledEngineBase
+from dynamo_tpu.engine.loop import BlockState, ScheduledEngineBase
 from dynamo_tpu.engine.scheduler import PrefillBatch, StepPlan
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models import llama
-from dynamo_tpu.ops.sampling import (TOPK_MAX, sample_tokens,
+from dynamo_tpu.ops.sampling import (TOPK_MAX, reveal, sample_tokens,
                                      top_candidates)
 
 logger = logging.getLogger(__name__)
@@ -142,6 +142,17 @@ class JaxEngineConfig:
     spec_ngram_max: int = 4
     spec_ngram_min: int = 2
     spec_chain_break: int = 8
+    # generation by diffusion over blocks (a model whose config says so,
+    # ``ModelConfig.generation``): the worker's defaults for the one
+    # reveal rule (``ops/sampling.reveal``). ``denoising_steps`` is the
+    # number of revealing passes a block of masks takes at most (0 = the
+    # block length: one token a pass), ``confidence_threshold`` the
+    # confidence above which a pass reveals beyond its quota (>= 1: the
+    # static schedule). A request overrides both under ``nvext``. The
+    # passes a fused dispatch runs are ``decode_multistep`` (1 = pass by
+    # pass).
+    denoising_steps: int = 0
+    confidence_threshold: float = 0.9
     # prompt-scoring (completions echo + logprobs) length cap; 0 = use
     # max_context. Scoring runs the PAGED chunked-prefill forward — linear
     # memory — but against a FRESH scratch cache allocated next to the
@@ -238,9 +249,10 @@ def _token_bucket(n: int, lo: int) -> int:
     prefill budget beside up to 64 decode rows). A ladder over the step's
     TOKENS, so a step of three chunks and twenty decode rows pays for
     1,152 slots and not for ``rows x longest chunk``."""
-    if n <= 512:
+    if n <= 512 and lo <= 512:
         return _bucket(n, lo, 512)
-    return -(-n // 128) * 128
+    # (a floor above 512 pins the axis: one packed program, every step)
+    return max(-(-n // 128) * 128, lo)
 
 
 class JaxEngine(ScheduledEngineBase):
@@ -444,6 +456,9 @@ class JaxEngine(ScheduledEngineBase):
                     "logits_window support (all built-in families carry "
                     f"it); {model_cfg.model_type!r} has none — drop "
                     "--speculative-num-tokens to serve it")
+        self.gen_block = int(model_cfg.gen_block)
+        if self.gen_block > 1:
+            self._init_block_diffusion(forward_fn)
         self.table_width = self.cfg.max_context // self.cfg.page_size
         self._rng = jax.random.PRNGKey(self.cfg.seed)
         self._step_counter = 0
@@ -550,6 +565,49 @@ class JaxEngine(ScheduledEngineBase):
         # state cannot linger in the composition-keyed caches
         self._released: set = set()
         self._released_lock = threading.Lock()
+        # requests refused at admission, by reason
+        # (dynamo_worker_requests_refused_total)
+        self.requests_refused: Dict[str, int] = {}
+
+    def _init_block_diffusion(self, forward_fn) -> None:
+        """A model that generates by diffusion over blocks: refuse by name
+        what does not compose with it yet, and hand the scheduler the
+        block length."""
+        B = self.gen_block
+        no = None
+        if self.spec_K:
+            no = ("--speculative-num-tokens (n-gram drafts verify one "
+                  "next token a position)")
+        elif forward_fn is not None:
+            no = "a custom forward_fn (pipeline stages)"
+        elif self.cfg.mesh is not None:
+            no = "a device mesh (the per-shard kernels take no block)"
+        elif not self._fwd_has_logits_window:
+            no = "a family forward without logits_window"
+        elif not self.cfg.pipeline_decode:
+            no = "pipeline_decode off (a pass dispatch is asynchronous)"
+        elif self.cfg.page_size % B:
+            no = (f"a page of {self.cfg.page_size} tokens that is not a "
+                  f"multiple of the block")
+        if no is not None:
+            raise ValueError(
+                f"{self.model_cfg.model_type!r} generates by diffusion over "
+                f"blocks of {B} positions, which does not compose with {no}")
+        self.scheduler.cfg.gen_block = B
+        self.gen_steps = min(max(int(self.cfg.denoising_steps or B), 1), B)
+        self.gen_threshold = float(self.cfg.confidence_threshold)
+        # per-width pass programs, and the composition-keyed sampling
+        # arrays of a pass dispatch (the ``_samp_cache`` pattern)
+        self._jit_passes: Dict[int, Callable] = {}
+        self._gen_samp_cache: Optional[Tuple] = None
+
+    @property
+    def generation(self) -> str:
+        """How this engine generates, for the ``startup.engine`` span."""
+        if self.gen_block <= 1:
+            return "causal"
+        return (f"block_diffusion[B={self.gen_block},steps={self.gen_steps}"
+                f",tau={self.gen_threshold:g}]")
 
     def _per_shard(self, kernel: Callable, forward_fn) -> Callable:
         """On a mesh, run a GQA stacked kernel once per ``tp`` shard under
@@ -624,7 +682,36 @@ class JaxEngine(ScheduledEngineBase):
                 self._guided_bytes[e] = None
         self._guided_vocab = GuidedVocab(self._guided_bytes, list(eos_ids))
 
+    def _refusal(self, request) -> Optional[Tuple[str, str]]:
+        """(reason, message) of what a block-diffusion engine cannot
+        serve yet, or None: requests whose sampling would have to act on
+        one next token a row a step."""
+        so = request.sampling_options
+        what = None
+        if so.guided:
+            what = ("guided", "guided decoding (response_format, forced "
+                    "tool calls)")
+        elif (so.frequency_penalty or so.presence_penalty
+              or (so.repetition_penalty not in (None, 0, 1.0))):
+            what = ("penalties", "frequency, presence and repetition "
+                    "penalties")
+        elif so.logit_bias:
+            what = ("logit_bias", "logit_bias")
+        elif request.prefill_only:
+            what = ("disagg_prefill", "disaggregated prefill (a prefill "
+                    "worker samples one next token)")
+        if what is None:
+            return None
+        return what[0], (f"{what[1]} cannot be served by a model that "
+                         "generates by diffusion over blocks")
+
     def validate_request(self, request) -> Optional[str]:
+        if self.gen_block > 1:
+            refused = self._refusal(request)
+            if refused is not None:
+                self.requests_refused[refused[0]] = (
+                    self.requests_refused.get(refused[0], 0) + 1)
+                return refused[1]
         spec = request.sampling_options.guided
         if not spec:
             return None
@@ -1745,7 +1832,8 @@ class JaxEngine(ScheduledEngineBase):
         # is device-resident) or spec mode (its own [B, K+1] verify
         # path). pipeline_decode False means strict step-at-a-time
         # debugging — fusion off too.
-        return (self.multistep > 1 and self.cfg.pipeline_decode
+        return ((self.multistep > 1 or self.gen_block > 1)
+                and self.cfg.pipeline_decode
                 and self.step_tap is None and not self.spec_K)
 
     @property
@@ -1953,6 +2041,8 @@ class JaxEngine(ScheduledEngineBase):
         blocking. A chained block takes its first token / position /
         liveness / budgets from the previous block's on-device carry —
         only the (possibly grown) page table re-uploads."""
+        if self.gen_block > 1:
+            return self._dispatch_passes(plan, prev_handle)
         seqs = plan.seqs
         w = plan.width
         B = _bucket(len(seqs), self.cfg.min_decode_bucket,
@@ -2010,6 +2100,201 @@ class JaxEngine(ScheduledEngineBase):
                                time.perf_counter() - _t0)
         return (packed_block, carry)
 
+    # -- generation by diffusion over blocks -------------------------------
+
+    # a seeded row's key folds ``position * PASS_KEY_STRIDE + pass``: the
+    # same for a pass whatever dispatch runs it (a block of B masks takes
+    # at most B + 1 passes)
+    PASS_KEY_STRIDE = 64
+
+    def _passes_impl(self, params, pages, table, state, rng, step0, samp,
+                     n_passes=1):
+        """FUSED passes of generation by diffusion over blocks:
+        ``n_passes`` forward passes over every row's current block in one
+        jitted program, a ``lax.scan`` with the donated ``pages`` and the
+        rows' block state as its carry.
+
+        ``state`` per row: ``tok``/``rev [R, B]`` the block's tokens and
+        which positions are revealed (by POSITION: a revealed token may
+        have any id, the mask's included), ``pidx`` the next pass's index
+        within the block, ``start`` the block's first position, ``tail``
+        the prompt tokens inside it, ``budget`` the tokens the row may
+        still emit, ``alive``. A pass feeds the block with the mask
+        token's id at unrevealed positions through the family forward at
+        ``[R, B]`` with logits at every position, which writes the
+        block's keys and values IN PLACE: nothing reads them but this
+        row's later passes (``num_computed``, the prefix cache and the
+        page hashes move only when the host sees the block commit), and
+        every pass rewrites all B. A block with masks left reveals some
+        (``ops/sampling.reveal``); positions past the row's budget are
+        never revealed. A block with no such mask left at the pass's
+        start is COMMITTED by it: the keys and values it wrote are the
+        final tokens', the row moves to the next block (all masks) and
+        its budget falls by the tokens the block emits; a row whose
+        budget is spent is dead for the rest of the dispatch and of the
+        chain (``new_lens`` 0: it writes nothing and routes to no
+        expert).
+
+        Returns (pages, packed ``[R, n_passes, 2 + B * (3 + 2K)]`` int32
+        - per pass: alive at its start, committed by it, and per position
+        revealed-now, the sampled token, its log-probability's bits, K
+        top ids, K top log-probability bits - the state for a chained
+        dispatch, and the summed MoE counts)."""
+        R, B = state["tok"].shape
+        mask_id = jnp.int32(self.model_cfg.mask_token_id)
+        offs = jnp.arange(B, dtype=jnp.int32)[None, :]
+        rep = functools.partial(jnp.repeat, repeats=B, axis=0)
+        temp, top_k, top_p = (rep(samp["temp"]), rep(samp["top_k"]),
+                              rep(samp["top_p"]))
+        seeds, min_p = rep(samp["seeds"]), rep(samp["min_p"])
+        bits = functools.partial(jax.lax.bitcast_convert_type,
+                                 new_dtype=jnp.int32)
+
+        def body(carry, j):
+            pages, tok, rev, pidx, start, tail, budget, alive = carry
+            pos = start[:, None] + offs
+            new = alive.astype(jnp.int32) * B
+            total = jnp.where(alive, start + B, 1)
+            logits, pages, aux = self._run_forward(
+                self._attn_prefill, params, jnp.where(rev, tok, mask_id),
+                pos, pages, table, total, new, logits_window=B)
+            # masks the budget pays for: the positions past it are never
+            # revealed (the block's tail is not emitted, and what a served
+            # token was conditioned on is a function of served tokens)
+            masked = ~rev & (offs < (tail + budget)[:, None])
+            served = alive          # the rows this pass serves
+            with jax.named_scope("pass/confidence"):
+                lf = logits.astype(jnp.float32).reshape(R * B, -1)
+                sampled, lps = sample_tokens(
+                    lf, jax.random.fold_in(rng, step0 + j), temp, top_k,
+                    top_p, seeds=seeds, seed_rng=rng,
+                    seed_pos=(pos * self.PASS_KEY_STRIDE
+                              + pidx[:, None]).reshape(R * B),
+                    min_p=min_p)
+                sampled, lps = sampled.reshape(R, B), lps.reshape(R, B)
+                tops = []
+                if self.cfg.num_top_logprobs > 0:
+                    ids, lp_bits = self._topk_cols(lf)
+                    tops = [ids.reshape(R, -1), lp_bits.reshape(R, -1)]
+            with jax.named_scope("pass/reveal"):
+                now = alive[:, None] & reveal(
+                    jnp.exp(lps), masked, pidx, samp["steps"], samp["tau"])
+                tok = jnp.where(now, sampled, tok)
+                rev = rev | now
+            with jax.named_scope("pass/commit"):
+                commit = alive & ~jnp.any(masked, axis=1)
+                budget = jnp.where(commit, budget - (B - tail), budget)
+                start = jnp.where(commit, start + B, start)
+                tail = jnp.where(commit, 0, tail)
+                rev = rev & ~commit[:, None]
+                pidx = jnp.where(commit, 0, pidx + alive.astype(jnp.int32))
+                alive = alive & ~(commit & (budget <= 0))
+            packed = jnp.concatenate(
+                [served[:, None].astype(jnp.int32),
+                 commit[:, None].astype(jnp.int32), now.astype(jnp.int32),
+                 sampled, bits(lps)] + tops, axis=1)
+            return ((pages, tok, rev, pidx, start, tail, budget, alive),
+                    (packed, aux))
+
+        keys = ("tok", "rev", "pidx", "start", "tail", "budget", "alive")
+        (pages, *out), (passes, aux) = jax.lax.scan(
+            body, (pages, *(state[k] for k in keys)),
+            jnp.arange(n_passes, dtype=jnp.int32))
+        return (pages, jnp.moveaxis(passes, 0, 1), dict(zip(keys, out)),
+                {k: jnp.sum(v.astype(jnp.int32)) for k, v in aux.items()})
+
+    def _get_jit_passes(self, w: int):
+        fn = self._jit_passes.get(w)
+        if fn is None:
+            fn = jax.jit(functools.partial(self._passes_impl, n_passes=w),
+                         donate_argnums=(1,))
+            self._jit_passes[w] = fn
+        return fn
+
+    def _gen_sampling(self, seqs, R: int) -> dict:
+        """Device arrays of the rows' sampling and reveal parameters,
+        rebuilt when the batch's composition changes."""
+        key = (R, tuple((s.request.request_id, id(s)) for s in seqs))
+        cached = self._gen_samp_cache
+        if cached is not None and cached[0] == key:
+            return cached[1]
+        a = {"temp": np.zeros(R, np.float32), "top_k": np.zeros(R, np.int32),
+             "top_p": np.ones(R, np.float32), "seeds": np.zeros(R, np.int32),
+             "min_p": np.zeros(R, np.float32),
+             "steps": np.full(R, self.gen_steps, np.int32),
+             "tau": np.full(R, self.gen_threshold, np.float32)}
+        for i, seq in enumerate(seqs):
+            so = seq.request.sampling_options
+            if so.temperature is not None:
+                a["temp"][i] = so.temperature
+            a["top_k"][i] = so.top_k or 0
+            if so.top_p is not None:
+                a["top_p"][i] = so.top_p
+            if so.seed is not None:
+                # the _sampling_extras seed mapping: [1, 2^31-1], 0 = off
+                a["seeds"][i] = (int(so.seed) % 0x7FFFFFFF) + 1
+            a["min_p"][i] = so.min_p or 0.0
+            if so.denoising_steps:
+                a["steps"][i] = so.denoising_steps
+            if so.confidence_threshold is not None:
+                a["tau"][i] = so.confidence_threshold
+        out = {k: jnp.asarray(v) for k, v in a.items()}
+        self._gen_samp_cache = (key, out)
+        return out
+
+    def _dispatch_passes(self, plan, prev_handle=None):
+        """Dispatch one ``GenPassBatch``; returns the (packed passes,
+        device state) handle without blocking. A fresh dispatch uploads
+        each row's block state from the host's mirror
+        (``Sequence.block_state``); a chained one takes the previous
+        dispatch's device state - only the grown page table re-uploads."""
+        seqs, w, B = plan.seqs, plan.width, self.gen_block
+        R = _bucket(len(seqs), self.cfg.min_decode_bucket,
+                    self.cfg.max_num_seqs)
+        _table_np, table = self._table_arrays(seqs, R)
+        samp = self._gen_sampling(seqs, R)
+        if prev_handle is not None:
+            state = prev_handle[1]
+        else:
+            st = {"tok": np.zeros((R, B), np.int32),
+                  "rev": np.zeros((R, B), bool),
+                  "pidx": np.zeros(R, np.int32),
+                  "start": np.zeros(R, np.int32),
+                  "tail": np.zeros(R, np.int32),
+                  "budget": np.zeros(R, np.int32),
+                  "alive": np.zeros(R, bool)}      # pad rows: never write
+            for i, seq in enumerate(seqs):
+                tail = plan.tails[i]
+                # a block in mid-denoising, or a fresh one: the prompt's
+                # tail, then masks
+                bs = seq.block_state or BlockState(
+                    B, seq.tokens.tokens()[len(seq) - tail:] if tail else ())
+                st["tok"][i], st["rev"][i] = bs.tok, bs.rev
+                st["pidx"][i] = bs.pidx
+                st["start"][i] = plan.start_lens[i]
+                st["tail"][i] = tail
+                st["budget"][i] = plan.budgets[i]
+                st["alive"][i] = plan.budgets[i] > 0
+            state = {k: jnp.asarray(v) for k, v in st.items()}
+        plan._step_id = self._step_counter
+        fn = self._get_jit_passes(w)
+        _ckey = (id(fn), R, w)
+        _fresh = _ckey not in self._jit_seen
+        _t0 = time.perf_counter() if _fresh else 0.0
+        self.pages, packed, state, aux = fn(
+            self.params, self.pages, table, state, self._rng,
+            np.int32(self._step_counter), samp)
+        self._queue_moe_aux(aux, steps=w)
+        self._step_counter += w
+        self.decode_dispatches += 1
+        self.multistep_blocks += 1
+        self.last_padded = (R, w * B)
+        self.last_program = f"passes{w}[{R},{B}]"
+        if _fresh:
+            self._mark_compile(_ckey, "multistep", R, w,
+                               time.perf_counter() - _t0)
+        return (packed, state)
+
     def prime_multistep(self, B: int, widths=None):
         """Compile the fused block program(s) for padded batch ``B``
         outside serving (bench priming): garbage-page no-op dispatches —
@@ -2050,6 +2335,9 @@ class JaxEngine(ScheduledEngineBase):
         every float column (the block-path fix for the per-fetch
         ``.copy().view(np.float32)``)."""
         host = np.asarray(handle[0])
+        if self.gen_block > 1:
+            # a pass dispatch: the loop unpacks it (``_process_passes``)
+            return host, None, None
         hostf = host.view(np.float32)
         sampled = host[:, :, 0]
         logprobs = hostf[:, :, 1]

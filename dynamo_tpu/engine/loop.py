@@ -35,6 +35,7 @@ from dynamo_tpu.engine.pages import PageAllocator
 from dynamo_tpu.engine.steptrace import get_step_recorder
 from dynamo_tpu.engine.scheduler import (
     DecodeBatch,
+    GenPassBatch,
     MixedStepBatch,
     MultiStepBatch,
     Phase,
@@ -71,6 +72,29 @@ def migration_token(out: "LLMEngineOutput") -> Optional[dict]:
         return None
     tok = out.kv_transfer_params.get(MIGRATION_KEY)
     return tok if isinstance(tok, dict) else None
+
+
+class BlockState:
+    """The host's mirror of one row's block under denoising (generation
+    by diffusion over blocks): what a fresh pass dispatch uploads, and
+    what the block's tokens are emitted from when it commits. ``rev``
+    says which positions are revealed (the prompt's tail from the start),
+    ``tok``/``lp``/``top``/``rpass`` what each holds, the log-probabilities
+    it was revealed with and the pass that revealed it; ``pidx`` is the
+    next pass's index within the block."""
+
+    __slots__ = ("tok", "rev", "lp", "top", "rpass", "pidx")
+
+    def __init__(self, block: int, tail_tokens) -> None:
+        n = len(tail_tokens)
+        self.tok = np.zeros(block, np.int32)
+        self.tok[:n] = tail_tokens
+        self.rev = np.zeros(block, bool)
+        self.rev[:n] = True
+        self.lp = np.zeros(block, np.float32)
+        self.top: List[Optional[Dict[int, float]]] = [None] * block
+        self.rpass = np.zeros(block, np.int32)
+        self.pidx = 0
 
 
 class ScheduledEngineBase(EngineBase):
@@ -142,6 +166,12 @@ class ScheduledEngineBase(EngineBase):
         # dispatch touched: a device scalar until the result is fetched
         self.last_experts_touched: Any = None
         self._last_dispatch_end: Optional[float] = None
+        # what the passes of generation by diffusion over blocks did
+        # (dynamo_worker_gen_*; worker/metrics.py): row-passes by kind,
+        # positions revealed, blocks committed
+        self.gen_counts: Dict[str, int] = {
+            "passes_reveal": 0, "passes_commit": 0, "tokens_revealed": 0,
+            "blocks_committed": 0}
 
     # -- subclass hook -----------------------------------------------------
 
@@ -239,7 +269,10 @@ class ScheduledEngineBase(EngineBase):
             rows = len(seqs)
             width = getattr(plan, "width", 0) or 0
             if kind == "multistep":
-                tokens_real = rows * width
+                # (a pass dispatch: times the block, until its result
+                # says which rows lived, ``_process_passes``)
+                tokens_real = rows * width * max(
+                    1, self.scheduler.cfg.gen_block)
             elif kind in ("prefill", "mixed"):
                 chunks = getattr(plan, "chunks", ()) or ()
                 dec = getattr(plan, "decode_seqs", ()) or ()
@@ -326,13 +359,15 @@ class ScheduledEngineBase(EngineBase):
                 token: Optional[int] = None,
                 logprob: Optional[float] = None,
                 kv_transfer_params: Optional[dict] = None,
-                top: Optional[Dict[int, float]] = None) -> None:
+                top: Optional[Dict[int, float]] = None,
+                reveal_pass: Optional[int] = None) -> None:
         self.scheduler.finish(seq)
         self.release_request(seq.request.request_id)
         out = LLMEngineOutput(
             token_ids=[token] if token is not None else [],
             log_probs=[logprob] if logprob is not None else None,
             top_logprobs=[top] if top is not None else None,
+            reveal_pass=None if reveal_pass is None else [reveal_pass],
             finish_reason=reason,
             prompt_tokens=seq.num_prompt,
             completion_tokens=len(seq.generated),
@@ -346,6 +381,11 @@ class ScheduledEngineBase(EngineBase):
             # StageStitcher turns these into decode-span attrs
             out.timings = {"decode_steps": float(seq.decode_steps),
                            "decode_dispatches": float(seq.decode_dispatches)}
+            if seq.gen_passes:
+                # generation by diffusion over blocks: the forward passes
+                # and committed blocks behind those tokens
+                out.timings["passes"] = float(seq.gen_passes)
+                out.timings["blocks"] = float(seq.gen_blocks)
             if seq.multistep_fallbacks:
                 # fused-path refusals that touched this sequence: the
                 # decode span carries the count so a slow stream is
@@ -381,8 +421,11 @@ class ScheduledEngineBase(EngineBase):
         engines run guided rows per-step only — nothing to check."""
 
     def _accept_token(self, seq: Sequence, token: int, logprob: float,
-                      top: Optional[Dict[int, float]] = None) -> None:
-        """Append a sampled token and resolve stop conditions."""
+                      top: Optional[Dict[int, float]] = None,
+                      reveal_pass: Optional[int] = None) -> None:
+        """Append a sampled token and resolve stop conditions.
+        ``reveal_pass`` (generation by diffusion over blocks) rides the
+        token's frame: the pass of its block that revealed it."""
         req = seq.request
         sc = req.stop_conditions
         seq.tokens.append(token)
@@ -390,19 +433,23 @@ class ScheduledEngineBase(EngineBase):
         n = len(seq.generated)
         min_ok = sc.min_tokens is None or n >= sc.min_tokens
         if (not sc.ignore_eos and min_ok and token in req.eos_token_ids):
-            self._finish(seq, FinishReason.EOS, token, logprob, top=top)
+            self._finish(seq, FinishReason.EOS, token, logprob, top=top,
+                         reveal_pass=reveal_pass)
             return
         if min_ok and sc.stop_token_ids and token in sc.stop_token_ids:
-            self._finish(seq, FinishReason.STOP, token, logprob, top=top)
+            self._finish(seq, FinishReason.STOP, token, logprob, top=top,
+                         reveal_pass=reveal_pass)
             return
         max_new = sc.max_tokens if sc.max_tokens is not None else (
             self.max_context - seq.num_prompt)
         if n >= max_new or len(seq) >= self.max_context:
-            self._finish(seq, FinishReason.LENGTH, token, logprob, top=top)
+            self._finish(seq, FinishReason.LENGTH, token, logprob, top=top,
+                         reveal_pass=reveal_pass)
             return
         self._emit(seq, LLMEngineOutput(
             token_ids=[token], log_probs=[logprob],
-            top_logprobs=[top] if top is not None else None))
+            top_logprobs=[top] if top is not None else None,
+            reveal_pass=None if reveal_pass is None else [reveal_pass]))
 
     def _plan_spec_appends(self, seq: Sequence,
                            cand: List[Tuple[int, float, int]]
@@ -499,6 +546,9 @@ class ScheduledEngineBase(EngineBase):
         ``_accept_token`` exactly), advance KV accounting over the written
         prefix, then stream the tokens out — one frame per token per row,
         so a token never waits on the rest of its block being processed."""
+        if isinstance(plan, GenPassBatch):
+            self._process_passes(plan, sampled)
+            return
         top_ids = extras.get("top_ids") if extras else None  # [B, w, K]
 
         def top_for(i: int, j: int, seq: Sequence
@@ -549,11 +599,91 @@ class ScheduledEngineBase(EngineBase):
                 # before the next block samples from a wrong state
                 self.multistep_guided_check(seq)
         self.scheduler.commit_block(plan)
+        self._publish_step(plan)
+
+    def _publish_step(self, plan: StepPlan) -> None:
+        """What every resolved step ends in: the allocator's KV events go
+        out (always drained: unbounded growth otherwise) and the step's
+        outcome is reported."""
         events = self.allocator.drain_events()
         if events and self.kv_event_cb is not None:
             self.kv_event_cb(events)
         if self.step_outcome_cb is not None:
             self.step_outcome_cb(getattr(plan, "_step_id", None), True)
+
+    def _process_passes(self, plan: GenPassBatch, host: np.ndarray) -> None:
+        """Resolve one pass dispatch (``JaxEngine._passes_impl``'s packed
+        ``[R, w, 2 + B * (3 + 2K)]``): replay each row's passes over its
+        ``BlockState``. A revealing pass fills positions in; a committing
+        pass emits the block's tokens in position order - one frame a
+        token, each with the pass that revealed it and the
+        log-probabilities of that pass - through ``_accept_token``, so
+        ``max_tokens`` inside a block ends the stream at exactly that
+        count and a stop token ends it where it stands. Only then do
+        ``num_computed``, the page hashes and the prefix cache move."""
+        B = self.scheduler.cfg.gen_block
+        hostf = host.view(np.float32)
+        K = (host.shape[2] - 2 - 3 * B) // (2 * B)
+        at = 2 + 3 * B
+        row_passes = revealed = commits = 0
+        for i, seq in enumerate(plan.seqs):
+            if seq.phase is not Phase.RUNNING:
+                continue    # ended before this (chained) dispatch ran
+            if seq.cancelled:
+                # the device kept denoising; nothing of it was committed
+                self._finish(seq, FinishReason.CANCELLED)
+                continue
+            want_top = K and seq.request.sampling_options.logprobs is not None
+            seq.decode_dispatches += 1
+            bs = seq.block_state
+            for j in range(plan.width):
+                if not host[i, j, 0]:
+                    break           # its budget ran out on the device
+                row_passes += 1
+                seq.gen_passes += 1
+                start = seq.num_computed
+                tail = len(seq) - start
+                if bs is None:
+                    # (only a prompt's first block has a tail to look up)
+                    bs = BlockState(
+                        B, seq.tokens.tokens()[start:] if tail else ())
+                if not host[i, j, 1]:
+                    for b in np.flatnonzero(host[i, j, 2:2 + B]):
+                        bs.tok[b] = host[i, j, 2 + B + b]
+                        bs.lp[b] = hostf[i, j, 2 + 2 * B + b]
+                        bs.rpass[b] = bs.pidx
+                        bs.rev[b] = True
+                        revealed += 1
+                        if want_top:
+                            lo = at + b * K
+                            bs.top[b] = {
+                                int(t): float(l) for t, l in zip(
+                                    host[i, j, lo:lo + K],
+                                    hostf[i, j, lo + B * K:lo + B * K + K])}
+                    bs.pidx += 1
+                    continue
+                commits += 1
+                seq.gen_blocks += 1
+                for b in range(tail, B):
+                    seq.decode_steps += 1
+                    self._accept_token(seq, int(bs.tok[b]), float(bs.lp[b]),
+                                       bs.top[b], reveal_pass=int(bs.rpass[b]))
+                    if seq.phase is not Phase.RUNNING:
+                        break
+                bs = None
+                if seq.phase is not Phase.RUNNING:
+                    break
+                seq.num_computed = start + B
+                self.scheduler._commit_full_pages(seq)
+            seq.block_state = bs
+        counts = self.gen_counts
+        counts["passes_commit"] += commits
+        counts["passes_reveal"] += row_passes - commits
+        counts["tokens_revealed"] += revealed
+        counts["blocks_committed"] += commits
+        self.steptrace.note_passes(plan._steprec, plan.width, row_passes,
+                                   revealed, commits, row_passes * B)
+        self._publish_step(plan)
 
     def _process(self, plan: StepPlan, sampled: np.ndarray,
                  logprobs: np.ndarray,
@@ -577,6 +707,10 @@ class ScheduledEngineBase(EngineBase):
                 if seq.cancelled:
                     self._finish(seq, FinishReason.CANCELLED)
                 elif chunk.is_last:
+                    if self.scheduler.cfg.gen_block > 1:
+                        # block diffusion: a prefill writes the prompt's
+                        # whole blocks and nothing is sampled from it
+                        continue
                     if seq.request.prefill_only:
                         # disagg prefill worker: one token, KV stays cached;
                         # the final frame advertises the transferable blocks
@@ -623,12 +757,7 @@ class ScheduledEngineBase(EngineBase):
                 seq.decode_steps += 1
                 self._accept_token(seq, int(sampled[i]), float(logprobs[i]),
                                    top_for(i, seq))
-        # always drain (unbounded growth otherwise); publish if anyone listens
-        events = self.allocator.drain_events()
-        if events and self.kv_event_cb is not None:
-            self.kv_event_cb(events)
-        if self.step_outcome_cb is not None:
-            self.step_outcome_cb(getattr(plan, "_step_id", None), True)
+        self._publish_step(plan)
 
     # -- serialized out-of-band work ---------------------------------------
 
@@ -830,6 +959,12 @@ class ScheduledEngineBase(EngineBase):
                 if isinstance(plan, DecodeBatch):
                     if self.supports_multistep:
                         ms = self.scheduler.plan_multistep(plan)
+                        if ms is None and self.scheduler.cfg.gen_block > 1:
+                            # no row of a block-diffusion batch could be
+                            # planned (the pool: they were preempted and
+                            # wait): there is no one-token step to fall
+                            # back on
+                            plan = None
                     else:
                         reason = self.multistep_unsupported_reason
                         if reason is not None:

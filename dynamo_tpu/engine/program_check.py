@@ -139,7 +139,9 @@ def step_programs(engine, batch: int, chunk: int, width: int = 8,
                   sharding=None, num_pages: Optional[int] = None,
                   tokens: Optional[int] = None) -> Dict[str, tuple]:
     """``name -> (jitted program, its arguments as shapes)`` for the decode
-    step ``[batch, 1]``, the fused block of ``width`` decode steps, the
+    step ``[batch, 1]``, the fused block of ``width`` decode steps (of an
+    engine that generates by diffusion over blocks, in their place, the
+    fused dispatch of ``width`` passes), the
     padded prefill-carrying step ``[batch, chunk]``, where the engine
     packs (``engine.padded_reason`` is None) the token-packed step of
     ``tokens`` slots (default ``chunk``) over ``batch`` rows, and where
@@ -175,6 +177,24 @@ def step_programs(engine, batch: int, chunk: int, width: int = 8,
             sds((B,), i32), sds((B,), f32), sds((B, 1), i32), None, None)),
         "mixed": (engine._jit_step, step_args(B, chunk)),
     }
+    G = getattr(engine, "gen_block", 1)
+    if G > 1:
+        # generation by diffusion over blocks: no program feeds one token
+        # a row; the fused dispatch scans ``width`` passes over
+        # ``[batch, G]`` (the revealing and the committing pass are one
+        # program)
+        del out["decode"], out["fused"]
+        state = {"tok": sds((B, G), i32), "rev": sds((B, G), jnp.bool_),
+                 "alive": sds((B,), jnp.bool_),
+                 **{k: sds((B,), i32)
+                    for k in ("pidx", "start", "tail", "budget")}}
+        samp = {"temp": sds((B,), f32), "top_k": sds((B,), i32),
+                "top_p": sds((B,), f32), "seeds": sds((B,), i32),
+                "min_p": sds((B,), f32), "steps": sds((B,), i32),
+                "tau": sds((B,), f32)}
+        out["passes"] = (engine._get_jit_passes(width), (
+            params, pages, sds((B, engine.table_width), i32), state,
+            sds((2,), jnp.uint32), sds((), i32), samp))
     if engine.padded_reason is None:
         out["packed"] = (engine._jit_packed,
                          step_args(B, tokens or chunk, lead=1))

@@ -69,7 +69,8 @@ class Sequence:
                  "phase", "cancelled", "arrival", "salt_hash",
                  "enqueued_unix", "admitted_unix", "timings_sent",
                  "decode_steps", "decode_dispatches", "table_version",
-                 "multistep_fallbacks", "compile_ms", "compile_events")
+                 "multistep_fallbacks", "compile_ms", "compile_events",
+                 "block_state", "gen_passes", "gen_blocks")
 
     def __init__(self, request: PreprocessedRequest, page_size: int,
                  salt_hash: int = 0):
@@ -113,6 +114,13 @@ class Sequence:
         # the request trace carries an xla_compile event
         self.compile_ms = 0.0
         self.compile_events = 0
+        # generation by diffusion over blocks: the block being denoised
+        # (engine/loop.py ``BlockState``; None = all masks past the
+        # prompt's tail), and the forward passes and committed blocks
+        # this request cost (the decode span's ``passes`` / ``blocks``)
+        self.block_state = None
+        self.gen_passes = 0
+        self.gen_blocks = 0
 
     def pages_changed(self) -> None:
         self.table_version += 1
@@ -205,6 +213,31 @@ class MultiStepBatch:
 
 
 @dataclass
+class GenPassBatch(MultiStepBatch):
+    """One fused dispatch of ``width`` forward PASSES of generation by
+    diffusion over blocks: every live row runs its current block of
+    ``gen_block`` positions through the model ``width`` times, each row
+    in its own phase. A pass over a block that still holds masks reveals
+    some of them (``ops/sampling.reveal``); a pass over a block without
+    masks is its COMMITTING pass: its keys and values are the final
+    ones, the row moves on to the next block and the host emits the
+    block's tokens. It is a ``MultiStepBatch`` to the loop (one dispatch,
+    one fetch, chained through a device carry), with the tokens of a
+    pass counted per row, 0 to ``gen_block``.
+
+    ``start_lens[i]`` is row i's first uncommitted position
+    (``num_computed``, a block boundary), ``tails[i]`` the prompt tokens
+    that lie in that block (``len(seq) - num_computed``: they are known
+    from the start and never emitted), ``budgets[i]`` the tokens the row
+    may still emit. ``inflight`` counts the passes of chained dispatches
+    the host has not processed yet: pages are grown for every block the
+    device may reach meanwhile."""
+
+    tails: List[int] = field(default_factory=list)
+    inflight: int = 0
+
+
+@dataclass
 class MixedStepBatch:
     """ONE token-budgeted dispatch advancing prefill chunks AND decode
     rows together — continuous batching at real occupancy instead of the
@@ -232,7 +265,7 @@ class MixedStepBatch:
 
 
 StepPlan = Union[PrefillBatch, DecodeBatch, SpecDecodeBatch, MultiStepBatch,
-                 MixedStepBatch]
+                 GenPassBatch, MixedStepBatch]
 
 
 @dataclass
@@ -300,6 +333,12 @@ class SchedulerConfig:
     # return means the grammar's table exceeded the byte cap and only
     # that batch falls back, under the "guided_table" reason.
     guided_fuse_check: Optional[Callable] = None
+    # generation by diffusion over blocks (``ModelConfig.gen_block``; 1 =
+    # a causal model, every path as it was): prompts are prefilled in
+    # whole blocks and cut on block boundaries, running rows advance by
+    # fused pass dispatches (``GenPassBatch``) and wait through an
+    # admission step instead of riding it (``gen_rows_waited``)
+    gen_block: int = 1
 
 
 # what bounds a run of consecutive prefill-carrying (mixed) steps: what
@@ -364,6 +403,9 @@ class Scheduler:
         self._steps_since_decode = 0
         # mixed-dispatch diagnostics (the engine also counts dispatches)
         self.mixed_plans = 0
+        # block diffusion: running rows that waited through an admission
+        # step (a prefill step carries no row in mid-block)
+        self.gen_rows_waited = 0
         # admission runs: consecutive prefill-carrying (mixed) steps, by
         # what ended each (``RUN_ENDS``), and the steps they held; the
         # ratio is a run's mean length (dynamo_worker_sched_admission_*)
@@ -460,7 +502,11 @@ class Scheduler:
         # sequence len(seq) includes generated tokens; the revive covers them
         # too since its full pages were committed before release.)
         match = self.alloc.match_prefix(hashes)
-        cached = min(match.num_pages * self.page_size, len(seq) - 1)
+        # (block diffusion: the prompt's whole blocks are all there is to
+        # prefill, and no logits are taken from them)
+        cached = min(match.num_pages * self.page_size,
+                     len(seq) - 1 if self.cfg.gen_block <= 1
+                     else self._prefill_target(seq))
         full_cached_pages = cached // self.page_size
         if full_cached_pages < match.num_pages:
             self.alloc.release(match.page_ids[full_cached_pages:])
@@ -493,8 +539,23 @@ class Scheduler:
         if not seq.generated:  # first admission: report the prefix hit
             seq.cached_tokens = cached
         seq.phase = Phase.PREFILL
+        self._skip_empty_prefill(seq)
         self.active[seq.request.request_id] = seq
         return seq
+
+    def _prefill_target(self, seq: Sequence) -> int:
+        """Positions a prefill computes: every token of a causal row; of
+        a block-diffusion row the whole blocks (the tail rides the first
+        generated block: its block's other positions are still masks)."""
+        B = self.cfg.gen_block
+        return len(seq) if B <= 1 else len(seq) // B * B
+
+    def _skip_empty_prefill(self, seq: Sequence) -> None:
+        """A block-diffusion row whose whole blocks are all cached (or
+        whose prompt is shorter than a block) has nothing to prefill."""
+        if (self.cfg.gen_block > 1 and seq.phase is Phase.PREFILL
+                and seq.num_computed >= self._prefill_target(seq)):
+            seq.phase = Phase.RUNNING
 
     def _pages_needed(self, num_tokens: int) -> int:
         # positions [0, num_tokens-1] must be addressable
@@ -534,6 +595,7 @@ class Scheduler:
         victim.pages_changed()
         victim.committed_pages = 0
         victim.num_computed = 0
+        victim.block_state = None   # it resumes at a block boundary
         victim.phase = Phase.WAITING
         self.active.pop(victim.request.request_id)
         self.waiting.appendleft(victim)
@@ -609,6 +671,7 @@ class Scheduler:
         for s in self.active.values():
             if s.phase == Phase.PREFILL:
                 self._adopt_resident(s)
+                self._skip_empty_prefill(s)
         rt = self.cfg.ring_threshold
 
         def ring_eligible(s: Sequence) -> bool:
@@ -679,8 +742,14 @@ class Scheduler:
                 break
             # len(seq), not num_prompt: a revived preempted sequence must
             # also re-prefill the tokens it had generated before eviction
-            remaining = len(seq) - seq.num_computed
+            remaining = self._prefill_target(seq) - seq.num_computed
             length = min(remaining, budget)
+            if length < remaining:
+                # a chunk ends on a block boundary (any position is one
+                # for a causal row)
+                length -= length % self.cfg.gen_block
+                if length <= 0:
+                    break
             chunks.append(PrefillChunk(seq=seq, start=seq.num_computed,
                                        length=length,
                                        is_last=(length == remaining)))
@@ -760,7 +829,10 @@ class Scheduler:
                 # rides the next run's first step, as it always has
                 batch = None
             if batch is not None:
-                if (self.cfg.mixed_batch and not batch.ring
+                if self.cfg.gen_block > 1:
+                    # rows in mid-block wait through an admission step
+                    self.gen_rows_waited += len(decodable)
+                elif (self.cfg.mixed_batch and not batch.ring
                         and self.cfg.spec_tokens == 0 and decodable):
                     ready = self._grow_ready(decodable)
                     # re-filter: growth may have preempted a planned chunk's
@@ -790,13 +862,27 @@ class Scheduler:
                     # legacy (or decode-less) prefill step; under a deep
                     # waiting queue keep preferring prefill up to the
                     # decode-progress bound
+                    # (block diffusion: running rows wait through an
+                    # admission step instead of riding it, so a wave of
+                    # prompts is prefilled in consecutive steps, as far
+                    # as the guarantee allows, and its rows then run
+                    # their passes together)
+                    more = self.waiting or (
+                        self.cfg.gen_block > 1 and any(
+                            s.phase is Phase.PREFILL
+                            for s in self.active.values()))
                     self._prefer_prefill = bool(
-                        self.waiting and K > 0
+                        more and K > 0
                         and self._steps_since_decode + 1 < K - 1)
                     if decodable:
                         self._steps_since_decode += 1
                     return batch
         self._prefer_prefill = True
+        if self.cfg.gen_block > 1:
+            # an admission may have put a row straight to RUNNING (its
+            # prompt's whole blocks were all cached: nothing to prefill)
+            decodable = [s for s in self.active.values()
+                         if s.phase == Phase.RUNNING]
         if not decodable:
             return None
         ready = self._grow_ready(decodable)
@@ -1162,6 +1248,8 @@ class Scheduler:
         ``plan_multistep_chained`` keeps the refusal). With it off, the
         legacy gate applies and the refusal is recorded as a fallback
         reason."""
+        if self.cfg.gen_block > 1:
+            return self._plan_passes(batch.seqs, 0)
         if not self.cfg.mixed_batch:
             if self.waiting:
                 self.record_fallback("waiters", batch.seqs)
@@ -1205,9 +1293,78 @@ class Scheduler:
             return None
         if any(s.phase is Phase.PREFILL for s in self.active.values()):
             return None
+        if isinstance(prev, GenPassBatch):
+            return self._plan_passes(prev.seqs, prev.inflight + prev.width)
         return self._plan_block(prev.seqs,
                                 [len(s) + prev.width for s in prev.seqs],
                                 chained=True)
+
+    def _gen_budget(self, seq: Sequence) -> int:
+        """Tokens a block-diffusion row may still emit (``max_tokens``
+        and the context limit; the host's ``_accept_token`` applies the
+        same two)."""
+        rem = 1 << 20
+        max_new = self._max_new(seq)
+        if max_new is not None:
+            rem = min(rem, max_new - len(seq.generated))
+        if self.max_context_hint is not None:
+            rem = min(rem, self.max_context_hint - len(seq))
+        return max(rem, 0)
+
+    def _plan_passes(self, seqs: List[Sequence],
+                     inflight: int) -> Optional[GenPassBatch]:
+        """One fused dispatch of ``decode_multistep`` passes over every
+        row's current block (``GenPassBatch``). ``inflight`` > 0 plans a
+        CHAINED dispatch: the rows' block state is the previous
+        dispatch's device carry, and the host's view of each row lags by
+        ``inflight`` passes. Pages are grown for every block a row can
+        reach by the end of this dispatch: a block takes at least two
+        passes (one that reveals, one that commits), and no row goes
+        past the block its budget ends in. A fresh plan preempts the
+        newest rows when the pool is short (they resume at a block
+        boundary); a chained one is refused instead, and the chain
+        breaks."""
+        B = self.cfg.gen_block
+        w = max(1, self.cfg.decode_multistep)
+        chained = inflight > 0
+        rows: List[Sequence] = []
+        for seq in (seqs if chained
+                    else sorted(seqs, key=lambda s: s.arrival)):
+            if seq.phase is not Phase.RUNNING:
+                if chained:
+                    rows.append(seq)        # dead in the device carry too
+                continue
+            budget = self._gen_budget(seq)
+            tail = len(seq) - seq.num_computed
+            last = seq.num_computed + (tail + budget + B - 1) // B * B
+            reach = seq.num_computed + B * ((inflight + w + 1) // 2 + 1)
+            want = self._pages_needed(min(last, reach))
+            while len(seq.page_ids) < want:
+                try:
+                    seq.page_ids.extend(
+                        self.alloc.allocate(want - len(seq.page_ids)))
+                    seq.pages_changed()
+                except OutOfPages:
+                    if chained:
+                        return None
+                    if (not self._preempt_one()
+                            or seq.phase is not Phase.RUNNING):
+                        break
+            if seq.phase is Phase.RUNNING and len(seq.page_ids) >= want:
+                rows.append(seq)
+        # a fresh plan's later growth may have preempted an earlier row
+        rows = [s for s in rows if chained or s.phase is Phase.RUNNING]
+        if not any(s.phase is Phase.RUNNING for s in rows):
+            return None
+        live = [s.phase is Phase.RUNNING for s in rows]
+        return GenPassBatch(
+            seqs=rows, width=w, chained=chained, inflight=inflight,
+            start_lens=[s.num_computed for s in rows],
+            tails=[len(s) - s.num_computed if a else 0
+                   for s, a in zip(rows, live)],
+            budgets=[self._gen_budget(s) if a else 0
+                     for s, a in zip(rows, live)],
+            min_gates=[0] * len(rows))
 
     def on_multistep_done(self, plan: MultiStepBatch,
                           advances: List[int]) -> None:

@@ -108,7 +108,8 @@ class StepRecord:
               "pool_free", "pool_pinned", "plan_ms", "dispatch_ms",
               "fetch_ms", "process_ms", "unpack_ms", "device_ms",
               "ready_unix", "gap_ms", "compile_ms", "fallback", "chained",
-              "experts_touched")
+              "experts_touched", "passes", "row_passes", "revealed",
+              "commits")
     # _enqueue: perf_counter at the start of the enqueue, kept until the
     # result arrives and device_ms can be taken; _experts: the dispatch's
     # count of experts touched while it is still a device scalar; neither
@@ -143,6 +144,14 @@ class StepRecord:
         # experts the dispatch read, summed over its expert layers and
         # steps (MoE families' grouped layer; 0 elsewhere)
         self.experts_touched = 0
+        # a dispatch of generation by diffusion over blocks (0 elsewhere):
+        # forward passes it scanned, passes summed over the rows alive at
+        # each (a pass serves every live row), positions those passes
+        # revealed, and blocks they committed
+        self.passes = 0
+        self.row_passes = 0
+        self.revealed = 0
+        self.commits = 0
         self._enqueue = 0.0
         self._experts = None
 
@@ -334,6 +343,7 @@ class StepRecorder:
             rec.fallback = fallback
             rec.chained = chained
             rec.experts_touched = 0
+            rec.passes = rec.row_passes = rec.revealed = rec.commits = 0
             rec._enqueue = enqueue
             rec._experts = experts
             if tokens_padded > 0:
@@ -386,6 +396,20 @@ class StepRecorder:
             rec.fetch_ms = fetch_ms
             rec.process_ms = process_ms
             rec.unpack_ms = fetch_ms + process_ms
+
+    def note_passes(self, rec: Optional[StepRecord], passes: int,
+                    row_passes: int, revealed: int, commits: int,
+                    positions: int) -> None:
+        """What a pass dispatch did, known once its result is unpacked:
+        rows die inside a dispatch, so ``tokens_real`` - the positions
+        it computed, ``row_passes`` times the block length - is known
+        only now too."""
+        if rec is None or not self.enabled:
+            return
+        with self._lock:
+            rec.passes, rec.row_passes = passes, row_passes
+            rec.revealed, rec.commits = revealed, commits
+            rec.tokens_real = positions
 
     def note_compile(self, kind: str, seconds: float,
                      rec: Optional[StepRecord] = None) -> None:

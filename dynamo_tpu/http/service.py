@@ -67,6 +67,9 @@ def _legacy_logprobs(entries: List[dict], offset_start: int = 0):
              for t in e.get("top_logprobs", [])} or None)
         out["text_offset"].append(off)
         off += len(e["token"])
+        if "reveal_pass" in e:
+            # one integer a token, beside the arrays OpenAI defines
+            out.setdefault("reveal_pass", []).append(e["reveal_pass"])
     return out, off
 
 

@@ -101,6 +101,17 @@ class ModelConfig:
     attn_logit_softcap: float = 0.0    # 0 = disabled
     final_logit_softcap: float = 0.0
     query_pre_attn_scalar: float = 0.0  # 0 = use head_dim
+    # how the model generates. "causal": one next token a row a step.
+    # "block_diffusion" (sdar, sdar_moe): positions are cut into blocks of
+    # ``gen_block`` from 0, a query sees every key of its own and earlier
+    # blocks, the logits at a position score the token AT that position,
+    # and a block is denoised in place - passes over ``[rows, gen_block]``
+    # reveal masked positions (``mask_token_id`` stands in for them) by
+    # confidence, then one pass commits the finished block's keys and
+    # values (``engine/scheduler.py`` ``GenPassBatch``)
+    generation: str = "causal"
+    gen_block: int = 1
+    mask_token_id: int = -1
 
     @property
     def q_size(self) -> int:
@@ -115,6 +126,19 @@ class ModelConfig:
             raise ValueError(
                 f"moe_backend {self.moe_backend!r}: 'grouped' (the exact "
                 "expert layer) or 'dispatch' (capacity-factor, wide-EP)")
+        if self.generation not in ("causal", "block_diffusion"):
+            raise ValueError(f"generation {self.generation!r}: 'causal' or "
+                             "'block_diffusion'")
+        if (self.generation == "block_diffusion") != (self.gen_block > 1):
+            raise ValueError(
+                f"generation {self.generation!r} with gen_block "
+                f"{self.gen_block}: block diffusion needs a block of at "
+                "least 2 positions and a causal model has none")
+        if self.gen_block > 1 and not (
+                0 <= self.mask_token_id < self.vocab_size):
+            raise ValueError(
+                f"mask_token_id {self.mask_token_id} outside the "
+                f"vocabulary of {self.vocab_size}")
 
     @classmethod
     def from_hf(cls, hf: Dict[str, Any], dtype: str = "bfloat16") -> "ModelConfig":
@@ -164,6 +188,16 @@ class ModelConfig:
             extra["rope_interleave"] = bool(
                 hf.get("rope_interleave", True))
         mla = bool(extra.get("kv_lora_rank"))
+        if mt in ("sdar", "sdar_moe"):
+            # generation by diffusion over blocks (the Qwen3 / Qwen3-MoE
+            # block under a block-wise visibility). The published config
+            # carries neither size: ``block_size`` and ``mask_token_id``
+            # are read from the file's keys where it states them
+            extra.update(
+                generation="block_diffusion",
+                gen_block=int(hf.get("block_size")
+                              or hf.get("block_length") or 4),
+                mask_token_id=int(hf.get("mask_token_id", 151669)))
         return cls(
             vocab_size=hf["vocab_size"],
             hidden_size=hf["hidden_size"],
@@ -184,7 +218,7 @@ class ModelConfig:
             # gemma ties embeddings by default and serializes nothing
             tie_word_embeddings=bool(hf.get("tie_word_embeddings",
                                             mt.startswith("gemma"))),
-            qk_norm=mt in ("qwen3", "qwen3_moe"),
+            qk_norm=mt in ("qwen3", "qwen3_moe", "sdar", "sdar_moe"),
             attention_bias=bool(hf.get("attention_bias", mt == "qwen2")),
             model_type=mt,
             dtype=dtype,

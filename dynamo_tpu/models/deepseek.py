@@ -49,8 +49,10 @@ import numpy as np
 
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.llama import (
+    MOE_INIT_GAIN as INIT_GAIN,
     _logits,
     _rms_norm,
+    randn_stack as _randn_stack,
     make_pages,
 )
 from dynamo_tpu.ops.attention import NEG_INF, write_kv
@@ -140,21 +142,6 @@ def rope_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
 
 # ------------------------------------------------------------------- params
 
-def _randn_stack(key, n: int, shape: tuple, scale: float,
-                 dtype) -> jnp.ndarray:
-    """``[n, *shape]`` normal weights drawn a layer at a time inside one
-    program, each from its own split key, straight into ``dtype``: no
-    float32 copy of the whole stack ever exists (a stacked expert matrix
-    of 4 x 256 x 2048 x 768 is 6.4 GB in float32, twice over if drawn in
-    one call, beside the 11 GB the finished weights take)."""
-    @jax.jit
-    def draw(keys):
-        return jax.lax.map(
-            lambda k: (jax.random.normal(k, shape, jnp.float32)
-                       * scale).astype(dtype), keys)
-    return draw(jax.random.split(key, n))
-
-
 def _attn_leaves(cfg: ModelConfig, key, scale: float,
                  n: int) -> Dict[str, jnp.ndarray]:
     dtype = jnp.dtype(cfg.dtype)
@@ -182,14 +169,6 @@ def _attn_leaves(cfg: ModelConfig, key, scale: float,
     else:
         leaves["wq"] = randn((H, cfg.num_heads * qk_head))
     return leaves
-
-
-# Standard deviation of the random weights, times sqrt(hidden): 0.012 at
-# the 2,048-wide models of this family, kept per fan-in so that toy widths
-# see activations of the same size. Chosen by measurement, not taken from
-# a paper (the DeepSeek papers' 0.006 and the other families' 0.02 are
-# the two ends that were tried first): see ``init_params``.
-INIT_GAIN = 0.012 * 2048 ** 0.5
 
 
 def init_params(cfg: ModelConfig, rng: jax.Array,

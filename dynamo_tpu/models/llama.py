@@ -68,6 +68,29 @@ def make_pages(cfg: ModelConfig, num_pages: int, page_size: int,
                       page_size, cfg.head_dim), dtype=dtype)
 
 
+def randn_stack(key, n: int, shape: tuple, scale: float,
+                dtype) -> jnp.ndarray:
+    """``[n, *shape]`` normal weights drawn a layer at a time inside one
+    program, each from its own split key, straight into ``dtype``: no
+    float32 copy of the whole stack ever exists (a stacked expert matrix
+    of 4 x 256 x 2048 x 768 is 6.4 GB in float32, twice over if drawn in
+    one call, beside the 11 GB the finished weights take). The MLA and
+    the MoE families' initialisers share it."""
+    @jax.jit
+    def draw(keys):
+        return jax.lax.map(
+            lambda k: (jax.random.normal(k, shape, jnp.float32)
+                       * scale).astype(dtype), keys)
+    return draw(jax.random.split(key, n))
+
+
+# Standard deviation of the sparse families' random weights, times
+# sqrt(hidden): 0.012 at their 2,048-wide models, kept per fan-in so that
+# toy widths see activations of the same size. Chosen by measurement
+# (``deepseek.init_params``), not taken from a paper.
+MOE_INIT_GAIN = 0.012 * 2048 ** 0.5
+
+
 def init_params(cfg: ModelConfig, rng: jax.Array, scale: float = 0.02) -> Params:
     """Random-normal init (for tests/benchmarks; real serving loads HF weights)."""
     dtype = jnp.dtype(cfg.dtype)
@@ -220,6 +243,14 @@ def write_rows(pages, lidx, k, v, page_table, positions, total_lens,
                            new_lens, total_lens)
 
 
+def visibility(cfg: ModelConfig) -> Dict[str, int]:
+    """What ``attend_rows`` hands the attention op beyond the causal
+    arguments: nothing for a causal model (its programs are the ones they
+    were), the visibility block of a model that generates by diffusion
+    over blocks (``ops.attention.horizon``)."""
+    return {"block": cfg.gen_block} if cfg.gen_block > 1 else {}
+
+
 def attend_rows(attn_impl, q, pages, lidx, page_table, positions,
                 total_lens, new_lens, sm_scale, starts, **kw):
     """Attention of either step form. A packed step's ``attn_impl`` has
@@ -277,7 +308,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         with jax.named_scope("layer.attn"):
             attn = attend_rows(attn_impl, q, pages, lidx, page_table,
                                positions, total_lens, new_lens, sm_scale,
-                               starts)
+                               starts, **visibility(cfg))
         with jax.named_scope("layer.ffn"):
             h = _finish_layer(cfg, lp, h, attn)
         return (h, pages), None

@@ -36,6 +36,8 @@ from dynamo_tpu.models.llama import (
     attend_rows,
     make_pages,
     packed_rows,
+    randn_stack,
+    visibility,
     write_rows,
 )
 from dynamo_tpu.models import llama
@@ -94,8 +96,10 @@ def grouped_experts(xt: jnp.ndarray, top_w: jnp.ndarray,
         sorted_e = flat_e[order]
         sorted_t = order // k
         # first[e]: assignments of experts below e; first[E]: all routed
-        first = jnp.searchsorted(sorted_e, jnp.arange(E + 1, dtype=i32)
-                                 ).astype(i32)
+        # (compare_all: one fused comparison of every pair; the default
+        # binary search is a loop of a dozen small device operations)
+        first = jnp.searchsorted(sorted_e, jnp.arange(E + 1, dtype=i32),
+                                 method="compare_all").astype(i32)
         counts = first[1:] - first[:-1]                     # [E]
     aux = {"moe_experts_touched": jnp.sum(counts > 0).astype(i32),
            "moe_assignments": first[E]}
@@ -120,7 +124,7 @@ def grouped_experts(xt: jnp.ndarray, top_w: jnp.ndarray,
             row_first = (tile_end - tiles) * tm             # [E]
             tile_expert = jnp.minimum(jnp.searchsorted(
                 tile_end, jnp.arange(n_tiles, dtype=i32),
-                side="right"), E - 1).astype(i32)
+                side="right", method="compare_all"), E - 1).astype(i32)
             # each row's token, from the row's side: the tile's expert,
             # the row's rank in its group, the sorted assignment there
             row = jnp.arange(M, dtype=i32)
@@ -344,25 +348,37 @@ def grouped_on_chip(attn_impl) -> bool:
 
 
 def init_params(cfg: ModelConfig, rng: jax.Array,
-                scale: float = 0.02) -> Params:
-    """Random init; attention/embedding weights come from llama.init_params,
-    dense-MLP weights are replaced by the expert stack."""
-    params = llama.init_params(cfg, rng, scale)
+                scale: Optional[float] = None) -> Params:
+    """Random init (tests/benchmarks; the benchmark's worker and its
+    reference child both call this, so both hold the same weights).
+    Attention and embedding weights come from ``llama.init_params`` (with
+    a dense FFN one column wide in place of the one it would draw and
+    drop); the expert stacks are drawn a layer at a time inside one
+    program (``llama.randn_stack``), so no float32 copy of a whole stack
+    ever exists: ``w_gate`` of 7 layers x 128 experts x 2048 x 768 is 5.6
+    GB in float32 beside the 10 GB the finished weights take.
+
+    ``scale`` defaults to ``MOE_INIT_GAIN / sqrt(hidden)`` (0.012 at a
+    hidden size of 2,048), the sparse families' measured scale
+    (``deepseek.init_params``): bfloat16 against float32 swaps one of the
+    chosen experts at the top-k boundary in some tokens, and the scale
+    sets what a swap costs against what a real fault costs."""
+    if scale is None:
+        scale = llama.MOE_INIT_GAIN / cfg.hidden_size ** 0.5
+    import dataclasses
+    params = llama.init_params(
+        dataclasses.replace(cfg, intermediate_size=1), rng, scale)
     layers = params["layers"]
-    for k in ("w_gate", "w_up", "w_down"):
+    for k in EXPERT_LEAVES:
         del layers[k]
     dtype = jnp.dtype(cfg.dtype)
     L, H, E = cfg.num_layers, cfg.hidden_size, cfg.num_experts
     I = cfg.moe_intermediate_size or cfg.intermediate_size
     keys = iter(jax.random.split(jax.random.fold_in(rng, 7), 4))
-
-    def randn(key, shape):
-        return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
-
-    layers["w_router"] = randn(next(keys), (L, H, E))
-    layers["w_gate"] = randn(next(keys), (L, E, H, I))
-    layers["w_up"] = randn(next(keys), (L, E, H, I))
-    layers["w_down"] = randn(next(keys), (L, E, I, H))
+    layers["w_router"] = randn_stack(next(keys), L, (H, E), scale, dtype)
+    layers["w_gate"] = randn_stack(next(keys), L, (E, H, I), scale, dtype)
+    layers["w_up"] = randn_stack(next(keys), L, (E, H, I), scale, dtype)
+    layers["w_down"] = randn_stack(next(keys), L, (E, I, H), scale, dtype)
     return params
 
 
@@ -381,7 +397,8 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     stats)."""
     sm_scale = cfg.head_dim ** -0.5
     starts = packed_rows(packed, new_lens)
-    h = params["embed"][tokens]
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens]
     B, S = tokens.shape
     # slots that hold no token route to no expert
     valid = (jnp.arange(S) < jnp.sum(new_lens) if packed
@@ -393,11 +410,16 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     def body(carry, xs):
         h, pages = carry
         lp, lidx = xs
-        q, k, v = _project_qkv(cfg, lp, h, positions)
-        pages = write_rows(pages, lidx, k, v, page_table, positions,
-                           total_lens, new_lens, starts)
-        attn = attend_rows(attn_impl, q, pages, lidx, page_table, positions,
-                           total_lens, new_lens, sm_scale, starts)
+        # the stage names of ``llama.forward`` (docs/observability.md)
+        with jax.named_scope("layer.attn_in"):
+            q, k, v = _project_qkv(cfg, lp, h, positions)
+        with jax.named_scope("layer.kv_write"):
+            pages = write_rows(pages, lidx, k, v, page_table, positions,
+                               total_lens, new_lens, starts)
+        with jax.named_scope("layer.attn"):
+            attn = attend_rows(attn_impl, q, pages, lidx, page_table,
+                               positions, total_lens, new_lens, sm_scale,
+                               starts, **visibility(cfg))
         grouped = dict(kw, layer=lidx) if experts else {}
         h, aux = _moe_layer_tail(cfg, {**lp, **experts}, h, attn,
                                  ep_mesh=ep_mesh, **grouped)
@@ -405,8 +427,10 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 
     (h, pages), aux = jax.lax.scan(
         body, (h, pages), (scanned, jnp.arange(cfg.num_layers)))
-    return (_logits(cfg, params, h, new_lens, window=logits_window,
-                    starts=starts), pages, sum_aux(aux))
+    with jax.named_scope("logits"):
+        logits = _logits(cfg, params, h, new_lens, window=logits_window,
+                         starts=starts)
+    return logits, pages, sum_aux(aux)
 
 
 forward.supports_packed = True

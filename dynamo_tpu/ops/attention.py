@@ -45,6 +45,10 @@ import jax.numpy as jnp
 
 NEG_INF = -1e30
 
+# new tokens a row up to which ``_write_pages`` lays them over their slots
+# by selects instead of a per-row shift
+SELECT_SHIFT_MAX = 8
+
 
 def _write_pages(pool: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
                  v_new: jnp.ndarray, page_table: jnp.ndarray,
@@ -80,6 +84,18 @@ def _write_pages(pool: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
         # one token: whichever slot the mask below picks holds it (XLA runs
         # the shift as a loop over rows, 57 us a decode layer on a v5e)
         laid = jnp.broadcast_to(new, (B, page_size, 2, Hkv, Dh))
+    elif S <= SELECT_SHIFT_MAX:
+        # a few tokens (a pass over a block of diffusion generation, a
+        # verify window): each laid over its slot by a select. The shift
+        # below runs as a loop over rows, a dozen small operations a row a
+        # layer: at [32, 4] they were 74,000 of a million device
+        # operations in 6 s (PERF.md section 6, PR 37)
+        tok_at = (jnp.arange(J * page_size, dtype=start.dtype)[None, :]
+                  - off[:, None])                  # the token at a slot
+        laid = jnp.zeros((B, J * page_size, 2, Hkv, Dh), pool.dtype)
+        for s in range(S):
+            laid = jnp.where((tok_at == s)[:, :, None, None, None],
+                             new[:, s][:, None], laid)
     else:
         laid = jax.vmap(
             lambda buf, row, o: jax.lax.dynamic_update_slice_in_dim(
@@ -169,9 +185,22 @@ def _softcap(scores: jnp.ndarray, cap) -> jnp.ndarray:
     return jnp.tanh(scores / cap) * cap
 
 
+def horizon(positions, block: int = 1):
+    """The last key position each query sees. ``block`` 1 is the causal
+    mask (a query sees itself and what lies before: the array comes back
+    as it is, so a causal program is the program it was); ``block`` B > 1
+    is the visibility of generation by diffusion over blocks: positions
+    are cut into blocks of B from 0, and query ``i`` sees key ``j`` iff
+    ``j // B <= i // B`` - every key of its own and of earlier blocks."""
+    if block <= 1:
+        return positions
+    return positions // block * block + (block - 1)
+
+
 def _attend(qg: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             positions: jnp.ndarray, total_lens: jnp.ndarray,
-            sm_scale: float, window=None, softcap=None) -> jnp.ndarray:
+            sm_scale: float, window=None, softcap=None,
+            block: int = 1) -> jnp.ndarray:
     """qg [B,S,Hkv,G,Dh]; k/v [B,Hkv,T,Dh] -> [B,S,Hkv*G,Dh].
 
     ``window`` (traced int32, 0 = unlimited) restricts each query to the
@@ -183,7 +212,7 @@ def _attend(qg: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         k.astype(jnp.float32)) * sm_scale  # [B,Hkv,S,G,T]
     scores = _softcap(scores, softcap)
     t_pos = jnp.arange(T)[None, None, :]                   # [1, 1, T]
-    causal = t_pos <= positions[:, :, None]                # [B, S, T]
+    causal = t_pos <= horizon(positions, block)[:, :, None]  # [B, S, T]
     valid = t_pos < total_lens[:, None, None]              # [B, 1, T]
     if window is not None:
         in_win = (window <= 0) | (t_pos > positions[:, :, None] - window)
@@ -204,7 +233,8 @@ def _attend_blockwise(qg: jnp.ndarray, gather_chunk, num_table_pages: int,
                       page_size: int, chunk_pages: int,
                       positions: jnp.ndarray, total_lens: jnp.ndarray,
                       sm_scale: float, window=None, softcap=None,
-                      return_partials: bool = False) -> jnp.ndarray:
+                      return_partials: bool = False,
+                      block: int = 1) -> jnp.ndarray:
     """Flash-style chunked attention over the paged context.
 
     The full-gather path above materializes ``[B,Hkv,S,G,T]`` scores — at
@@ -237,7 +267,8 @@ def _attend_blockwise(qg: jnp.ndarray, gather_chunk, num_table_pages: int,
                        preferred_element_type=jnp.float32) * sm_scale
         s = _softcap(s, softcap)
         t_pos = c * span + jnp.arange(span)
-        causal = t_pos[None, None, :] <= positions[:, :, None]   # [B,S,span]
+        causal = (t_pos[None, None, :]
+                  <= horizon(positions, block)[:, :, None])      # [B,S,span]
         if window is not None:
             in_win = ((window <= 0)
                       | (t_pos[None, None, :] > positions[:, :, None]
@@ -315,7 +346,7 @@ def ragged_paged_attention(q: jnp.ndarray, pages: jnp.ndarray, layer_idx,
                            page_table: jnp.ndarray, q_starts: jnp.ndarray,
                            q_lens: jnp.ndarray, kv_lens: jnp.ndarray,
                            sm_scale: float, window=None,
-                           softcap=None) -> jnp.ndarray:
+                           softcap=None, block: int = 1) -> jnp.ndarray:
     """Ragged paged attention over a FLATTENED mixed batch — the reference
     lowering of the kernel shape continuous batching needs (Ragged Paged
     Attention, PAPERS.md): one dispatch where each row contributes an
@@ -369,7 +400,7 @@ def ragged_paged_attention(q: jnp.ndarray, pages: jnp.ndarray, layer_idx,
 
     out = _attend_blockwise(qg, gather_chunk, P, ps, chunk_pages,
                             pos[:, None], tok_total, sm_scale,
-                            window=window, softcap=softcap)
+                            window=window, softcap=softcap, block=block)
     out = out.reshape(T, Hq, Dh)
     return jnp.where(valid[:, None, None], out, 0.0).astype(q.dtype)
 
@@ -377,8 +408,10 @@ def ragged_paged_attention(q: jnp.ndarray, pages: jnp.ndarray, layer_idx,
 def paged_attention(q: jnp.ndarray, pages: jnp.ndarray, layer_idx,
                     page_table: jnp.ndarray, positions: jnp.ndarray,
                     total_lens: jnp.ndarray, sm_scale: float,
-                    window=None, softcap=None) -> jnp.ndarray:
+                    window=None, softcap=None,
+                    block: int = 1) -> jnp.ndarray:
     """Attend queries to the stacked paged context (scan path).
+    ``block``: the visibility block (``horizon``; 1 = causal).
 
     q:          [B, S, Hq, Dh]
     pages:      [L, N, 2, Hkv, page_size, Dh]
@@ -404,8 +437,8 @@ def paged_attention(q: jnp.ndarray, pages: jnp.ndarray, layer_idx,
 
         return _attend_blockwise(qg, gather_chunk, P, ps, PAGES_PER_CHUNK,
                                  positions, total_lens, sm_scale,
-                                 window=window,
-                                 softcap=softcap).astype(q.dtype)
+                                 window=window, softcap=softcap,
+                                 block=block).astype(q.dtype)
 
     # Single fused gather: the traced layer_idx participates as an advanced
     # index so XLA reads only the gathered pages (slicing pages[layer_idx]
@@ -414,10 +447,11 @@ def paged_attention(q: jnp.ndarray, pages: jnp.ndarray, layer_idx,
     k = _gathered_to_bhtd(gathered[:, :, 0])
     v = _gathered_to_bhtd(gathered[:, :, 1])
     return _attend(qg, k, v, positions, total_lens, sm_scale,
-                   window=window, softcap=softcap).astype(q.dtype)
+                   window=window, softcap=softcap,
+                   block=block).astype(q.dtype)
 
 
-__all__ = ["write_kv", "write_kv_packed", "paged_attention",
+__all__ = ["write_kv", "write_kv_packed", "paged_attention", "horizon",
            "ragged_paged_attention",
            "merge_softmax_partials", "normalize_softmax_partials",
            "NEG_INF"]

@@ -89,10 +89,21 @@ def _fit_query_block(S: int, Hq: int, Dh: int, span: int,
                               14 * span + 24 * Dh, slab_bytes)
 
 
+def _horizon(qpos, block: int):
+    """The last key position a query at ``qpos`` sees: itself under the
+    causal mask (``block`` 1: the expression is ``qpos``, the program the
+    one it was), the end of its block of ``block`` positions under the
+    block-wise visibility of generation by diffusion over blocks
+    (``ops.attention.horizon``)."""
+    if block <= 1:
+        return qpos
+    return jax.lax.div(qpos, block) * block + (block - 1)
+
+
 def _prefill_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
                     qstart_ref, lens_ref, out_ref, buf, sem, *,
                     page_size: int, n_kv: int, chunk: int, q_block: int,
-                    softcap: float):
+                    softcap: float, block: int = 1):
     """One program per (sequence, query-block): stream visible page chunks,
     causal online-softmax attend.
 
@@ -121,7 +132,7 @@ def _prefill_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
 
     # kv this block can see: causal bound (its last query's position + 1)
     # clamped to the live context
-    block_last = q_start + (j + 1) * SB - 1
+    block_last = _horizon(q_start + (j + 1) * SB - 1, block)
     visible = jnp.minimum(ctx, block_last + 1)
     num_chunks = jnp.maximum(jax.lax.div(visible + span - 1, span), 1)
     # first kv position the block's EARLIEST query can see (the window
@@ -188,7 +199,7 @@ def _prefill_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
         s4 = s.reshape(n_kv, G, SB, span)
         t_pos = c * span + jax.lax.broadcasted_iota(
             jnp.int32, (1, 1, 1, span), 3)
-        mask = (t_pos <= qpos) & (t_pos < ctx)             # [1, G, SB, span]
+        mask = (t_pos <= _horizon(qpos, block)) & (t_pos < ctx)  # [1,G,SB,span]
         # per-row sliding window: row at position p sees t > p - win
         mask &= (win <= 0) | (t_pos > qpos - win)
         s4 = jnp.where(mask, s4, NEG_INF)
@@ -218,10 +229,11 @@ def _prefill_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("sm_scale", "softcap", "interpret"))
+                   static_argnames=("sm_scale", "softcap", "interpret",
+                                    "block"))
 def _paged_prefill(q, kv_pages, layer_idx, window, page_table, q_start,
                    total_lens, sm_scale: float, softcap: float = 0.0,
-                   interpret: bool = False):
+                   interpret: bool = False, block: int = 1):
     B, S, Hq, Dh = q.shape
     _L, _N, _two, Hkv, page_size, _ = kv_pages.shape
     P = page_table.shape[1]
@@ -236,7 +248,7 @@ def _paged_prefill(q, kv_pages, layer_idx, window, page_table, q_start,
 
     kernel = functools.partial(_prefill_kernel, page_size=page_size,
                                n_kv=Hkv, chunk=chunk, q_block=SB,
-                               softcap=softcap)
+                               softcap=softcap, block=block)
     return pl.pallas_call(
         kernel,
         grid=(B, n_q_blocks),
@@ -266,8 +278,8 @@ def paged_prefill_attention_stacked(q: jnp.ndarray, pages: jnp.ndarray,
                                     positions: jnp.ndarray,
                                     total_lens: jnp.ndarray, sm_scale: float,
                                     window=None, softcap=None,
-                                    interpret: bool | None = None
-                                    ) -> jnp.ndarray:
+                                    interpret: bool | None = None,
+                                    block: int = 1) -> jnp.ndarray:
     """Drop-in for ``ops.attention.paged_attention`` on prefill steps
     (S > 1, positions contiguous per row — the engine's chunk batches).
 
@@ -281,6 +293,10 @@ def paged_prefill_attention_stacked(q: jnp.ndarray, pages: jnp.ndarray,
     window:     optional scalar (python int or traced, 0 = unlimited) —
                 gemma-2 alternating sliding-window layers
     softcap:    optional STATIC float (gemma logit soft-capping)
+    block:      STATIC visibility block: a query sees every key of its own
+                and earlier blocks of ``block`` positions (1 = causal);
+                what a pass over a block of diffusion generation and its
+                block-wise prefill run
     """
     layer = jnp.asarray(layer_idx, jnp.int32).reshape(1)
     win = (jnp.zeros((1,), jnp.int32) if window is None
@@ -290,7 +306,8 @@ def paged_prefill_attention_stacked(q: jnp.ndarray, pages: jnp.ndarray,
                          positions[:, 0].astype(jnp.int32),
                          total_lens.astype(jnp.int32), sm_scale,
                          softcap=float(softcap or 0.0),
-                         interpret=_resolve_interpret(interpret))
+                         interpret=_resolve_interpret(interpret),
+                         block=int(block))
     return out
 
 

@@ -42,6 +42,7 @@ from dynamo_tpu.ops.pallas.decode import _resolve_interpret, supports  # noqa: F
 from dynamo_tpu.ops.pallas.prefill import (
     PAGES_PER_CHUNK,
     _fit_query_block,
+    _horizon,
 )
 
 NEG_INF = -1e30
@@ -51,7 +52,7 @@ def _ragged_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
                    rows_ref, qstart_ref, qlen_ref, lens_ref, out_ref,
                    buf, sem, m_ref, l_ref, acc_ref, *,
                    page_size: int, n_kv: int, chunk: int, q_block: int,
-                   softcap: float):
+                   softcap: float, block: int = 1):
     """One program per block of ``SB`` packed slots.
 
     q_ref/out_ref: [SB, Hq, Dh]; rows_ref [2, n_blocks]: the first row
@@ -96,6 +97,11 @@ def _ragged_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
         # kv the row's slots of this block can see: causal bound, inside
         # the live context by construction (hi <= q_start + q_len)
         visible = pos0 + hi
+        if block > 1:
+            # block-wise visibility: the last slot sees to its block's end
+            visible = jnp.where(
+                active, jax.lax.div(visible - 1, block) * block + block,
+                visible)
         num_chunks = jnp.maximum(jax.lax.div(visible + span - 1, span), 1)
         first_pos = jnp.where(win > 0,
                               jnp.maximum(pos0 + lo - win + 1, 0), 0)
@@ -149,7 +155,7 @@ def _ragged_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
             s4 = s.reshape(n_kv, G, SB, span)
             t_pos = c * span + jax.lax.broadcasted_iota(
                 jnp.int32, (1, 1, 1, span), 3)
-            mask = in_row & (t_pos <= qpos)                # [1, G, SB, span]
+            mask = in_row & (t_pos <= _horizon(qpos, block))  # [1,G,SB,span]
             mask &= (win <= 0) | (t_pos > qpos - win)
             s4 = jnp.where(mask, s4, NEG_INF)
             s = s4.reshape(n_kv, G * SB, span)
@@ -183,10 +189,11 @@ def _ragged_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("sm_scale", "softcap", "interpret"))
+                   static_argnames=("sm_scale", "softcap", "interpret",
+                                    "block"))
 def _ragged_mixed(q, kv_pages, layer_idx, window, page_table, q_starts,
                   q_lens, kv_lens, sm_scale: float, softcap: float = 0.0,
-                  interpret: bool = False):
+                  interpret: bool = False, block: int = 1):
     T, Hq, Dh = q.shape
     _L, _N, _two, Hkv, page_size, _ = kv_pages.shape
     P = page_table.shape[1]
@@ -209,7 +216,7 @@ def _ragged_mixed(q, kv_pages, layer_idx, window, page_table, q_starts,
 
     kernel = functools.partial(_ragged_kernel, page_size=page_size,
                                n_kv=Hkv, chunk=chunk, q_block=SB,
-                               softcap=softcap)
+                               softcap=softcap, block=block)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     G = Hq // Hkv
     out = pl.pallas_call(
@@ -242,8 +249,8 @@ def ragged_mixed_attention_packed(q: jnp.ndarray, pages: jnp.ndarray,
                                   q_lens: jnp.ndarray,
                                   kv_lens: jnp.ndarray, sm_scale: float,
                                   window=None, softcap=None,
-                                  interpret: bool | None = None
-                                  ) -> jnp.ndarray:
+                                  interpret: bool | None = None,
+                                  block: int = 1) -> jnp.ndarray:
     """Drop-in for ``ops.attention.ragged_paged_attention`` on a
     token-packed step.
 
@@ -259,6 +266,8 @@ def ragged_mixed_attention_packed(q: jnp.ndarray, pages: jnp.ndarray,
     kv_lens:    [R] context per row including its new tokens
     window:     optional scalar (python int or traced, 0 = unlimited)
     softcap:    optional STATIC float (gemma logit soft-capping)
+    block:      STATIC visibility block (``ops.attention.horizon``; 1 =
+                causal)
     """
     layer = jnp.asarray(layer_idx, jnp.int32).reshape(1)
     win = (jnp.zeros((1,), jnp.int32) if window is None
@@ -269,7 +278,8 @@ def ragged_mixed_attention_packed(q: jnp.ndarray, pages: jnp.ndarray,
                          q_lens.astype(jnp.int32),
                          kv_lens.astype(jnp.int32), sm_scale,
                          softcap=float(softcap or 0.0),
-                         interpret=_resolve_interpret(interpret))
+                         interpret=_resolve_interpret(interpret),
+                         block=int(block))
 
 
 # the family forwards consult these markers before handing an impl their

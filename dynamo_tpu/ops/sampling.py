@@ -348,6 +348,43 @@ def sample_tokens(logits: jnp.ndarray, rng: jax.Array,
     return tokens.astype(jnp.int32), chosen_logit - logz
 
 
+def reveal(conf: jnp.ndarray, masked: jnp.ndarray, pass_idx: jnp.ndarray,
+           steps: jnp.ndarray, threshold: jnp.ndarray) -> jnp.ndarray:
+    """Which masked positions of each row's block one pass of generation
+    by diffusion over blocks reveals - the one reveal rule of the program.
+
+    conf:      [R, B] f32 confidence of each position's sampled token (its
+               probability); only masked positions count
+    masked:    [R, B] bool positions not revealed yet
+    pass_idx:  [R] i32 the pass's index within its block, from 0
+    steps:     [R] i32 denoising steps a block (clipped to 1..B)
+    threshold: [R] f32 confidence threshold (>= 1 never fires: the static
+               schedule)
+    returns    [R, B] bool, a subset of ``masked``.
+
+    The pass's quota is ``B // steps``, one more in the first ``B % steps``
+    passes, never more than are masked. Every masked position whose
+    confidence exceeds the threshold is revealed if those are at least
+    the quota; else the quota's worth of the most confident (ties to the
+    lower position). At least one masked position is revealed while any
+    is left, so a block of B masks commits after at most B + 1 passes."""
+    B = conf.shape[1]
+    steps = jnp.clip(steps, 1, B)
+    quota = B // steps + (pass_idx < B % steps).astype(jnp.int32)
+    quota = jnp.minimum(quota, jnp.sum(masked, axis=1))[:, None]   # [R, 1]
+    c = jnp.where(masked, conf, -jnp.inf)
+    high = masked & (c > threshold[:, None])
+    idx = jnp.arange(B)
+    # beats[r, i, j]: position j comes before position i in the order
+    beats = (c[:, None, :] > c[:, :, None]) | (
+        (c[:, None, :] == c[:, :, None]) & (idx[None, None, :]
+                                            < idx[None, :, None]))
+    rank = jnp.sum(beats, axis=2)
+    top = masked & (rank < quota)
+    return jnp.where(jnp.sum(high, axis=1, keepdims=True) >= quota,
+                     high, top)
+
+
 def spec_verify(logits: jnp.ndarray, tokens: jnp.ndarray, rng: jax.Array,
                 temperature: jnp.ndarray, top_k: jnp.ndarray,
                 top_p: jnp.ndarray,
@@ -444,5 +481,6 @@ def spec_verify(logits: jnp.ndarray, tokens: jnp.ndarray, rng: jax.Array,
 
 __all__ = ["SamplingParamsBatch", "sample_tokens", "apply_penalties",
            "apply_vocab_mask", "update_penalty_window",
-           "penalty_window_entries", "spec_verify", "top_candidates",
+           "penalty_window_entries", "spec_verify", "reveal",
+           "top_candidates",
            "candidate_form", "TOPK_MAX"]

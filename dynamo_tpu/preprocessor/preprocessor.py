@@ -188,6 +188,32 @@ class OpenAIPreprocessor:
                 guided = req.guided_spec()
                 if guided is not None:
                     _validate_guided_spec(guided)
+        nv = req.nvext
+        steps = getattr(nv, "denoising_steps", None) if nv else None
+        tau = getattr(nv, "confidence_threshold", None) if nv else None
+        if steps is not None and int(steps) < 1:
+            raise ValueError("nvext.denoising_steps must be at least 1")
+        if self.card.extra.get("generation") == "block_diffusion":
+            # what acts on one next token a row a step does not compose
+            # with generation by diffusion over blocks yet: a clear 400
+            # here, before the stream opens (the worker refuses the same,
+            # and counts it, for requests that reach it another way)
+            for what, on in (
+                    ("guided decoding (response_format, forced tool calls)",
+                     guided is not None),
+                    ("frequency, presence and repetition penalties",
+                     bool(req.frequency_penalty or req.presence_penalty
+                          or req.repetition_penalty not in (None, 0, 1.0))),
+                    ("logit_bias", bool(req.logit_bias))):
+                if on:
+                    raise ValueError(
+                        f"{what} cannot be served by a model that "
+                        "generates by diffusion over blocks")
+        elif steps is not None or tau is not None:
+            raise ValueError(
+                "nvext.denoising_steps and nvext.confidence_threshold are "
+                "parameters of generation by diffusion over blocks; "
+                f"{self.card.name!r} generates one next token a step")
         sampling = SamplingOptions(
             temperature=req.temperature,
             top_p=req.top_p,
@@ -201,6 +227,8 @@ class OpenAIPreprocessor:
             n=req.n,
             logprobs=logprobs,
             guided=guided,
+            denoising_steps=None if steps is None else int(steps),
+            confidence_threshold=None if tau is None else float(tau),
         )
         return PreprocessedRequest(
             token_ids=token_ids,

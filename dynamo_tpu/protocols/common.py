@@ -92,6 +92,12 @@ class SamplingOptions:
     # guided decoding (OpenAI response_format -> engine/guided.py):
     # {"mode": "json"} or {"mode": "json_schema", "schema": {...}}
     guided: Optional[Dict[str, Any]] = None
+    # generation by diffusion over blocks (``nvext.denoising_steps`` /
+    # ``nvext.confidence_threshold``; None = the worker's defaults): the
+    # revealing passes a block of masks takes at most, and the confidence
+    # above which a pass reveals a position beyond its quota
+    denoising_steps: Optional[int] = None
+    confidence_threshold: Optional[float] = None
 
     def to_dict(self) -> Dict[str, Any]:
         d = _asdict_shallow(self)
@@ -103,7 +109,7 @@ class SamplingOptions:
         kw = {k: d.get(k) for k in (
             "temperature", "top_p", "top_k", "frequency_penalty",
             "presence_penalty", "repetition_penalty", "seed", "logprobs",
-            "min_p", "guided")}
+            "min_p", "guided", "denoising_steps", "confidence_threshold")}
         lb = d.get("logit_bias")
         if lb:
             # wire form may carry string token-id keys (OpenAI JSON)
@@ -202,6 +208,10 @@ class LLMEngineOutput:
     cum_log_probs: Optional[float] = None
     log_probs: Optional[List[float]] = None
     top_logprobs: Optional[List[Dict[int, float]]] = None
+    # generation by diffusion over blocks: per token, the index of the
+    # pass (within its block, from 0) that revealed it; its log_probs and
+    # top_logprobs are the log-softmax at its position in that pass
+    reveal_pass: Optional[List[int]] = None
     finish_reason: Optional[FinishReason] = None
     error: Optional[str] = None
     kv_transfer_params: Optional[Dict[str, Any]] = None
@@ -218,8 +228,8 @@ class LLMEngineOutput:
         d: Dict[str, Any] = {"token_ids": list(self.token_ids)}
         if self.finish_reason is not None:
             d["finish_reason"] = self.finish_reason.value
-        for k in ("cum_log_probs", "log_probs", "top_logprobs", "error",
-                  "kv_transfer_params", "prompt_tokens", "completion_tokens",
+        for k in ("cum_log_probs", "log_probs", "top_logprobs",
+                  "reveal_pass", "error", "kv_transfer_params", "prompt_tokens", "completion_tokens",
                   "cached_tokens", "timings"):
             v = getattr(self, k)
             if v is not None:
@@ -234,6 +244,7 @@ class LLMEngineOutput:
             cum_log_probs=d.get("cum_log_probs"),
             log_probs=d.get("log_probs"),
             top_logprobs=d.get("top_logprobs"),
+            reveal_pass=d.get("reveal_pass"),
             finish_reason=FinishReason(fr) if fr else None,
             error=d.get("error"),
             kv_transfer_params=d.get("kv_transfer_params"),

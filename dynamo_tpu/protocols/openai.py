@@ -28,6 +28,12 @@ class Extensions(BaseModel):
     # takes precedence over the X-Request-Timeout header and the service's
     # configured default
     timeout_s: Optional[float] = None
+    # generation by diffusion over blocks (models whose config says so):
+    # revealing passes a block takes at most, and the confidence above
+    # which a pass reveals a position beyond its quota; None = the
+    # worker's --denoising-steps / --confidence-threshold
+    denoising_steps: Optional[int] = None
+    confidence_threshold: Optional[float] = None
 
 
 class ChatMessage(BaseModel):

@@ -618,6 +618,11 @@ class StageStitcher:
                 # dynamo_worker_multistep_fallback_total{reason})
                 self.decode_attrs["multistep_fallbacks"] = int(
                     timings["multistep_fallbacks"])
+            for key in ("passes", "blocks"):
+                # generation by diffusion over blocks: the forward passes
+                # and committed blocks behind the request's tokens
+                if key in timings:
+                    self.decode_attrs[key] = int(timings[key])
         if timings and "compile_ms" in timings and self.parent is not None:
             # a fresh-jit-bucket compile stalled this request (engine
             # steptrace detection): an event on the hop span so the stall

@@ -111,6 +111,32 @@ def build_parser() -> argparse.ArgumentParser:
                    help="decode steps fused into one jitted dispatch with "
                         "on-device sampling/stop checks (default: "
                         "DYN_DECODE_MULTISTEP or 8; 1 disables fusion)")
+    p.add_argument("--min-decode-bucket", type=int, default=1,
+                   help="floor of the padded decode batch (rows of a decode "
+                        "step, a fused block, a pass dispatch): raised to "
+                        "--max-num-seqs it leaves ONE compiled shape "
+                        "instead of one per power of two as load ramps")
+    p.add_argument("--min-prefill-bucket", type=int, default=16,
+                   help="floor of a prefill-carrying step's token axis "
+                        "(the packed step's T, a padded step's chunk "
+                        "length): raised to --max-prefill-chunk every such "
+                        "step is one program, at the price of padding a "
+                        "part-filled step")
+    p.add_argument("--min-prefill-seqs-bucket", type=int, default=1,
+                   help="floor of a prefill-carrying step's padded rows")
+    p.add_argument("--denoising-steps", type=int, default=0,
+                   help="a model that generates by diffusion over blocks: "
+                        "revealing passes a block of masks takes at most "
+                        "(0 = the block length, one token a pass); a "
+                        "request overrides it with nvext.denoising_steps. "
+                        "--decode-multistep is then the passes one fused "
+                        "dispatch runs")
+    p.add_argument("--confidence-threshold", type=float, default=0.9,
+                   help="generation by diffusion over blocks: a pass "
+                        "reveals every masked position whose confidence "
+                        "exceeds this if those are at least its quota "
+                        "(>= 1: the static schedule); per request "
+                        "nvext.confidence_threshold")
     p.add_argument("--penalty-window", type=int, default=32,
                    help="device ring-buffer slots per penalized/logit_bias "
                         "row — such rows ride the fused decode block while "
@@ -194,7 +220,10 @@ def build_engine(args: argparse.Namespace, startup=None) -> JaxEngine:
             attrs["moe.experts"] = (
                 cfg.moe_backend if cfg.moe_backend != "grouped" else
                 f"grouped[E={cfg.num_experts},k={cfg.num_experts_per_tok}]")
-        return JaxEngine(cfg, params, engine_cfg, forward_fn=forward_fn)
+        engine = JaxEngine(cfg, params, engine_cfg, forward_fn=forward_fn)
+        if engine.gen_block > 1:
+            attrs["generation"] = engine.generation
+        return engine
 
 
 def _build_weights(args: argparse.Namespace):
@@ -226,7 +255,12 @@ def _build_weights(args: argparse.Namespace):
         spec_chain_break=args.speculative_chain_break,
         decode_multistep=args.decode_multistep,
         penalty_window=args.penalty_window,
-        guided_table_bytes=args.guided_table_bytes)
+        guided_table_bytes=args.guided_table_bytes,
+        min_decode_bucket=args.min_decode_bucket,
+        min_prefill_bucket=args.min_prefill_bucket,
+        min_prefill_seqs_bucket=args.min_prefill_seqs_bucket,
+        denoising_steps=args.denoising_steps,
+        confidence_threshold=args.confidence_threshold)
     forward_fn = None
     pp = args.pipeline_parallel_size
     if pp > 1:
@@ -346,6 +380,10 @@ async def amain(args: argparse.Namespace) -> None:
     # advertise the engine's sparse penalty/logit_bias window so the
     # frontend preprocessor rejects requests the device would truncate
     card.penalty_window = engine.cfg.penalty_window
+    if engine.gen_block > 1:
+        # the frontend refuses with a 400 what this generation rule does
+        # not compose with (preprocessor._build)
+        card.extra["generation"] = engine.model_cfg.generation
     # what the worker warms before it reports ready: today the guided
     # decoder's byte vocabulary (response_format: the engine needs the
     # tokenizer's byte view to walk grammar masks) and no step program

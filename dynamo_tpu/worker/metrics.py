@@ -162,7 +162,25 @@ class EngineDispatchCollector:
         "moe_expert_slots": "Experts the grouped layer could have read: "
                             "forward passes x expert layers x experts (the "
                             "denominator of the touched share)",
+        "gen_tokens_revealed": "Generation by diffusion over blocks: "
+                               "masked positions the passes revealed "
+                               "(tokens revealed / row-passes = tokens a "
+                               "forward pass yields a row)",
+        "gen_blocks_committed": "Generation by diffusion over blocks: "
+                                "blocks whose committing pass ran (their "
+                                "tokens are emitted, their pages hashed "
+                                "and published, only then)",
+        "gen_rows_waited": "Generation by diffusion over blocks: running "
+                           "rows that waited through an admission step "
+                           "(a prefill step carries no row in mid-block)",
     }
+
+    # a row's pass either reveals positions of its block or commits it
+    PASS_KINDS = ("reveal", "commit")
+
+    # what a block-diffusion engine refuses at admission
+    # (engine/jax_engine.py _refusal), pre-seeded like the fallback reasons
+    REFUSAL_REASONS = ("guided", "penalties", "logit_bias", "disagg_prefill")
 
     # the known fallback reasons, pre-seeded so every label shows on the
     # scrape at 0 and dashboards/alerts can reference them before the
@@ -255,6 +273,34 @@ class EngineDispatchCollector:
         for form, value in sorted(forms.items()):
             pf.add_metric([str(form)], float(value))
         yield pf
+        gp = CounterMetricFamily(
+            "dynamo_worker_gen_passes",
+            "Generation by diffusion over blocks: forward passes summed "
+            "over the rows each served (a pass serves every live row, "
+            "each in its own phase), by what the pass did to the row's "
+            "block: 'reveal' (it held masks; some were revealed) or "
+            "'commit' (it held none: the pass wrote its final keys and "
+            "values and the block's tokens were emitted)",
+            labels=["kind"])
+        kinds = dict.fromkeys(self.PASS_KINDS, 0.0)
+        kinds.update(stats.get("gen_passes") or {})
+        for kind, value in sorted(kinds.items()):
+            gp.add_metric([str(kind)], float(value))
+        yield gp
+        rf = CounterMetricFamily(
+            "dynamo_worker_requests_refused",
+            "Requests the engine refused at admission because the "
+            "model's generation rule does not compose with what they "
+            "ask for, by reason (guided / penalties / logit_bias / "
+            "disagg_prefill on a model that generates by diffusion over "
+            "blocks); the frontend answers the same requests with HTTP "
+            "400 before they reach a worker",
+            labels=["reason"])
+        refused = dict.fromkeys(self.REFUSAL_REASONS, 0.0)
+        refused.update(stats.get("requests_refused") or {})
+        for reason, value in sorted(refused.items()):
+            rf.add_metric([str(reason)], float(value))
+        yield rf
 
 
 class StepTraceCollector:
@@ -376,6 +422,7 @@ def engine_dispatch_stats(engine) -> Dict[str, object]:
     labeled families."""
     sched = getattr(engine, "scheduler", None)
     moe = engine.moe_counts() if hasattr(engine, "moe_counts") else {}
+    gen = getattr(engine, "gen_counts", None) or {}
     return {
         "moe_assignments": float(moe.get("moe_assignments", 0)),
         "moe_experts_touched": float(moe.get("moe_experts_touched", 0)),
@@ -393,6 +440,13 @@ def engine_dispatch_stats(engine) -> Dict[str, object]:
             getattr(sched, "admission_run_steps", 0)),
         "preemptions": float(getattr(sched, "num_preemptions", 0)),
         "prefill_steps": dict(getattr(engine, "prefill_steps", None) or {}),
+        "gen_passes": {k: float(gen.get(f"passes_{k}", 0))
+                       for k in EngineDispatchCollector.PASS_KINDS},
+        "gen_tokens_revealed": float(gen.get("tokens_revealed", 0)),
+        "gen_blocks_committed": float(gen.get("blocks_committed", 0)),
+        "gen_rows_waited": float(getattr(sched, "gen_rows_waited", 0)),
+        "requests_refused": dict(
+            getattr(engine, "requests_refused", None) or {}),
     }
 
 

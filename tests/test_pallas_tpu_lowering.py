@@ -499,3 +499,137 @@ def test_grouped_expert_layer_compiles_in_the_tpu_compiler(tokens):
     assert not [ln for ln in text.splitlines()
                 if " scatter(" in ln or f"bf16[{E},{H},{I}]" in ln
                 or f"bf16[{E},{I},{H}]" in ln]
+
+
+# -- generation by diffusion over blocks (SDAR): the block-wise visibility --
+
+@pytest.mark.parametrize("S", [4, 512])
+def test_gqa_prefill_kernel_lowers_with_a_visibility_block(S):
+    """``paged_prefill`` under the block-wise visibility at SDAR-30B-A3B's
+    heads (32/4 x 128): a pass over ``[32 rows, 4 positions]`` and a padded
+    block-wise prefill chunk."""
+    from dynamo_tpu.ops.pallas.prefill import paged_prefill_attention_stacked
+
+    Hq, Hkv, Dh, R = 32, 4, 128, 32
+
+    def fn(q, pages, table, positions, total):
+        return paged_prefill_attention_stacked(
+            q, pages, 1, table, positions, total, 0.088, interpret=False,
+            block=4)
+
+    exp = _export_tpu(
+        fn,
+        jax.ShapeDtypeStruct((R, S, Hq, Dh), jnp.bfloat16),
+        jax.ShapeDtypeStruct((L, N, 2, Hkv, PS, Dh), jnp.bfloat16),
+        jax.ShapeDtypeStruct((R, P * 8), jnp.int32),
+        jax.ShapeDtypeStruct((R, S), jnp.int32),
+        jax.ShapeDtypeStruct((R,), jnp.int32))
+    _assert_mosaic(exp)
+
+
+def test_packed_ragged_kernel_lowers_with_a_visibility_block():
+    from dynamo_tpu.ops.pallas.ragged import ragged_mixed_attention_packed
+
+    Hq, Hkv, Dh, T, R = 32, 4, 128, 1024, 32
+
+    def fn(q, pages, table, starts, q_lens, kv_lens):
+        return ragged_mixed_attention_packed(
+            q, pages, 1, table, starts, q_lens, kv_lens, 0.088,
+            interpret=False, block=4)
+
+    exp = _export_tpu(
+        fn,
+        jax.ShapeDtypeStruct((T, Hq, Dh), jnp.bfloat16),
+        jax.ShapeDtypeStruct((L, N, 2, Hkv, PS, Dh), jnp.bfloat16),
+        jax.ShapeDtypeStruct((R, P * 8), jnp.int32),
+        jax.ShapeDtypeStruct((R,), jnp.int32),
+        jax.ShapeDtypeStruct((R,), jnp.int32),
+        jax.ShapeDtypeStruct((R,), jnp.int32))
+    _assert_mosaic(exp)
+
+
+def _sdar_config(layers: int):
+    import json
+    import os
+
+    from dynamo_tpu.models.config import ModelConfig
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "configs",
+        "sdar-30b-a3b-chat.json")
+    with open(path) as f:
+        hf = json.load(f)
+    hf.pop("benchmark")
+    hf["num_hidden_layers"] = layers
+    return ModelConfig.from_hf(hf)
+
+
+def test_block_diffusion_programs_compile_in_the_tpu_compiler():
+    """The fused pass dispatch ``passes3[32,4]`` and the token-packed
+    block-wise prefill step of SDAR-30B-A3B-Chat at its published widths
+    (two of its layers; the cell's rows, pool and dispatch width) compile
+    for a v5e with the Mosaic kernels in them, copy no pool, sort no axis
+    of the vocabulary, build no ``[positions, experts, width]`` temporary
+    and keep their temporaries under 0.5 GB (engine/program_check.py)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
+    from dynamo_tpu.engine.program_check import (
+        expert_temporaries, pool_copies, step_programs, vocab_sorts)
+    from dynamo_tpu.models import moe
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu in this install
+        pytest.skip(f"no compile-only TPU topology: {e}")
+    cfg = _sdar_config(2)
+    assert (cfg.generation, cfg.gen_block, cfg.qk_norm) == (
+        "block_diffusion", 4, True)
+    abs_params = jax.eval_shape(
+        lambda: moe.init_params(cfg, jax.random.PRNGKey(0)))
+    eng = JaxEngine(cfg, abs_params, JaxEngineConfig(
+        num_pages=16, page_size=16, max_num_seqs=32, max_context=2048,
+        attn_impl="pallas", decode_multistep=3, denoising_steps=2))
+    assert eng.padded_reason is None
+    programs = step_programs(
+        eng, 32, 512, width=3, num_pages=2048, tokens=1024,
+        sharding=SingleDeviceSharding(topo.devices[0]))
+    assert set(programs) == {"passes", "mixed", "packed"}
+    pool = (2, 2048) + tuple(eng.pages.shape[2:])
+    for name in ("passes", "packed"):
+        fn, args = programs[name]
+        compiled = fn.lower(*args).compile()
+        hlo = compiled.as_text()
+        assert "tpu_custom_call" in hlo or "moe_grouped" in hlo
+        assert pool_copies(hlo, pool, eng.pages.dtype) == []
+        assert vocab_sorts(hlo, cfg.vocab_size) == []
+        positions = 32 * 4 if name == "passes" else 1024
+        assert expert_temporaries(hlo, positions, 128, 768) == []
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_the_expert_stacks_are_drawn_without_a_float32_copy():
+    """``moe.init_params`` draws ``w_gate [7, 128, 2048, 768]`` (5.6 GB in
+    float32) a layer at a time inside one program (``llama.randn_stack``):
+    the compiled initialiser's temporaries stay under two layers' worth of
+    float32, so the worker and the reference child load on a 16 GB chip."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from dynamo_tpu.models.llama import randn_stack
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu in this install
+        pytest.skip(f"no compile-only TPU topology: {e}")
+    shape = (128, 2048, 768)
+    one_layer_f32 = 4 * 128 * 2048 * 768
+    key = jax.ShapeDtypeStruct(
+        (2,), jnp.uint32, sharding=SingleDeviceSharding(topo.devices[0]))
+    compiled = jax.jit(lambda k: randn_stack(
+        k, 7, shape, 0.012, jnp.bfloat16)).lower(key).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == 7 * one_layer_f32 // 2
+    assert mem.temp_size_in_bytes < 2 * one_layer_f32
