@@ -7,7 +7,7 @@ points a user calls — a coordinator, one ``dynamo_tpu.worker.main`` holding
 the chip and ``dynamo_tpu.frontend.main``, three separate processes — sends
 a few OpenAI requests that make every hot-path program compile and run, and
 checks what comes back. Before the servers start, a child of its own
-compiles each of the five Pallas kernels natively at serving widths and
+compiles each of the six Pallas attention kernels natively at serving widths and
 compares it with the XLA path it replaces, then compiles the decode, fused
 and mixed step programs at the serving geometry and reads their HLO: none
 may copy the page pool or hold a temporary of its size.
@@ -780,6 +780,7 @@ def run_kernels(dry: bool) -> list:
     from dynamo_tpu.ops.pallas.decode import paged_decode_attention_stacked
     from dynamo_tpu.ops.pallas.mla_decode import mla_paged_decode_stacked
     from dynamo_tpu.ops.pallas.mla_prefill import mla_paged_prefill_stacked
+    from dynamo_tpu.ops.pallas.mla_ragged import mla_ragged_attention_packed
     from dynamo_tpu.ops.pallas.prefill import (
         paged_prefill_attention_stacked)
     from dynamo_tpu.ops.pallas.ragged import ragged_mixed_attention_packed
@@ -865,7 +866,7 @@ def run_kernels(dry: bool) -> list:
               qp, pages, 1, table, cu, new_mixed, total_mixed, sm)[None],
           [n_real])
 
-    # --- MLA: ops/pallas/mla_{decode,prefill} against the latent XLA path
+    # --- MLA: ops/pallas/mla_{decode,prefill,ragged} against the latent XLA path
     # of models/deepseek.py (_mla_attend for a decode step,
     # _mla_attend_blockwise for a prefill chunk). Those end in the W_UV
     # expansion and the output projection; with identity matrices there
@@ -916,6 +917,20 @@ def run_kernels(dry: bool) -> list:
               cfg, lp, h0_s, q_lat_s, q_pe_s, eye_uv, gather_chunk, P, ps,
               pos, total).reshape(B, S, nh, dkv),
           new)
+    # the token-packed step over the latent cache: the rows of the GQA
+    # packed check above, against the pure-JAX latent attention over the
+    # same layout
+    key_lat, key_pe = jax.random.split(next(key))
+    qp_lat = jax.random.normal(key_lat, (qp.shape[0], nh, dkv))
+    qp_pe = jax.random.normal(key_pe, (qp.shape[0], nh, dr))
+    check("mla_ragged",
+          lambda: mla_ragged_attention_packed(
+              qp_lat, qp_pe, lat_pages, 1, table, cu, new_mixed,
+              total_mixed, scale, interpret=interpret)[None],
+          lambda: deepseek.mla_ragged_attention(
+              cfg, qp_lat, qp_pe, lat_pages, 1, table, cu, new_mixed,
+              total_mixed)[None],
+          [n_real])
     return results
 
 

@@ -243,16 +243,22 @@ def _bucket(n: int, lo: int, hi: int) -> int:
     return min(b, hi)
 
 
-def _token_bucket(n: int, lo: int) -> int:
+def _token_bucket(n: int, lo: int, cap: int = 0) -> int:
     """The packed step's token axis ``T``: powers of two from ``lo`` up to
-    512, then steps of 128 (1,152 for the worker's default 1,024-token
-    prefill budget beside up to 64 decode rows). A ladder over the step's
-    TOKENS, so a step of three chunks and twenty decode rows pays for
-    1,152 slots and not for ``rows x longest chunk``."""
+    512, then ONE rung, the step's cap (``cap``: what the prefill budget
+    beside the decode rows rounds to in steps of 128 — 1,152 for the
+    worker's default 1,024 tokens beside up to 64 rows). A ladder over the
+    step's TOKENS, so a step of three chunks and twenty decode rows pays
+    for 1,152 slots and not for ``rows x longest chunk``. One rung above
+    512 because each rung is a program, first called (seconds) where a run
+    first meets it: steps of 128 gave a server that admits one or two
+    prompts beside its rows five rungs that a few per cent of its steps
+    land on, and the pad costs little where it lands (PERF.md section 6,
+    PR 38). A floor above 512 pins the axis instead: one packed program,
+    steps of 128 above it."""
     if n <= 512 and lo <= 512:
         return _bucket(n, lo, 512)
-    # (a floor above 512 pins the axis: one packed program, every step)
-    return max(-(-n // 128) * 128, lo)
+    return max(-(-n // 128) * 128, lo, cap if lo <= 512 else 0)
 
 
 class JaxEngine(ScheduledEngineBase):
@@ -477,6 +483,10 @@ class JaxEngine(ScheduledEngineBase):
         # decode rows (_packed_step_impl). Everywhere else they are the
         # plain [B, S] step program, and the counter says why.
         self.padded_reason = self._why_padded(forward_fn, family)
+        # the most slots a packed step holds: the prompt-token budget and
+        # one token for every other row, in steps of 128 (_token_bucket)
+        self._packed_cap = -(-(self.cfg.max_prefill_chunk
+                               + self.cfg.max_num_seqs) // 128) * 128
         self._jit_packed = (jax.jit(self._packed_step_impl,
                                     donate_argnums=(1,))
                             if self.padded_reason is None else None)
@@ -953,7 +963,8 @@ class JaxEngine(ScheduledEngineBase):
         """None where the prefill-carrying steps run token-packed, else
         the first reason they cannot: a custom
         ``forward_fn`` (pipeline stages), a family forward that does not
-        declare the packed form (MLA: its kernels are row-padded), a mesh
+        declare the packed form (``supports_packed``; every built-in
+        family declares it), a mesh
         with ``dp > 1`` (``_shard_batch`` splits rows, a packed axis has
         none), speculation (the verify window is ``[B, K+1]``), and the
         XLA ``scan`` path (the CPU's). Read off what the
@@ -1554,7 +1565,8 @@ class JaxEngine(ScheduledEngineBase):
                 B = _bucket(len(chunks), self.cfg.min_prefill_seqs_bucket,
                             self.cfg.max_num_seqs)
                 S = (_token_bucket(sum(c.length for c in chunks),
-                                   self.cfg.min_prefill_bucket)
+                                   self.cfg.min_prefill_bucket,
+                                   self._packed_cap)
                      if pack else
                      _bucket(max(c.length for c in chunks),
                              self.cfg.min_prefill_bucket,

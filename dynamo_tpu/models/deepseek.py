@@ -54,8 +54,10 @@ from dynamo_tpu.models.llama import (
     _rms_norm,
     randn_stack as _randn_stack,
     make_pages,
+    packed_rows,
+    write_rows,
 )
-from dynamo_tpu.ops.attention import NEG_INF, write_kv
+from dynamo_tpu.ops.attention import NEG_INF, _pad_table, packed_token_rows
 
 Params = Dict[str, Any]
 
@@ -338,16 +340,26 @@ def _mla_attend_blockwise(cfg: ModelConfig, lp, h, q_lat, q_pe, w_uv,
                           gather_chunk, num_table_pages: int, ps: int,
                           positions: jnp.ndarray, total_lens: jnp.ndarray
                           ) -> jnp.ndarray:
-    """Flash-style chunked latent attention for prefill (S > 1): the
-    context streams in page chunks with an online softmax, so the peak
-    intermediate is ``[B, nh, S, span]`` scores + a fixed
-    ``[B, nh, S, dkv]`` latent accumulator regardless of context length —
-    the full-gather path's ``[B, nh, S, T]`` scores are GBs per layer at
-    DeepSeek-V3 head counts (same failure mode
-    ``ops/attention._attend_blockwise`` exists for)."""
-    B, S, H = h.shape
-    nh, dkv = cfg.num_heads, cfg.kv_lora_rank
-    sm_scale = _mla_scale(cfg)
+    """``_latent_blockwise`` + output projection residual: the XLA path
+    of a prefill chunk batch (S > 1)."""
+    lat = _latent_blockwise(q_lat, q_pe, gather_chunk, num_table_pages, ps,
+                            positions, total_lens, _mla_scale(cfg))
+    return _expand_and_project(cfg, lp, h, lat, w_uv)
+
+
+def _latent_blockwise(q_lat, q_pe, gather_chunk, num_table_pages: int,
+                      ps: int, positions: jnp.ndarray,
+                      total_lens: jnp.ndarray, sm_scale: float
+                      ) -> jnp.ndarray:
+    """Flash-style chunked latent attention: the context streams in page
+    chunks with an online softmax, so the peak intermediate is
+    ``[B, nh, S, span]`` scores + a fixed ``[B, nh, S, dkv]`` latent
+    accumulator regardless of context length — the full-gather path's
+    ``[B, nh, S, T]`` scores are GBs per layer at DeepSeek-V3 head counts
+    (same failure mode ``ops/attention._attend_blockwise`` exists for).
+    ``gather_chunk(c)`` gives chunk ``c`` of the (padded) table as
+    ``_gather_ctx`` does. Returns the latent output [B, S, nh, dkv]."""
+    B, S, nh, dkv = q_lat.shape
     span = PAGES_PER_CHUNK * ps
     n_static = -(-num_table_pages // PAGES_PER_CHUNK)
     n_chunks = jnp.minimum(
@@ -378,9 +390,42 @@ def _mla_attend_blockwise(cfg: ModelConfig, lp, h, q_lat, q_pe, w_uv,
     den0 = jnp.zeros((B, nh, S), jnp.float32)
     mx0 = jnp.full((B, nh, S), NEG_INF, jnp.float32)
     num, den, _mx = jax.lax.fori_loop(0, n_chunks, body, (num0, den0, mx0))
-    lat = (num / jnp.maximum(den, 1e-20)[..., None]) \
+    return (num / jnp.maximum(den, 1e-20)[..., None]) \
         .transpose(0, 2, 1, 3)                             # [B,S,nh,dkv]
-    return _expand_and_project(cfg, lp, h, lat, w_uv)
+
+
+def mla_ragged_attention(cfg: ModelConfig, q_lat: jnp.ndarray,
+                         q_pe: jnp.ndarray, pages: jnp.ndarray, layer_idx,
+                         page_table: jnp.ndarray, q_starts: jnp.ndarray,
+                         q_lens: jnp.ndarray, kv_lens: jnp.ndarray
+                         ) -> jnp.ndarray:
+    """Latent attention of a TOKEN-PACKED step (``llama.packed_rows``):
+    the pure-JAX reference of ``ops/pallas/mla_ragged.py``, the CPU-test
+    oracle, and what a packed forward runs when it is handed no kernel.
+
+    q_lat [T, nh, dkv] / q_pe [T, nh, dr] hold every row's query tokens
+    back to back, row ``r`` at slots ``q_starts[r] .. q_starts[r] +
+    q_lens[r]`` and positions ``kv_lens[r] - q_lens[r] ..``; page_table
+    [R, P]. Each token attends to its row's pages as a [T, 1]-query batch
+    of ``_latent_blockwise`` (``ops.attention.ragged_paged_attention``'s
+    construction). Returns the latent output [T, nh, dkv] in f32, zero in
+    the slots of no row."""
+    T = q_lat.shape[0]
+    P = page_table.shape[1]
+    valid, pos, tok_table, tok_total = packed_token_rows(
+        T, page_table, q_starts, q_lens, kv_lens)
+    table = _pad_table(tok_table, PAGES_PER_CHUNK)
+
+    def gather_chunk(c):
+        tbl = jax.lax.dynamic_slice(table, (0, c * PAGES_PER_CHUNK),
+                                    (T, PAGES_PER_CHUNK))
+        return _gather_ctx(cfg, pages[layer_idx, tbl])
+
+    lat = _latent_blockwise(q_lat[:, None].astype(jnp.float32),
+                            q_pe[:, None], gather_chunk, P,
+                            pages.shape[-2], pos[:, None], tok_total,
+                            _mla_scale(cfg))[:, 0]
+    return jnp.where(valid[:, None, None], lat, 0.0)
 
 
 def _gather_ctx(cfg: ModelConfig, gathered: jnp.ndarray):
@@ -488,16 +533,27 @@ def _dense_mlp(lp: Dict[str, jnp.ndarray], x: jnp.ndarray) -> jnp.ndarray:
 # ----------------------------------------------------------------- forward
 
 def _attend(cfg: ModelConfig, lp, h, q_lat, q_pe, w_uv, positions,
-            total_lens, page_table, pages, lidx, *, use_pallas: bool):
+            total_lens, new_lens, page_table, pages, lidx, *,
+            use_pallas: bool, starts=None):
     """The attention stage of ``_layer_step`` (latent attention over the
-    paged cache plus the out-projection residual), by the path the
-    geometry picks. Returns the new ``h``."""
-    from dynamo_tpu.ops.attention import _pad_table
-
+    paged cache plus the out-projection residual), by the path the step
+    form (``starts``: ``llama.packed_rows``) and the geometry pick.
+    Returns the new ``h``."""
     S = h.shape[1]
     P = page_table.shape[1]
     ps = pages.shape[-2]
-    if use_pallas and S == 1:
+    if starts is not None:
+        rows = (pages, lidx, page_table, starts, new_lens, total_lens)
+        if use_pallas:
+            from dynamo_tpu.ops.pallas.mla_ragged import (
+                mla_ragged_attention_packed)
+
+            lat = mla_ragged_attention_packed(q_lat[0], q_pe[0], *rows,
+                                              _mla_scale(cfg))
+        else:
+            lat = mla_ragged_attention(cfg, q_lat[0], q_pe[0], *rows)
+        h = _expand_and_project(cfg, lp, h, lat[None], w_uv)
+    elif use_pallas and S == 1:
         from dynamo_tpu.ops.pallas.mla_decode import (
             mla_paged_decode_stacked)
 
@@ -534,11 +590,14 @@ def _attend(cfg: ModelConfig, lp, h, q_lat, q_pe, w_uv, positions,
 
 def _layer_step(cfg: ModelConfig, lp, h, positions, total_lens, new_lens,
                 page_table, pages, lidx, *, moe: bool,
-                use_pallas: bool = False, ep_mesh=None, moe_kw=None):
+                use_pallas: bool = False, ep_mesh=None, moe_kw=None,
+                starts=None):
     """One decoder layer against the stacked paged latent cache.
-    ``use_pallas`` routes S==1 through the MLA Pallas decode kernel
-    (``ops/pallas/mla_decode.py``) and S>1 through the prefill kernel
-    when the geometry supports them; ``moe_kw`` goes to the grouped
+    ``use_pallas`` routes a token-packed step (``starts``:
+    ``llama.packed_rows``, ``h`` ``[1, T, H]``) through the ragged MLA
+    kernel (``ops/pallas/mla_ragged.py``), S==1 through the decode kernel
+    (``mla_decode.py``) and a padded S>1 through the prefill kernel when
+    the geometry supports them; ``moe_kw`` goes to the grouped
     expert layer. Returns ``(h, pages, aux)``, ``aux`` the expert layer's
     counts (empty for a dense layer)."""
     # stage names for the device trace, as in models/llama.py
@@ -546,11 +605,12 @@ def _layer_step(cfg: ModelConfig, lp, h, positions, total_lens, new_lens,
         q_lat, q_pe, c_kv, k_pe, w_uv = _mla_qkv(cfg, lp, h, positions)
         k_new, v_new = _cache_rows(cfg, c_kv, k_pe)
     with jax.named_scope("layer.kv_write"):
-        pages = write_kv(pages, lidx, k_new, v_new, page_table,
-                         positions, new_lens)
+        pages = write_rows(pages, lidx, k_new, v_new, page_table,
+                           positions, total_lens, new_lens, starts)
     with jax.named_scope("layer.attn"):
         h = _attend(cfg, lp, h, q_lat, q_pe, w_uv, positions, total_lens,
-                    page_table, pages, lidx, use_pallas=use_pallas)
+                    new_lens, page_table, pages, lidx,
+                    use_pallas=use_pallas, starts=starts)
     with jax.named_scope("layer.moe" if moe else "layer.ffn"):
         x = _rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
         if moe:
@@ -567,18 +627,20 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             page_table: jnp.ndarray, total_lens: jnp.ndarray,
             new_lens: jnp.ndarray,
             attn_impl: Optional[Callable] = None, ep_mesh=None,
-            logits_window: int = 1
+            logits_window: int = 1, packed: bool = False
             ) -> Tuple[jnp.ndarray, jnp.ndarray, dict]:
-    """Scan forward (llama.forward contract plus the ``aux`` third return
+    """Scan forward (llama.forward contract, the token-packed form
+    included, plus the ``aux`` third return
     carrying the expert layer's counts summed over layers, like
     models/moe.py: ``moe_experts_touched`` and ``moe_assignments``, or the
     dispatch backend's ``moe_dropped_assignments``). The GQA
     Pallas kernels the engine passes as ``attn_impl`` cannot run latent
     attention, so they are never CALLED here — but an impl carrying the
-    ``pallas_paged_kernel`` marker (both stacked kernels set it) opts
+    ``pallas_paged_kernel`` marker (every stacked kernel sets it) opts
     the family into its OWN latent kernels when the geometry supports it
-    (kv_lora_rank % 128 == 0 — true for real V2/V3 checkpoints): S==1
-    steps ride ``ops/pallas/mla_decode.py``, S>1 chunks
+    (kv_lora_rank % 128 == 0 — true for real V2/V3 checkpoints): a
+    token-packed step rides ``ops/pallas/mla_ragged.py``, S==1
+    steps ``ops/pallas/mla_decode.py``, padded S>1 chunks
     ``ops/pallas/mla_prefill.py``, and the expert layer its
     ``moe_grouped`` kernel. Any other non-None impl is ignored
     (the XLA paths serve), matching gemma's marker pattern."""
@@ -589,6 +651,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     use_pallas = (getattr(attn_impl, "pallas_paged_kernel", False)
                   and mla_supports(cfg.kv_lora_rank, pages.shape[-2]))
     K = cfg.first_k_dense_replace
+    starts = packed_rows(packed, new_lens)
     with jax.named_scope("embed"):
         h = params["embed"][tokens]
     B, S = tokens.shape
@@ -604,7 +667,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             h, pages, aux = _layer_step(
                 cfg, lp, h, positions, total_lens, new_lens, page_table,
                 pages, lidx, moe=moe, use_pallas=use_pallas, ep_mesh=ep_mesh,
-                moe_kw=kw)
+                moe_kw=kw, starts=starts)
             return (h, pages), aux
         return step
 
@@ -614,17 +677,22 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             (params["dense_layers"], jnp.arange(K)))
     if "moe_layers" in params:
         scanned, experts = split_experts(cfg, params["moe_layers"])
-        # slots that hold no token (padding of a [B, S] step, dead rows
-        # of a fused block) route to no expert
-        valid = (jnp.arange(S)[None, :] < new_lens[:, None]).reshape(B * S)
+        # slots that hold no token (padding of either step form, dead
+        # rows of a fused block) route to no expert
+        valid = (jnp.arange(S) < jnp.sum(new_lens) if packed else
+                 (jnp.arange(S)[None, :] < new_lens[:, None]).reshape(B * S))
         (h, pages), aux = jax.lax.scan(
             body(True, experts, valid=valid,
                  use_pallas=grouped_on_chip(attn_impl)), (h, pages),
             (scanned, K + jnp.arange(cfg.num_layers - K)))
         aux = sum_aux(aux)
     with jax.named_scope("logits"):
-        logits = _logits(cfg, params, h, new_lens, window=logits_window)
+        logits = _logits(cfg, params, h, new_lens, window=logits_window,
+                         starts=starts)
     return logits, pages, aux
+
+
+forward.supports_packed = True
 
 
 # ------------------------------------------------------------------ loader

@@ -342,6 +342,25 @@ def _gathered_to_bhtd(g: jnp.ndarray) -> jnp.ndarray:
     return g.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, P * ps, Dh)
 
 
+def packed_token_rows(T: int, page_table: jnp.ndarray,
+                      q_starts: jnp.ndarray, q_lens: jnp.ndarray,
+                      kv_lens: jnp.ndarray):
+    """A token-packed step's rows, told per token: ``(valid [T], pos [T],
+    table [T, P], total [T])``. Token ``t`` belongs to the first row whose
+    end exceeds ``t`` and sits at ``kv_lens - q_lens + (t - q_starts)`` of
+    it; a pad slot (of no row) gets the garbage page with a 1-token
+    context: finite work, its result masked by ``valid``."""
+    t_idx = jnp.arange(T)
+    ends = q_starts + q_lens
+    row = jnp.sum(t_idx[:, None] >= ends[None, :], axis=1)
+    row = jnp.minimum(row, page_table.shape[0] - 1)
+    valid = (t_idx >= q_starts[row]) & (t_idx < ends[row])
+    pos = kv_lens[row] - q_lens[row] + (t_idx - q_starts[row])
+    return (valid, jnp.where(valid, pos, 0),
+            jnp.where(valid[:, None], page_table[row], 0),
+            jnp.where(valid, kv_lens[row], 1))
+
+
 def ragged_paged_attention(q: jnp.ndarray, pages: jnp.ndarray, layer_idx,
                            page_table: jnp.ndarray, q_starts: jnp.ndarray,
                            q_lens: jnp.ndarray, kv_lens: jnp.ndarray,
@@ -373,21 +392,11 @@ def ragged_paged_attention(q: jnp.ndarray, pages: jnp.ndarray, layer_idx,
     this is the portable reference and the CPU-test oracle.
     """
     T, Hq, Dh = q.shape
-    B, P = page_table.shape
+    P = page_table.shape[1]
     Hkv = pages.shape[3]
     ps = pages.shape[4]
-    t_idx = jnp.arange(T)
-    ends = q_starts + q_lens
-    # packed rows: token t belongs to the first row whose end exceeds t
-    row = jnp.sum(t_idx[:, None] >= ends[None, :], axis=1)
-    row = jnp.minimum(row, B - 1)
-    valid = (t_idx >= q_starts[row]) & (t_idx < ends[row])
-    pos = kv_lens[row] - q_lens[row] + (t_idx - q_starts[row])
-    pos = jnp.where(valid, pos, 0)
-    # pad tokens attend the garbage page with a 1-token context: finite
-    # work, masked result discarded below
-    tok_table = jnp.where(valid[:, None], page_table[row], 0)
-    tok_total = jnp.where(valid, kv_lens[row], 1)
+    valid, pos, tok_table, tok_total = packed_token_rows(
+        T, page_table, q_starts, q_lens, kv_lens)
     qg = q.reshape(T, 1, Hkv, Hq // Hkv, Dh)
     chunk_pages = min(PAGES_PER_CHUNK, P)
     table = _pad_table(tok_table, chunk_pages)
@@ -452,6 +461,6 @@ def paged_attention(q: jnp.ndarray, pages: jnp.ndarray, layer_idx,
 
 
 __all__ = ["write_kv", "write_kv_packed", "paged_attention", "horizon",
-           "ragged_paged_attention",
+           "ragged_paged_attention", "packed_token_rows",
            "merge_softmax_partials", "normalize_softmax_partials",
            "NEG_INF"]

@@ -10,8 +10,9 @@ over layers), so a step program compiles one layer body:
 - ``prefill.paged_prefill_attention_stacked`` — a padded ``[B, S]`` chunk
   batch; ``ragged.ragged_mixed_attention_packed`` — a token-packed step.
 - ``mla_decode.mla_paged_decode_stacked`` /
-  ``mla_prefill.mla_paged_prefill_stacked`` — the same two step forms over
-  DeepSeek's latent cache.
+  ``mla_prefill.mla_paged_prefill_stacked`` /
+  ``mla_ragged.mla_ragged_attention_packed`` — the same three step forms
+  over DeepSeek's latent cache.
 
 The XLA implementations in ``dynamo_tpu.ops.attention`` remain the portable
 reference (CPU tests).
@@ -20,7 +21,9 @@ reference (CPU tests).
 from dynamo_tpu.ops.pallas.decode import paged_decode_attention_stacked
 from dynamo_tpu.ops.pallas.mla_decode import mla_paged_decode_stacked
 from dynamo_tpu.ops.pallas.mla_prefill import mla_paged_prefill_stacked
+from dynamo_tpu.ops.pallas.mla_ragged import mla_ragged_attention_packed
 from dynamo_tpu.ops.pallas.ragged import ragged_mixed_attention_packed
 
 __all__ = ["paged_decode_attention_stacked", "mla_paged_decode_stacked",
-           "mla_paged_prefill_stacked", "ragged_mixed_attention_packed"]
+           "mla_paged_prefill_stacked", "mla_ragged_attention_packed",
+           "ragged_mixed_attention_packed"]

@@ -207,7 +207,8 @@ def build_engine(args: argparse.Namespace, startup=None) -> JaxEngine:
     of the build: ``startup.weights`` (configuration, mesh, parameters)
     and ``startup.engine`` (page pools, jit wrappers; its attribute
     ``sample.top_candidates`` says which form the sampler's selection
-    takes at this vocabulary). Callers that keep no startup trace (run.py,
+    takes at this vocabulary, ``prefill.form`` which form the
+    prefill-carrying steps take: ``packed`` or ``padded:<reason>``). Callers that keep no startup trace (run.py,
     step followers) pass none."""
     from dynamo_tpu.ops.sampling import candidate_form
     from dynamo_tpu.utils.tracing import StartupTrace
@@ -221,6 +222,11 @@ def build_engine(args: argparse.Namespace, startup=None) -> JaxEngine:
                 cfg.moe_backend if cfg.moe_backend != "grouped" else
                 f"grouped[E={cfg.num_experts},k={cfg.num_experts_per_tok}]")
         engine = JaxEngine(cfg, params, engine_cfg, forward_fn=forward_fn)
+        # the form of the prefill-carrying steps: what
+        # dynamo_worker_prefill_steps_total{form} will count
+        attrs["prefill.form"] = (
+            "packed" if engine.padded_reason is None
+            else f"padded:{engine.padded_reason}")
         if engine.gen_block > 1:
             attrs["generation"] = engine.generation
         return engine

@@ -31,7 +31,7 @@ def test_cpu_dry_run_passes_and_says_where_it_ran():
     assert "worker0: platform=cpu" in r.stdout
     assert "attn_impl=scan" in r.stdout
     for kernel in ("decode", "prefill", "ragged", "mla_decode",
-                   "mla_prefill"):
+                   "mla_prefill", "mla_ragged"):
         assert f"kernel {kernel} " in r.stdout, r.stdout
     # the compiled step programs were read for copies of the page pool
     for program in ("decode", "fused", "mixed"):

@@ -512,6 +512,90 @@ class TestMlaPallasPrefill:
                                    rtol=2e-3, atol=2e-3)
 
 
+class TestMlaPallasRagged:
+    """The latent (MLA) Pallas kernel of a TOKEN-PACKED step
+    (``ops/pallas/mla_ragged``), interpreted, against the full-gather
+    latent math told per row — independent of the blockwise reference the
+    packed forward runs off the chip (``deepseek.mla_ragged_attention``),
+    which is held to the same oracle here."""
+
+    # (start position, new tokens) per row: a chunk deep in a cached
+    # prefix that spans several 64-token page chunks, a fresh chunk, two
+    # decode rows, a pad row
+    PLAN = [(150, 21), (0, 30), (77, 1), (8, 1), (0, 0)]
+
+    def _mk(self, seed=0):
+        L, ps, dkv, dr, nh, P = 3, 8, 128, 16, 4, 24
+        R = len(self.PLAN)
+        pages = jax.random.normal(jax.random.PRNGKey(seed),
+                                  (L, 1 + R * P, 2, 1, ps, dkv))
+        pages = pages.at[:, :, 1, :, :, dr:].set(0.0)
+        table = jnp.arange(1, 1 + R * P, dtype=jnp.int32).reshape(R, P)
+        new = jnp.asarray([n for _s, n in self.PLAN], jnp.int32)
+        total = jnp.asarray([s + n if n else 1 for s, n in self.PLAN],
+                            jnp.int32)
+        T = 64                      # 54 real slots, the rest pad
+        q_lat = jax.random.normal(jax.random.PRNGKey(seed + 1),
+                                  (T, nh, dkv))
+        q_pe = jax.random.normal(jax.random.PRNGKey(seed + 2),
+                                 (T, nh, dr))
+        return pages, q_lat, q_pe, (table, jnp.cumsum(new) - new, new,
+                                    total)
+
+    def _ref(self, q_lat, q_pe, pages, layer, rows):
+        """Row by row through the padded oracle of the prefill kernel
+        (whose softmax scale is 0.1)."""
+        table, starts, new, total = rows
+        out = np.zeros(q_lat.shape, np.float32)
+        for r, (start, n) in enumerate(self.PLAN):
+            if not n:
+                continue
+            sl = slice(int(starts[r]), int(starts[r]) + n)
+            pos = (start + jnp.arange(n))[None]
+            out[sl] = np.asarray(TestMlaPallasPrefill._ref(
+                q_lat[sl][None], q_pe[sl][None], pages, layer,
+                table[r:r + 1], pos, total[r:r + 1]))[0]
+        return out
+
+    @pytest.mark.parametrize("query_block", [None, 8])
+    def test_kernel_and_reference_match_latent_attention(self, query_block,
+                                                         monkeypatch):
+        from dynamo_tpu.ops.pallas import mla_ragged as mr
+        if query_block:
+            monkeypatch.setattr(mr, "_query_block", lambda *a: query_block)
+        mr._mla_ragged.clear_cache()
+        pages, q_lat, q_pe, rows = self._mk()
+        cfg = ds_cfg(kv_lora_rank=128, head_dim=128, num_heads=4,
+                     qk_rope_head_dim=16, qk_nope_head_dim=84)
+        assert abs(deepseek._mla_scale(cfg) - 0.1) < 1e-9
+        for layer in (0, 2):
+            ref = self._ref(q_lat, q_pe, pages, layer, rows)
+            out = mr.mla_ragged_attention_packed(
+                q_lat, q_pe, pages, layer, *rows, 0.1, interpret=True)
+            np.testing.assert_allclose(ref, np.asarray(out),
+                                       rtol=2e-4, atol=2e-4)
+            xla = deepseek.mla_ragged_attention(cfg, q_lat, q_pe, pages,
+                                                layer, *rows)
+            np.testing.assert_allclose(ref, np.asarray(xla),
+                                       rtol=2e-4, atol=2e-4)
+        mr._mla_ragged.clear_cache()
+
+    def test_traced_layer_inside_scan(self):
+        from dynamo_tpu.ops.pallas.mla_ragged import (
+            mla_ragged_attention_packed)
+        pages, q_lat, q_pe, rows = self._mk(seed=5)
+
+        def body(carry, lidx):
+            return carry, mla_ragged_attention_packed(
+                q_lat, q_pe, pages, lidx, *rows, 0.1, interpret=True)
+
+        _, outs = jax.lax.scan(body, 0, jnp.arange(pages.shape[0]))
+        for layer in range(pages.shape[0]):
+            np.testing.assert_allclose(
+                self._ref(q_lat, q_pe, pages, layer, rows),
+                np.asarray(outs[layer]), rtol=2e-4, atol=2e-4)
+
+
 class TestEngine:
     async def test_engine_generates_deepseek(self):
         from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig

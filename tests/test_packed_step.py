@@ -5,10 +5,13 @@ program: every row's new tokens back to back (chunk rows, then decode
 rows, pads behind them), the rows as descriptors ``[R]``. Under test:
 
 - every family that declares the packed form (``forward.supports_packed``:
-  llama/qwen, the llama-attention MoE, gemma) gives the same logits rows
-  and the same pool contents packed as padded, on plans with and without a
-  cached prefix, chunk ends off a page boundary, decode rows, pad rows and
-  a single row;
+  llama/qwen, the llama-attention MoE, gemma, MLA with and without a
+  compressed query) gives the same logits rows and the same pool contents
+  packed as padded, on plans with and without a cached prefix, chunk ends
+  off a page boundary, decode rows, pad rows and a single row;
+- the ragged MLA kernel (``ops/pallas/mla_ragged.py``, interpreted) gives
+  what its pure-JAX reference gives on the same plans, over one block of
+  slots and over several;
 - the engine packs where it can tell it may and serves padded everywhere
   else, and says which and why
   (``dynamo_worker_prefill_steps_total{form}``);
@@ -49,6 +52,15 @@ PLANS = {
     "single": [(PS + 1, 9)],
 }
 
+# MLA: latent attention over its own 2-slot cache [L, N, 2, 1, ps, dkv],
+# one dense layer then mixture layers with a shared expert
+MLA = dict(model_type="deepseek_v2", num_layers=3, num_heads=2,
+           num_kv_heads=1, head_dim=32, kv_lora_rank=32,
+           qk_rope_head_dim=8, qk_nope_head_dim=16, v_head_dim=16,
+           num_experts=4, num_experts_per_tok=2, moe_intermediate_size=32,
+           n_shared_experts=1, first_k_dense_replace=1,
+           routed_scaling_factor=1.0)
+
 FAMILIES = {
     "llama": (llama, dict()),
     "qwen3": (llama, dict(qk_norm=True)),
@@ -57,6 +69,8 @@ FAMILIES = {
     "gemma": (gemma, dict(model_type="gemma2", sliding_window=6,
                           attn_logit_softcap=30.0,
                           final_logit_softcap=20.0)),
+    "mla": (deepseek, dict(MLA, q_lora_rank=0)),
+    "mla_q_lora": (deepseek, dict(MLA, q_lora_rank=24)),
 }
 
 
@@ -113,8 +127,11 @@ def test_packed_forward_matches_padded(family, plan):
     want = mod.forward(params, cfg, *a["padded"], pages, *rows)
     got = mod.forward(params, cfg, *a["packed"], pages, *rows, packed=True)
     real = np.asarray(a["new"]) > 0
-    # the same rows in the same order, [R, V]
+    # the same rows in the same order, [R, V], and the same expert counts
     assert got[0].shape == want[0].shape
+    assert len(got) == len(want)
+    for k, v in (want[2] if len(want) > 2 else {}).items():
+        assert int(got[2][k]) == int(v), k
     np.testing.assert_allclose(np.asarray(got[0])[real],
                                np.asarray(want[0])[real],
                                rtol=2e-4, atol=2e-4)
@@ -155,17 +172,98 @@ def test_packed_forward_on_the_kernel_matches_padded(family, plan):
                                rtol=5e-3, atol=5e-3)
 
 
-def test_mla_does_not_declare_the_packed_form():
-    assert not getattr(deepseek.forward, "supports_packed", False)
+def _latent_plan(plan):
+    """A plan's packed row descriptors, latent queries for its slots and a
+    latent pool (slot 1 = the rope key zero-padded to the latent width)."""
+    nh, dkv, dr = 4, 128, 16
+    a = _plan_arrays(PLANS[plan], seed=3)
+    T = a["packed"][0].shape[1]
+    kq, kr, kp = jax.random.split(jax.random.PRNGKey(3), 3)
+    pool = jax.random.normal(kp, (2, N, 2, 1, PS, dkv))
+    pool = pool.at[:, :, 1, :, :, dr:].set(0.0)
+    cfg = _cfg(**dict(MLA, num_heads=nh, kv_lora_rank=dkv, head_dim=dkv,
+                      qk_rope_head_dim=dr))
+    starts = jnp.cumsum(a["new"]) - a["new"]
+    return (cfg, jax.random.normal(kq, (T, nh, dkv)),
+            jax.random.normal(kr, (T, nh, dr)), pool,
+            (a["table"], starts, a["new"], a["total"]))
+
+
+@pytest.mark.parametrize("query_block", [None, 8])
+@pytest.mark.parametrize("plan", sorted(PLANS))
+def test_mla_ragged_kernel_matches_its_reference(plan, query_block,
+                                                 monkeypatch):
+    """``mla_ragged`` (interpreted) against the pure-JAX latent attention
+    over the same packed layout: chunk from 0, continued chunk, cached
+    prefix, decode rows, pad and empty rows; slots of no row read zero.
+    ``query_block`` 8 cuts the plan's slots into several blocks, so rows
+    start and end inside a block and a block holds several rows."""
+    from dynamo_tpu.ops.pallas import mla_ragged
+
+    if query_block:
+        monkeypatch.setattr(mla_ragged, "_query_block",
+                            lambda *a: query_block)
+    mla_ragged._mla_ragged.clear_cache()
+    cfg, q_lat, q_pe, pool, rows = _latent_plan(plan)
+    want = deepseek.mla_ragged_attention(cfg, q_lat, q_pe, pool, 1, *rows)
+    got = mla_ragged.mla_ragged_attention_packed(
+        q_lat, q_pe, pool, 1, *rows, deepseek._mla_scale(cfg),
+        interpret=True)
+    mla_ragged._mla_ragged.clear_cache()
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    n_real = int(rows[2].sum())
+    assert float(jnp.abs(want[:n_real]).min(axis=(1, 2)).max()) > 0
+    np.testing.assert_array_equal(np.asarray(got)[n_real:], 0.0)
+
+
+@pytest.mark.parametrize("plan", sorted(PLANS))
+def test_packed_mla_forward_on_the_kernel_matches_padded(plan):
+    """The MLA forward, packed over ``mla_ragged`` (interpreted; the
+    marker of any stacked kernel opts the family into its own), against
+    the padded form on the XLA path."""
+    from dynamo_tpu.ops.pallas.ragged import ragged_mixed_attention_packed
+
+    cfg = _cfg(**dict(MLA, kv_lora_rank=128, head_dim=128, q_lora_rank=24))
+    params = deepseek.init_params(cfg, jax.random.PRNGKey(1))
+    pages = jax.random.normal(jax.random.PRNGKey(2),
+                              (cfg.num_layers, N, 2, 1, PS, 128))
+    pages = pages.at[:, :, 1, :, :, cfg.qk_rope_head_dim:].set(0.0)
+    a = _plan_arrays(PLANS[plan], seed=5)
+    rows = (a["table"], a["total"], a["new"])
+    want = deepseek.forward(params, cfg, *a["padded"], pages, *rows)
+    got = deepseek.forward(params, cfg, *a["packed"], pages, *rows,
+                           attn_impl=ragged_mixed_attention_packed,
+                           packed=True)
+    real = np.asarray(a["new"]) > 0
+    np.testing.assert_allclose(np.asarray(got[0])[real],
+                               np.asarray(want[0])[real],
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(got[1])[:, 1:],
+                               np.asarray(want[1])[:, 1:],
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_mla_declares_the_packed_form():
+    assert deepseek.forward.supports_packed
 
 
 @pytest.mark.parametrize("n,want", [
-    (1, 16), (16, 16), (17, 32), (300, 512), (512, 512), (513, 640),
+    (1, 16), (16, 16), (17, 32), (300, 512), (512, 512), (513, 1152),
     (1024 + 22, 1152), (1024 + 64, 1152)])
 def test_the_token_ladder(n, want):
-    """Powers of two from the floor to 512, then steps of 128: 1,152 for
-    the worker's 1,024-token budget beside its decode rows."""
-    assert _token_bucket(n, 16) == want
+    """Powers of two from the floor to 512, then one rung, the step's cap:
+    1,152 for the worker's 1,024-token budget beside its decode rows."""
+    assert _token_bucket(n, 16, 1152) == want
+
+
+def test_the_token_ladder_under_a_pinned_floor_and_a_small_cap():
+    """A floor above 512 pins the axis (one program, steps of 128 above
+    it, whatever the cap); a cap under 512 never shortens a step."""
+    assert [_token_bucket(n, 1024, 1152) for n in (1, 600, 1024, 1025)] \
+        == [1024, 1024, 1024, 1152]
+    assert [_token_bucket(n, 16, 128) for n in (100, 520)] == [128, 640]
 
 
 # -- the engine: which form, and why --------------------------------------
@@ -203,31 +301,46 @@ def _plain_forward(params, cfg, tokens, positions, pages, page_table,
                          total_lens, new_lens, attn_impl=attn_impl)
 
 
-def _mla_cfg():
-    return ModelConfig(
-        vocab_size=128, hidden_size=64, intermediate_size=96, num_layers=2,
-        num_heads=2, num_kv_heads=1, head_dim=32, model_type="deepseek_v2",
-        dtype="float32", q_lora_rank=0, kv_lora_rank=32,
-        qk_rope_head_dim=8, qk_nope_head_dim=16, v_head_dim=16,
-        num_experts=4, num_experts_per_tok=2, moe_intermediate_size=32,
-        n_shared_experts=1, first_k_dense_replace=1,
-        routed_scaling_factor=1.0)
+def _mla_cfg(**kw):
+    return _cfg(**dict(MLA, vocab_size=128, num_layers=2, q_lora_rank=0,
+                       **kw))
+
+
+def _unmarked_family(cfg):
+    """A family whose forward does not declare the packed form (what MLA
+    was until ISSUE 38): llama behind a forward without the marker."""
+    import types
+
+    def forward(params, cfg, tokens, positions, pages, page_table,
+                total_lens, new_lens, attn_impl=None, logits_window=1):
+        return llama.forward(params, cfg, tokens, positions, pages,
+                             page_table, total_lens, new_lens,
+                             attn_impl=attn_impl,
+                             logits_window=logits_window)
+
+    return types.SimpleNamespace(forward=forward,
+                                 init_params=llama.init_params)
 
 
 @pytest.mark.parametrize("case,reason", [
     ("kernels", None), ("spec", "spec"), ("dp", "dp"),
     ("forward_fn", "forward"), ("family", "family"),
-    ("scan", "attn_impl")])
-def test_engine_picks_the_form_from_what_it_is(case, reason):
+    ("scan", "attn_impl"), ("mla", None), ("mla_scan", "attn_impl")])
+def test_engine_picks_the_form_from_what_it_is(case, reason, monkeypatch):
     kw = {
         "kernels": dict(),
         "spec": dict(spec_tokens=2),
         "dp": dict(attn_impl="scan", mesh=_dp_mesh()) if case == "dp"
         else {},
         "forward_fn": dict(forward_fn=_plain_forward),
-        "family": dict(cfg=_mla_cfg(), attn_impl="scan", page_size=4),
+        "family": dict(),
         "scan": dict(attn_impl="scan"),
+        "mla": dict(cfg=_mla_cfg(kv_lora_rank=128, head_dim=128)),
+        "mla_scan": dict(cfg=_mla_cfg(), attn_impl="scan", page_size=4),
     }[case]
+    if case == "family":
+        import dynamo_tpu.models as models
+        monkeypatch.setattr(models, "get_family", _unmarked_family)
     eng = _engine(**kw)
     assert eng.padded_reason == reason
     assert (eng._jit_packed is None) == (reason is not None)
@@ -270,19 +383,22 @@ async def _serve(eng, reqs):
 REQS = [(range(1, 30), "a", 12), ([5, 6, 7], "b", 9), (range(40, 61), "c", 7)]
 
 
-async def test_packed_engine_streams_what_the_padded_engine_streams():
-    """The engine on the kernels (interpreted) serves packed, the XLA scan
-    engine padded; greedy streams are the same tokens, and each counts its
-    prefill-carrying steps under its own form."""
-    packed = _engine()
+@pytest.mark.parametrize("family", ["llama", "mla"])
+async def test_packed_engine_streams_what_the_padded_engine_streams(family):
+    """The engine on the kernels (interpreted) serves packed — the Llama
+    tree through ``ragged_mixed``, MLA through ``mla_ragged`` — the XLA
+    scan engine padded; greedy streams are the same tokens, and each
+    counts its prefill-carrying steps under its own form."""
+    cfg = (_mla_cfg(kv_lora_rank=128, head_dim=128) if family == "mla"
+           else None)
+    packed = _engine(cfg=cfg)
     got = await _serve(packed, [_req(*r) for r in REQS])
-    padded = _engine(attn_impl="scan")
+    padded = _engine(cfg=cfg, attn_impl="scan")
     want = await _serve(padded, [_req(*r) for r in REQS])
     assert got == want
     assert [len(t) for t in got] == [12, 9, 7]
-    n = packed.prefill_steps["packed"]
-    assert n > 0 and packed.mixed_steps > 0
-    assert sum(packed.prefill_steps.values()) == n
+    assert packed.prefill_steps["packed"] > 0 and packed.mixed_steps > 0
+    assert set(packed.prefill_steps) == {"packed"}
     assert padded.prefill_steps["padded:attn_impl"] > 0
     assert set(padded.prefill_steps) == {"padded:attn_impl"}
 

@@ -24,10 +24,11 @@ def _native_kernels(monkeypatch):
     off ``jax.default_backend()`` (cpu here), but these tests lower for
     the TPU platform — the kernels must take their native path."""
     from dynamo_tpu.ops.pallas import (decode, mla_decode, mla_prefill,
-                                       moe_grouped, prefill, ragged)
+                                       mla_ragged, moe_grouped, prefill,
+                                       ragged)
 
-    for mod in (decode, prefill, mla_decode, mla_prefill, ragged,
-                moe_grouped):
+    for mod in (decode, prefill, mla_decode, mla_prefill, mla_ragged,
+                ragged, moe_grouped):
         monkeypatch.setattr(mod, "_resolve_interpret",
                             lambda interpret: False)
 
@@ -633,3 +634,93 @@ def test_the_expert_stacks_are_drawn_without_a_float32_copy():
     mem = compiled.memory_analysis()
     assert mem.output_size_in_bytes == 7 * one_layer_f32 // 2
     assert mem.temp_size_in_bytes < 2 * one_layer_f32
+
+
+# -- latent attention over a token-packed step (ISSUE 38) -----------------
+
+def _v5e_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu in this install
+        pytest.skip(f"no compile-only TPU topology: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("T", [256, 512, 1152])
+@pytest.mark.parametrize("nh", [32, 16])
+def test_mla_ragged_kernel_compiles_in_the_tpu_compiler(nh, T):
+    """``mla_ragged`` at the MLA cell's geometry (JoyAI-LLM-Flash: 32
+    heads, latent 512, rope 64, page 16, 16 rows over a table of 512
+    pages) and at DeepSeek-V2-Lite's (16 heads), on the three token axes
+    the cell's packed step meets: the TPU compiler takes it, which
+    includes the scoped-VMEM limit that export lowering does not check."""
+    from dynamo_tpu.ops.pallas.mla_ragged import mla_ragged_attention_packed
+
+    one_chip = _v5e_chip()
+    dkv, dr, R = 512, 64, 16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q_lat, q_pe, pages, table, starts, q_lens, kv_lens):
+        return mla_ragged_attention_packed(
+            q_lat, q_pe, pages, 1, table, starts, q_lens, kv_lens, 0.072)
+
+    text = jax.jit(fn).lower(
+        sds((T, nh, dkv), jnp.float32), sds((T, nh, dr), jnp.bfloat16),
+        sds((5, 4096, 2, 1, PS, dkv), jnp.bfloat16), sds((R, 512), jnp.int32),
+        sds((R,), jnp.int32), sds((R,), jnp.int32),
+        sds((R,), jnp.int32)).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "mla_ragged" in calls[0]
+
+
+def test_mla_engine_packs_without_a_pool_copy():
+    """An MLA engine on the kernels declares no reason to pad, and its
+    token-packed step at the MLA cell's widths (JoyAI-LLM-Flash's dense
+    layer and one expert layer; 16 rows, 512 slots, the cell's pool)
+    compiles for a v5e with ``mla_ragged`` and ``moe_grouped`` in it, no
+    pool-sized copy (whole latent pages are written in place) and no sort
+    over the vocabulary (engine/program_check.py)."""
+    import json
+    import os
+
+    from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
+    from dynamo_tpu.engine.program_check import (
+        pool_copies, step_programs, vocab_sorts)
+    from dynamo_tpu.models import deepseek
+    from dynamo_tpu.models.config import ModelConfig
+
+    one_chip = _v5e_chip()
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "configs",
+        "joyai-llm-flash.json")
+    with open(path) as f:
+        hf = json.load(f)
+    hf.pop("benchmark")
+    hf["num_hidden_layers"] = 2
+    cfg = ModelConfig.from_hf(hf)
+    abs_params = jax.eval_shape(
+        lambda: deepseek.init_params(cfg, jax.random.PRNGKey(0)))
+    eng = JaxEngine(cfg, abs_params, JaxEngineConfig(
+        num_pages=16, page_size=16, max_num_seqs=16, max_context=8192,
+        max_prefill_chunk=1024, attn_impl="pallas", decode_multistep=4))
+    assert eng.padded_reason is None and eng._packed_cap == 1152
+    programs = step_programs(eng, 16, 512, width=4, sharding=one_chip,
+                             num_pages=4096)
+    assert set(programs) == {"decode", "fused", "mixed", "packed"}
+    fn, args = programs["packed"]
+    compiled = fn.lower(*args).compile()
+    hlo = compiled.as_text()
+    assert "mla_ragged" in hlo and "moe_grouped" in hlo
+    assert "mla_prefill" not in hlo
+    pool = (2, 4096) + tuple(eng.pages.shape[2:])
+    assert pool[2:] == (2, 1, 16, 512)
+    assert pool_copies(hlo, pool, eng.pages.dtype) == []
+    assert vocab_sorts(hlo, cfg.vocab_size) == []
+    # a third of the padded [16, 512] step's 1.66 GB of temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9

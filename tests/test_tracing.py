@@ -626,7 +626,9 @@ async def test_worker_writes_its_startup_trace_when_ready(tmp_path):
     end = rec["start_unix"] + rec["duration_s"]
     assert end <= t_ready + 0.1 and kids[-1]["end_unix"] == \
         pytest.approx(end, abs=1e-3)
-    # which form the sampler's selection takes at this vocabulary (512)
-    assert kids[2]["attrs"] == {"sample.top_candidates": "direct"}
+    # which form the sampler's selection takes at this vocabulary (512),
+    # and which the prefill-carrying steps take (the CPU's XLA path)
+    assert kids[2]["attrs"] == {"sample.top_candidates": "direct",
+                                "prefill.form": "padded:attn_impl"}
     # the stages cover the start-up: imports and the engine build dominate
     assert sum(s["duration_s"] for s in kids) >= 0.8 * rec["duration_s"]
