@@ -1607,10 +1607,8 @@ async def _measure_routing() -> dict:
             await coord.stop()
 
 
-# step-flight-recorder leg geometry: generated tokens per row, A/B rounds
+# step-flight-recorder leg geometry: generated tokens per row
 STEPTRACE_GEN = int(os.environ.get("BENCH_STEPTRACE_GEN", "48"))
-STEPTRACE_ROUNDS = int(os.environ.get("BENCH_STEPTRACE_ROUNDS", "5"))
-STEPTRACE_REPS = int(os.environ.get("BENCH_STEPTRACE_REPS", "6"))
 
 
 async def _measure_steptrace() -> dict:
@@ -1618,18 +1616,14 @@ async def _measure_steptrace() -> dict:
     tiny engine with the per-dispatch ring (``engine/steptrace.py``)
     capturing every step.
 
-    Three phases on one engine:
+    Two phases on one engine:
 
     1. warm a small cohort's jit buckets, then RERUN the same shape on a
        fresh recorder — zero compile events expected (detection must not
        false-positive on warmed buckets);
     2. drive a cohort shape the engine has NEVER seen (bigger batch,
        longer prompts) mid-trace — the cold prefill/decode buckets must
-       surface as compile events attributable to specific StepRecords;
-    3. on-vs-off A/B on the now-warm big cohort, rounds interleaved so
-       clock drift hits both arms: recorder overhead must stay under the
-       ISSUE's 2% tok/s budget (it is one lock + in-place slot writes
-       per DISPATCH, not per token — fused width 8 amortises it 8x).
+       surface as compile events attributable to specific StepRecords.
 
     Results land in the run's JSON (``steptrace``) and — when
     ``BENCH_STEPTRACE_OUT`` names a path — in a standalone artifact
@@ -1674,7 +1668,7 @@ async def _measure_steptrace() -> dict:
         # phase 1: warm the small-cohort buckets (prefill bucket 8,
         # decode batch 2), then rerun the SAME shape on a fresh recorder
         await cohort("warm", 2, 8)
-        trace = StepRecorder(capacity=4096, enabled=True)
+        trace = StepRecorder(capacity=4096)
         engine.steptrace = trace
         await cohort("rerun", 2, 8)
         warm_rerun_events = sum(trace.compile_events.values())
@@ -1706,50 +1700,9 @@ async def _measure_steptrace() -> dict:
             "pool_pinned": agg["pool_pinned"],
         }
 
-        # phase 3: on-vs-off A/B on the now-warm big cohort. A single
-        # cohort is ~60ms of wall on CPU and jitters +-10% round to
-        # round, so the A/B is PAIRED: each round runs both arms
-        # back-to-back (order alternating so drift cannot favour one),
-        # each arm repeats the cohort STEPTRACE_REPS times to widen the
-        # window, and the reported overhead is the MEDIAN of the
-        # per-round paired differences — robust to the one round a GC
-        # pause lands in.
-        async def ab_arm(enabled: bool) -> float:
-            engine.steptrace = StepRecorder(capacity=4096, enabled=enabled)
-            tokens = 0
-            wall = 0.0
-            for _ in range(STEPTRACE_REPS):
-                t, w = await cohort("ab", 6, 24)
-                tokens += t
-                wall += w
-            return tokens / wall if wall > 0 else 0.0
-
-        await ab_arm(True)  # settle: any residual compile lands here
-        offs: list = []
-        ons: list = []
-        for r in range(STEPTRACE_ROUNDS):
-            if r % 2 == 0:
-                offs.append(await ab_arm(False))
-                ons.append(await ab_arm(True))
-            else:
-                ons.append(await ab_arm(True))
-                offs.append(await ab_arm(False))
-        diffs = sorted((o - n) / o * 100
-                       for o, n in zip(offs, ons) if o > 0)
-        overhead_pct = (round(diffs[len(diffs) // 2], 2)
-                        if diffs else 0.0)
-        med = lambda xs: sorted(xs)[len(xs) // 2] if xs else 0.0  # noqa: E731
-        ab_info = {"off_tok_s": round(med(offs), 1),
-                   "on_tok_s": round(med(ons), 1),
-                   "overhead_pct": overhead_pct,
-                   "rounds": STEPTRACE_ROUNDS, "reps": STEPTRACE_REPS}
-
-        result = {"compile": compile_info, "aggregates": aggregates_info,
-                  "ab": ab_info}
+        result = {"compile": compile_info, "aggregates": aggregates_info}
         _note("steptrace", midrun_compiles=midrun_events,
-              warm_rerun_events=warm_rerun_events,
-              overhead_pct=overhead_pct, off_tok_s=ab_info["off_tok_s"],
-              on_tok_s=ab_info["on_tok_s"])
+              warm_rerun_events=warm_rerun_events)
         out_path = os.environ.get("BENCH_STEPTRACE_OUT")
         if out_path:
             with open(out_path, "w") as f:

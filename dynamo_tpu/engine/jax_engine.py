@@ -46,6 +46,7 @@ import numpy as np
 
 from dynamo_tpu.engine.loop import BlockState, ScheduledEngineBase
 from dynamo_tpu.engine.scheduler import PrefillBatch, StepPlan
+from dynamo_tpu.engine.steptrace import stage
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models import llama
 from dynamo_tpu.ops.sampling import (TOPK_MAX, reveal, sample_tokens,
@@ -1506,17 +1507,18 @@ class JaxEngine(ScheduledEngineBase):
     def _execute_plan(self, plan: StepPlan):
         """Build padded arrays, run the jitted step, fetch sampled tokens."""
         from dynamo_tpu.engine.scheduler import (MixedStepBatch,
-                                                 PrefillChunk,
                                                  SpecDecodeBatch)
         if isinstance(plan, SpecDecodeBatch):
-            arrays = self._spec_arrays(plan.seqs, plan.drafts)
+            with stage("assemble"):
+                arrays = self._spec_arrays(plan.seqs, plan.drafts)
             plan._step_id = self._step_counter
             if self.step_tap is not None:
                 self.step_tap("spec", arrays, self._step_counter)
             packed = self._invoke_step("spec", arrays, self._step_counter)
             self._step_counter += 1
             self.decode_dispatches += 1
-            host = np.asarray(packed)
+            with stage("wait"):
+                host = np.asarray(packed)
             hostf = host.view(np.float32)   # one reinterpret, no copies
             B = host.shape[0]
             K, S = self.spec_K, self.spec_K + 1
@@ -1535,95 +1537,13 @@ class JaxEngine(ScheduledEngineBase):
                 extras["spec_top_lps"] = hostf[
                     :, base + S * kt:base + 2 * S * kt].reshape(B, S, kt)
             return sampled, logprobs, extras
-        P = self.table_width
         mixed = isinstance(plan, MixedStepBatch)
-        if mixed or isinstance(plan, PrefillBatch):
-            chunks = list(plan.chunks)
-            ring = (not mixed) and plan.ring
-            if mixed:
-                # decode rows ARE ragged chunks of length 1: feed the
-                # newest token at position len-1 (== num_computed), sample
-                # its successor at the row's last-real-token slot — the
-                # same array shape the prefill rows use
-                chunks += [PrefillChunk(seq=s, start=len(s) - 1, length=1,
-                                        is_last=True)
-                           for s in plan.decode_seqs]
-            # the form of this step: token-packed, or padded and why
-            reason = "ring" if ring else self.padded_reason
-            pack = reason is None
-            form = "packed" if pack else f"padded:{reason}"
-            self.prefill_steps[form] = self.prefill_steps.get(form, 0) + 1
-            if ring:
-                # whole-prompt sequence-parallel step: B=1, S may exceed the
-                # chunk budget; pad S to a power of two (bounded compile
-                # count) that divides evenly over the sp ring
-                B = 1
-                S = _bucket(chunks[0].length, self.cfg.min_prefill_bucket,
-                            self.cfg.max_context)
-                S = -(-S // self._sp) * self._sp
-            else:
-                B = _bucket(len(chunks), self.cfg.min_prefill_seqs_bucket,
-                            self.cfg.max_num_seqs)
-                S = (_token_bucket(sum(c.length for c in chunks),
-                                   self.cfg.min_prefill_bucket,
-                                   self._packed_cap)
-                     if pack else
-                     _bucket(max(c.length for c in chunks),
-                             self.cfg.min_prefill_bucket,
-                             self.cfg.max_prefill_chunk))
-            # packed: every row's new tokens back to back on one [1, T]
-            # axis, chunk rows then decode rows; the row arrays stay [B]
-            toks = np.zeros((1 if pack else B, S), np.int32)
-            pos = np.zeros_like(toks)
-            table = np.zeros((B, P), np.int32)
-            total = np.ones(B, np.int32)   # pad rows: 1 garbage-page token
-            new = np.zeros(B, np.int32)    # pad rows: write nothing
-            temp = np.zeros(B, np.float32)
-            top_k = np.zeros(B, np.int32)
-            top_p = np.ones(B, np.float32)
-            at = 0                         # a packed row's first slot
-            for i, c in enumerate(chunks):
-                seq = c.seq
-                # where the row's new tokens go: its own padded row, or
-                # its slots of the packed axis
-                row, lo = (0, at) if pack else (i, 0)
-                at += c.length
-                if c.length == 1 and c.start == len(seq) - 1:
-                    # decode row: skip the O(context) token-list build
-                    toks[row, lo] = seq.tokens.last_token()
-                else:
-                    all_tokens = seq.tokens.tokens()
-                    toks[row, lo:lo + c.length] = all_tokens[
-                        c.start:c.start + c.length]
-                pos[row, lo:lo + c.length] = np.arange(c.start,
-                                                       c.start + c.length)
-                table[i, :len(seq.page_ids)] = seq.page_ids
-                total[i] = c.start + c.length
-                new[i] = c.length
-                so = seq.request.sampling_options
-                if so.temperature is not None:
-                    temp[i] = so.temperature
-                top_k[i] = so.top_k or 0
-                if so.top_p is not None:
-                    top_p[i] = so.top_p
-        else:
-            return self.fetch_packed(self.dispatch_decode(plan))
-        kind = "step"
-        if mixed:
-            kind = "mixed"
-            self.decode_dispatches += 1
-            self.mixed_steps += 1
-        if pack:
-            # the program, not the plan: followers replay it by this name
-            kind = "packed"
-        elif ring:
-            kind = "ring"
-            self.ring_steps += 1
-            logger.info("ring prefill: %d prompt tokens in one step over "
-                        "sp=%d", plan.chunks[0].length, self._sp)
-        arrays = dict(toks=toks, pos=pos, table=table, total=total, new=new,
-                      temp=temp, top_k=top_k, top_p=top_p,
-                      **self._sampling_extras([c.seq for c in chunks], B))
+        if not (mixed or isinstance(plan, PrefillBatch)):
+            handle = self.dispatch_decode(plan)
+            with stage("wait"):
+                return self.fetch_packed(handle)
+        with stage("assemble"):
+            kind, chunks, arrays = self._prefill_arrays(plan, mixed)
         plan._step_id = self._step_counter
         if self.step_tap is not None:
             self.step_tap(kind, arrays, self._step_counter)
@@ -1642,7 +1562,100 @@ class JaxEngine(ScheduledEngineBase):
             # real sync or a symmetric failure would read as divergence.
             B = arrays["total"].shape[0]
             return np.zeros(B, np.int64), np.zeros(B, np.float32), None
-        return self.fetch_packed(packed)
+        with stage("wait"):
+            return self.fetch_packed(packed)
+
+    def _prefill_arrays(self, plan, mixed: bool):
+        """Host arrays for one prefill-carrying step (token-packed, padded
+        or ring), with the kind of program that runs them and the rows in
+        the arrays' order: (kind, chunks, arrays)."""
+        from dynamo_tpu.engine.scheduler import PrefillChunk
+        P = self.table_width
+        chunks = list(plan.chunks)
+        ring = (not mixed) and plan.ring
+        if mixed:
+            # decode rows ARE ragged chunks of length 1: feed the
+            # newest token at position len-1 (== num_computed), sample
+            # its successor at the row's last-real-token slot — the
+            # same array shape the prefill rows use
+            chunks += [PrefillChunk(seq=s, start=len(s) - 1, length=1,
+                                    is_last=True)
+                       for s in plan.decode_seqs]
+        # the form of this step: token-packed, or padded and why
+        reason = "ring" if ring else self.padded_reason
+        pack = reason is None
+        form = "packed" if pack else f"padded:{reason}"
+        self.prefill_steps[form] = self.prefill_steps.get(form, 0) + 1
+        if ring:
+            # whole-prompt sequence-parallel step: B=1, S may exceed the
+            # chunk budget; pad S to a power of two (bounded compile
+            # count) that divides evenly over the sp ring
+            B = 1
+            S = _bucket(chunks[0].length, self.cfg.min_prefill_bucket,
+                        self.cfg.max_context)
+            S = -(-S // self._sp) * self._sp
+        else:
+            B = _bucket(len(chunks), self.cfg.min_prefill_seqs_bucket,
+                        self.cfg.max_num_seqs)
+            S = (_token_bucket(sum(c.length for c in chunks),
+                               self.cfg.min_prefill_bucket,
+                               self._packed_cap)
+                 if pack else
+                 _bucket(max(c.length for c in chunks),
+                         self.cfg.min_prefill_bucket,
+                         self.cfg.max_prefill_chunk))
+        # packed: every row's new tokens back to back on one [1, T]
+        # axis, chunk rows then decode rows; the row arrays stay [B]
+        toks = np.zeros((1 if pack else B, S), np.int32)
+        pos = np.zeros_like(toks)
+        table = np.zeros((B, P), np.int32)
+        total = np.ones(B, np.int32)   # pad rows: 1 garbage-page token
+        new = np.zeros(B, np.int32)    # pad rows: write nothing
+        temp = np.zeros(B, np.float32)
+        top_k = np.zeros(B, np.int32)
+        top_p = np.ones(B, np.float32)
+        at = 0                         # a packed row's first slot
+        for i, c in enumerate(chunks):
+            seq = c.seq
+            # where the row's new tokens go: its own padded row, or
+            # its slots of the packed axis
+            row, lo = (0, at) if pack else (i, 0)
+            at += c.length
+            if c.length == 1 and c.start == len(seq) - 1:
+                # decode row: skip the O(context) token-list build
+                toks[row, lo] = seq.tokens.last_token()
+            else:
+                all_tokens = seq.tokens.tokens()
+                toks[row, lo:lo + c.length] = all_tokens[
+                    c.start:c.start + c.length]
+            pos[row, lo:lo + c.length] = np.arange(c.start,
+                                                   c.start + c.length)
+            table[i, :len(seq.page_ids)] = seq.page_ids
+            total[i] = c.start + c.length
+            new[i] = c.length
+            so = seq.request.sampling_options
+            if so.temperature is not None:
+                temp[i] = so.temperature
+            top_k[i] = so.top_k or 0
+            if so.top_p is not None:
+                top_p[i] = so.top_p
+        kind = "step"
+        if mixed:
+            kind = "mixed"
+            self.decode_dispatches += 1
+            self.mixed_steps += 1
+        if pack:
+            # the program, not the plan: followers replay it by this name
+            kind = "packed"
+        elif ring:
+            kind = "ring"
+            self.ring_steps += 1
+            logger.info("ring prefill: %d prompt tokens in one step over "
+                        "sp=%d", plan.chunks[0].length, self._sp)
+        arrays = dict(toks=toks, pos=pos, table=table, total=total, new=new,
+                      temp=temp, top_k=top_k, top_p=top_p,
+                      **self._sampling_extras([c.seq for c in chunks], B))
+        return kind, chunks, arrays
 
     def _decode_arrays(self, seqs, chained: bool) -> dict:
         """Padded host arrays for one decode step.
@@ -1653,34 +1666,35 @@ class JaxEngine(ScheduledEngineBase):
         token from the previous packed output."""
         B = _bucket(len(seqs), self.cfg.min_decode_bucket,
                     self.cfg.max_num_seqs)
-        toks = np.zeros((B, 1), np.int32)
-        pos = np.zeros((B, 1), np.int32)
         # composition+version-cached padded table (also pre-warms the
         # device upload _step_table reuses for this dispatch)
         table, _ = self._table_arrays(seqs, B)
-        total = np.ones(B, np.int32)
-        new = np.zeros(B, np.int32)
-        temp = np.zeros(B, np.float32)
-        top_k = np.zeros(B, np.int32)
-        top_p = np.ones(B, np.float32)
-        for i, seq in enumerate(seqs):
-            if chained:
-                pos[i, 0] = len(seq)
-                total[i] = len(seq) + 1
-            else:
-                toks[i, 0] = seq.tokens.last_token()
-                pos[i, 0] = len(seq) - 1
-                total[i] = len(seq)
-            new[i] = 1
-            so = seq.request.sampling_options
-            if so.temperature is not None:
-                temp[i] = so.temperature
-            top_k[i] = so.top_k or 0
-            if so.top_p is not None:
-                top_p[i] = so.top_p
-        return dict(toks=toks, pos=pos, table=table, total=total, new=new,
-                    temp=temp, top_k=top_k, top_p=top_p,
-                    **self._sampling_extras(seqs, B))
+        with stage("assemble"):
+            toks = np.zeros((B, 1), np.int32)
+            pos = np.zeros((B, 1), np.int32)
+            total = np.ones(B, np.int32)
+            new = np.zeros(B, np.int32)
+            temp = np.zeros(B, np.float32)
+            top_k = np.zeros(B, np.int32)
+            top_p = np.ones(B, np.float32)
+            for i, seq in enumerate(seqs):
+                if chained:
+                    pos[i, 0] = len(seq)
+                    total[i] = len(seq) + 1
+                else:
+                    toks[i, 0] = seq.tokens.last_token()
+                    pos[i, 0] = len(seq) - 1
+                    total[i] = len(seq)
+                new[i] = 1
+                so = seq.request.sampling_options
+                if so.temperature is not None:
+                    temp[i] = so.temperature
+                top_k[i] = so.top_k or 0
+                if so.top_p is not None:
+                    top_p[i] = so.top_p
+            return dict(toks=toks, pos=pos, table=table, total=total, new=new,
+                        temp=temp, top_k=top_k, top_p=top_p,
+                        **self._sampling_extras(seqs, B))
 
     def _spec_arrays(self, seqs, drafts: np.ndarray) -> dict:
         """Padded host arrays for one speculative verify step [B, K+1].
@@ -1875,137 +1889,136 @@ class JaxEngine(ScheduledEngineBase):
         ride the block carry instead — fresh blocks preload them in
         ``dispatch_multistep``, chained blocks pass them straight
         through on device."""
-        with self._released_lock:
-            released = self._released
+        with stage("assemble"):
+            with self._released_lock:
+                released = self._released
+                if released:
+                    self._released = set()
             if released:
-                self._released = set()
-        if released:
-            # finished/cancelled rows: drop step-thread automata and any
-            # composition cache that still references them, so a dead
-            # guided/penalized row's table and window slots free up even
-            # if an identical-looking batch never re-forms
-            for rid in released:
-                self._guided_reqs.pop(rid, None)
+                # finished/cancelled rows: drop step-thread automata and any
+                # composition cache that still references them, so a dead
+                # guided/penalized row's table and window slots free up even
+                # if an identical-looking batch never re-forms
+                for rid in released:
+                    self._guided_reqs.pop(rid, None)
+                cached = self._samp_cache
+                if cached is not None and any(
+                        rid in released for rid, _s in cached[0][1]):
+                    self._samp_cache = None
+            key = (B, tuple((s.request.request_id, id(s)) for s in seqs))
             cached = self._samp_cache
-            if cached is not None and any(
-                    rid in released for rid, _s in cached[0][1]):
-                self._samp_cache = None
-        key = (B, tuple((s.request.request_id, id(s)) for s in seqs))
-        cached = self._samp_cache
-        if cached is not None and cached[0] == key:
-            return cached[1]
-        temp = np.zeros(B, np.float32)
-        top_k = np.zeros(B, np.int32)
-        top_p = np.ones(B, np.float32)
-        seeds = np.zeros(B, np.int32)
-        min_p = np.zeros(B, np.float32)
-        pen_active = False
-        stop_lists = []
-        W = self.cfg.penalty_window
-        pfp = np.zeros(B, np.float32)
-        ppp = np.zeros(B, np.float32)
-        prp = np.ones(B, np.float32)
-        pact = np.zeros(B, bool)
-        prompt_ids = np.zeros((B, 2 * max(W, 1)), np.int32)
-        prompt_valid = np.zeros((B, 2 * max(W, 1)), bool)
-        pw_active = False
-        guided_specs: dict = {}
-        for i, seq in enumerate(seqs):
-            so = seq.request.sampling_options
-            if so.temperature is not None:
-                temp[i] = so.temperature
-            top_k[i] = so.top_k or 0
-            if so.top_p is not None:
-                top_p[i] = so.top_p
-            if so.seed is not None:
-                # the _sampling_extras seed mapping: [1, 2^31-1], 0 = off
-                seeds[i] = (int(so.seed) % 0x7FFFFFFF) + 1
-                pen_active = True
-            if so.min_p:
-                min_p[i] = so.min_p
-                pen_active = True
-            f = so.frequency_penalty or 0.0
-            p = so.presence_penalty or 0.0
-            r = so.repetition_penalty
-            rep_on = r is not None and r > 0 and r != 1.0
-            if W > 0 and (f or p or rep_on or so.logit_bias):
-                pw_active = pen_active = True
-                pact[i] = True
-                pfp[i], ppp[i] = f, p
-                if rep_on:
-                    prp[i] = r
-                    row = self._penalty_row(seq, W)
-                    ps = row["prestatic"]
-                    prompt_ids[i, :len(ps)] = ps
-                    prompt_valid[i, :len(ps)] = True
-            spec = so.guided
-            if spec and self._guided_vocab is not None:
-                table = self._guided_table_for(spec)
-                gr = self._guided_req_for(seq, spec)
-                if table is not None and not gr.wedged:
-                    guided_specs[i] = (spec, table)
-            sc = seq.request.stop_conditions
-            ids = list(sc.stop_token_ids or [])
-            if not sc.ignore_eos:
-                ids += list(seq.request.eos_token_ids or [])
-            stop_lists.append(ids)
-        E = max([len(x) for x in stop_lists] + [1])
-        E = 1 << (E - 1).bit_length()   # pow2 pad: bounded trace count
-        stop_ids = np.full((B, E), -1, np.int32)
-        for i, ids in enumerate(stop_lists):
-            stop_ids[i, :len(ids)] = ids
-        pen = None
-        gt_host = None
-        if pen_active or guided_specs:
-            pen = {"seeds": jnp.asarray(seeds), "min_p": jnp.asarray(min_p)}
-            if pw_active:
-                pen["pw"] = {
-                    "fp": jnp.asarray(pfp), "pp": jnp.asarray(ppp),
-                    "rp": jnp.asarray(prp), "active": jnp.asarray(pact),
-                    "prompt_ids": jnp.asarray(prompt_ids),
-                    "prompt_valid": jnp.asarray(prompt_valid),
-                }
-            if guided_specs:
-                # batch the distinct tables behind sentinel state 0
-                # (all-ones mask, self-loop): unguided/wedged rows sit at
-                # state 0 and ride the same gather as guided ones
-                gv = self._guided_vocab
-                V = self.model_cfg.vocab_size
-                by_key: dict = {}
-                offsets: dict = {}
-                S = 1
-                for i, (spec, table) in guided_specs.items():
-                    import json as _json
-                    k = _json.dumps(spec, sort_keys=True)
-                    if k not in by_key:
-                        by_key[k] = table
-                        offsets[k] = S
-                        S += table.num_states
-                    offsets[i] = offsets[k]
-                S_pad = 1 << (S - 1).bit_length()
-                trans = np.zeros((S_pad, V), np.int32)
-                masks = np.full((S_pad, gv.words), 0xFFFFFFFF, np.uint32)
-                trans[0] = 0
-                for k, table in by_key.items():
-                    o = offsets[k]
-                    n = table.num_states
-                    trans[o:o + n] = table.trans + o
-                    masks[o:o + n] = table.masks
-                # pad states: unreachable; all-ones masks + self-loops so
-                # an off-by-one could never -inf a whole row
-                for s in range(S, S_pad):
-                    trans[s] = s
-                pen["gt"] = {"trans": jnp.asarray(trans),
-                             "masks": jnp.asarray(masks)}
-                gt_host = {"trans": trans,
-                           "offsets": {i: offsets[i] for i in guided_specs}}
-        out = {
-            "temp": jnp.asarray(temp), "top_k": jnp.asarray(top_k),
-            "top_p": jnp.asarray(top_p), "stop_ids": jnp.asarray(stop_ids),
-            "pen": pen,
-            "needs_pcarry": pw_active or bool(guided_specs),
-            "gt_host": gt_host,
-        }
+            if cached is not None and cached[0] == key:
+                return cached[1]
+            temp = np.zeros(B, np.float32)
+            top_k = np.zeros(B, np.int32)
+            top_p = np.ones(B, np.float32)
+            seeds = np.zeros(B, np.int32)
+            min_p = np.zeros(B, np.float32)
+            pen_active = False
+            stop_lists = []
+            W = self.cfg.penalty_window
+            pfp = np.zeros(B, np.float32)
+            ppp = np.zeros(B, np.float32)
+            prp = np.ones(B, np.float32)
+            pact = np.zeros(B, bool)
+            prompt_ids = np.zeros((B, 2 * max(W, 1)), np.int32)
+            prompt_valid = np.zeros((B, 2 * max(W, 1)), bool)
+            pw_active = False
+            guided_specs: dict = {}
+            for i, seq in enumerate(seqs):
+                so = seq.request.sampling_options
+                if so.temperature is not None:
+                    temp[i] = so.temperature
+                top_k[i] = so.top_k or 0
+                if so.top_p is not None:
+                    top_p[i] = so.top_p
+                if so.seed is not None:
+                    # the _sampling_extras seed mapping: [1, 2^31-1], 0 = off
+                    seeds[i] = (int(so.seed) % 0x7FFFFFFF) + 1
+                    pen_active = True
+                if so.min_p:
+                    min_p[i] = so.min_p
+                    pen_active = True
+                f = so.frequency_penalty or 0.0
+                p = so.presence_penalty or 0.0
+                r = so.repetition_penalty
+                rep_on = r is not None and r > 0 and r != 1.0
+                if W > 0 and (f or p or rep_on or so.logit_bias):
+                    pw_active = pen_active = True
+                    pact[i] = True
+                    pfp[i], ppp[i] = f, p
+                    if rep_on:
+                        prp[i] = r
+                        row = self._penalty_row(seq, W)
+                        ps = row["prestatic"]
+                        prompt_ids[i, :len(ps)] = ps
+                        prompt_valid[i, :len(ps)] = True
+                spec = so.guided
+                if spec and self._guided_vocab is not None:
+                    table = self._guided_table_for(spec)
+                    gr = self._guided_req_for(seq, spec)
+                    if table is not None and not gr.wedged:
+                        guided_specs[i] = (spec, table)
+                sc = seq.request.stop_conditions
+                ids = list(sc.stop_token_ids or [])
+                if not sc.ignore_eos:
+                    ids += list(seq.request.eos_token_ids or [])
+                stop_lists.append(ids)
+            E = max([len(x) for x in stop_lists] + [1])
+            E = 1 << (E - 1).bit_length()   # pow2 pad: bounded trace count
+            stop_ids = np.full((B, E), -1, np.int32)
+            for i, ids in enumerate(stop_lists):
+                stop_ids[i, :len(ids)] = ids
+            pen = None
+            gt_host = None
+            if pen_active or guided_specs:
+                pen = {"seeds": seeds, "min_p": min_p}
+                if pw_active:
+                    pen["pw"] = {
+                        "fp": pfp, "pp": ppp, "rp": prp, "active": pact,
+                        "prompt_ids": prompt_ids,
+                        "prompt_valid": prompt_valid,
+                    }
+                if guided_specs:
+                    # batch the distinct tables behind sentinel state 0
+                    # (all-ones mask, self-loop): unguided/wedged rows sit at
+                    # state 0 and ride the same gather as guided ones
+                    gv = self._guided_vocab
+                    V = self.model_cfg.vocab_size
+                    by_key: dict = {}
+                    offsets: dict = {}
+                    S = 1
+                    for i, (spec, table) in guided_specs.items():
+                        import json as _json
+                        k = _json.dumps(spec, sort_keys=True)
+                        if k not in by_key:
+                            by_key[k] = table
+                            offsets[k] = S
+                            S += table.num_states
+                        offsets[i] = offsets[k]
+                    S_pad = 1 << (S - 1).bit_length()
+                    trans = np.zeros((S_pad, V), np.int32)
+                    masks = np.full((S_pad, gv.words), 0xFFFFFFFF, np.uint32)
+                    trans[0] = 0
+                    for k, table in by_key.items():
+                        o = offsets[k]
+                        n = table.num_states
+                        trans[o:o + n] = table.trans + o
+                        masks[o:o + n] = table.masks
+                    # pad states: unreachable; all-ones masks + self-loops so
+                    # an off-by-one could never -inf a whole row
+                    for s in range(S, S_pad):
+                        trans[s] = s
+                    pen["gt"] = {"trans": trans, "masks": masks}
+                    gt_host = {"trans": trans, "offsets": {
+                        i: offsets[i] for i in guided_specs}}
+        with stage("upload"):
+            # (``pen`` and its ``pw`` / ``gt`` groups: numpy until here)
+            out = jax.tree_util.tree_map(jnp.asarray, {
+                "temp": temp, "top_k": top_k, "top_p": top_p,
+                "stop_ids": stop_ids, "pen": pen})
+        out["needs_pcarry"] = pw_active or bool(guided_specs)
+        out["gt_host"] = gt_host
         self._samp_cache = (key, out)
         return out
 
@@ -2018,34 +2031,36 @@ class JaxEngine(ScheduledEngineBase):
         transition table over the row's generated tokens from its
         grammar's offset; wedged rows were already dropped to sentinel
         state 0 at composition time)."""
-        W = self.cfg.penalty_window
-        pids = np.zeros((B, W), np.int32)
-        pcnt = np.zeros((B, W), np.float32)
-        pctx = np.zeros((B, W), np.float32)
-        pbias = np.zeros((B, W), np.float32)
-        pn = np.zeros(B, np.int32)
-        gstate = np.zeros(B, np.int32)
-        gt_host = samp.get("gt_host")
-        for i, seq in enumerate(seqs):
-            row = self._penalty_row(seq, W)
-            if row is not None:
-                lb = row["lb"]
-                entries = row["entries"][:W]
-                for j, (t, c, x) in enumerate(entries):
-                    pids[i, j] = t
-                    pcnt[i, j] = c
-                    pctx[i, j] = 1.0 if x else 0.0
-                    pbias[i, j] = lb.get(t, 0.0)
-                pn[i] = len(entries)
-            if gt_host is not None and i in gt_host["offsets"]:
-                s = gt_host["offsets"][i]
-                trans = gt_host["trans"]
-                for t in seq.generated:
-                    s = int(trans[s, int(t)])
-                gstate[i] = s
-        return {"pids": jnp.asarray(pids), "pcnt": jnp.asarray(pcnt),
-                "pctx": jnp.asarray(pctx), "pbias": jnp.asarray(pbias),
-                "pn": jnp.asarray(pn), "gstate": jnp.asarray(gstate)}
+        with stage("assemble"):
+            W = self.cfg.penalty_window
+            pids = np.zeros((B, W), np.int32)
+            pcnt = np.zeros((B, W), np.float32)
+            pctx = np.zeros((B, W), np.float32)
+            pbias = np.zeros((B, W), np.float32)
+            pn = np.zeros(B, np.int32)
+            gstate = np.zeros(B, np.int32)
+            gt_host = samp.get("gt_host")
+            for i, seq in enumerate(seqs):
+                row = self._penalty_row(seq, W)
+                if row is not None:
+                    lb = row["lb"]
+                    entries = row["entries"][:W]
+                    for j, (t, c, x) in enumerate(entries):
+                        pids[i, j] = t
+                        pcnt[i, j] = c
+                        pctx[i, j] = 1.0 if x else 0.0
+                        pbias[i, j] = lb.get(t, 0.0)
+                    pn[i] = len(entries)
+                if gt_host is not None and i in gt_host["offsets"]:
+                    s = gt_host["offsets"][i]
+                    trans = gt_host["trans"]
+                    for t in seq.generated:
+                        s = int(trans[s, int(t)])
+                    gstate[i] = s
+        with stage("upload"):
+            return {"pids": jnp.asarray(pids), "pcnt": jnp.asarray(pcnt),
+                    "pctx": jnp.asarray(pctx), "pbias": jnp.asarray(pbias),
+                    "pn": jnp.asarray(pn), "gstate": jnp.asarray(gstate)}
 
     def dispatch_multistep(self, plan, prev_handle=None):
         """Dispatch one fused block of ``plan.width`` decode steps;
@@ -2073,19 +2088,20 @@ class JaxEngine(ScheduledEngineBase):
                           "pctx": c["pctx"], "pbias": c["pbias"],
                           "pn": c["pn"], "gstate": c["gstate"]}
         else:
-            tok = np.zeros((B, 1), np.int32)
-            pos = np.zeros((B, 1), np.int32)
-            total = np.ones(B, np.int32)    # pad rows: 1 garbage-page token
-            alive = np.zeros(B, bool)       # pad rows: never write
-            budget = np.zeros(B, np.int32)
-            min_gate = np.zeros(B, np.int32)
-            for i, (seq, sl) in enumerate(zip(seqs, plan.start_lens)):
-                tok[i, 0] = seq.tokens.last_token()
-                pos[i, 0] = sl - 1
-                total[i] = sl
-                alive[i] = True
-                budget[i] = plan.budgets[i]
-                min_gate[i] = plan.min_gates[i]
+            with stage("assemble"):
+                tok = np.zeros((B, 1), np.int32)
+                pos = np.zeros((B, 1), np.int32)
+                total = np.ones(B, np.int32)  # pad rows: 1 garbage-page token
+                alive = np.zeros(B, bool)     # pad rows: never write
+                budget = np.zeros(B, np.int32)
+                min_gate = np.zeros(B, np.int32)
+                for i, (seq, sl) in enumerate(zip(seqs, plan.start_lens)):
+                    tok[i, 0] = seq.tokens.last_token()
+                    pos[i, 0] = sl - 1
+                    total[i] = sl
+                    alive[i] = True
+                    budget[i] = plan.budgets[i]
+                    min_gate[i] = plan.min_gates[i]
             if samp["needs_pcarry"]:
                 pcarry = self._fresh_pcarry(seqs, B, samp)
         plan._step_id = self._step_counter
@@ -2093,13 +2109,19 @@ class JaxEngine(ScheduledEngineBase):
         _ckey = (id(fn), B, w, pcarry is not None)
         _fresh = _ckey not in self._jit_seen
         _t0 = time.perf_counter() if _fresh else 0.0
-        self.pages, packed_block, carry, aux = fn(
-            self.params, self.pages, jnp.asarray(tok), jnp.asarray(pos),
-            jnp.asarray(table), jnp.asarray(total), jnp.asarray(alive),
-            jnp.asarray(budget), jnp.asarray(min_gate), self._rng,
-            np.int32(self._step_counter), samp["temp"], samp["top_k"],
-            samp["top_p"], samp["stop_ids"], samp["pen"], pcarry)
-        self._queue_moe_aux(aux, steps=w)
+        with stage("upload"):
+            # (a chained block's are the previous block's carry: on the
+            # device already)
+            tok, pos, total, alive, budget, min_gate = (
+                jnp.asarray(x)
+                for x in (tok, pos, total, alive, budget, min_gate))
+        with stage("enqueue"):
+            self.pages, packed_block, carry, aux = fn(
+                self.params, self.pages, tok, pos, table, total, alive,
+                budget, min_gate, self._rng,
+                np.int32(self._step_counter), samp["temp"], samp["top_k"],
+                samp["top_p"], samp["stop_ids"], samp["pen"], pcarry)
+            self._queue_moe_aux(aux, steps=w)
         # one rng-fold key per fused step: the counter advances by the
         # block width so fused and per-step runs consume the same keys
         self._step_counter += w
@@ -2226,31 +2248,35 @@ class JaxEngine(ScheduledEngineBase):
     def _gen_sampling(self, seqs, R: int) -> dict:
         """Device arrays of the rows' sampling and reveal parameters,
         rebuilt when the batch's composition changes."""
-        key = (R, tuple((s.request.request_id, id(s)) for s in seqs))
-        cached = self._gen_samp_cache
-        if cached is not None and cached[0] == key:
-            return cached[1]
-        a = {"temp": np.zeros(R, np.float32), "top_k": np.zeros(R, np.int32),
-             "top_p": np.ones(R, np.float32), "seeds": np.zeros(R, np.int32),
-             "min_p": np.zeros(R, np.float32),
-             "steps": np.full(R, self.gen_steps, np.int32),
-             "tau": np.full(R, self.gen_threshold, np.float32)}
-        for i, seq in enumerate(seqs):
-            so = seq.request.sampling_options
-            if so.temperature is not None:
-                a["temp"][i] = so.temperature
-            a["top_k"][i] = so.top_k or 0
-            if so.top_p is not None:
-                a["top_p"][i] = so.top_p
-            if so.seed is not None:
-                # the _sampling_extras seed mapping: [1, 2^31-1], 0 = off
-                a["seeds"][i] = (int(so.seed) % 0x7FFFFFFF) + 1
-            a["min_p"][i] = so.min_p or 0.0
-            if so.denoising_steps:
-                a["steps"][i] = so.denoising_steps
-            if so.confidence_threshold is not None:
-                a["tau"][i] = so.confidence_threshold
-        out = {k: jnp.asarray(v) for k, v in a.items()}
+        with stage("assemble"):
+            key = (R, tuple((s.request.request_id, id(s)) for s in seqs))
+            cached = self._gen_samp_cache
+            if cached is not None and cached[0] == key:
+                return cached[1]
+            a = {"temp": np.zeros(R, np.float32),
+                 "top_k": np.zeros(R, np.int32),
+                 "top_p": np.ones(R, np.float32),
+                 "seeds": np.zeros(R, np.int32),
+                 "min_p": np.zeros(R, np.float32),
+                 "steps": np.full(R, self.gen_steps, np.int32),
+                 "tau": np.full(R, self.gen_threshold, np.float32)}
+            for i, seq in enumerate(seqs):
+                so = seq.request.sampling_options
+                if so.temperature is not None:
+                    a["temp"][i] = so.temperature
+                a["top_k"][i] = so.top_k or 0
+                if so.top_p is not None:
+                    a["top_p"][i] = so.top_p
+                if so.seed is not None:
+                    # the _sampling_extras seed mapping: [1, 2^31-1], 0 = off
+                    a["seeds"][i] = (int(so.seed) % 0x7FFFFFFF) + 1
+                a["min_p"][i] = so.min_p or 0.0
+                if so.denoising_steps:
+                    a["steps"][i] = so.denoising_steps
+                if so.confidence_threshold is not None:
+                    a["tau"][i] = so.confidence_threshold
+        with stage("upload"):
+            out = {k: jnp.asarray(v) for k, v in a.items()}
         self._gen_samp_cache = (key, out)
         return out
 
@@ -2268,35 +2294,39 @@ class JaxEngine(ScheduledEngineBase):
         if prev_handle is not None:
             state = prev_handle[1]
         else:
-            st = {"tok": np.zeros((R, B), np.int32),
-                  "rev": np.zeros((R, B), bool),
-                  "pidx": np.zeros(R, np.int32),
-                  "start": np.zeros(R, np.int32),
-                  "tail": np.zeros(R, np.int32),
-                  "budget": np.zeros(R, np.int32),
-                  "alive": np.zeros(R, bool)}      # pad rows: never write
-            for i, seq in enumerate(seqs):
-                tail = plan.tails[i]
-                # a block in mid-denoising, or a fresh one: the prompt's
-                # tail, then masks
-                bs = seq.block_state or BlockState(
-                    B, seq.tokens.tokens()[len(seq) - tail:] if tail else ())
-                st["tok"][i], st["rev"][i] = bs.tok, bs.rev
-                st["pidx"][i] = bs.pidx
-                st["start"][i] = plan.start_lens[i]
-                st["tail"][i] = tail
-                st["budget"][i] = plan.budgets[i]
-                st["alive"][i] = plan.budgets[i] > 0
-            state = {k: jnp.asarray(v) for k, v in st.items()}
+            with stage("assemble"):
+                st = {"tok": np.zeros((R, B), np.int32),
+                      "rev": np.zeros((R, B), bool),
+                      "pidx": np.zeros(R, np.int32),
+                      "start": np.zeros(R, np.int32),
+                      "tail": np.zeros(R, np.int32),
+                      "budget": np.zeros(R, np.int32),
+                      "alive": np.zeros(R, bool)}      # pad rows: never write
+                for i, seq in enumerate(seqs):
+                    tail = plan.tails[i]
+                    # a block in mid-denoising, or a fresh one: the prompt's
+                    # tail, then masks
+                    bs = seq.block_state or BlockState(
+                        B, (seq.tokens.tokens()[len(seq) - tail:]
+                            if tail else ()))
+                    st["tok"][i], st["rev"][i] = bs.tok, bs.rev
+                    st["pidx"][i] = bs.pidx
+                    st["start"][i] = plan.start_lens[i]
+                    st["tail"][i] = tail
+                    st["budget"][i] = plan.budgets[i]
+                    st["alive"][i] = plan.budgets[i] > 0
+            with stage("upload"):
+                state = {k: jnp.asarray(v) for k, v in st.items()}
         plan._step_id = self._step_counter
         fn = self._get_jit_passes(w)
         _ckey = (id(fn), R, w)
         _fresh = _ckey not in self._jit_seen
         _t0 = time.perf_counter() if _fresh else 0.0
-        self.pages, packed, state, aux = fn(
-            self.params, self.pages, table, state, self._rng,
-            np.int32(self._step_counter), samp)
-        self._queue_moe_aux(aux, steps=w)
+        with stage("enqueue"):
+            self.pages, packed, state, aux = fn(
+                self.params, self.pages, table, state, self._rng,
+                np.int32(self._step_counter), samp)
+            self._queue_moe_aux(aux, steps=w)
         self._step_counter += w
         self.decode_dispatches += 1
         self.multistep_blocks += 1
@@ -2440,35 +2470,33 @@ class JaxEngine(ScheduledEngineBase):
         _ckey = (id(step_fn), _B, _S, a.get("mask_words") is not None)
         _fresh = _ckey not in self._jit_seen
         _t0 = time.perf_counter() if _fresh else 0.0
-        if kind == "spec":
-            # shares the post-step aux handling below: a MoE family's
-            # verify step reports dispatch drops like any other step
-            gm = a.get("gmask")
-            self.pages, packed, aux = self._jit_spec(
-                self.params, self.pages, jnp.asarray(a["toks"]),
-                jnp.asarray(a["pos"]), jnp.asarray(a["table"]),
-                jnp.asarray(a["total"]), jnp.asarray(a["new"]),
-                self._rng, np.int32(step), jnp.asarray(a["temp"]),
-                jnp.asarray(a["top_k"]), jnp.asarray(a["top_p"]),
-                jnp.asarray(gm) if gm is not None else None)
-        elif kind == "chained":
-            prev = prev_packed if prev_packed is not None else self._last_packed
-            pen = self._pen_arg(a, a["pos"].shape[0])
-            temp, top_k, top_p = self._step_sampling(a, kind, seqs)
-            self.pages, packed, aux = self._jit_chained(
-                self.params, self.pages, prev,
-                jnp.asarray(a["pos"]), self._step_table(a, kind, seqs),
-                jnp.asarray(a["total"]), jnp.asarray(a["new"]),
-                self._rng, np.int32(step), temp, top_k, top_p, pen)
-        else:
-            pen = self._pen_arg(a, _B)
-            temp, top_k, top_p = self._step_sampling(a, kind, seqs)
+        # the step's arguments go up first, so that the upload and the
+        # call each have their stage (``steptrace.stage``); the decode
+        # paths' cached sampling arrays and table mark their own
+        temp, top_k, top_p = self._step_sampling(a, kind, seqs)
+        table = self._step_table(a, kind, seqs)
+        with stage("upload"):
+            pos, total, new = (jnp.asarray(a[k])
+                               for k in ("pos", "total", "new"))
+            if kind == "spec":
+                gm = a.get("gmask")
+                extra = jnp.asarray(gm) if gm is not None else None
+            else:
+                extra = self._pen_arg(a, _B)
+            if kind == "chained":
+                feed = (prev_packed if prev_packed is not None
+                        else self._last_packed)
+            else:
+                feed = jnp.asarray(a["toks"])
+        with stage("enqueue"):
+            # one signature for every family: ``feed`` the tokens (a
+            # chained step: the previous step's packed output), ``extra``
+            # the ``pen`` pytree (a verify step: its guided masks). A MoE
+            # family's verify step reports dispatch drops like any other
             self.pages, packed, aux = step_fn(
-                self.params, self.pages, jnp.asarray(a["toks"]),
-                jnp.asarray(a["pos"]), self._step_table(a, kind, seqs),
-                jnp.asarray(a["total"]), jnp.asarray(a["new"]),
-                self._rng, np.int32(step), temp, top_k, top_p, pen)
-        self._queue_moe_aux(aux)
+                self.params, self.pages, feed, pos, table, total, new,
+                self._rng, np.int32(step), temp, top_k, top_p, extra)
+            self._queue_moe_aux(aux)
         # the slots the device computed and the step program with its
         # bucket, as the ring names them: a packed step pays for its T
         # slots whatever its rows
@@ -2489,8 +2517,9 @@ class JaxEngine(ScheduledEngineBase):
         if seqs is not None and kind in ("step", "chained"):
             samp = self._device_sampling(seqs, a["pos"].shape[0])
             return samp["temp"], samp["top_k"], samp["top_p"]
-        return (jnp.asarray(a["temp"]), jnp.asarray(a["top_k"]),
-                jnp.asarray(a["top_p"]))
+        with stage("upload"):
+            return (jnp.asarray(a["temp"]), jnp.asarray(a["top_k"]),
+                    jnp.asarray(a["top_p"]))
 
     def _table_arrays(self, seqs, B: int):
         """Padded page-table (host, device) pair for a decode-family
@@ -2500,27 +2529,29 @@ class JaxEngine(ScheduledEngineBase):
         ~B*P zero-fill + one upload every step. The host array is never
         mutated after upload (stale hits copy first), so a device array
         that zero-copied it stays valid."""
-        P = self.table_width
-        key = (B, tuple((s.request.request_id, id(s)) for s in seqs))
-        cached = self._table_cache
-        if cached is not None and cached[0] == key:
-            _k, versions, table, dev = cached
-            stale = [i for i, s in enumerate(seqs)
-                     if versions[i] != s.table_version]
-            if not stale:
-                return table, dev
-            table = table.copy()
-            for i in stale:
-                s = seqs[i]
-                table[i, :] = 0
-                table[i, :len(s.page_ids)] = s.page_ids
-                versions[i] = s.table_version
-        else:
-            table = np.zeros((B, P), np.int32)
-            versions = [s.table_version for s in seqs]
-            for i, s in enumerate(seqs):
-                table[i, :len(s.page_ids)] = s.page_ids
-        dev = jnp.asarray(table)
+        with stage("assemble"):
+            P = self.table_width
+            key = (B, tuple((s.request.request_id, id(s)) for s in seqs))
+            cached = self._table_cache
+            if cached is not None and cached[0] == key:
+                _k, versions, table, dev = cached
+                stale = [i for i, s in enumerate(seqs)
+                         if versions[i] != s.table_version]
+                if not stale:
+                    return table, dev
+                table = table.copy()
+                for i in stale:
+                    s = seqs[i]
+                    table[i, :] = 0
+                    table[i, :len(s.page_ids)] = s.page_ids
+                    versions[i] = s.table_version
+            else:
+                table = np.zeros((B, P), np.int32)
+                versions = [s.table_version for s in seqs]
+                for i, s in enumerate(seqs):
+                    table[i, :len(s.page_ids)] = s.page_ids
+        with stage("upload"):
+            dev = jnp.asarray(table)
         self._table_cache = (key, versions, table, dev)
         return table, dev
 
@@ -2531,7 +2562,8 @@ class JaxEngine(ScheduledEngineBase):
         replay raw arrays)."""
         if seqs is not None and kind in ("step", "chained"):
             return self._table_arrays(seqs, a["pos"].shape[0])[1]
-        return jnp.asarray(a["table"])
+        with stage("upload"):
+            return jnp.asarray(a["table"])
 
     def _queue_moe_aux(self, aux: dict, steps: int = 1) -> None:
         """One dispatch's expert-layer counts (device scalars; nothing

@@ -255,8 +255,7 @@ class ScheduledEngineBase(EngineBase):
         compile events the engine buffered during this dispatch — those
         also land on every live request the step served
         (``Sequence.compile_ms``), so a mid-run compile shows up in the
-        request's own trace. Returns the live ring record (or None when
-        disabled)."""
+        request's own trace. Returns the live ring record."""
         st = self.steptrace
         gap_ms = 0.0
         if self._last_dispatch_end is not None:
@@ -264,45 +263,44 @@ class ScheduledEngineBase(EngineBase):
                          (dispatch.t0 - self._last_dispatch_end) * 1000.0)
         self._last_dispatch_end = dispatch.t1
         seqs = getattr(plan, "seqs", ()) if plan is not None else ()
-        rec = None
-        if st.enabled:
-            rows = len(seqs)
-            width = getattr(plan, "width", 0) or 0
-            if kind == "multistep":
-                # (a pass dispatch: times the block, until its result
-                # says which rows lived, ``_process_passes``)
-                tokens_real = rows * width * max(
-                    1, self.scheduler.cfg.gen_block)
-            elif kind in ("prefill", "mixed"):
-                chunks = getattr(plan, "chunks", ()) or ()
-                dec = getattr(plan, "decode_seqs", ()) or ()
-                rows = len(chunks) + len(dec)
-                tokens_real = sum(c.length for c in chunks) + len(dec)
-            elif kind == "spec":
-                drafts = getattr(plan, "drafts", None)
-                k = drafts.shape[1] if drafts is not None else 0
-                tokens_real = rows * (k + 1)
-            else:
-                tokens_real = rows
-            padded = self.last_padded
-            if padded is not None:
-                batch = padded[0]
-                tokens_padded = padded[0] * padded[1]
-            else:
-                batch = rows
-                tokens_padded = tokens_real
-            mgr = getattr(self, "_export_leases", None)
-            rec = st.record(
-                kind, program=self.last_program, width=width, rows=rows,
-                batch=batch, tokens_real=tokens_real,
-                tokens_padded=tokens_padded,
-                queue_depth=len(self.scheduler.waiting),
-                running=len(self.scheduler.active),
-                pool_free=self.allocator.num_free,
-                pool_pinned=mgr.pinned_pages if mgr is not None else 0,
-                plan_ms=plan_ms, dispatch_ms=dispatch.ms,
-                gap_ms=gap_ms, fallback=fallback, chained=chained,
-                enqueue=dispatch.t0, experts=self.last_experts_touched)
+        rows = len(seqs)
+        width = getattr(plan, "width", 0) or 0
+        if kind == "multistep":
+            # (a pass dispatch: times the block, until its result
+            # says which rows lived, ``_process_passes``)
+            tokens_real = rows * width * max(
+                1, self.scheduler.cfg.gen_block)
+        elif kind in ("prefill", "mixed"):
+            chunks = getattr(plan, "chunks", ()) or ()
+            dec = getattr(plan, "decode_seqs", ()) or ()
+            rows = len(chunks) + len(dec)
+            tokens_real = sum(c.length for c in chunks) + len(dec)
+        elif kind == "spec":
+            drafts = getattr(plan, "drafts", None)
+            k = drafts.shape[1] if drafts is not None else 0
+            tokens_real = rows * (k + 1)
+        else:
+            tokens_real = rows
+        padded = self.last_padded
+        if padded is not None:
+            batch = padded[0]
+            tokens_padded = padded[0] * padded[1]
+        else:
+            batch = rows
+            tokens_padded = tokens_real
+        mgr = getattr(self, "_export_leases", None)
+        rec = st.record(
+            kind, program=self.last_program, width=width, rows=rows,
+            batch=batch, tokens_real=tokens_real,
+            tokens_padded=tokens_padded,
+            queue_depth=len(self.scheduler.waiting),
+            running=len(self.scheduler.active),
+            pool_free=self.allocator.num_free,
+            pool_pinned=mgr.pinned_pages if mgr is not None else 0,
+            plan_ms=plan_ms, dispatch_ms=dispatch.ms,
+            gap_ms=gap_ms, fallback=fallback, chained=chained,
+            enqueue=dispatch.t0, experts=self.last_experts_touched,
+            phase=dispatch)
         self.last_padded = None
         self.last_program = ""
         self.last_experts_touched = None
@@ -314,7 +312,7 @@ class ScheduledEngineBase(EngineBase):
         if plan is not None:
             plan._steprec = rec
             # what the later phases of this dispatch (fetch, process) are
-            # annotated with, whether or not the ring is on
+            # annotated with
             plan._stepid = (dispatch.seq, kind)
         return rec
 
@@ -899,7 +897,7 @@ class ScheduledEngineBase(EngineBase):
             with st.phase("process", *plan._stepid) as process:
                 (self._process_multistep if multi
                  else self._process)(plan, *result)
-            st.note_unpack(rec, fetch.ms, process.ms)
+            st.note_unpack(rec, fetch.ms, process.ms, fetch.resume_ms)
 
         async def flush() -> None:
             nonlocal pending

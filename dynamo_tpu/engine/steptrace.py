@@ -22,6 +22,17 @@ dispatch's ``seq``, so any profile of the process carries the same
 phases on the device trace's clock (a TraceMe that checks one flag when
 no profile runs).
 
+A ``dispatch`` phase is taken apart into six stages the same way, on both
+clocks: ``handover`` (the loop's thread -> the first instruction on the
+worker thread) and ``resume`` (the call's return -> the loop's thread
+back in the coroutine) from the stamps ``Phase.in_thread`` takes anyway;
+``assemble`` (plan -> host arrays), ``upload`` (host arrays -> device
+arrays), ``enqueue`` (the jitted call until it returns its futures) and
+``wait`` (synchronous kinds: the call's return -> the result on the
+host) marked by the engine, inside the call, with ``stage(name)``: a
+``perf_counter`` pair summed per stage and a ``dispatch.<stage>``
+annotation with the dispatch's ``seq`` and ``kind``.
+
 Design constraints, in order:
 
 * The hot path must cost <2% tok/s on fused decode (bench-proven).
@@ -33,16 +44,14 @@ Design constraints, in order:
   collector renders them at scrape time.
 * Bounded memory: the ring holds ``DYN_STEPTRACE_RING`` records
   (default 2048) and overwrites oldest-first. ``snapshot()`` paginates
-  newest-first for ``GET /v1/steptrace``.
-* ``DYN_STEPTRACE_DISABLE=1`` turns the whole thing into a no-op
-  (``record()`` returns None before taking the lock).
-"""
+  newest-first for ``GET /v1/steptrace``."""
 
 from __future__ import annotations
 
 import asyncio
 import contextlib
 import contextvars
+import logging
 import os
 import sys
 import threading
@@ -50,10 +59,14 @@ import time
 from bisect import bisect_left
 from typing import Any, Dict, List, Optional
 
+from dynamo_tpu.utils import aio
+
 __all__ = [
-    "StepRecord", "StepRecorder", "Phase", "get_step_recorder",
-    "set_step_recorder",
+    "StepRecord", "StepRecorder", "Phase", "STAGES", "stage",
+    "get_step_recorder", "set_step_recorder",
 ]
+
+logger = logging.getLogger(__name__)
 
 
 def _env_int(name: str, default: int) -> int:
@@ -70,6 +83,12 @@ _DUR_BOUNDS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
 _GAP_BOUNDS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
                0.025, 0.05, 0.1, 0.25, 1.0)
 _OCC_BOUNDS = (0.1, 0.25, 0.5, 0.625, 0.75, 0.875, 0.95, 1.0)
+_STAGE_BOUNDS = (0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005,
+                 0.01, 0.025, 0.05, 0.1, 0.25, 1.0, 2.5)
+
+# the host's side of one dispatch, in the order it passes through them
+STAGES = ("handover", "assemble", "upload", "enqueue", "wait", "resume")
+_NO_STAGES = (0.0,) * len(STAGES)
 
 
 class _Hist:
@@ -109,7 +128,8 @@ class StepRecord:
               "fetch_ms", "process_ms", "unpack_ms", "device_ms",
               "ready_unix", "gap_ms", "compile_ms", "fallback", "chained",
               "experts_touched", "passes", "row_passes", "revealed",
-              "commits")
+              "commits", "handover_ms", "assemble_ms", "upload_ms",
+              "enqueue_ms", "resume_ms", "fetch_resume_ms")
     # _enqueue: perf_counter at the start of the enqueue, kept until the
     # result arrives and device_ms can be taken; _experts: the dispatch's
     # count of experts touched while it is still a device scalar; neither
@@ -152,6 +172,15 @@ class StepRecord:
         self.row_passes = 0
         self.revealed = 0
         self.commits = 0
+        # the dispatch phase by stage (``dispatch_ms`` less these five is
+        # the ``wait`` for the result, of a synchronous kind), and how
+        # long the fetched result waited for the loop's thread
+        self.handover_ms = 0.0
+        self.assemble_ms = 0.0
+        self.upload_ms = 0.0
+        self.enqueue_ms = 0.0
+        self.resume_ms = 0.0
+        self.fetch_resume_ms = 0.0
         self._enqueue = 0.0
         self._experts = None
 
@@ -183,12 +212,15 @@ class Phase:
     it hands to a worker thread. There the loop's side (the hand-over to
     the thread and back included: what ``ms`` measures) is annotated on
     the loop's thread, and the call itself once more, under the same name
-    and ``seq``, on the thread that does the work; ``ready``/
-    ``ready_unix`` are taken the moment the call returns, before the
-    event loop gets round to resuming."""
+    and ``seq``, on the thread that does the work: ``called`` is its
+    first instruction there, ``ready``/``ready_unix`` are taken the
+    moment the call returns, before the event loop gets round to
+    resuming. While the call runs the phase is the thread's current one,
+    which is where ``stage`` adds up what the engine marks inside it."""
 
-    __slots__ = ("_recorder", "name", "seq", "kind", "t0", "t1", "ready",
-                 "ready_unix", "_ann")
+    __slots__ = ("_recorder", "name", "seq", "kind", "t0", "t1", "called",
+                 "ready", "ready_unix", "assemble_s", "upload_s",
+                 "enqueue_s", "wait_s", "_open", "_ann")
 
     def __init__(self, recorder: "StepRecorder", name: str, seq: int,
                  kind: str) -> None:
@@ -196,11 +228,31 @@ class Phase:
         self.name = name
         self.seq = seq
         self.kind = kind
-        self.t0 = self.t1 = self.ready = self.ready_unix = 0.0
+        self.t0 = self.t1 = self.called = self.ready = 0.0
+        self.ready_unix = 0.0
+        self.assemble_s = self.upload_s = self.enqueue_s = 0.0
+        self.wait_s = 0.0
+        self._open = False
 
     @property
     def ms(self) -> float:
         return (self.t1 - self.t0) * 1000.0
+
+    @property
+    def handover_ms(self) -> float:
+        """The loop's thread -> the call's first instruction."""
+        return max(0.0, self.called - self.t0) * 1000.0
+
+    @property
+    def resume_ms(self) -> float:
+        """The call's return -> the loop's thread back in the coroutine."""
+        return max(0.0, self.t1 - self.ready) * 1000.0
+
+    def stage_seconds(self) -> tuple:
+        """A finished threaded phase in seconds, in ``STAGES``' order."""
+        return (max(0.0, self.called - self.t0), self.assemble_s,
+                self.upload_s, self.enqueue_s, self.wait_s,
+                max(0.0, self.t1 - self.ready))
 
     def _annotation(self):
         return _trace_annotation("loop." + self.name, self.seq, self.kind)
@@ -217,12 +269,19 @@ class Phase:
         waits = self._recorder.loop_wait_s
         if self.name in waits:
             waits[self.name] += self.t1 - self.t0
+        if self.ready:
+            self._say_if_late()
 
     def _call(self, fn, args):
-        with self._annotation():
-            out = fn(*args)
-            self.ready = time.perf_counter()
-            self.ready_unix = time.time()
+        self.called = time.perf_counter()
+        _current.phase = self
+        try:
+            with self._annotation():
+                out = fn(*args)
+                self.ready = time.perf_counter()
+                self.ready_unix = time.time()
+        finally:
+            _current.phase = None
         return out
 
     async def in_thread(self, fn, *args, head_start: float = 0.0):
@@ -251,6 +310,63 @@ class Phase:
             returned.wait(head_start)
             return await pending
 
+    def _say_if_late(self) -> None:
+        """One line in the process's log where ready work waited as long
+        for a thread (``handover``) or for the event loop (``resume``) as
+        the loop's own heartbeat finds worth one (``aio.LAG_WARN_S``): an
+        untraced run keeps its logs, so a silence in the token stream can
+        be laid beside them."""
+        for what, ms in (("handover", self.handover_ms),
+                         ("resume", self.resume_ms)):
+            if ms > aio.LAG_WARN_S * 1000.0:
+                logger.warning(
+                    "step loop late: %s of seq %d (loop.%s, kind %s) took "
+                    "%.1f ms", what, self.seq, self.name, self.kind or "-",
+                    ms)
+
+
+# the threaded phase whose call runs on this thread, if any
+_current = threading.local()
+_NO_STAGE = contextlib.nullcontext()
+
+
+class _Stage:
+    """One opening of a stage inside a dispatch call."""
+
+    __slots__ = ("_phase", "_name", "_ann", "_t0")
+
+    def __init__(self, phase: Phase, name: str) -> None:
+        self._phase = phase
+        self._name = name
+
+    def __enter__(self) -> None:
+        ph = self._phase
+        ph._open = True
+        self._ann = _trace_annotation("dispatch." + self._name, ph.seq,
+                                      ph.kind)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        ph, attr = self._phase, self._name + "_s"
+        setattr(ph, attr, getattr(ph, attr) + dt)
+        ph._open = False
+
+
+def stage(name: str):
+    """Mark one stage (``assemble``, ``upload``, ``enqueue`` or ``wait``)
+    of the dispatch whose call runs on this thread: ``with
+    stage("upload"): ...``. It may open any number of times in one
+    dispatch and the phase keeps the sum. A null context where no phase is
+    current (priming, tests, ``bench.py``) and inside a stage that is
+    already open, whose time it stays."""
+    ph = getattr(_current, "phase", None)
+    if ph is None or ph._open:
+        return _NO_STAGE
+    return _Stage(ph, name)
+
 
 class StepRecorder:
     """Process-wide step ring + inline fleet aggregates.
@@ -264,15 +380,10 @@ class StepRecorder:
     same lock — scrape-time only, never on the hot path.
     """
 
-    def __init__(self, capacity: Optional[int] = None,
-                 enabled: Optional[bool] = None) -> None:
+    def __init__(self, capacity: Optional[int] = None) -> None:
         if capacity is None:
             capacity = _env_int("DYN_STEPTRACE_RING", 2048)
         self.capacity = max(1, capacity)
-        if enabled is None:
-            enabled = os.environ.get(
-                "DYN_STEPTRACE_DISABLE", "") not in ("1", "true", "yes")
-        self.enabled = enabled
         self._ring = [StepRecord() for _ in range(self.capacity)]
         self._n = 0                      # dispatches ever recorded
         self._lock = threading.Lock()
@@ -280,6 +391,8 @@ class StepRecorder:
         self._dur: Dict[str, _Hist] = {}
         self._occ: Dict[str, _Hist] = {}
         self._gap = _Hist(_GAP_BOUNDS)
+        # a dispatch phase's stages, each over the dispatches it opened in
+        self._stage = {name: _Hist(_STAGE_BOUNDS) for name in STAGES}
         self.compile_events: Dict[str, int] = {}
         self.compile_seconds: Dict[str, float] = {}
         self.pool_free = 0
@@ -305,15 +418,14 @@ class StepRecorder:
                pool_pinned: int = 0, plan_ms: float = 0.0,
                dispatch_ms: float = 0.0, gap_ms: float = 0.0,
                fallback: str = "", chained: bool = False,
-               enqueue: float = 0.0, experts: Any = None
-               ) -> Optional[StepRecord]:
+               enqueue: float = 0.0, experts: Any = None,
+               phase: Optional[Phase] = None) -> StepRecord:
         """Stamp one dispatch; returns the live ring slot (later patched
-        by note_ready/note_unpack/note_compile) or None when disabled.
+        by note_ready/note_unpack/note_compile).
         ``enqueue`` is the perf_counter at the start of the enqueue;
         ``experts`` the experts its expert layers touched, a device scalar
-        that ``note_ready`` reads once the result is on the host."""
-        if not self.enabled:
-            return None
+        that ``note_ready`` reads once the result is on the host;
+        ``phase`` the finished dispatch phase, for its stages."""
         now = time.time()
         with self._lock:
             rec = self._ring[self._n % self.capacity]
@@ -346,6 +458,18 @@ class StepRecorder:
             rec.passes = rec.row_passes = rec.revealed = rec.commits = 0
             rec._enqueue = enqueue
             rec._experts = experts
+            rec.fetch_resume_ms = 0.0
+            stages = _NO_STAGES if phase is None else phase.stage_seconds()
+            # (spelled out: a generator here costs the hot path 5 us)
+            rec.handover_ms = stages[0] * 1000.0
+            rec.assemble_ms = stages[1] * 1000.0
+            rec.upload_ms = stages[2] * 1000.0
+            rec.enqueue_ms = stages[3] * 1000.0
+            rec.resume_ms = stages[5] * 1000.0
+            if phase is not None:
+                for name, seconds in zip(STAGES, stages):
+                    if seconds > 0.0:
+                        self._stage[name].observe(seconds)
             if tokens_padded > 0:
                 o = self._occ.get(kind)
                 if o is None:
@@ -368,7 +492,7 @@ class StepRecorder:
         N is fetched) two programs never count the same time. It is the
         host's estimate: the enqueue's own host work and the copy back
         are inside it. The per-kind duration histogram observes it."""
-        if rec is None or not self.enabled:
+        if rec is None:
             return
         with self._lock:
             device_s = max(0.0, ready - max(rec._enqueue, self._last_ready))
@@ -384,18 +508,20 @@ class StepRecorder:
             rec.experts_touched, rec._experts = int(rec._experts), None
 
     def note_unpack(self, rec: Optional[StepRecord], fetch_ms: float,
-                    process_ms: float) -> None:
+                    process_ms: float, fetch_resume_ms: float = 0.0) -> None:
         """Patch the host's time after the dispatch into its record:
-        blocked in the fetch (waiting for the device and the copy back),
-        and in its own unpack-and-emit work; ``unpack_ms`` is their sum
-        (known only when the overlapped fetch completes, often after
-        the NEXT dispatch has been stamped)."""
-        if rec is None or not self.enabled:
+        blocked in the fetch (waiting for the device and the copy back;
+        ``fetch_resume_ms`` of it the result lay on the host until the
+        loop's thread came round), and in its own unpack-and-emit work;
+        ``unpack_ms`` is their sum (known only when the overlapped fetch
+        completes, often after the NEXT dispatch has been stamped)."""
+        if rec is None:
             return
         with self._lock:
             rec.fetch_ms = fetch_ms
             rec.process_ms = process_ms
             rec.unpack_ms = fetch_ms + process_ms
+            rec.fetch_resume_ms = fetch_resume_ms
 
     def note_passes(self, rec: Optional[StepRecord], passes: int,
                     row_passes: int, revealed: int, commits: int,
@@ -404,7 +530,7 @@ class StepRecorder:
         rows die inside a dispatch, so ``tokens_real`` - the positions
         it computed, ``row_passes`` times the block length - is known
         only now too."""
-        if rec is None or not self.enabled:
+        if rec is None:
             return
         with self._lock:
             rec.passes, rec.row_passes = passes, row_passes
@@ -415,8 +541,6 @@ class StepRecorder:
                      rec: Optional[StepRecord] = None) -> None:
         """Count a first-call compile on a fresh (kind, shape) jit
         bucket; attributes it to ``rec`` when the dispatch is known."""
-        if not self.enabled:
-            return
         with self._lock:
             self.compile_events[kind] = self.compile_events.get(kind, 0) + 1
             self.compile_seconds[kind] = (
@@ -442,8 +566,7 @@ class StepRecorder:
                 rec = self._ring[(self._n - 1 - i) % self.capacity]
                 recs.append(rec.to_dict())
             return {"total": self._n, "capacity": self.capacity,
-                    "enabled": self.enabled, "count": len(recs),
-                    "offset": offset, "records": recs}
+                    "count": len(recs), "offset": offset, "records": recs}
 
     def aggregates(self) -> Dict[str, Any]:
         """Plain-data aggregate snapshot for the metrics collector."""
@@ -455,6 +578,8 @@ class StepRecorder:
                               for k, h in self._occ.items()},
                 "gap": (self._gap.cumulative(), self._gap.sum,
                         self._gap.count),
+                "stage": {k: (h.cumulative(), h.sum, h.count)
+                          for k, h in self._stage.items()},
                 "compile_events": dict(self.compile_events),
                 "compile_seconds": dict(self.compile_seconds),
                 "pool_free": self.pool_free,

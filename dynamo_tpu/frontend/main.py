@@ -17,6 +17,7 @@ from dynamo_tpu.llm.model_manager import ModelManager, ModelWatcher
 from dynamo_tpu.runtime.push_router import RouterMode
 from dynamo_tpu.runtime.resilience import RouterPolicyConfig
 from dynamo_tpu.runtime.runtime import DEFAULT_COORDINATOR, DistributedRuntime
+from dynamo_tpu.utils.aio import reap_task, watch_loop_lag
 from dynamo_tpu.utils.config import RuntimeConfig
 from dynamo_tpu.utils.logging import configure_logging
 
@@ -164,11 +165,16 @@ async def amain(args: argparse.Namespace) -> None:
     if args.standalone:
         print(f"coordinator listening on {drt._embedded.address}", flush=True)
     print(f"frontend listening on {service.host}:{service.port}", flush=True)
+    # dynamo_event_loop_lag_seconds, and a line in the log when this loop
+    # (every stream's frames pass through it) was away
+    lag_watch = asyncio.ensure_future(
+        watch_loop_lag(service.metrics.loop_lag.observe, "frontend"))
     try:
         await drt.runtime.wait_shutdown()
     except asyncio.CancelledError:
         pass
     finally:
+        await reap_task(lag_watch)
         await service.stop()
         await watcher.stop()
         await drt.close()

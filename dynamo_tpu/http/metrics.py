@@ -29,6 +29,24 @@ _STAGE_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
                   0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0)
 
 
+_LAG_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
+                1.0, 2.5, 5.0)
+
+
+def loop_lag_histogram(registry: Optional[CollectorRegistry]) -> Histogram:
+    """``dynamo_event_loop_lag_seconds``: how much later than asked the
+    process's event loop woke its heartbeat (``utils/aio.watch_loop_lag``,
+    every 100 ms) — the wait of every ready callback at that moment.
+    Registered on the frontend's registry and on the worker's under one
+    name, like the stage histogram."""
+    return Histogram(
+        "dynamo_event_loop_lag_seconds",
+        "Overshoot of the event loop's 100 ms heartbeat: how long ready "
+        "callbacks (stream frames, lease keepalives, the step loop's "
+        "resume) waited for the loop's thread",
+        buckets=_LAG_BUCKETS, registry=registry)
+
+
 class StageMetrics:
     """``dynamo_tpu_stage_duration_seconds{stage}`` — per-stage request
     latency breakdown (queue|prefill|kv_transfer|decode|tokenize|detokenize),
@@ -118,6 +136,7 @@ class FrontendMetrics:
         # per-stage latency breakdown from trace spans; HttpService attaches
         # the process tracer at start and detaches at stop
         self.stage = StageMetrics(self.registry)
+        self.loop_lag = loop_lag_histogram(self.registry)
         # failure-aware routing counters/gauges, sampled from the process-
         # wide RouterStats book at scrape time (routers live in ModelWatcher,
         # outside this registry's reach)

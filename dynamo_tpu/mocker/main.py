@@ -15,6 +15,7 @@ from dynamo_tpu.llm.register import register_llm, serve_engine
 from dynamo_tpu.mocker.engine import MockEngineArgs, MockerEngine
 from dynamo_tpu.model_card import ModelDeploymentCard
 from dynamo_tpu.runtime.runtime import DEFAULT_COORDINATOR, DistributedRuntime
+from dynamo_tpu.utils.aio import reap_task, watch_loop_lag
 from dynamo_tpu.utils.logging import configure_logging
 from dynamo_tpu.worker.events import kv_events_subject, ordered_kv_publisher
 
@@ -102,9 +103,12 @@ async def amain(args: argparse.Namespace) -> None:
     if system is not None:
         system.register_drain(drain)
     print(f"mocker worker serving model {card.name}", flush=True)
+    lag_watch = asyncio.ensure_future(
+        watch_loop_lag(wm.loop_lag.observe, "worker"))
     try:
         await drt.runtime.wait_shutdown()
     finally:
+        await reap_task(lag_watch)
         if system is not None:
             await system.stop()
         if event_pump is not None:

@@ -3,8 +3,18 @@
 from __future__ import annotations
 
 import asyncio
+import logging
 import random
-from typing import Optional
+import time
+from typing import Callable, Optional
+
+logger = logging.getLogger(__name__)
+
+# the event loop's heartbeat: how often it asks itself the time, and how
+# late an answer is worth a line in the log (the step loop holds its
+# hand-overs and resumes to the same limit, ``engine/steptrace.py``)
+LAG_PERIOD_S = 0.1
+LAG_WARN_S = 0.25
 
 
 def decorrelated_jitter(prev_s: float, base_s: float, cap_s: float) -> float:
@@ -37,4 +47,20 @@ async def reap_task(task: Optional[asyncio.Task]) -> None:
             raise exc
 
 
-__all__ = ["reap_task"]
+async def watch_loop_lag(observe: Callable[[float], None],
+                         who: str) -> None:
+    """Heartbeat of the running event loop: sleep ``LAG_PERIOD_S``, hand
+    ``observe`` the seconds it woke later than that (what every ready
+    callback of this process had to wait at that moment), and say so once
+    in the log past ``LAG_WARN_S``. Runs until cancelled."""
+    while True:
+        t0 = time.perf_counter()
+        await asyncio.sleep(LAG_PERIOD_S)
+        lag = max(0.0, time.perf_counter() - t0 - LAG_PERIOD_S)
+        observe(lag)
+        if lag > LAG_WARN_S:
+            logger.warning("event loop late: the %s's loop was away "
+                           "%.1f ms", who, lag * 1000.0)
+
+
+__all__ = ["decorrelated_jitter", "reap_task", "watch_loop_lag"]

@@ -33,6 +33,7 @@ from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.hf_loader import load_hf_params
 from dynamo_tpu.runtime.runtime import DEFAULT_COORDINATOR, DistributedRuntime
+from dynamo_tpu.utils.aio import reap_task, watch_loop_lag
 from dynamo_tpu.utils.logging import configure_logging
 from dynamo_tpu.worker.events import kv_events_subject, ordered_kv_publisher
 
@@ -683,9 +684,14 @@ async def amain(args: argparse.Namespace) -> None:
           f"visible_chips={placement['visible_chips']} "
           f"attn_impl={placement['attn_impl']} (disagg={args.disagg})",
           flush=True)
+    # dynamo_event_loop_lag_seconds, and a line in the log when this loop
+    # (the step loop, the streams' frames and the lease share it) was away
+    lag_watch = asyncio.ensure_future(
+        watch_loop_lag(wm.loop_lag.observe, "worker"))
     try:
         await drt.runtime.wait_shutdown()
     finally:
+        await reap_task(lag_watch)
         if queue_worker is not None:
             await queue_worker.stop()
         if bulk_server is not None:

@@ -38,7 +38,7 @@ from prometheus_client import CollectorRegistry, Counter, Gauge
 from prometheus_client.core import (CounterMetricFamily, GaugeMetricFamily,
                                     HistogramMetricFamily)
 
-from dynamo_tpu.http.metrics import StageMetrics
+from dynamo_tpu.http.metrics import StageMetrics, loop_lag_histogram
 
 
 class KvbmStatsCollector:
@@ -343,7 +343,8 @@ class StepTraceCollector:
                 logging.getLogger(__name__).debug(
                     "steptrace aggregate sample failed", exc_info=True)
         from dynamo_tpu.engine.steptrace import (_DUR_BOUNDS, _GAP_BOUNDS,
-                                                 _OCC_BOUNDS)
+                                                 _OCC_BOUNDS, _STAGE_BOUNDS,
+                                                 STAGES)
         dur = HistogramMetricFamily(
             "dynamo_worker_step_duration_seconds",
             "Device time of one dispatch by kind (prefill/decode/chained/"
@@ -377,6 +378,22 @@ class StepTraceCollector:
                        or (self._zero_hist(_GAP_BOUNDS), 0.0, 0))
         gap.add_metric([], buckets=gb, sum_value=gs)
         yield gap
+        stage = HistogramMetricFamily(
+            "dynamo_worker_dispatch_stage_seconds",
+            "The host's side of one dispatch by stage: handover (the "
+            "loop's thread to the worker thread), assemble (plan to host "
+            "arrays), upload (host arrays to device arrays), enqueue (the "
+            "jitted call until it returns), wait (a synchronous kind: the "
+            "call's return to the result on the host) and resume (the "
+            "call's return to the event loop's coroutine); each over the "
+            "dispatches in which it opened",
+            labels=["stage"])
+        stages = dict(agg.get("stage") or {})
+        for name in STAGES:
+            b, s, _n = stages.get(name) or (self._zero_hist(_STAGE_BOUNDS),
+                                            0.0, 0)
+            stage.add_metric([name], buckets=b, sum_value=s)
+        yield stage
         yield GaugeMetricFamily(
             "dynamo_worker_page_pool_free_pages",
             "Free KV pages at the most recent dispatch's plan time",
@@ -546,6 +563,7 @@ class WorkerMetrics:
             "after the first one failed, by outcome (ok, failed)",
             ["outcome"], registry=self.registry)
         self.stage = StageMetrics(self.registry)
+        self.loop_lag = loop_lag_histogram(self.registry)
         # KVBM tier/prefetch gauges+counters, sampled at scrape time from
         # TieredEngine.kvbm_stats() once attached (zero-valued until then)
         self.kvbm = KvbmStatsCollector(self.registry)

@@ -33,11 +33,8 @@ def test_single_process_tiny_chain():
     # cost-vs-RR comparison stays inside the smoke chain's budget
     env["BENCH_ROUTING_REQS"] = "16"
     env["BENCH_ROUTING_STALL"] = "0.25,0.4"
-    # short steptrace leg (fewer generated tokens, fewer A/B rounds) so
-    # the recorder-overhead A/B stays inside the smoke chain's budget
+    # short steptrace leg (fewer generated tokens)
     env["BENCH_STEPTRACE_GEN"] = "24"
-    env["BENCH_STEPTRACE_ROUNDS"] = "3"
-    env["BENCH_STEPTRACE_REPS"] = "2"
     # short shared-prefix leg (fewer requests/groups, shorter prefixes)
     # so the three-arm hot/cold-on/cold-off comparison stays inside the
     # smoke chain's budget
@@ -102,10 +99,8 @@ def test_single_process_tiny_chain():
     assert rt["breaker_metric_seen"] is True
     assert rt["trace_attrs_ok"] is True
     # step flight recorder leg: a warmed-shape rerun must produce ZERO
-    # compile events (no false positives), the deliberately cold cohort
-    # must surface mid-trace compiles attributable to StepRecords, and
-    # the recorder's on-vs-off overhead must stay inside the 2% budget
-    # (loose CI bound: CPU wall-clock jitters, the sign can flip)
+    # compile events (no false positives), and the deliberately cold
+    # cohort must surface mid-trace compiles attributable to StepRecords
     stp = result["steptrace"]
     assert stp["compile"]["warm_rerun_events"] == 0, stp
     assert stp["compile"]["midrun_events"] >= 1, stp
@@ -114,8 +109,6 @@ def test_single_process_tiny_chain():
     assert stp["aggregates"]["records"] > 0
     assert stp["aggregates"]["occupancy_samples"] > 0
     assert stp["aggregates"]["gap_samples"] > 0
-    assert stp["ab"]["on_tok_s"] > 0 and stp["ab"]["off_tok_s"] > 0
-    assert stp["ab"]["overhead_pct"] < 5.0, stp
     # fleet-wide KV reuse leg: the cold index-on worker really onboarded
     # its prefixes over G4 peer pulls (blocks + bytes recorded, the
     # admission_onboard kv_transfer spans landed in the flight recorder)

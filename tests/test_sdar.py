@@ -399,7 +399,6 @@ def test_a_preempted_row_resumes_at_a_block_boundary(params):
 def test_counters_ring_and_spans_say_what_the_passes_did(params):
     from dynamo_tpu.worker.metrics import engine_dispatch_stats
     eng = engine(params)
-    eng.steptrace.enabled = True
     n0 = eng.steptrace.total
     (got,) = run(eng, serve(eng, prompt_of(16), 16, "m"))
     stats = engine_dispatch_stats(eng)
@@ -428,7 +427,6 @@ def test_prompt_chunks_end_on_block_boundaries(params):
     rides the first generated block), and a prompt shorter than a block is
     not prefilled at all."""
     eng = engine(params, max_prefill_chunk=10)
-    eng.steptrace.enabled = True
     n0 = eng.steptrace.total
     run(eng, serve(eng, prompt_of(31), 5, "long"))
     chunks = [r["tokens_real"] for r in reversed(
@@ -436,7 +434,6 @@ def test_prompt_chunks_end_on_block_boundaries(params):
         if r["seq"] >= n0 and r["kind"] == "prefill"]
     assert chunks == [8, 8, 8, 4]
     eng = engine(params)
-    eng.steptrace.enabled = True
     n0 = eng.steptrace.total
     (short,) = run(eng, serve(eng, prompt_of(3), 6, "short"))
     assert len(short["toks"]) == 6
@@ -449,7 +446,6 @@ def test_a_wave_of_prompts_is_prefilled_before_its_rows_run(params):
     decode-progress guarantee raised a wave is prefilled in consecutive
     steps (``gen_rows_waited`` counts the rows that waited)."""
     eng = engine(params, max_prefill_chunk=16, decode_progress_every=8)
-    eng.steptrace.enabled = True
     n0 = eng.steptrace.total
     run(eng, *(serve(eng, prompt_of(24, i), 8, f"w{i}") for i in range(4)))
     kinds = [r["kind"] for r in reversed(

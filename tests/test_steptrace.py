@@ -11,6 +11,7 @@ import sys
 import time
 
 import aiohttp
+from prometheus_client import generate_latest
 import pytest
 
 from dynamo_tpu.engine.steptrace import (
@@ -24,7 +25,7 @@ from dynamo_tpu.engine.steptrace import (
 def fresh_recorder():
     """Each test gets its own process step recorder (engines pick up the
     global singleton at construction)."""
-    rec = StepRecorder(capacity=256, enabled=True)
+    rec = StepRecorder(capacity=256)
     set_step_recorder(rec)
     yield rec
     set_step_recorder(None)
@@ -48,7 +49,7 @@ def stamp(rec, kind="decode", device_ms=5.0, **kw):
 
 class TestRing:
     def test_bounds_and_newest_first_pagination(self):
-        rec = StepRecorder(capacity=4, enabled=True)
+        rec = StepRecorder(capacity=4)
         for i in range(10):
             stamp(rec, rows=i)
         snap = rec.snapshot(limit=100)
@@ -60,7 +61,7 @@ class TestRing:
         assert rec.snapshot(limit=2, offset=100)["records"] == []
 
     def test_slots_reused_in_place(self):
-        rec = StepRecorder(capacity=2, enabled=True)
+        rec = StepRecorder(capacity=2)
         r0 = stamp(rec, fallback="pages")
         rec.note_compile("decode", 1.2, r0)
         stamp(rec)
@@ -69,19 +70,14 @@ class TestRing:
         # wrap must clear the per-dispatch patch fields, not inherit them
         assert r0.compile_ms == 0.0 and r0.fallback == ""
 
-    def test_env_knobs(self, monkeypatch):
+    def test_ring_size_knob(self, monkeypatch):
         monkeypatch.setenv("DYN_STEPTRACE_RING", "7")
-        assert StepRecorder().capacity == 7
-        monkeypatch.setenv("DYN_STEPTRACE_DISABLE", "1")
         rec = StepRecorder()
-        assert rec.record("decode", dispatch_ms=1.0) is None
-        rec.note_compile("decode", 1.0)
-        snap = rec.snapshot()
-        assert snap["enabled"] is False and snap["total"] == 0
-        assert rec.aggregates()["compile_events"] == {}
+        assert rec.capacity == 7
+        assert "enabled" not in rec.snapshot()   # there is no off switch
 
     def test_unpack_and_compile_patching(self):
-        rec = StepRecorder(capacity=8, enabled=True)
+        rec = StepRecorder(capacity=8)
         r = stamp(rec, kind="multistep", width=8, gap_ms=2.0)
         rec.note_unpack(r, 0.5, 0.2)
         rec.note_compile("multistep", 2.5, r)
@@ -94,7 +90,7 @@ class TestRing:
         rec.note_ready(None, 1.0, 1.0)
 
     def test_aggregates_shape(self):
-        rec = StepRecorder(capacity=8, enabled=True)
+        rec = StepRecorder(capacity=8)
         stamp(rec, kind="decode", tokens_real=2, tokens_padded=4,
               gap_ms=1.0, pool_free=33, pool_pinned=3)
         # no occupancy sample for an unpadded dispatch; pool gauges track
@@ -125,7 +121,7 @@ def test_metric_rendering():
             '{kind="multistep",le="+Inf"} 0.0') in out
     assert 'dynamo_worker_compile_events_total{kind="prefill"} 0.0' in out
     assert 'dynamo_worker_step_gap_seconds_count 0.0' in out
-    rec = StepRecorder(capacity=8, enabled=True)
+    rec = StepRecorder(capacity=8)
     r = stamp(rec, kind="multistep", width=8, tokens_real=16,
               tokens_padded=64, gap_ms=0.3, pool_free=50, pool_pinned=5)
     rec.note_compile("multistep", 2.0, r)
@@ -592,6 +588,190 @@ class TestHeadStart:
         with pytest.raises(ValueError):
             await rec.phase("dispatch", 0, "multistep").in_thread(
                 boom, head_start=0.5)
+
+
+class TestDispatchStages:
+    """The host's side of a dispatch taken apart (``steptrace.stage``): a
+    fake engine marks its stages inside a threaded ``dispatch`` phase, as
+    ``jax_engine.py`` does, and the ring keeps their sums."""
+
+    @staticmethod
+    def fake_dispatch(synchronous):
+        from dynamo_tpu.engine.steptrace import stage
+
+        def call():
+            with stage("assemble"):
+                time.sleep(0.004)
+            with stage("upload"):
+                time.sleep(0.002)
+            with stage("assemble"):          # a helper's host half: sums
+                time.sleep(0.003)
+            with stage("upload"):
+                time.sleep(0.001)
+            with stage("enqueue"):
+                time.sleep(0.002)
+            if synchronous:
+                with stage("wait"):
+                    time.sleep(0.01)
+            return "handle"
+        return call
+
+    @pytest.mark.parametrize("kind,synchronous", [("multistep", False),
+                                                  ("mixed", True)])
+    async def test_the_stages_add_up_to_the_dispatch(self, kind,
+                                                     synchronous):
+        rec = StepRecorder(capacity=8)
+        ph = rec.phase("dispatch", rec.total, kind)
+        assert await ph.in_thread(self.fake_dispatch(synchronous)) == "handle"
+        r = rec.record(kind, dispatch_ms=ph.ms, enqueue=ph.t0, phase=ph)
+        d = r.to_dict()
+        # a stage opened twice keeps the sum of both
+        assert d["assemble_ms"] >= 7.0 and d["upload_ms"] >= 3.0
+        assert d["enqueue_ms"] >= 2.0
+        five = sum(d[f"{k}_ms"] for k in ("handover", "assemble", "upload",
+                                          "enqueue", "resume"))
+        assert d["handover_ms"] > 0.0 and d["resume_ms"] > 0.0
+        # what is left of dispatch_ms is the wait for the result
+        wait_ms = d["dispatch_ms"] - five
+        assert wait_ms >= -0.1
+        if synchronous:
+            assert ph.wait_s >= 0.01 and wait_ms >= ph.wait_s * 1000.0
+            six = five + ph.wait_s * 1000.0
+        else:
+            assert ph.wait_s == 0.0
+            six = five
+        # the six stages cover the phase but for the bookkeeping between
+        assert 0.9 * d["dispatch_ms"] <= six <= d["dispatch_ms"] + 0.1
+
+    async def test_a_stage_outside_any_phase_is_a_null_context(self):
+        from dynamo_tpu.engine import steptrace
+        assert steptrace.stage("assemble") is steptrace._NO_STAGE
+        with steptrace.stage("upload"):
+            pass                      # priming, tests, bench.py: nothing
+        rec = StepRecorder(capacity=8)
+        ph = rec.phase("dispatch", 0, "decode")
+        await ph.in_thread(lambda: None)
+        # and no phase stays current on a thread once its call returned
+        seen = await asyncio.to_thread(
+            lambda: getattr(steptrace._current, "phase", None))
+        assert seen is None
+
+    async def test_a_stage_inside_a_stage_stays_the_outer_ones(self):
+        from dynamo_tpu.engine.steptrace import stage
+        rec = StepRecorder(capacity=8)
+
+        def call():
+            with stage("assemble"):
+                with stage("upload"):
+                    time.sleep(0.005)
+
+        ph = rec.phase("dispatch", 0, "mixed")
+        await ph.in_thread(call)
+        assert ph.upload_s == 0.0 and ph.assemble_s >= 0.005
+        assert ph.assemble_s * 1000.0 <= ph.ms
+
+    async def test_fetch_resume_lands_on_its_own_record(self):
+        """``note_unpack`` patches the record of the dispatch that was
+        fetched, after the NEXT dispatch has been stamped."""
+        rec = StepRecorder(capacity=8)
+        first = rec.record("multistep", dispatch_ms=1.0)
+        second = rec.record("multistep", dispatch_ms=1.0, chained=True)
+        fetch = rec.phase("fetch", first.seq, "multistep")
+        await fetch.in_thread(time.sleep, 0.001)
+        rec.note_unpack(first, fetch.ms, 0.5, fetch.resume_ms)
+        newest, older = rec.snapshot(limit=2)["records"]
+        assert older["seq"] == first.seq and newest["seq"] == second.seq
+        assert older["fetch_resume_ms"] == fetch.resume_ms > 0.0
+        assert older["fetch_resume_ms"] <= older["fetch_ms"]
+        assert newest["fetch_resume_ms"] == 0.0
+        # a reused slot starts from zero again
+        for _ in range(8):
+            r = rec.record("decode")
+        assert r.fetch_resume_ms == 0.0 and r.assemble_ms == 0.0
+
+    async def test_the_histogram_renders_the_six_stages(self):
+        from prometheus_client import CollectorRegistry, generate_latest
+        from dynamo_tpu.engine.steptrace import STAGES
+        from dynamo_tpu.worker.metrics import StepTraceCollector
+        reg = CollectorRegistry()
+        coll = StepTraceCollector(reg)
+        name = "dynamo_worker_dispatch_stage_seconds"
+        text = generate_latest(reg).decode()
+        for st in STAGES:     # the schema is there before any dispatch
+            assert f'{name}_count{{stage="{st}"}} 0.0' in text
+        rec = StepRecorder(capacity=8)
+        coll.attach(rec.aggregates)
+        ph = rec.phase("dispatch", 0, "mixed")
+        await ph.in_thread(self.fake_dispatch(True))
+        rec.record("mixed", dispatch_ms=ph.ms, phase=ph)
+        ph = rec.phase("dispatch", 1, "multistep")
+        await ph.in_thread(self.fake_dispatch(False))
+        rec.record("multistep", dispatch_ms=ph.ms, phase=ph)
+        text = generate_latest(reg).decode()
+        assert len(STAGES) == 6
+        for st in STAGES:
+            # ``wait`` opened in the synchronous dispatch alone
+            n = 1.0 if st == "wait" else 2.0
+            assert f'{name}_count{{stage="{st}"}} {n}' in text
+        assert f'{name}_bucket{{le="+Inf",stage="assemble"}} 2.0' in text
+
+    async def test_a_call_inside_its_head_start_resumes_at_once(self):
+        rec = StepRecorder(capacity=8)
+        ph = rec.phase("dispatch", 0, "multistep")
+        await ph.in_thread(time.sleep, 0.002, head_start=0.5)
+        # the loop's thread was waiting for it: no other callback ran
+        # between the call's return and the coroutine's next line
+        assert 0.0 < ph.resume_ms < 5.0
+        assert ph.handover_ms < 5.0 and ph.ms >= 2.0
+
+    async def test_a_late_resume_is_said_in_the_log(self, caplog,
+                                                    monkeypatch):
+        from dynamo_tpu.engine import steptrace
+        from dynamo_tpu.utils import aio
+        monkeypatch.setattr(aio, "LAG_WARN_S", 0.01)
+        rec = StepRecorder(capacity=8)
+
+        async def hog():              # the loop is away when the call ends
+            time.sleep(0.05)
+
+        ph = rec.phase("fetch", 41, "multistep")
+        with caplog.at_level("WARNING", logger=steptrace.__name__):
+            other = asyncio.ensure_future(hog())
+            await ph.in_thread(time.sleep, 0.001)
+            await other
+        assert ph.resume_ms > 10.0
+        (line,) = [r.getMessage() for r in caplog.records]
+        assert "resume of seq 41" in line and "loop.fetch" in line
+        caplog.clear()
+        with caplog.at_level("WARNING", logger=steptrace.__name__):
+            await rec.phase("dispatch", 42, "mixed").in_thread(
+                time.sleep, 0.001)
+        assert not caplog.records
+
+
+async def test_the_event_loops_heartbeat_says_when_it_was_late(caplog,
+                                                               monkeypatch):
+    from dynamo_tpu.http.metrics import FrontendMetrics
+    from dynamo_tpu.utils import aio
+    from dynamo_tpu.worker.metrics import WorkerMetrics
+    monkeypatch.setattr(aio, "LAG_PERIOD_S", 0.01)
+    monkeypatch.setattr(aio, "LAG_WARN_S", 0.03)
+    # one name on both /metrics
+    for metrics in (FrontendMetrics(), WorkerMetrics()):
+        assert b"dynamo_event_loop_lag_seconds_count 0.0" in generate_latest(
+            metrics.registry)
+    seen = []
+    with caplog.at_level("WARNING", logger=aio.__name__):
+        task = asyncio.ensure_future(aio.watch_loop_lag(seen.append, "worker"))
+        await asyncio.sleep(0.035)        # three or so beats on time
+        on_time = len(seen)
+        assert on_time >= 2 and not caplog.records
+        time.sleep(0.08)                  # the loop is away
+        await asyncio.sleep(0.02)
+        await aio.reap_task(task)
+    assert max(seen[:on_time]) < 0.03 and max(seen) >= 0.05
+    (line,) = [r.getMessage() for r in caplog.records]
+    assert "worker's loop was away" in line
 
 
 def test_module_names_the_benchmark_matches_on():

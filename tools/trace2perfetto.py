@@ -85,9 +85,11 @@ def step_events(records, pid: int) -> list:
         args = {k: r[k] for k in
                 ("seq", "program", "width", "rows", "batch", "tokens_real",
                  "tokens_padded", "queue_depth", "running", "pool_free",
-                 "pool_pinned", "plan_ms", "dispatch_ms", "fetch_ms",
-                 "process_ms", "unpack_ms", "gap_ms", "compile_ms",
-                 "fallback", "chained") if r.get(k)}
+                 "pool_pinned", "plan_ms", "dispatch_ms", "handover_ms",
+                 "assemble_ms", "upload_ms", "enqueue_ms", "resume_ms",
+                 "fetch_ms", "fetch_resume_ms", "process_ms", "unpack_ms",
+                 "gap_ms", "compile_ms", "fallback", "chained")
+                if r.get(k)}
         if r.get("ready_unix"):
             dur_ms = float(r.get("device_ms", 0.0))
             ts = float(r["ready_unix"]) - dur_ms / 1e3
