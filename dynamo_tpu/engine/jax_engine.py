@@ -491,10 +491,20 @@ class JaxEngine(ScheduledEngineBase):
         self._jit_packed = (jax.jit(self._packed_step_impl,
                                     donate_argnums=(1,))
                             if self.padded_reason is None else None)
+        # rows of packed steps the decode kernel attended (the ring's
+        # ``decode_kernel_rows``, summed:
+        # dynamo_worker_packed_decode_kernel_rows_total)
+        self.packed_decode_kernel_rows = 0
         # prefill-carrying dispatches by form: "packed", "padded:<reason>"
         # (dynamo_worker_prefill_steps_total; the collector pre-seeds the
         # labels, worker/metrics.py PREFILL_FORMS)
         self.prefill_steps: Dict[str, int] = {}
+        # whether a packed step's one-token rows take the decode kernel:
+        # a causal GQA model's on the kernels (``ops/pallas/ragged.py``)
+        self._packed_splits = False
+        if self.padded_reason is None and not model_cfg.kv_lora_rank:
+            from dynamo_tpu.ops.pallas.ragged import takes_decode_kernel
+            self._packed_splits = takes_decode_kernel(self.gen_block)
         self._last_packed = None  # most recent packed output (device)
         self.ring_steps = 0  # diagnostics: sequence-parallel prefills run
         self.chained_steps = 0  # diagnostics: pipelined decode steps run
@@ -619,6 +629,32 @@ class JaxEngine(ScheduledEngineBase):
             return "causal"
         return (f"block_diffusion[B={self.gen_block},steps={self.gen_steps}"
                 f",tau={self.gen_threshold:g}]")
+
+    @property
+    def packed_attention(self) -> Optional[str]:
+        """The kernels that attend a token-packed step, by row kind, for
+        the ``startup.engine`` span (None: no step runs packed): latent
+        attention has one kernel for every row; the GQA families hand
+        their one-token rows to the decode kernel unless the model's
+        visibility has a block (``ops/pallas/ragged.py``)."""
+        if self.padded_reason is not None:
+            return None
+        if self.model_cfg.kv_lora_rank:
+            return "mla_ragged"
+        if self._packed_splits:
+            return "chunks:ragged_mixed,one_token:paged_decode"
+        return "ragged_mixed"
+
+    def _decode_kernel_rows(self, new: np.ndarray, slots: int) -> int:
+        """Rows of one packed step that the decode kernel attends: the
+        one-token rows behind the last row of several tokens — the rule of
+        ``ragged_mixed_attention_packed``, on the host's copy of
+        ``new_lens``."""
+        if not self._packed_splits or len(new) > slots:
+            return 0
+        several = np.flatnonzero(new > 1)
+        n_chunk = int(several[-1]) + 1 if several.size else 0
+        return int(np.count_nonzero(new[n_chunk:] == 1))
 
     def _per_shard(self, kernel: Callable, forward_fn) -> Callable:
         """On a mesh, run a GQA stacked kernel once per ``tp`` shard under
@@ -991,10 +1027,14 @@ class JaxEngine(ScheduledEngineBase):
         as in ``_step_impl``, and row ``r`` owns slots ``cu[r] .. cu[r] +
         new_lens[r]`` with ``cu`` the exclusive cumulative sum the forward
         computes on the device (``models/llama.packed_rows``). Everything
-        per token runs on ``[1, T, H]``; the cache write, the ragged
-        attention kernel and the last-token select take the rows as
-        descriptors. Sampling sees the same ``[R]`` rows in the same order
-        as the padded step."""
+        per token runs on ``[1, T, H]``; the cache write, the attention op
+        and the last-token select take the rows as descriptors. The
+        attention op (``_attn_packed``, ``ops/pallas/ragged.py``) runs the
+        ragged kernel over the rows of several tokens and the decode
+        kernel over the trailing one-token rows — both inside this one
+        program; latent attention has ``mla_ragged`` for every row.
+        Sampling sees the same ``[R]`` rows in the same order as the
+        padded step."""
         logits, pages, aux = self._run_forward(
             self._attn_packed, params, tokens, positions, pages, page_table,
             total_lens, new_lens, packed=True)
@@ -1647,6 +1687,8 @@ class JaxEngine(ScheduledEngineBase):
         if pack:
             # the program, not the plan: followers replay it by this name
             kind = "packed"
+            self.last_decode_kernel_rows = self._decode_kernel_rows(new, S)
+            self.packed_decode_kernel_rows += self.last_decode_kernel_rows
         elif ring:
             kind = "ring"
             self.ring_steps += 1

@@ -162,6 +162,8 @@ class ScheduledEngineBase(EngineBase):
         self.steptrace = get_step_recorder()
         self.last_padded: Optional[Tuple[int, int]] = None
         self.last_program = ""
+        # rows of a token-packed step that the decode kernel attended
+        self.last_decode_kernel_rows = 0
         # ... and, from a MoE family's step programs, the experts the
         # dispatch touched: a device scalar until the result is fetched
         self.last_experts_touched: Any = None
@@ -300,9 +302,11 @@ class ScheduledEngineBase(EngineBase):
             plan_ms=plan_ms, dispatch_ms=dispatch.ms,
             gap_ms=gap_ms, fallback=fallback, chained=chained,
             enqueue=dispatch.t0, experts=self.last_experts_touched,
+            decode_kernel_rows=self.last_decode_kernel_rows,
             phase=dispatch)
         self.last_padded = None
         self.last_program = ""
+        self.last_decode_kernel_rows = 0
         self.last_experts_touched = None
         for ev in self.drain_compile_events():
             st.note_compile(ev.get("kind", kind), ev["seconds"], rec)

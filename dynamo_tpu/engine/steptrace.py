@@ -123,8 +123,9 @@ class StepRecord:
     index (survives ring wrap, anchors pagination)."""
 
     FIELDS = ("seq", "t_unix", "kind", "program", "width", "rows", "batch",
-              "tokens_real", "tokens_padded", "queue_depth", "running",
-              "pool_free", "pool_pinned", "plan_ms", "dispatch_ms",
+              "decode_kernel_rows", "tokens_real", "tokens_padded",
+              "queue_depth", "running", "pool_free", "pool_pinned",
+              "plan_ms", "dispatch_ms",
               "fetch_ms", "process_ms", "unpack_ms", "device_ms",
               "ready_unix", "gap_ms", "compile_ms", "fallback", "chained",
               "experts_touched", "passes", "row_passes", "revealed",
@@ -144,6 +145,10 @@ class StepRecord:
         self.width = 0
         self.rows = 0
         self.batch = 0
+        # rows of a token-packed step that the decode kernel attended (its
+        # trailing one-token rows; 0 for every other program, and where a
+        # model's visibility block keeps them with the ragged kernel)
+        self.decode_kernel_rows = 0
         self.tokens_real = 0
         self.tokens_padded = 0
         self.queue_depth = 0
@@ -419,6 +424,7 @@ class StepRecorder:
                dispatch_ms: float = 0.0, gap_ms: float = 0.0,
                fallback: str = "", chained: bool = False,
                enqueue: float = 0.0, experts: Any = None,
+               decode_kernel_rows: int = 0,
                phase: Optional[Phase] = None) -> StepRecord:
         """Stamp one dispatch; returns the live ring slot (later patched
         by note_ready/note_unpack/note_compile).
@@ -437,6 +443,7 @@ class StepRecorder:
             rec.width = width
             rec.rows = rows
             rec.batch = batch
+            rec.decode_kernel_rows = decode_kernel_rows
             rec.tokens_real = tokens_real
             rec.tokens_padded = tokens_padded
             rec.queue_depth = queue_depth

@@ -254,7 +254,11 @@ def visibility(cfg: ModelConfig) -> Dict[str, int]:
 def attend_rows(attn_impl, q, pages, lidx, page_table, positions,
                 total_lens, new_lens, sm_scale, starts, **kw):
     """Attention of either step form. A packed step's ``attn_impl`` has
-    ``ops.attention.ragged_paged_attention``'s signature (the default)."""
+    ``ops.attention.ragged_paged_attention``'s signature (the default);
+    the engine's, ``ops/pallas/ragged.ragged_mixed_attention_packed``,
+    reads the row kinds off ``new_lens``: rows of several tokens through
+    the ragged kernel, the trailing one-token rows through the decode
+    kernel, unless ``kw`` carries a visibility ``block``."""
     if starts is None:
         return (attn_impl or paged_attention)(
             q, pages, lidx, page_table, positions, total_lens, sm_scale,

@@ -8,7 +8,9 @@ over layers), so a step program compiles one layer body:
   attention that reads KV pages directly from HBM (fuses away the XLA
   path's [B, T, Hkv, Dh] gather; page-major slabs, one DMA per page).
 - ``prefill.paged_prefill_attention_stacked`` — a padded ``[B, S]`` chunk
-  batch; ``ragged.ragged_mixed_attention_packed`` — a token-packed step.
+  batch; ``ragged.ragged_mixed_attention_packed`` — a token-packed step:
+  the ragged kernel over its rows of several tokens, the decode kernel
+  over its one-token rows.
 - ``mla_decode.mla_paged_decode_stacked`` /
   ``mla_prefill.mla_paged_prefill_stacked`` /
   ``mla_ragged.mla_ragged_attention_packed`` — the same three step forms

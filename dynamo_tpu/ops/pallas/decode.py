@@ -91,6 +91,10 @@ def _decode_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
     layers) — chunks wholly before the window are never even DMA'd.
     ``softcap`` (static; 0 = disabled) applies gemma-style logit
     soft-capping ``cap * tanh(s / cap)`` before the softmax.
+
+    A sequence of length 0 streams nothing and writes zeros: a
+    token-packed step runs this kernel over all of its rows with a length
+    for the one-token rows only (``ops/pallas/ragged.py``).
     """
     b = pl.program_id(0)
     layer = layer_ref[0]
@@ -137,7 +141,11 @@ def _decode_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
 
     span = chunk * page_size
     c0 = jax.lax.div(first_pos, span)  # skip chunks before the window
-    start_chunk(jax.lax.rem(c0, 2), c0)
+
+    # a row of length 0 has no chunk: start no copy that nothing waits for
+    @pl.when(num_chunks > c0)
+    def _():
+        start_chunk(jax.lax.rem(c0, 2), c0)
 
     def body(c, carry):
         m, l, acc = carry

@@ -1,13 +1,30 @@
-"""Ragged mixed-batch paged attention on TPU over a TOKEN-PACKED step — one
-kernel for prefill chunks AND decode rows (the Ragged Paged Attention
-kernel shape, PAPERS.md).
+"""Paged attention on TPU over a TOKEN-PACKED step: the ragged kernel for
+the rows that bring several tokens (prompt chunks; the Ragged Paged
+Attention kernel shape, PAPERS.md) and the decode kernel for the rows that
+bring one.
 
 The engine's prefill-carrying step lays every row's new tokens back to back
 on one ``[T]`` axis (``engine/jax_engine._packed_step_impl``): chunk rows
 first, then the decode rows, one token each; row ``r`` owns slots
-``q_starts[r] .. q_starts[r] + q_lens[r]``. Nothing around this kernel is
-padded to ``[rows, longest chunk]`` any more, so the kernel reads the
-queries where they lie:
+``q_starts[r] .. q_starts[r] + q_lens[r]``. Nothing around the kernels is
+padded to ``[rows, longest chunk]`` any more.
+
+**Which kernel serves which row** (``ragged_mixed_attention_packed``, read
+off the step itself): the ragged kernel multiplies a whole block of ``SB``
+slots' queries with every chunk of a row's keys and masks the other rows'
+slots out — right for a chunk that fills the block, ``SB`` times the work
+for a row that owns one slot of it. So the trailing run of one-token rows
+(the decode rows; their slots are contiguous) goes through
+``ops/pallas/decode.py``'s ``paged_decode``, one grid program and a
+``[Hkv, G, Dh]`` query each: their queries are one ``dynamic_slice`` of the
+packed axis, their outputs one select and ``dynamic_update_slice`` back,
+and the ragged kernel gets them as rows without slots and row bounds that
+end before them. A one-token row among the chunk rows stays with the ragged
+kernel. Under a visibility ``block`` > 1 (generation by diffusion over
+blocks: a one-slot row sees to its block's end, and ``paged_decode`` knows
+no ``block``) every row takes the ragged kernel, the program it always was.
+
+The ragged kernel reads the queries where they lie:
 
 - The grid runs over ALIGNED blocks of ``SB`` packed slots (plain
   ``BlockSpec``s on ``q`` and the output: no row is aligned to anything,
@@ -17,9 +34,10 @@ queries where they lie:
   slots out. Every slot belongs to one row, so one running softmax state
   per slot serves the whole loop: a row's pass leaves the other rows'
   slots as they were.
-- A block wholly past the packed tokens loops over no live row and writes
-  zeros. A decode row costs its own context once, in the one block that
-  holds its slot.
+- A block wholly past the packed tokens, or one that holds nothing but
+  rows the decode kernel took, loops over no row and writes zeros. A
+  one-token row that does stay here costs a whole block's matmuls over its
+  context, in the one block that holds its slot.
 - The page-streaming double buffer, the SMEM layer index for the
   ``lax.scan`` forward, the causal online softmax in f32 and window /
   softcap are the prefill kernel's (``ops/pallas/prefill.py``).
@@ -38,7 +56,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dynamo_tpu.ops.pallas.decode import _resolve_interpret, supports  # noqa: F401
+from dynamo_tpu.ops.pallas.decode import (  # noqa: F401
+    _paged_decode,
+    _resolve_interpret,
+    supports,
+)
 from dynamo_tpu.ops.pallas.prefill import (
     PAGES_PER_CHUNK,
     _fit_query_block,
@@ -193,7 +215,9 @@ def _ragged_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
                                     "block"))
 def _ragged_mixed(q, kv_pages, layer_idx, window, page_table, q_starts,
                   q_lens, kv_lens, sm_scale: float, softcap: float = 0.0,
-                  interpret: bool = False, block: int = 1):
+                  interpret: bool = False, block: int = 1, n_rows=None):
+    """``n_rows`` (optional traced scalar): only the first ``n_rows`` rows
+    have slots for this kernel; a block's row bounds end there."""
     T, Hq, Dh = q.shape
     _L, _N, _two, Hkv, page_size, _ = kv_pages.shape
     P = page_table.shape[1]
@@ -213,6 +237,8 @@ def _ragged_mixed(q, kv_pages, layer_idx, window, page_table, q_starts,
     rows = jnp.stack([
         jnp.sum(((q_starts + q_lens)[None, :] <= t0), axis=1),
         jnp.sum((q_starts[None, :] < t0 + SB), axis=1)]).astype(jnp.int32)
+    if n_rows is not None:
+        rows = jnp.minimum(rows, n_rows)
 
     kernel = functools.partial(_ragged_kernel, page_size=page_size,
                                n_kv=Hkv, chunk=chunk, q_block=SB,
@@ -243,6 +269,28 @@ def _ragged_mixed(q, kv_pages, layer_idx, window, page_table, q_starts,
     return out[:T]
 
 
+def takes_decode_kernel(block: int = 1) -> bool:
+    """Whether a packed step's one-token rows go through ``paged_decode``
+    (causal visibility) or stay with the ragged kernel (a visibility
+    ``block``: the decode kernel knows none)."""
+    return block <= 1
+
+
+def _decode_rows(q_starts, q_lens):
+    """The rows of a packed step that the decode kernel attends: the
+    one-token rows behind the last row of several tokens whose slots
+    follow one another, row ``r`` at slot ``first + r`` — by
+    ``_prefill_arrays``' layout every decode row of the step and a
+    one-token chunk that happens to be the last chunk row; a one-token row
+    off that line (there is none in the engine's steps) stays with the
+    ragged kernel. Returns (mask [R], first)."""
+    R = q_lens.shape[0]
+    r = jnp.arange(R, dtype=jnp.int32)
+    n_chunk = jnp.max(jnp.where(q_lens > 1, r + 1, 0))
+    first = q_starts[jnp.minimum(n_chunk, R - 1)] - n_chunk
+    return (r >= n_chunk) & (q_lens == 1) & (q_starts - r == first), first
+
+
 def ragged_mixed_attention_packed(q: jnp.ndarray, pages: jnp.ndarray,
                                   layer_idx, page_table: jnp.ndarray,
                                   q_starts: jnp.ndarray,
@@ -252,7 +300,9 @@ def ragged_mixed_attention_packed(q: jnp.ndarray, pages: jnp.ndarray,
                                   interpret: bool | None = None,
                                   block: int = 1) -> jnp.ndarray:
     """Drop-in for ``ops.attention.ragged_paged_attention`` on a
-    token-packed step.
+    token-packed step: the trailing run of one-token rows through the
+    decode kernel, every other row through the ragged kernel (module
+    docstring).
 
     q:          [T, Hq, Dh] every row's query tokens back to back; slots of
                 no row are pad (their output is zero)
@@ -272,14 +322,41 @@ def ragged_mixed_attention_packed(q: jnp.ndarray, pages: jnp.ndarray,
     layer = jnp.asarray(layer_idx, jnp.int32).reshape(1)
     win = (jnp.zeros((1,), jnp.int32) if window is None
            else jnp.asarray(window, jnp.int32).reshape(1))
-    return _ragged_mixed(q, pages, layer, win,
-                         page_table.astype(jnp.int32),
-                         q_starts.astype(jnp.int32),
-                         q_lens.astype(jnp.int32),
-                         kv_lens.astype(jnp.int32), sm_scale,
-                         softcap=float(softcap or 0.0),
-                         interpret=_resolve_interpret(interpret),
-                         block=int(block))
+    page_table = page_table.astype(jnp.int32)
+    q_starts = q_starts.astype(jnp.int32)
+    q_lens = q_lens.astype(jnp.int32)
+    kv_lens = kv_lens.astype(jnp.int32)
+    kw = dict(softcap=float(softcap or 0.0),
+              interpret=_resolve_interpret(interpret))
+    T, R = q.shape[0], page_table.shape[0]
+    if not takes_decode_kernel(int(block)) or R > T:
+        # (R > T: no window of R slots to cut the decode rows' queries
+        # from; a step of so few tokens has little to save)
+        return _ragged_mixed(q, pages, layer, win, page_table, q_starts,
+                             q_lens, kv_lens, sm_scale, block=int(block),
+                             **kw)
+
+    decode, first = _decode_rows(q_starts, q_lens)
+    r = jnp.arange(R, dtype=jnp.int32)
+    ragged_lens = jnp.where(decode, 0, q_lens)
+    out = _ragged_mixed(
+        q, pages, layer, win, page_table, q_starts, ragged_lens, kv_lens,
+        sm_scale, n_rows=jnp.max(jnp.where(ragged_lens > 0, r + 1, 0)),
+        **kw)
+    # their queries are the R slots from ``first`` on, moved inside the
+    # packed axis where that window overhangs it; the row arrays turn with
+    # it, so program j of the decode kernel serves row j - turn. A row of
+    # length 0 (every row that is no decode row) streams no page there,
+    # and its output is not selected.
+    at = jnp.clip(first, 0, T - R)
+    turn = first - at
+    lens = jnp.roll(jnp.where(decode, kv_lens, 0), turn)
+    attended = _paged_decode(
+        jax.lax.dynamic_slice_in_dim(q, at, R), pages, layer, win,
+        jnp.roll(page_table, turn, axis=0), lens, sm_scale, **kw)
+    merged = jnp.where((lens > 0)[:, None, None], attended,
+                       jax.lax.dynamic_slice_in_dim(out, at, R))
+    return jax.lax.dynamic_update_slice_in_dim(out, merged, at, 0)
 
 
 # the family forwards consult these markers before handing an impl their
@@ -288,4 +365,5 @@ ragged_mixed_attention_packed.supports_window_softcap = True
 ragged_mixed_attention_packed.pallas_paged_kernel = True
 
 
-__all__ = ["ragged_mixed_attention_packed", "supports"]
+__all__ = ["ragged_mixed_attention_packed", "supports",
+           "takes_decode_kernel"]

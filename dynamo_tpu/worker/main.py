@@ -209,7 +209,9 @@ def build_engine(args: argparse.Namespace, startup=None) -> JaxEngine:
     and ``startup.engine`` (page pools, jit wrappers; its attribute
     ``sample.top_candidates`` says which form the sampler's selection
     takes at this vocabulary, ``prefill.form`` which form the
-    prefill-carrying steps take: ``packed`` or ``padded:<reason>``). Callers that keep no startup trace (run.py,
+    prefill-carrying steps take: ``packed`` or ``padded:<reason>``,
+    ``prefill.attention`` which kernels attend a packed step's rows).
+    Callers that keep no startup trace (run.py,
     step followers) pass none."""
     from dynamo_tpu.ops.sampling import candidate_form
     from dynamo_tpu.utils.tracing import StartupTrace
@@ -228,6 +230,9 @@ def build_engine(args: argparse.Namespace, startup=None) -> JaxEngine:
         attrs["prefill.form"] = (
             "packed" if engine.padded_reason is None
             else f"padded:{engine.padded_reason}")
+        if engine.packed_attention is not None:
+            # which kernel attends which rows of a packed step
+            attrs["prefill.attention"] = engine.packed_attention
         if engine.gen_block > 1:
             attrs["generation"] = engine.generation
         return engine

@@ -137,6 +137,14 @@ class EngineDispatchCollector:
         "mixed_dispatches": "Mixed prefill+decode dispatches (prefill "
                             "chunks and decode rows advanced in ONE "
                             "ragged [B, S] step, DYN_MIXED_BATCH)",
+        "packed_decode_kernel_rows": "Rows of token-packed steps that the "
+                                     "decode kernel attended: a packed "
+                                     "step's trailing one-token rows (the "
+                                     "ragged kernel takes the rows of "
+                                     "several tokens); stays 0 where a "
+                                     "model's visibility block or latent "
+                                     "attention keeps every row in one "
+                                     "kernel",
         "guided_parity_mismatches": "Guided rows whose host-side automaton "
                                     "re-walk disagreed with the device "
                                     "transition table after a fused block "
@@ -448,6 +456,8 @@ def engine_dispatch_stats(engine) -> Dict[str, object]:
         "decode_multistep_blocks": float(
             getattr(engine, "multistep_blocks", 0)),
         "mixed_dispatches": float(getattr(engine, "mixed_steps", 0)),
+        "packed_decode_kernel_rows": float(
+            getattr(engine, "packed_decode_kernel_rows", 0)),
         "guided_parity_mismatches": float(
             getattr(engine, "guided_parity_mismatches", 0)),
         "multistep_fallbacks": dict(

@@ -254,6 +254,7 @@ class TestPallasPerShard:
     partitioned"). Here the kernels run in interpret mode on the CPU mesh;
     the native compile is chip_smoke.py's tp variant."""
 
+    @pytest.mark.async_timeout(240)
     async def test_tp_and_dp_tp_match_single_device_scan(self):
         cfg = ModelConfig.tiny(num_heads=4, num_kv_heads=2, head_dim=128)
         kw = dict(ENGINE_KW, page_size=8)

@@ -383,6 +383,7 @@ async def _serve(eng, reqs):
 REQS = [(range(1, 30), "a", 12), ([5, 6, 7], "b", 9), (range(40, 61), "c", 7)]
 
 
+@pytest.mark.async_timeout(240)
 @pytest.mark.parametrize("family", ["llama", "mla"])
 async def test_packed_engine_streams_what_the_padded_engine_streams(family):
     """The engine on the kernels (interpreted) serves packed — the Llama
@@ -500,3 +501,170 @@ def test_the_marker_is_read_off_the_familys_forward():
     wrapped = functools.partial(moe.forward, ep_mesh=None)
     assert not hasattr(wrapped, "supports_packed")
     assert moe.forward.supports_packed
+
+
+# -- which kernel attends which rows (ISSUE 40) ---------------------------
+
+
+def _watch_plans(eng, monkeypatch):
+    """The one-token rows at the tail of every packed step the engine
+    assembles, in dispatch order: what the decode kernel is to take."""
+    tails = []
+    assemble = eng._prefill_arrays
+
+    def watched(plan, mixed):
+        lens = [c.length for c in plan.chunks]
+        lens += [1] * (len(plan.decode_seqs) if mixed else 0)
+        n = 0
+        while n < len(lens) and lens[-1 - n] == 1:
+            n += 1
+        tails.append(n)
+        return assemble(plan, mixed)
+
+    monkeypatch.setattr(eng, "_prefill_arrays", watched)
+    return tails
+
+
+def _ring_since(eng, total):
+    """The ring's records stamped after it held ``total`` (the recorder is
+    the process's: other tests' engines wrote to it), oldest first."""
+    snap = eng.steptrace.snapshot(limit=512)
+    return snap["records"][:snap["total"] - total][::-1]
+
+
+@pytest.mark.async_timeout(300)
+async def test_a_packed_step_of_many_decode_rows_serves_the_padded_tokens(
+        monkeypatch):
+    """36 rows decode while two longer prompts are admitted: the packed
+    steps carry >= 32 one-token rows behind the prompt chunks — the decode
+    kernel's rows, the ragged kernel's chunks — and every stream is the
+    padded XLA engine's, token for token. The ring's
+    ``decode_kernel_rows`` counts exactly those rows, step by step, and
+    the worker counter adds them up."""
+    from dynamo_tpu.worker.metrics import engine_dispatch_stats
+
+    reqs = [([3 + i, 5, 7 + i % 5], f"d{i}", 24) for i in range(36)]
+    late = [(range(1, 40), "p0", 5), (range(50, 81), "p1", 5)]
+
+    async def serve(eng):
+        started = asyncio.Event()
+        flowing = set()
+
+        async def one(r, wait):
+            if wait:
+                await started.wait()
+            out = []
+            async for f in eng.generate(_req(*r)):
+                out += f.token_ids
+                flowing.add(r[1])
+                if len(flowing) == len(reqs):
+                    started.set()
+            return out
+
+        try:
+            return await asyncio.gather(
+                *(one(r, False) for r in reqs),
+                *(one(r, True) for r in late))
+        finally:
+            await eng.stop()
+
+    kw = dict(max_num_seqs=40, num_pages=256, max_prefill_chunk=32)
+    packed = _engine(**kw)
+    assert packed.packed_attention == \
+        "chunks:ragged_mixed,one_token:paged_decode"
+    tails = _watch_plans(packed, monkeypatch)
+    before = packed.steptrace.total
+    got = await serve(packed)
+    ring = _ring_since(packed, before)
+    want = await serve(_engine(attn_impl="scan", **kw))
+    assert got == want
+    assert [len(t) for t in got] == [24] * 36 + [5, 5]
+    recs = [r for r in ring if r["program"].startswith("packed[")]
+    assert [r["decode_kernel_rows"] for r in recs] == tails
+    assert max(tails) >= 32
+    # a step of decode rows and chunks: fewer rows for the decode kernel
+    # than the step has, more slots than rows
+    assert all(r["decode_kernel_rows"] <= r["rows"] <= r["tokens_real"]
+               for r in recs)
+    assert engine_dispatch_stats(packed)["packed_decode_kernel_rows"] \
+        == float(sum(tails)) == float(packed.packed_decode_kernel_rows)
+    # every other program of the ring reads 0
+    assert not [r for r in ring if not r["program"].startswith("packed[")
+                and r["decode_kernel_rows"]]
+
+
+def _block_cfg():
+    """A model that generates by diffusion over blocks of 4."""
+    return ModelConfig.from_hf(dict(
+        vocab_size=256, hidden_size=128, intermediate_size=128,
+        num_hidden_layers=2, num_attention_heads=2,
+        num_key_value_heads=1, head_dim=128, model_type="sdar",
+        block_size=4, mask_token_id=255, max_position_embeddings=512),
+        dtype="float32")
+
+
+async def test_the_ring_reads_no_decode_kernel_row_under_a_visibility_block():
+    """Generation by diffusion over blocks: the prompt's whole blocks are
+    prefilled in a packed step of the ragged kernel alone, and neither the
+    ring's field nor the worker counter counts a row."""
+    eng = _engine(cfg=_block_cfg())
+    n0 = eng.steptrace.total
+    try:
+        out = [t async for f in eng.generate(_req(range(1, 14), "b", 8))
+               for t in f.token_ids]
+    finally:
+        await eng.stop()
+    assert len(out) == 8
+    ring = _ring_since(eng, n0)
+    assert [r["program"] for r in ring if r["kind"] == "prefill"] \
+        == ["packed[16,1]"]
+    assert not [r for r in ring if r["decode_kernel_rows"]]
+    assert eng.packed_decode_kernel_rows == 0
+
+
+@pytest.mark.parametrize("case,says", [
+    ("gqa", "chunks:ragged_mixed,one_token:paged_decode"),
+    ("block", "ragged_mixed"), ("mla", "mla_ragged"), ("scan", None)])
+def test_the_engine_says_which_kernels_attend_a_packed_step(case, says):
+    """``startup.engine``'s ``prefill.attention``, read off what the
+    engine is: the decode kernel takes the one-token rows of a causal GQA
+    model; a visibility block (generation by diffusion over blocks) and
+    latent attention keep every row in one kernel, and their ring field
+    reads 0 whatever the rows."""
+    cfg, kw = {
+        "gqa": (None, {}),
+        "block": (_block_cfg(), {}),
+        "mla": (_mla_cfg(kv_lora_rank=128, head_dim=128), {}),
+        "scan": (None, dict(attn_impl="scan")),
+    }[case]
+    eng = _engine(cfg=cfg, **kw)
+    assert eng.packed_attention == says
+    new = np.asarray([9, 5, 1, 1, 1, 0, 0, 0], np.int32)
+    assert eng._decode_kernel_rows(new, 32) == (3 if case == "gqa" else 0)
+    # more rows than slots: the ragged kernel alone, as the op decides
+    assert eng._decode_kernel_rows(new, 4) == 0
+    # only one-token rows, none, a one-token chunk among the chunks
+    for lens, n in (([1, 1, 1, 0], 3), ([7, 2, 0, 0], 0),
+                    ([4, 1, 6, 1, 1, 0], 2)):
+        assert eng._decode_kernel_rows(np.asarray(lens, np.int32), 16) \
+            == (n if case == "gqa" else 0)
+
+
+def test_the_host_counts_the_rows_the_op_takes():
+    """``JaxEngine._decode_kernel_rows`` (the ring's field) and
+    ``ops/pallas/ragged._decode_rows`` (the program) are one rule, on the
+    layouts ``_prefill_arrays`` makes: chunk rows, decode rows, pads."""
+    from dynamo_tpu.ops.pallas.ragged import _decode_rows
+
+    eng = _engine()
+    rng = np.random.default_rng(0)
+    for _ in range(50):
+        chunks = rng.integers(1, 9, size=rng.integers(0, 4))
+        new = np.concatenate([chunks, np.ones(rng.integers(0, 6), int),
+                              np.zeros(rng.integers(0, 3), int)]) \
+            .astype(np.int32)
+        if not new.size:
+            continue
+        starts = (np.cumsum(new) - new).astype(np.int32)
+        mask, _first = _decode_rows(jnp.asarray(starts), jnp.asarray(new))
+        assert eng._decode_kernel_rows(new, 64) == int(mask.sum()), new

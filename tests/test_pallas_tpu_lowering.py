@@ -106,13 +106,14 @@ def test_gqa_prefill_kernel_lowers(window, softcap):
 
 @pytest.mark.parametrize("window,softcap", [(None, None), (4096, 50.0)])
 def test_packed_ragged_kernel_lowers(window, softcap):
-    """The packed ragged kernel (one dispatch for prefill chunks + decode
-    rows on one token axis, `ops/pallas/ragged.py`) lowers at Qwen3-4B
-    widths and the batch cell's step: 32/8 heads of 128, page 16, 1,152
-    slots, 32 rows — the program the engine's packed step runs on chip."""
+    """The packed step's attention (`ops/pallas/ragged.py`: the ragged
+    kernel over the prompt chunks, the decode kernel over the one-token
+    rows, both in one program) lowers at Qwen3-4B widths and the batch
+    cell's step: 32/8 heads of 128, page 16, 1,152 slots, 64 rows — the
+    program the engine's packed step runs on chip."""
     from dynamo_tpu.ops.pallas.ragged import ragged_mixed_attention_packed
 
-    Hq, Hkv, Dh, T, R = 32, 8, 128, 1152, 32
+    Hq, Hkv, Dh, T, R = 32, 8, 128, 1152, 64
 
     def fn(q, pages, table, starts, q_lens, kv_lens):
         return ragged_mixed_attention_packed(
@@ -128,6 +129,8 @@ def test_packed_ragged_kernel_lowers(window, softcap):
         jax.ShapeDtypeStruct((R,), jnp.int32),
         jax.ShapeDtypeStruct((R,), jnp.int32))
     _assert_mosaic(exp)
+    text = exp.mlir_module()
+    assert "ragged_mixed" in text and "paged_decode" in text
 
 
 def test_mla_decode_kernel_lowers_v3_geometry():
@@ -288,6 +291,10 @@ def test_tp_sharded_steps_compile_in_the_tpu_compiler():
             sds((B_,), jnp.int32), sds((B_,), jnp.float32)).compile()
         hlo = compiled.as_text()
         assert "tpu_custom_call" in hlo
+        # the packed step holds both kernels, each per shard: the ragged
+        # one for the chunks, the decode one for the one-token rows
+        assert (("ragged_mixed" in hlo and "paged_decode" in hlo)
+                == (impl == eng._packed_step_impl))
         assert "all-gather" not in hlo, "the sharded cache was gathered"
         # nor is a chip's slab of it copied or re-laid around the cache
         # write (engine/program_check.py)
@@ -547,6 +554,9 @@ def test_packed_ragged_kernel_lowers_with_a_visibility_block():
         jax.ShapeDtypeStruct((R,), jnp.int32),
         jax.ShapeDtypeStruct((R,), jnp.int32))
     _assert_mosaic(exp)
+    # the ragged kernel alone, as before the decode kernel entered the
+    # causal packed step
+    assert "paged_decode" not in exp.mlir_module()
 
 
 def _sdar_config(layers: int):
