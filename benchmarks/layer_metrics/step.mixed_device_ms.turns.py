@@ -1,0 +1,6 @@
+"""``step.mixed_device_ms`` in the conversation cells, which are judged on ``out_tok_per_s`` (a per-layer
+metric names one end-to-end metric and lists its cells, so the quantity is split)."""
+
+from layer_metrics import reader
+
+compute = reader("step.mixed_device_ms").compute

@@ -46,7 +46,7 @@ import numpy as np
 
 from dynamo_tpu.engine.loop import BlockState, ScheduledEngineBase
 from dynamo_tpu.engine.scheduler import PrefillBatch, StepPlan
-from dynamo_tpu.engine.steptrace import stage
+from dynamo_tpu.engine.steptrace import MOE_COUNTS, stage
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models import llama
 from dynamo_tpu.ops.sampling import (TOPK_MAX, reveal, sample_tokens,
@@ -538,16 +538,16 @@ class JaxEngine(ScheduledEngineBase):
         self._pending_moe_aux: list = []
         self.moe_totals: Dict[str, int] = {
             "moe_dropped_assignments": 0, "moe_experts_touched": 0,
-            "moe_assignments": 0}
+            "moe_assignments": 0, "moe_held_assignments": 0,
+            "moe_zero_assignments": 0}
         # appends happen on the step worker thread, drains on either that
         # thread (the >512 cap) or the event-loop thread (stats scrape)
         self._moe_aux_lock = threading.Lock()
         # what "every expert of every expert layer" counts to in one
         # forward pass: the denominator of the touched share
+        # (of the experts this worker HOLDS: the touched count's range)
         self._moe_slots_per_step = int(
-            getattr(model_cfg, "num_experts", 0)
-            * (model_cfg.num_layers
-               - getattr(model_cfg, "first_k_dense_replace", 0)))
+            model_cfg.experts_held * model_cfg.num_expert_layers)
         self.moe_expert_slots = 0
         # compile-event detection (engine/steptrace.py): the first call on
         # a fresh (jit program, B, S) bucket ALWAYS traces+compiles, so
@@ -2615,7 +2615,7 @@ class JaxEngine(ScheduledEngineBase):
         if "moe_experts_touched" in aux:
             self.moe_expert_slots += steps * self._moe_slots_per_step
             # for this dispatch's ring record (loop._stamp_dispatch)
-            self.last_experts_touched = aux["moe_experts_touched"]
+            self.last_moe_counts = tuple(aux[k] for k in MOE_COUNTS)
         with self._moe_aux_lock:
             self._pending_moe_aux.append(aux)
             overflow = len(self._pending_moe_aux) > 512

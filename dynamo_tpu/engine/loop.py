@@ -164,9 +164,10 @@ class ScheduledEngineBase(EngineBase):
         self.last_program = ""
         # rows of a token-packed step that the decode kernel attended
         self.last_decode_kernel_rows = 0
-        # ... and, from a MoE family's step programs, the experts the
-        # dispatch touched: a device scalar until the result is fetched
-        self.last_experts_touched: Any = None
+        # ... and, from a MoE family's step programs, the expert layer's
+        # counts (steptrace.MOE_COUNTS): device scalars until the result is
+        # fetched
+        self.last_moe_counts: Any = None
         self._last_dispatch_end: Optional[float] = None
         # what the passes of generation by diffusion over blocks did
         # (dynamo_worker_gen_*; worker/metrics.py): row-passes by kind,
@@ -301,13 +302,13 @@ class ScheduledEngineBase(EngineBase):
             pool_pinned=mgr.pinned_pages if mgr is not None else 0,
             plan_ms=plan_ms, dispatch_ms=dispatch.ms,
             gap_ms=gap_ms, fallback=fallback, chained=chained,
-            enqueue=dispatch.t0, experts=self.last_experts_touched,
+            enqueue=dispatch.t0, experts=self.last_moe_counts,
             decode_kernel_rows=self.last_decode_kernel_rows,
             phase=dispatch)
         self.last_padded = None
         self.last_program = ""
         self.last_decode_kernel_rows = 0
-        self.last_experts_touched = None
+        self.last_moe_counts = None
         for ev in self.drain_compile_events():
             st.note_compile(ev.get("kind", kind), ev["seconds"], rec)
             for seq in seqs:

@@ -117,6 +117,12 @@ class _Hist:
         return out
 
 
+# the grouped expert layer's counts a ring record carries, in the order the
+# engine hands them over (``models/moe.grouped_experts``'s ``aux`` keys)
+MOE_COUNTS = ("moe_experts_touched", "moe_assignments",
+              "moe_held_assignments", "moe_zero_assignments")
+
+
 class StepRecord:
     """One engine dispatch. Slots + in-place reuse keep the ring
     allocation-free in steady state; ``seq`` is the monotonic dispatch
@@ -128,13 +134,14 @@ class StepRecord:
               "plan_ms", "dispatch_ms",
               "fetch_ms", "process_ms", "unpack_ms", "device_ms",
               "ready_unix", "gap_ms", "compile_ms", "fallback", "chained",
-              "experts_touched", "passes", "row_passes", "revealed",
+              "experts_touched", "moe_assignments", "moe_held_assignments",
+              "moe_zero_assignments", "passes", "row_passes", "revealed",
               "commits", "handover_ms", "assemble_ms", "upload_ms",
               "enqueue_ms", "resume_ms", "fetch_resume_ms")
     # _enqueue: perf_counter at the start of the enqueue, kept until the
     # result arrives and device_ms can be taken; _experts: the dispatch's
-    # count of experts touched while it is still a device scalar; neither
-    # is exported
+    # expert-layer counts (MOE_COUNTS) while they are still device
+    # scalars; neither is exported
     __slots__ = FIELDS + ("_enqueue", "_experts")
 
     def __init__(self) -> None:
@@ -169,6 +176,13 @@ class StepRecord:
         # experts the dispatch read, summed over its expert layers and
         # steps (MoE families' grouped layer; 0 elsewhere)
         self.experts_touched = 0
+        # every pick of the dispatch's valid tokens, and of those the
+        # picks computed here (an expert this worker holds) and the picks
+        # of zero-compute experts (the token itself, no matmul); what is
+        # left was an expert held elsewhere
+        self.moe_assignments = 0
+        self.moe_held_assignments = 0
+        self.moe_zero_assignments = 0
         # a dispatch of generation by diffusion over blocks (0 elsewhere):
         # forward passes it scanned, passes summed over the rows alive at
         # each (a pass serves every live row), positions those passes
@@ -429,8 +443,9 @@ class StepRecorder:
         """Stamp one dispatch; returns the live ring slot (later patched
         by note_ready/note_unpack/note_compile).
         ``enqueue`` is the perf_counter at the start of the enqueue;
-        ``experts`` the experts its expert layers touched, a device scalar
-        that ``note_ready`` reads once the result is on the host;
+        ``experts`` its expert layers' counts (``MOE_COUNTS`` order),
+        device scalars that ``note_ready`` reads in one transfer once the
+        result is on the host;
         ``phase`` the finished dispatch phase, for its stages."""
         now = time.time()
         with self._lock:
@@ -461,7 +476,8 @@ class StepRecorder:
             rec.compile_ms = 0.0
             rec.fallback = fallback
             rec.chained = chained
-            rec.experts_touched = 0
+            rec.experts_touched = rec.moe_assignments = 0
+            rec.moe_held_assignments = rec.moe_zero_assignments = 0
             rec.passes = rec.row_passes = rec.revealed = rec.commits = 0
             rec._enqueue = enqueue
             rec._experts = experts
@@ -511,8 +527,12 @@ class StepRecorder:
                 h = self._dur[rec.kind] = _Hist(_DUR_BOUNDS)
             h.observe(device_s)
         if rec._experts is not None:
-            # the program has run, so the scalar is there to be read
-            rec.experts_touched, rec._experts = int(rec._experts), None
+            # the program has run, so the scalars are there to be read
+            import jax
+            counts, rec._experts = jax.device_get(rec._experts), None
+            (rec.experts_touched, rec.moe_assignments,
+             rec.moe_held_assignments, rec.moe_zero_assignments) = (
+                int(c) for c in counts)
 
     def note_unpack(self, rec: Optional[StepRecord], fetch_ms: float,
                     process_ms: float, fetch_resume_ms: float = 0.0) -> None:

@@ -4,7 +4,9 @@ Each family exposes ``init_params(cfg, rng)``, ``make_pages`` (the stacked
 paged KV cache ``[L, N, 2, Hkv, page_size, Dh]``) and ONE ``forward``: a
 ``lax.scan`` over the layers against that cache, whose attention op is an
 argument (``attn_impl``). ``get_family(cfg)`` maps a config to its
-implementation: MLA configs (``kv_lora_rank > 0``) use ``models.deepseek``,
+implementation: double-layer configs (``attn_blocks_per_layer == 2``:
+LongCat-Flash) use ``models.longcat``, other MLA configs (``kv_lora_rank >
+0``) ``models.deepseek``,
 other MoE configs (``num_experts > 0``: mixtral / qwen3_moe routing)
 ``models.moe``, gemma-2 ``models.gemma``; everything else in the Llama tree
 (llama 2/3, mistral, qwen2/qwen3) uses ``models.llama``.
@@ -16,6 +18,11 @@ from dynamo_tpu.models.llama import forward, init_params, make_pages
 
 def get_family(cfg: ModelConfig):
     """Return the module implementing this config's model family."""
+    if cfg.attn_blocks_per_layer == 2:
+        # LongCat-Flash: double layers of latent attention around a
+        # shortcut-connected expert branch with zero-compute experts
+        from dynamo_tpu.models import longcat
+        return longcat
     if cfg.kv_lora_rank:
         # MLA (deepseek v2/v3): latent paged cache, absorbed attention
         from dynamo_tpu.models import deepseek

@@ -112,10 +112,48 @@ class ModelConfig:
     generation: str = "causal"
     gen_block: int = 1
     mask_token_id: int = -1
+    # LongCat-Flash family (models/longcat.py): a layer holds TWO latent
+    # attention blocks and two dense FFNs around one expert branch, so the
+    # paged cache has ``attn_blocks_per_layer`` cache layers a layer
+    # (``num_cache_layers``: what the cache is sized by, everywhere)
+    attn_blocks_per_layer: int = 1
+    # the router's last ``zero_expert_num`` outputs compute nothing: a
+    # pick of one adds the token itself times its weight ("identity", the
+    # one kind implemented); ``num_experts`` counts the computing ones
+    zero_expert_num: int = 0
+    zero_expert_type: str = "identity"
+    # which experts live here: rank ``ep_rank`` of ``ep_size`` holds the
+    # contiguous ``experts_held`` from ``expert_offset`` on; the router
+    # keeps its whole width, picks of experts held elsewhere add nothing
+    # here (1 holds all)
+    ep_size: int = 1
+    ep_rank: int = 0
+    # ``mla_scale_q_lora`` / ``mla_scale_kv_lora``: the compressed query's
+    # projection and the normed kv latent are multiplied by
+    # sqrt(hidden / rank) (1.0 = the DeepSeek form)
+    mla_q_scale: float = 1.0
+    mla_kv_scale: float = 1.0
 
     @property
     def q_size(self) -> int:
         return self.num_heads * self.head_dim
+
+    @property
+    def num_cache_layers(self) -> int:
+        return self.num_layers * self.attn_blocks_per_layer
+
+    @property
+    def experts_held(self) -> int:
+        return self.num_experts // self.ep_size
+
+    @property
+    def expert_offset(self) -> int:
+        return self.ep_rank * self.experts_held
+
+    @property
+    def num_expert_layers(self) -> int:
+        return ((self.num_layers - self.first_k_dense_replace)
+                if self.num_experts else 0)
 
     @property
     def kv_size(self) -> int:
@@ -139,9 +177,22 @@ class ModelConfig:
             raise ValueError(
                 f"mask_token_id {self.mask_token_id} outside the "
                 f"vocabulary of {self.vocab_size}")
+        if self.zero_expert_num and self.zero_expert_type != "identity":
+            raise NotImplementedError(
+                f"zero_expert_type {self.zero_expert_type!r}: only "
+                "'identity' zero-compute experts are implemented")
+        if self.ep_size < 1 or self.num_experts % self.ep_size:
+            raise ValueError(
+                f"ep_size {self.ep_size} does not divide the "
+                f"{self.num_experts} routed experts")
+        if not 0 <= self.ep_rank < self.ep_size:
+            raise ValueError(
+                f"ep_rank {self.ep_rank} outside ep_size {self.ep_size}")
 
     @classmethod
     def from_hf(cls, hf: Dict[str, Any], dtype: str = "bfloat16") -> "ModelConfig":
+        if "expert_ffn_hidden_size" in hf and "ffn_hidden_size" in hf:
+            return cls._from_longcat(hf, dtype)
         heads = hf["num_attention_heads"]
         mt = hf.get("model_type", "llama")
         num_experts = hf.get("num_local_experts", hf.get("num_experts", 0)) or 0
@@ -235,6 +286,62 @@ class ModelConfig:
             query_pre_attn_scalar=float(
                 hf.get("query_pre_attn_scalar") or 0.0),
             **extra,
+        )
+
+    @classmethod
+    def _from_longcat(cls, hf: Dict[str, Any], dtype: str) -> "ModelConfig":
+        """The LongCat-Flash family, read off its OWN key names
+        (``num_layers``, ``ffn_hidden_size``, ``expert_ffn_hidden_size``,
+        ``moe_topk``, ``zero_expert_num``, ...), never off a model_type.
+
+        Which experts a model directory holds: a published file holds all
+        ``n_routed_experts``. A directory written for one rank of an
+        expert-parallel deployment says so with ``ep_rank`` (which no
+        published file carries) beside ``ep_size``; ``n_routed_experts``
+        then counts the experts HELD, and the router's width is rebuilt as
+        ``n_routed_experts * ep_size + zero_expert_num``."""
+        if (hf.get("attention_method") or "MLA") != "MLA":
+            raise NotImplementedError(
+                f"attention_method {hf['attention_method']!r} (MLA only)")
+        H = int(hf["hidden_size"])
+        ep_size = int(hf.get("ep_size") or 1) if "ep_rank" in hf else 1
+        q_rank, kv_rank = int(hf["q_lora_rank"]), int(hf["kv_lora_rank"])
+        return cls(
+            vocab_size=hf["vocab_size"],
+            hidden_size=H,
+            intermediate_size=int(hf["ffn_hidden_size"]),
+            num_layers=int(hf["num_layers"]),
+            num_heads=int(hf["num_attention_heads"]),
+            num_kv_heads=1,             # the latent page layout, as above
+            head_dim=kv_rank,
+            rope_theta=float(hf.get("rope_theta", 10000.0)),
+            rms_norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
+            max_position_embeddings=hf.get("max_position_embeddings", 8192),
+            tie_word_embeddings=bool(hf.get("tie_word_embeddings", False)),
+            attention_bias=bool(hf.get("attention_bias", False)),
+            model_type=hf.get("model_type", "longcat_flash"),
+            dtype=dtype,
+            num_experts=int(hf["n_routed_experts"]) * ep_size,
+            num_experts_per_tok=int(hf["moe_topk"]),
+            moe_intermediate_size=int(hf["expert_ffn_hidden_size"]),
+            norm_topk_prob=False,       # the picked scores stay as they are
+            q_lora_rank=q_rank,
+            kv_lora_rank=kv_rank,
+            qk_rope_head_dim=int(hf["qk_rope_head_dim"]),
+            qk_nope_head_dim=int(hf["qk_nope_head_dim"]),
+            v_head_dim=int(hf["v_head_dim"]),
+            routed_scaling_factor=float(
+                hf.get("routed_scaling_factor") or 1.0),
+            rope_interleave=bool(hf.get("rope_interleave", True)),
+            attn_blocks_per_layer=2,
+            zero_expert_num=int(hf.get("zero_expert_num") or 0),
+            zero_expert_type=hf.get("zero_expert_type") or "identity",
+            ep_size=ep_size,
+            ep_rank=int(hf.get("ep_rank") or 0),
+            mla_q_scale=((H / q_rank) ** 0.5
+                         if hf.get("mla_scale_q_lora") else 1.0),
+            mla_kv_scale=((H / kv_rank) ** 0.5
+                          if hf.get("mla_scale_kv_lora") else 1.0),
         )
 
     @classmethod

@@ -252,6 +252,8 @@ def _mla_qkv(cfg: ModelConfig, lp: Dict[str, jnp.ndarray], h: jnp.ndarray,
             q = _rms_norm(x @ lp["wq_a"], lp["q_a_norm"], eps) @ lp["wq_b"]
     else:
         q = x @ lp["wq"]
+    if cfg.mla_q_scale != 1.0:          # LongCat's mla_scale_q_lora
+        q = (q.astype(jnp.float32) * cfg.mla_q_scale).astype(q.dtype)
     q = q.reshape(B, S, nh, dn + dr)
     q_nope, q_pe = q[..., :dn], q[..., dn:]
     inv_freq, att_scale = yarn_freqs(cfg)
@@ -261,6 +263,12 @@ def _mla_qkv(cfg: ModelConfig, lp: Dict[str, jnp.ndarray], h: jnp.ndarray,
 
     ckv = x @ lp["wkv_a"]                                  # [B,S,dkv+dr]
     c_kv = _rms_norm(ckv[..., :dkv], lp["kv_a_norm"], eps)
+    if cfg.mla_kv_scale != 1.0:
+        # LongCat's mla_scale_kv_lora: the cache holds the SCALED latent,
+        # so keys and values both expand from it as published
+        # (multiplied in float32: bfloat16 holds sqrt(12) to 0.13 % only)
+        c_kv = (c_kv.astype(jnp.float32)
+                * cfg.mla_kv_scale).astype(c_kv.dtype)
     k_pe = rope_interleaved(ckv[..., dkv:], positions, cfg.rope_theta,
                             inv_freq=inv_freq, scale=att_scale,
                             interleaved=cfg.rope_interleave)
@@ -645,7 +653,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     ``moe_grouped`` kernel. Any other non-None impl is ignored
     (the XLA paths serve), matching gemma's marker pattern."""
     from dynamo_tpu.models.moe import (grouped_on_chip, split_experts,
-                                       sum_aux)
+                                       sum_aux, token_slots)
     from dynamo_tpu.ops.pallas.mla_decode import supports as mla_supports
 
     use_pallas = (getattr(attn_impl, "pallas_paged_kernel", False)
@@ -654,7 +662,6 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     starts = packed_rows(packed, new_lens)
     with jax.named_scope("embed"):
         h = params["embed"][tokens]
-    B, S = tokens.shape
     aux = {}
 
     def body(moe, experts=None, **moe_kw):
@@ -677,10 +684,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             (params["dense_layers"], jnp.arange(K)))
     if "moe_layers" in params:
         scanned, experts = split_experts(cfg, params["moe_layers"])
-        # slots that hold no token (padding of either step form, dead
-        # rows of a fused block) route to no expert
-        valid = (jnp.arange(S) < jnp.sum(new_lens) if packed else
-                 (jnp.arange(S)[None, :] < new_lens[:, None]).reshape(B * S))
+        valid = token_slots(tokens, new_lens, packed)
         (h, pages), aux = jax.lax.scan(
             body(True, experts, valid=valid,
                  use_pallas=grouped_on_chip(attn_impl)), (h, pages),

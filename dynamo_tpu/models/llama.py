@@ -54,7 +54,9 @@ def _head_rms_norm(x: jnp.ndarray, w: jnp.ndarray, eps: float) -> jnp.ndarray:
 
 def make_pages(cfg: ModelConfig, num_pages: int, page_size: int,
                dtype=None) -> jnp.ndarray:
-    """Stacked paged KV cache: [L, N, 2, Hkv, page_size, Dh].
+    """Stacked paged KV cache: [L, N, 2, Hkv, page_size, Dh], ``L`` the
+    model's cache layers (its layers, or two a layer for a family with two
+    attention blocks in each: ``cfg.num_cache_layers``).
 
     Page-major: one page is a contiguous slab carrying K AND V for all kv
     heads, so page-granular DMAs (Pallas decode kernel, disagg block
@@ -64,7 +66,7 @@ def make_pages(cfg: ModelConfig, num_pages: int, page_size: int,
     hand out pages starting at index 1.
     """
     dtype = dtype or jnp.dtype(cfg.dtype)
-    return jnp.zeros((cfg.num_layers, num_pages, 2, cfg.num_kv_heads,
+    return jnp.zeros((cfg.num_cache_layers, num_pages, 2, cfg.num_kv_heads,
                       page_size, cfg.head_dim), dtype=dtype)
 
 

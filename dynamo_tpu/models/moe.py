@@ -53,7 +53,8 @@ def _unsort(order: jnp.ndarray, values: jnp.ndarray) -> jnp.ndarray:
 
 def grouped_experts(xt: jnp.ndarray, top_w: jnp.ndarray,
                     top_i: jnp.ndarray, w_gate, w_up, w_down, *,
-                    layer=None, valid=None, use_pallas: bool = False
+                    layer=None, valid=None, use_pallas: bool = False,
+                    first_expert: int = 0, num_routed: Optional[int] = None
                     ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """The exact expert layer every MoE family runs (routing-agnostic: the
     caller brings its gate's ``top_w``/``top_i``). The ``T * k``
@@ -79,18 +80,45 @@ def grouped_experts(xt: jnp.ndarray, top_w: jnp.ndarray,
     reads only the experts that own a row; otherwise ``lax.ragged_dot``,
     the plain form (the CPU, meshes, widths the kernel cannot tile).
 
+    A pick is one of three things. The weights hold the ``E`` experts
+    ``first_expert .. first_expert + E`` of the router's ``num_routed``
+    computing experts (default: all of them, from 0): a pick among those
+    is computed here; a pick of another computing expert is held
+    elsewhere and adds nothing here (one rank's share of an
+    expert-parallel layer, without its exchange); a pick at or above
+    ``num_routed`` is a zero-compute expert and adds ``w * x`` in the
+    combine, in float32, with no row, no fetch and no FLOP. Both kinds
+    that are not computed here sort behind every group, where an
+    unrouted slot's already go. Of a token's ``k`` distinct picks at most
+    ``min(k, E)`` are held, so the static row bound of the grouped call
+    is ``T * min(k, E)``.
+
     Returns ``(out [T, H] float32, aux)``; ``aux`` holds the counts the
-    step programs hand on: ``moe_experts_touched`` (experts with at least
-    one row) and ``moe_assignments`` (routed assignments)."""
+    step programs hand on: ``moe_experts_touched`` (held experts with at
+    least one row), ``moe_assignments`` (every pick of a valid token),
+    ``moe_held_assignments`` (picks computed here) and
+    ``moe_zero_assignments`` (picks of zero-compute experts)."""
     T, H = xt.shape
     k = top_i.shape[1]
     E = w_gate.shape[-3]
     A = T * k
     i32 = jnp.int32
-    flat_e = top_i.reshape(A).astype(i32)
-    if valid is not None:
+    picked = top_i.reshape(A).astype(i32)
+    live = None if valid is None else jnp.repeat(valid, k)
+    whole = first_expert == 0 and num_routed is None
+    is_zero = None
+    if whole:
+        flat_e = picked
+    else:
+        local = picked - first_expert
+        flat_e = jnp.where((local >= 0) & (local < E), local, E)
+        if num_routed is not None:
+            is_zero = picked >= num_routed
+            if live is not None:
+                is_zero &= live
+    if live is not None:
         # an unrouted assignment sorts behind every expert's
-        flat_e = jnp.where(jnp.repeat(valid, k), flat_e, E)
+        flat_e = jnp.where(live, flat_e, E)
     with jax.named_scope("sort"):
         order = jnp.argsort(flat_e, stable=True).astype(i32)
         sorted_e = flat_e[order]
@@ -101,8 +129,17 @@ def grouped_experts(xt: jnp.ndarray, top_w: jnp.ndarray,
         first = jnp.searchsorted(sorted_e, jnp.arange(E + 1, dtype=i32),
                                  method="compare_all").astype(i32)
         counts = first[1:] - first[:-1]                     # [E]
+    if whole:               # every pick of a valid token is computed here
+        picks = first[E]
+    else:
+        picks = (jnp.asarray(A, i32) if live is None
+                 else jnp.sum(live).astype(i32))
     aux = {"moe_experts_touched": jnp.sum(counts > 0).astype(i32),
-           "moe_assignments": first[E]}
+           "moe_assignments": picks,
+           "moe_held_assignments": first[E],
+           "moe_zero_assignments": (jnp.sum(is_zero).astype(i32)
+                                    if is_zero is not None
+                                    else jnp.zeros((), i32))}
     if w_gate.ndim == 3:        # one layer's experts: a stack of one
         w_gate, w_up, w_down = w_gate[None], w_up[None], w_down[None]
         layer = 0
@@ -115,7 +152,8 @@ def grouped_experts(xt: jnp.ndarray, top_w: jnp.ndarray,
         # are few and most groups hold a row or two; 128 (the MXU's edge)
         # once groups are long enough to fill them
         tm = 16 if A <= 2048 else 128
-        n_tiles = -(-A // tm) + min(E, A)       # bound on sum ceil(c / tm)
+        held = T * min(k, E)                    # bound on sum c
+        n_tiles = -(-held // tm) + min(E, held)  # ... on sum ceil(c / tm)
         M = n_tiles * tm
         with jax.named_scope("sort"):
             tiles = -(-counts // tm)                        # [E]
@@ -163,6 +201,12 @@ def grouped_experts(xt: jnp.ndarray, top_w: jnp.ndarray,
         y = jnp.where(routed, ys[jnp.minimum(pos, ys.shape[0] - 1)]
                       .reshape(T, k, H), 0.0)
         out = jnp.sum(y * top_w.astype(jnp.float32)[..., None], axis=1)
+        if is_zero is not None:
+            # the identity experts: the token itself times their weights
+            zero_w = jnp.where(is_zero.reshape(T, k),
+                               top_w.astype(jnp.float32), 0.0)
+            out = out + (jnp.sum(zero_w, axis=1, keepdims=True)
+                         * xt.astype(jnp.float32))
     return out, aux
 
 
@@ -333,6 +377,17 @@ def split_experts(cfg: ModelConfig, layers: Dict[str, jnp.ndarray]):
             {k: layers[k] for k in EXPERT_LEAVES})
 
 
+def token_slots(tokens: jnp.ndarray, new_lens: jnp.ndarray,
+                packed: bool) -> jnp.ndarray:
+    """``[B * S]`` bool: the slots of a step that hold a token. The others
+    (padding of either step form, dead rows of a fused block) route to no
+    expert: ``grouped_experts``'s ``valid``."""
+    B, S = tokens.shape
+    if packed:
+        return jnp.arange(S) < jnp.sum(new_lens)
+    return (jnp.arange(S)[None, :] < new_lens[:, None]).reshape(B * S)
+
+
 def sum_aux(aux: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
     """Per-layer (or per-step) counts stacked by a scan -> their sums."""
     return {k: jnp.sum(v.astype(jnp.int32)) for k, v in aux.items()}
@@ -399,10 +454,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     starts = packed_rows(packed, new_lens)
     with jax.named_scope("embed"):
         h = params["embed"][tokens]
-    B, S = tokens.shape
-    # slots that hold no token route to no expert
-    valid = (jnp.arange(S) < jnp.sum(new_lens) if packed
-             else (jnp.arange(S)[None, :] < new_lens[:, None]).reshape(B * S))
+    valid = token_slots(tokens, new_lens, packed)
     scanned, experts = split_experts(cfg, params["layers"])
     kw = (dict(valid=valid, use_pallas=grouped_on_chip(attn_impl))
           if experts else {})

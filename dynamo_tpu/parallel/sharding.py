@@ -42,6 +42,14 @@ class ModelSharding:
     def __init__(self, cfg: ModelConfig, mesh: Mesh):
         self.cfg = cfg
         self.mesh = mesh
+        if cfg.attn_blocks_per_layer != 1:
+            # models/longcat.py: its pytree (attn0/attn1/ffn0/ffn1 and a
+            # held range of experts) has no specs here, and the MLA specs
+            # below would place another family's leaves
+            raise NotImplementedError(
+                f"no sharding specs for {cfg.model_type!r} (two attention "
+                "blocks a layer); it is served on one chip as one rank of "
+                "its expert-parallel deployment (ep_rank of ep_size)")
         tp = mesh.shape.get("tp", 1)
         ep = mesh.shape.get("ep", 1)
         if tp > 1:

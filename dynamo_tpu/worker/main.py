@@ -223,7 +223,11 @@ def build_engine(args: argparse.Namespace, startup=None) -> JaxEngine:
         if cfg.num_experts:
             attrs["moe.experts"] = (
                 cfg.moe_backend if cfg.moe_backend != "grouped" else
-                f"grouped[E={cfg.num_experts},k={cfg.num_experts_per_tok}]")
+                f"grouped[E={cfg.num_experts},k={cfg.num_experts_per_tok}]"
+                + (f"[held={cfg.expert_offset}+{cfg.experts_held}]"
+                   if cfg.ep_size > 1 else "")
+                + (f"[zero={cfg.zero_expert_num}]"
+                   if cfg.zero_expert_num else ""))
         engine = JaxEngine(cfg, params, engine_cfg, forward_fn=forward_fn)
         # the form of the prefill-carrying steps: what
         # dynamo_worker_prefill_steps_total{form} will count

@@ -160,16 +160,24 @@ class EngineDispatchCollector:
                        "queue under page pressure (their prompt is computed "
                        "again on re-admission, less what the prefix cache "
                        "kept)",
-        "moe_assignments": "Token-to-expert assignments the grouped expert "
-                           "layer computed (tokens x experts per token x "
-                           "expert layers; slots that hold no token route "
-                           "nowhere and are not counted)",
+        "moe_assignments": "Token-to-expert assignments the router made "
+                           "(tokens x experts per token x expert layers; "
+                           "slots that hold no token route nowhere and are "
+                           "not counted)",
+        "moe_held_assignments": "Assignments to an expert this worker "
+                                "holds: the ones its grouped expert layer "
+                                "computed (all of them unless the model "
+                                "directory names a rank of an "
+                                "expert-parallel deployment)",
+        "moe_zero_assignments": "Assignments to a zero-compute expert: "
+                                "the token itself times the weight, no "
+                                "row, no fetch, no FLOP",
         "moe_experts_touched": "Experts with at least one assignment, "
                                "summed over expert layers and forward "
                                "passes: what the grouped layer read",
         "moe_expert_slots": "Experts the grouped layer could have read: "
-                            "forward passes x expert layers x experts (the "
-                            "denominator of the touched share)",
+                            "forward passes x expert layers x experts "
+                            "held (the denominator of the touched share)",
         "gen_tokens_revealed": "Generation by diffusion over blocks: "
                                "masked positions the passes revealed "
                                "(tokens revealed / row-passes = tokens a "
@@ -450,6 +458,8 @@ def engine_dispatch_stats(engine) -> Dict[str, object]:
     gen = getattr(engine, "gen_counts", None) or {}
     return {
         "moe_assignments": float(moe.get("moe_assignments", 0)),
+        "moe_held_assignments": float(moe.get("moe_held_assignments", 0)),
+        "moe_zero_assignments": float(moe.get("moe_zero_assignments", 0)),
         "moe_experts_touched": float(moe.get("moe_experts_touched", 0)),
         "moe_expert_slots": float(moe.get("moe_expert_slots", 0)),
         "decode_dispatches": float(getattr(engine, "decode_dispatches", 0)),

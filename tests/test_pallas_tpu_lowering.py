@@ -509,6 +509,43 @@ def test_grouped_expert_layer_compiles_in_the_tpu_compiler(tokens):
                 or f"bf16[{E},{I},{H}]" in ln]
 
 
+@pytest.mark.parametrize("tokens,rows", [(128, 1792), (512, 8192)])
+def test_grouped_layer_with_a_held_range_compiles_in_the_tpu_compiler(
+        tokens, rows):
+    """``grouped_experts`` as one rank of an expert-parallel layer, at the
+    widths of the benchmark's LongCat-Flash cell (16 held of 512 experts of
+    6,144 x 2,048, a 768-wide router with 256 zero-compute experts, top-12,
+    four layers stacked) and its two shapes: 128 decode rows (16-row tiles)
+    and the 512 slots of its packed step (128-row tiles). The kernel's rows
+    are the bound a held range can receive (``T x min(k, held)`` and a tile
+    a held expert: what ``benchmarks/longcat_cost.grouped_rows`` counts),
+    the held stacks are its operands as they are, and nothing scatters."""
+    from dynamo_tpu.models.moe import grouped_experts
+
+    one_chip = _v5e_chip()
+    E, H, I, k, layers = 16, 6144, 2048, 12, 4
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def share_of_a_layer(xt, top_w, top_i, wg, wu, wd, layer, valid):
+        return grouped_experts(xt, top_w, top_i, wg, wu, wd, layer=layer,
+                               valid=valid, use_pallas=True,
+                               first_expert=0, num_routed=512)
+
+    text = jax.jit(share_of_a_layer).lower(
+        sds((tokens, H), jnp.bfloat16), sds((tokens, k), jnp.float32),
+        sds((tokens, k), jnp.int32), sds((layers, E, H, I), jnp.bfloat16),
+        sds((layers, E, H, I), jnp.bfloat16),
+        sds((layers, E, I, H), jnp.bfloat16), sds((), jnp.int32),
+        sds((tokens,), jnp.bool_)).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "%moe_grouped" in calls[0]
+    assert f"f32[{rows},{H}]" in calls[0]
+    assert calls[0].count(f"bf16[{layers},{E},") == 3
+    assert not [ln for ln in text.splitlines() if " scatter(" in ln]
+
+
 # -- generation by diffusion over blocks (SDAR): the block-wise visibility --
 
 @pytest.mark.parametrize("S", [4, 512])
