@@ -516,6 +516,9 @@ class JaxEngine(ScheduledEngineBase):
         # dispatch tap the M-tokens-cost-<=M/N+c regression guard counts
         # (dynamo_worker_decode_dispatches_total samples these at scrape)
         self._jit_ms: Dict[int, Callable] = {}
+        # from a mixed step's packed output to the carry of the block
+        # chained behind it (``_handover_impl``), made on first use
+        self._jit_handover: Optional[Callable] = None
         self.decode_dispatches = 0   # decode-family jitted dispatches
         self.multistep_blocks = 0    # of which fused multi-step blocks
         self.mixed_steps = 0         # mixed prefill+decode dispatches
@@ -1222,6 +1225,46 @@ class JaxEngine(ScheduledEngineBase):
         return (pages, jnp.moveaxis(steps, 0, 1), carry,
                 {k: jnp.sum(v.astype(jnp.int32)) for k, v in aux.items()})
 
+    def _handover_impl(self, prev_packed, rows, stop_ids):
+        """From a mixed step's packed output to the carry a fused block
+        starts from, on the device: the block's first token, liveness,
+        positions and budgets without the step's result ever being on the
+        host. ``rows`` ``[5, B]`` int32 is the host's one upload: for each
+        row of the block the row of ``prev_packed`` that holds its token,
+        its position and total length at block start, its token budget
+        and its outstanding ``min_tokens`` gate, the token in flight
+        counted in all four (``Scheduler.plan_multistep_behind``). A row
+        lives unless that token is one of its ``stop_ids`` with the gate
+        passed, or spent its budget: the two rules ``_accept_token``
+        applies on the host when the step's result arrives. Pad rows
+        carry budget 0. Returns the keys of ``_multistep_impl``'s carry
+        that a chained block reads."""
+        src, pos, total, budget, min_gate = rows
+        tok = prev_packed[src, :1]                          # [B, 1] int32
+        hit = jnp.any(stop_ids == tok, axis=1)
+        alive = (budget > 0) & ~(hit & (min_gate <= 0))
+        return {"tok": tok, "pos": pos[:, None], "total": total,
+                "alive": alive, "budget": budget, "min_gate": min_gate}
+
+    def _replicated(self):
+        """The mesh's fully replicated sharding where the page pool is
+        sharded over one, else None."""
+        if self.cfg.mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+            if isinstance(self.pages.sharding, NamedSharding):
+                return NamedSharding(self.cfg.mesh, PartitionSpec())
+        return None
+
+    def _get_jit_handover(self):
+        fn = self._jit_handover
+        if fn is None:
+            # on a mesh its outputs are replicated like a block's carry,
+            # so the block program sees a chained block's arguments
+            rep = self._replicated()
+            kw = {} if rep is None else {"out_shardings": rep}
+            fn = self._jit_handover = jax.jit(self._handover_impl, **kw)
+        return fn
+
     def _get_jit_multistep(self, w: int):
         fn = self._jit_ms.get(w)
         if fn is None:
@@ -1236,17 +1279,9 @@ class JaxEngine(ScheduledEngineBase):
             # resharding here would either break donation or ship a
             # sharded packed buffer the host cannot np.asarray.
             kw = {}
-            if self.cfg.mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec
-                if isinstance(self.pages.sharding, NamedSharding):
-                    rep = NamedSharding(self.cfg.mesh, PartitionSpec())
-                    pages_sh = self.pages.sharding
-                    carry_sh = {k: rep for k in ("tok", "pos", "total",
-                                                 "alive", "budget",
-                                                 "min_gate", "pids",
-                                                 "pcnt", "pctx", "pbias",
-                                                 "pn", "gstate")}
-                    kw["out_shardings"] = (pages_sh, rep, carry_sh, rep)
+            rep = self._replicated()
+            if rep is not None:
+                kw["out_shardings"] = (self.pages.sharding, rep, rep, rep)
             fn = jax.jit(functools.partial(self._multistep_impl, n_steps=w),
                          donate_argnums=(1,), **kw)
             self._jit_ms[w] = fn
@@ -1582,15 +1617,9 @@ class JaxEngine(ScheduledEngineBase):
             handle = self.dispatch_decode(plan)
             with stage("wait"):
                 return self.fetch_packed(handle)
-        with stage("assemble"):
-            kind, chunks, arrays = self._prefill_arrays(plan, mixed)
-        plan._step_id = self._step_counter
-        if self.step_tap is not None:
-            self.step_tap(kind, arrays, self._step_counter)
-        packed = self._invoke_step(kind, arrays, self._step_counter)
-        self._step_counter += 1
-        if (self.step_tap is None
-                and not any(c.is_last for c in chunks)):
+        packed, arrays = self._dispatch_prefill(plan, mixed)
+        if (self.step_tap is None and not mixed
+                and not any(c.is_last for c in plan.chunks)):
             # No row samples a token this step (intermediate chunks of long
             # prompts): skip the device->host readback, one fetch less per
             # chunk of TTFT (cost on the chip: not measured); _process never
@@ -1604,6 +1633,18 @@ class JaxEngine(ScheduledEngineBase):
             return np.zeros(B, np.int64), np.zeros(B, np.float32), None
         with stage("wait"):
             return self.fetch_packed(packed)
+
+    def _dispatch_prefill(self, plan, mixed: bool):
+        """Dispatch one prefill-carrying step without fetching its
+        result: (the device's packed output, the host arrays it ran on)."""
+        with stage("assemble"):
+            kind, _chunks, arrays = self._prefill_arrays(plan, mixed)
+        plan._step_id = self._step_counter
+        if self.step_tap is not None:
+            self.step_tap(kind, arrays, self._step_counter)
+        packed = self._invoke_step(kind, arrays, self._step_counter)
+        self._step_counter += 1
+        return packed, arrays
 
     def _prefill_arrays(self, plan, mixed: bool):
         """Host arrays for one prefill-carrying step (token-packed, padded
@@ -1887,7 +1928,22 @@ class JaxEngine(ScheduledEngineBase):
                       "top_lps": hostf[:, 2 + K:]}
         return sampled, logprobs, extras
 
+    def dispatch_step(self, plan):
+        """Dispatch one mixed step WITHOUT fetching its result; returns
+        the on-device packed output, for ``fetch_packed`` and for the
+        block chained behind it (``dispatch_multistep``)."""
+        return self._dispatch_prefill(plan, True)[0]
+
     # -- fused multi-step decode (loop.py hooks) ---------------------------
+
+    @property
+    def supports_step_chain(self) -> bool:
+        # wherever a fused block can follow a mixed step: both forms of
+        # the step end in ``_sample_tail``'s packed rows, chunk rows then
+        # decode rows, and the hand-over reads column 0 of either (a
+        # block-diffusion engine plans no mixed step: it admits with
+        # prefill steps)
+        return self.supports_multistep
 
     @property
     def supports_multistep(self) -> bool:
@@ -2108,8 +2164,12 @@ class JaxEngine(ScheduledEngineBase):
         """Dispatch one fused block of ``plan.width`` decode steps;
         returns the opaque (packed block, device carry) handle without
         blocking. A chained block takes its first token / position /
-        liveness / budgets from the previous block's on-device carry —
-        only the (possibly grown) page table re-uploads."""
+        liveness / budgets from the device — only the (possibly grown)
+        page table re-uploads: behind a block (``prev_handle`` that
+        block's handle) from its carry, behind a mixed step
+        (``plan.behind == "mixed"``, ``prev_handle`` the step's packed
+        output, still being computed) from ``_handover_impl`` over that
+        output, in front of the same block program."""
         if self.gen_block > 1:
             return self._dispatch_passes(plan, prev_handle)
         seqs = plan.seqs
@@ -2119,8 +2179,13 @@ class JaxEngine(ScheduledEngineBase):
         _table_np, table = self._table_arrays(seqs, B)
         samp = self._device_sampling(seqs, B)
         pcarry = None
-        if prev_handle is not None:
+        if plan.behind == "mixed":
+            c = self._handover(plan, prev_handle, B, samp["stop_ids"])
+        elif prev_handle is not None:
             c = prev_handle[1]
+        else:
+            c = None
+        if c is not None:
             tok, pos, total, alive = c["tok"], c["pos"], c["total"], c["alive"]
             budget, min_gate = c["budget"], c["min_gate"]
             if samp["needs_pcarry"]:
@@ -2152,8 +2217,7 @@ class JaxEngine(ScheduledEngineBase):
         _fresh = _ckey not in self._jit_seen
         _t0 = time.perf_counter() if _fresh else 0.0
         with stage("upload"):
-            # (a chained block's are the previous block's carry: on the
-            # device already)
+            # (a chained block's are on the device already)
             tok, pos, total, alive, budget, min_gate = (
                 jnp.asarray(x)
                 for x in (tok, pos, total, alive, budget, min_gate))
@@ -2175,6 +2239,30 @@ class JaxEngine(ScheduledEngineBase):
             self._mark_compile(_ckey, "multistep", B, w,
                                time.perf_counter() - _t0)
         return (packed_block, carry)
+
+    def _handover(self, plan, prev_packed, B: int, stop_ids) -> dict:
+        """The carry of a block chained behind a mixed step, from the
+        step's packed output (``_handover_impl``): one upload, one small
+        program enqueued behind the step."""
+        with stage("assemble"):
+            n = len(plan.seqs)
+            rows = np.zeros((5, B), np.int32)
+            rows[2] = 1                # pad rows: 1 garbage-page token
+            start = np.asarray(plan.start_lens, np.int32)
+            rows[:, :n] = (plan.src_rows, start - 1, start, plan.budgets,
+                           plan.min_gates)
+        fn = self._get_jit_handover()
+        _ckey = (id(fn), prev_packed.shape, B, stop_ids.shape)
+        _fresh = _ckey not in self._jit_seen
+        _t0 = time.perf_counter() if _fresh else 0.0
+        with stage("upload"):
+            rows = jnp.asarray(rows)
+        with stage("enqueue"):
+            carry = fn(prev_packed, rows, stop_ids)
+        if _fresh:
+            self._mark_compile(_ckey, "multistep", B, 0,
+                               time.perf_counter() - _t0)
+        return carry
 
     # -- generation by diffusion over blocks -------------------------------
 

@@ -239,6 +239,16 @@ class ScheduledEngineBase(EngineBase):
     def fetch_packed_block(self, handle):           # pragma: no cover - hook
         raise NotImplementedError
 
+    # Optional hooks for chaining a fused block BEHIND a mixed step
+    # (JaxEngine implements): dispatch_step enqueues a mixed step and
+    # returns its on-device packed output without blocking (fetch_packed
+    # blocks on it); dispatch_multistep then takes that output as
+    # ``prev_handle`` for a plan whose ``behind`` is ``"mixed"``.
+    supports_step_chain = False
+
+    def dispatch_step(self, plan):                  # pragma: no cover - hook
+        raise NotImplementedError
+
     def drain_compile_events(self) -> List[dict]:
         """Buffered first-call jit-compile events since the last drain
         (``{"kind", "batch", "width", "seconds"}`` dicts). The jit engine
@@ -249,7 +259,7 @@ class ScheduledEngineBase(EngineBase):
 
     def _stamp_dispatch(self, kind: str, plan, dispatch,
                         plan_ms: float = 0.0, fallback: str = "",
-                        chained: bool = False):
+                        chained: bool = False, chained_behind: str = ""):
         """Stamp one dispatch into the step ring, from its finished
         ``dispatch`` phase: queue/pool pressure at plan time,
         real-vs-padded tokens and the program's name (``last_padded`` /
@@ -302,7 +312,8 @@ class ScheduledEngineBase(EngineBase):
             pool_pinned=mgr.pinned_pages if mgr is not None else 0,
             plan_ms=plan_ms, dispatch_ms=dispatch.ms,
             gap_ms=gap_ms, fallback=fallback, chained=chained,
-            enqueue=dispatch.t0, experts=self.last_moe_counts,
+            chained_behind=chained_behind, enqueue=dispatch.t0,
+            experts=self.last_moe_counts,
             decode_kernel_rows=self.last_decode_kernel_rows,
             phase=dispatch)
         self.last_padded = None
@@ -872,12 +883,18 @@ class ScheduledEngineBase(EngineBase):
             self.step_outcome_cb(getattr(plan, "_step_id", None), False)
 
     async def _loop_body(self) -> None:
-        # pending = a dispatched decode step whose results are still on
-        # device: (plan, handle). While it is in flight the scheduler may
-        # plan the NEXT decode step chained to its on-device tokens; the
-        # host then fetches the pending step's results while the chained
-        # step executes — the device->host readback is fully hidden in
-        # steady-state decode (VERDICT r2 item 2).
+        # pending = a dispatched step whose results are still on device:
+        # (plan, handle). While it is in flight the scheduler may plan
+        # the NEXT dispatch chained to its on-device tokens — a decode
+        # step behind a decode step, a fused block behind a fused block,
+        # and a fused block behind the mixed step that ends an admission
+        # run (``Scheduler.chains_behind``: such a step returns at its
+        # enqueue like the decode kinds; every other mixed step is
+        # resolved inside its dispatch, as prefill and spec steps are).
+        # The host then fetches and processes the pending step's results
+        # while the chained dispatch executes — the device->host
+        # readback and the whole host turn between the two programs are
+        # hidden behind the device's work (VERDICT r2 item 2).
         #
         # Every phase of the loop runs under ``st.phase`` (the one
         # stamping helper, engine/steptrace.py): host-clock stamps for the
@@ -925,6 +942,9 @@ class ScheduledEngineBase(EngineBase):
                         chained = (
                             self.scheduler.plan_multistep_chained(prev_plan)
                             if self.supports_multistep else None)
+                    elif isinstance(prev_plan, MixedStepBatch):
+                        chained = self.scheduler.plan_multistep_behind(
+                            prev_plan)
                     else:
                         chained = (self.scheduler.plan_chained(prev_plan)
                                    if self.supports_pipelining else None)
@@ -947,8 +967,10 @@ class ScheduledEngineBase(EngineBase):
                             self._fail_plan(prev_plan, e2)
                         self._fail_plan(chained, e)
                         continue
-                    self._stamp_dispatch(kind, chained, dispatch,
-                                         plan_ms=planning.ms, chained=True)
+                    self._stamp_dispatch(
+                        kind, chained, dispatch, plan_ms=planning.ms,
+                        chained=True,
+                        chained_behind=getattr(chained, "behind", ""))
                     pending = (chained, handle)
                     # overlap: unpack step/block N (streaming its tokens
                     # out) while N+1 runs on device
@@ -972,6 +994,11 @@ class ScheduledEngineBase(EngineBase):
                         reason = self.multistep_unsupported_reason
                         if reason is not None:
                             self.scheduler.record_fallback(reason, plan.seqs)
+                # a mixed step the next block can chain behind returns at
+                # its enqueue
+                chains = (isinstance(plan, MixedStepBatch)
+                          and self.supports_step_chain
+                          and self.scheduler.chains_behind(plan))
             if plan is None:
                 self._work.clear()
                 if self.scheduler.waiting:
@@ -1000,6 +1027,8 @@ class ScheduledEngineBase(EngineBase):
                                         self.dispatch_multistep, (ms, None))
             elif isinstance(plan, DecodeBatch) and self.supports_pipelining:
                 kind, fn, args = "decode", self.dispatch_decode, (plan,)
+            elif chains:
+                kind, fn, args = "mixed", self.dispatch_step, (plan,)
             else:
                 asynchronous = False
                 if isinstance(plan, SpecDecodeBatch):

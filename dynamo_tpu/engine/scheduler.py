@@ -198,8 +198,11 @@ class MultiStepBatch:
     the outstanding ``min_tokens`` requirement at block start — the device
     stop check consumes them (rows past their stop are masked to no-ops so
     finished sequences stop writing KV). ``chained`` marks a block whose
-    first input token/position/liveness come from the previous block's
-    on-device carry instead of host arrays."""
+    first input token/position/liveness come from the device instead of
+    host arrays, and ``behind`` says from what: ``"block"``, the previous
+    block's carry, or ``"mixed"``, the packed output of the
+    prefill-carrying step still in flight — then ``src_rows[i]`` is the
+    row of that output that holds row i's first token."""
 
     seqs: List[Sequence]
     width: int
@@ -207,6 +210,8 @@ class MultiStepBatch:
     start_lens: List[int] = field(default_factory=list)
     budgets: List[int] = field(default_factory=list)
     min_gates: List[int] = field(default_factory=list)
+    behind: str = ""
+    src_rows: List[int] = field(default_factory=list)
 
     # mirrors the other plan kinds' diagnostic slot (set by the engine)
     _step_id: Optional[int] = None
@@ -348,6 +353,15 @@ class SchedulerConfig:
 # of them in the end does not fill a step ("partial")
 RUN_ENDS = ("queue", "rows", "pages", "partial")
 
+# why a fused block was not chained behind the prefill-carrying (mixed)
+# step in front of it: the step is not its run's last ("run"), the rows
+# of the block cannot be told before the step's result ("rows": one is
+# cancelled, or runs outside the step), a row's device penalty window or
+# guided automaton state is built on the host from tokens that include
+# the one in flight ("pcarry"), or the block planner refused ("budget",
+# "pages")
+CHAIN_REFUSALS = ("run", "rows", "pcarry", "budget", "pages")
+
 
 def _penalized(so) -> bool:
     """Does the row keep a device penalty window (penalties or a bias)?"""
@@ -415,6 +429,21 @@ class Scheduler:
         # why the last admission pass stopped (one of RUN_ENDS), and the
         # pass of the run under way
         self._admit_stop = self._run_stop = "queue"
+        # fused blocks whose first tokens came from the device, by what
+        # they were chained behind, and the chains behind a mixed step
+        # that were refused, by reason (``CHAIN_REFUSALS``)
+        # (dynamo_worker_multistep_chained_total{behind},
+        # dynamo_worker_multistep_chain_refused_total{reason})
+        self.chained_blocks: Dict[str, int] = {"block": 0, "mixed": 0}
+        self.chain_refusals: Dict[str, int] = dict.fromkeys(
+            CHAIN_REFUSALS, 0)
+
+    def record_chain_refusal(self, reason: str, seqs=()) -> None:
+        """Count one chain behind a mixed step that was not taken (the
+        block that follows is built from host state, as it always was);
+        ``seqs`` is ``record_fallback``'s, unused: no row leaves the
+        fused path here."""
+        self.chain_refusals[reason] = self.chain_refusals.get(reason, 0) + 1
 
     def record_fallback(self, reason: str, seqs=()) -> None:
         """Count one fused-path refusal; also stamp the sequences it
@@ -782,7 +811,12 @@ class Scheduler:
         upgrades to a fused multi-step block, and completions free rows
         and pages for the next run. Where no queue stands behind a mixed
         step the run is one step long and the plans alternate mixed /
-        pure-decode. With ``mixed_batch`` off, the
+        pure-decode. Behind a run's last step the loop does not come
+        back here for that pure-decode plan where the block can be
+        chained on the device (``chains_behind``,
+        ``plan_multistep_behind``: the same rows in the same order,
+        planned while the step runs; this method's bookkeeping for the
+        plan is done there). With ``mixed_batch`` off, the
         legacy prefill-XOR-decode alternation applies, except that a deep
         waiting queue may take up to ``decode_progress_every - 1``
         consecutive prefill steps (burst TTFT) before a decode step is
@@ -790,10 +824,13 @@ class Scheduler:
         latency under sustained arrivals."""
         plan = self._next_plan()
         if self._run_steps and not isinstance(plan, MixedStepBatch):
-            # the run of mixed steps is over: count it by what bounded it
-            self.admission_runs[self._run_stop] += 1
-            self._run_steps = 0
+            self._end_run()
         return plan
+
+    def _end_run(self) -> None:
+        """The run of mixed steps is over: count it by what bounded it."""
+        self.admission_runs[self._run_stop] += 1
+        self._run_steps = 0
 
     def _next_plan(self) -> Optional[StepPlan]:
         self._chain_run = 0
@@ -1167,9 +1204,15 @@ class Scheduler:
                     and len(seq) >= self.max_context_hint))
 
     def _plan_block(self, seqs: List[Sequence], start_lens: List[int],
-                    chained: bool) -> Optional[MultiStepBatch]:
+                    behind: str = "") -> Optional[MultiStepBatch]:
         """Compute the fuse width for one block over ``seqs`` and allocate
-        its pages, or None to fall back to the per-step path.
+        its pages, or None to fall back to the per-step path. ``behind``
+        names what a chained block follows (``MultiStepBatch.behind``).
+        Behind a mixed step every row can still run when the block
+        starts (a prompt whose last chunk rides that step is not RUNNING
+        yet), and a refusal is a chain not taken
+        (``record_chain_refusal``), not a fallback: the block is planned
+        again from host state.
 
         The width is the configured cap (``decode_multistep``), narrowed
         to what the row with the MOST tokens left can still use
@@ -1193,21 +1236,24 @@ class Scheduler:
         cap = self.cfg.decode_multistep
         if cap < 2:
             return None
+        refuse = (self.record_chain_refusal if behind == "mixed"
+                  else self.record_fallback)
         if self.cfg.spec_tokens > 0:
-            self.record_fallback("spec", seqs)
+            refuse("spec", seqs)
             return None
         w, most = cap, 0
         budgets: List[int] = []
         min_gates: List[int] = []
         for seq, sl in zip(seqs, start_lens):
-            if seq.phase is not Phase.RUNNING:
-                # chained only (``plan_multistep_chained`` let it in)
+            if behind != "mixed" and seq.phase is not Phase.RUNNING:
+                # behind a block only (``plan_multistep_chained`` let it
+                # in)
                 budgets.append(0)
                 min_gates.append(0)
                 continue
             reason, row_cap = self._fuse_gate(seq, sl)
             if reason is not None:
-                self.record_fallback(reason, seqs)
+                refuse(reason, seqs)
                 return None
             w = min(w, row_cap)
             sc = seq.request.stop_conditions
@@ -1223,7 +1269,7 @@ class Scheduler:
             budgets.append(min(rem, 1 << 20))  # int32-safe device budget
             min_gates.append(max(0, (sc.min_tokens or 0) - gen_eff))
         if most < 2:
-            self.record_fallback("budget", seqs)
+            refuse("budget", seqs)
             return None
         w = min(w, most)
         w = 1 << (w.bit_length() - 1)
@@ -1231,11 +1277,13 @@ class Scheduler:
                 seqs, start_lens, [min(w, b) for b in budgets]):
             w //= 2
         if w < 2:
-            self.record_fallback("pages", seqs)
+            refuse("pages", seqs)
             return None
-        return MultiStepBatch(seqs=list(seqs), width=w, chained=chained,
+        if behind:
+            self.chained_blocks[behind] += 1
+        return MultiStepBatch(seqs=list(seqs), width=w, chained=bool(behind),
                               start_lens=list(start_lens), budgets=budgets,
-                              min_gates=min_gates)
+                              min_gates=min_gates, behind=behind)
 
     def plan_multistep(self, batch: DecodeBatch) -> Optional[MultiStepBatch]:
         """Try to upgrade a planned decode step into a fused block.
@@ -1257,8 +1305,7 @@ class Scheduler:
             if any(s.phase is Phase.PREFILL for s in self.active.values()):
                 self.record_fallback("prefill", batch.seqs)
                 return None
-        return self._plan_block(batch.seqs, [len(s) for s in batch.seqs],
-                                chained=False)
+        return self._plan_block(batch.seqs, [len(s) for s in batch.seqs])
 
     def plan_multistep_chained(self, prev: MultiStepBatch
                                ) -> Optional[MultiStepBatch]:
@@ -1275,10 +1322,13 @@ class Scheduler:
         end of one stream does not stall the others, and the chain breaks
         where the next arrival is admitted anyway. Unlike
         ``plan_multistep``, the waiting/prefilling refusals survive the
-        mixed-batch gate lift ON PURPOSE: a chain break here is the block
-        boundary where arrivals get their admission/prefill (mixed) step
-        — it is not a fallback to per-step decode and is not counted as
-        one."""
+        mixed-batch gate lift ON PURPOSE: a chain of BLOCKS breaks where
+        arrivals get their admission/prefill (mixed) step (the host has
+        to see the completions that free their rows and pages) — it is
+        not a fallback to per-step decode and is not counted as one. The
+        other side of that boundary no longer breaks the device's chain:
+        the block behind the mixed step takes its first tokens from the
+        step's on-device output (``plan_multistep_behind``)."""
         if self.waiting:
             return None
         live = 0
@@ -1297,7 +1347,102 @@ class Scheduler:
             return self._plan_passes(prev.seqs, prev.inflight + prev.width)
         return self._plan_block(prev.seqs,
                                 [len(s) + prev.width for s in prev.seqs],
-                                chained=True)
+                                behind="block")
+
+    def _run_goes_on(self, step: MixedStepBatch) -> bool:
+        """Will ``_next_plan`` follow ``step``, once it is accounted for,
+        with another mixed step of the same run (its ``go_on`` rule, read
+        ahead)? A queue stands and what the run's admission pass took,
+        less this step's chunks, still fills a whole step. A prompt for
+        the ring reads as "goes on": the next plan is no decode plan
+        either way."""
+        if len(self.waiting) < self.cfg.max_prefill_seqs:
+            return False
+        rode = {id(c.seq): c.length for c in step.chunks}
+        rt = self.cfg.ring_threshold
+        left: List[int] = []
+        for s in sorted((s for s in self.active.values()
+                         if s.phase is Phase.PREFILL),
+                        key=lambda s: s.arrival):
+            rem = (self._prefill_target(s) - s.num_computed
+                   - rode.get(id(s), 0))
+            if rem <= 0:
+                continue
+            if rt is not None and rem > rt:
+                return True
+            left.append(rem)
+        return (sum(left[:self.cfg.max_prefill_seqs])
+                >= self.cfg.max_prefill_chunk)
+
+    def chains_behind(self, step: MixedStepBatch) -> bool:
+        """May the fused block that follows ``step`` be chained behind it
+        on the device: dispatched while the step runs, its first tokens
+        read from the step's packed output (``plan_multistep_behind``)?
+        Asked BEFORE the step is dispatched, from what the host knows
+        then, so that a step that does not chain is dispatched as it
+        always was. It may where the plan after it is the pure-decode
+        plan (the step is its run's last), every row of that plan rides
+        the step, and no row keeps per-token state the host builds (a
+        penalty window, a guided automaton: both would lack the token in
+        flight). A refusal is counted by reason (``CHAIN_REFUSALS``)."""
+        if self.cfg.decode_multistep < 2:
+            return False    # no block follows (a mixed step is only
+                            # planned for a causal model without drafts)
+        if self._run_goes_on(step):
+            self.record_chain_refusal("run")
+            return False
+        riding = {id(s) for s in step.seqs}
+        if (any(s.cancelled for s in step.seqs)
+                or any(s.phase is Phase.RUNNING and id(s) not in riding
+                       for s in self.active.values())):
+            self.record_chain_refusal("rows")
+            return False
+        for s in step.seqs:
+            so = s.request.sampling_options
+            if so.guided or _penalized(so):
+                self.record_chain_refusal("pcarry")
+                return False
+        return True
+
+    def plan_multistep_behind(self, step: MixedStepBatch
+                              ) -> Optional[MultiStepBatch]:
+        """Plan the fused block that follows the mixed ``step`` while the
+        step's result is still on the device (``chains_behind`` allowed
+        it, the step is dispatched and not yet accounted for).
+
+        Its rows are the rows the pure-decode plan would hold once the
+        step resolved, in that plan's order (by arrival): the step's
+        decode rows, and every prompt whose LAST chunk rides the step
+        (a prompt with an intermediate chunk stays out, as does a
+        ``prefill_only`` one, which ends at its first token). Each starts
+        one token past what the host holds (``len(seq) + 1``: the token
+        the step samples is counted in budgets, ``min_tokens`` gates,
+        widths and the pages grown up front, as ``plan_multistep_chained``
+        counts ``prev.width``), and ``src_rows`` maps it to its row of the
+        step's packed output, where the device reads that token and
+        decides whether the row still lives (``JaxEngine._handover_impl``).
+        A row the step's token ends rides the block dead from its start.
+        None where the block planner refuses (budget, pages: counted as
+        chain refusals); then nothing has changed and the loop resolves
+        the step and plans from host state. On success the scheduler
+        stands where ``schedule()`` would have left it after returning
+        the pure-decode plan: the admission run is over and counted."""
+        at = {id(c.seq): i for i, c in enumerate(step.chunks)
+              if c.is_last and not c.seq.request.prefill_only}
+        at.update((id(s), len(step.chunks) + j)
+                  for j, s in enumerate(step.decode_seqs))
+        # (the order ``_next_plan`` would give them)
+        rows = sorted((s for s in self.active.values() if id(s) in at),
+                      key=lambda s: s.arrival)
+        plan = self._plan_block(rows, [len(s) + 1 for s in rows],
+                                behind="mixed")
+        if plan is None:
+            return None
+        plan.src_rows = [at[id(s)] for s in rows]
+        self._prefer_prefill = True
+        self._steps_since_decode = 0
+        self._end_run()
+        return plan
 
     def _gen_budget(self, seq: Sequence) -> int:
         """Tokens a block-diffusion row may still emit (``max_tokens``

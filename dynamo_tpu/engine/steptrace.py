@@ -134,9 +134,10 @@ class StepRecord:
               "plan_ms", "dispatch_ms",
               "fetch_ms", "process_ms", "unpack_ms", "device_ms",
               "ready_unix", "gap_ms", "compile_ms", "fallback", "chained",
-              "experts_touched", "moe_assignments", "moe_held_assignments",
-              "moe_zero_assignments", "passes", "row_passes", "revealed",
-              "commits", "handover_ms", "assemble_ms", "upload_ms",
+              "chained_behind", "experts_touched", "moe_assignments",
+              "moe_held_assignments", "moe_zero_assignments", "passes",
+              "row_passes", "revealed", "commits", "handover_ms",
+              "assemble_ms", "upload_ms",
               "enqueue_ms", "resume_ms", "fetch_resume_ms")
     # _enqueue: perf_counter at the start of the enqueue, kept until the
     # result arrives and device_ms can be taken; _experts: the dispatch's
@@ -173,6 +174,11 @@ class StepRecord:
         self.compile_ms = 0.0
         self.fallback = ""
         self.chained = False
+        # a fused decode block whose first tokens came from the device:
+        # what it was chained behind, "block" (the previous block's
+        # carry) or "mixed" (the packed output of the prefill-carrying
+        # step in front of it); "" on every other record
+        self.chained_behind = ""
         # experts the dispatch read, summed over its expert layers and
         # steps (MoE families' grouped layer; 0 elsewhere)
         self.experts_touched = 0
@@ -437,7 +443,8 @@ class StepRecorder:
                pool_pinned: int = 0, plan_ms: float = 0.0,
                dispatch_ms: float = 0.0, gap_ms: float = 0.0,
                fallback: str = "", chained: bool = False,
-               enqueue: float = 0.0, experts: Any = None,
+               chained_behind: str = "", enqueue: float = 0.0,
+               experts: Any = None,
                decode_kernel_rows: int = 0,
                phase: Optional[Phase] = None) -> StepRecord:
         """Stamp one dispatch; returns the live ring slot (later patched
@@ -476,6 +483,7 @@ class StepRecorder:
             rec.compile_ms = 0.0
             rec.fallback = fallback
             rec.chained = chained
+            rec.chained_behind = chained_behind
             rec.experts_touched = rec.moe_assignments = 0
             rec.moe_held_assignments = rec.moe_zero_assignments = 0
             rec.passes = rec.row_passes = rec.revealed = rec.commits = 0

@@ -21,7 +21,9 @@ start-up, before its first jax computation:
   from a temporary name, a pid or a time. A process started for the CPU
   compiles uncached: its toy programs build in milliseconds, XLA:CPU
   reloads stored executables with a machine-type warning apiece, and the
-  test suite must leave nothing in the checkout for the chip tool to copy.
+  test suite must leave nothing in the checkout for the chip tool to copy
+  (the suite sets the variable itself, to a directory under the temporary
+  one: ``tests/conftest.py``).
 """
 
 from __future__ import annotations

@@ -218,6 +218,12 @@ class EngineDispatchCollector:
     # RUN_ENDS), pre-seeded likewise
     RUN_ENDS = ("queue", "rows", "pages", "partial")
 
+    # what a fused block can be chained behind on the device, and why a
+    # chain behind a mixed step is not taken (engine/scheduler.py
+    # CHAIN_REFUSALS), pre-seeded likewise
+    CHAINED_BEHIND = ("block", "mixed")
+    CHAIN_REFUSALS = ("run", "rows", "pcarry", "budget", "pages")
+
     # the forms a prefill-carrying step can take (engine/jax_engine.py
     # _why_padded, and "ring" per plan), pre-seeded like the fallback
     # reasons
@@ -274,6 +280,37 @@ class EngineDispatchCollector:
         for ended_by, value in sorted(ends.items()):
             runs.add_metric([str(ended_by)], float(value))
         yield runs
+        chained = CounterMetricFamily(
+            "dynamo_worker_multistep_chained",
+            "Fused multi-step blocks whose first tokens came from the "
+            "device, by what they were chained behind: 'block' (the "
+            "previous block's carry) or 'mixed' (the packed output of the "
+            "prefill-carrying step that ended an admission run, still in "
+            "flight when the block was enqueued); the rest of "
+            "dynamo_worker_decode_multistep_blocks_total were built from "
+            "host state with the device waiting",
+            labels=["behind"])
+        behind = dict.fromkeys(self.CHAINED_BEHIND, 0.0)
+        behind.update(stats.get("chained_blocks") or {})
+        for what, value in sorted(behind.items()):
+            chained.add_metric([str(what)], float(value))
+        yield chained
+        refused = CounterMetricFamily(
+            "dynamo_worker_multistep_chain_refused",
+            "Mixed steps behind which the fused block was NOT chained on "
+            "the device, by reason: 'run' (another mixed step of the same "
+            "admission run follows), 'rows' (a row was cancelled, or runs "
+            "outside the step), 'pcarry' (a row's penalty window or "
+            "guided automaton state is built on the host and would lack "
+            "the token in flight), 'budget' / 'pages' (the block planner "
+            "refused with that token counted); the step and the block "
+            "behind it then run as they did before the chain existed",
+            labels=["reason"])
+        why = dict.fromkeys(self.CHAIN_REFUSALS, 0.0)
+        why.update(stats.get("chain_refusals") or {})
+        for reason, value in sorted(why.items()):
+            refused.add_metric([str(reason)], float(value))
+        yield refused
         # prefill-carrying steps by the form they ran in, so a model that
         # silently serves padded shows on the scrape
         pf = CounterMetricFamily(
@@ -451,7 +488,8 @@ def engine_dispatch_stats(engine) -> Dict[str, object]:
     """The ``EngineDispatchCollector.attach`` source for a
     ``ScheduledEngineBase`` engine (JaxEngine and the mocker both carry
     the counters). Values are floats, except ``multistep_fallbacks``,
-    ``admission_runs`` and ``prefill_steps``: per-label count dicts the collector renders as
+    ``admission_runs``, ``chained_blocks``, ``chain_refusals`` and
+    ``prefill_steps``: per-label count dicts the collector renders as
     labeled families."""
     sched = getattr(engine, "scheduler", None)
     moe = engine.moe_counts() if hasattr(engine, "moe_counts") else {}
@@ -473,6 +511,8 @@ def engine_dispatch_stats(engine) -> Dict[str, object]:
         "multistep_fallbacks": dict(
             getattr(sched, "multistep_fallbacks", None) or {}),
         "admission_runs": dict(getattr(sched, "admission_runs", None) or {}),
+        "chained_blocks": dict(getattr(sched, "chained_blocks", None) or {}),
+        "chain_refusals": dict(getattr(sched, "chain_refusals", None) or {}),
         "sched_admission_run_steps": float(
             getattr(sched, "admission_run_steps", 0)),
         "preemptions": float(getattr(sched, "num_preemptions", 0)),

@@ -246,6 +246,38 @@ class TestShardedMixedDispatch:
         finally:
             await eng.stop()
 
+    async def test_the_block_behind_an_admission_chains_on_the_mesh(
+            self, tp2):
+        """The hand-over's outputs are replicated like a block's carry, so
+        on the mesh too the block behind a mixed step takes its first
+        tokens from the step's on-device output, and the streams are those
+        of an engine that resolves every mixed step inside its dispatch
+        (greedy and seeded; requests queued together with
+        ``max_prefill_seqs=1`` are admitted one mixed step apart)."""
+        cfg, shard = tp2
+
+        class SyncSteps(JaxEngine):
+            supports_step_chain = False
+
+        streams = []
+        for cls in (JaxEngine, SyncSteps):
+            params = llama.init_params(cfg, jax.random.PRNGKey(0))
+            eng = cls(cfg, params, JaxEngineConfig(
+                mesh=shard.mesh, shard_params_fn=shard.shard_params,
+                shard_pages_fn=shard.shard_pages, max_prefill_seqs=1,
+                decode_multistep=4, **ENGINE_KW))
+            try:
+                streams.append(await asyncio.gather(
+                    run_tokens(eng, range(1, 10), "a", max_tokens=20),
+                    run_tokens(eng, range(20, 31), "b", max_tokens=9),
+                    run_tokens(eng, range(40, 47), "c", max_tokens=12,
+                               seed=5, temp=0.8)))
+                behind = eng.scheduler.chained_blocks["mixed"]
+                assert (behind > 0) == (cls is JaxEngine), behind
+            finally:
+                await eng.stop()
+        assert streams[0] == streams[1]
+
 
 class TestPallasPerShard:
     """On a mesh the GQA Pallas kernels run once per tp shard under

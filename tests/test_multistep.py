@@ -232,8 +232,9 @@ class TestStreamEnds:
     async def test_a_streams_end_keeps_the_chain_and_the_width(self):
         """Four rows, one much shorter: its end neither narrows the other
         rows' blocks nor breaks their chain (the ring shows every block at
-        the full width, only the one behind the last admission unchained),
-        and every row's tokens equal the per-step run's."""
+        the full width and chained: the one behind the last admission
+        behind that mixed step, the others behind a block), and every
+        row's tokens equal the per-step run's."""
         def mk():
             return reqs_staggered(lens=(7, 41, 41, 41))
 
@@ -250,8 +251,9 @@ class TestStreamEnds:
             recs = recs[last_admission + 1:]
             assert len(recs) >= 9
             assert all(r["width"] == 4 and r["rows"] == 4 for r in recs)
-            assert [r["chained"] for r in recs] == [False] + [True] * (
-                len(recs) - 1)
+            assert all(r["chained"] for r in recs)
+            assert [r["chained_behind"] for r in recs] == ["mixed"] + [
+                "block"] * (len(recs) - 1)
             assert recs[-1]["running"] == 3
         finally:
             await eng.stop()
@@ -676,6 +678,385 @@ class TestConstrainedParity:
             assert "cx" not in eng._guided_reqs
         finally:
             await eng.stop()
+
+
+class SyncSteps(JaxEngine):
+    """The engine with every mixed step resolved inside its dispatch:
+    the synchronous path a chained run is compared with."""
+    supports_step_chain = False
+
+
+class FailsBehindAStep(JaxEngine):
+    """The first block chained behind a mixed step fails at its dispatch."""
+    failed = False
+
+    def dispatch_multistep(self, plan, prev_handle=None):
+        if plan.behind == "mixed" and not self.failed:
+            self.failed = True
+            raise RuntimeError("planted: block behind a step")
+        return super().dispatch_multistep(plan, prev_handle)
+
+
+class CancelsBehindAStep(JaxEngine):
+    """Cancels ``victim`` the moment the first block chained behind a
+    mixed step is enqueued: both programs are in flight, neither result
+    is on the host."""
+    victim = None
+
+    def dispatch_multistep(self, plan, prev_handle=None):
+        handle = super().dispatch_multistep(plan, prev_handle)
+        if plan.behind == "mixed" and self.victim is not None:
+            self.scheduler.cancel(self.victim)
+            self.victim = None
+        return handle
+
+
+def staggered_engine(cls=JaxEngine, **kw):
+    """One prompt an admission pass: requests queued together are
+    admitted one mixed step apart, so the sequence of plans is fixed by
+    the requests alone (no clock in it)."""
+    defaults = dict(num_pages=64, page_size=4, max_num_seqs=4,
+                    max_prefill_chunk=16, max_context=64,
+                    decode_multistep=4, max_prefill_seqs=1,
+                    # one bucket for the pair of rows the cases serve, as
+                    # a deployment pins its buckets: a block holds the
+                    # same program with a dead row riding as without
+                    min_prefill_bucket=16, min_prefill_seqs_bucket=2,
+                    min_decode_bucket=2)
+    defaults.update(kw)
+    return cls.random_init(ModelConfig.tiny(), JaxEngineConfig(**defaults))
+
+
+async def serve_staggered(reqs, cls=JaxEngine, setup=None, **kw):
+    """([frames per request], the run's ring oldest first, the engine)."""
+    from dynamo_tpu.engine.steptrace import StepRecorder, set_step_recorder
+    ring = set_step_recorder(StepRecorder(512))
+    eng = staggered_engine(cls, **kw)
+    if setup is not None:
+        setup(eng)
+    try:
+        frames = await asyncio.gather(*[collect(eng, r) for r in reqs])
+    finally:
+        await eng.stop()
+    return frames, ring.snapshot(limit=512)["records"][::-1], eng
+
+
+def streamed(frames):
+    """What a client saw of each request: tokens, their log-probabilities,
+    and how it ended."""
+    return [([t for f in fs for t in f.token_ids],
+             [lp for f in fs for lp in (f.log_probs or [])],
+             fs[-1].finish_reason) for fs in frames]
+
+
+def behind_mixed(ring):
+    """(the mixed record, the block record) of every block the ring shows
+    chained behind a mixed step."""
+    return [(a, b) for a, b in zip(ring, ring[1:])
+            if b["chained_behind"] == "mixed"]
+
+
+# r0's 6th token comes from the first mixed step (its prefill gives one,
+# the block behind it four): the token a case makes r0's last
+R0_AT_THE_STEP = 5
+
+
+def two(r0_kw=None, r1_prompt=11, samp=None):
+    def mk():
+        return [make_req([1, 2, 3, 4, 5], "r0", samp=samp() if samp else None,
+                         **{"max_tokens": 20, **(r0_kw or {})}),
+                make_req(list(range(20, 20 + r1_prompt)), "r1", max_tokens=9,
+                         samp=samp() if samp else None)]
+    return mk
+
+
+class TestChainBehindMixed:
+    """A fused block chained behind the mixed step that ends an admission
+    run (``Scheduler.chains_behind`` / ``plan_multistep_behind``,
+    ``JaxEngine._handover_impl``): the step returns at its enqueue, the
+    block takes its first tokens from the step's on-device output, and
+    every request streams what the synchronous path streams."""
+
+    # what the synchronous path streams for the plain pair ``two()``,
+    # served once for the cases that compare with it
+    _plain = None
+
+    @classmethod
+    async def plain_synchronous(cls):
+        if cls._plain is None:
+            frames, ring, eng = await serve_staggered(two()(), SyncSteps)
+            assert eng.scheduler.chained_blocks["mixed"] == 0
+            assert not behind_mixed(ring)
+            assert all(r["fetch_ms"] == 0.0 for r in ring
+                       if r["kind"] == "mixed")
+            cls._plain = streamed(frames)
+        return cls._plain
+
+    async def _both(self, mk, want=None, **kw):
+        got, ring, eng = await serve_staggered(mk(), **kw)
+        if want is None:
+            frames, _ring0, eng0 = await serve_staggered(mk(), SyncSteps,
+                                                         **kw)
+            want = streamed(frames)
+            assert eng0.allocator.num_free == eng0.allocator.num_pages - 1
+        # tokens, log-probabilities and endings, request for request (the
+        # pinned row bucket keeps a block one program with a dead row
+        # riding or a row gone: no rounding between the two paths)
+        assert streamed(got) == want
+        # nothing leaks, whichever path ended a row
+        assert eng.allocator.num_free == eng.allocator.num_pages - 1
+        return streamed(got), ring, eng
+
+    @pytest.mark.parametrize("sampling", ["greedy", "seeded"])
+    async def test_a_joining_prompt_and_a_decode_row(self, sampling):
+        """r1's whole prompt rides the step: the block behind it holds
+        both rows, r1 from ``prompt_len + 1``."""
+        samp = None if sampling == "greedy" else (
+            lambda: SamplingOptions(temperature=1.0, seed=4242))
+        out, ring, eng = await self._both(
+            two(samp=samp),
+            want=None if samp else await self.plain_synchronous())
+        assert [len(t) for t, _lp, _r in out] == [20, 9]
+        (step, block), = behind_mixed(ring)
+        assert step["kind"] == "mixed" and step["program"].startswith("mixed[")
+        # the step returned at its enqueue: its result was fetched later
+        assert step["fetch_ms"] > 0.0 and not step["chained"]
+        assert block["chained"] and block["rows"] == 2
+        assert block["program"] == "multistep4[2]"
+        assert eng.scheduler.chained_blocks == {"block": 2, "mixed": 1}
+        assert not any(eng.scheduler.chain_refusals.values())
+        # the block behind the step is the only one built on the device
+        # from a step; no block was built from host state behind one
+        assert [r["chained_behind"] for r in ring
+                if r["kind"] == "multistep"] == ["", "mixed", "block",
+                                                 "block", ""]
+
+    @pytest.mark.parametrize("ends_by", ["stop_token", "budget"])
+    async def test_a_row_the_steps_token_ends_rides_dead(self, ends_by):
+        """r0's token from the step is its last (a stop token; the end of
+        its budget): the device has the row dead from the block's start,
+        the host appends nothing for it, r1 runs on beside it."""
+        if ends_by == "stop_token":
+            r0 = (await self.plain_synchronous())[0][0]
+            last = r0[R0_AT_THE_STEP]
+            assert last not in r0[:R0_AT_THE_STEP]
+            mk = two(r0_kw={"stop_token_ids": [last]})
+            reason = FinishReason.STOP
+        else:
+            mk = two(r0_kw={"max_tokens": R0_AT_THE_STEP + 1})
+            reason = FinishReason.LENGTH
+        out, ring, eng = await self._both(mk)
+        assert len(out[0][0]) == R0_AT_THE_STEP + 1 and out[0][2] == reason
+        assert len(out[1][0]) == 9
+        (step, block), = behind_mixed(ring)
+        # the synchronous path's block would hold r1 alone
+        assert block["rows"] == 2 and block["width"] == 4
+        assert eng.scheduler.chained_blocks["mixed"] == 1
+
+    async def test_an_intermediate_chunk_stays_out(self):
+        """r1's prompt takes two steps: the block behind the first holds
+        r0 alone, the block behind the second both."""
+        out, ring, eng = await self._both(two(r1_prompt=21))
+        assert [len(t) for t, _lp, _r in out] == [20, 9]
+        rows = [(a["rows"], b["rows"]) for a, b in behind_mixed(ring)]
+        assert rows == [(2, 1), (2, 2)]
+        assert eng.scheduler.chained_blocks["mixed"] == 2
+
+    async def test_only_the_last_step_of_a_run_chains(self):
+        """A queue behind a long prompt: the run's first mixed step is
+        followed by another and resolves inside its dispatch, counted as
+        ``run``; the run's last chains."""
+        def mk():
+            return [make_req([1, 2, 3, 4, 5], "r0", max_tokens=24),
+                    make_req(list(range(10, 50)), "r1", max_tokens=6),
+                    make_req([7, 8, 9], "r2", max_tokens=6)]
+
+        frames, ring, eng = await serve_staggered(mk())
+        assert [len(t) for t, _lp, _r in streamed(frames)] == [24, 6, 6]
+        kinds = [(r["kind"], r["fetch_ms"] > 0.0, r["chained_behind"])
+                 for r in ring[2:5]]
+        assert kinds == [("mixed", False, ""), ("mixed", True, ""),
+                         ("multistep", True, "mixed")]
+        assert eng.scheduler.chain_refusals["run"] == 1
+        assert eng.scheduler.admission_runs == \
+            {"queue": 1, "rows": 0, "pages": 0, "partial": 2}
+
+    async def test_a_penalised_row_keeps_the_synchronous_path(self):
+        """A device penalty window is preloaded from host tokens, which
+        would lack the one in flight: the chain is refused and counted,
+        every step and block is the synchronous path's."""
+        def samp():
+            return SamplingOptions(temperature=0.0, frequency_penalty=0.7)
+
+        frames, ring, eng = await serve_staggered(two(samp=samp)())
+        assert [len(t) for t, _lp, _r in streamed(frames)] == [20, 9]
+        assert not behind_mixed(ring)
+        assert eng.scheduler.chain_refusals["pcarry"] == 1
+        assert eng.scheduler.chained_blocks["mixed"] == 0
+        assert all(r["fetch_ms"] == 0.0 for r in ring
+                   if r["kind"] == "mixed")
+        from dynamo_tpu.worker.metrics import engine_dispatch_stats
+        stats = engine_dispatch_stats(eng)
+        assert stats["chain_refusals"]["pcarry"] == 1
+        assert stats["chained_blocks"]["block"] > 0
+
+    async def test_a_cancel_while_both_programs_are_in_flight(self):
+        """r0 is cancelled with the step and the block behind it both
+        enqueued and neither fetched: it ends CANCELLED with the tokens it
+        had, r1 streams what it streams alone beside r0, nothing leaks."""
+        def victim(eng):
+            eng.victim = "r0"
+
+        got, ring, eng = await serve_staggered(two()(), CancelsBehindAStep,
+                                               setup=victim)
+        got, want = streamed(got), await self.plain_synchronous()
+        assert len(behind_mixed(ring)) == 1
+        assert got[0][2] == FinishReason.CANCELLED
+        # the five tokens it had streamed before the step; the step's own
+        # and the block's are dropped
+        assert got[0][0] == want[0][0][:R0_AT_THE_STEP]
+        assert got[1] == want[1]
+        assert eng.allocator.num_free == eng.allocator.num_pages - 1
+
+    async def test_a_dispatch_error_in_the_chained_block(self):
+        """The block behind the step fails at its dispatch: the step is
+        finished first (each row gets the token the step sampled), then
+        the block's rows fail; the engine serves on."""
+        eng = staggered_engine(FailsBehindAStep)
+        try:
+            frames = await asyncio.gather(*[collect(eng, r)
+                                            for r in two()()])
+            got, want = streamed(frames), await self.plain_synchronous()
+            assert eng.failed
+            assert got[0][0] == want[0][0][:R0_AT_THE_STEP + 1]
+            assert got[1][0] == want[1][0][:1]
+            for fs in frames:
+                assert fs[-1].finish_reason == FinishReason.ERROR
+                assert "planted" in fs[-1].error
+            assert eng.allocator.num_free == eng.allocator.num_pages - 1
+            after = await collect(eng, make_req([4, 5, 6], "after",
+                                                max_tokens=6))
+            assert len(toks_of(after)) == 6
+        finally:
+            await eng.stop()
+
+
+class TestChainBehindMixedPlan:
+    """The scheduler's side, without an engine."""
+
+    def make(self, **cfg):
+        cfg.setdefault("decode_multistep", 4)
+        cfg.setdefault("max_prefill_seqs", 1)
+        cfg.setdefault("max_prefill_chunk", 16)
+        sched = Scheduler(PageAllocator(33, 4), SchedulerConfig(**cfg))
+        sched.max_context_hint = 64
+        return sched
+
+    def step_with(self, sched, r0, r1):
+        """r0 running with one token out, r1 admitted into a mixed step."""
+        from dynamo_tpu.engine.scheduler import MixedStepBatch
+        sched.add_request(r0)
+        first = sched.schedule()
+        assert isinstance(first, PrefillBatch)
+        sched.on_step_done(first)
+        a = first.chunks[0].seq
+        a.tokens.append(9)
+        a.generated.append(9)
+        sched.add_request(r1)
+        assert isinstance(sched.schedule(), DecodeBatch)
+        step = sched.schedule()
+        assert isinstance(step, MixedStepBatch)
+        return step, a
+
+    def test_rows_lengths_and_the_map_into_the_steps_output(self):
+        sched = self.make()
+        step, a = self.step_with(
+            sched, make_req([1, 2, 3, 4, 5], "a", max_tokens=12),
+            make_req(list(range(20, 31)), "b", max_tokens=3,
+                     min_tokens=3))
+        b = step.chunks[0].seq
+        assert sched.chains_behind(step)
+        free = sched.alloc.num_free
+        plan = sched.plan_multistep_behind(step)
+        # by arrival, as the decode plan would hold them; the step's
+        # packed rows are its chunks, then its decode rows
+        assert plan.seqs == [a, b] and plan.src_rows == [1, 0]
+        assert plan.chained and plan.behind == "mixed"
+        assert plan.start_lens == [len(a) + 1, len(b) + 1] == [7, 12]
+        # the token in flight is counted: a has 12 - 1 - 1 left, b
+        # 3 - 1, and b's min_tokens gate has 2 to go
+        assert plan.budgets == [10, 2] and plan.min_gates == [0, 2]
+        assert plan.width == 4
+        # pages for what each row writes: a 4 positions from 6, b 2 from 11
+        assert len(a.page_ids) == 3 and len(b.page_ids) == 4
+        assert sched.alloc.num_free == free - 2
+        # the admission run is over, as after ``schedule()``'s decode plan
+        assert sched.admission_runs["queue"] == 1 and sched._run_steps == 0
+        assert sched.chained_blocks == {"block": 0, "mixed": 1}
+
+    @pytest.mark.parametrize("why", ["pcarry", "guided", "rows", "budget",
+                                     "pages", "off"])
+    def test_a_refusal_is_counted_by_its_reason(self, why):
+        samp = {"pcarry": SamplingOptions(temperature=0.0,
+                                          presence_penalty=0.5),
+                "guided": SamplingOptions(temperature=0.0,
+                                          guided={"type": "json_object"})
+                }.get(why)
+        sched = self.make(**({"decode_multistep": 1} if why == "off"
+                             else {}))
+        step, a = self.step_with(
+            sched, make_req([1, 2, 3, 4, 5], "a",
+                            max_tokens=3 if why == "budget" else 12),
+            make_req(list(range(20, 31)), "b",
+                     max_tokens=2 if why == "budget" else 8, samp=samp))
+        if why == "rows":
+            a.cancelled = True
+        if why in ("budget", "pages"):
+            assert sched.chains_behind(step)
+            if why == "pages":
+                held = sched.alloc.allocate(sched.alloc.num_free)
+                assert held
+            before = [len(s.page_ids) for s in step.seqs]
+            assert sched.plan_multistep_behind(step) is None
+            assert [len(s.page_ids) for s in step.seqs] == before
+            # the run is still open: ``schedule()`` closes it
+            assert sched._run_steps == 1
+        else:
+            assert not sched.chains_behind(step)
+        want = {"guided": "pcarry", "off": None}.get(why, why)
+        assert sched.chain_refusals == {
+            r: int(r == want) for r in sched.chain_refusals}
+        assert sched.chained_blocks["mixed"] == 0
+        # a chain not taken is no fallback from the fused path
+        assert sched.multistep_fallbacks == {}
+
+    def test_the_hand_over_on_the_device(self):
+        """``_handover_impl``: the first token from the step's column 0
+        by the row map; a row lives unless that token is a stop id with
+        its ``min_tokens`` gate passed, or its budget is spent; pad rows
+        are dead."""
+        import numpy as np
+        eng = tiny_engine(decode_multistep=4)
+        packed = np.zeros((4, 2), np.int32)
+        packed[:, 0] = [50, 60, 70, 80]
+        #               row: stop hit, gate open | hit, gate shut | spent |
+        #               plain | pad
+        rows = np.array([[3, 3, 1, 0, 0],            # src
+                         [6, 6, 9, 4, 0],            # pos
+                         [7, 7, 10, 5, 1],           # total
+                         [5, 5, 0, 5, 0],            # budget
+                         [0, 2, 0, 0, 0]], np.int32)  # min_gate
+        stop_ids = np.full((5, 2), -1, np.int32)
+        stop_ids[0] = stop_ids[1] = [80, -1]
+        stop_ids[3] = [99, 51]
+        c = eng._get_jit_handover()(packed, rows, stop_ids)
+        assert c["tok"][:, 0].tolist() == [80, 80, 60, 50, 50]
+        assert c["alive"].tolist() == [False, True, False, True, False]
+        assert c["pos"][:, 0].tolist() == [6, 6, 9, 4, 0]
+        assert c["total"].tolist() == [7, 7, 10, 5, 1]
+        assert c["budget"].tolist() == [5, 5, 0, 5, 0]
+        assert c["min_gate"].tolist() == [0, 2, 0, 0, 0]
+        assert c["tok"].shape == (5, 1) and c["alive"].dtype == bool
 
 
 class TestMockerBlockPath:

@@ -540,6 +540,63 @@ class TestDeviceTime:
         assert all(r["program"].endswith("]") for r in recs)
 
 
+class TestBlockBehindAPackedStep:
+    async def test_the_step_keeps_its_kind_and_the_two_share_no_time(
+            self, fresh_recorder):
+        """An engine on the kernels (interpreted), one prompt an admission
+        pass: the mixed step that admits the second request returns at its
+        enqueue and keeps ``kind`` ``mixed`` and its ``packed[T,R]``
+        program; the block behind it is ``chained`` behind ``mixed``, was
+        enqueued before the step's result arrived, and the two records'
+        ``device_ms`` share no time: they add up to the span from the
+        step's enqueue to the block's arrival."""
+        from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
+        from dynamo_tpu.models.config import ModelConfig
+        eng = JaxEngine.random_init(
+            ModelConfig.tiny(head_dim=128, num_heads=2, num_kv_heads=1,
+                             hidden_size=128),
+            JaxEngineConfig(num_pages=64, page_size=8, max_num_seqs=4,
+                            max_prefill_chunk=16, max_context=128,
+                            min_prefill_bucket=16, min_prefill_seqs_bucket=2,
+                            min_decode_bucket=2, decode_multistep=4,
+                            max_prefill_seqs=1, attn_impl="pallas"))
+        assert eng.padded_reason is None
+        try:
+            await asyncio.gather(
+                collect(eng, make_req([1, 2, 3, 4, 5], "a", max_tokens=12)),
+                collect(eng, make_req(range(20, 31), "b", max_tokens=6)))
+        finally:
+            await eng.stop()
+        recs = fresh_recorder.snapshot(limit=64)["records"][::-1]
+        (step, block), = [(a, b) for a, b in zip(recs, recs[1:])
+                          if b["chained_behind"] == "mixed"]
+        assert step["kind"] == "mixed"
+        assert step["program"] == "packed[16,2]"
+        assert not step["chained"] and step["chained_behind"] == ""
+        # asynchronous where it chains: the call is the enqueue, the
+        # result was fetched afterwards
+        assert step["fetch_ms"] > 0.0
+        assert step["dispatch_ms"] < step["device_ms"]
+        assert block["kind"] == "multistep" and block["chained"]
+        assert block["program"] == "multistep4[2]"
+        assert block["t_unix"] < step["ready_unix"] <= block["ready_unix"]
+        # the block's time starts where the step's result arrived ...
+        assert block["device_ms"] == pytest.approx(
+            (block["ready_unix"] - step["ready_unix"]) * 1e3, abs=5.0)
+        # ... so the two sum to the span from the step's enqueue (the
+        # device had nothing in front of it: the block before the step
+        # had been fetched) to the block's arrival
+        enqueue_unix = step["t_unix"] - step["dispatch_ms"] / 1e3
+        assert step["device_ms"] + block["device_ms"] == pytest.approx(
+            (block["ready_unix"] - enqueue_unix) * 1e3, abs=5.0)
+        # every other record keeps the field empty but the blocks chained
+        # behind a block
+        assert {r["chained_behind"] for r in recs
+                if r["kind"] != "multistep"} == {""}
+        assert all(r["chained_behind"] == ("block" if r["chained"] else "")
+                   for r in recs if r["kind"] == "multistep" and r is not block)
+
+
 class TestHeadStart:
     """``Phase.in_thread(..., head_start=s)``: the call is on its thread
     before the loop does anything else, for at most ``s`` seconds."""
