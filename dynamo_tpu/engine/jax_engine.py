@@ -171,6 +171,11 @@ class JaxEngineConfig:
     mesh: Optional[object] = None
     sp_axis: str = "sp"
     ring_threshold: Optional[int] = None
+    # slots of the recurrent-state pool, for a family with linear-attention
+    # layers (``ModelConfig.state_layers``): a request owns one while it
+    # is admitted, so fewer than ``max_num_seqs`` caps the rows. None =
+    # ``max_num_seqs``; a family without such layers has no pool
+    state_slots: Optional[int] = None
 
 
 # prompt-scoring LM-head chunk: the ONE constant both the host padding
@@ -305,6 +310,27 @@ class JaxEngine(ScheduledEngineBase):
         self.mixed_batch = (bool(self.cfg.mixed_batch)
                             if self.cfg.mixed_batch is not None
                             else mixed_batch_default())
+        # a family with linear-attention layers: what only moves block
+        # chains is refused by name here, at start-up
+        self.state_slots = 0
+        if model_cfg.state_layers:
+            for what, on in (
+                    ("a device mesh (--tensor-parallel-size, "
+                     "--data-parallel-size, --sequence-parallel-size)",
+                     self.cfg.mesh is not None
+                     or self.cfg.shard_pages_fn is not None),
+                    ("a custom forward_fn (pipeline stages)",
+                     forward_fn is not None),
+                    ("--quantize", bool(self.cfg.quantize)),
+                    ("--speculative-num-tokens (a rejected draft would "
+                     "have to roll the state back)",
+                     bool(self.cfg.spec_tokens))):
+                if on:
+                    model_cfg.paged_only(what)
+            self.state_slots = int(self.cfg.state_slots
+                                   or self.cfg.max_num_seqs)
+            if self.state_slots < 1:
+                raise ValueError("state_slots must be at least 1")
         super().__init__(
             num_pages=self.cfg.num_pages, page_size=self.cfg.page_size,
             max_num_seqs=self.cfg.max_num_seqs,
@@ -321,7 +347,8 @@ class JaxEngine(ScheduledEngineBase):
             decode_progress_every=(
                 int(self.cfg.decode_progress_every)
                 if self.cfg.decode_progress_every is not None
-                else decode_progress_default()))
+                else decode_progress_default()),
+            state_slots=self.state_slots)
         # fused-path gates for penalized/guided rows: the scheduler
         # narrows block widths by the penalty window's remaining capacity
         # and asks the engine whether a row's grammar lowered to a device
@@ -434,8 +461,15 @@ class JaxEngine(ScheduledEngineBase):
                 paged_prefill_attention_stacked, forward_fn)
             self._attn_packed = self._per_shard(
                 ragged_mixed_attention_packed, forward_fn)
-        self.pages = llama.make_pages(model_cfg, self.cfg.num_pages,
-                                      self.cfg.page_size)
+        if self.state_slots:
+            # two kinds of cache in one donated value: the paged pool of
+            # the full-attention layers and the linear layers' state pools
+            self.pages = family.make_pages(
+                model_cfg, self.cfg.num_pages, self.cfg.page_size,
+                state_slots=self.state_slots)
+        else:
+            self.pages = llama.make_pages(model_cfg, self.cfg.num_pages,
+                                          self.cfg.page_size)
         if self.cfg.shard_params_fn is not None:
             self.params = self.cfg.shard_params_fn(self.params)
         if self.cfg.shard_pages_fn is not None:
@@ -466,7 +500,11 @@ class JaxEngine(ScheduledEngineBase):
         self.gen_block = int(model_cfg.gen_block)
         if self.gen_block > 1:
             self._init_block_diffusion(forward_fn)
-        self.table_width = self.cfg.max_context // self.cfg.page_size
+        # a row of the page table: the row's pages and, for a family with
+        # a recurrent state, its slot of the state pool in one more column
+        # (``_table_row``; the family's forward cuts it off)
+        self.table_width = (self.cfg.max_context // self.cfg.page_size
+                            + bool(self.state_slots))
         self._rng = jax.random.PRNGKey(self.cfg.seed)
         self._step_counter = 0
         self._jit_step = jax.jit(self._step_impl, donate_argnums=(1,))
@@ -632,6 +670,28 @@ class JaxEngine(ScheduledEngineBase):
             return "causal"
         return (f"block_diffusion[B={self.gen_block},steps={self.gen_steps}"
                 f",tau={self.gen_threshold:g}]")
+
+    @property
+    def kv_pool(self):
+        """The paged pool alone (of a family with a recurrent state,
+        ``pages`` holds the state pools too)."""
+        return self.pages["kv"] if self.state_slots else self.pages
+
+    @property
+    def cache_kinds(self) -> str:
+        """The kinds of cache the engine keeps, for ``startup.engine``."""
+        L, _n, _two, Hkv, _ps, Dh = self.kv_pool.shape
+        kinds = f"paged[L={L},Hkv={Hkv},Dh={Dh}]"
+        if self.state_slots:
+            kinds += (f"+state[L={self.model_cfg.state_layers},"
+                      f"S={self.state_slots},f32]")
+        return kinds
+
+    def _table_row(self, table: np.ndarray, i: int, seq) -> None:
+        """Row ``i`` of a step's page table: where ``seq``'s cache lives."""
+        table[i, :len(seq.page_ids)] = seq.page_ids
+        if self.state_slots:
+            table[i, -1] = seq.state_slot
 
     @property
     def packed_attention(self) -> Optional[str]:
@@ -1711,7 +1771,7 @@ class JaxEngine(ScheduledEngineBase):
                     c.start:c.start + c.length]
             pos[row, lo:lo + c.length] = np.arange(c.start,
                                                    c.start + c.length)
-            table[i, :len(seq.page_ids)] = seq.page_ids
+            self._table_row(table, i, seq)
             total[i] = c.start + c.length
             new[i] = c.length
             so = seq.request.sampling_options
@@ -2673,13 +2733,13 @@ class JaxEngine(ScheduledEngineBase):
                 for i in stale:
                     s = seqs[i]
                     table[i, :] = 0
-                    table[i, :len(s.page_ids)] = s.page_ids
+                    self._table_row(table, i, s)
                     versions[i] = s.table_version
             else:
                 table = np.zeros((B, P), np.int32)
                 versions = [s.table_version for s in seqs]
                 for i, s in enumerate(seqs):
-                    table[i, :len(s.page_ids)] = s.page_ids
+                    self._table_row(table, i, s)
         with stage("upload"):
             dev = jnp.asarray(table)
         self._table_cache = (key, versions, table, dev)
@@ -2761,6 +2821,9 @@ class JaxEngine(ScheduledEngineBase):
     def _ensure_page_io_jits(self):
         if hasattr(self, "_jit_gather_pages"):
             return
+        self.model_cfg.paged_only("KV page export / import (disaggregated "
+                                  "prefill, the host and disk tiers, drain "
+                                  "and migration)")
         rep = None
         if self.cfg.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec

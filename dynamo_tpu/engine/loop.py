@@ -107,7 +107,7 @@ class ScheduledEngineBase(EngineBase):
                  spec_tokens: int = 0, spec_ngram_max: int = 4,
                  spec_ngram_min: int = 2, spec_chain_break: int = 8,
                  decode_multistep: int = 1, mixed_batch: bool = True,
-                 decode_progress_every: int = 2):
+                 decode_progress_every: int = 2, state_slots: int = 0):
         if max_context % page_size:
             raise ValueError("max_context must be a multiple of page_size")
         self.max_context = max_context
@@ -120,7 +120,8 @@ class ScheduledEngineBase(EngineBase):
             spec_ngram_min=spec_ngram_min,
             spec_chain_break=spec_chain_break,
             decode_multistep=decode_multistep, mixed_batch=mixed_batch,
-            decode_progress_every=decode_progress_every))
+            decode_progress_every=decode_progress_every,
+            state_slots=state_slots))
         self.scheduler.max_context_hint = max_context
         self._queues: Dict[str, asyncio.Queue] = {}
         self._work = asyncio.Event()
@@ -294,6 +295,20 @@ class ScheduledEngineBase(EngineBase):
             tokens_real = rows * (k + 1)
         else:
             tokens_real = rows
+        state = (0, 0, 0, 0)
+        if self.scheduler.cfg.state_slots:
+            # (rows whose state the dispatch read, tokens through the
+            # chunk form of the rule, row-steps through the one-token
+            # form, query-key pairs a full-attention layer scored)
+            if kind in ("prefill", "mixed"):
+                several = [c.length for c in chunks if c.length > 1]
+                pairs = sum(c.length * (2 * c.start + c.length + 1) // 2
+                            for c in chunks) + sum(len(s) for s in dec)
+                state = (rows, sum(several), rows - len(several), pairs)
+            else:
+                w = max(1, width)
+                pairs = sum(len(s) for s in seqs) * w + rows * w * (w - 1) // 2
+                state = (rows, 0, rows * w, pairs)
         padded = self.last_padded
         if padded is not None:
             batch = padded[0]
@@ -315,7 +330,7 @@ class ScheduledEngineBase(EngineBase):
             chained_behind=chained_behind, enqueue=dispatch.t0,
             experts=self.last_moe_counts,
             decode_kernel_rows=self.last_decode_kernel_rows,
-            phase=dispatch)
+            state=state, phase=dispatch)
         self.last_padded = None
         self.last_program = ""
         self.last_decode_kernel_rows = 0
@@ -1105,7 +1120,9 @@ class ScheduledEngineBase(EngineBase):
         extras = dict(resume_extras or {})
         # only engines whose pages hold real, exportable KV can offer a
         # resume (the export handlers gather through this same hook)
-        can_export = hasattr(self, "dispatch_gather_pages")
+        # (a recurrent state does not travel yet: such rows replay)
+        can_export = (hasattr(self, "dispatch_gather_pages")
+                      and not self.scheduler.cfg.state_slots)
         try:
             frames, ttl = await self.run_exclusive(
                 self._freeze_sync, extras, can_export)

@@ -129,7 +129,9 @@ def expert_temporaries(hlo_text: str, tokens: int, experts: int,
 
 
 def _pool_shape(engine, num_pages: Optional[int]):
-    shape = engine.pages.shape
+    """The paged pool's shape (of a family with a recurrent state,
+    ``engine.pages`` holds the state pools beside it)."""
+    shape = engine.kv_pool.shape
     if num_pages is not None:
         shape = (shape[0], num_pages) + shape[2:]
     return tuple(shape)
@@ -154,7 +156,11 @@ def step_programs(engine, batch: int, chunk: int, width: int = 8,
 
     params = jax.tree_util.tree_map(lambda l: sds(l.shape, l.dtype),
                                     engine.params)
-    pages = sds(_pool_shape(engine, num_pages), engine.pages.dtype)
+    pages = sds(_pool_shape(engine, num_pages), engine.kv_pool.dtype)
+    if engine.state_slots:
+        # the state pools as the engine holds them, beside the paged pool
+        pages = {**jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype), engine.pages), "kv": pages}
     i32, f32 = jnp.int32, jnp.float32
 
     def step_args(B, S, lead=None):
@@ -216,7 +222,7 @@ def check_step_programs(engine, batch: int, chunk: int, width: int = 8,
 
     programs = step_programs(engine, batch, chunk, width, sharding,
                              num_pages, tokens)
-    shape, dtype = _pool_shape(engine, num_pages), engine.pages.dtype
+    shape, dtype = _pool_shape(engine, num_pages), engine.kv_pool.dtype
     pool_bytes = math.prod(shape) * jnp.dtype(dtype).itemsize
     vocab = engine.model_cfg.vocab_size
     selection = candidate_form(vocab)

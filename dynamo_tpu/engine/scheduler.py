@@ -42,7 +42,8 @@ from typing import Callable, Deque, Dict, List, Optional, Union
 
 import numpy as np
 
-from dynamo_tpu.engine.pages import OutOfPages, PageAllocator
+from dynamo_tpu.engine.pages import (OutOfPages, PageAllocator,
+                                     PrefixMatch)
 from dynamo_tpu.engine.spec import propose_ngram
 from dynamo_tpu.protocols.common import PreprocessedRequest
 from dynamo_tpu.protocols.events import (
@@ -70,7 +71,7 @@ class Sequence:
                  "enqueued_unix", "admitted_unix", "timings_sent",
                  "decode_steps", "decode_dispatches", "table_version",
                  "multistep_fallbacks", "compile_ms", "compile_events",
-                 "block_state", "gen_passes", "gen_blocks")
+                 "block_state", "gen_passes", "gen_blocks", "state_slot")
 
     def __init__(self, request: PreprocessedRequest, page_size: int,
                  salt_hash: int = 0):
@@ -121,6 +122,10 @@ class Sequence:
         self.block_state = None
         self.gen_passes = 0
         self.gen_blocks = 0
+        # the slot of the recurrent-state pool this request's linear
+        # layers read and write while it is admitted (0: none - slot 0 is
+        # no request's; a family without such layers never has one)
+        self.state_slot = 0
 
     def pages_changed(self) -> None:
         self.table_version += 1
@@ -279,6 +284,13 @@ class SchedulerConfig:
     max_prefill_chunk: int = 512     # prompt-token budget per prefill step
     max_prefill_seqs: int = 8        # max sequences sharing one prefill step
     watermark: float = 0.01          # keep this fraction of pages free at admit
+    # slots of the recurrent-state pool (a family with linear-attention
+    # layers: an admitted sequence owns its pages AND one slot, given at
+    # admission, taken back at ``finish`` and at preemption; with slots
+    # the prefix cache is off - no page is committed, claimed or adopted,
+    # because a prefix's pages without the state that matches them are a
+    # wrong answer). 0: every other family, nothing changes
+    state_slots: int = 0
     max_queue: int = 4096
     # prompts longer than this (and with no resident prefix) prefill in ONE
     # sequence-parallel ring step instead of chunks; None disables (set by
@@ -437,6 +449,12 @@ class Scheduler:
         self.chained_blocks: Dict[str, int] = {"block": 0, "mixed": 0}
         self.chain_refusals: Dict[str, int] = dict.fromkeys(
             CHAIN_REFUSALS, 0)
+        # free slots of the recurrent-state pool, low numbers first (slot
+        # 0 is never handed out), and the prefix lookups that were not
+        # made because a hit could not be used, by reason
+        # (dynamo_worker_prefix_reuse_refused_total{reason})
+        self._free_slots: List[int] = list(range(config.state_slots, 0, -1))
+        self.prefix_reuse_refused: Dict[str, int] = {"recurrent_state": 0}
 
     def record_chain_refusal(self, reason: str, seqs=()) -> None:
         """Count one chain behind a mixed step that was not taken (the
@@ -521,7 +539,9 @@ class Scheduler:
         if not self.waiting:
             self._admit_stop = "queue"
             return None
-        if len(self.active) >= self.cfg.max_num_seqs:
+        recurrent = self.cfg.state_slots > 0
+        if len(self.active) >= self.cfg.max_num_seqs or (
+                recurrent and not self._free_slots):
             self._admit_stop = "rows"
             return None
         seq = self.waiting[0]
@@ -530,7 +550,10 @@ class Scheduler:
         # to compute so the final-chunk logits exist. (For a preempted
         # sequence len(seq) includes generated tokens; the revive covers them
         # too since its full pages were committed before release.)
-        match = self.alloc.match_prefix(hashes)
+        # (a row with a recurrent state claims nothing: the whole prompt,
+        # or the whole of a preempted row, is computed from token 0)
+        match = PrefixMatch() if recurrent else self.alloc.match_prefix(
+            hashes)
         # (block diffusion: the prompt's whole blocks are all there is to
         # prefill, and no logits are taken from them)
         cached = min(match.num_pages * self.page_size,
@@ -556,8 +579,12 @@ class Scheduler:
             self.alloc.release(match.page_ids)
             self._admit_stop = "pages"
             return None
-        self.alloc.count_lookup(hits=full_cached_pages,
-                                misses=len(hashes) - full_cached_pages)
+        if recurrent:
+            self.prefix_reuse_refused["recurrent_state"] += 1
+            seq.state_slot = self._free_slots.pop()
+        else:
+            self.alloc.count_lookup(hits=full_cached_pages,
+                                    misses=len(hashes) - full_cached_pages)
         self.waiting.popleft()
         seq.page_ids = match.page_ids + fresh
         seq.pages_changed()
@@ -593,6 +620,8 @@ class Scheduler:
     # -- per-step bookkeeping ---------------------------------------------
 
     def _commit_full_pages(self, seq: Sequence) -> None:
+        if self.cfg.state_slots:
+            return      # nothing is published: no request could use it
         full = seq.num_computed // self.page_size
         blocks = seq.tokens.blocks
         for i in range(seq.committed_pages, min(full, len(seq.page_ids))):
@@ -608,9 +637,18 @@ class Scheduler:
         self._commit_full_pages(seq)
         self.alloc.release(seq.page_ids)
         seq.page_ids = []
+        self._release_slot(seq)
         seq.pages_changed()
         seq.phase = Phase.FINISHED
         self.active.pop(seq.request.request_id, None)
+
+    def _release_slot(self, seq: Sequence) -> None:
+        """Take back the sequence's slot of the state pool. The slot is
+        not cleared: the next owner's first chunk starts at position 0,
+        which starts from zeros whatever the slot holds."""
+        if seq.state_slot:
+            self._free_slots.append(seq.state_slot)
+            seq.state_slot = 0
 
     def _preempt_one(self) -> bool:
         """Evict the newest running sequence back to the waiting queue."""
@@ -621,6 +659,7 @@ class Scheduler:
         self._commit_full_pages(victim)
         self.alloc.release(victim.page_ids)
         victim.page_ids = []
+        self._release_slot(victim)
         victim.pages_changed()
         victim.committed_pages = 0
         victim.num_computed = 0

@@ -138,7 +138,8 @@ class StepRecord:
               "moe_held_assignments", "moe_zero_assignments", "passes",
               "row_passes", "revealed", "commits", "handover_ms",
               "assemble_ms", "upload_ms",
-              "enqueue_ms", "resume_ms", "fetch_resume_ms")
+              "enqueue_ms", "resume_ms", "fetch_resume_ms",
+              "state_rows", "gdn_tokens", "gdn_step_rows", "score_pairs")
     # _enqueue: perf_counter at the start of the enqueue, kept until the
     # result arrives and device_ms can be taken; _experts: the dispatch's
     # expert-layer counts (MOE_COUNTS) while they are still device
@@ -197,6 +198,16 @@ class StepRecord:
         self.row_passes = 0
         self.revealed = 0
         self.commits = 0
+        # a dispatch of a model with linear-attention layers (0
+        # elsewhere): rows whose recurrent state it read and wrote, tokens
+        # that went through the chunk form of the rule (rows of several
+        # tokens), row-steps through the one-token form (a layer each),
+        # and the (query, key) pairs one full-attention layer scored: a
+        # new token at position p sees p + 1 keys
+        self.state_rows = 0
+        self.gdn_tokens = 0
+        self.gdn_step_rows = 0
+        self.score_pairs = 0
         # the dispatch phase by stage (``dispatch_ms`` less these five is
         # the ``wait`` for the result, of a synchronous kind), and how
         # long the fetched result waited for the loop's thread
@@ -446,6 +457,7 @@ class StepRecorder:
                chained_behind: str = "", enqueue: float = 0.0,
                experts: Any = None,
                decode_kernel_rows: int = 0,
+               state: tuple = (0, 0, 0, 0),
                phase: Optional[Phase] = None) -> StepRecord:
         """Stamp one dispatch; returns the live ring slot (later patched
         by note_ready/note_unpack/note_compile).
@@ -487,6 +499,8 @@ class StepRecorder:
             rec.experts_touched = rec.moe_assignments = 0
             rec.moe_held_assignments = rec.moe_zero_assignments = 0
             rec.passes = rec.row_passes = rec.revealed = rec.commits = 0
+            (rec.state_rows, rec.gdn_tokens, rec.gdn_step_rows,
+             rec.score_pairs) = state
             rec._enqueue = enqueue
             rec._experts = experts
             rec.fetch_resume_ms = 0.0

@@ -70,6 +70,7 @@ class TieredEngine(EngineBase):
         from dynamo_tpu.kvbm.prefetch import (
             PrefetchScheduler, prefetch_depth_bytes)
 
+        engine.model_cfg.paged_only("the host and disk tiers (kvbm/)")
         self.engine = engine
         self.cfg = config or TieredKvConfig()
         self.host = HostTier(self.cfg.host_budget_bytes)

@@ -4,7 +4,10 @@ Each family exposes ``init_params(cfg, rng)``, ``make_pages`` (the stacked
 paged KV cache ``[L, N, 2, Hkv, page_size, Dh]``) and ONE ``forward``: a
 ``lax.scan`` over the layers against that cache, whose attention op is an
 argument (``attn_impl``). ``get_family(cfg)`` maps a config to its
-implementation: double-layer configs (``attn_blocks_per_layer == 2``:
+implementation: configs with linear-attention layers
+(``full_attention_interval > 0``: Qwen3-Next) use ``models.qwen3_next``,
+whose ``make_pages`` returns the paged pool AND the recurrent-state pools;
+double-layer configs (``attn_blocks_per_layer == 2``:
 LongCat-Flash) use ``models.longcat``, other MLA configs (``kv_lora_rank >
 0``) ``models.deepseek``,
 other MoE configs (``num_experts > 0``: mixtral / qwen3_moe routing)
@@ -18,6 +21,12 @@ from dynamo_tpu.models.llama import forward, init_params, make_pages
 
 def get_family(cfg: ModelConfig):
     """Return the module implementing this config's model family."""
+    if cfg.full_attention_interval:
+        # Qwen3-Next: Gated DeltaNet linear-attention layers with a
+        # recurrent state beside the paged cache, gated full attention
+        # every ``full_attention_interval``-th layer
+        from dynamo_tpu.models import qwen3_next
+        return qwen3_next
     if cfg.attn_blocks_per_layer == 2:
         # LongCat-Flash: double layers of latent attention around a
         # shortcut-connected expert branch with zero-compute experts

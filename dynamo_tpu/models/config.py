@@ -133,14 +133,79 @@ class ModelConfig:
     # sqrt(hidden / rank) (1.0 = the DeepSeek form)
     mla_q_scale: float = 1.0
     mla_kv_scale: float = 1.0
+    # Qwen3-Next family (models/qwen3_next.py): layer ``i`` is gated full
+    # attention where ``(i + 1) % full_attention_interval == 0`` and a Gated
+    # DeltaNet linear-attention layer otherwise (0 = every layer attends:
+    # every other family). A linear layer keeps no pages: a request carries
+    # one recurrent state ``[linear_num_value_heads, linear_key_head_dim,
+    # linear_value_head_dim]`` float32 and the convolution's last
+    # ``linear_conv_kernel_dim - 1`` inputs a layer, in a slot of the state
+    # pool beside the paged cache (``state_layers`` of them)
+    full_attention_interval: int = 0
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 0
+    # rotary position embedding turns the first ``partial_rotary_factor``
+    # of a head's dimensions only
+    partial_rotary_factor: float = 1.0
+    # one always-on expert beside the routed ones, its output gated by a
+    # sigmoid of the token (0 = none)
+    shared_expert_intermediate_size: int = 0
 
     @property
     def q_size(self) -> int:
         return self.num_heads * self.head_dim
 
     @property
+    def num_periods(self) -> int:
+        """Periods of ``full_attention_interval`` layers (linear layers,
+        then one full-attention layer); 0 for a family without."""
+        return (self.num_layers // self.full_attention_interval
+                if self.full_attention_interval else 0)
+
+    @property
+    def state_layers(self) -> int:
+        """Layers that keep a recurrent state and no pages."""
+        return self.num_periods * max(self.full_attention_interval - 1, 0)
+
+    @property
     def num_cache_layers(self) -> int:
-        return self.num_layers * self.attn_blocks_per_layer
+        """Layers of the paged pool: the attention blocks that keep keys
+        and values (the full-attention layers alone where the others are
+        linear)."""
+        return ((self.num_layers - self.state_layers)
+                * self.attn_blocks_per_layer)
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """``"linear_attention"`` / ``"full_attention"`` a layer."""
+        n = self.full_attention_interval
+        return tuple("linear_attention" if n and (i + 1) % n
+                     else "full_attention" for i in range(self.num_layers))
+
+    def paged_only(self, what: str) -> None:
+        """Raise, by the family's name, where ``what`` moves block chains
+        of the paged cache only: a request of a family with linear layers
+        is its pages AND its slot of the state pool, and a chain without
+        the matching state is a wrong answer, not a slow one."""
+        if self.state_layers:
+            raise NotImplementedError(
+                f"model_type {self.model_type!r} keeps a recurrent state "
+                f"beside the paged cache ({self.state_layers} "
+                f"linear-attention layers): {what} moves block chains only "
+                "and cannot move a state yet")
+
+    @property
+    def linear_conv_dim(self) -> int:
+        """Channels the linear layers' convolution runs over: q | k | v."""
+        return (2 * self.linear_num_key_heads * self.linear_key_head_dim
+                + self.linear_num_value_heads * self.linear_value_head_dim)
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor)
 
     @property
     def experts_held(self) -> int:
@@ -188,11 +253,30 @@ class ModelConfig:
         if not 0 <= self.ep_rank < self.ep_size:
             raise ValueError(
                 f"ep_rank {self.ep_rank} outside ep_size {self.ep_size}")
+        n = self.full_attention_interval
+        if n and (n < 2 or self.num_layers % n):
+            raise ValueError(
+                f"full_attention_interval {n} does not cut "
+                f"{self.num_layers} layers into whole periods of at least "
+                "one linear and one full-attention layer")
 
     @classmethod
     def from_hf(cls, hf: Dict[str, Any], dtype: str = "bfloat16") -> "ModelConfig":
         if "expert_ffn_hidden_size" in hf and "ffn_hidden_size" in hf:
             return cls._from_longcat(hf, dtype)
+        kinds = set(hf.get("layer_types") or ())
+        if ("full_attention_interval" in hf
+                or "linear_attention" in kinds
+                or any(k.startswith("linear_") for k in hf)):
+            return cls._from_qwen3_next(hf, dtype)
+        if kinds - {"full_attention"} and not str(
+                hf.get("model_type", "")).startswith("gemma"):
+            # a per-layer kind this loader would silently drop: the file
+            # describes another model than the one it would build
+            raise NotImplementedError(
+                f"layer_types holds {sorted(kinds - {'full_attention'})}: "
+                "only full-attention layers (and the qwen3_next pattern of "
+                "linear_attention layers) are implemented")
         heads = hf["num_attention_heads"]
         mt = hf.get("model_type", "llama")
         num_experts = hf.get("num_local_experts", hf.get("num_experts", 0)) or 0
@@ -342,6 +426,91 @@ class ModelConfig:
                          if hf.get("mla_scale_q_lora") else 1.0),
             mla_kv_scale=((H / kv_rank) ** 0.5
                           if hf.get("mla_scale_kv_lora") else 1.0),
+        )
+
+    @classmethod
+    def _from_qwen3_next(cls, hf: Dict[str, Any], dtype: str) -> "ModelConfig":
+        """The Qwen3-Next family, read off its own keys
+        (``full_attention_interval``, ``linear_*``, ``partial_rotary_factor``,
+        ``shared_expert_intermediate_size``), never off a model_type. What
+        of the published keys the family does not implement is an error
+        that names the key, not a plain attention model built from the
+        keys this loader knows.
+
+        As for LongCat, a directory written for one rank of an
+        expert-parallel deployment says so with ``ep_rank`` beside
+        ``ep_size``: ``num_experts`` then counts the experts HELD and the
+        router's width is ``num_experts * ep_size``."""
+        def no(key, why):
+            raise NotImplementedError(
+                f"{key} {hf.get(key)!r}: {why} (models/qwen3_next.py)")
+        wanted = ("full_attention_interval", "linear_num_key_heads",
+                  "linear_num_value_heads", "linear_key_head_dim",
+                  "linear_value_head_dim", "linear_conv_kernel_dim")
+        for key in wanted:
+            if not hf.get(key):
+                no(key, "the linear-attention family needs every one of "
+                        + ", ".join(wanted))
+        n, L = int(hf["full_attention_interval"]), int(hf["num_hidden_layers"])
+        kinds = hf.get("layer_types")
+        if kinds is not None and list(kinds) != [
+                "linear_attention" if (i + 1) % n else "full_attention"
+                for i in range(L)]:
+            no("layer_types", "only the pattern full_attention_interval "
+                              "gives is implemented")
+        if int(hf.get("decoder_sparse_step") or 1) != 1:
+            no("decoder_sparse_step", "every layer's FFN is the sparse "
+                                      "block")
+        if hf.get("mlp_only_layers"):
+            no("mlp_only_layers", "every layer's FFN is the sparse block")
+        if hf.get("use_sliding_window"):
+            no("use_sliding_window", "the full-attention layers see their "
+                                     "whole context")
+        if hf.get("rope_scaling"):
+            no("rope_scaling", "plain rotary positions only")
+        if (hf.get("hidden_act") or "silu") != "silu":
+            no("hidden_act", "SwiGLU experts")
+        if int(hf["linear_num_value_heads"]) % int(
+                hf["linear_num_key_heads"]):
+            no("linear_num_value_heads", "a key head serves a whole number "
+                                         "of value heads")
+        if not hf.get("num_experts"):
+            no("num_experts", "the family's FFN is the sparse block")
+        ep_size = int(hf.get("ep_size") or 1) if "ep_rank" in hf else 1
+        heads = int(hf["num_attention_heads"])
+        return cls(
+            vocab_size=hf["vocab_size"],
+            hidden_size=int(hf["hidden_size"]),
+            intermediate_size=int(hf.get("intermediate_size") or 0),
+            num_layers=L,
+            num_heads=heads,
+            num_kv_heads=int(hf.get("num_key_value_heads", heads)),
+            head_dim=int(hf.get("head_dim")
+                         or hf["hidden_size"] // heads),
+            rope_theta=float(hf.get("rope_theta", 10000.0)),
+            rms_norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+            max_position_embeddings=hf.get("max_position_embeddings", 8192),
+            tie_word_embeddings=bool(hf.get("tie_word_embeddings", False)),
+            qk_norm=True,
+            attention_bias=bool(hf.get("attention_bias", False)),
+            model_type=hf.get("model_type", "qwen3_next"),
+            dtype=dtype,
+            num_experts=int(hf["num_experts"]) * ep_size,
+            num_experts_per_tok=int(hf["num_experts_per_tok"]),
+            moe_intermediate_size=int(hf["moe_intermediate_size"]),
+            norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+            ep_size=ep_size,
+            ep_rank=int(hf.get("ep_rank") or 0),
+            full_attention_interval=n,
+            linear_num_key_heads=int(hf["linear_num_key_heads"]),
+            linear_num_value_heads=int(hf["linear_num_value_heads"]),
+            linear_key_head_dim=int(hf["linear_key_head_dim"]),
+            linear_value_head_dim=int(hf["linear_value_head_dim"]),
+            linear_conv_kernel_dim=int(hf["linear_conv_kernel_dim"]),
+            partial_rotary_factor=float(
+                hf.get("partial_rotary_factor") or 1.0),
+            shared_expert_intermediate_size=int(
+                hf.get("shared_expert_intermediate_size") or 0),
         )
 
     @classmethod

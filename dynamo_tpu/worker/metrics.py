@@ -311,6 +311,33 @@ class EngineDispatchCollector:
         for reason, value in sorted(why.items()):
             refused.add_metric([str(reason)], float(value))
         yield refused
+        # the recurrent-state pool of a family with linear-attention
+        # layers (both 0 for every other family), and the prefix lookups
+        # such a family does not make
+        yield GaugeMetricFamily(
+            "dynamo_worker_state_slots_total",
+            "Slots of the recurrent-state pool (--state-slots): requests "
+            "of a model with linear-attention layers that can be admitted "
+            "at once; 0 for a model that keeps no such state",
+            value=float(stats.get("state_slots_total", 0)))
+        yield GaugeMetricFamily(
+            "dynamo_worker_state_slots_in_use",
+            "Slots of the recurrent-state pool held by admitted requests "
+            "(given at admission, taken back at finish and at preemption)",
+            value=float(stats.get("state_slots_in_use", 0)))
+        pr = CounterMetricFamily(
+            "dynamo_worker_prefix_reuse_refused",
+            "Admissions whose prefix lookup was not made because a hit "
+            "could not be used, by reason: 'recurrent_state' (the model "
+            "keeps a state beside the paged cache; a prefix's pages "
+            "without the state that matches them are a wrong answer, so "
+            "the whole prompt is computed)",
+            labels=["reason"])
+        why = {"recurrent_state": 0.0}
+        why.update(stats.get("prefix_reuse_refused") or {})
+        for reason, value in sorted(why.items()):
+            pr.add_metric([str(reason)], float(value))
+        yield pr
         # prefill-carrying steps by the form they ran in, so a model that
         # silently serves padded shows on the scrape
         pf = CounterMetricFamily(
@@ -506,6 +533,12 @@ def engine_dispatch_stats(engine) -> Dict[str, object]:
         "mixed_dispatches": float(getattr(engine, "mixed_steps", 0)),
         "packed_decode_kernel_rows": float(
             getattr(engine, "packed_decode_kernel_rows", 0)),
+        "state_slots_total": float(getattr(engine, "state_slots", 0)),
+        "state_slots_in_use": float(
+            getattr(engine, "state_slots", 0)
+            - len(getattr(sched, "_free_slots", ()))),
+        "prefix_reuse_refused": dict(
+            getattr(sched, "prefix_reuse_refused", None) or {}),
         "guided_parity_mismatches": float(
             getattr(engine, "guided_parity_mismatches", 0)),
         "multistep_fallbacks": dict(

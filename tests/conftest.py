@@ -57,6 +57,7 @@ ASYNC_TEST_TIMEOUT = float(os.environ.get("DYN_TEST_TIMEOUT", "60"))
 LONGEST_FIRST = (
     "benchmarks/test_benchmarks_e2e.py",
     "test_packed_step.py",
+    "test_qwen3_next.py",
     "test_sdar.py",
     "test_pallas_tpu_lowering.py",
     "test_deepseek.py",

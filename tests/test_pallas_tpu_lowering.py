@@ -23,12 +23,12 @@ def _native_kernels(monkeypatch):
     """Pin interpret OFF during export: ``_resolve_interpret(None)`` keys
     off ``jax.default_backend()`` (cpu here), but these tests lower for
     the TPU platform — the kernels must take their native path."""
-    from dynamo_tpu.ops.pallas import (decode, mla_decode, mla_prefill,
+    from dynamo_tpu.ops.pallas import (decode, gdn, mla_decode, mla_prefill,
                                        mla_ragged, moe_grouped, prefill,
                                        ragged)
 
     for mod in (decode, prefill, mla_decode, mla_prefill, mla_ragged,
-                ragged, moe_grouped):
+                ragged, moe_grouped, gdn):
         monkeypatch.setattr(mod, "_resolve_interpret",
                             lambda interpret: False)
 
@@ -771,3 +771,134 @@ def test_mla_engine_packs_without_a_pool_copy():
     assert vocab_sorts(hlo, cfg.vocab_size) == []
     # a third of the padded [16, 512] step's 1.66 GB of temporaries
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+# -- the gated delta rule and a 256-wide head (ISSUE 43) -------------------
+
+@pytest.mark.parametrize("N,R", [(1152, 64), (64, 64)])
+def test_the_gated_delta_rule_compiles_in_the_tpu_compiler(N, R):
+    """``gdn_chunk`` and ``gdn_step`` at Qwen3-Next's widths (16 key heads,
+    32 value heads of 128, six linear layers' states of 65 slots) over the
+    cell's packed step (1,152 slots, 64 rows) and over a decode step (one
+    slot a row: the chunk form is not in the program): the TPU compiler
+    takes both, and the state pool is aliased, not copied."""
+    from dynamo_tpu.ops import gdn
+
+    one_chip = _v5e_chip()
+    Hk, Hv, D = 16, 32, 128
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def rule(q, k, v, g, b, pool, layer, start, new, total, slots):
+        rows = gdn.token_rows(N, start, new, total, slots)
+        return gdn.gated_delta_rule(q, k, v, g, b, pool, layer, rows,
+                                    use_pallas=True, several=N > R)
+
+    bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    compiled = jax.jit(rule, donate_argnums=(5,)).lower(
+        sds((N, Hk, D), bf16), sds((N, Hk, D), bf16), sds((N, Hv, D), bf16),
+        sds((N, Hv), f32), sds((N, Hv), f32), sds((6, 65, Hv, D, D), f32),
+        sds((), i32), *[sds((R,), i32)] * 4).compile()
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    names = sorted(ln.split("=")[0].strip().split(".")[0] for ln in calls)
+    assert names == (["%gdn_chunk", "%gdn_step"] if N > R else ["%gdn_step"])
+    pool_bytes = 6 * 65 * Hv * D * D * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes / 2
+
+
+def test_the_paged_kernels_compile_at_a_head_of_256():
+    """``paged_decode`` and ``ragged_mixed`` at Qwen3-Next's gated
+    attention (16 query heads over 2 key/value heads of 256: eight queries
+    a key head), the cell's pool and table, 64 rows and 1,152 slots."""
+    from dynamo_tpu.ops.pallas.decode import paged_decode_attention_stacked
+    from dynamo_tpu.ops.pallas.ragged import ragged_mixed_attention_packed
+
+    one_chip = _v5e_chip()
+    Hq, Hkv, Dh, R, T, P = 16, 2, 256, 64, 1152, 800
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pages = sds((2, 51200, 2, Hkv, PS, Dh), jnp.bfloat16)
+    i32 = jnp.int32
+
+    def decode(q, pages, table, positions, total):
+        return paged_decode_attention_stacked(q, pages, 1, table, positions,
+                                              total, Dh ** -0.5)
+
+    def packed(q, pages, table, starts, q_lens, kv_lens):
+        return ragged_mixed_attention_packed(q, pages, 1, table, starts,
+                                             q_lens, kv_lens, Dh ** -0.5)
+
+    text = jax.jit(decode).lower(
+        sds((R, 1, Hq, Dh), jnp.bfloat16), pages, sds((R, P), i32),
+        sds((R, 1), i32), sds((R,), i32)).compile().as_text()
+    assert "paged_decode" in text
+    text = jax.jit(packed).lower(
+        sds((T, Hq, Dh), jnp.bfloat16), pages, sds((R, P), i32),
+        sds((R,), i32), sds((R,), i32), sds((R,), i32)).compile().as_text()
+    assert "ragged_mixed" in text and "paged_decode" in text
+
+
+def test_qwen3_next_step_programs_compile_for_a_v5e():
+    """The long-document cell's two step programs - the token-packed step
+    of 1,152 slots over 64 rows and the fused block of two decode steps -
+    at the published widths (abstract weights: 4,133,998,720 parameters)
+    and the cell's pools compile for a v5e with the rule's two kernels, the
+    paged kernels and ``moe_grouped`` in them, with neither the paged pool
+    nor the state pool copied, inside the chip's memory."""
+    import json
+    import os
+
+    from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
+    from dynamo_tpu.engine.program_check import pool_copies, step_programs
+    from dynamo_tpu.models import qwen3_next
+    from dynamo_tpu.models.config import ModelConfig
+
+    one_chip = _v5e_chip()
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "configs",
+        "qwen3-next-80b-a3b-instruct.json")
+    with open(path) as f:
+        hf = json.load(f)
+    args = hf.pop("benchmark")["worker_args"]
+    args = {args[i]: int(args[i + 1]) for i in range(0, len(args), 2)
+            if args[i + 1].isdigit()}
+    cfg = ModelConfig.from_hf(hf)
+    abs_params = jax.eval_shape(
+        lambda: qwen3_next.init_params(cfg, jax.random.PRNGKey(0)))
+    rows, chunk = args["--max-num-seqs"], args["--max-prefill-chunk"]
+    eng = JaxEngine(cfg, abs_params, JaxEngineConfig(
+        num_pages=16, page_size=16, max_num_seqs=rows,
+        max_context=args["--max-context"], max_prefill_chunk=chunk,
+        attn_impl="pallas", decode_multistep=args["--decode-multistep"],
+        state_slots=args["--state-slots"]))
+    assert eng.padded_reason is None
+    assert eng._packed_cap == args["--min-prefill-bucket"]
+    assert eng.packed_attention == (
+        "chunks:ragged_mixed,one_token:paged_decode")
+    programs = step_programs(
+        eng, rows, chunk, width=args["--decode-multistep"],
+        sharding=one_chip, num_pages=args["--num-pages"],
+        tokens=eng._packed_cap)
+    pool = (2, args["--num-pages"]) + tuple(eng.kv_pool.shape[2:])
+    state = f"f32[6,{rows + 1},32,128,128]"
+    want = {"packed": {"gdn_chunk", "gdn_step", "moe_grouped",
+                       "paged_decode", "ragged_mixed"},
+            "fused": {"gdn_step", "moe_grouped", "paged_decode"}}
+    for name, kernels in want.items():
+        fn, fn_args = programs[name]
+        compiled = fn.lower(*fn_args).compile()
+        hlo = compiled.as_text()
+        calls = {ln.split("=")[0].strip().lstrip("%").split(".")[0]
+                 for ln in hlo.splitlines() if "tpu_custom_call" in ln}
+        assert calls == kernels, name
+        assert pool_copies(hlo, pool, eng.kv_pool.dtype) == []
+        assert not [ln for ln in hlo.splitlines()
+                    if " copy(" in ln and state in ln.split(" copy(")[0]]
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
