@@ -51,6 +51,108 @@ def _unsort(order: jnp.ndarray, values: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.sort((order, values), num_keys=1)[1]
 
 
+def _windows(values: jnp.ndarray, start: jnp.ndarray,
+             width: int) -> jnp.ndarray:
+    """``out[i] = values[start[i] : start[i] + width]`` (zeros past the
+    end), ``width`` a power of two, ``0 <= start[i] <= len(values)``.
+
+    Not as a gather of ``width``-long slices: XLA's TPU compiler expands
+    that into a loop of one ``dynamic-slice`` an index, and what it does
+    run as one operation, a gather of single entries, costs ~8 ns an entry
+    whatever it fetches (PERF.md section 6, PR 44). It fetches whole rows
+    of a 2-D table at the same price an index, so: the list as rows of
+    ``width``, the two rows a window straddles (``2 * len(start)``
+    indices), and the window's start brought to lane 0 by ``log2(width)``
+    static rotations, each taken where ``start % width`` has that bit."""
+    n = values.shape[0]
+    rows = n // width + 2
+    table = jnp.pad(values, (0, rows * width - n)).reshape(rows, width)
+    first_row, lane = start // width, start % width
+    pair = table[jnp.stack([first_row, first_row + 1], axis=1)]
+    pair = pair.reshape(-1, 2 * width)
+    step = 1
+    while step < width:
+        pair = jnp.where((lane & step != 0)[:, None],
+                         jnp.roll(pair, -step, axis=1), pair)
+        step *= 2
+    return pair[:, :width]
+
+
+def _sorted_picks(flat_e: jnp.ndarray, E: int, k: int):
+    """The ``T * k`` assignments sorted by expert (stable; ``flat_e`` is
+    ``E`` for a pick that is not computed here, which sorts behind every
+    group): ``(sorted_t [A], order [A], first [E + 1])`` - the token of
+    each sorted assignment, the permutation, and ``first[e]``, the
+    assignments of experts below ``e`` (``first[E]``: all that are computed
+    here). The experts in order are the sort's own first output: no
+    ``flat_e[order]`` gather."""
+    i32 = jnp.int32
+    A = flat_e.shape[0]
+    sorted_e, order = jax.lax.sort(
+        (flat_e, jnp.arange(A, dtype=i32)), num_keys=1, is_stable=True)
+    # compare_all: one fused comparison of every pair; the default binary
+    # search is a loop of a dozen small device operations
+    first = jnp.searchsorted(sorted_e, jnp.arange(E + 1, dtype=i32),
+                             method="compare_all").astype(i32)
+    return order // k, order, first
+
+
+def _tile_plan(sorted_t: jnp.ndarray, order: jnp.ndarray,
+               first: jnp.ndarray, *, tm: int, n_tiles: int):
+    """Which token each row of the grouped call holds, built from the
+    tile's side. The call has ``n_tiles * tm`` rows; a group starts on a
+    tile boundary, so a tile has one expert, and a group's rows are
+    consecutive entries of the sorted assignment list: row ``r`` of expert
+    ``e`` holds entry ``r - shift[e]``, and entry ``a`` of expert ``e``
+    sits in row ``a + shift[e]``, with ``shift[e] = row_first[e] -
+    first[e]`` - one table of ``E`` offsets serves both directions.
+
+    Looked up a TILE (``n_tiles`` indices each): its expert's ``shift`` and
+    the row its group ends at. Computed a row, under a broadcast over
+    ``[n_tiles, tm]``: whether it is live (a compare) and which entry it
+    holds (an add) - and the entries themselves come as ``n_tiles`` whole
+    windows of the sorted list (``_windows``), not as ``n_tiles * tm``
+    single lookups. An assignment looks nothing up either: its expert's
+    ``shift`` comes from comparing its place in the sorted list with the
+    groups' starts.
+
+    Returns ``(row_tok [M], row_live [M], pos [A], tile_expert [n_tiles],
+    num_tiles)``: each row's token (0 where dead), whether a group owns
+    the row, each assignment's row in token-major order (meaningless for a
+    pick no group holds), each tile's expert (tiles past the last keep its
+    expert: nothing is fetched for them) and the number of live tiles."""
+    i32 = jnp.int32
+    A, E = sorted_t.shape[0], first.shape[0] - 1
+    counts = first[1:] - first[:-1]                         # [E]
+    tiles = -(-counts // tm)
+    tile_end = jnp.cumsum(tiles)
+    num_tiles = tile_end[-1]
+    row_first = (tile_end - tiles) * tm                     # [E]
+    shift = row_first - first[:-1]
+    tile = jnp.arange(n_tiles, dtype=i32)
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        tile_end, tile, side="right", method="compare_all"),
+        E - 1).astype(i32)
+    alive, row0, row_end = tile < num_tiles, tile * tm, row_first + counts
+    src0 = jnp.where(alive, row0 - shift[tile_expert], 0)
+    left = jnp.where(alive, row_end[tile_expert] - row0, 0)
+    row_live = jnp.arange(tm, dtype=i32)[None, :] < left[:, None]
+    row_tok = jnp.where(row_live, _windows(sorted_t, src0, tm), 0)
+    tile_expert = jnp.where(
+        alive, tile_expert, tile_expert[jnp.maximum(num_tiles - 1, 0)])
+    # an assignment's shift without a lookup: over the sorted list it steps
+    # at each group's start (groups of no pick share a start, their steps
+    # add up there) - every entry compared with every start, the fused
+    # form ``first`` itself comes from; picks no group holds take the last
+    entry = jnp.arange(A, dtype=i32)
+    entry_shift = jnp.sum(jnp.where(
+        entry[:, None] >= first[None, :-1],
+        jnp.diff(shift, prepend=0)[None, :], 0), axis=1)
+    pos = _unsort(order, entry + entry_shift)
+    return (row_tok.reshape(n_tiles * tm), row_live.reshape(n_tiles * tm),
+            pos, tile_expert, num_tiles)
+
+
 def grouped_experts(xt: jnp.ndarray, top_w: jnp.ndarray,
                     top_i: jnp.ndarray, w_gate, w_up, w_down, *,
                     layer=None, valid=None, use_pallas: bool = False,
@@ -65,7 +167,10 @@ def grouped_experts(xt: jnp.ndarray, top_w: jnp.ndarray,
     no drop: the result equals the every-expert-on-every-token mask form
     up to summation order, at ``k / E`` of its FLOPs and without its
     ``[T, E, I]`` temporaries. Sorts, searches and gathers only: no
-    scatter.
+    scatter. In front of the kernel the plan of which row holds which
+    token is built a tile at a time (``_tile_plan``): two lookups a tile,
+    a compare and an add a row, the sorted list fetched as whole windows -
+    a row or an assignment looks nothing up by itself.
 
     ``xt [T, H]``; ``top_w``/``top_i [T, k]``; weights ``[E, H, I]`` /
     ``[E, I, H]``, or stacked ``[L, E, ...]`` with ``layer`` the (traced)
@@ -120,14 +225,7 @@ def grouped_experts(xt: jnp.ndarray, top_w: jnp.ndarray,
         # an unrouted assignment sorts behind every expert's
         flat_e = jnp.where(live, flat_e, E)
     with jax.named_scope("sort"):
-        order = jnp.argsort(flat_e, stable=True).astype(i32)
-        sorted_e = flat_e[order]
-        sorted_t = order // k
-        # first[e]: assignments of experts below e; first[E]: all routed
-        # (compare_all: one fused comparison of every pair; the default
-        # binary search is a loop of a dozen small device operations)
-        first = jnp.searchsorted(sorted_e, jnp.arange(E + 1, dtype=i32),
-                                 method="compare_all").astype(i32)
+        sorted_t, order, first = _sorted_picks(flat_e, E, k)
         counts = first[1:] - first[:-1]                     # [E]
     if whole:               # every pick of a valid token is computed here
         picks = first[E]
@@ -154,31 +252,9 @@ def grouped_experts(xt: jnp.ndarray, top_w: jnp.ndarray,
         tm = 16 if A <= 2048 else 128
         held = T * min(k, E)                    # bound on sum c
         n_tiles = -(-held // tm) + min(E, held)  # ... on sum ceil(c / tm)
-        M = n_tiles * tm
         with jax.named_scope("sort"):
-            tiles = -(-counts // tm)                        # [E]
-            tile_end = jnp.cumsum(tiles)
-            num_tiles = tile_end[-1]
-            row_first = (tile_end - tiles) * tm             # [E]
-            tile_expert = jnp.minimum(jnp.searchsorted(
-                tile_end, jnp.arange(n_tiles, dtype=i32),
-                side="right", method="compare_all"), E - 1).astype(i32)
-            # each row's token, from the row's side: the tile's expert,
-            # the row's rank in its group, the sorted assignment there
-            row = jnp.arange(M, dtype=i32)
-            row_e = tile_expert[row // tm]
-            row_rank = row - row_first[row_e]
-            live = (row // tm < num_tiles) & (row_rank < counts[row_e])
-            row_tok = jnp.where(live, sorted_t[jnp.minimum(
-                first[row_e] + row_rank, A - 1)], 0)
-            # tiles past the last keep its expert: nothing is fetched
-            tile_expert = jnp.where(
-                jnp.arange(n_tiles) < num_tiles, tile_expert,
-                tile_expert[jnp.maximum(num_tiles - 1, 0)])
-            # each assignment's row, back in token-major order
-            safe_e = jnp.minimum(sorted_e, E - 1)
-            pos = _unsort(order, row_first[safe_e]
-                          + jnp.arange(A, dtype=i32) - first[safe_e])
+            row_tok, _, pos, tile_expert, num_tiles = _tile_plan(
+                sorted_t, order, first, tm=tm, n_tiles=n_tiles)
         with jax.named_scope("experts"):
             ys = moe_grouped(
                 xt[row_tok], tile_expert, num_tiles.reshape(1),

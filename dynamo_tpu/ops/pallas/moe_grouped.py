@@ -3,7 +3,13 @@
 The exact expert layer (``models/moe.grouped_experts``) sorts the ``T * k``
 token-to-expert assignments by expert and lays them out in row tiles of
 ``tm`` rows, each group starting on a tile boundary, so every tile belongs
-to ONE expert. This kernel walks the tiles and computes, per tile,
+to ONE expert. The layout is planned from the tile's side
+(``models/moe._tile_plan``): what is constant over a tile - its expert,
+how far its group's rows sit past the group's place in the sorted list,
+the row the group ends at - is looked up once a TILE; a ROW only compares
+(is it live) and adds (which sorted assignment it holds), and the tokens
+arrive as one window of ``tm`` consecutive sorted assignments a tile. This
+kernel walks the tiles and computes, per tile,
 
     out = (silu(x @ W_gate[e]) * (x @ W_up[e])) @ W_down[e]        (f32)
 

@@ -546,6 +546,67 @@ def test_grouped_layer_with_a_held_range_compiles_in_the_tpu_compiler(
     assert not [ln for ln in text.splitlines() if " scatter(" in ln]
 
 
+def test_the_routing_plan_looks_nothing_up_a_row_in_the_tpu_compiler():
+    """``grouped_experts`` at the long-document step of the Qwen3-Next cell
+    (1,152 slots, top-10 of 512 with 128 held, experts of 2,048 x 512): the
+    ``sort`` scope of the compiled program holds no gather with a single
+    index a row of the grouped call (27,904) or an assignment (11,520) -
+    neither from the ``[E]``-sized tables nor from the sorted list. What it
+    looks up is a tile's (218 indices) and the two table rows a tile's
+    window of the sorted list straddles (436 indices of 128 entries), and
+    no loop stands in for a gather. The row-wise plan cost 8 ns an index,
+    five times a row (PERF.md section 6, PR 44)."""
+    import math
+    import re
+
+    from dynamo_tpu.models.moe import grouped_experts
+
+    one_chip = _v5e_chip()
+    T, k, E, routed, H, I, layers = 1152, 10, 128, 512, 2048, 512, 2
+    tm, n_tiles = 128, -(-T * k // 128) + E
+    M = n_tiles * tm
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def share_of_a_layer(xt, top_w, top_i, wg, wu, wd, layer, valid):
+        return grouped_experts(xt, top_w, top_i, wg, wu, wd, layer=layer,
+                               valid=valid, use_pallas=True,
+                               first_expert=0, num_routed=routed)
+
+    text = jax.jit(share_of_a_layer).lower(
+        sds((T, H), jnp.bfloat16), sds((T, k), jnp.float32),
+        sds((T, k), jnp.int32), sds((layers, E, H, I), jnp.bfloat16),
+        sds((layers, E, H, I), jnp.bfloat16),
+        sds((layers, E, I, H), jnp.bfloat16), sds((), jnp.int32),
+        sds((T,), jnp.bool_)).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and f"f32[{M},{H}]" in calls[0]
+
+    def scoped(ln):
+        found = re.search(r'op_name="([^"]*)"', ln)
+        return found is not None and "/sort/" in found.group(1)
+
+    looked_up = {}                  # indices a gather -> entries an index
+    for ln in text.splitlines():
+        if " gather(" not in ln or not scoped(ln):
+            continue
+        count, entries = (
+            math.prod(int(d) for d in re.search(pattern, ln).group(1).split(
+                ","))
+            for pattern in (r"= \w+\[([\d,]*)\]",
+                            r"slice_sizes=\{([\d,]*)\}"))
+        looked_up[count // entries] = entries
+    assert looked_up, "the sort scope's gathers were not found by name"
+    assert max(looked_up) == 2 * n_tiles and looked_up[2 * n_tiles] == tm
+    assert all(entries == 1 for indices, entries in looked_up.items()
+               if indices != 2 * n_tiles)
+    # a gather of longer slices is expanded into a loop of dynamic slices
+    assert not [ln for ln in text.splitlines()
+                if " while(" in ln and scoped(ln)]
+    assert not [ln for ln in text.splitlines() if " scatter(" in ln]
+
+
 # -- generation by diffusion over blocks (SDAR): the block-wise visibility --
 
 @pytest.mark.parametrize("S", [4, 512])
