@@ -557,6 +557,9 @@ class JaxEngine(ScheduledEngineBase):
         # from a mixed step's packed output to the carry of the block
         # chained behind it (``_handover_impl``), made on first use
         self._jit_handover: Optional[Callable] = None
+        # the tokens of a mixed step chained behind a mixed step, its
+        # decode rows' from that step's packed output (``_fill_impl``)
+        self._jit_fill: Optional[Callable] = None
         self.decode_dispatches = 0   # decode-family jitted dispatches
         self.multistep_blocks = 0    # of which fused multi-step blocks
         self.mixed_steps = 0         # mixed prefill+decode dispatches
@@ -1306,6 +1309,30 @@ class JaxEngine(ScheduledEngineBase):
         return {"tok": tok, "pos": pos[:, None], "total": total,
                 "alive": alive, "budget": budget, "min_gate": min_gate}
 
+    def _fill_impl(self, toks, prev_packed, fill):
+        """The token array of a mixed step chained behind a mixed step,
+        completed on the device: the host left its decode rows' slots
+        empty, and ``fill`` ``[2, B]`` int32, its one more upload, says
+        for each the slot of ``toks`` (flattened; the padded form's row
+        ``i`` starts at ``i * S``) and the row of ``prev_packed`` whose
+        column 0 holds its token (``Scheduler.plan_mixed_behind``). Pad
+        entries point past the end and are dropped. No row is masked
+        here: one that this token ends rides the step with it and the
+        host drops what it samples."""
+        slot, src = fill
+        return toks.reshape(-1).at[slot].set(
+            prev_packed[src, 0], mode="drop").reshape(toks.shape)
+
+    def _get_jit_fill(self):
+        fn = self._jit_fill
+        if fn is None:
+            # on a mesh its output is replicated, as the host's upload of
+            # the same array is to the step program
+            rep = self._replicated()
+            kw = {} if rep is None else {"out_shardings": rep}
+            fn = self._jit_fill = jax.jit(self._fill_impl, **kw)
+        return fn
+
     def _replicated(self):
         """The mesh's fully replicated sharding where the page pool is
         sharded over one, else None."""
@@ -1694,15 +1721,18 @@ class JaxEngine(ScheduledEngineBase):
         with stage("wait"):
             return self.fetch_packed(packed)
 
-    def _dispatch_prefill(self, plan, mixed: bool):
+    def _dispatch_prefill(self, plan, mixed: bool, prev_packed=None):
         """Dispatch one prefill-carrying step without fetching its
-        result: (the device's packed output, the host arrays it ran on)."""
+        result: (the device's packed output, the host arrays it ran on).
+        ``prev_packed``: the packed output of the mixed step a chained
+        one (``plan.behind``) takes its decode rows' tokens from."""
         with stage("assemble"):
             kind, _chunks, arrays = self._prefill_arrays(plan, mixed)
         plan._step_id = self._step_counter
         if self.step_tap is not None:
             self.step_tap(kind, arrays, self._step_counter)
-        packed = self._invoke_step(kind, arrays, self._step_counter)
+        packed = self._invoke_step(kind, arrays, self._step_counter,
+                                   prev_packed=prev_packed)
         self._step_counter += 1
         return packed, arrays
 
@@ -1714,13 +1744,17 @@ class JaxEngine(ScheduledEngineBase):
         P = self.table_width
         chunks = list(plan.chunks)
         ring = (not mixed) and plan.ring
+        # a step chained behind a mixed step: its decode rows' tokens are
+        # that step's, still on the device (``_fill_impl``)
+        behind = mixed and bool(plan.behind)
         if mixed:
             # decode rows ARE ragged chunks of length 1: feed the
             # newest token at position len-1 (== num_computed), sample
             # its successor at the row's last-real-token slot — the
-            # same array shape the prefill rows use
-            chunks += [PrefillChunk(seq=s, start=len(s) - 1, length=1,
-                                    is_last=True)
+            # same array shape the prefill rows use (chained: the newest
+            # token is the one in flight, at position len)
+            chunks += [PrefillChunk(seq=s, start=len(s) - 1 + behind,
+                                    length=1, is_last=True)
                        for s in plan.decode_seqs]
         # the form of this step: token-packed, or padded and why
         reason = "ring" if ring else self.padded_reason
@@ -1756,13 +1790,22 @@ class JaxEngine(ScheduledEngineBase):
         top_k = np.zeros(B, np.int32)
         top_p = np.ones(B, np.float32)
         at = 0                         # a packed row's first slot
+        # (chained) for each decode row the slot of ``toks``, flattened,
+        # that its token goes to and the row of the previous step's
+        # packed output that holds it; a pad entry's slot lies past the
+        # end and is dropped
+        fill = np.full((2, B), toks.size, np.int32) if behind else None
+        first_decode = len(plan.chunks)
         for i, c in enumerate(chunks):
             seq = c.seq
             # where the row's new tokens go: its own padded row, or
             # its slots of the packed axis
             row, lo = (0, at) if pack else (i, 0)
             at += c.length
-            if c.length == 1 and c.start == len(seq) - 1:
+            if behind and i >= first_decode:
+                j = i - first_decode
+                fill[:, j] = (row * S + lo, plan.src_rows[j])
+            elif c.length == 1 and c.start == len(seq) - 1:
                 # decode row: skip the O(context) token-list build
                 toks[row, lo] = seq.tokens.last_token()
             else:
@@ -1798,6 +1841,8 @@ class JaxEngine(ScheduledEngineBase):
         arrays = dict(toks=toks, pos=pos, table=table, total=total, new=new,
                       temp=temp, top_k=top_k, top_p=top_p,
                       **self._sampling_extras([c.seq for c in chunks], B))
+        if behind:
+            arrays["fill"] = fill
         return kind, chunks, arrays
 
     def _decode_arrays(self, seqs, chained: bool) -> dict:
@@ -1988,11 +2033,14 @@ class JaxEngine(ScheduledEngineBase):
                       "top_lps": hostf[:, 2 + K:]}
         return sampled, logprobs, extras
 
-    def dispatch_step(self, plan):
+    def dispatch_step(self, plan, prev_handle=None):
         """Dispatch one mixed step WITHOUT fetching its result; returns
-        the on-device packed output, for ``fetch_packed`` and for the
-        block chained behind it (``dispatch_multistep``)."""
-        return self._dispatch_prefill(plan, True)[0]
+        the on-device packed output, for ``fetch_packed`` and for what is
+        chained behind it: a fused block (``dispatch_multistep``) or the
+        next mixed step, which gets this step's output as its
+        ``prev_handle`` (``plan.behind == "mixed"``) and reads its decode
+        rows' tokens from it on the device (``_fill_impl``)."""
+        return self._dispatch_prefill(plan, True, prev_handle)[0]
 
     # -- fused multi-step decode (loop.py hooks) ---------------------------
 
@@ -2299,6 +2347,21 @@ class JaxEngine(ScheduledEngineBase):
             self._mark_compile(_ckey, "multistep", B, w,
                                time.perf_counter() - _t0)
         return (packed_block, carry)
+
+    def _fill(self, toks, prev_packed, fill):
+        """A chained mixed step's tokens, its decode rows' read from the
+        step in flight (``_fill_impl``): one small program enqueued
+        behind that step, in front of the step program."""
+        fn = self._get_jit_fill()
+        _ckey = (id(fn), toks.shape, prev_packed.shape, fill.shape)
+        _fresh = _ckey not in self._jit_seen
+        _t0 = time.perf_counter() if _fresh else 0.0
+        with stage("enqueue"):
+            toks = fn(toks, prev_packed, fill)
+        if _fresh:
+            self._mark_compile(_ckey, "mixed", fill.shape[1], toks.shape[1],
+                               time.perf_counter() - _t0)
+        return toks
 
     def _handover(self, plan, prev_packed, B: int, stop_ids) -> dict:
         """The carry of a block chained behind a mixed step, from the
@@ -2678,6 +2741,11 @@ class JaxEngine(ScheduledEngineBase):
                         else self._last_packed)
             else:
                 feed = jnp.asarray(a["toks"])
+            fill = a.get("fill")
+            if fill is not None:
+                fill = jnp.asarray(fill)
+        if fill is not None:
+            feed = self._fill(feed, prev_packed, fill)
         with stage("enqueue"):
             # one signature for every family: ``feed`` the tokens (a
             # chained step: the previous step's packed output), ``extra``

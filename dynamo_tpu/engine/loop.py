@@ -240,14 +240,15 @@ class ScheduledEngineBase(EngineBase):
     def fetch_packed_block(self, handle):           # pragma: no cover - hook
         raise NotImplementedError
 
-    # Optional hooks for chaining a fused block BEHIND a mixed step
-    # (JaxEngine implements): dispatch_step enqueues a mixed step and
-    # returns its on-device packed output without blocking (fetch_packed
-    # blocks on it); dispatch_multistep then takes that output as
-    # ``prev_handle`` for a plan whose ``behind`` is ``"mixed"``.
+    # Optional hooks for chaining BEHIND a mixed step (JaxEngine
+    # implements): dispatch_step enqueues a mixed step and returns its
+    # on-device packed output without blocking (fetch_packed blocks on
+    # it); dispatch_multistep, or dispatch_step for the run's next mixed
+    # step, then takes that output as ``prev_handle`` for a plan whose
+    # ``behind`` is ``"mixed"``.
     supports_step_chain = False
 
-    def dispatch_step(self, plan):                  # pragma: no cover - hook
+    def dispatch_step(self, plan, prev_handle=None):  # pragma: no cover
         raise NotImplementedError
 
     def drain_compile_events(self) -> List[dict]:
@@ -302,8 +303,11 @@ class ScheduledEngineBase(EngineBase):
             # form, query-key pairs a full-attention layer scored)
             if kind in ("prefill", "mixed"):
                 several = [c.length for c in chunks if c.length > 1]
+                # (a chained step's decode rows: the token in flight too)
+                ahead = bool(getattr(plan, "behind", ""))
                 pairs = sum(c.length * (2 * c.start + c.length + 1) // 2
-                            for c in chunks) + sum(len(s) for s in dec)
+                            for c in chunks) + sum(len(s) + ahead
+                                                   for s in dec)
                 state = (rows, sum(several), rows - len(several), pairs)
             else:
                 w = max(1, width)
@@ -733,6 +737,10 @@ class ScheduledEngineBase(EngineBase):
         if isinstance(plan, (PrefillBatch, MixedStepBatch)):
             for i, chunk in enumerate(plan.chunks):
                 seq = chunk.seq
+                if seq.phase is Phase.FINISHED:
+                    # cancelled, and ended with the step in front of this
+                    # (chained) one: its frame is out
+                    continue
                 if seq.cancelled:
                     self._finish(seq, FinishReason.CANCELLED)
                 elif chunk.is_last:
@@ -899,13 +907,23 @@ class ScheduledEngineBase(EngineBase):
 
     async def _loop_body(self) -> None:
         # pending = a dispatched step whose results are still on device:
-        # (plan, handle). While it is in flight the scheduler may plan
-        # the NEXT dispatch chained to its on-device tokens — a decode
-        # step behind a decode step, a fused block behind a fused block,
-        # and a fused block behind the mixed step that ends an admission
-        # run (``Scheduler.chains_behind``: such a step returns at its
-        # enqueue like the decode kinds; every other mixed step is
-        # resolved inside its dispatch, as prefill and spec steps are).
+        # (plan, handle, follows). While it is in flight the scheduler
+        # may plan the NEXT dispatch chained to its on-device tokens — a
+        # decode step behind a decode step, a fused block behind a fused
+        # block, and behind a mixed step what its admission run goes on
+        # with: the run's next mixed step, or behind the run's last the
+        # fused block (``Scheduler.chains_behind`` says which, ``follows``
+        # keeps the answer: such a step returns at its enqueue like the
+        # decode kinds, and a mixed step chained behind one is asked
+        # when the loop comes back to it, the step in front of it
+        # resolved by then). A run is mixed -> mixed -> ... -> block on
+        # the device; only its first step waits for the host, because it
+        # admits. A mixed step that chains nothing (a cancelled row, a
+        # row outside the step, a penalised or guided row) is resolved
+        # inside its dispatch, as prefill and spec steps are, and so is
+        # every mixed step of an engine without the hook (multi-host
+        # lockstep, speculation, the mocker); block diffusion and the
+        # ring admit with prefill steps.
         # The host then fetches and processes the pending step's results
         # while the chained dispatch executes — the device->host
         # readback and the whole host turn between the two programs are
@@ -915,7 +933,7 @@ class ScheduledEngineBase(EngineBase):
         # stamping helper, engine/steptrace.py): host-clock stamps for the
         # ring, and a ``loop.<phase>`` annotation carrying the dispatch's
         # ring number for whatever profile is running.
-        pending: Optional[Tuple[StepPlan, Any]] = None
+        pending: Optional[Tuple[StepPlan, Any, Optional[str]]] = None
         st = self.steptrace
 
         async def finish(plan, handle) -> None:
@@ -939,7 +957,7 @@ class ScheduledEngineBase(EngineBase):
         async def flush() -> None:
             nonlocal pending
             if pending is not None:
-                plan, handle = pending
+                plan, handle, _follows = pending
                 pending = None
                 await finish(plan, handle)
 
@@ -951,15 +969,22 @@ class ScheduledEngineBase(EngineBase):
             # planning, so all phases of one dispatch carry it
             seq = st.total
             if pending is not None:
-                prev_plan, prev_handle = pending
+                prev_plan, prev_handle, follows = pending
                 with st.phase("plan", seq, "chained") as planning:
                     if isinstance(prev_plan, MultiStepBatch):
                         chained = (
                             self.scheduler.plan_multistep_chained(prev_plan)
                             if self.supports_multistep else None)
                     elif isinstance(prev_plan, MixedStepBatch):
-                        chained = self.scheduler.plan_multistep_behind(
-                            prev_plan)
+                        if follows is None:
+                            # itself chained: asked now that the step in
+                            # front of it has resolved
+                            follows = self.scheduler.chains_behind(prev_plan)
+                        chained = (
+                            self.scheduler.plan_mixed_behind(prev_plan)
+                            if follows == "mixed" else
+                            self.scheduler.plan_multistep_behind(prev_plan)
+                            if follows else None)
                     else:
                         chained = (self.scheduler.plan_chained(prev_plan)
                                    if self.supports_pipelining else None)
@@ -967,6 +992,8 @@ class ScheduledEngineBase(EngineBase):
                     pending = None
                     if isinstance(chained, MultiStepBatch):
                         kind, fn = "multistep", self.dispatch_multistep
+                    elif isinstance(chained, MixedStepBatch):
+                        kind, fn = "mixed", self.dispatch_step
                     else:
                         kind, fn = "chained", self.dispatch_chained
                     dispatch = st.phase("dispatch", seq, kind)
@@ -986,7 +1013,7 @@ class ScheduledEngineBase(EngineBase):
                         kind, chained, dispatch, plan_ms=planning.ms,
                         chained=True,
                         chained_behind=getattr(chained, "behind", ""))
-                    pending = (chained, handle)
+                    pending = (chained, handle, None)
                     # overlap: unpack step/block N (streaming its tokens
                     # out) while N+1 runs on device
                     await finish(prev_plan, prev_handle)
@@ -1009,11 +1036,12 @@ class ScheduledEngineBase(EngineBase):
                         reason = self.multistep_unsupported_reason
                         if reason is not None:
                             self.scheduler.record_fallback(reason, plan.seqs)
-                # a mixed step the next block can chain behind returns at
+                # a mixed step that what follows it can chain behind
+                # (the run's next mixed step, the fused block) returns at
                 # its enqueue
-                chains = (isinstance(plan, MixedStepBatch)
-                          and self.supports_step_chain
-                          and self.scheduler.chains_behind(plan))
+                chains = (self.scheduler.chains_behind(plan)
+                          if isinstance(plan, MixedStepBatch)
+                          and self.supports_step_chain else "")
             if plan is None:
                 self._work.clear()
                 if self.scheduler.waiting:
@@ -1071,7 +1099,7 @@ class ScheduledEngineBase(EngineBase):
                 kind, plan, dispatch, plan_ms=planning.ms,
                 fallback="" if ms is not None else self._consume_fallback())
             if asynchronous:
-                pending = (plan, out)
+                pending = (plan, out, chains)
                 continue
             st.note_ready(rec, dispatch.ready, dispatch.ready_unix)
             with st.phase("process", seq, kind) as process:

@@ -262,10 +262,19 @@ class MixedStepBatch:
     batch: per-row ``new_lens`` carries the raggedness, each row samples
     at its last real token, and decode rows' sampling (seeds included:
     they key on token position) matches the plain decode step exactly.
+
+    ``behind == "mixed"`` marks a step planned while the mixed step in
+    front of it is still in flight (``Scheduler.plan_mixed_behind``): its
+    decode rows feed the token that step samples, which the host has not
+    seen - each at position ``len`` instead of ``len - 1`` - and
+    ``src_rows[j]`` is the row of that step's packed output that holds
+    decode row j's token.
     """
 
     chunks: List[PrefillChunk]
     decode_seqs: List[Sequence] = field(default_factory=list)
+    behind: str = ""
+    src_rows: List[int] = field(default_factory=list)
 
     _step_id: Optional[int] = None
 
@@ -365,13 +374,15 @@ class SchedulerConfig:
 # of them in the end does not fill a step ("partial")
 RUN_ENDS = ("queue", "rows", "pages", "partial")
 
-# why a fused block was not chained behind the prefill-carrying (mixed)
-# step in front of it: the step is not its run's last ("run"), the rows
-# of the block cannot be told before the step's result ("rows": one is
-# cancelled, or runs outside the step), a row's device penalty window or
-# guided automaton state is built on the host from tokens that include
-# the one in flight ("pcarry"), or the block planner refused ("budget",
-# "pages")
+# why the program that follows a prefill-carrying (mixed) step was not
+# chained behind it: the rows of that program cannot be told before the
+# step's result ("rows": one is cancelled, or runs outside the step), a
+# row's device penalty window or guided automaton state is built on the
+# host from tokens that include the one in flight ("pcarry"), the run did
+# not go on as it was read ahead when the step was dispatched ("run": the
+# queue fell, or what is left of the prompts no longer fills a step), or
+# the planner of the block or of the next mixed step refused ("budget",
+# "pages": it never preempts and never admits)
 CHAIN_REFUSALS = ("run", "rows", "pcarry", "budget", "pages")
 
 
@@ -442,11 +453,14 @@ class Scheduler:
         # pass of the run under way
         self._admit_stop = self._run_stop = "queue"
         # fused blocks whose first tokens came from the device, by what
-        # they were chained behind, and the chains behind a mixed step
-        # that were refused, by reason (``CHAIN_REFUSALS``)
+        # they were chained behind, the mixed steps whose decode rows'
+        # tokens did, and the chains behind a mixed step that were
+        # refused, by reason (``CHAIN_REFUSALS``)
         # (dynamo_worker_multistep_chained_total{behind},
+        # dynamo_worker_mixed_chained_total{behind},
         # dynamo_worker_multistep_chain_refused_total{reason})
         self.chained_blocks: Dict[str, int] = {"block": 0, "mixed": 0}
+        self.chained_steps: Dict[str, int] = {"mixed": 0}
         self.chain_refusals: Dict[str, int] = dict.fromkeys(
             CHAIN_REFUSALS, 0)
         # free slots of the recurrent-state pool, low numbers first (slot
@@ -457,8 +471,8 @@ class Scheduler:
         self.prefix_reuse_refused: Dict[str, int] = {"recurrent_state": 0}
 
     def record_chain_refusal(self, reason: str, seqs=()) -> None:
-        """Count one chain behind a mixed step that was not taken (the
-        block that follows is built from host state, as it always was);
+        """Count one chain behind a mixed step that was not taken (what
+        follows the step is planned from host state, as it always was);
         ``seqs`` is ``record_fallback``'s, unused: no row leaves the
         fused path here."""
         self.chain_refusals[reason] = self.chain_refusals.get(reason, 0) + 1
@@ -850,12 +864,15 @@ class Scheduler:
         upgrades to a fused multi-step block, and completions free rows
         and pages for the next run. Where no queue stands behind a mixed
         step the run is one step long and the plans alternate mixed /
-        pure-decode. Behind a run's last step the loop does not come
-        back here for that pure-decode plan where the block can be
-        chained on the device (``chains_behind``,
-        ``plan_multistep_behind``: the same rows in the same order,
-        planned while the step runs; this method's bookkeeping for the
-        plan is done there). With ``mixed_batch`` off, the
+        pure-decode. Behind a mixed step the loop does not come back
+        here where what follows can be chained on the device
+        (``chains_behind``): behind a step that is not its run's last,
+        the run's next mixed step (``plan_mixed_behind``), behind a
+        run's last step the fused block (``plan_multistep_behind``) -
+        the same rows in the same order, planned while the step runs;
+        this method's bookkeeping for the plan is done there. Only a
+        run's first step is always planned here: it admits, and needs
+        the completions the host has seen. With ``mixed_batch`` off, the
         legacy prefill-XOR-decode alternation applies, except that a deep
         waiting queue may take up to ``decode_progress_every - 1``
         consecutive prefill steps (burst TTFT) before a decode step is
@@ -1126,15 +1143,8 @@ class Scheduler:
                 # are fine (their keys fold the token position, not host
                 # state).
                 return None
-            sc = seq.request.stop_conditions
-            max_new = sc.max_tokens if sc.max_tokens is not None else (
-                self.max_context_hint - seq.num_prompt
-                if self.max_context_hint else None)
             # after step N the sequence has len+1 tokens / generated+1
-            if max_new is not None and len(seq.generated) + 1 >= max_new:
-                return None
-            if (self.max_context_hint is not None
-                    and len(seq) + 1 >= self.max_context_hint):
+            if self._ends_at_next(seq):
                 return None
         if any(s.phase is Phase.PREFILL for s in self.active.values()):
             return None
@@ -1388,6 +1398,35 @@ class Scheduler:
                                 [len(s) + prev.width for s in prev.seqs],
                                 behind="block")
 
+    def _chunks_behind(self, step: MixedStepBatch
+                       ) -> Optional[List[PrefillChunk]]:
+        """The chunks ``_prefill_plan(admit=False)`` would pack once
+        ``step`` is accounted for, read ahead: the prompts still in
+        prefill, oldest first, each from where the step's own chunk
+        leaves it. None where one of them is for the ring. Nothing is
+        adopted from the prefix cache here (a block that became resident
+        meanwhile is computed once more; ``_prefill_plan`` adopts what
+        is left the next time the host plans)."""
+        rode = {id(c.seq): c.length for c in step.chunks}
+        rt = self.cfg.ring_threshold
+        budget = self.cfg.max_prefill_chunk
+        chunks: List[PrefillChunk] = []
+        for s in sorted((s for s in self.active.values()
+                         if s.phase is Phase.PREFILL),
+                        key=lambda s: s.arrival):
+            start = s.num_computed + rode.get(id(s), 0)
+            remaining = self._prefill_target(s) - start
+            if remaining <= 0:
+                continue        # its last chunk rides the step
+            if rt is not None and remaining > rt:
+                return None
+            if len(chunks) < self.cfg.max_prefill_seqs and budget > 0:
+                length = min(remaining, budget)
+                chunks.append(PrefillChunk(seq=s, start=start, length=length,
+                                           is_last=(length == remaining)))
+                budget -= length
+        return chunks
+
     def _run_goes_on(self, step: MixedStepBatch) -> bool:
         """Will ``_next_plan`` follow ``step``, once it is accounted for,
         with another mixed step of the same run (its ``go_on`` rule, read
@@ -1395,53 +1434,144 @@ class Scheduler:
         less this step's chunks, still fills a whole step. A prompt for
         the ring reads as "goes on": the next plan is no decode plan
         either way."""
-        if len(self.waiting) < self.cfg.max_prefill_seqs:
+        if not self._queue_stands():
             return False
-        rode = {id(c.seq): c.length for c in step.chunks}
-        rt = self.cfg.ring_threshold
-        left: List[int] = []
-        for s in sorted((s for s in self.active.values()
-                         if s.phase is Phase.PREFILL),
-                        key=lambda s: s.arrival):
-            rem = (self._prefill_target(s) - s.num_computed
-                   - rode.get(id(s), 0))
-            if rem <= 0:
-                continue
-            if rt is not None and rem > rt:
-                return True
-            left.append(rem)
-        return (sum(left[:self.cfg.max_prefill_seqs])
+        chunks = self._chunks_behind(step)
+        return chunks is None or self._fills_a_step(chunks)
+
+    def _queue_stands(self) -> bool:
+        """More requests wait than the next admission pass could take
+        (and the decode-progress guarantee, which at 1 forces the decode
+        plan behind every step, lets a run go on at all)."""
+        return (len(self.waiting) >= self.cfg.max_prefill_seqs
+                and self.cfg.decode_progress_every != 1)
+
+    def _fills_a_step(self, chunks: List[PrefillChunk]) -> bool:
+        return (sum(c.length for c in chunks)
                 >= self.cfg.max_prefill_chunk)
 
-    def chains_behind(self, step: MixedStepBatch) -> bool:
-        """May the fused block that follows ``step`` be chained behind it
-        on the device: dispatched while the step runs, its first tokens
-        read from the step's packed output (``plan_multistep_behind``)?
-        Asked BEFORE the step is dispatched, from what the host knows
-        then, so that a step that does not chain is dispatched as it
-        always was. It may where the plan after it is the pure-decode
-        plan (the step is its run's last), every row of that plan rides
-        the step, and no row keeps per-token state the host builds (a
-        penalty window, a guided automaton: both would lack the token in
-        flight). A refusal is counted by reason (``CHAIN_REFUSALS``)."""
+    def chains_behind(self, step: MixedStepBatch) -> str:
+        """What may be chained behind ``step`` on the device, dispatched
+        while the step runs with its first tokens read from the step's
+        packed output: ``"mixed"``, the next mixed step of the step's
+        admission run (``plan_mixed_behind``: the run goes on),
+        ``"block"``, the fused block (``plan_multistep_behind``: the
+        step is its run's last and the pure-decode plan follows), or
+        ``""``, nothing. Asked from what the host knows with the step
+        not yet accounted for: BEFORE a step planned from host state is
+        dispatched, so that one that does not chain is dispatched as it
+        always was; of a step that was itself chained, when the step in
+        front of it has resolved. Something chains where every row that
+        runs rides the step, none is cancelled, and none keeps per-token
+        state the host builds (a penalty window, a guided automaton:
+        both would lack the token in flight). A refusal is counted by
+        reason (``CHAIN_REFUSALS``)."""
         if self.cfg.decode_multistep < 2:
-            return False    # no block follows (a mixed step is only
+            return ""       # no block follows (a mixed step is only
                             # planned for a causal model without drafts)
-        if self._run_goes_on(step):
-            self.record_chain_refusal("run")
-            return False
         riding = {id(s) for s in step.seqs}
         if (any(s.cancelled for s in step.seqs)
                 or any(s.phase is Phase.RUNNING and id(s) not in riding
                        for s in self.active.values())):
             self.record_chain_refusal("rows")
-            return False
+            return ""
         for s in step.seqs:
             so = s.request.sampling_options
             if so.guided or _penalized(so):
                 self.record_chain_refusal("pcarry")
-                return False
-        return True
+                return ""
+        return "mixed" if self._run_goes_on(step) else "block"
+
+    def _rows_behind(self, step: MixedStepBatch) -> Dict[int, int]:
+        """Where each row that decodes once ``step`` resolved finds its
+        token in the step's packed output (chunk rows, then decode rows),
+        by ``id``: the step's decode rows, and every prompt whose LAST
+        chunk rides it (a ``prefill_only`` one ends at its first
+        token)."""
+        at = {id(c.seq): i for i, c in enumerate(step.chunks)
+              if c.is_last and not c.seq.request.prefill_only}
+        at.update((id(s), len(step.chunks) + j)
+                  for j, s in enumerate(step.decode_seqs))
+        return at
+
+    def plan_mixed_behind(self, step: MixedStepBatch
+                          ) -> Optional[MixedStepBatch]:
+        """Plan the mixed step that follows the mixed ``step`` in its
+        admission run while the step's result is still on the device
+        (``chains_behind`` answered ``"mixed"``; the step is dispatched
+        and not yet accounted for): the plan ``_next_plan`` would return
+        once the step resolved.
+
+        Its chunks are the next chunks of the prompts still in prefill
+        (``_chunks_behind``), and they fill the step, as a run's every
+        step but the first does. Its decode rows are the step's decode
+        rows and every prompt whose last chunk rides the step
+        (``_rows_behind``), by arrival, each one token past what the
+        host holds: the engine feeds position ``len(seq)`` and reads the
+        token for it from row ``src_rows[j]`` of the step's packed
+        output. A row that token is sure to end (it spends the row's
+        budget or reaches the context ceiling) is left out, as it is
+        from the plan the host would make. A row it ends by a stop id,
+        which the host cannot know, RIDES with that one token: it writes
+        position ``len(seq)`` of a page, and its state slot, that it
+        alone owns until the host sees the step's result and frees them
+        - after this step was enqueued, so whoever takes them next
+        writes behind it - and ``_process`` drops what it samples (the
+        row is FINISHED by then).
+
+        Never admits and never preempts. None - with the refusal counted
+        and NOTHING changed, the loop then resolves the step and plans
+        from host state - where a row of the step is cancelled
+        (``rows``), the run does not go on after all (``run``: the queue
+        fell since the step was dispatched, what is left no longer fills
+        a step, or a prompt for the ring is next), no decode row is left
+        (``budget``: the host's plan would admit) or the pool lacks the
+        rows' next pages (``pages``). On success the bookkeeping stands
+        where ``schedule()`` would have left it."""
+        if any(s.cancelled for s in step.seqs):
+            self.record_chain_refusal("rows")
+            return None
+        chunks = self._chunks_behind(step) if self._queue_stands() else None
+        if chunks is None or not self._fills_a_step(chunks):
+            self.record_chain_refusal("run")
+            return None
+        at = self._rows_behind(step)
+        # (the order ``_grow_ready`` gives them)
+        rows = [s for s in sorted((s for s in self.active.values()
+                                   if id(s) in at), key=lambda s: s.arrival)
+                if not self._ends_at_next(s)]
+        if not rows:
+            self.record_chain_refusal("budget")
+            return None
+        need = [max(0, self._pages_needed(len(s) + 1) - len(s.page_ids))
+                for s in rows]
+        if sum(need) > self.alloc.num_free:
+            self.record_chain_refusal("pages")
+            return None
+        for s, n in zip(rows, need):
+            if n:
+                s.page_ids.extend(self.alloc.allocate(n))
+                s.pages_changed()
+        self._chain_run = 0
+        self._admit_stop = "partial"
+        self._prefer_prefill = False
+        self._steps_since_decode = 0
+        self.mixed_plans += 1
+        self._run_steps += 1
+        self.admission_run_steps += 1
+        self.chained_steps["mixed"] += 1
+        return MixedStepBatch(chunks=chunks, decode_seqs=rows,
+                              behind="mixed",
+                              src_rows=[at[id(s)] for s in rows])
+
+    def _ends_at_next(self, seq: Sequence) -> bool:
+        """Is the next token this row gets sure to be its last: it spends
+        the budget, or the row reaches the context ceiling (the rules of
+        ``_accept_token`` that need no look at the token)?"""
+        max_new = self._max_new(seq)
+        return ((max_new is not None and len(seq.generated) + 1 >= max_new)
+                or (self.max_context_hint is not None
+                    and len(seq) + 1 >= self.max_context_hint))
 
     def plan_multistep_behind(self, step: MixedStepBatch
                               ) -> Optional[MultiStepBatch]:
@@ -1466,10 +1596,7 @@ class Scheduler:
         the step and plans from host state. On success the scheduler
         stands where ``schedule()`` would have left it after returning
         the pure-decode plan: the admission run is over and counted."""
-        at = {id(c.seq): i for i, c in enumerate(step.chunks)
-              if c.is_last and not c.seq.request.prefill_only}
-        at.update((id(s), len(step.chunks) + j)
-                  for j, s in enumerate(step.decode_seqs))
+        at = self._rows_behind(step)
         # (the order ``_next_plan`` would give them)
         rows = sorted((s for s in self.active.values() if id(s) in at),
                       key=lambda s: s.arrival)
@@ -1577,6 +1704,10 @@ class Scheduler:
         if isinstance(plan, (PrefillBatch, MixedStepBatch)):
             for chunk in plan.chunks:
                 seq = chunk.seq
+                if seq.phase is Phase.FINISHED:
+                    # cancelled, and ended when the step in front of this
+                    # (chained) one resolved
+                    continue
                 seq.num_computed += chunk.length
                 if chunk.is_last:
                     seq.phase = Phase.RUNNING

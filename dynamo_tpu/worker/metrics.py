@@ -218,10 +218,11 @@ class EngineDispatchCollector:
     # RUN_ENDS), pre-seeded likewise
     RUN_ENDS = ("queue", "rows", "pages", "partial")
 
-    # what a fused block can be chained behind on the device, and why a
-    # chain behind a mixed step is not taken (engine/scheduler.py
-    # CHAIN_REFUSALS), pre-seeded likewise
+    # what a fused block, and what a mixed step, can be chained behind
+    # on the device, and why a chain behind a mixed step is not taken
+    # (engine/scheduler.py CHAIN_REFUSALS), pre-seeded likewise
     CHAINED_BEHIND = ("block", "mixed")
+    MIXED_CHAINED_BEHIND = ("mixed",)
     CHAIN_REFUSALS = ("run", "rows", "pcarry", "budget", "pages")
 
     # the forms a prefill-carrying step can take (engine/jax_engine.py
@@ -295,16 +296,34 @@ class EngineDispatchCollector:
         for what, value in sorted(behind.items()):
             chained.add_metric([str(what)], float(value))
         yield chained
+        steps = CounterMetricFamily(
+            "dynamo_worker_mixed_chained",
+            "Prefill-carrying (mixed) steps whose decode rows' tokens came "
+            "from the device, by what they were chained behind: 'mixed' "
+            "(the packed output of the mixed step in front of them in "
+            "their admission run, still in flight when they were "
+            "enqueued); the rest of dynamo_worker_mixed_dispatches_total "
+            "(every run's first step among them: it admits) were built "
+            "from host state",
+            labels=["behind"])
+        behind = dict.fromkeys(self.MIXED_CHAINED_BEHIND, 0.0)
+        behind.update(stats.get("chained_steps") or {})
+        for what, value in sorted(behind.items()):
+            steps.add_metric([str(what)], float(value))
+        yield steps
         refused = CounterMetricFamily(
             "dynamo_worker_multistep_chain_refused",
-            "Mixed steps behind which the fused block was NOT chained on "
-            "the device, by reason: 'run' (another mixed step of the same "
-            "admission run follows), 'rows' (a row was cancelled, or runs "
+            "Mixed steps behind which what follows (the fused block, or "
+            "the next mixed step of the admission run) was NOT chained on "
+            "the device, by reason: 'rows' (a row was cancelled, or runs "
             "outside the step), 'pcarry' (a row's penalty window or "
             "guided automaton state is built on the host and would lack "
-            "the token in flight), 'budget' / 'pages' (the block planner "
-            "refused with that token counted); the step and the block "
-            "behind it then run as they did before the chain existed",
+            "the token in flight), 'run' (the run did not go on as read "
+            "ahead at the step's dispatch: the queue fell or what is left "
+            "of the prompts no longer fills a step), 'budget' / 'pages' "
+            "(the planner refused with that token counted: it never "
+            "preempts and never admits); the step and what follows it "
+            "then run as they did before the chain existed",
             labels=["reason"])
         why = dict.fromkeys(self.CHAIN_REFUSALS, 0.0)
         why.update(stats.get("chain_refusals") or {})
@@ -515,7 +534,8 @@ def engine_dispatch_stats(engine) -> Dict[str, object]:
     """The ``EngineDispatchCollector.attach`` source for a
     ``ScheduledEngineBase`` engine (JaxEngine and the mocker both carry
     the counters). Values are floats, except ``multistep_fallbacks``,
-    ``admission_runs``, ``chained_blocks``, ``chain_refusals`` and
+    ``admission_runs``, ``chained_blocks``, ``chained_steps``,
+    ``chain_refusals`` and
     ``prefill_steps``: per-label count dicts the collector renders as
     labeled families."""
     sched = getattr(engine, "scheduler", None)
@@ -545,6 +565,7 @@ def engine_dispatch_stats(engine) -> Dict[str, object]:
             getattr(sched, "multistep_fallbacks", None) or {}),
         "admission_runs": dict(getattr(sched, "admission_runs", None) or {}),
         "chained_blocks": dict(getattr(sched, "chained_blocks", None) or {}),
+        "chained_steps": dict(getattr(sched, "chained_steps", None) or {}),
         "chain_refusals": dict(getattr(sched, "chain_refusals", None) or {}),
         "sched_admission_run_steps": float(
             getattr(sched, "admission_run_steps", 0)),

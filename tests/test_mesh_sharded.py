@@ -279,6 +279,42 @@ class TestShardedMixedDispatch:
         assert streams[0] == streams[1]
 
 
+    async def test_a_run_of_mixed_steps_chains_on_the_mesh(self, tp2):
+        """``_fill_impl``'s output is replicated, as the host's upload of
+        the token array is: on the mesh too a run's mixed steps are
+        enqueued one behind the other, each reading its decode rows'
+        tokens from the one in front, and the streams are those of an
+        engine that resolves every mixed step inside its dispatch (greedy
+        and seeded; a prompt of three chunks with a request waiting behind
+        it, one prompt an admission pass)."""
+        cfg, shard = tp2
+
+        class SyncSteps(JaxEngine):
+            supports_step_chain = False
+
+        streams = []
+        for cls in (JaxEngine, SyncSteps):
+            params = llama.init_params(cfg, jax.random.PRNGKey(0))
+            eng = cls(cfg, params, JaxEngineConfig(
+                mesh=shard.mesh, shard_params_fn=shard.shard_params,
+                shard_pages_fn=shard.shard_pages, max_prefill_seqs=1,
+                decode_multistep=4, min_prefill_seqs_bucket=4,
+                min_decode_bucket=4, **ENGINE_KW))
+            try:
+                streams.append(await asyncio.gather(
+                    run_tokens(eng, range(1, 6), "a", max_tokens=24),
+                    run_tokens(eng, range(40, 47), "b", max_tokens=24,
+                               seed=5, temp=0.8),
+                    run_tokens(eng, range(50, 100), "c", max_tokens=6),
+                    run_tokens(eng, range(20, 24), "d", max_tokens=6)))
+                chained = eng.scheduler.chained_steps["mixed"]
+                assert (chained >= 2) == (cls is JaxEngine), chained
+                assert (chained > 0) == (cls is JaxEngine), chained
+            finally:
+                await eng.stop()
+        assert streams[0] == streams[1]
+
+
 class TestPallasPerShard:
     """On a mesh the GQA Pallas kernels run once per tp shard under
     shard_map (``JaxEngine._per_shard``): the TPU compiler refuses a Mosaic

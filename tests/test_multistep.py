@@ -723,8 +723,15 @@ def staggered_engine(cls=JaxEngine, **kw):
                     # same program with a dead row riding as without
                     min_prefill_bucket=16, min_prefill_seqs_bucket=2,
                     min_decode_bucket=2)
+    model = ModelConfig.tiny()
+    if kw.pop("form", "padded") == "packed":
+        # on the kernels (interpreted here) the prefill-carrying steps run
+        # token-packed; their geometry: head_dim % 128, page_size % 8
+        model = ModelConfig.tiny(head_dim=128, num_heads=2, num_kv_heads=1,
+                                 hidden_size=128)
+        defaults.update(page_size=8, max_context=128, attn_impl="pallas")
     defaults.update(kw)
-    return cls.random_init(ModelConfig.tiny(), JaxEngineConfig(**defaults))
+    return cls.random_init(model, JaxEngineConfig(**defaults))
 
 
 async def serve_staggered(reqs, cls=JaxEngine, setup=None, **kw):
@@ -862,10 +869,11 @@ class TestChainBehindMixed:
         assert rows == [(2, 1), (2, 2)]
         assert eng.scheduler.chained_blocks["mixed"] == 2
 
-    async def test_only_the_last_step_of_a_run_chains(self):
-        """A queue behind a long prompt: the run's first mixed step is
-        followed by another and resolves inside its dispatch, counted as
-        ``run``; the run's last chains."""
+    async def test_every_step_of_a_run_but_the_first_chains(self):
+        """A queue behind a long prompt: the run's first mixed step
+        returns at its enqueue with the run's second chained behind it
+        (``TestMixedBehindMixed``), and the block behind the run's last;
+        nothing is refused as ``run``."""
         def mk():
             return [make_req([1, 2, 3, 4, 5], "r0", max_tokens=24),
                     make_req(list(range(10, 50)), "r1", max_tokens=6),
@@ -875,9 +883,10 @@ class TestChainBehindMixed:
         assert [len(t) for t, _lp, _r in streamed(frames)] == [24, 6, 6]
         kinds = [(r["kind"], r["fetch_ms"] > 0.0, r["chained_behind"])
                  for r in ring[2:5]]
-        assert kinds == [("mixed", False, ""), ("mixed", True, ""),
+        assert kinds == [("mixed", True, ""), ("mixed", True, "mixed"),
                          ("multistep", True, "mixed")]
-        assert eng.scheduler.chain_refusals["run"] == 1
+        assert eng.scheduler.chain_refusals["run"] == 0
+        assert eng.scheduler.chained_steps == {"mixed": 1}
         assert eng.scheduler.admission_runs == \
             {"queue": 1, "rows": 0, "pages": 0, "partial": 2}
 
@@ -1057,6 +1066,532 @@ class TestChainBehindMixedPlan:
         assert c["budget"].tolist() == [5, 5, 0, 5, 0]
         assert c["min_gate"].tolist() == [0, 2, 0, 0, 0]
         assert c["tok"].shape == (5, 1) and c["alive"].dtype == bool
+
+
+# -- a mixed step chained behind a mixed step -------------------------------
+
+
+def pair(ra_kw=None, samp=None):
+    """Two rows decoding (ra, rb), a prompt of four steps (rc: three full
+    chunks of 16 and two tokens) and a request that waits behind it (rd):
+    the run that admits rc is three mixed steps long and ends in a block.
+    ra's 11th token and rb's 6th come from the run's first step."""
+    def mk():
+        def s():
+            return samp() if samp else None
+        return [make_req([1, 2, 3, 4, 5], "ra", samp=s(),
+                         **{"max_tokens": 30, **(ra_kw or {})}),
+                make_req([6, 7, 8, 9], "rb", max_tokens=30, samp=s()),
+                make_req(list(range(10, 60)), "rc", max_tokens=6, samp=s()),
+                make_req([7, 8, 9], "rd", max_tokens=6, samp=s())]
+    return mk
+
+
+RA_AT_THE_RUN = 10      # ra's tokens before the run's first step
+# one row bucket for two rows to four, as a deployment pins its buckets: a
+# step holds the same program with a dead row riding as without (another
+# bucket is another program, and rounds the last digit its own way)
+PAIR_KW = dict(min_prefill_seqs_bucket=4, min_decode_bucket=4)
+
+
+def mixed_run(ring):
+    """The first run of consecutive mixed records longer than one."""
+    run = []
+    for r in ring:
+        if r["kind"] == "mixed":
+            run.append(r)
+        elif len(run) > 1:
+            break
+        else:
+            run = []
+    return run
+
+
+async def both(mk, want=None, **kw):
+    """Serve ``mk()``'s requests on the chaining engine and on the
+    synchronous one: tokens, log-probabilities and endings equal, request
+    for request, and nothing leaks on either."""
+    cls = kw.pop("cls", JaxEngine)
+    kw = {**PAIR_KW, **kw}
+    got, ring, eng = await serve_staggered(mk(), cls, **kw)
+    if want is None:
+        frames, ring0, eng0 = await serve_staggered(mk(), SyncSteps, **kw)
+        want = streamed(frames)
+        assert not any(r["chained_behind"] == "mixed" for r in ring0)
+        assert eng0.scheduler.chained_steps == {"mixed": 0}
+        assert eng0.allocator.num_free == eng0.allocator.num_pages - 1
+    assert streamed(got) == want
+    assert eng.allocator.num_free == eng.allocator.num_pages - 1
+    return streamed(got), ring, eng
+
+
+class CancelsBehindAMixedStep(JaxEngine):
+    """Cancels ``victim`` the moment the first mixed step chained behind a
+    mixed step is enqueued: both steps are in flight, neither result is
+    on the host."""
+    victim = None
+
+    def dispatch_step(self, plan, prev_handle=None):
+        handle = super().dispatch_step(plan, prev_handle)
+        if plan.behind == "mixed" and self.victim is not None:
+            self.scheduler.cancel(self.victim)
+            self.victim = None
+        return handle
+
+
+class FailsBehindAMixedStep(JaxEngine):
+    """The first mixed step chained behind a mixed step fails at its
+    dispatch."""
+    failed = False
+
+    def dispatch_step(self, plan, prev_handle=None):
+        if plan.behind == "mixed" and not self.failed:
+            self.failed = True
+            raise RuntimeError("planted: step behind a step")
+        return super().dispatch_step(plan, prev_handle)
+
+
+class TestMixedBehindMixed:
+    """Inside an admission run the next mixed step is planned and enqueued
+    while the one before it runs (``Scheduler.plan_mixed_behind``,
+    ``JaxEngine._fill_impl``): its decode rows' tokens are read from that
+    step's output on the device, and every request streams what the
+    synchronous path streams."""
+
+    _plain = {}
+
+    @classmethod
+    async def plain_synchronous(cls, form="padded"):
+        """What the synchronous path streams for ``pair()``."""
+        if form not in cls._plain:
+            frames, _ring, _eng = await serve_staggered(
+                pair()(), SyncSteps, form=form, **PAIR_KW)
+            cls._plain[form] = streamed(frames)
+        return cls._plain[form]
+
+    @pytest.mark.parametrize("form", ["padded", "packed"])
+    @pytest.mark.parametrize("sampling", ["greedy", "seeded"])
+    async def test_a_run_of_three_steps_ends_in_a_chained_block(
+            self, form, sampling):
+        """mixed -> mixed -> mixed -> block on the device: only the run's
+        first step is planned from host state; tokens and
+        log-probabilities are the synchronous path's."""
+        samp = None if sampling == "greedy" else (
+            lambda: SamplingOptions(temperature=1.0, seed=4242))
+        out, ring, eng = await both(
+            pair(samp=samp), form=form,
+            want=None if samp else await self.plain_synchronous(form))
+        assert [len(t) for t, _lp, _r in out] == [30, 30, 6, 6]
+        run = mixed_run(ring)
+        assert [(r["chained"], r["chained_behind"]) for r in run] == [
+            (False, ""), (True, "mixed"), (True, "mixed")]
+        # every step returned at its enqueue; its result was fetched later
+        assert all(r["fetch_ms"] > 0.0 for r in run)
+        # one program for the run, the one the synchronous path runs
+        prog = "packed[" if form == "packed" else "mixed["
+        assert len({r["program"] for r in run}) == 1
+        assert run[0]["program"].startswith(prog)
+        # rc's chunk and the two decode rows, a token each
+        assert [(r["rows"], r["tokens_real"]) for r in run] == [(3, 18)] * 3
+        block = ring[ring.index(run[-1]) + 1]
+        assert block["kind"] == "multistep" and block["rows"] == 2
+        assert block["chained_behind"] == "mixed"
+        assert eng.scheduler.chained_steps == {"mixed": 2}
+        assert not any(eng.scheduler.chain_refusals.values())
+        assert eng.scheduler.admission_runs == \
+            {"queue": 1, "rows": 0, "pages": 0, "partial": 3}
+
+    @pytest.mark.parametrize("form", ["padded", "packed"])
+    async def test_a_prompt_whose_last_chunk_rides_joins_the_next_step(
+            self, form):
+        """Two prompts an admission pass: r2's whole prompt rides the
+        run's first step beside r3's first chunk, and r2 is a decode row
+        of the chained step behind it, its first token read on the
+        device."""
+        def mk():
+            return [make_req([1, 2, 3, 4, 5], "r0", max_tokens=30),
+                    make_req([6, 7, 8, 9], "r1", max_tokens=30),
+                    make_req(list(range(10, 18)), "r2", max_tokens=12),
+                    make_req(list(range(20, 60)), "r3", max_tokens=6),
+                    make_req([7, 8, 9], "r4", max_tokens=6),
+                    make_req([3, 8, 9], "r5", max_tokens=6)]
+
+        out, ring, eng = await both(mk, form=form, max_prefill_seqs=2)
+        assert [len(t) for t, _lp, _r in out] == [30, 30, 12, 6, 6, 6]
+        run = mixed_run(ring)
+        assert [r["chained_behind"] for r in run] == ["", "mixed", "mixed"]
+        # two chunks of 8 and two decode rows; then r3's chunk of 16 and
+        # three decode rows, r2 among them
+        assert [(r["rows"], r["tokens_real"]) for r in run] == [
+            (4, 18), (4, 19), (4, 19)]
+        assert eng.scheduler.chained_steps == {"mixed": 2}
+
+    @pytest.mark.parametrize("ends_by", [
+        "stop_token", "stop_token_of_a_chained_step", "budget",
+        "min_tokens_gate"])
+    async def test_a_row_the_token_in_flight_ends(self, ends_by):
+        """ra's token from a step of the run is its last. A stop id the
+        host cannot know: ra rides the next step with that token and
+        what it samples there is dropped. The end of its budget, which
+        the host knows: ra is left out, as from the synchronous plan. A
+        stop id under a ``min_tokens`` gate: ra lives on. Its pages are
+        freed and taken again (rd's) and everything reads as on the
+        synchronous path."""
+        ra = (await self.plain_synchronous())[0][0]
+        at = RA_AT_THE_RUN + (ends_by == "stop_token_of_a_chained_step")
+        last = ra[at]
+        assert last not in ra[:at]
+        kw, n, reason, rows = {
+            "stop_token": ({"stop_token_ids": [last]}, at + 1,
+                           FinishReason.STOP, [3, 3, 2]),
+            "stop_token_of_a_chained_step": (
+                {"stop_token_ids": [last]}, at + 1, FinishReason.STOP,
+                [3, 3, 3]),
+            "budget": ({"max_tokens": at + 1}, at + 1, FinishReason.LENGTH,
+                       [3, 2, 2]),
+            "min_tokens_gate": ({"stop_token_ids": [last],
+                                 "min_tokens": at + 2}, 30,
+                                FinishReason.LENGTH, [3, 3, 3]),
+        }[ends_by]
+        out, ring, eng = await both(pair(ra_kw=kw))
+        assert len(out[0][0]) == n and out[0][2] == reason
+        assert [len(t) for t, _lp, _r in out[1:]] == [30, 6, 6]
+        # the synchronous path's second step holds rc's chunk and rb; a
+        # row a stop id ended rides ONE more step and the chain goes on
+        # without it
+        run = mixed_run(ring)
+        assert [r["rows"] for r in run] == rows
+        assert all(r["chained_behind"] == "mixed" for r in run[1:])
+        assert not any(eng.scheduler.chain_refusals.values())
+
+    @pytest.mark.parametrize("victim", ["ra", "rc"])
+    async def test_a_cancel_while_two_steps_are_in_flight(self, victim):
+        """A decode row (ra) or the prompt in mid-prefill (rc) is
+        cancelled with the run's first two steps both enqueued and
+        neither fetched: it ends CANCELLED, once, with the tokens it had;
+        the chain breaks there (``rows``) and the others stream what the
+        synchronous path streams."""
+        def setup(eng):
+            eng.victim = victim
+
+        got, ring, eng = await serve_staggered(
+            pair()(), CancelsBehindAMixedStep, setup=setup, **PAIR_KW)
+        want = await self.plain_synchronous()
+        i = ["ra", "rb", "rc", "rd"].index(victim)
+        assert got[i][-1].finish_reason == FinishReason.CANCELLED
+        assert sum(f.finish_reason is not None for f in got[i]) == 1
+        got = streamed(got)
+        # the step's own token is dropped with the cancel
+        assert got[i][0] == (want[i][0][:RA_AT_THE_RUN] if victim == "ra"
+                             else [])
+        for j in range(4):
+            if j != i:
+                # (with rc gone the steps that follow have other shapes
+                # than the plain run's: another program rounds a
+                # log-probability's last digit its own way)
+                assert got[j][0] == want[j][0] and got[j][2] == want[j][2]
+                if victim == "ra":
+                    assert got[j] == want[j]
+        run = mixed_run(ring)
+        assert [r["chained_behind"] for r in run[:2]] == ["", "mixed"]
+        assert eng.scheduler.chain_refusals["rows"] == 1
+        assert eng.allocator.num_free == eng.allocator.num_pages - 1
+
+    async def test_a_dispatch_error_in_the_chained_step(self):
+        """The step behind the run's first fails at its dispatch: the
+        first is finished (each row gets the token it sampled), then the
+        failed step's rows end in ERROR; the engine serves on."""
+        eng = staggered_engine(FailsBehindAMixedStep, **PAIR_KW)
+        try:
+            frames = await asyncio.gather(*[collect(eng, r)
+                                            for r in pair()()])
+            got, want = streamed(frames), await self.plain_synchronous()
+            assert eng.failed
+            assert got[0][0] == want[0][0][:RA_AT_THE_RUN + 1]
+            assert got[1][0] == want[1][0][:6]
+            assert got[2][0] == []
+            for fs in frames[:3]:
+                assert fs[-1].finish_reason == FinishReason.ERROR
+                assert "planted" in fs[-1].error
+            assert got[3] == want[3]
+            assert eng.allocator.num_free == eng.allocator.num_pages - 1
+        finally:
+            await eng.stop()
+
+    async def test_a_penalised_row_keeps_the_synchronous_path(self):
+        """A penalty window is built on the host from tokens that would
+        lack the one in flight: every step of the run is resolved inside
+        its dispatch, counted as ``pcarry``."""
+        def samp():
+            return SamplingOptions(temperature=0.0, presence_penalty=0.6)
+
+        frames, ring, eng = await serve_staggered(pair(samp=samp)(),
+                                                  **PAIR_KW)
+        frames0, ring0, _eng0 = await serve_staggered(
+            pair(samp=samp)(), SyncSteps, **PAIR_KW)
+        assert streamed(frames) == streamed(frames0)
+        assert [(r["kind"], r["program"], r["rows"]) for r in ring] == \
+            [(r["kind"], r["program"], r["rows"]) for r in ring0]
+        assert all(r["fetch_ms"] == 0.0 and not r["chained"]
+                   for r in ring if r["kind"] == "mixed")
+        assert eng.scheduler.chained_steps == {"mixed": 0}
+        assert eng.scheduler.chain_refusals["pcarry"] == len(
+            [r for r in ring if r["kind"] == "mixed"])
+
+    def test_the_tokens_are_filled_in_on_the_device(self):
+        """``_fill_impl``: each decode row's slot of the token array, of
+        either form, gets column 0 of its row of the previous step's
+        output; pad entries (a slot past the end) change nothing."""
+        import numpy as np
+        eng = tiny_engine(decode_multistep=4)
+        prev = np.zeros((4, 2), np.int32)
+        prev[:, 0] = [50, 60, 70, 80]
+        fn = eng._get_jit_fill()
+        # packed [1, T]: a chunk of 5 tokens, then rows at slots 5 and 6
+        toks = np.zeros((1, 8), np.int32)
+        toks[0, :5] = [1, 2, 3, 4, 5]
+        fill = np.array([[5, 6, 8, 8], [3, 0, 0, 0]], np.int32)
+        assert np.asarray(fn(toks, prev, fill)).tolist() == \
+            [[1, 2, 3, 4, 5, 80, 50, 0]]
+        # padded [B, S]: row i starts at i * S
+        toks = np.zeros((4, 4), np.int32)
+        toks[0] = [1, 2, 3, 4]
+        fill = np.array([[4, 8, 16, 16], [1, 2, 0, 0]], np.int32)
+        assert np.asarray(fn(toks, prev, fill)).tolist() == \
+            [[1, 2, 3, 4], [60, 0, 0, 0], [70, 0, 0, 0], [0, 0, 0, 0]]
+
+    def test_the_counter_and_the_reasons_are_on_the_scrape_at_zero(self):
+        from prometheus_client import CollectorRegistry
+
+        from dynamo_tpu.engine.scheduler import CHAIN_REFUSALS
+        from dynamo_tpu.worker.metrics import (WorkerMetrics,
+                                               engine_dispatch_stats)
+        wm = WorkerMetrics(CollectorRegistry())
+        assert type(wm.engine).CHAIN_REFUSALS == CHAIN_REFUSALS
+
+        def value(name, **labels):
+            return wm.registry.get_sample_value(name, labels)
+
+        assert value("dynamo_worker_mixed_chained_total",
+                     behind="mixed") == 0.0
+        for reason in CHAIN_REFUSALS:
+            assert value("dynamo_worker_multistep_chain_refused_total",
+                         reason=reason) == 0.0
+
+        class Eng:
+            scheduler = Scheduler(PageAllocator(9, 4), SchedulerConfig())
+        Eng.scheduler.chained_steps["mixed"] = 5
+        Eng.scheduler.chain_refusals["run"] = 2
+        wm.engine.attach(lambda: engine_dispatch_stats(Eng))
+        assert value("dynamo_worker_mixed_chained_total",
+                     behind="mixed") == 5.0
+        assert value("dynamo_worker_multistep_chain_refused_total",
+                     reason="run") == 2.0
+
+
+def _resolve(sched, plan, token=7):
+    """What the loop's ``_process`` does to the scheduler for a mixed or
+    decode plan: account for the step, append a token to every row that
+    sampled one, end a row whose budget or context that spends."""
+    sched.on_step_done(plan)
+    rows = [c.seq for c in getattr(plan, "chunks", ()) if c.is_last]
+    rows += list(getattr(plan, "decode_seqs", None)
+                 or (plan.seqs if isinstance(plan, DecodeBatch) else ()))
+    for seq in rows:
+        if seq.phase is not Phase.RUNNING:
+            continue
+        seq.tokens.append(token)
+        seq.generated.append(token)
+        if (len(seq.generated) >= seq.request.stop_conditions.max_tokens
+                or len(seq) >= sched.max_context_hint):
+            sched.finish(seq)
+
+
+def _books(sched, which="all"):
+    """Everything ``plan_mixed_behind`` may touch; ``which="shared"``:
+    what two schedulers that made the same plans share (not which pages
+    a row got - one of them freed a row's before it grew another's, the
+    other after - nor the counters of the chains themselves)."""
+    seqs = list(sched.active.values()) + list(sched.waiting)
+    shared = (sched._run_steps, sched.admission_run_steps, sched.mixed_plans,
+              sched._prefer_prefill, sched._steps_since_decode,
+              sched._admit_stop, dict(sched.admission_runs),
+              sched.alloc.num_free,
+              {s.request.request_id: (len(s.page_ids), s.num_computed,
+                                      s.phase) for s in seqs},
+              [s.request.request_id for s in sched.waiting])
+    if which == "shared":
+        return shared
+    return shared + (dict(sched.chained_steps), dict(sched.chained_blocks),
+                     {s.request.request_id: (list(s.page_ids),
+                                             s.table_version)
+                      for s in seqs})
+
+
+def _shape(plan):
+    """A mixed plan by what the engine makes of it: chunks, and decode
+    rows in order with the position each feeds and the pages it holds."""
+    from dynamo_tpu.engine.scheduler import MixedStepBatch
+    if not isinstance(plan, MixedStepBatch):
+        return type(plan).__name__
+    return ([(c.seq.request.request_id, c.start, c.length, c.is_last)
+             for c in plan.chunks],
+            [(s.request.request_id, len(s) - 1, len(s.page_ids))
+             for s in plan.decode_seqs])
+
+
+class TestMixedBehindMixedPlan:
+    """The scheduler's side, without an engine."""
+
+    def make(self, pages=129, **cfg):
+        cfg.setdefault("decode_multistep", 4)
+        cfg.setdefault("max_prefill_seqs", 1)
+        cfg.setdefault("max_prefill_chunk", 16)
+        sched = Scheduler(PageAllocator(pages, 4), SchedulerConfig(**cfg))
+        sched.max_context_hint = 96
+        return sched
+
+    def queue(self, seed):
+        import random
+        rng = random.Random(seed)
+        sched = self.make(max_num_seqs=6, max_prefill_seqs=2)
+        for i in range(14):
+            n = rng.choice([3, 5, 9, 17, 30, 41, 50])
+            sched.add_request(make_req(
+                [rng.randrange(5, 200) for _ in range(n)], f"q{i}",
+                max_tokens=rng.choice([1, 2, 3, 5, 8, 13])))
+        return sched
+
+    @pytest.mark.parametrize("seed", range(8))
+    def test_the_plan_is_the_one_the_host_would_make(self, seed):
+        """On a seeded random queue, served once with every mixed step
+        resolved before the next is planned (B) and once with the next
+        planned while the step is in flight (A): ``plan_mixed_behind``
+        returns ``_next_plan``'s plan - chunks, row order, positions,
+        pages - and leaves the same books; a refusal leaves them
+        untouched and ``schedule()`` then returns that plan."""
+        from dynamo_tpu.engine.scheduler import MixedStepBatch
+        a, b = self.queue(seed), self.queue(seed)
+        chained = refused = 0
+        plan_a, plan_b = a.schedule(), b.schedule()
+        for _ in range(400):
+            if plan_a is None:
+                break
+            # (the step in front of a chained plan has resolved by now:
+            # both sides hold the token it fed on)
+            assert _shape(plan_a) == _shape(plan_b)
+            follows = (a.chains_behind(plan_a)
+                       if isinstance(plan_a, MixedStepBatch) else "")
+            _resolve(b, plan_b)
+            plan_b = b.schedule()
+            if follows == "mixed":
+                before = _books(a)
+                nxt = a.plan_mixed_behind(plan_a)
+                if nxt is None:
+                    refused += 1
+                    assert _books(a) == before
+                else:
+                    chained += 1
+                    assert nxt.behind == "mixed"
+                    at = {id(s): i for i, s in enumerate(plan_a.seqs)}
+                    assert nxt.src_rows == [at[id(s)]
+                                            for s in nxt.decode_seqs]
+                _resolve(a, plan_a)
+                plan_a = nxt if nxt is not None else a.schedule()
+            else:
+                _resolve(a, plan_a)
+                plan_a = a.schedule()
+            assert _books(a, "shared") == _books(b, "shared")
+        assert plan_a is None and plan_b is None
+        assert not a.active and not a.waiting
+        assert chained >= 3
+        assert a.chained_steps == {"mixed": chained}
+        assert sum(a.chain_refusals.values()) == refused
+
+    def step_with(self, sched, a_kw=None, b_prompt=40, b_samp=None,
+                  a_prompt=5):
+        """a running with one token out, b's first chunk admitted into a
+        mixed step, c waiting behind it."""
+        from dynamo_tpu.engine.scheduler import MixedStepBatch
+        sched.add_request(make_req(list(range(1, a_prompt + 1)), "a",
+                                   **{"max_tokens": 12, **(a_kw or {})}))
+        first = sched.schedule()
+        _resolve(sched, first)
+        sched.add_request(make_req(list(range(20, 20 + b_prompt)), "b",
+                                   max_tokens=8, samp=b_samp))
+        sched.add_request(make_req([7, 8, 9], "c", max_tokens=4))
+        assert isinstance(sched.schedule(), DecodeBatch)
+        step = sched.schedule()
+        assert isinstance(step, MixedStepBatch) and not step.behind
+        return step
+
+    def test_rows_positions_and_the_map_into_the_steps_output(self):
+        sched = self.make()
+        step = self.step_with(sched)
+        a, b = step.decode_seqs[0], step.chunks[0].seq
+        assert sched.chains_behind(step) == "mixed"
+        free, pages = sched.alloc.num_free, len(a.page_ids)
+        nxt = sched.plan_mixed_behind(step)
+        assert [(c.seq, c.start, c.length, c.is_last)
+                for c in nxt.chunks] == [(b, 16, 16, False)]
+        # a feeds the token in flight at position len(a) = 6: the second
+        # page's last slot is 7, nothing to grow; the step's packed rows
+        # are its chunks, then its decode rows
+        assert nxt.decode_seqs == [a] and nxt.src_rows == [1]
+        assert nxt.behind == "mixed"
+        assert len(a.page_ids) == pages and sched.alloc.num_free == free
+        assert (sched._run_steps, sched.mixed_plans) == (2, 2)
+        assert sched.chained_steps == {"mixed": 1}
+        # the step behind it: b's last chunk; then the run is over
+        _resolve(sched, step)
+        assert sched.chains_behind(nxt) == "block"
+
+    @pytest.mark.parametrize("why", ["rows", "rows_later", "pcarry",
+                                     "pages", "budget", "queue_fell",
+                                     "part_filled", "off"])
+    def test_a_refusal_changes_nothing_and_is_counted(self, why):
+        from dynamo_tpu.engine.scheduler import MixedStepBatch
+        samp = (SamplingOptions(temperature=0.0, presence_penalty=0.5)
+                if why == "pcarry" else None)
+        sched = self.make(**({"decode_multistep": 1} if why == "off"
+                             else {}))
+        step = self.step_with(
+            sched, a_kw={"max_tokens": 2} if why == "budget" else None,
+            b_prompt=20 if why == "part_filled" else 40, b_samp=samp,
+            # (a row of 8 tokens feeds position 8 next: a third page)
+            a_prompt=7 if why == "pages" else 5)
+        a = step.decode_seqs[0]
+        if why == "rows":
+            a.cancelled = True
+        follows = sched.chains_behind(step)
+        if why in ("rows", "pcarry", "off"):
+            assert follows == ""
+        else:
+            # (a part-filled next step ends the run: the block follows;
+            # asked for all the same, the next step is refused)
+            assert follows == ("block" if why == "part_filled" else "mixed")
+            if why == "rows_later":
+                a.cancelled = True
+            elif why == "pages":
+                assert sched.alloc.allocate(sched.alloc.num_free)
+            elif why == "queue_fell":
+                sched.cancel("c")
+            before = _books(sched)
+            assert sched.plan_mixed_behind(step) is None
+            assert _books(sched) == before
+        want = {"rows_later": "rows", "queue_fell": "run",
+                "part_filled": "run", "off": None}.get(why, why)
+        assert sched.chain_refusals == {
+            r: int(r == want) for r in sched.chain_refusals}
+        assert sched.chained_steps == {"mixed": 0}
+        assert sched.multistep_fallbacks == {}
+        if why not in ("pages", "rows", "rows_later"):
+            # the synchronous path: the step resolves, the host plans
+            _resolve(sched, step)
+            nxt = sched.schedule()
+            assert isinstance(nxt, (MixedStepBatch, DecodeBatch,
+                                    PrefillBatch))
+            assert not getattr(nxt, "behind", "")
 
 
 class TestMockerBlockPath:

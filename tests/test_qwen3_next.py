@@ -477,6 +477,74 @@ async def test_the_served_path_gives_the_references_greedy_tokens(attn_impl):
         await eng.stop()
 
 
+@pytest.mark.parametrize("attn_impl", ["scan", "pallas"])
+async def test_chained_steps_carry_the_state_and_a_freed_slot_starts_from_zero(
+        attn_impl):
+    """Two rows and two slots, one prompt an admission pass: r1's prompt
+    of 150 tokens is computed in chunks of 32 beside r0, every step of the
+    run but the first enqueued while the one before it runs - the
+    recurrent state and the convolution's carried inputs go from chunk to
+    chunk on the device alone, and r0's token for each step comes from
+    the step before. r0 ends on a stop id at the run's second step: it
+    rides the third with that token and writes into its slot once more;
+    r2, which waited, then takes that slot and starts from zeros. r1 and
+    r2 stream the reference's greedy continuation, r0 what it streams
+    alone up to its stop."""
+    over = ({"head_dim": 128, "num_attention_heads": 2,
+             "num_key_value_heads": 1} if attn_impl == "pallas" else {})
+    hf = _config(tiny=True, **over)
+    cfg = ModelConfig.from_hf(hf, dtype="float32")
+    params = qwen3_next.init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(11)
+    p0, p1, p2 = (rng.integers(0, 512, n).tolist() for n in (5, 150, 20))
+    kw = dict(attn_impl=attn_impl, max_num_seqs=2, max_prefill_seqs=1,
+              max_prefill_chunk=32, min_prefill_bucket=32,
+              min_prefill_seqs_bucket=2, min_decode_bucket=2)
+    with jax.default_matmul_precision("highest"):
+        solo = _engine(cfg, params, **kw)
+        try:
+            alone, _ = await _collect(solo, _req(p0, "r0", 12))
+        finally:
+            await solo.stop()
+        # r0's tokens: one from its prefill, four from the block behind
+        # it, the 6th from the run's first step, the 7th from its second
+        last = alone[6]
+        assert last not in alone[:6]
+        r0 = _req(p0, "r0", 24)
+        r0.stop_conditions.stop_token_ids = [last]
+        from dynamo_tpu.engine.steptrace import (StepRecorder,
+                                                 set_step_recorder)
+        set_step_recorder(StepRecorder(256))    # this engine's steps alone
+        eng = _engine(cfg, params, **kw)
+        try:
+            got = await asyncio.gather(
+                _collect(eng, r0), _collect(eng, _req(p1, "r1", 6)),
+                _collect(eng, _req(p2, "r2", 6)))
+            assert got[0][0] == alone[:7]
+            for p, (toks, _frames) in zip((p1, p2), got[1:]):
+                assert len(toks) == 6
+                assert _is_the_references_greedy(hf, params, p, toks)
+            sched = eng.scheduler
+            assert sorted(sched._free_slots) == [1, 2]
+            assert eng.allocator.num_free == eng.allocator.num_pages - 1
+            ring = eng.steptrace.snapshot(limit=4096)["records"][::-1]
+            mixed = [r for r in ring if r["kind"] == "mixed"]
+            # the run: r1's chunk and r0, dead in the third step; with no
+            # decode row left the host plans r1's last two chunks itself
+            # (``budget``), and r2's prompt then rides beside r1
+            assert [(r["chained_behind"], r["gdn_tokens"],
+                     r["gdn_step_rows"]) for r in mixed] == [
+                ("", 32, 1), ("mixed", 32, 1), ("mixed", 32, 1),
+                ("", 20, 1)]
+            assert sched.chained_steps == {"mixed": 2}
+            assert sched.chain_refusals == {
+                r: int(r == "budget") for r in sched.chain_refusals}
+            form = "packed" if attn_impl == "pallas" else "padded:attn_impl"
+            assert set(eng.prefill_steps) == {form}
+        finally:
+            await eng.stop()
+
+
 async def test_a_slot_is_reused_without_a_leak_and_no_prefix_is_reused(tiny):
     """One slot: request B behind request A reads what A left in the slot
     only if the first chunk does not start from zeros. B equals itself on a

@@ -597,6 +597,59 @@ class TestBlockBehindAPackedStep:
                    for r in recs if r["kind"] == "multistep" and r is not block)
 
 
+    async def test_a_packed_step_behind_a_packed_step(self, fresh_recorder):
+        """A run of three packed steps (a prompt of three full chunks, a
+        request waiting behind it): the two steps chained behind a step
+        keep ``kind`` ``mixed`` and the program's name, carry ``chained``
+        and ``chained_behind`` ``mixed``, were enqueued before the result
+        in front of them arrived, and no two records of the run count the
+        same time: ``device_ms`` of a chained step runs from the previous
+        result's arrival to its own."""
+        from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
+        from dynamo_tpu.models.config import ModelConfig
+        eng = JaxEngine.random_init(
+            ModelConfig.tiny(head_dim=128, num_heads=2, num_kv_heads=1,
+                             hidden_size=128),
+            JaxEngineConfig(num_pages=64, page_size=8, max_num_seqs=4,
+                            max_prefill_chunk=16, max_context=128,
+                            min_prefill_bucket=16, min_prefill_seqs_bucket=2,
+                            min_decode_bucket=2, decode_multistep=4,
+                            max_prefill_seqs=1, attn_impl="pallas"))
+        try:
+            await asyncio.gather(
+                collect(eng, make_req([1, 2, 3, 4, 5], "a", max_tokens=20)),
+                collect(eng, make_req(range(10, 60), "b", max_tokens=4)),
+                collect(eng, make_req([7, 8, 9], "c", max_tokens=4)))
+        finally:
+            await eng.stop()
+        recs = fresh_recorder.snapshot(limit=64)["records"][::-1]
+        first = next(i for i, r in enumerate(recs) if r["kind"] == "mixed")
+        run, block = recs[first:first + 3], recs[first + 3]
+        assert [(r["kind"], r["program"], r["chained"], r["chained_behind"])
+                for r in run] == [
+            ("mixed", "packed[32,2]", False, ""),
+            ("mixed", "packed[32,2]", True, "mixed"),
+            ("mixed", "packed[32,2]", True, "mixed")]
+        assert block["kind"] == "multistep" and block["chained"]
+        assert block["chained_behind"] == "mixed"
+        # b's chunk of 16 and a's token: the decode kernel's one row
+        assert all(r["tokens_real"] == 17 and r["decode_kernel_rows"] == 1
+                   for r in run)
+        for a, b in zip(run + [block], (run + [block])[1:]):
+            # enqueued while the one in front ran; its time starts where
+            # that one's result arrived
+            assert b["t_unix"] < a["ready_unix"] <= b["ready_unix"]
+            assert b["device_ms"] == pytest.approx(
+                (b["ready_unix"] - a["ready_unix"]) * 1e3, abs=5.0)
+            assert a["fetch_ms"] > 0.0
+        enqueue_unix = run[0]["t_unix"] - run[0]["dispatch_ms"] / 1e3
+        assert sum(r["device_ms"] for r in run + [block]) == pytest.approx(
+            (block["ready_unix"] - enqueue_unix) * 1e3, abs=8.0)
+        # no program but the run's own compiled behind a chained step's
+        # record: the hand-over's first call is on the first chained one
+        assert run[1]["compile_ms"] > 0.0 and run[2]["compile_ms"] == 0.0
+
+
 class TestHeadStart:
     """``Phase.in_thread(..., head_start=s)``: the call is on its thread
     before the loop does anything else, for at most ``s`` seconds."""
