@@ -545,7 +545,7 @@ class JaxEngine(ScheduledEngineBase):
             self._packed_splits = takes_decode_kernel(self.gen_block)
         self._last_packed = None  # most recent packed output (device)
         self.ring_steps = 0  # diagnostics: sequence-parallel prefills run
-        self.chained_steps = 0  # diagnostics: pipelined decode steps run
+        self.chained_decode_steps = 0  # diagnostics: decode steps chained
         # diagnostics + test tap: jitted page-scatter dispatches (KV
         # inject commits). The batched inject pipeline's regression guard
         # counts these instead of timing walls.
@@ -1296,7 +1296,7 @@ class JaxEngine(ScheduledEngineBase):
         row of the block the row of ``prev_packed`` that holds its token,
         its position and total length at block start, its token budget
         and its outstanding ``min_tokens`` gate, the token in flight
-        counted in all four (``Scheduler.plan_multistep_behind``). A row
+        counted in all four (``Scheduler.plan_behind``). A row
         lives unless that token is one of its ``stop_ids`` with the gate
         passed, or spent its budget: the two rules ``_accept_token``
         applies on the host when the step's result arrives. Pad rows
@@ -1315,7 +1315,7 @@ class JaxEngine(ScheduledEngineBase):
         empty, and ``fill`` ``[2, B]`` int32, its one more upload, says
         for each the slot of ``toks`` (flattened; the padded form's row
         ``i`` starts at ``i * S``) and the row of ``prev_packed`` whose
-        column 0 holds its token (``Scheduler.plan_mixed_behind``). Pad
+        column 0 holds its token (``Scheduler.plan_behind``). Pad
         entries point past the end and are dropped. No row is masked
         here: one that this token ends rides the step with it and the
         host drops what it samples."""
@@ -1989,7 +1989,7 @@ class JaxEngine(ScheduledEngineBase):
         # chain (drafts need the sampled tokens host-side), but plain
         # decode steps between them still do — the scheduler breaks a
         # chain every spec_chain_break steps so fresh context gets a
-        # chance to draft (plan_chained)
+        # chance to draft (``Scheduler._in_flight``)
         return self.cfg.pipeline_decode
 
     def dispatch_decode(self, plan):
@@ -2014,7 +2014,7 @@ class JaxEngine(ScheduledEngineBase):
         packed = self._invoke_step("chained", arrays, self._step_counter,
                                    prev_packed=prev_packed, seqs=plan.seqs)
         self._step_counter += 1
-        self.chained_steps += 1
+        self.chained_decode_steps += 1
         self.decode_dispatches += 1
         return packed
 

@@ -34,6 +34,7 @@ from dynamo_tpu.engine.base import EngineBase
 from dynamo_tpu.engine.pages import PageAllocator
 from dynamo_tpu.engine.steptrace import get_step_recorder
 from dynamo_tpu.engine.scheduler import (
+    CHAIN_KINDS,
     DecodeBatch,
     GenPassBatch,
     MixedStepBatch,
@@ -250,6 +251,14 @@ class ScheduledEngineBase(EngineBase):
 
     def dispatch_step(self, plan, prev_handle=None):  # pragma: no cover
         raise NotImplementedError
+
+    @property
+    def chain_kinds(self) -> Tuple[str, ...]:
+        """The kinds of step in flight this engine can enqueue a program
+        behind (``scheduler.CHAIN_KINDS``), by the hooks it has."""
+        return tuple(kind for kind, has in zip(CHAIN_KINDS, (
+            self.supports_pipelining, self.supports_multistep,
+            self.supports_step_chain)) if has)
 
     def drain_compile_events(self) -> List[dict]:
         """Buffered first-call jit-compile events since the last drain
@@ -907,23 +916,22 @@ class ScheduledEngineBase(EngineBase):
 
     async def _loop_body(self) -> None:
         # pending = a dispatched step whose results are still on device:
-        # (plan, handle, follows). While it is in flight the scheduler
-        # may plan the NEXT dispatch chained to its on-device tokens — a
-        # decode step behind a decode step, a fused block behind a fused
-        # block, and behind a mixed step what its admission run goes on
-        # with: the run's next mixed step, or behind the run's last the
-        # fused block (``Scheduler.chains_behind`` says which, ``follows``
-        # keeps the answer: such a step returns at its enqueue like the
-        # decode kinds, and a mixed step chained behind one is asked
-        # when the loop comes back to it, the step in front of it
-        # resolved by then). A run is mixed -> mixed -> ... -> block on
-        # the device; only its first step waits for the host, because it
-        # admits. A mixed step that chains nothing (a cancelled row, a
-        # row outside the step, a penalised or guided row) is resolved
-        # inside its dispatch, as prefill and spec steps are, and so is
-        # every mixed step of an engine without the hook (multi-host
-        # lockstep, speculation, the mocker); block diffusion and the
-        # ring admit with prefill steps.
+        # (plan, handle). While it is in flight the scheduler may plan
+        # the NEXT dispatch chained to its on-device tokens
+        # (``Scheduler.plan_behind``) - a decode step behind a decode
+        # step, a fused block behind a fused block, and behind a mixed
+        # step what its admission run goes on with: the run's next mixed
+        # step, or behind the run's last the fused block. A run is
+        # mixed -> mixed -> ... -> block on the device; only its first
+        # step waits for the host, because it admits. A mixed step that
+        # something can chain behind returns at its enqueue like the
+        # decode kinds; one that chains nothing
+        # (``Scheduler.chains_behind``: a cancelled row, a row outside
+        # the step, a penalised or guided row) is resolved inside its
+        # dispatch, as prefill and spec steps are, and so is every mixed
+        # step of an engine without the hook (multi-host lockstep,
+        # speculation, the mocker); block diffusion and the ring admit
+        # with prefill steps.
         # The host then fetches and processes the pending step's results
         # while the chained dispatch executes — the device->host
         # readback and the whole host turn between the two programs are
@@ -933,7 +941,7 @@ class ScheduledEngineBase(EngineBase):
         # stamping helper, engine/steptrace.py): host-clock stamps for the
         # ring, and a ``loop.<phase>`` annotation carrying the dispatch's
         # ring number for whatever profile is running.
-        pending: Optional[Tuple[StepPlan, Any, Optional[str]]] = None
+        pending: Optional[Tuple[StepPlan, Any]] = None
         st = self.steptrace
 
         async def finish(plan, handle) -> None:
@@ -957,7 +965,7 @@ class ScheduledEngineBase(EngineBase):
         async def flush() -> None:
             nonlocal pending
             if pending is not None:
-                plan, handle, _follows = pending
+                plan, handle = pending
                 pending = None
                 await finish(plan, handle)
 
@@ -969,25 +977,10 @@ class ScheduledEngineBase(EngineBase):
             # planning, so all phases of one dispatch carry it
             seq = st.total
             if pending is not None:
-                prev_plan, prev_handle, follows = pending
+                prev_plan, prev_handle = pending
                 with st.phase("plan", seq, "chained") as planning:
-                    if isinstance(prev_plan, MultiStepBatch):
-                        chained = (
-                            self.scheduler.plan_multistep_chained(prev_plan)
-                            if self.supports_multistep else None)
-                    elif isinstance(prev_plan, MixedStepBatch):
-                        if follows is None:
-                            # itself chained: asked now that the step in
-                            # front of it has resolved
-                            follows = self.scheduler.chains_behind(prev_plan)
-                        chained = (
-                            self.scheduler.plan_mixed_behind(prev_plan)
-                            if follows == "mixed" else
-                            self.scheduler.plan_multistep_behind(prev_plan)
-                            if follows else None)
-                    else:
-                        chained = (self.scheduler.plan_chained(prev_plan)
-                                   if self.supports_pipelining else None)
+                    chained = self.scheduler.plan_behind(prev_plan,
+                                                         self.chain_kinds)
                 if chained is not None:
                     pending = None
                     if isinstance(chained, MultiStepBatch):
@@ -1013,7 +1006,7 @@ class ScheduledEngineBase(EngineBase):
                         kind, chained, dispatch, plan_ms=planning.ms,
                         chained=True,
                         chained_behind=getattr(chained, "behind", ""))
-                    pending = (chained, handle, None)
+                    pending = (chained, handle)
                     # overlap: unpack step/block N (streaming its tokens
                     # out) while N+1 runs on device
                     await finish(prev_plan, prev_handle)
@@ -1039,9 +1032,9 @@ class ScheduledEngineBase(EngineBase):
                 # a mixed step that what follows it can chain behind
                 # (the run's next mixed step, the fused block) returns at
                 # its enqueue
-                chains = (self.scheduler.chains_behind(plan)
-                          if isinstance(plan, MixedStepBatch)
-                          and self.supports_step_chain else "")
+                chains = (isinstance(plan, MixedStepBatch)
+                          and self.scheduler.chains_behind(
+                              plan, self.chain_kinds))
             if plan is None:
                 self._work.clear()
                 if self.scheduler.waiting:
@@ -1099,7 +1092,7 @@ class ScheduledEngineBase(EngineBase):
                 kind, plan, dispatch, plan_ms=planning.ms,
                 fallback="" if ms is not None else self._consume_fallback())
             if asynchronous:
-                pending = (plan, out, chains)
+                pending = (plan, out)
                 continue
             st.note_ready(rec, dispatch.ready, dispatch.ready_unix)
             with st.phase("process", seq, kind) as process:

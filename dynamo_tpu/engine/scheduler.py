@@ -264,7 +264,7 @@ class MixedStepBatch:
     they key on token position) matches the plain decode step exactly.
 
     ``behind == "mixed"`` marks a step planned while the mixed step in
-    front of it is still in flight (``Scheduler.plan_mixed_behind``): its
+    front of it is still in flight (``Scheduler.plan_behind``): its
     decode rows feed the token that step samples, which the host has not
     seen - each at position ``len`` instead of ``len - 1`` - and
     ``src_rows[j]`` is the row of that step's packed output that holds
@@ -285,6 +285,39 @@ class MixedStepBatch:
 
 StepPlan = Union[PrefillBatch, DecodeBatch, SpecDecodeBatch, MultiStepBatch,
                  GenPassBatch, MixedStepBatch]
+
+# the kinds of step the device can run a program behind, reading its
+# first tokens from the step's on-device output: a decode step (the next
+# decode step, row for row), a fused block (the next block, from its
+# carry) and a mixed step (the next mixed step of its admission run, or
+# the block that ends the run). An engine names the ones it has the
+# programs for (``EngineLoop.chain_kinds``)
+CHAIN_KINDS = ("decode", "block", "mixed")
+
+
+@dataclass
+class InFlight:
+    """A dispatched step the host has not accounted for, as the planner
+    sees it (``Scheduler.plan_behind``): what the step will have done to
+    each row it carries by the time the plan behind it runs. The
+    scheduler reads every length, budget, page count and phase through it
+    (``_out``, ``_len``, ``_computed``, ``_phase``); with nothing in
+    flight they read the host's state as it stands.
+
+    ``out`` is how far a row the step samples for is ahead of what the
+    host holds: 1 token behind a decode or a mixed step, ``width`` behind
+    a block, the passes in flight behind a pass dispatch. ``src`` maps
+    such a row (by ``id``) to its row of the step's output: the step's
+    rows in the step's order; of a mixed step its packed output's, chunk
+    rows then decode rows - the decode rows, and every prompt whose LAST
+    chunk rides (a ``prefill_only`` one ends at its first token and is
+    not among them). ``rode`` maps a prompt to its chunk that rides."""
+
+    plan: StepPlan
+    kind: str           # one of CHAIN_KINDS
+    out: int
+    src: Dict[int, int]
+    rode: Dict[int, PrefillChunk] = field(default_factory=dict)
 
 
 @dataclass
@@ -374,15 +407,16 @@ class SchedulerConfig:
 # of them in the end does not fill a step ("partial")
 RUN_ENDS = ("queue", "rows", "pages", "partial")
 
-# why the program that follows a prefill-carrying (mixed) step was not
-# chained behind it: the rows of that program cannot be told before the
-# step's result ("rows": one is cancelled, or runs outside the step), a
-# row's device penalty window or guided automaton state is built on the
-# host from tokens that include the one in flight ("pcarry"), the run did
-# not go on as it was read ahead when the step was dispatched ("run": the
-# queue fell, or what is left of the prompts no longer fills a step), or
-# the planner of the block or of the next mixed step refused ("budget",
-# "pages": it never preempts and never admits)
+# why nothing was chained behind a prefill-carrying (mixed) step in
+# flight (``Scheduler.plan_behind``): the rows of what follows cannot be
+# told before the step's result ("rows": one is cancelled, or runs outside
+# the step), a row's device penalty window or guided automaton state is
+# built on the host from tokens that include the one in flight ("pcarry"),
+# the run goes on with a step that does not chain ("run": a prompt for the
+# ring is next), no row is left, or none with two tokens to go, so that
+# the host's plan would admit or decode step by step ("budget"), or the
+# pool lacks the rows' next pages ("pages": the plan behind a step never
+# preempts and never admits)
 CHAIN_REFUSALS = ("run", "rows", "pcarry", "budget", "pages")
 
 
@@ -392,6 +426,13 @@ def _penalized(so) -> bool:
                 or (so.repetition_penalty is not None
                     and so.repetition_penalty > 0
                     and so.repetition_penalty != 1.0))
+
+
+def _constrained(seq) -> bool:
+    """Does the row's sampling read per-token state beside its tokens: a
+    penalty window, a guided automaton?"""
+    so = seq.request.sampling_options
+    return bool(so.guided or _penalized(so))
 
 
 class Scheduler:
@@ -404,9 +445,12 @@ class Scheduler:
         self.waiting: Deque[Sequence] = deque()
         self.active: Dict[str, Sequence] = {}  # request_id -> seq (prefill+running)
         self._prefer_prefill = True
+        # the dispatched step the plan under way is made behind
+        # (``plan_behind``); None while the host plans from its own state
+        self._flight: Optional[InFlight] = None
         self.num_preemptions = 0
-        # set by the engine loop: the context ceiling used for the
-        # deterministic end-of-stream check in plan_chained
+        # set by the engine loop: the context ceiling of the
+        # deterministic end-of-stream checks (``_out_of_budget``)
         self.max_context_hint: Optional[int] = None
         # engine-dp rank advertised in load metrics (reference
         # WorkerStats.data_parallel_rank, kv_router/protocols.rs:52);
@@ -419,7 +463,7 @@ class Scheduler:
         # speculative-decode acceptance counters (reference surface:
         # SpecDecodeStats in the metrics plane, protocols/events.py)
         self.spec_stats = SpecDecodeStats()
-        # consecutive chained steps since the last schedule() (the
+        # consecutive chained decode steps since the last schedule() (the
         # spec_chain_break counter)
         self._chain_run = 0
         # blocks adopted mid-prefill from the prefix cache (injected by the
@@ -476,6 +520,13 @@ class Scheduler:
         ``seqs`` is ``record_fallback``'s, unused: no row leaves the
         fused path here."""
         self.chain_refusals[reason] = self.chain_refusals.get(reason, 0) + 1
+
+    def _refuse(self, reason: str) -> None:
+        """Nothing is chained behind the step in flight. Behind a mixed
+        step that is counted by its reason; behind a decode step or a
+        block it is how every chain ends."""
+        if self._flight.kind == "mixed":
+            self.record_chain_refusal(reason)
 
     def record_fallback(self, reason: str, seqs=()) -> None:
         """Count one fused-path refusal; also stamp the sequences it
@@ -631,6 +682,56 @@ class Scheduler:
         # positions [0, num_tokens-1] must be addressable
         return (num_tokens + self.page_size - 1) // self.page_size
 
+    # -- the step in flight ------------------------------------------------
+
+    def _out(self, seq: Sequence) -> int:
+        """Tokens (passes, of a pass dispatch) the step in flight puts
+        the row ahead of what the host holds."""
+        f = self._flight
+        return f.out if f is not None and id(seq) in f.src else 0
+
+    def _len(self, seq: Sequence) -> int:
+        """The row's tokens with those in flight counted."""
+        return len(seq) if self._flight is None else len(seq) + self._out(seq)
+
+    def _computed(self, seq: Sequence) -> int:
+        """The prompt positions computed once the chunk that rides the
+        step in flight is."""
+        f = self._flight
+        chunk = f.rode.get(id(seq)) if f is not None and f.rode else None
+        return seq.num_computed + (chunk.length if chunk is not None else 0)
+
+    def _phase(self, seq: Sequence) -> Phase:
+        """The row's phase once the step in flight has resolved, as far
+        as the host can tell before it has: a prompt whose last chunk
+        rides a mixed step decodes (a ``prefill_only`` one has ended),
+        and a row the ONE token in flight is sure to end has ended. A
+        row that ends inside a block in flight ends on the device: the
+        plan behind the block keeps it, as a dead row."""
+        f = self._flight
+        phase = seq.phase
+        if f is None or f.kind == "block":
+            return phase
+        if phase is Phase.PREFILL:
+            chunk = f.rode.get(id(seq))
+            if chunk is None or not chunk.is_last:
+                return phase
+            phase = Phase.RUNNING if id(seq) in f.src else Phase.FINISHED
+        if (phase is Phase.RUNNING and id(seq) in f.src
+                and self._out_of_budget(seq, f.out)):
+            return Phase.FINISHED
+        return phase
+
+    def _out_of_budget(self, seq: Sequence, ahead: int = 0) -> bool:
+        """Has the row, ``ahead`` tokens past what the host holds, spent
+        its budget or reached the context ceiling (the rules of
+        ``_accept_token`` that need no look at the token)?"""
+        max_new = self._max_new(seq)
+        return ((max_new is not None
+                 and len(seq.generated) + ahead >= max_new)
+                or (self.max_context_hint is not None
+                    and len(seq) + ahead >= self.max_context_hint))
+
     # -- per-step bookkeeping ---------------------------------------------
 
     def _commit_full_pages(self, seq: Sequence) -> None:
@@ -684,9 +785,16 @@ class Scheduler:
         self.num_preemptions += 1
         return True
 
+    def _short(self, seq: Sequence) -> int:
+        """Pages the row lacks for the position it feeds next (``len -
+        1``; behind a step in flight the position of the token in
+        flight, ``len``)."""
+        return max(0, self._pages_needed(self._len(seq)) - len(seq.page_ids))
+
     def _grow_for_decode(self, seq: Sequence) -> bool:
-        """Ensure the page for position ``len-1`` exists; may preempt others."""
-        need = self._pages_needed(len(seq)) - len(seq.page_ids)
+        """Ensure the page for the position the row feeds next exists;
+        may preempt others."""
+        need = self._short(seq)
         while need > 0:
             try:
                 seq.page_ids.extend(self.alloc.allocate(need))
@@ -746,11 +854,15 @@ class Scheduler:
         """Admit waiting sequences (bounded by slots, pages, and batch
         width; none with ``admit`` off), then pack up to
         ``max_prefill_seqs`` chunks into one step under the
-        ``max_prefill_chunk`` token budget, oldest first."""
+        ``max_prefill_chunk`` token budget, oldest first. Behind a step
+        in flight every prompt goes on from where the chunk that rides
+        the step leaves it."""
         # adopt blocks that became resident since admission (prefetch or
         # disagg injects, concurrent requests committing a shared prefix)
-        # so each chunk starts where residency ends
-        for s in self.active.values():
+        # so each chunk starts where residency ends (not behind a step in
+        # flight, under whose chunk the cursor would move: such a block is
+        # computed once more, or adopted the next time the host plans)
+        for s in self.active.values() if self._flight is None else ():
             if s.phase == Phase.PREFILL:
                 self._adopt_resident(s)
                 self._skip_empty_prefill(s)
@@ -762,8 +874,8 @@ class Scheduler:
             # (prefix-cache hits always are — admission truncates to full
             # pages); the REMAINING tokens must justify a ring step
             return (rt is not None
-                    and s.num_computed % self.page_size == 0
-                    and len(s) - s.num_computed > rt)
+                    and self._computed(s) % self.page_size == 0
+                    and len(s) - self._computed(s) > rt)
 
         # cap admission at the batch width so admitted pages don't sit idle
         # across many steps waiting for a row; ring candidates run alone and
@@ -774,9 +886,10 @@ class Scheduler:
                         if s.phase == Phase.PREFILL and not ring_eligible(s))
         n_ring = sum(1 for s in self.active.values()
                      if s.phase == Phase.PREFILL and ring_eligible(s))
-        # why the pass stops: until ``_try_admit`` refuses for another
-        # reason, by its own cap (or a ring prompt held back)
-        self._admit_stop = "partial"
+        if admit:
+            # why the pass stops: until ``_try_admit`` refuses for another
+            # reason, by its own cap (or a ring prompt held back)
+            self._admit_stop = "partial"
         reserve = self._block_reserve() if admit else 0
         while admit and n_prefill < self.cfg.max_prefill_seqs:
             while self.waiting and self.waiting[0].cancelled:
@@ -799,7 +912,8 @@ class Scheduler:
             else:
                 n_prefill += 1
         prefilling = sorted(
-            (s for s in self.active.values() if s.phase == Phase.PREFILL),
+            (s for s in self.active.values()
+             if self._phase(s) == Phase.PREFILL),
             key=lambda s: s.arrival)
         if not prefilling:
             return None
@@ -814,8 +928,8 @@ class Scheduler:
         if ring_eligible(prefilling[0]):
             seq = prefilling[0]
             return PrefillBatch(ring=True, chunks=[PrefillChunk(
-                seq=seq, start=seq.num_computed,
-                length=len(seq) - seq.num_computed, is_last=True)])
+                seq=seq, start=self._computed(seq),
+                length=len(seq) - self._computed(seq), is_last=True)])
         budget = self.cfg.max_prefill_chunk
         chunks: List[PrefillChunk] = []
         packable = [s for s in prefilling if not ring_eligible(s)]
@@ -824,7 +938,8 @@ class Scheduler:
                 break
             # len(seq), not num_prompt: a revived preempted sequence must
             # also re-prefill the tokens it had generated before eviction
-            remaining = self._prefill_target(seq) - seq.num_computed
+            start = self._computed(seq)
+            remaining = self._prefill_target(seq) - start
             length = min(remaining, budget)
             if length < remaining:
                 # a chunk ends on a block boundary (any position is one
@@ -832,54 +947,83 @@ class Scheduler:
                 length -= length % self.cfg.gen_block
                 if length <= 0:
                     break
-            chunks.append(PrefillChunk(seq=seq, start=seq.num_computed,
-                                       length=length,
+            chunks.append(PrefillChunk(seq=seq, start=start, length=length,
                                        is_last=(length == remaining)))
             budget -= length
         return PrefillBatch(chunks=chunks) if chunks else None
 
     def _grow_ready(self, decodable: List[Sequence]) -> List[Sequence]:
         """Grow pages for the decode rows (may preempt newest RUNNING
-        sequences); returns the rows that survived with pages in place."""
+        sequences); returns the rows that survived with pages in place,
+        by arrival. Behind a step in flight nothing is preempted: every
+        row's page is there, or no row is returned and no page taken."""
+        rows = sorted(decodable, key=lambda s: s.arrival)
+        if self._flight is not None and sum(
+                map(self._short, rows)) > self.alloc.num_free:
+            return []
         ready: List[Sequence] = []
-        for seq in sorted(decodable, key=lambda s: s.arrival):
-            if seq.phase != Phase.RUNNING:
+        for seq in rows:
+            if self._phase(seq) != Phase.RUNNING:
                 continue  # preempted by an earlier grow
             if self._grow_for_decode(seq):
                 ready.append(seq)
-        return [s for s in ready if s.phase == Phase.RUNNING]
+        return [s for s in ready if self._phase(s) == Phase.RUNNING]
 
     def schedule(self) -> Optional[StepPlan]:
-        """Pick the next engine step, or None if there is nothing to run.
+        """Pick the next engine step from the host's state, or None if
+        there is nothing to run (``_next_plan`` says what runs when). A
+        pure-decode plan the loop upgrades to a fused block
+        (``plan_multistep``); while a step it dispatched is in flight it
+        asks ``plan_behind`` first, and comes here when that refuses and
+        the step has resolved."""
+        return self._plan(None)
 
-        With ``mixed_batch`` on (the default), prefill steps carry the
-        decode rows along as length-1 ragged chunks (MixedStepBatch).
-        A run of them starts with an admission pass: the prompts that
-        wait, as far as rows, ``max_prefill_seqs`` and the pool allow
-        (``_try_admit`` leaves in the pool what the admitted rows will
-        ask for, ``_block_reserve``). After a mixed step comes another
-        one while a queue stands and what the pass admitted still fills
-        a whole step; every step of a run takes every decode row one
-        token on. Then comes the pure-decode plan, which the loop
-        upgrades to a fused multi-step block, and completions free rows
-        and pages for the next run. Where no queue stands behind a mixed
-        step the run is one step long and the plans alternate mixed /
-        pure-decode. Behind a mixed step the loop does not come back
-        here where what follows can be chained on the device
-        (``chains_behind``): behind a step that is not its run's last,
-        the run's next mixed step (``plan_mixed_behind``), behind a
-        run's last step the fused block (``plan_multistep_behind``) -
-        the same rows in the same order, planned while the step runs;
-        this method's bookkeeping for the plan is done there. Only a
-        run's first step is always planned here: it admits, and needs
-        the completions the host has seen. With ``mixed_batch`` off, the
-        legacy prefill-XOR-decode alternation applies, except that a deep
-        waiting queue may take up to ``decode_progress_every - 1``
-        consecutive prefill steps (burst TTFT) before a decode step is
-        forced: the decode-progress guarantee that bounds decode tail
-        latency under sustained arrivals."""
-        plan = self._next_plan()
-        if self._run_steps and not isinstance(plan, MixedStepBatch):
+    def plan_behind(self, prev: StepPlan,
+                    kinds=CHAIN_KINDS) -> Optional[StepPlan]:
+        """Plan what the device runs BEHIND ``prev``, which is dispatched
+        and not yet accounted for (its result is still on the device),
+        or None: the loop then resolves ``prev`` and plans from host
+        state. ``kinds`` are the ``CHAIN_KINDS`` the engine has programs
+        for.
+
+        The plan is the one ``schedule()`` would return once ``prev`` had
+        resolved, made by the same function (``_next_plan``) over what
+        the host holds plus what ``prev`` will have done (``InFlight``):
+        behind a decode step the next decode step, behind a fused block
+        the next block, behind a mixed step the next mixed step of its
+        admission run or, behind the run's last, the block. ``behind``
+        on a mixed step or a block says what it was chained behind, and
+        ``src_rows`` where each of its rows finds its newest token in a
+        mixed step's packed output. The scheduler's books stand where
+        ``schedule()`` would have left them; after a refusal NOTHING has
+        changed (but pages a block that was then refused took for a
+        narrower width, which its rows need next anyway), and behind a
+        mixed step the refusal is counted (``CHAIN_REFUSALS``)."""
+        flight = self._in_flight(prev, kinds)
+        return self._plan(flight) if flight is not None else None
+
+    def chains_behind(self, step: MixedStepBatch, kinds=CHAIN_KINDS) -> bool:
+        """May something be chained behind the mixed ``step``
+        (``plan_behind`` will say what)? Asked BEFORE a step planned
+        from host state is dispatched: one that chains returns at its
+        enqueue, one that does not is resolved inside its dispatch, as it
+        always was. The answer is ``plan_behind``'s first test
+        (``_rows_known``), and a refusal is counted there."""
+        self._flight = self._in_flight(step, kinds)
+        try:
+            return self._flight is not None and self._rows_known()
+        finally:
+            self._flight = None
+
+    def _plan(self, flight: Optional[InFlight]) -> Optional[StepPlan]:
+        self._flight = flight
+        try:
+            plan = (self._next_plan()
+                    if flight is None or self._rows_known() else None)
+        finally:
+            self._flight = None
+        if (self._run_steps and not isinstance(plan, MixedStepBatch)
+                and (plan is not None or flight is None)):
             self._end_run()
         return plan
 
@@ -888,14 +1032,101 @@ class Scheduler:
         self.admission_runs[self._run_stop] += 1
         self._run_steps = 0
 
-    def _next_plan(self) -> Optional[StepPlan]:
-        self._chain_run = 0
-        # drop cancelled active sequences
-        for seq in [s for s in self.active.values() if s.cancelled]:
-            self.finish(seq)
-            self.reaped.append(seq)
+    def _in_flight(self, prev: StepPlan, kinds) -> Optional[InFlight]:
+        """The planner's view of ``prev``, or None where nothing is
+        chained behind a step of its kind: the engine lacks the program,
+        no block follows a mixed step (``decode_multistep`` < 2: a mixed
+        step is only planned for a causal model without drafts), or
+        speculation is due (chains never consult the draft proposer:
+        they break every ``spec_chain_break`` steps so that repetitive
+        context gets its verify steps)."""
+        rode: Dict[int, PrefillChunk] = {}
+        if isinstance(prev, MultiStepBatch):
+            kind, out = "block", prev.width + getattr(prev, "inflight", 0)
+        elif isinstance(prev, MixedStepBatch):
+            kind, out = "mixed", 1
+        elif isinstance(prev, DecodeBatch):
+            kind, out = "decode", 1
+        else:
+            return None
+        if kind not in kinds:
+            return None
+        if kind == "mixed":
+            if self.cfg.decode_multistep < 2:
+                return None
+            rode = {id(c.seq): c for c in prev.chunks}
+            src = {id(c.seq): i for i, c in enumerate(prev.chunks)
+                   if c.is_last and not c.seq.request.prefill_only}
+            src.update((id(s), len(prev.chunks) + j)
+                       for j, s in enumerate(prev.decode_seqs))
+        else:
+            src = {id(s): i for i, s in enumerate(prev.seqs)}
+        if (kind == "decode" and self.cfg.spec_tokens > 0
+                and self._chain_run >= self.cfg.spec_chain_break):
+            return None
+        return InFlight(prev, kind, out, src, rode)
 
-        decodable = [s for s in self.active.values() if s.phase == Phase.RUNNING]
+    def _rows_known(self) -> bool:
+        """Can the rows of what follows the step in flight be told
+        before its result? Not where a row is cancelled or runs outside
+        a mixed step (the host's plan would drop the one and take the
+        other along), and not where a row of a one-token step keeps
+        per-token state the HOST builds - a penalty window, a guided
+        automaton: both would lack the token in flight (a block carries
+        them on the device; seeds alone are fine, their keys fold the
+        token's position)."""
+        f = self._flight
+        if (any(s.cancelled for s in (*f.plan.seqs, *self.active.values()))
+                or f.kind == "mixed" and any(
+                    s.phase is Phase.RUNNING and id(s) not in f.src
+                    for s in self.active.values())):
+            self._refuse("rows")
+            return False
+        if f.kind != "block" and any(_constrained(s) for s in f.plan.seqs):
+            self._refuse("pcarry")
+            return False
+        return True
+
+    def _next_plan(self) -> Optional[StepPlan]:
+        """What runs next: the one statement of it, for the host's own
+        plan and for the plan behind a step in flight.
+
+        With ``mixed_batch`` on (the default), prefill steps carry the
+        decode rows along as length-1 ragged chunks (MixedStepBatch). A
+        run of them starts with an admission pass: the prompts that
+        wait, as far as rows, ``max_prefill_seqs`` and the pool allow
+        (``_try_admit`` leaves in the pool what the admitted rows will
+        ask for, ``_block_reserve``). After a mixed step comes another
+        one while a queue stands and what the pass admitted still fills
+        a whole step; every step of a run takes every decode row one
+        token on. Then comes the pure-decode plan (a fused block, once
+        upgraded), and completions free rows and pages for the next run.
+        Where no queue stands behind a mixed step the run is one step
+        long and the plans alternate mixed / pure-decode. With
+        ``mixed_batch`` off, the legacy prefill-XOR-decode alternation
+        applies, except that a deep waiting queue may take up to
+        ``decode_progress_every - 1`` consecutive prefill steps (burst
+        TTFT) before a decode step is forced: the decode-progress
+        guarantee that bounds decode tail latency under sustained
+        arrivals.
+
+        Behind a step in flight the plan differs in this and nothing
+        else: it never admits (where the host's plan would, it is
+        refused: an admission needs the completions the host has not
+        seen), never preempts and never adopts from the prefix cache;
+        a row the token in flight is sure to end is gone (``_phase``);
+        its rows are those the device can find in the step's output
+        (``_rows_behind``); and the plan says where (``src_rows``)."""
+        f = self._flight
+        if f is None:
+            self._chain_run = 0
+            # drop cancelled active sequences
+            for seq in [s for s in self.active.values() if s.cancelled]:
+                self.finish(seq)
+                self.reaped.append(seq)
+
+        decodable = [s for s in self.active.values()
+                     if self._phase(s) == Phase.RUNNING]
 
         K = self.cfg.decode_progress_every
         force_decode = bool(decodable and K > 0
@@ -912,7 +1143,11 @@ class Scheduler:
                      and len(self.waiting) >= self.cfg.max_prefill_seqs)
         if not force_decode and (self._prefer_prefill or not decodable
                                  or go_on):
-            batch = self._prefill_plan(admit=not go_on)
+            if f is not None and not go_on and (self.waiting
+                                                or not decodable):
+                # the host's plan admits, or has no row left to decode
+                return self._refuse("budget")
+            batch = self._prefill_plan(admit=not go_on and f is None)
             if (go_on and batch is not None
                     and sum(c.length for c in batch.chunks)
                     < self.cfg.max_prefill_chunk):
@@ -921,6 +1156,11 @@ class Scheduler:
                 # tokens) the steady state does not call; what is left
                 # rides the next run's first step, as it always has
                 batch = None
+            if f is not None and batch is not None and (
+                    batch.ring or f.kind != "mixed"):
+                # a step for the ring; prompt chunks behind a decode step
+                # or a block: the device chains neither
+                return self._refuse("run")
             if batch is not None:
                 if self.cfg.gen_block > 1:
                     # rows in mid-block wait through an admission step
@@ -928,6 +1168,8 @@ class Scheduler:
                 elif (self.cfg.mixed_batch and not batch.ring
                         and self.cfg.spec_tokens == 0 and decodable):
                     ready = self._grow_ready(decodable)
+                    if f is not None and not ready:
+                        return self._refuse("pages")
                     # re-filter: growth may have preempted a planned chunk's
                     # sequence back to WAITING — drop its chunk
                     chunks = [c for c in batch.chunks
@@ -943,8 +1185,13 @@ class Scheduler:
                                               if self.waiting else "queue")
                         self._run_steps += 1
                         self.admission_run_steps += 1
-                        return MixedStepBatch(chunks=chunks,
+                        step = MixedStepBatch(chunks=chunks,
                                               decode_seqs=ready)
+                        if f is not None:
+                            step.behind = f.kind
+                            step.src_rows = [f.src[id(s)] for s in ready]
+                            self.chained_steps[f.kind] += 1
+                        return step
                     if not chunks and not ready:
                         return None
                     if not chunks:
@@ -970,23 +1217,84 @@ class Scheduler:
                     if decodable:
                         self._steps_since_decode += 1
                     return batch
-        self._prefer_prefill = True
+        plan = (self._decode_plan(decodable) if f is None
+                else self._decode_behind(decodable))
+        if plan is not None or f is None:
+            self._prefer_prefill = True
+        if plan is not None:
+            self._steps_since_decode = 0
+        return plan
+
+    def _decode_plan(self, decodable: List[Sequence]) -> Optional[StepPlan]:
+        """The host's pure-decode plan (a verify step where drafts
+        match)."""
         if self.cfg.gen_block > 1:
             # an admission may have put a row straight to RUNNING (its
             # prompt's whole blocks were all cached: nothing to prefill)
             decodable = [s for s in self.active.values()
                          if s.phase == Phase.RUNNING]
-        if not decodable:
-            return None
         ready = self._grow_ready(decodable)
         if not ready:
             return None
-        self._steps_since_decode = 0
         if self.cfg.spec_tokens > 0:
             spec = self._spec_plan(ready)
             if spec is not None:
                 return spec
         return DecodeBatch(seqs=ready)
+
+    def _decode_behind(self, decodable: List[Sequence]) -> Optional[StepPlan]:
+        """The pure-decode plan behind the step in flight, as the device
+        runs it: behind a decode step the next one, its pages grown one
+        position ahead; behind a block or a mixed step the fused block,
+        which the loop does not have to upgrade."""
+        f = self._flight
+        rows = self._rows_behind(decodable)
+        if rows is None:
+            return None
+        if f.kind == "decode":
+            if not self._grow_ready(rows):
+                return None
+            self._chain_run += 1
+            return DecodeBatch(seqs=rows)
+        plan = (self._plan_passes(rows) if self.cfg.gen_block > 1
+                else self._plan_block(rows))
+        if plan is not None and f.kind == "mixed":
+            plan.src_rows = [f.src[id(s)] for s in rows]
+        return plan
+
+    def _rows_behind(self, decodable: List[Sequence]
+                     ) -> Optional[List[Sequence]]:
+        """Which rows the pure-decode plan behind the step in flight
+        holds, and in which order: those the device can find in the
+        step's output.
+
+        Behind a mixed step a row is found by ``src_rows``: the rows are
+        the host's (``decodable``, by arrival), and a block takes along
+        the rows the token in flight is sure to end, dead from its start
+        as the device will have them. Behind a decode step or a block
+        row i reads row i: the step's rows in the step's order, and that
+        only where its live rows are the rows the host would plan. A
+        decode step's rows all have to live (a row that is sure to end,
+        or any other change of the row set, breaks the chain). A block
+        keeps a row that ended because its budget ran out (``_spent``:
+        dead in the device's carry too) as a dead row while more than
+        half of its rows live - the end of one stream does not stall the
+        others - and breaks at a row the host alone ended: the device
+        has that one alive."""
+        f = self._flight
+        if f.kind == "mixed":
+            return sorted((s for s in self.active.values()
+                           if id(s) in f.src), key=lambda s: s.arrival)
+        rows = list(f.plan.seqs)
+        live = [s for s in rows if s.phase is Phase.RUNNING]
+        if live != sorted(decodable, key=lambda s: s.arrival):
+            return None
+        if f.kind == "decode":
+            return rows if len(live) == len(rows) else None
+        if 2 * len(live) <= len(rows) or not all(
+                self._spent(s) for s in rows if s.phase is not Phase.RUNNING):
+            return None
+        return rows
 
     # -- speculative decoding ----------------------------------------------
 
@@ -997,25 +1305,20 @@ class Scheduler:
         Penalties / logit_bias mutate logits from host bookkeeping that
         goes stale within a multi-token step; per-request seeds key their
         randomness on a single token position. Any such row sends the
-        whole batch down the plain decode path (same rule as
-        ``plan_chained``). Top-logprobs requests ARE eligible (the verify
+        whole batch down the plain decode path. Top-logprobs requests ARE eligible (the verify
         step packs per-position alternatives), and so are GUIDED rows —
         the host walks the automaton along the known draft path and ships
         one allow-mask per chunk slot (JaxEngine._guided_spec_masks), so
         structured outputs keep their exactness under speculation."""
         so = seq.request.sampling_options
-        rep_on = (so.repetition_penalty is not None
-                  and so.repetition_penalty > 0
-                  and so.repetition_penalty != 1.0)
-        return not (so.frequency_penalty or so.presence_penalty or rep_on
-                    or so.logit_bias or so.seed is not None or so.min_p)
+        return not (_penalized(so) or so.seed is not None or so.min_p)
 
     def _spec_plan(self, ready: List[Sequence]) -> Optional[SpecDecodeBatch]:
         """Try to upgrade this decode step to a [B, K+1] verify step."""
         K = self.cfg.spec_tokens
         if not all(self._spec_eligible(s) for s in ready):
             return None
-        # context-ceiling guard (as plan_chained's): the verify step feeds
+        # context-ceiling guard: the verify step feeds
         # positions len .. len+K-1 and needs pages/table slots for len+K
         # tokens — a row within K of max_context would overrun the static
         # page-table width (and the positions themselves). Those rows are
@@ -1094,75 +1397,9 @@ class Scheduler:
         for seq in plan.seqs:
             self._commit_full_pages(seq)
 
-    def plan_chained(self, prev: DecodeBatch) -> Optional[DecodeBatch]:
-        """Plan decode step N+1 while step N's results are still on device.
-
-        Called BEFORE ``on_step_done(prev)`` ran — sequence state still
-        excludes step N's token. Returns a DecodeBatch over exactly
-        ``prev.seqs`` (same order, so the device can index step N's sampled
-        tokens row-for-row), or None when chaining is unsafe:
-
-        - anything is waiting/prefilling (the normal schedule would prefer a
-          prefill step, and new rows would break row alignment),
-        - any prev sequence finished/was cancelled per host knowledge,
-        - any sequence deterministically finishes at step N (max_tokens /
-          max_context) — its N+1 row would be wasted work and the drain
-          boundary is cheap,
-        - page growth for the +1 lookahead fails (no preemption on this
-          path; the caller falls back to the drain-then-schedule flow).
-
-        Safety of the speculative row for a sequence that turns out to
-        finish at step N (EOS/stop): the device writes step N's token KV at
-        position ``len`` into a page that can never be committed (its last
-        position is not computed), so after release it returns to the free
-        list — a later owner overwrites before any masked read. The row's
-        sampled output is discarded at process time (phase != RUNNING).
-        """
-        if self.waiting:
-            return None
-        if self.cfg.spec_tokens > 0:
-            # chains never consult the draft proposer: break periodically
-            # so repetitive context gets its verify steps (the chain's
-            # readback-hiding covers the non-matching stretches)
-            if (self.cfg.spec_chain_break <= 0
-                    or self._chain_run >= self.cfg.spec_chain_break):
-                return None
-        for seq in prev.seqs:
-            if seq.phase is not Phase.RUNNING or seq.cancelled:
-                return None
-            so = seq.request.sampling_options
-            if (so.frequency_penalty or so.presence_penalty or so.guided
-                    or (so.repetition_penalty is not None
-                        and so.repetition_penalty > 0
-                        and so.repetition_penalty != 1.0)):
-                # penalty windows and guided-decoding masks are built from
-                # host bookkeeping, which at chain-planning time still
-                # excludes step N's token — a chained step would penalize
-                # one token stale / mask against a stale automaton state.
-                # Such traffic takes the fetch-then-plan flow; seeds alone
-                # are fine (their keys fold the token position, not host
-                # state).
-                return None
-            # after step N the sequence has len+1 tokens / generated+1
-            if self._ends_at_next(seq):
-                return None
-        if any(s.phase is Phase.PREFILL for s in self.active.values()):
-            return None
-        # +1 lookahead growth: step N+1 writes KV at position len(seq)
-        for seq in prev.seqs:
-            need = self._pages_needed(len(seq) + 1) - len(seq.page_ids)
-            if need > 0:
-                try:
-                    seq.page_ids.extend(self.alloc.allocate(need))
-                    seq.pages_changed()
-                except OutOfPages:
-                    return None
-        self._chain_run += 1
-        return DecodeBatch(seqs=list(prev.seqs))
-
     # -- fused multi-step decode --------------------------------------------
 
-    def _fuse_gate(self, seq: Sequence, sl: int):
+    def _fuse_gate(self, seq: Sequence):
         """Admit one row to the fused block, or name the refusal.
 
         Returns ``(reason, width_cap)``: ``reason`` is a fallback-reason
@@ -1199,8 +1436,7 @@ class Scheduler:
                 n_prompt = seq.num_prompt - min(
                     seq.request.resumed_tokens, seq.num_prompt)
                 distinct |= set(toks[n_prompt:seq.num_prompt])
-            inflight = sl - len(seq)
-            cap = W - len(distinct) - inflight
+            cap = W - len(distinct) - self._out(seq)
             if cap < 2:
                 return "penalty_window", cap
         if so.guided:
@@ -1244,24 +1480,17 @@ class Scheduler:
         Rows the host alone ended (cancelled, stop strings, errors) are
         alive on the device and never ride; constrained rows keep
         per-request device state that ``release_request`` drops."""
-        so = seq.request.sampling_options
-        if so.guided or _penalized(so):
-            return False
-        max_new = self._max_new(seq)
-        return ((max_new is not None and len(seq.generated) >= max_new)
-                or (self.max_context_hint is not None
-                    and len(seq) >= self.max_context_hint))
+        return not _constrained(seq) and self._out_of_budget(seq)
 
-    def _plan_block(self, seqs: List[Sequence], start_lens: List[int],
-                    behind: str = "") -> Optional[MultiStepBatch]:
+    def _plan_block(self, seqs: List[Sequence]) -> Optional[MultiStepBatch]:
         """Compute the fuse width for one block over ``seqs`` and allocate
-        its pages, or None to fall back to the per-step path. ``behind``
-        names what a chained block follows (``MultiStepBatch.behind``).
-        Behind a mixed step every row can still run when the block
-        starts (a prompt whose last chunk rides that step is not RUNNING
-        yet), and a refusal is a chain not taken
-        (``record_chain_refusal``), not a fallback: the block is planned
-        again from host state.
+        its pages, or None to fall back to the per-step path. Behind a
+        step in flight the block is chained (``MultiStepBatch.behind``
+        names the step's kind): every row starts ``_out`` tokens past
+        what the host holds, counted in budgets, ``min_tokens`` gates,
+        widths and the pages grown up front. Behind a mixed step a
+        refusal is a chain not taken (``record_chain_refusal``), not a
+        fallback: the block is planned again from host state.
 
         The width is the configured cap (``decode_multistep``), narrowed
         to what the row with the MOST tokens left can still use
@@ -1275,8 +1504,8 @@ class Scheduler:
         its budget and masks it for the rest of the block, so a stream
         that ends takes no other row through narrower, unchained blocks.
         In a chained block a row that ends inside the block still in
-        flight (or ended by its budget, ``_spent``) rides as a dead row:
-        the device carry has it dead from block start. Penalized / biased
+        flight, or has ended (``_rows_behind``), rides as a dead row: the
+        device has it dead from block start. Penalized / biased
         rows additionally cap the width by their remaining device
         penalty-window capacity (``_fuse_gate``); spec-decode mode and
         rows the gate cannot admit (no penalty window configured,
@@ -1285,6 +1514,8 @@ class Scheduler:
         cap = self.cfg.decode_multistep
         if cap < 2:
             return None
+        behind = self._flight.kind if self._flight is not None else ""
+        start_lens = [self._len(s) for s in seqs]
         refuse = (self.record_chain_refusal if behind == "mixed"
                   else self.record_fallback)
         if self.cfg.spec_tokens > 0:
@@ -1294,19 +1525,17 @@ class Scheduler:
         budgets: List[int] = []
         min_gates: List[int] = []
         for seq, sl in zip(seqs, start_lens):
-            if behind != "mixed" and seq.phase is not Phase.RUNNING:
-                # behind a block only (``plan_multistep_chained`` let it
-                # in)
-                budgets.append(0)
+            if self._phase(seq) is not Phase.RUNNING:
+                budgets.append(0)       # a dead row
                 min_gates.append(0)
                 continue
-            reason, row_cap = self._fuse_gate(seq, sl)
+            reason, row_cap = self._fuse_gate(seq)
             if reason is not None:
                 refuse(reason, seqs)
                 return None
             w = min(w, row_cap)
             sc = seq.request.stop_conditions
-            gen_eff = len(seq.generated) + (sl - len(seq))
+            gen_eff = len(seq.generated) + self._out(seq)
             max_new = self._max_new(seq)
             rem = (max_new - gen_eff) if max_new is not None else 1 << 20
             if self.max_context_hint is not None:
@@ -1331,7 +1560,7 @@ class Scheduler:
         if behind:
             self.chained_blocks[behind] += 1
         return MultiStepBatch(seqs=list(seqs), width=w, chained=bool(behind),
-                              start_lens=list(start_lens), budgets=budgets,
+                              start_lens=start_lens, budgets=budgets,
                               min_gates=min_gates, behind=behind)
 
     def plan_multistep(self, batch: DecodeBatch) -> Optional[MultiStepBatch]:
@@ -1341,12 +1570,11 @@ class Scheduler:
         prefills" gate is LIFTED: arrivals onboard through the mixed
         steps that alternate with the fused blocks, so fusing while they
         wait no longer head-of-line blocks admission for more than one
-        block (chained blocks still break at boundaries —
-        ``plan_multistep_chained`` keeps the refusal). With it off, the
-        legacy gate applies and the refusal is recorded as a fallback
-        reason."""
+        block (a chain of blocks still breaks there, ``_next_plan``: the
+        host's plan admits). With it off, the legacy gate applies and the
+        refusal is recorded as a fallback reason."""
         if self.cfg.gen_block > 1:
-            return self._plan_passes(batch.seqs, 0)
+            return self._plan_passes(batch.seqs)
         if not self.cfg.mixed_batch:
             if self.waiting:
                 self.record_fallback("waiters", batch.seqs)
@@ -1354,261 +1582,7 @@ class Scheduler:
             if any(s.phase is Phase.PREFILL for s in self.active.values()):
                 self.record_fallback("prefill", batch.seqs)
                 return None
-        return self._plan_block(batch.seqs, [len(s) for s in batch.seqs])
-
-    def plan_multistep_chained(self, prev: MultiStepBatch
-                               ) -> Optional[MultiStepBatch]:
-        """Plan block k+1 while block k's results are still on device.
-
-        Host sequence state excludes block k's (unfetched) tokens, so the
-        effective row length is ``len(seq) + prev.width`` — positions and
-        budgets are computed from that offset, and the device carry
-        supplies the actual first token / liveness. Refused when the batch
-        may change (waiting/prefilling arrivals, any row cancelled or
-        ended by the host alone). A row that ended because its budget ran
-        out (``_spent``) is dead in the device carry too and stays in the
-        chain as a dead row, while more than half of the rows live: the
-        end of one stream does not stall the others, and the chain breaks
-        where the next arrival is admitted anyway. Unlike
-        ``plan_multistep``, the waiting/prefilling refusals survive the
-        mixed-batch gate lift ON PURPOSE: a chain of BLOCKS breaks where
-        arrivals get their admission/prefill (mixed) step (the host has
-        to see the completions that free their rows and pages) — it is
-        not a fallback to per-step decode and is not counted as one. The
-        other side of that boundary no longer breaks the device's chain:
-        the block behind the mixed step takes its first tokens from the
-        step's on-device output (``plan_multistep_behind``)."""
-        if self.waiting:
-            return None
-        live = 0
-        for seq in prev.seqs:
-            if seq.phase is Phase.RUNNING:
-                if seq.cancelled:
-                    return None
-                live += 1
-            elif not self._spent(seq):
-                return None
-        if 2 * live <= len(prev.seqs):
-            return None
-        if any(s.phase is Phase.PREFILL for s in self.active.values()):
-            return None
-        if isinstance(prev, GenPassBatch):
-            return self._plan_passes(prev.seqs, prev.inflight + prev.width)
-        return self._plan_block(prev.seqs,
-                                [len(s) + prev.width for s in prev.seqs],
-                                behind="block")
-
-    def _chunks_behind(self, step: MixedStepBatch
-                       ) -> Optional[List[PrefillChunk]]:
-        """The chunks ``_prefill_plan(admit=False)`` would pack once
-        ``step`` is accounted for, read ahead: the prompts still in
-        prefill, oldest first, each from where the step's own chunk
-        leaves it. None where one of them is for the ring. Nothing is
-        adopted from the prefix cache here (a block that became resident
-        meanwhile is computed once more; ``_prefill_plan`` adopts what
-        is left the next time the host plans)."""
-        rode = {id(c.seq): c.length for c in step.chunks}
-        rt = self.cfg.ring_threshold
-        budget = self.cfg.max_prefill_chunk
-        chunks: List[PrefillChunk] = []
-        for s in sorted((s for s in self.active.values()
-                         if s.phase is Phase.PREFILL),
-                        key=lambda s: s.arrival):
-            start = s.num_computed + rode.get(id(s), 0)
-            remaining = self._prefill_target(s) - start
-            if remaining <= 0:
-                continue        # its last chunk rides the step
-            if rt is not None and remaining > rt:
-                return None
-            if len(chunks) < self.cfg.max_prefill_seqs and budget > 0:
-                length = min(remaining, budget)
-                chunks.append(PrefillChunk(seq=s, start=start, length=length,
-                                           is_last=(length == remaining)))
-                budget -= length
-        return chunks
-
-    def _run_goes_on(self, step: MixedStepBatch) -> bool:
-        """Will ``_next_plan`` follow ``step``, once it is accounted for,
-        with another mixed step of the same run (its ``go_on`` rule, read
-        ahead)? A queue stands and what the run's admission pass took,
-        less this step's chunks, still fills a whole step. A prompt for
-        the ring reads as "goes on": the next plan is no decode plan
-        either way."""
-        if not self._queue_stands():
-            return False
-        chunks = self._chunks_behind(step)
-        return chunks is None or self._fills_a_step(chunks)
-
-    def _queue_stands(self) -> bool:
-        """More requests wait than the next admission pass could take
-        (and the decode-progress guarantee, which at 1 forces the decode
-        plan behind every step, lets a run go on at all)."""
-        return (len(self.waiting) >= self.cfg.max_prefill_seqs
-                and self.cfg.decode_progress_every != 1)
-
-    def _fills_a_step(self, chunks: List[PrefillChunk]) -> bool:
-        return (sum(c.length for c in chunks)
-                >= self.cfg.max_prefill_chunk)
-
-    def chains_behind(self, step: MixedStepBatch) -> str:
-        """What may be chained behind ``step`` on the device, dispatched
-        while the step runs with its first tokens read from the step's
-        packed output: ``"mixed"``, the next mixed step of the step's
-        admission run (``plan_mixed_behind``: the run goes on),
-        ``"block"``, the fused block (``plan_multistep_behind``: the
-        step is its run's last and the pure-decode plan follows), or
-        ``""``, nothing. Asked from what the host knows with the step
-        not yet accounted for: BEFORE a step planned from host state is
-        dispatched, so that one that does not chain is dispatched as it
-        always was; of a step that was itself chained, when the step in
-        front of it has resolved. Something chains where every row that
-        runs rides the step, none is cancelled, and none keeps per-token
-        state the host builds (a penalty window, a guided automaton:
-        both would lack the token in flight). A refusal is counted by
-        reason (``CHAIN_REFUSALS``)."""
-        if self.cfg.decode_multistep < 2:
-            return ""       # no block follows (a mixed step is only
-                            # planned for a causal model without drafts)
-        riding = {id(s) for s in step.seqs}
-        if (any(s.cancelled for s in step.seqs)
-                or any(s.phase is Phase.RUNNING and id(s) not in riding
-                       for s in self.active.values())):
-            self.record_chain_refusal("rows")
-            return ""
-        for s in step.seqs:
-            so = s.request.sampling_options
-            if so.guided or _penalized(so):
-                self.record_chain_refusal("pcarry")
-                return ""
-        return "mixed" if self._run_goes_on(step) else "block"
-
-    def _rows_behind(self, step: MixedStepBatch) -> Dict[int, int]:
-        """Where each row that decodes once ``step`` resolved finds its
-        token in the step's packed output (chunk rows, then decode rows),
-        by ``id``: the step's decode rows, and every prompt whose LAST
-        chunk rides it (a ``prefill_only`` one ends at its first
-        token)."""
-        at = {id(c.seq): i for i, c in enumerate(step.chunks)
-              if c.is_last and not c.seq.request.prefill_only}
-        at.update((id(s), len(step.chunks) + j)
-                  for j, s in enumerate(step.decode_seqs))
-        return at
-
-    def plan_mixed_behind(self, step: MixedStepBatch
-                          ) -> Optional[MixedStepBatch]:
-        """Plan the mixed step that follows the mixed ``step`` in its
-        admission run while the step's result is still on the device
-        (``chains_behind`` answered ``"mixed"``; the step is dispatched
-        and not yet accounted for): the plan ``_next_plan`` would return
-        once the step resolved.
-
-        Its chunks are the next chunks of the prompts still in prefill
-        (``_chunks_behind``), and they fill the step, as a run's every
-        step but the first does. Its decode rows are the step's decode
-        rows and every prompt whose last chunk rides the step
-        (``_rows_behind``), by arrival, each one token past what the
-        host holds: the engine feeds position ``len(seq)`` and reads the
-        token for it from row ``src_rows[j]`` of the step's packed
-        output. A row that token is sure to end (it spends the row's
-        budget or reaches the context ceiling) is left out, as it is
-        from the plan the host would make. A row it ends by a stop id,
-        which the host cannot know, RIDES with that one token: it writes
-        position ``len(seq)`` of a page, and its state slot, that it
-        alone owns until the host sees the step's result and frees them
-        - after this step was enqueued, so whoever takes them next
-        writes behind it - and ``_process`` drops what it samples (the
-        row is FINISHED by then).
-
-        Never admits and never preempts. None - with the refusal counted
-        and NOTHING changed, the loop then resolves the step and plans
-        from host state - where a row of the step is cancelled
-        (``rows``), the run does not go on after all (``run``: the queue
-        fell since the step was dispatched, what is left no longer fills
-        a step, or a prompt for the ring is next), no decode row is left
-        (``budget``: the host's plan would admit) or the pool lacks the
-        rows' next pages (``pages``). On success the bookkeeping stands
-        where ``schedule()`` would have left it."""
-        if any(s.cancelled for s in step.seqs):
-            self.record_chain_refusal("rows")
-            return None
-        chunks = self._chunks_behind(step) if self._queue_stands() else None
-        if chunks is None or not self._fills_a_step(chunks):
-            self.record_chain_refusal("run")
-            return None
-        at = self._rows_behind(step)
-        # (the order ``_grow_ready`` gives them)
-        rows = [s for s in sorted((s for s in self.active.values()
-                                   if id(s) in at), key=lambda s: s.arrival)
-                if not self._ends_at_next(s)]
-        if not rows:
-            self.record_chain_refusal("budget")
-            return None
-        need = [max(0, self._pages_needed(len(s) + 1) - len(s.page_ids))
-                for s in rows]
-        if sum(need) > self.alloc.num_free:
-            self.record_chain_refusal("pages")
-            return None
-        for s, n in zip(rows, need):
-            if n:
-                s.page_ids.extend(self.alloc.allocate(n))
-                s.pages_changed()
-        self._chain_run = 0
-        self._admit_stop = "partial"
-        self._prefer_prefill = False
-        self._steps_since_decode = 0
-        self.mixed_plans += 1
-        self._run_steps += 1
-        self.admission_run_steps += 1
-        self.chained_steps["mixed"] += 1
-        return MixedStepBatch(chunks=chunks, decode_seqs=rows,
-                              behind="mixed",
-                              src_rows=[at[id(s)] for s in rows])
-
-    def _ends_at_next(self, seq: Sequence) -> bool:
-        """Is the next token this row gets sure to be its last: it spends
-        the budget, or the row reaches the context ceiling (the rules of
-        ``_accept_token`` that need no look at the token)?"""
-        max_new = self._max_new(seq)
-        return ((max_new is not None and len(seq.generated) + 1 >= max_new)
-                or (self.max_context_hint is not None
-                    and len(seq) + 1 >= self.max_context_hint))
-
-    def plan_multistep_behind(self, step: MixedStepBatch
-                              ) -> Optional[MultiStepBatch]:
-        """Plan the fused block that follows the mixed ``step`` while the
-        step's result is still on the device (``chains_behind`` allowed
-        it, the step is dispatched and not yet accounted for).
-
-        Its rows are the rows the pure-decode plan would hold once the
-        step resolved, in that plan's order (by arrival): the step's
-        decode rows, and every prompt whose LAST chunk rides the step
-        (a prompt with an intermediate chunk stays out, as does a
-        ``prefill_only`` one, which ends at its first token). Each starts
-        one token past what the host holds (``len(seq) + 1``: the token
-        the step samples is counted in budgets, ``min_tokens`` gates,
-        widths and the pages grown up front, as ``plan_multistep_chained``
-        counts ``prev.width``), and ``src_rows`` maps it to its row of the
-        step's packed output, where the device reads that token and
-        decides whether the row still lives (``JaxEngine._handover_impl``).
-        A row the step's token ends rides the block dead from its start.
-        None where the block planner refuses (budget, pages: counted as
-        chain refusals); then nothing has changed and the loop resolves
-        the step and plans from host state. On success the scheduler
-        stands where ``schedule()`` would have left it after returning
-        the pure-decode plan: the admission run is over and counted."""
-        at = self._rows_behind(step)
-        # (the order ``_next_plan`` would give them)
-        rows = sorted((s for s in self.active.values() if id(s) in at),
-                      key=lambda s: s.arrival)
-        plan = self._plan_block(rows, [len(s) + 1 for s in rows],
-                                behind="mixed")
-        if plan is None:
-            return None
-        plan.src_rows = [at[id(s)] for s in rows]
-        self._prefer_prefill = True
-        self._steps_since_decode = 0
-        self._end_run()
-        return plan
+        return self._plan_block(batch.seqs)
 
     def _gen_budget(self, seq: Sequence) -> int:
         """Tokens a block-diffusion row may still emit (``max_tokens``
@@ -1622,13 +1596,12 @@ class Scheduler:
             rem = min(rem, self.max_context_hint - len(seq))
         return max(rem, 0)
 
-    def _plan_passes(self, seqs: List[Sequence],
-                     inflight: int) -> Optional[GenPassBatch]:
+    def _plan_passes(self, seqs: List[Sequence]) -> Optional[GenPassBatch]:
         """One fused dispatch of ``decode_multistep`` passes over every
-        row's current block (``GenPassBatch``). ``inflight`` > 0 plans a
-        CHAINED dispatch: the rows' block state is the previous
-        dispatch's device carry, and the host's view of each row lags by
-        ``inflight`` passes. Pages are grown for every block a row can
+        row's current block (``GenPassBatch``). Behind a dispatch in
+        flight it is CHAINED: the rows' block state is that dispatch's
+        device carry, and the host's view of each row lags by the passes
+        in flight (``InFlight.out``). Pages are grown for every block a row can
         reach by the end of this dispatch: a block takes at least two
         passes (one that reveals, one that commits), and no row goes
         past the block its budget ends in. A fresh plan preempts the
@@ -1637,7 +1610,8 @@ class Scheduler:
         breaks."""
         B = self.cfg.gen_block
         w = max(1, self.cfg.decode_multistep)
-        chained = inflight > 0
+        chained = self._flight is not None
+        inflight = self._flight.out if chained else 0
         rows: List[Sequence] = []
         for seq in (seqs if chained
                     else sorted(seqs, key=lambda s: s.arrival)):
@@ -1746,4 +1720,4 @@ class Scheduler:
 
 __all__ = ["Scheduler", "SchedulerConfig", "Sequence", "Phase",
            "PrefillChunk", "PrefillBatch", "DecodeBatch", "SpecDecodeBatch",
-           "MultiStepBatch", "MixedStepBatch"]
+           "MultiStepBatch", "MixedStepBatch", "InFlight", "CHAIN_KINDS"]

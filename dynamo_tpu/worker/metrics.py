@@ -318,9 +318,9 @@ class EngineDispatchCollector:
             "the device, by reason: 'rows' (a row was cancelled, or runs "
             "outside the step), 'pcarry' (a row's penalty window or "
             "guided automaton state is built on the host and would lack "
-            "the token in flight), 'run' (the run did not go on as read "
-            "ahead at the step's dispatch: the queue fell or what is left "
-            "of the prompts no longer fills a step), 'budget' / 'pages' "
+            "the token in flight), 'run' (the run goes on with a step "
+            "the device does not chain: a prompt for the ring is next), "
+            "'budget' / 'pages' "
             "(the planner refused with that token counted: it never "
             "preempts and never admits); the step and what follows it "
             "then run as they did before the chain existed",

@@ -478,7 +478,7 @@ class TestPipelinedDecode:
             results = await asyncio.gather(*[collect(eng, r) for r in reqs])
             toks = [[t for f in frames for t in f.token_ids]
                     for frames in results]
-            return toks, eng.chained_steps
+            return toks, eng.chained_decode_steps
         finally:
             await eng.stop()
 
@@ -492,7 +492,7 @@ class TestPipelinedDecode:
 
     async def test_chained_page_growth_across_boundary(self):
         # page_size=4: decode crosses page boundaries repeatedly while
-        # chained, exercising the +1 lookahead growth in plan_chained
+        # chained, exercising the +1 look-ahead growth of ``Scheduler.plan_behind``
         eng = tiny_engine(pipeline_decode=True, num_pages=32,
                           decode_multistep=1)
         try:
@@ -502,7 +502,7 @@ class TestPipelinedDecode:
             toks = [t for f in frames for t in f.token_ids]
             assert len(toks) == 21
             assert frames[-1].finish_reason == FinishReason.LENGTH
-            assert eng.chained_steps > 10
+            assert eng.chained_decode_steps > 10
         finally:
             await eng.stop()
 

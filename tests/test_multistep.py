@@ -388,7 +388,7 @@ class TestSchedulerWidth:
         # "a" ends inside the block in flight: dead row, no pages, and the
         # chain goes on at full width
         pages_a = list(a.page_ids)
-        nxt = sched.plan_multistep_chained(ms)
+        nxt = sched.plan_behind(ms)
         assert nxt is not None and nxt.width == 8 and nxt.chained
         assert nxt.budgets[0] == 0 and nxt.budgets[1] == 63 - 8
         assert a.page_ids == pages_a
@@ -399,12 +399,12 @@ class TestSchedulerWidth:
                 seq.tokens.append(7)
                 seq.generated.append(7)
         sched.finish(a)
-        after = sched.plan_multistep_chained(nxt)
+        after = sched.plan_behind(nxt)
         assert after is not None and after.seqs == nxt.seqs
         assert after.budgets[0] == 0
         # but not a row the host alone ended: the device has it alive
         sched.finish(b)
-        assert sched.plan_multistep_chained(after) is None
+        assert sched.plan_behind(after) is None
 
     def test_chain_breaks_when_half_the_rows_are_dead(self):
         sched, _ = self.make()
@@ -414,7 +414,7 @@ class TestSchedulerWidth:
             a.tokens.append(7)
             a.generated.append(7)
         sched.finish(a)
-        assert sched.plan_multistep_chained(ms) is None
+        assert sched.plan_behind(ms) is None
 
     def test_stop_string_lookback_caps_width(self):
         sched, _ = self.make()
@@ -779,7 +779,7 @@ def two(r0_kw=None, r1_prompt=11, samp=None):
 
 class TestChainBehindMixed:
     """A fused block chained behind the mixed step that ends an admission
-    run (``Scheduler.chains_behind`` / ``plan_multistep_behind``,
+    run (``Scheduler.chains_behind`` / ``plan_behind``,
     ``JaxEngine._handover_impl``): the step returns at its enqueue, the
     block takes its first tokens from the step's on-device output, and
     every request streams what the synchronous path streams."""
@@ -986,7 +986,7 @@ class TestChainBehindMixedPlan:
         b = step.chunks[0].seq
         assert sched.chains_behind(step)
         free = sched.alloc.num_free
-        plan = sched.plan_multistep_behind(step)
+        plan = sched.plan_behind(step)
         # by arrival, as the decode plan would hold them; the step's
         # packed rows are its chunks, then its decode rows
         assert plan.seqs == [a, b] and plan.src_rows == [1, 0]
@@ -1026,7 +1026,7 @@ class TestChainBehindMixedPlan:
                 held = sched.alloc.allocate(sched.alloc.num_free)
                 assert held
             before = [len(s.page_ids) for s in step.seqs]
-            assert sched.plan_multistep_behind(step) is None
+            assert sched.plan_behind(step) is None
             assert [len(s.page_ids) for s in step.seqs] == before
             # the run is still open: ``schedule()`` closes it
             assert sched._run_steps == 1
@@ -1153,7 +1153,7 @@ class FailsBehindAMixedStep(JaxEngine):
 
 class TestMixedBehindMixed:
     """Inside an admission run the next mixed step is planned and enqueued
-    while the one before it runs (``Scheduler.plan_mixed_behind``,
+    while the one before it runs (``Scheduler.plan_behind``,
     ``JaxEngine._fill_impl``): its decode rows' tokens are read from that
     step's output on the device, and every request streams what the
     synchronous path streams."""
@@ -1390,58 +1390,172 @@ class TestMixedBehindMixed:
 
 
 def _resolve(sched, plan, token=7):
-    """What the loop's ``_process`` does to the scheduler for a mixed or
-    decode plan: account for the step, append a token to every row that
-    sampled one, end a row whose budget or context that spends."""
+    """What the loop's ``_process`` / ``_process_multistep`` /
+    ``_process_passes`` do to the scheduler: account for the step, append
+    the tokens it sampled (no stop id among them: an end the host cannot
+    foresee is no part of a plan), end a row whose budget or context that
+    spends. A pass dispatch commits a block every two passes."""
+    from dynamo_tpu.engine.scheduler import GenPassBatch
+
+    def give(seq):
+        seq.tokens.append(token)
+        seq.generated.append(token)
+        if sched._out_of_budget(seq):
+            sched.finish(seq)
+
+    B = sched.cfg.gen_block
+    if isinstance(plan, GenPassBatch):
+        for seq in plan.seqs:
+            for _ in range(plan.width // 2):
+                if seq.phase is not Phase.RUNNING:
+                    break
+                for _ in range(len(seq) - seq.num_computed, B):
+                    give(seq)
+                    if seq.phase is not Phase.RUNNING:
+                        break
+                else:
+                    seq.num_computed += B
+                    sched._commit_full_pages(seq)
+        return
+    if isinstance(plan, MultiStepBatch):
+        took = [min(plan.width, n) if s.phase is Phase.RUNNING else 0
+                for s, n in zip(plan.seqs, plan.budgets)]
+        sched.on_multistep_done(plan, took)
+        for seq, n in zip(plan.seqs, took):
+            for _ in range(n):
+                give(seq)
+        sched.commit_block(plan)
+        return
     sched.on_step_done(plan)
-    rows = [c.seq for c in getattr(plan, "chunks", ()) if c.is_last]
+    rows = [c.seq for c in getattr(plan, "chunks", ())
+            if c.is_last and B == 1]
     rows += list(getattr(plan, "decode_seqs", None)
                  or (plan.seqs if isinstance(plan, DecodeBatch) else ()))
     for seq in rows:
-        if seq.phase is not Phase.RUNNING:
-            continue
-        seq.tokens.append(token)
-        seq.generated.append(token)
-        if (len(seq.generated) >= seq.request.stop_conditions.max_tokens
-                or len(seq) >= sched.max_context_hint):
-            sched.finish(seq)
+        if seq.phase is Phase.RUNNING:
+            give(seq)
 
 
 def _books(sched, which="all"):
-    """Everything ``plan_mixed_behind`` may touch; ``which="shared"``:
+    """Everything a plan behind a step may touch; ``which="shared"``:
     what two schedulers that made the same plans share (not which pages
     a row got - one of them freed a row's before it grew another's, the
-    other after - nor the counters of the chains themselves)."""
+    other after - nor the counters of the chains themselves). The
+    counters of refusals are no part of it: a refusal is counted."""
     seqs = list(sched.active.values()) + list(sched.waiting)
+    # (a chain of pass dispatches reserves further ahead with every link:
+    # ``GenPassBatch.inflight`` adds up along it, so its rows hold the
+    # pages the host's plan would give them and then some)
+    causal = sched.cfg.gen_block == 1
     shared = (sched._run_steps, sched.admission_run_steps, sched.mixed_plans,
               sched._prefer_prefill, sched._steps_since_decode,
-              sched._admit_stop, dict(sched.admission_runs),
-              sched.alloc.num_free,
-              {s.request.request_id: (len(s.page_ids), s.num_computed,
-                                      s.phase) for s in seqs},
+              dict(sched.admission_runs),
+              causal and sched.alloc.num_free, sorted(sched._free_slots),
+              {s.request.request_id: (causal and len(s.page_ids),
+                                      s.num_computed, s.phase)
+               for s in seqs},
               [s.request.request_id for s in sched.waiting])
     if which == "shared":
         return shared
+    # (``_admit_stop`` is an admission pass's own: written by each, read
+    # at once, and a plan behind a step makes no pass)
     return shared + (dict(sched.chained_steps), dict(sched.chained_blocks),
+                     sched._chain_run, sched._admit_stop,
                      {s.request.request_id: (list(s.page_ids),
                                              s.table_version)
                       for s in seqs})
 
 
 def _shape(plan):
-    """A mixed plan by what the engine makes of it: chunks, and decode
-    rows in order with the position each feeds and the pages it holds."""
+    """A plan by what the engine makes of it, read once the step in
+    front of it has resolved: a mixed step's chunks, and the rows that
+    decode, in order, each with the position it feeds and the pages it
+    holds; of a block also its width and each row's budget and gate.
+    Where a chained plan differs from the host's BY DESIGN, and nowhere
+    else, the difference is taken out here: a block behind a step keeps
+    a row the host has meanwhile seen end, dead from its start (budget
+    0; the host's plan does not hold it), and a chained pass dispatch
+    takes its rows' positions and budgets from the device's carry, not
+    from the lagging host state its plan records (and reserves pages
+    further ahead, ``_books``)."""
+    from dynamo_tpu.engine.scheduler import GenPassBatch, MixedStepBatch
+
+    def rid(s):
+        return s.request.request_id
+
+    if isinstance(plan, GenPassBatch):
+        return ("passes", plan.width,
+                [rid(s) for s in plan.seqs if s.phase is Phase.RUNNING])
+    if isinstance(plan, MultiStepBatch):
+        dead = [n for s, n in zip(plan.seqs, plan.budgets)
+                if s.phase is not Phase.RUNNING]
+        assert not any(dead) and (plan.chained or not dead)
+        return ("block", plan.width,
+                [(rid(s), sl, n, g, len(s.page_ids)) for s, sl, n, g in zip(
+                    plan.seqs, plan.start_lens, plan.budgets, plan.min_gates)
+                 if s.phase is Phase.RUNNING])
+    if isinstance(plan, MixedStepBatch):
+        return ([(rid(c.seq), c.start, c.length, c.is_last)
+                 for c in plan.chunks],
+                [(rid(s), len(s) - 1, len(s.page_ids))
+                 for s in plan.decode_seqs])
+    if isinstance(plan, DecodeBatch):
+        return ("decode", [(rid(s), len(s) - 1, len(s.page_ids))
+                           for s in plan.seqs])
+    return (type(plan).__name__,
+            [(rid(c.seq), c.start, c.length, c.is_last)
+             for c in getattr(plan, "chunks", ())])
+
+
+def _host_plan(sched):
+    """The host's own next dispatch, as the loop makes it: ``schedule()``,
+    a pure-decode plan upgraded where a block is to be had."""
+    plan = sched.schedule()
+    if isinstance(plan, DecodeBatch) and (sched.cfg.decode_multistep > 1
+                                          or sched.cfg.gen_block > 1):
+        ms = sched.plan_multistep(plan)
+        if ms is not None or sched.cfg.gen_block > 1:
+            plan = ms
+    return plan
+
+
+# (what is in flight, what is chained behind it) -> the configurations
+# that take that boundary, and the kinds of step the engine chains behind
+BOUNDARIES = {
+    # (a chain of decode steps breaks at every arrival and at every row
+    # that ends: five requests for six rows, none of one token)
+    ("decode", "decode"): (dict(decode_multistep=1, requests=5,
+                                budgets=(3, 5, 8, 13, 21)), ("decode",)),
+    # (and a chain of blocks: rows with several blocks to go)
+    ("block", "block"): (dict(requests=5, budgets=(8, 13, 21, 34, 55)),
+                         ("block",)),
+    # (a run goes on where prompts of several chunks stand in a queue)
+    ("mixed", "mixed"): (dict(prompts=(17, 30, 41, 50, 64)), ("mixed",)),
+    ("mixed", "block"): ({}, ("block", "mixed")),
+}
+FLAVOURS = {
+    "plain": {},
+    # a recurrent state beside the pages: a slot a row, no prefix cache
+    "recurrent": dict(state_slots=6),
+    # generation by diffusion over blocks: pass dispatches, waves of
+    # prefill steps (the legacy alternation), no mixed step
+    "blocks_of_4": dict(gen_block=4, mixed_batch=False),
+}
+ORACLE_CASES = [(b, "plain") for b in BOUNDARIES] + [
+    (("mixed", "mixed"), "recurrent"), (("mixed", "block"), "recurrent"),
+    (("block", "block"), "recurrent"), (("block", "block"), "blocks_of_4")]
+
+
+def _kind(plan):
     from dynamo_tpu.engine.scheduler import MixedStepBatch
-    if not isinstance(plan, MixedStepBatch):
-        return type(plan).__name__
-    return ([(c.seq.request.request_id, c.start, c.length, c.is_last)
-             for c in plan.chunks],
-            [(s.request.request_id, len(s) - 1, len(s.page_ids))
-             for s in plan.decode_seqs])
+    return ("block" if isinstance(plan, MultiStepBatch) else
+            "mixed" if isinstance(plan, MixedStepBatch) else
+            "decode" if isinstance(plan, DecodeBatch) else "")
 
 
-class TestMixedBehindMixedPlan:
-    """The scheduler's side, without an engine."""
+class TestPlanBehind:
+    """``Scheduler.plan_behind``, the scheduler's side of every chain,
+    without an engine."""
 
     def make(self, pages=129, **cfg):
         cfg.setdefault("decode_multistep", 4)
@@ -1451,62 +1565,94 @@ class TestMixedBehindMixedPlan:
         sched.max_context_hint = 96
         return sched
 
-    def queue(self, seed):
+    def queue(self, seed, requests=14, budgets=(1, 2, 3, 5, 8, 13),
+              prompts=(3, 5, 9, 17, 30, 41, 50), **cfg):
         import random
         rng = random.Random(seed)
-        sched = self.make(max_num_seqs=6, max_prefill_seqs=2)
-        for i in range(14):
-            n = rng.choice([3, 5, 9, 17, 30, 41, 50])
+        sched = self.make(max_num_seqs=6, max_prefill_seqs=2, **cfg)
+        for i in range(requests):
+            n = rng.choice(prompts)
             sched.add_request(make_req(
                 [rng.randrange(5, 200) for _ in range(n)], f"q{i}",
-                max_tokens=rng.choice([1, 2, 3, 5, 8, 13])))
+                max_tokens=rng.choice(budgets)))
         return sched
 
     @pytest.mark.parametrize("seed", range(8))
-    def test_the_plan_is_the_one_the_host_would_make(self, seed):
-        """On a seeded random queue, served once with every mixed step
+    @pytest.mark.parametrize(
+        "boundary,flavour", ORACLE_CASES,
+        ids=[f"{a}->{b}-{f}" for (a, b), f in ORACLE_CASES])
+    def test_the_plan_is_the_one_the_host_would_make(self, boundary,
+                                                     flavour, seed):
+        """On a seeded random queue, served once with every step
         resolved before the next is planned (B) and once with the next
-        planned while the step is in flight (A): ``plan_mixed_behind``
-        returns ``_next_plan``'s plan - chunks, row order, positions,
-        pages - and leaves the same books; a refusal leaves them
-        untouched and ``schedule()`` then returns that plan."""
-        from dynamo_tpu.engine.scheduler import MixedStepBatch
-        a, b = self.queue(seed), self.queue(seed)
-        chained = refused = 0
-        plan_a, plan_b = a.schedule(), b.schedule()
-        for _ in range(400):
+        planned while the step is in flight (A), at every boundary the
+        device chains across: ``plan_behind`` returns the plan
+        ``schedule()`` returns once the step has resolved - chunks, rows,
+        their order, positions, budgets, pages (``_shape``) - finds each
+        row's token where the step put it, and leaves the same books; a
+        refusal leaves them untouched, and ``schedule()`` then returns
+        that plan."""
+        cfg, kinds = BOUNDARIES[boundary]
+        cfg = {**cfg, **FLAVOURS[flavour]}
+        a, b = self.queue(seed, **cfg), self.queue(seed, **cfg)
+        took = {}
+        plan_a, plan_b = _host_plan(a), _host_plan(b)
+        for _ in range(600):
             if plan_a is None:
                 break
             # (the step in front of a chained plan has resolved by now:
-            # both sides hold the token it fed on)
+            # both sides hold the tokens it fed on)
+            if _shape(plan_a)[::2] == ("passes", []):
+                # by design: a pass dispatch chained from the lagging
+                # host state, whose rows have all ended on the device
+                # meanwhile, runs dead; the host's plan has none
+                assert plan_a.chained
+                _resolve(a, plan_a)
+                plan_a = a.plan_behind(plan_a, kinds) or _host_plan(a)
+                continue
             assert _shape(plan_a) == _shape(plan_b)
-            follows = (a.chains_behind(plan_a)
-                       if isinstance(plan_a, MixedStepBatch) else "")
             _resolve(b, plan_b)
-            plan_b = b.schedule()
-            if follows == "mixed":
-                before = _books(a)
-                nxt = a.plan_mixed_behind(plan_a)
-                if nxt is None:
-                    refused += 1
+            plan_b = _host_plan(b)
+            before = _books(a)
+            nxt = a.plan_behind(plan_a, kinds)
+            if nxt is None:
+                # (a block the pool was short for keeps what it took for
+                # the width it tried: the pages its rows need next)
+                if not a.multistep_fallbacks.get("pages"):
                     assert _books(a) == before
-                else:
-                    chained += 1
-                    assert nxt.behind == "mixed"
-                    at = {id(s): i for i, s in enumerate(plan_a.seqs)}
-                    assert nxt.src_rows == [at[id(s)]
-                                            for s in nxt.decode_seqs]
-                _resolve(a, plan_a)
-                plan_a = nxt if nxt is not None else a.schedule()
             else:
-                _resolve(a, plan_a)
-                plan_a = a.schedule()
+                at = (_kind(plan_a), _kind(nxt))
+                took[at] = took.get(at, 0) + 1
+                assert at[0] in kinds and at[1]
+                self.finds_its_tokens(plan_a, nxt)
+            _resolve(a, plan_a)
+            plan_a = nxt if nxt is not None else _host_plan(a)
             assert _books(a, "shared") == _books(b, "shared")
+            assert a.alloc.num_free <= b.alloc.num_free
         assert plan_a is None and plan_b is None
         assert not a.active and not a.waiting
-        assert chained >= 3
-        assert a.chained_steps == {"mixed": chained}
-        assert sum(a.chain_refusals.values()) == refused
+        assert took.get(boundary, 0) >= 2, took
+        assert a.chained_steps == {"mixed": took.get(("mixed", "mixed"), 0)}
+        assert a.chained_blocks["mixed"] == took.get(("mixed", "block"), 0)
+        if "mixed" not in kinds:
+            assert not any(a.chain_refusals.values())
+
+    @staticmethod
+    def finds_its_tokens(prev, nxt):
+        """Each row of ``nxt`` finds its newest token in ``prev``'s
+        output: behind a mixed step by ``src_rows`` (chunk rows, then
+        decode rows), behind a decode step or a block row for row."""
+        from dynamo_tpu.engine.scheduler import MixedStepBatch
+        rows = getattr(nxt, "decode_seqs", None) or nxt.seqs
+        if isinstance(prev, MixedStepBatch):
+            assert nxt.behind == "mixed"
+            at = {id(s): i for i, s in enumerate(prev.seqs)}
+            assert nxt.src_rows == [at[id(s)] for s in rows]
+        else:
+            assert rows == prev.seqs and not getattr(nxt, "src_rows", None)
+            assert getattr(nxt, "behind", "") == (
+                "block" if isinstance(prev, MultiStepBatch)
+                and not hasattr(prev, "tails") else "")
 
     def step_with(self, sched, a_kw=None, b_prompt=40, b_samp=None,
                   a_prompt=5):
@@ -1529,9 +1675,9 @@ class TestMixedBehindMixedPlan:
         sched = self.make()
         step = self.step_with(sched)
         a, b = step.decode_seqs[0], step.chunks[0].seq
-        assert sched.chains_behind(step) == "mixed"
+        assert sched.chains_behind(step)
         free, pages = sched.alloc.num_free, len(a.page_ids)
-        nxt = sched.plan_mixed_behind(step)
+        nxt = sched.plan_behind(step)
         assert [(c.seq, c.start, c.length, c.is_last)
                 for c in nxt.chunks] == [(b, 16, 16, False)]
         # a feeds the token in flight at position len(a) = 6: the second
@@ -1544,11 +1690,14 @@ class TestMixedBehindMixedPlan:
         assert sched.chained_steps == {"mixed": 1}
         # the step behind it: b's last chunk; then the run is over
         _resolve(sched, step)
-        assert sched.chains_behind(nxt) == "block"
+        block = sched.plan_behind(nxt)
+        # (b's last 8 tokens do not fill a step: b stays in prefill)
+        assert isinstance(block, MultiStepBatch) and block.behind == "mixed"
+        assert block.seqs == [a] and block.src_rows == [1]
+        assert sched._run_steps == 0
 
     @pytest.mark.parametrize("why", ["rows", "rows_later", "pcarry",
-                                     "pages", "budget", "queue_fell",
-                                     "part_filled", "off"])
+                                     "pages", "budget", "off"])
     def test_a_refusal_changes_nothing_and_is_counted(self, why):
         from dynamo_tpu.engine.scheduler import MixedStepBatch
         samp = (SamplingOptions(temperature=0.0, presence_penalty=0.5)
@@ -1557,32 +1706,28 @@ class TestMixedBehindMixedPlan:
                              else {}))
         step = self.step_with(
             sched, a_kw={"max_tokens": 2} if why == "budget" else None,
-            b_prompt=20 if why == "part_filled" else 40, b_samp=samp,
+            b_samp=samp,
             # (a row of 8 tokens feeds position 8 next: a third page)
             a_prompt=7 if why == "pages" else 5)
         a = step.decode_seqs[0]
         if why == "rows":
             a.cancelled = True
-        follows = sched.chains_behind(step)
-        if why in ("rows", "pcarry", "off"):
-            assert follows == ""
-        else:
-            # (a part-filled next step ends the run: the block follows;
-            # asked for all the same, the next step is refused)
-            assert follows == ("block" if why == "part_filled" else "mixed")
-            if why == "rows_later":
-                a.cancelled = True
-            elif why == "pages":
-                assert sched.alloc.allocate(sched.alloc.num_free)
-            elif why == "queue_fell":
-                sched.cancel("c")
-            before = _books(sched)
-            assert sched.plan_mixed_behind(step) is None
-            assert _books(sched) == before
-        want = {"rows_later": "rows", "queue_fell": "run",
-                "part_filled": "run", "off": None}.get(why, why)
-        assert sched.chain_refusals == {
-            r: int(r == want) for r in sched.chain_refusals}
+        # asked before the step is dispatched
+        assert sched.chains_behind(step) == (
+            why not in ("rows", "pcarry", "off"))
+        if why == "rows_later":
+            a.cancelled = True
+        elif why == "pages":
+            assert sched.alloc.allocate(sched.alloc.num_free)
+        want = {"rows_later": "rows", "off": None}.get(why, why)
+        counted = {r: int(r == want) for r in sched.chain_refusals}
+        if why in ("rows", "pcarry"):
+            assert sched.chain_refusals == counted
+            sched.chain_refusals[want] = 0
+        before = _books(sched)
+        assert sched.plan_behind(step) is None
+        assert sched.chain_refusals == counted
+        assert _books(sched) == before
         assert sched.chained_steps == {"mixed": 0}
         assert sched.multistep_fallbacks == {}
         if why not in ("pages", "rows", "rows_later"):
@@ -1592,6 +1737,28 @@ class TestMixedBehindMixedPlan:
             assert isinstance(nxt, (MixedStepBatch, DecodeBatch,
                                     PrefillBatch))
             assert not getattr(nxt, "behind", "")
+
+    @pytest.mark.parametrize("why", ["queue_fell", "part_filled"])
+    def test_a_run_that_does_not_go_on_ends_in_the_block(self, why):
+        """What follows a step is decided when it is planned, from what
+        the host holds then: where the queue fell while the step ran, or
+        what is left of the prompt does not fill a step, the host's next
+        plan is the pure-decode one, and the block is chained."""
+        sched = self.make()
+        step = self.step_with(sched,
+                              b_prompt=20 if why == "part_filled" else 40)
+        a = step.decode_seqs[0]
+        assert sched.chains_behind(step)
+        if why == "queue_fell":
+            sched.cancel("c")
+        plan = sched.plan_behind(step)
+        assert isinstance(plan, MultiStepBatch) and plan.behind == "mixed"
+        # b's prompt is still in prefill: the block holds a alone
+        assert plan.seqs == [a] and plan.src_rows == [1]
+        assert plan.start_lens == [len(a) + 1]
+        assert not any(sched.chain_refusals.values())
+        assert sched.chained_steps == {"mixed": 0}
+        assert sched._run_steps == 0 and sched._prefer_prefill
 
 
 class TestMockerBlockPath:
