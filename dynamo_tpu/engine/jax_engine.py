@@ -171,8 +171,9 @@ class JaxEngineConfig:
     mesh: Optional[object] = None
     sp_axis: str = "sp"
     ring_threshold: Optional[int] = None
-    # slots of the recurrent-state pool, for a family with linear-attention
-    # layers (``ModelConfig.state_layers``): a request owns one while it
+    # slots of the pool a family keeps beside its pages
+    # (``ModelConfig.slot_kind``: the recurrent state of linear-attention
+    # layers, the rings of window layers): a request owns one while it
     # is admitted, so fewer than ``max_num_seqs`` caps the rows. None =
     # ``max_num_seqs``; a family without such layers has no pool
     state_slots: Optional[int] = None
@@ -310,10 +311,10 @@ class JaxEngine(ScheduledEngineBase):
         self.mixed_batch = (bool(self.cfg.mixed_batch)
                             if self.cfg.mixed_batch is not None
                             else mixed_batch_default())
-        # a family with linear-attention layers: what only moves block
-        # chains is refused by name here, at start-up
+        # a family that keeps a slot a sequence beside its pages: what
+        # only moves block chains is refused by name here, at start-up
         self.state_slots = 0
-        if model_cfg.state_layers:
+        if model_cfg.slot_kind:
             for what, on in (
                     ("a device mesh (--tensor-parallel-size, "
                      "--data-parallel-size, --sequence-parallel-size)",
@@ -323,7 +324,7 @@ class JaxEngine(ScheduledEngineBase):
                      forward_fn is not None),
                     ("--quantize", bool(self.cfg.quantize)),
                     ("--speculative-num-tokens (a rejected draft would "
-                     "have to roll the state back)",
+                     "have to roll the slot back)",
                      bool(self.cfg.spec_tokens))):
                 if on:
                     model_cfg.paged_only(what)
@@ -348,7 +349,7 @@ class JaxEngine(ScheduledEngineBase):
                 int(self.cfg.decode_progress_every)
                 if self.cfg.decode_progress_every is not None
                 else decode_progress_default()),
-            state_slots=self.state_slots)
+            state_slots=self.state_slots, slot_kind=model_cfg.slot_kind)
         # fused-path gates for penalized/guided rows: the scheduler
         # narrows block widths by the penalty window's remaining capacity
         # and asks the engine whether a row's grammar lowered to a device
@@ -462,11 +463,14 @@ class JaxEngine(ScheduledEngineBase):
             self._attn_packed = self._per_shard(
                 ragged_mixed_attention_packed, forward_fn)
         if self.state_slots:
-            # two kinds of cache in one donated value: the paged pool of
-            # the full-attention layers and the linear layers' state pools
+            # several kinds of cache in one donated value: the paged pool
+            # of the full-attention layers and the pools whose slots the
+            # other layers' rows own (a window ring holds the most tokens
+            # a row brings in one step beside the window)
             self.pages = family.make_pages(
                 model_cfg, self.cfg.num_pages, self.cfg.page_size,
-                state_slots=self.state_slots)
+                state_slots=self.state_slots,
+                max_chunk=self.cfg.max_prefill_chunk)
         else:
             self.pages = llama.make_pages(model_cfg, self.cfg.num_pages,
                                           self.cfg.page_size)
@@ -676,19 +680,43 @@ class JaxEngine(ScheduledEngineBase):
 
     @property
     def kv_pool(self):
-        """The paged pool alone (of a family with a recurrent state,
-        ``pages`` holds the state pools too)."""
+        """The paged pool alone (of a family with a slot a sequence,
+        ``pages`` holds the other pools too)."""
         return self.pages["kv"] if self.state_slots else self.pages
+
+    @property
+    def page_pools(self) -> Tuple[str, ...]:
+        """The pools of ``pages`` that the page table addresses (axis 1:
+        the pages), of a family that keeps several."""
+        return tuple(k for k in ("kv", "index")
+                     if self.state_slots and k in self.pages)
 
     @property
     def cache_kinds(self) -> str:
         """The kinds of cache the engine keeps, for ``startup.engine``."""
         L, _n, _two, Hkv, _ps, Dh = self.kv_pool.shape
         kinds = f"paged[L={L},Hkv={Hkv},Dh={Dh}]"
-        if self.state_slots:
+        if self.model_cfg.state_layers:
             kinds += (f"+state[L={self.model_cfg.state_layers},"
                       f"S={self.state_slots},f32]")
+        if self.model_cfg.window_layers:
+            Li, _n, _ps, Di = self.pages["index"].shape
+            Lw, _s, Rp, _two, _one, ps, Dw = self.pages["win"].shape
+            kinds += (f"+index[L={Li},D={Di}]+window[L={Lw},"
+                      f"S={self.state_slots},R={Rp * ps},D={Dw}]")
         return kinds
+
+    @property
+    def cache_bytes(self) -> Dict[str, int]:
+        """Device bytes of the cache by kind
+        (``dynamo_worker_cache_bytes{kind}``)."""
+        kind = {"kv": "paged", "index": "index", "win": "window"}
+        out: Dict[str, int] = {}
+        pools = self.pages if self.state_slots else {"kv": self.pages}
+        for name, pool in pools.items():
+            k = kind.get(name, "state")
+            out[k] = out.get(k, 0) + int(pool.size) * pool.dtype.itemsize
+        return out
 
     def _table_row(self, table: np.ndarray, i: int, seq) -> None:
         """Row ``i`` of a step's page table: where ``seq``'s cache lives."""

@@ -98,6 +98,16 @@ class BlockState:
         self.pidx = 0
 
 
+def selected_keys(start: int, n: int, topk: int) -> int:
+    """Keys the queries at positions ``start .. start + n`` attend in a
+    layer that keeps the ``topk`` best of the ``p + 1`` a query at ``p``
+    sees (0 where no layer selects)."""
+    if not topk:
+        return 0
+    whole = max(0, min(start + n, topk) - start)   # queries that see <= topk
+    return whole * (2 * start + whole + 1) // 2 + (n - whole) * topk
+
+
 class ScheduledEngineBase(EngineBase):
     """Continuous batching over a PageAllocator; subclasses do the math."""
 
@@ -108,7 +118,8 @@ class ScheduledEngineBase(EngineBase):
                  spec_tokens: int = 0, spec_ngram_max: int = 4,
                  spec_ngram_min: int = 2, spec_chain_break: int = 8,
                  decode_multistep: int = 1, mixed_batch: bool = True,
-                 decode_progress_every: int = 2, state_slots: int = 0):
+                 decode_progress_every: int = 2, state_slots: int = 0,
+                 slot_kind: str = ""):
         if max_context % page_size:
             raise ValueError("max_context must be a multiple of page_size")
         self.max_context = max_context
@@ -122,8 +133,14 @@ class ScheduledEngineBase(EngineBase):
             spec_chain_break=spec_chain_break,
             decode_multistep=decode_multistep, mixed_batch=mixed_batch,
             decode_progress_every=decode_progress_every,
-            state_slots=state_slots))
+            state_slots=state_slots,
+            slot_kind=slot_kind or "recurrent_state"))
         self.scheduler.max_context_hint = max_context
+        # keys the full-attention layers' queries could see and, of a
+        # model that attends a learned selection, those they attended (one
+        # layer's; dynamo_worker_attn_{visible,selected}_keys_total)
+        self.attn_visible_keys = 0
+        self.attn_selected_keys = 0
         self._queues: Dict[str, asyncio.Queue] = {}
         self._work = asyncio.Event()
         self._loop_task: Optional[asyncio.Task] = None
@@ -305,23 +322,31 @@ class ScheduledEngineBase(EngineBase):
             tokens_real = rows * (k + 1)
         else:
             tokens_real = rows
-        state = (0, 0, 0, 0)
+        state = (0, 0, 0, 0, 0)
         if self.scheduler.cfg.state_slots:
-            # (rows whose state the dispatch read, tokens through the
+            # (rows whose slot the dispatch read, tokens through the
             # chunk form of the rule, row-steps through the one-token
-            # form, query-key pairs a full-attention layer scored)
+            # form, query-key pairs a full-attention layer scored, and
+            # the keys it attended where it attends a selection)
+            topk = getattr(getattr(self, "model_cfg", None),
+                           "index_topk", 0)
+            linear = self.scheduler.cfg.slot_kind == "recurrent_state"
             if kind in ("prefill", "mixed"):
                 several = [c.length for c in chunks if c.length > 1]
                 # (a chained step's decode rows: the token in flight too)
                 ahead = bool(getattr(plan, "behind", ""))
-                pairs = sum(c.length * (2 * c.start + c.length + 1) // 2
-                            for c in chunks) + sum(len(s) + ahead
-                                                   for s in dec)
-                state = (rows, sum(several), rows - len(several), pairs)
+                spans = [(c.start, c.length) for c in chunks] + [
+                    (len(s) + ahead - 1, 1) for s in dec]
+                gdn = (sum(several), rows - len(several))
             else:
                 w = max(1, width)
-                pairs = sum(len(s) for s in seqs) * w + rows * w * (w - 1) // 2
-                state = (rows, 0, rows * w, pairs)
+                spans = [(len(s) - 1, w) for s in seqs]
+                gdn = (0, rows * w)
+            state = (rows,) + (gdn if linear else (0, 0)) + (
+                sum(n * (2 * p + n + 1) // 2 for p, n in spans),
+                sum(selected_keys(p, n, topk) for p, n in spans))
+            self.attn_visible_keys += state[3]
+            self.attn_selected_keys += state[4]
         padded = self.last_padded
         if padded is not None:
             batch = padded[0]

@@ -158,9 +158,12 @@ def step_programs(engine, batch: int, chunk: int, width: int = 8,
                                     engine.params)
     pages = sds(_pool_shape(engine, num_pages), engine.kv_pool.dtype)
     if engine.state_slots:
-        # the state pools as the engine holds them, beside the paged pool
-        pages = {**jax.tree_util.tree_map(
-            lambda a: sds(a.shape, a.dtype), engine.pages), "kv": pages}
+        # the slot pools as the engine holds them, beside the paged pool
+        # (and what other pool the page table addresses, at its pages)
+        pages = {**{k: sds(a.shape[:1] + (_pool_shape(engine, num_pages)[1],)
+                           + a.shape[2:] if k in engine.page_pools
+                           else a.shape, a.dtype)
+                    for k, a in engine.pages.items()}, "kv": pages}
     i32, f32 = jnp.int32, jnp.float32
 
     def step_args(B, S, lead=None):

@@ -333,6 +333,9 @@ class SchedulerConfig:
     # because a prefix's pages without the state that matches them are a
     # wrong answer). 0: every other family, nothing changes
     state_slots: int = 0
+    # what the slots hold (``ModelConfig.slot_kind``): the reason the
+    # refused prefix lookups are counted under
+    slot_kind: str = "recurrent_state"
     max_queue: int = 4096
     # prompts longer than this (and with no resident prefix) prefill in ONE
     # sequence-parallel ring step instead of chunks; None disables (set by
@@ -512,7 +515,7 @@ class Scheduler:
         # made because a hit could not be used, by reason
         # (dynamo_worker_prefix_reuse_refused_total{reason})
         self._free_slots: List[int] = list(range(config.state_slots, 0, -1))
-        self.prefix_reuse_refused: Dict[str, int] = {"recurrent_state": 0}
+        self.prefix_reuse_refused: Dict[str, int] = {config.slot_kind: 0}
 
     def record_chain_refusal(self, reason: str, seqs=()) -> None:
         """Count one chain behind a mixed step that was not taken (what
@@ -645,7 +648,7 @@ class Scheduler:
             self._admit_stop = "pages"
             return None
         if recurrent:
-            self.prefix_reuse_refused["recurrent_state"] += 1
+            self.prefix_reuse_refused[self.cfg.slot_kind] += 1
             seq.state_slot = self._free_slots.pop()
         else:
             self.alloc.count_lookup(hits=full_cached_pages,

@@ -139,7 +139,8 @@ class StepRecord:
               "row_passes", "revealed", "commits", "handover_ms",
               "assemble_ms", "upload_ms",
               "enqueue_ms", "resume_ms", "fetch_resume_ms",
-              "state_rows", "gdn_tokens", "gdn_step_rows", "score_pairs")
+              "state_rows", "gdn_tokens", "gdn_step_rows", "score_pairs",
+              "selected_keys")
     # _enqueue: perf_counter at the start of the enqueue, kept until the
     # result arrives and device_ms can be taken; _experts: the dispatch's
     # expert-layer counts (MOE_COUNTS) while they are still device
@@ -208,6 +209,12 @@ class StepRecord:
         self.gdn_tokens = 0
         self.gdn_step_rows = 0
         self.score_pairs = 0
+        # a dispatch of a model whose full-attention layers attend a
+        # learned selection (``index_topk``; 0 elsewhere): the keys ONE
+        # such layer's queries attended - min(index_topk, p + 1) of the
+        # p + 1 a token at position p can see (``score_pairs``: what its
+        # indexer scored); ``state_rows`` are then the window slots read
+        self.selected_keys = 0
         # the dispatch phase by stage (``dispatch_ms`` less these five is
         # the ``wait`` for the result, of a synchronous kind), and how
         # long the fetched result waited for the loop's thread
@@ -457,7 +464,7 @@ class StepRecorder:
                chained_behind: str = "", enqueue: float = 0.0,
                experts: Any = None,
                decode_kernel_rows: int = 0,
-               state: tuple = (0, 0, 0, 0),
+               state: tuple = (0, 0, 0, 0, 0),
                phase: Optional[Phase] = None) -> StepRecord:
         """Stamp one dispatch; returns the live ring slot (later patched
         by note_ready/note_unpack/note_compile).
@@ -500,7 +507,7 @@ class StepRecorder:
             rec.moe_held_assignments = rec.moe_zero_assignments = 0
             rec.passes = rec.row_passes = rec.revealed = rec.commits = 0
             (rec.state_rows, rec.gdn_tokens, rec.gdn_step_rows,
-             rec.score_pairs) = state
+             rec.score_pairs, rec.selected_keys) = state
             rec._enqueue = enqueue
             rec._experts = experts
             rec.fetch_resume_ms = 0.0

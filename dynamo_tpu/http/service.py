@@ -157,7 +157,11 @@ class HttpService:
 
     async def start(self) -> "HttpService":
         self.metrics.stage.attach(self.tracer)
-        self._runner = web.AppRunner(self.app, access_log=None)
+        # a caller that hangs up cancels its handler at once, also while it
+        # waits for a first token (aiohttp's default since 3.7 lets the
+        # handler run on, and a queued prompt would be computed for nobody)
+        self._runner = web.AppRunner(self.app, access_log=None,
+                                     handler_cancellation=True)
         await self._runner.setup()
         site = web.TCPSite(self._runner, self.host, self.port)
         await site.start()

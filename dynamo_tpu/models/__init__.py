@@ -7,6 +7,9 @@ argument (``attn_impl``). ``get_family(cfg)`` maps a config to its
 implementation: configs with linear-attention layers
 (``full_attention_interval > 0``: Qwen3-Next) use ``models.qwen3_next``,
 whose ``make_pages`` returns the paged pool AND the recurrent-state pools;
+configs with window layers beside latent attention (``layer_types`` holding
+``sliding_attention``: dots3-note) use ``models.dots3``, whose
+``make_pages`` returns latent pages, index pages and window rings;
 double-layer configs (``attn_blocks_per_layer == 2``:
 LongCat-Flash) use ``models.longcat``, other MLA configs (``kv_lora_rank >
 0``) ``models.deepseek``,
@@ -27,6 +30,11 @@ def get_family(cfg: ModelConfig):
         # every ``full_attention_interval``-th layer
         from dynamo_tpu.models import qwen3_next
         return qwen3_next
+    if cfg.window_layers:
+        # latent attention of two geometries: full layers that attend a
+        # learned selection through an indexer, window layers over a ring
+        from dynamo_tpu.models import dots3
+        return dots3
     if cfg.attn_blocks_per_layer == 2:
         # LongCat-Flash: double layers of latent attention around a
         # shortcut-connected expert branch with zero-compute experts

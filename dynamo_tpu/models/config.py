@@ -38,6 +38,11 @@ def _topk_method(hf: Dict[str, Any], model_type: str) -> str:
     return method
 
 
+# the per-layer kinds ``layer_types`` may hold, and where each is built
+IMPLEMENTED_LAYER_KINDS = ("linear_attention", "full_attention",
+                           "sliding_attention")
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     vocab_size: int
@@ -153,6 +158,31 @@ class ModelConfig:
     # one always-on expert beside the routed ones, its output gated by a
     # sigmoid of the token (0 = none)
     shared_expert_intermediate_size: int = 0
+    # latent attention of two geometries in one model (models/dots3.py):
+    # ``layer_types[i]`` is ``"full_attention"`` (the geometry of the
+    # fields above; with ``index_topk`` an indexer of ``index_n_heads``
+    # heads of ``index_head_dim`` scores every visible token and the
+    # latent attention reads the best ``index_topk`` only, out of index
+    # pages beside the latent pages) or ``"sliding_attention"`` (the
+    # ``swa_*`` geometry over the last ``swa_window`` tokens, the query's
+    # own among them, kept in a ring a sequence whose size does not grow
+    # with its context). () = every layer the same: every other family
+    layer_types: tuple = ()
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    swa_window: int = 0
+    swa_num_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 0.0
+    # ``attention_gate_type`` / ``swa_attention_gate_type`` "headwise": a
+    # head's attention output is multiplied by a sigmoid of the token
+    attn_gate: bool = False
+    swa_attn_gate: bool = False
 
     @property
     def q_size(self) -> int:
@@ -171,31 +201,81 @@ class ModelConfig:
         return self.num_periods * max(self.full_attention_interval - 1, 0)
 
     @property
+    def window_layers(self) -> int:
+        """Layers that keep a ring of ``swa_window`` tokens a sequence
+        and no pages."""
+        return sum(k == "sliding_attention" for k in self.layer_types)
+
+    @property
+    def slot_kind(self) -> str:
+        """What a sequence keeps in a slot of fixed size beside its page
+        chain: ``"recurrent_state"`` (linear-attention layers),
+        ``"window_cache"`` (window layers), ``""`` for a family whose
+        cache is its pages."""
+        if self.state_layers:
+            return "recurrent_state"
+        return "window_cache" if self.window_layers else ""
+
+    @property
     def num_cache_layers(self) -> int:
         """Layers of the paged pool: the attention blocks that keep keys
         and values (the full-attention layers alone where the others are
-        linear)."""
-        return ((self.num_layers - self.state_layers)
+        linear or see a window)."""
+        return ((self.num_layers - self.state_layers - self.window_layers)
                 * self.attn_blocks_per_layer)
 
     @property
     def layer_kinds(self) -> tuple:
-        """``"linear_attention"`` / ``"full_attention"`` a layer."""
+        """``"linear_attention"`` / ``"sliding_attention"`` /
+        ``"full_attention"`` a layer."""
+        if self.layer_types:
+            return self.layer_types
         n = self.full_attention_interval
         return tuple("linear_attention" if n and (i + 1) % n
                      else "full_attention" for i in range(self.num_layers))
 
     def paged_only(self, what: str) -> None:
         """Raise, by the family's name, where ``what`` moves block chains
-        of the paged cache only: a request of a family with linear layers
-        is its pages AND its slot of the state pool, and a chain without
-        the matching state is a wrong answer, not a slow one."""
+        of the paged cache only: a request of a family with linear or
+        window layers is its pages AND its slot (``slot_kind``), and a
+        chain without the matching slot is a wrong answer, not a slow
+        one."""
         if self.state_layers:
             raise NotImplementedError(
                 f"model_type {self.model_type!r} keeps a recurrent state "
                 f"beside the paged cache ({self.state_layers} "
                 f"linear-attention layers): {what} moves block chains only "
                 "and cannot move a state yet")
+        if self.window_layers:
+            raise NotImplementedError(
+                f"model_type {self.model_type!r} keeps a window cache "
+                f"beside the paged cache ({self.window_layers} "
+                f"sliding-attention layers, a ring of {self.swa_window} "
+                f"tokens a sequence) and index pages: {what} moves block "
+                "chains of one pool only and cannot move either yet")
+
+    def window_cfg(self) -> "ModelConfig":
+        """The window layers' latent attention as a config of its own:
+        the ``swa_*`` geometry in the fields ``models/deepseek.py``'s
+        shared functions read (heads, ranks, head sizes, theta and the two
+        rescales)."""
+        import dataclasses
+        scaled = self.mla_q_scale != 1.0 or self.mla_kv_scale != 1.0
+        H = self.hidden_size
+        return dataclasses.replace(
+            self, num_heads=self.swa_num_heads,
+            q_lora_rank=self.swa_q_lora_rank,
+            kv_lora_rank=self.swa_kv_lora_rank,
+            head_dim=self.swa_kv_lora_rank,
+            qk_nope_head_dim=self.swa_qk_nope_head_dim,
+            qk_rope_head_dim=self.swa_qk_rope_head_dim,
+            v_head_dim=self.swa_v_head_dim,
+            rope_theta=self.swa_rope_theta,
+            mla_q_scale=((H / self.swa_q_lora_rank) ** 0.5
+                         if scaled else 1.0),
+            mla_kv_scale=((H / self.swa_kv_lora_rank) ** 0.5
+                          if scaled else 1.0),
+            attn_gate=self.swa_attn_gate)
 
     @property
     def linear_conv_dim(self) -> int:
@@ -253,12 +333,41 @@ class ModelConfig:
         if not 0 <= self.ep_rank < self.ep_size:
             raise ValueError(
                 f"ep_rank {self.ep_rank} outside ep_size {self.ep_size}")
+        if self.layer_types:
+            self.layer_pattern()
         n = self.full_attention_interval
         if n and (n < 2 or self.num_layers % n):
             raise ValueError(
                 f"full_attention_interval {n} does not cut "
                 f"{self.num_layers} layers into whole periods of at least "
                 "one linear and one full-attention layer")
+
+    def layer_pattern(self) -> tuple:
+        """``(G, P, tail)`` of a model with window layers: after the
+        ``first_k_dense_replace`` leading layers (full attention, a dense
+        FFN) come ``P`` periods of one full-attention layer and ``G``
+        window layers, then ``tail`` (0 or 1) full-attention layers -
+        what ``models/dots3.py`` scans over. Any other ``layer_types`` is
+        an error that names it."""
+        kinds, K = tuple(self.layer_types), self.first_k_dense_replace
+        bad = set(kinds) - set(IMPLEMENTED_LAYER_KINDS[1:])
+        rest = kinds[K:]
+        G = 0
+        while 1 + G < len(rest) and rest[1 + G] == "sliding_attention":
+            G += 1
+        period = ("full_attention",) + ("sliding_attention",) * G
+        P = len(rest) // len(period)
+        tail = len(rest) - P * len(period)
+        if (bad or len(kinds) != self.num_layers or not G or not P
+                or tail > 1 or any(k != "full_attention" for k in kinds[:K])
+                or rest != period * P + period[:tail]):
+            raise NotImplementedError(
+                f"layer_types {list(kinds)}: after first_k_dense_replace "
+                f"({K}) full-attention layers, whole periods of one "
+                "full_attention and some sliding_attention layers (and at "
+                "most one more full_attention layer) are implemented "
+                "(models/dots3.py)")
+        return G, P, tail
 
     @classmethod
     def from_hf(cls, hf: Dict[str, Any], dtype: str = "bfloat16") -> "ModelConfig":
@@ -269,14 +378,20 @@ class ModelConfig:
                 or "linear_attention" in kinds
                 or any(k.startswith("linear_") for k in hf)):
             return cls._from_qwen3_next(hf, dtype)
+        if ("sliding_attention" in kinds and hf.get("kv_lora_rank")) or any(
+                k.startswith(("swa_", "index_")) for k in hf):
+            return cls._from_dots3(hf, dtype)
         if kinds - {"full_attention"} and not str(
                 hf.get("model_type", "")).startswith("gemma"):
             # a per-layer kind this loader would silently drop: the file
             # describes another model than the one it would build
             raise NotImplementedError(
                 f"layer_types holds {sorted(kinds - {'full_attention'})}: "
-                "only full-attention layers (and the qwen3_next pattern of "
-                "linear_attention layers) are implemented")
+                "the layer kinds implemented are "
+                f"{list(IMPLEMENTED_LAYER_KINDS)} (linear_attention in the "
+                "pattern full_attention_interval gives, models/"
+                "qwen3_next.py; sliding_attention as latent attention "
+                "beside kv_lora_rank and the swa_* keys, models/dots3.py)")
         heads = hf["num_attention_heads"]
         mt = hf.get("model_type", "llama")
         num_experts = hf.get("num_local_experts", hf.get("num_experts", 0)) or 0
@@ -511,6 +626,105 @@ class ModelConfig:
                 hf.get("partial_rotary_factor") or 1.0),
             shared_expert_intermediate_size=int(
                 hf.get("shared_expert_intermediate_size") or 0),
+        )
+
+    @classmethod
+    def _from_dots3(cls, hf: Dict[str, Any], dtype: str) -> "ModelConfig":
+        """Latent attention of two geometries in one model, read off its
+        own keys (``layer_types`` holding ``sliding_attention`` beside
+        ``kv_lora_rank``, the ``swa_*`` and ``index_*`` keys), never off a
+        model_type. A file that has some of those keys and lacks one the
+        family needs, or asks for what the family does not implement, is
+        an error that names the key, never a model built without it.
+
+        As for LongCat, a directory written for one rank of an
+        expert-parallel deployment says so with ``ep_rank`` beside
+        ``ep_size``: ``n_routed_experts`` then counts the experts HELD
+        and the router's width is ``n_routed_experts * ep_size``."""
+        def no(key, why):
+            raise NotImplementedError(
+                f"{key} {hf.get(key)!r}: {why} (models/dots3.py)")
+        wanted = ("layer_types", "kv_lora_rank", "q_lora_rank",
+                  "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+                  "index_n_heads", "index_head_dim", "index_topk",
+                  "sliding_window_size", "swa_num_attention_heads",
+                  "swa_q_lora_rank", "swa_kv_lora_rank",
+                  "swa_qk_nope_head_dim", "swa_qk_rope_head_dim",
+                  "swa_v_head_dim", "swa_rope_theta", "n_routed_experts",
+                  "moe_intermediate_size", "num_experts_per_tok")
+        for key in wanted:
+            if not hf.get(key):
+                no(key, "the family of full layers with an indexer and "
+                        "window layers of their own geometry needs every "
+                        "one of " + ", ".join(wanted))
+        for key in ("attention_gate_type", "swa_attention_gate_type"):
+            if hf.get(key) not in (None, "none", "headwise"):
+                no(key, "only the headwise output gate is implemented")
+        if hf.get("rope_scaling"):
+            no("rope_scaling", "plain rotary positions only")
+        if (hf.get("hidden_act") or "silu") != "silu":
+            no("hidden_act", "SwiGLU experts")
+        if int(hf.get("moe_layer_freq") or 1) != 1:
+            no("moe_layer_freq", "every layer after the dense ones is "
+                                 "sparse")
+        if int(hf["index_head_dim"]) < int(hf["qk_rope_head_dim"]):
+            no("index_head_dim", "an index head holds the rotary "
+                                 "dimensions and more")
+        mt = hf.get("model_type", "dots3_note")
+        H = int(hf["hidden_size"])
+        ep_size = int(hf.get("ep_size") or 1) if "ep_rank" in hf else 1
+        q_rank, kv_rank = int(hf["q_lora_rank"]), int(hf["kv_lora_rank"])
+        rescale = bool(hf.get("apply_mla_qkv_lora_rescale"))
+        return cls(
+            vocab_size=hf["vocab_size"],
+            hidden_size=H,
+            intermediate_size=int(hf["intermediate_size"]),
+            num_layers=int(hf["num_hidden_layers"]),
+            num_heads=int(hf["num_attention_heads"]),
+            num_kv_heads=1,             # the latent page layout, as above
+            head_dim=kv_rank,
+            rope_theta=float(hf.get("rope_theta", 10000.0)),
+            rms_norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
+            max_position_embeddings=hf.get("max_position_embeddings", 8192),
+            tie_word_embeddings=bool(hf.get("tie_word_embeddings", False)),
+            attention_bias=bool(hf.get("attention_bias", False)),
+            model_type=mt,
+            dtype=dtype,
+            num_experts=int(hf["n_routed_experts"]) * ep_size,
+            num_experts_per_tok=int(hf["num_experts_per_tok"]),
+            moe_intermediate_size=int(hf["moe_intermediate_size"]),
+            norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+            q_lora_rank=q_rank,
+            kv_lora_rank=kv_rank,
+            qk_rope_head_dim=int(hf["qk_rope_head_dim"]),
+            qk_nope_head_dim=int(hf["qk_nope_head_dim"]),
+            v_head_dim=int(hf["v_head_dim"]),
+            n_shared_experts=int(hf.get("n_shared_experts") or 0),
+            first_k_dense_replace=int(hf.get("first_k_dense_replace") or 0),
+            routed_scaling_factor=float(
+                hf.get("routed_scaling_factor") or 1.0),
+            topk_method=_topk_method(hf, mt),
+            n_group=int(hf.get("n_group") or 1),
+            topk_group=int(hf.get("topk_group") or 1),
+            rope_interleave=bool(hf.get("rope_interleave", True)),
+            ep_size=ep_size,
+            ep_rank=int(hf.get("ep_rank") or 0),
+            mla_q_scale=(H / q_rank) ** 0.5 if rescale else 1.0,
+            mla_kv_scale=(H / kv_rank) ** 0.5 if rescale else 1.0,
+            layer_types=tuple(hf["layer_types"]),
+            index_n_heads=int(hf["index_n_heads"]),
+            index_head_dim=int(hf["index_head_dim"]),
+            index_topk=int(hf["index_topk"]),
+            swa_window=int(hf["sliding_window_size"]),
+            swa_num_heads=int(hf["swa_num_attention_heads"]),
+            swa_q_lora_rank=int(hf["swa_q_lora_rank"]),
+            swa_kv_lora_rank=int(hf["swa_kv_lora_rank"]),
+            swa_qk_nope_head_dim=int(hf["swa_qk_nope_head_dim"]),
+            swa_qk_rope_head_dim=int(hf["swa_qk_rope_head_dim"]),
+            swa_v_head_dim=int(hf["swa_v_head_dim"]),
+            swa_rope_theta=float(hf["swa_rope_theta"]),
+            attn_gate=hf.get("attention_gate_type") == "headwise",
+            swa_attn_gate=hf.get("swa_attention_gate_type") == "headwise",
         )
 
     @classmethod

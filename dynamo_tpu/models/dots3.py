@@ -1,0 +1,588 @@
+"""Latent attention of two geometries in one model (dots3-note family):
+full-attention layers that attend a LEARNED SELECTION of ``index_topk``
+tokens through an indexer with a cache of its own, window layers with a
+wider latent whose cache stops at the window, a headwise output gate on
+both, a sigmoid-routed sparse FFN held as one rank's share. Pure jax.
+
+With ``x = RMSNorm(h)`` a layer is ``h <- h + Attn(x)``, ``h <- h +
+FFN(RMSNorm(h))``.
+
+- **Full-attention layer** (``cfg``'s own geometry): ``models/deepseek``'s
+  latent attention in the absorbed form (``_mla_qkv``, ``_cache_rows``,
+  the latent page layout) with LongCat's two rescales (``cfg.mla_q_scale``
+  on the compressed query, ``cfg.mla_kv_scale`` on the normed latent: the
+  cache holds the SCALED latent and the unscaled rotary key). **Indexer**:
+  ``q_I = c_q W_Iqb`` (``index_n_heads`` heads of ``index_head_dim``,
+  rotary on the first ``qk_rope_head_dim``; ``c_q`` the normed, UNSCALED
+  compressed query), one key a token ``k_I = LayerNorm(x W_Ik)`` (rotary
+  on its first ``qk_rope_head_dim``; cached in index pages), head weights
+  ``w = x W_Iw``; ``I[t, s] = sum_j w[t, j] relu(q_I[t, j] . k_I[s])`` and
+  the ``min(index_topk, t + 1)`` best-scored tokens ``s <= t`` are the
+  ONLY keys the latent attention of token ``t`` reads, all heads alike
+  (``ops/sparse_latent.py``: the exact selection, the gather). The
+  indexer's constant factors (``index_n_heads ** -0.5 * index_head_dim **
+  -0.5``) change no selection and are left out.
+- **Window layer** (``cfg.window_cfg()``: other heads, head sizes, ranks,
+  theta, rescales): the same latent attention over ``{s : t - swa_window <
+  s <= t}``, no indexer, from a ring a sequence.
+- **Gate**, both kinds: ``gamma = sigmoid(x W_g)`` a head, multiplied onto
+  the head's attention output before the out-projection.
+- **FFN**: the first ``first_k_dense_replace`` layers a SwiGLU; every
+  other ``deepseek._gate`` (the ``noaux_tc`` sigmoid gate) over
+  ``moe.grouped_experts`` told which experts it holds
+  (``cfg.expert_offset``, ``cfg.experts_held``; a pick held elsewhere adds
+  nothing here), plus the shared expert, computed here.
+
+**Three kinds of cache** (``make_pages``, one donated tree through every
+step program): ``kv`` the latent pages of the full layers ``[Lf, N, 2, 1,
+ps, kv_lora_rank]``; ``index`` their index pages ``[Lf, N, ps,
+index_head_dim]``, addressed by the same page table; ``win`` the window
+layers' rings ``[Lw, slots + 1, R / ps, 2, 1, ps, swa_kv_lora_rank]``, a
+slot a sequence, each a ring of ``R`` positions in pages of the latent
+layout (``ops/sparse_latent.py``: token ``p`` at ``p mod R``), whose bytes
+do not grow with the context. A
+row's slot rides in the LAST column of its page-table row, as the
+recurrent state of ``models/qwen3_next.py`` does; slot 0 is no request's.
+
+Layers (``ModelConfig.layer_pattern``): the dense-FFN layers first, one
+``lax.scan`` over periods of one full layer and ``G`` window layers (an
+inner scan), then at most one more full layer. Weight layout:
+``params["dense_layers"]`` leaves ``[K, ...]``; ``params["layers"]["full"]``
+leaves ``[P + tail, ...]``; ``params["layers"]["win"]`` leaves ``[P, G,
+...]``. No checkpoint loader: the family serves seeded random weights
+until its published tensor names are in the repository.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Callable, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.models.deepseek import (
+    _attn_leaves,
+    _cache_rows,
+    _dense_mlp,
+    _gate,
+    _mla_qkv,
+    _mla_scale,
+    rope_interleaved,
+)
+from dynamo_tpu.models.llama import (
+    MOE_INIT_GAIN,
+    _logits,
+    _rms_norm,
+    packed_rows,
+    randn_stack,
+    write_rows,
+)
+from dynamo_tpu.ops import sparse_latent as sl
+from dynamo_tpu.ops.attention import write_slabs, write_slabs_packed
+from dynamo_tpu.ops.gdn import token_rows
+
+Params = Dict[str, Any]
+
+# Seeded weights (benchmarks/configs/dots3-note-prev.json ``assumed``, with
+# the measurements). At the matrices' common scale a head's attention
+# scores have a standard deviation of 0.30 before the softmax (measured at
+# the published width, both kinds): every key weighs about the same - the
+# largest probability of a query over 384 keys averages 0.006 - and
+# neither a selection of 2,048 of 16,000 keys nor a window moves the output
+# by more than bfloat16's noise. The queries' up-projection (``wq_b``) of
+# BOTH attention kinds is drawn at ``QUERY_GAIN`` times the common scale:
+# at 4 the scores' standard deviation is 1.18 and the eight largest
+# probabilities hold a fifth of the mass. Not more, because the SERVED
+# selection is itself bfloat16's: index keys cached in bfloat16 swap about
+# five of a query's 2,048 keys at the 2,048th place against the float32
+# reference (counted at these widths; float32 queries against the cached
+# keys still three), and the sharper the softmax the more often a swapped
+# key carries a head (none of 32,768 heads has a tenth of its mass on one
+# at 4, one in three hundred at 10) - on the chip
+# a clean run reads 0.12 nats at 4, 0.19 at 6, 0.67 at 8 and 1.6 at 10
+# (standard deviation 2.95) against the benchmark's fixed 0.3.
+QUERY_GAIN = 4.0
+# The indexer's projections stay at the common scale: relu(q_I . k_I) then
+# has a standard deviation of 2.7 and the score I one of 8.4 (same
+# measurement), so a selection is decided by a score's leading digits.
+INDEX_GAIN = 1.0
+LAYER_NORM_EPS = 1e-6
+
+
+def make_pages(cfg: ModelConfig, num_pages: int, page_size: int,
+               dtype=None, state_slots: int = 1,
+               max_chunk: int = 1) -> Dict[str, jnp.ndarray]:
+    """The family's cache (module docstring): latent pages and index pages
+    of the full layers, and the window layers' rings, ``state_slots``
+    requests' worth plus slot 0, each of ``ring_size(swa_window,
+    max_chunk)`` positions - ``max_chunk`` the most tokens a row brings in
+    one step."""
+    dtype = dtype or jnp.dtype(cfg.dtype)
+    Lf, Lw, n = cfg.num_cache_layers, cfg.window_layers, state_slots + 1
+    R = sl.ring_size(cfg.swa_window, max_chunk, page_size)
+    return {
+        "kv": jnp.zeros((Lf, num_pages, 2, 1, page_size, cfg.kv_lora_rank),
+                        dtype),
+        "index": jnp.zeros((Lf, num_pages, page_size, cfg.index_head_dim),
+                           dtype),
+        "win": jnp.zeros((Lw, n, R // page_size, 2, 1, page_size,
+                          cfg.swa_kv_lora_rank), dtype),
+    }
+
+
+def window_bytes_per_sequence(cfg: ModelConfig, max_chunk: int,
+                              page_size: int, dtype=None) -> int:
+    """Bytes one sequence holds for its window layers, whatever its
+    context: a ring a layer in the latent page layout (the rotary key
+    padded to the latent's width)."""
+    size = jnp.dtype(dtype or cfg.dtype).itemsize
+    return (cfg.window_layers
+            * sl.ring_size(cfg.swa_window, max_chunk, page_size)
+            * 2 * cfg.swa_kv_lora_rank * size)
+
+
+# ------------------------------------------------------------------ params
+
+def _attn_stack(cfg: ModelConfig, key, scale: float, lead: tuple,
+                indexer: bool) -> Dict[str, jnp.ndarray]:
+    """One attention kind's leaves ``lead + (...)``: ``deepseek``'s MLA
+    leaves at ``cfg``'s geometry, the gate, and the indexer's three
+    matrices and its key norm."""
+    n = 1
+    for d in lead:
+        n *= d
+    dtype = jnp.dtype(cfg.dtype)
+    H = cfg.hidden_size
+    k_mla, k_q, k_gate, k_iq, k_ik, k_iw = jax.random.split(key, 6)
+    leaves = _attn_leaves(cfg, k_mla, scale, n)
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    leaves["wq_b"] = randn_stack(k_q, n, (cfg.q_lora_rank,
+                                          cfg.num_heads * qk),
+                                 scale * QUERY_GAIN, dtype)
+    if cfg.attn_gate:
+        leaves["w_og"] = randn_stack(k_gate, n, (H, cfg.num_heads), scale,
+                                     dtype)
+    if indexer:
+        J, D = cfg.index_n_heads, cfg.index_head_dim
+        leaves["wi_qb"] = randn_stack(k_iq, n, (cfg.q_lora_rank, J * D),
+                                      scale * INDEX_GAIN, dtype)
+        leaves["wi_k"] = randn_stack(k_ik, n, (H, D), scale, dtype)
+        leaves["wi_w"] = randn_stack(k_iw, n, (H, J), scale, dtype)
+        leaves["i_norm_w"] = jnp.ones((n, D), dtype)
+        leaves["i_norm_b"] = jnp.zeros((n, D), dtype)
+    return {k: v.reshape(lead + v.shape[1:]) for k, v in leaves.items()}
+
+
+def _ffn_stack(cfg: ModelConfig, key, scale: float,
+               lead: tuple) -> Dict[str, jnp.ndarray]:
+    """The sparse FFN's leaves ``lead + (...)``: router (+ its float32
+    bias), shared expert, the experts this rank holds."""
+    n = 1
+    for d in lead:
+        n *= d
+    dtype = jnp.dtype(cfg.dtype)
+    H, E, Eh = cfg.hidden_size, cfg.num_experts, cfg.experts_held
+    Im = cfg.moe_intermediate_size
+    Is = Im * cfg.n_shared_experts
+    ks = iter(jax.random.split(key, 8))
+    leaves = {"w_router": randn_stack(next(ks), n, (H, E), scale, dtype)}
+    if cfg.topk_method == "noaux_tc":
+        leaves["router_bias"] = jnp.zeros((n, E), jnp.float32)
+    for leaf, shape in (("w_gate", (Eh, H, Im)), ("w_up", (Eh, H, Im)),
+                        ("w_down", (Eh, Im, H)), ("ws_gate", (H, Is)),
+                        ("ws_up", (H, Is)), ("ws_down", (Is, H))):
+        if Is or not leaf.startswith("ws_"):
+            leaves[leaf] = randn_stack(next(ks), n, shape, scale, dtype)
+    return {k: v.reshape(lead + v.shape[1:]) for k, v in leaves.items()}
+
+
+def init_params(cfg: ModelConfig, rng: jax.Array,
+                scale: Optional[float] = None) -> Params:
+    """Random init (tests/benchmarks; the benchmark's worker and its
+    reference child both call this, so both hold the same weights). Every
+    stack is drawn a layer at a time (``llama.randn_stack``) at ``scale``
+    (default ``MOE_INIT_GAIN / sqrt(hidden)``) but the two query-side
+    projections (``QUERY_GAIN``, ``INDEX_GAIN``). Only the experts this
+    rank holds are drawn."""
+    if scale is None:
+        scale = MOE_INIT_GAIN / cfg.hidden_size ** 0.5
+    dtype = jnp.dtype(cfg.dtype)
+    H, F = cfg.hidden_size, cfg.intermediate_size
+    K = cfg.first_k_dense_replace
+    G, P, tail = cfg.layer_pattern()
+    wcfg = cfg.window_cfg()
+    k_embed, k_head, k_dense, k_full, k_win = jax.random.split(rng, 5)
+    indexer = bool(cfg.index_topk)
+
+    params: Params = {
+        "embed": randn_stack(k_embed, 1, (cfg.vocab_size, H), scale,
+                             dtype)[0],
+        "final_norm": jnp.ones((H,), dtype),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = randn_stack(k_head, 1, (H, cfg.vocab_size),
+                                        scale, dtype)[0]
+    if K:
+        ka, kf = jax.random.split(k_dense)
+        dl = _attn_stack(cfg, ka, scale, (K,), indexer)
+        ks = jax.random.split(kf, 3)
+        for key, (leaf, shape) in zip(ks, (("w_gate", (H, F)),
+                                           ("w_up", (H, F)),
+                                           ("w_down", (F, H)))):
+            dl[leaf] = randn_stack(key, K, shape, scale, dtype)
+        params["dense_layers"] = dl
+    ka, kf = jax.random.split(k_full)
+    kwa, kwf = jax.random.split(k_win)
+    params["layers"] = {
+        "full": {**_attn_stack(cfg, ka, scale, (P + tail,), indexer),
+                 **_ffn_stack(cfg, kf, scale, (P + tail,))},
+        "win": {**_attn_stack(wcfg, kwa, scale, (P, G), False),
+                **_ffn_stack(cfg, kwf, scale, (P, G))},
+    }
+    return params
+
+
+# -------------------------------------------------------------- the layers
+
+class Step:
+    """What every layer of one step shares: the rows on the flat axis
+    (``ops/gdn.Rows``), each token's position, the step's form, and
+    whether the rows of several tokens run the masked form in the ragged
+    latent kernel (``kernel``: the chip) or everything the gathered form
+    (the CPU, the ``scan`` path, the oracle)."""
+
+    def __init__(self, tokens, positions, page_table, total_lens, new_lens,
+                 slots, starts, kernel: bool):
+        B, S = tokens.shape
+        self.packed = starts is not None
+        self.width = S
+        self.starts = starts
+        self.positions = positions
+        self.page_table = page_table
+        self.total_lens, self.new_lens = total_lens, new_lens
+        self.kernel = kernel
+        self.rows = token_rows(
+            B * S, starts if self.packed
+            else jnp.arange(B, dtype=jnp.int32) * S,
+            new_lens, total_lens, slots)
+        self.pos = sl.token_positions(self.rows, total_lens)
+        # the rows the masked form takes: those of several tokens, and in
+        # a [B, S > 1] step every row
+        least = 1 if self.packed else 0
+        self.q_lens = jnp.where(new_lens > least, new_lens, 0)
+        self.first = jnp.clip(self.rows.start, 0, B * S - 1)
+
+    @property
+    def walk(self) -> dict:
+        return dict(width=self.width, packed=self.packed)
+
+    @property
+    def one_token(self) -> bool:
+        """Whether the step can hold rows of one token."""
+        return self.width == 1 or self.packed
+
+    def write(self, pool, layer, k, v, table, ring: int = 0):
+        """``llama.write_rows`` of ``k`` / ``v [B, S, 1, D]`` into a pool
+        of the latent layout; ``ring``: positions wrap at ``ring`` (a
+        window ring's pages, which ``table`` names twice over)."""
+        positions, total = self.positions, self.total_lens
+        if ring:
+            begin = (total - self.new_lens) % ring
+            positions, total = positions % ring, begin + self.new_lens
+        return write_rows(pool, layer, k, v, table, positions, total,
+                          self.new_lens, self.starts)
+
+
+def _layer_norm(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    xf = x.astype(jnp.float32)
+    mu = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean((xf - mu) ** 2, axis=-1, keepdims=True)
+    return ((xf - mu) * jax.lax.rsqrt(var + LAYER_NORM_EPS)).astype(x.dtype) \
+        * w + b
+
+
+def _rope_head(cfg: ModelConfig, x: jnp.ndarray,
+               positions: jnp.ndarray) -> jnp.ndarray:
+    """Rotary on the first ``qk_rope_head_dim`` of the last axis, in the
+    family's convention (``cfg.rope_interleave``, ``cfg.rope_theta``)."""
+    dr = cfg.qk_rope_head_dim
+    return jnp.concatenate([
+        rope_interleaved(x[..., :dr], positions, cfg.rope_theta,
+                         interleaved=cfg.rope_interleave), x[..., dr:]], -1)
+
+
+def _finish(cfg: ModelConfig, lp, h, x, lat, w_uv) -> jnp.ndarray:
+    """``lat [N, nh, dkv]`` latent attention output -> ``W_UV`` expand ->
+    the headwise gate -> the out-projection residual."""
+    B, S, H = h.shape
+    out = jnp.einsum("nhk,hkd->nhd", lat, w_uv.astype(jnp.float32))
+    if cfg.attn_gate:
+        with jax.named_scope("gate"):
+            gamma = jax.nn.sigmoid(jnp.dot(
+                x.reshape(B * S, H), lp["w_og"],
+                preferred_element_type=jnp.float32))
+            out = out * gamma[:, :, None]
+    out = out.reshape(B, S, cfg.num_heads * cfg.v_head_dim).astype(h.dtype)
+    return h + out @ lp["wo"]
+
+
+def index_inputs(cfg: ModelConfig, lp, x: jnp.ndarray,
+                 positions: jnp.ndarray):
+    """The indexer's three projections of the normed stream ``x [B, S,
+    H]``: ``(q_I [N, J, D], k_I [N, D], w [N, J] float32)``."""
+    B, S, H = x.shape
+    J, D = cfg.index_n_heads, cfg.index_head_dim
+    eps = cfg.rms_norm_eps
+    c_q = _rms_norm(x @ lp["wq_a"], lp["q_a_norm"], eps)
+    q = _rope_head(cfg, (c_q @ lp["wi_qb"]).reshape(B, S, J, D), positions)
+    k = _rope_head(cfg, _layer_norm(x @ lp["wi_k"], lp["i_norm_w"],
+                                    lp["i_norm_b"]), positions)
+    w = jnp.dot(x, lp["wi_w"], preferred_element_type=jnp.float32)
+    return (q.reshape(B * S, J, D), k.reshape(B * S, D),
+            w.reshape(B * S, J))
+
+
+def _masked(st: Step, q_lat, q_pe, pool, layer, table, kv_lens, bias,
+            scale: float, name: str):
+    """The masked form (``ops/pallas/mla_ragged.py`` with a bias) for the
+    step's rows of several tokens: ``[N, nh, dkv]`` float32, zero in every
+    other slot."""
+    from dynamo_tpu.ops.pallas.mla_ragged import mla_ragged_attention_packed
+
+    return mla_ragged_attention_packed(
+        q_lat, q_pe, pool, layer, table, st.rows.start, st.q_lens, kv_lens,
+        scale, bias=bias, name=name)
+
+
+def full_block(cfg: ModelConfig, lp, h, cache, lidx, st: Step):
+    """``h + Attn(norm(h))`` of a full-attention layer against layer
+    ``lidx`` of the latent and the index pages. Returns ``(h, cache)``."""
+    B, S, H = h.shape
+    N, nh = B * S, cfg.num_heads
+    kv, index = cache["kv"], cache["index"]
+    with jax.named_scope("layer.attn_in"):
+        q_lat, q_pe, c_kv, k_pe, w_uv = _mla_qkv(cfg, lp, h, st.positions)
+        k_new, v_new = _cache_rows(cfg, c_kv, k_pe)
+        x = _rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
+        q_i, k_i, w_i = index_inputs(cfg, lp, x, st.positions)
+        q_lat, q_pe = q_lat.reshape(N, nh, -1), q_pe.reshape(N, nh, -1)
+    with jax.named_scope("layer.kv_write"):
+        kv = st.write(kv, lidx, k_new, v_new, st.page_table)
+        # the index pages as a pool of one array a token
+        Li, Np, ps, D = index.shape
+        k_i = k_i.reshape(B, S, 1, 1, D)
+        index = (write_slabs_packed(
+            index.reshape(Li, Np, 1, 1, ps, D), lidx, k_i[0], st.page_table,
+            st.starts, st.new_lens, st.total_lens) if st.packed
+            else write_slabs(
+                index.reshape(Li, Np, 1, 1, ps, D), lidx, k_i,
+                st.page_table, st.positions, st.new_lens)).reshape(
+                    index.shape)
+    with jax.named_scope("layer.attn"):
+        scale, scopes = _mla_scale(cfg), ("index/score", "index/topk")
+        q_i = q_i.astype(index.dtype)
+        if st.kernel:
+            one, bias = sl.select_split(
+                q_i, w_i, index, lidx, st.page_table, st.rows,
+                st.total_lens, cfg.index_topk, scopes=scopes, **st.walk)
+            with jax.named_scope("sparse"):
+                lat = jnp.zeros((N, nh, q_lat.shape[-1]), jnp.float32)
+                if bias is not None:
+                    lat = _masked(st, q_lat, q_pe, kv, lidx, st.page_table,
+                                  st.total_lens, bias, scale, "mla_selected")
+                if one is not None:
+                    (sel, live), to = one
+                    lat = sl.lay(lat, sl.sparse_attend(
+                        q_lat[st.first], q_pe[st.first], kv, lidx,
+                        st.page_table, sel, live, scale), to)
+        else:
+            sel, live = sl.select(
+                q_i, w_i, index, lidx, st.page_table, st.rows,
+                st.total_lens, cfg.index_topk, scopes=scopes, **st.walk)
+            with jax.named_scope("sparse"):
+                lat = sl.sparse_attend(
+                    q_lat, q_pe, kv, lidx, st.page_table[st.rows.row], sel,
+                    live & st.rows.valid[:, None], scale)
+        h = _finish(cfg, lp, h, x, lat, w_uv)
+    return h, {**cache, "kv": kv, "index": index}
+
+
+def window_block(wcfg: ModelConfig, lp, h, cache, widx, st: Step):
+    """``h + Attn(norm(h))`` of a window layer (``wcfg``: the window
+    geometry, ``ModelConfig.window_cfg``) against layer ``widx`` of the
+    rings. Returns ``(h, cache)``."""
+    B, S, H = h.shape
+    N, nh = B * S, wcfg.num_heads
+    win = cache["win"]
+    Lw, n_slots, Rp, _two, _one, ps, dkv = win.shape
+    ring = Rp * ps
+    with jax.named_scope("layer.attn_in"):
+        q_lat, q_pe, c_kv, k_pe, w_uv = _mla_qkv(wcfg, lp, h, st.positions)
+        k_new, v_new = _cache_rows(wcfg, c_kv, k_pe)
+        x = _rms_norm(h, lp["attn_norm"], wcfg.rms_norm_eps)
+        q_lat, q_pe = q_lat.reshape(N, nh, -1), q_pe.reshape(N, nh, -1)
+    with jax.named_scope("layer.kv_write"):
+        pool = st.write(win.reshape(Lw, n_slots * Rp, 2, 1, ps, dkv), widx,
+                        k_new, v_new,
+                        sl.ring_table(st.rows.slot, Rp, twice=True), ring)
+        win = pool.reshape(win.shape)
+    with jax.named_scope("layer.attn"):
+        with jax.named_scope("window"):
+            scale = _mla_scale(wcfg)
+            walk = dict(st.walk, only_one_token=st.kernel)
+            lat = jnp.zeros((N, nh, dkv), jnp.float32)
+            if st.one_token or not st.kernel:
+                lat = sl.window_attend(q_lat, q_pe, win, widx, st.rows,
+                                       st.total_lens, wcfg.swa_window,
+                                       scale, **walk)
+            if st.kernel and st.width > 1:
+                seen = sl.ring_seen(st.rows, st.pos, st.total_lens, ring,
+                                    wcfg.swa_window)
+                lat = lat + _masked(
+                    st, q_lat, q_pe, pool, widx,
+                    sl.ring_table(st.rows.slot, Rp),
+                    jnp.minimum(st.total_lens, ring),
+                    jnp.where(seen, 0.0, sl.NEG_INF), scale, "mla_window")
+        h = _finish(wcfg, lp, h, x, lat, w_uv)
+    return h, {**cache, "win": win}
+
+
+def sparse_block(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
+                 x: jnp.ndarray, **kw
+                 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """The layer's FFN as this rank computes it: its held experts' part of
+    the routed sum plus the shared expert. ``x [B, S, H]`` (normed) ->
+    ``([B, S, H], aux)``; ``kw`` goes to ``grouped_experts``."""
+    from dynamo_tpu.models.moe import grouped_experts
+
+    B, S, H = x.shape
+    xt = x.reshape(B * S, H)
+    with jax.named_scope("route"):
+        top_w, top_i = _gate(cfg, lp, x)
+    out, aux = grouped_experts(
+        xt, top_w.reshape(B * S, -1), top_i.reshape(B * S, -1),
+        lp["w_gate"], lp["w_up"], lp["w_down"],
+        first_expert=cfg.expert_offset, num_routed=cfg.num_experts, **kw)
+    if cfg.n_shared_experts:
+        with jax.named_scope("shared"):
+            out = out + jnp.dot(
+                jax.nn.silu(xt @ lp["ws_gate"]) * (xt @ lp["ws_up"]),
+                lp["ws_down"], preferred_element_type=jnp.float32)
+    return out.reshape(B, S, H).astype(x.dtype), aux
+
+
+def _ffn(cfg, lp, h, moe_kw):
+    with jax.named_scope("layer.moe"):
+        out, aux = sparse_block(
+            cfg, lp, _rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps),
+            **moe_kw)
+    return h + out, aux
+
+
+# ----------------------------------------------------------------- forward
+
+def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
+            positions: jnp.ndarray, pages: Dict[str, jnp.ndarray],
+            page_table: jnp.ndarray, total_lens: jnp.ndarray,
+            new_lens: jnp.ndarray,
+            attn_impl: Optional[Callable] = None, packed: bool = False
+            ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray], dict]:
+    """Scan forward (``llama.forward`` contract, the token-packed form
+    included, plus the ``aux`` third return of the MoE families).
+    ``pages`` is ``make_pages``'s tree; ``page_table [B, P + 1]`` carries
+    each row's window slot in its last column. A passed ``attn_impl`` is
+    never called: its ``pallas_paged_kernel`` marker opts the family into
+    the masked form of both attention kinds for the rows of several
+    tokens (``ops/pallas/mla_ragged.py`` with a bias: ``mla_selected``,
+    ``mla_window`` in a device trace) and into ``moe_grouped``. No ``logits_window``: a verify window or a scoring
+    pass would have to take back what a rejected token wrote to a ring,
+    so the engine offers neither."""
+    from dynamo_tpu.models.moe import (grouped_on_chip, split_experts,
+                                       sum_aux, token_slots)
+
+    if cfg.moe_backend != "grouped":
+        raise NotImplementedError(
+            f"moe_backend {cfg.moe_backend!r}: this family's sparse block "
+            "(held range, shared expert) runs the grouped layer only")
+    from dynamo_tpu.ops.pallas.mla_decode import supports as mla_supports
+
+    # (a slot past the pool's is held to its last: a write to no slot would
+    # be dropped in silence)
+    slots = jnp.minimum(page_table[:, -1], pages["win"].shape[1] - 1)
+    page_table = page_table[:, :-1]
+    ps = pages["kv"].shape[-2]
+    kernel = (getattr(attn_impl, "pallas_paged_kernel", False)
+              and mla_supports(cfg.kv_lora_rank, ps)
+              and mla_supports(cfg.swa_kv_lora_rank, ps))
+    st = Step(tokens, positions, page_table, total_lens, new_lens, slots,
+              packed_rows(packed, new_lens), kernel)
+    wcfg = cfg.window_cfg()
+    K = cfg.first_k_dense_replace
+    G, P, tail = cfg.layer_pattern()
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens]
+    moe_kw = dict(valid=token_slots(tokens, new_lens, packed),
+                  use_pallas=grouped_on_chip(attn_impl))
+    lf, lw = params["layers"]["full"], params["layers"]["win"]
+    full_scanned, full_experts = split_experts(cfg, lf)
+    win_scanned, win_experts = split_experts(cfg, lw)
+    # the window layers' experts as ONE stack over periods and places, so
+    # the grouped layer indexes it by the layer and no slice is made
+    win_experts = {k: v.reshape((-1,) + v.shape[2:])
+                   for k, v in win_experts.items()}
+
+    def dense(carry, xs):
+        h, cache = carry
+        lp, lidx = xs
+        h, cache = full_block(cfg, lp, h, cache, lidx, st)
+        with jax.named_scope("layer.ffn"):
+            h = h + _dense_mlp(lp, _rms_norm(h, lp["mlp_norm"],
+                                             cfg.rms_norm_eps))
+        return (h, cache), None
+
+    def full(h, cache, fp, p):
+        h, cache = full_block(cfg, fp, h, cache, K + p, st)
+        h, aux = _ffn(cfg, {**fp, **full_experts}, h, dict(moe_kw, layer=p))
+        return (h, cache), aux
+
+    def period(carry, xs):
+        h, cache = carry
+        fp, wp, p = xs
+        (h, cache), aux_f = full(h, cache, fp, p)
+
+        def window(carry, xs):
+            h, cache = carry
+            lp, j = xs
+            widx = p * G + j
+            h, cache = window_block(wcfg, lp, h, cache, widx, st)
+            h, aux = _ffn(cfg, {**lp, **win_experts}, h,
+                          dict(moe_kw, layer=widx))
+            return (h, cache), aux
+
+        (h, cache), aux_w = jax.lax.scan(window, (h, cache),
+                                         (wp, jnp.arange(G)))
+        return (h, cache), {k: aux_f[k] + jnp.sum(aux_w[k]) for k in aux_f}
+
+    if K:
+        (h, pages), _ = jax.lax.scan(dense, (h, pages),
+                                     (params["dense_layers"], jnp.arange(K)))
+    head = jax.tree_util.tree_map(lambda v: v[:P], full_scanned)
+    (h, pages), aux = jax.lax.scan(period, (h, pages),
+                                   (head, win_scanned, jnp.arange(P)))
+    aux = sum_aux(aux)
+    for t in range(tail):
+        last = jax.tree_util.tree_map(lambda v: v[P + t], full_scanned)
+        (h, pages), aux_t = full(h, pages, last, P + t)
+        aux = {k: aux[k] + aux_t[k].astype(jnp.int32) for k in aux}
+    with jax.named_scope("logits"):
+        logits = _logits(cfg, params, h, new_lens, starts=st.starts)
+    return logits, pages, aux
+
+
+forward.supports_packed = True
+
+
+__all__ = ["init_params", "forward", "make_pages", "sparse_block",
+           "index_inputs", "full_block", "window_block",
+           "window_bytes_per_sequence"]

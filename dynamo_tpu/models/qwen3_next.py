@@ -98,10 +98,13 @@ def zc_norm(x: jnp.ndarray, w: jnp.ndarray, eps: float) -> jnp.ndarray:
 
 
 def make_pages(cfg: ModelConfig, num_pages: int, page_size: int,
-               dtype=None, state_slots: int = 1) -> Dict[str, jnp.ndarray]:
+               dtype=None, state_slots: int = 1,
+               max_chunk: int = 1) -> Dict[str, jnp.ndarray]:
     """The family's cache: the paged pool of its full-attention layers
     (``llama.make_pages``'s layout) and the two pools a linear layer's
-    rows carry, ``state_slots`` requests' worth plus slot 0."""
+    rows carry, ``state_slots`` requests' worth plus slot 0 (a state's
+    size does not depend on ``max_chunk``, the most tokens a row brings
+    in one step)."""
     dtype = dtype or jnp.dtype(cfg.dtype)
     Lg, n = cfg.state_layers, state_slots + 1
     return {

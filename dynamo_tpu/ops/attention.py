@@ -54,7 +54,17 @@ def _write_pages(pool: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
                  v_new: jnp.ndarray, page_table: jnp.ndarray,
                  positions: jnp.ndarray, new_lens: jnp.ndarray) -> jnp.ndarray:
     """The cache write behind ``write_kv`` (``layer_idx`` a scalar, pool
-    ``[L, N, 2, Hkv, ps, Dh]``), a page at a time: gather the pages each
+    ``[L, N, 2, Hkv, ps, Dh]``): ``write_slabs`` of keys and values."""
+    return write_slabs(pool, layer_idx, jnp.stack([k_new, v_new], axis=2),
+                       page_table, positions, new_lens)
+
+
+def write_slabs(pool: jnp.ndarray, layer_idx, new: jnp.ndarray,
+                page_table: jnp.ndarray, positions: jnp.ndarray,
+                new_lens: jnp.ndarray) -> jnp.ndarray:
+    """``new [B, S, A, Hkv, Dh]`` into a pool ``[L, N, A, Hkv, ps, Dh]``
+    (``A`` = 2, keys and values, for the attention caches; 1 for a pool of
+    one array a token), a page at a time: gather the pages each
     row's new tokens fall in, lay the tokens over them, scatter the pages
     back.
 
@@ -64,7 +74,7 @@ def _write_pages(pool: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
     in at most ``J`` pages in table order. Why pages and no smaller
     window: the module docstring.
     """
-    Hkv, page_size, Dh = pool.shape[-3:]
+    A, Hkv, page_size, Dh = pool.shape[-4:]
     B, S = positions.shape
     J = (S + page_size - 2) // page_size + 1
     start = positions[:, 0]
@@ -79,11 +89,11 @@ def _write_pages(pool: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
                      jnp.take_along_axis(page_table, logical, axis=1), 0)
     at = pool.at[layer_idx, phys]
     # the new tokens shifted to their slots, token-major, then page-major
-    new = jnp.stack([k_new, v_new], axis=2).astype(pool.dtype)
+    new = new.astype(pool.dtype)
     if S == 1:
         # one token: whichever slot the mask below picks holds it (XLA runs
         # the shift as a loop over rows, 57 us a decode layer on a v5e)
-        laid = jnp.broadcast_to(new, (B, page_size, 2, Hkv, Dh))
+        laid = jnp.broadcast_to(new, (B, page_size, A, Hkv, Dh))
     elif S <= SELECT_SHIFT_MAX:
         # a few tokens (a pass over a block of diffusion generation, a
         # verify window): each laid over its slot by a select. The shift
@@ -92,7 +102,7 @@ def _write_pages(pool: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
         # operations in 6 s (PERF.md section 6, PR 37)
         tok_at = (jnp.arange(J * page_size, dtype=start.dtype)[None, :]
                   - off[:, None])                  # the token at a slot
-        laid = jnp.zeros((B, J * page_size, 2, Hkv, Dh), pool.dtype)
+        laid = jnp.zeros((B, J * page_size, A, Hkv, Dh), pool.dtype)
         for s in range(S):
             laid = jnp.where((tok_at == s)[:, :, None, None, None],
                              new[:, s][:, None], laid)
@@ -100,10 +110,10 @@ def _write_pages(pool: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
         laid = jax.vmap(
             lambda buf, row, o: jax.lax.dynamic_update_slice_in_dim(
                 buf, row, o, axis=0))(
-            jnp.zeros((B, J * page_size, 2, Hkv, Dh), pool.dtype), new, off)
+            jnp.zeros((B, J * page_size, A, Hkv, Dh), pool.dtype), new, off)
     t = jnp.arange(J * page_size, dtype=start.dtype)[None, :]
     real = (t >= off[:, None]) & (t < (off + new_lens)[:, None])
-    return _commit_pages(at, laid.reshape(B, J, page_size, 2, Hkv, Dh),
+    return _commit_pages(at, laid.reshape(B, J, page_size, A, Hkv, Dh),
                          real.reshape(B, J, page_size))
 
 
@@ -123,8 +133,19 @@ def write_kv_packed(pool: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
                     v_new: jnp.ndarray, page_table: jnp.ndarray,
                     starts: jnp.ndarray, new_lens: jnp.ndarray,
                     total_lens: jnp.ndarray) -> jnp.ndarray:
-    """``_write_pages`` for a TOKEN-PACKED step into the stacked pool: ``k_new``/``v_new``
-    ``[T, Hkv, Dh]`` hold every row's new tokens back to back, row ``r``
+    """``_write_pages`` for a TOKEN-PACKED step into the stacked pool
+    (``write_slabs_packed`` of keys and values)."""
+    return write_slabs_packed(pool, layer_idx,
+                              jnp.stack([k_new, v_new], axis=1), page_table,
+                              starts, new_lens, total_lens)
+
+
+def write_slabs_packed(pool: jnp.ndarray, layer_idx, new: jnp.ndarray,
+                       page_table: jnp.ndarray, starts: jnp.ndarray,
+                       new_lens: jnp.ndarray,
+                       total_lens: jnp.ndarray) -> jnp.ndarray:
+    """``write_slabs`` for a TOKEN-PACKED step: ``new [T, A, Hkv, Dh]``
+    holds every row's new tokens back to back, row ``r``
     at slots ``starts[r] .. starts[r] + new_lens[r]`` and at positions
     ``total_lens[r] - new_lens[r] ..``. Same pages, same select, same
     scatter; what differs is how the slabs are found. The rows' live pages
@@ -132,8 +153,8 @@ def write_kv_packed(pool: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
     of ``n`` tokens at any offset spans at most ``(n - 1) // ps + 2``), and
     slab ``m`` takes the ``ps`` packed tokens from ``starts[r] + j * ps -
     off[r]`` on: one slice a page, no per-row padded buffer."""
-    Hkv, page_size, Dh = pool.shape[-3:]
-    T = k_new.shape[0]
+    page_size = pool.shape[-2]
+    T = new.shape[0]
     R = page_table.shape[0]
     M = T // page_size + 2 * R
     begin = total_lens - new_lens                   # first new position [R]
@@ -151,7 +172,7 @@ def write_kv_packed(pool: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
     at = pool.at[layer_idx, phys]
     # the slab's tokens: slot s of slab m is token j*ps + s - off of the row
     tok0 = j * page_size - off[row]                               # [M]
-    new = jnp.stack([k_new, v_new], axis=1).astype(pool.dtype)
+    new = new.astype(pool.dtype)
     new = jnp.pad(new, ((page_size, page_size), (0, 0), (0, 0), (0, 0)))
     laid = jax.vmap(lambda s: jax.lax.dynamic_slice_in_dim(
         new, s, page_size, axis=0))(page_size + starts[row] + tok0)

@@ -25,6 +25,16 @@ The shape is ``ops/pallas/ragged.py``'s, the mathematics
   (``mla_prefill._query_block``: the f32 accumulator ``[nh*SB, dkv]`` is
   the large buffer). No window, softcap or visibility block: no MLA family
   has them.
+- **With a bias** (``bias [T, S]`` float32: what ``models/dots3.py``'s two
+  attention kinds hand over) the kernel knows no positions: every slot
+  attends EVERY key of its row's ``kv_lens`` and the bias, added to the
+  scores of all heads alike, says which it sees (0) and which not
+  (``NEG_INF``) - a learned selection of the context, or the entries of a
+  window ring in whatever order the ring holds them. The bias travels as
+  ``[chunks, T, span]`` so that a chunk's block is one leading index, and
+  a chunk is 32 pages where the unbiased kernel's is 8
+  (``BIASED_PAGES_PER_CHUNK``: 21.3 -> 10.8 ms a layer for 512 queries of
+  128 heads over 8,192 keys on a v5e).
 
 The pure-JAX reference over the same layout, and the CPU-test oracle, is
 ``models.deepseek.mla_ragged_attention``; CPU tests of this kernel run in
@@ -46,12 +56,30 @@ from dynamo_tpu.ops.pallas.mla_prefill import PAGES_PER_CHUNK, _query_block
 
 NEG_INF = -1e30
 
+# the masked form (``bias``): pages a chunk, query rows a block and the
+# scoped-VMEM limit the call asks for. A chunk of 32 pages (512 keys where
+# the unbiased kernel streams 128) runs the rescale of the accumulator
+# ``[nh*SB, dkv]`` and of the running max and sum once for four times the
+# keys: at 128 keys those were more vector work than the scores' softmax
+# itself and the MXU stood at 30 % of its peak. What the wider chunk
+# needs of VMEM is past the 16 MiB the compiler scopes by default.
+BIASED_PAGES_PER_CHUNK = 32
+BIASED_M_ROWS = 1024
+BIASED_VMEM_STACK = 48 * 2**20
+BIASED_VMEM_LIMIT = 96 * 2**20
+
 
 def _mla_ragged_kernel(q2_ref, kv_hbm, layer_ref, table_ref, rows_ref,
-                       qstart_ref, qlen_ref, lens_ref, out_ref,
-                       buf, sem, m_ref, l_ref, acc_ref, *,
-                       page_size: int, chunk: int, q_block: int):
+                       qstart_ref, qlen_ref, lens_ref, *rest,
+                       page_size: int, chunk: int, q_block: int,
+                       biased: bool = False, rope_dim: int = 0):
     """One program per block of ``SB`` packed slots.
+
+    ``rest``: ``[bias_ref]`` (``biased``: ``[chunks, SB, span]`` float32,
+    added to every head's scores; the causal mask is then the bias's),
+    then the output and the scratch buffers below. ``rope_dim`` (a
+    multiple of 128, or 0: the whole width): the columns of the rotary
+    slot that are not padding.
 
     q2_ref:  [2, SB, nh, dkv] — slot 0 = absorbed latent queries, slot 1 =
              roped queries zero-padded to dkv; pre-scaled.
@@ -67,6 +95,8 @@ def _mla_ragged_kernel(q2_ref, kv_hbm, layer_ref, table_ref, rows_ref,
     A row without slots in the block runs its chunk loop zero times, so no
     page DMA is armed and no matmul runs (the skip rides the loop bounds:
     Mosaic cannot lower the layout transposes inside a ``pl.when``)."""
+    bias_ref = rest[0] if biased else None
+    out_ref, buf, sem, m_ref, l_ref, acc_ref = rest[biased:]
     i = pl.program_id(0)
     layer = layer_ref[0]
     SB = q_block
@@ -96,7 +126,7 @@ def _mla_ragged_kernel(q2_ref, kv_hbm, layer_ref, table_ref, rows_ref,
         pos0 = ctx - q_len - q_start
         # kv the row's slots of this block can see: the causal bound,
         # inside the live context by construction (hi <= q_start + q_len)
-        visible = pos0 + hi
+        visible = ctx if biased else pos0 + hi
         num_chunks = jnp.maximum(jax.lax.div(visible + span - 1, span), 1)
         n_end = jnp.where(active, num_chunks, 0)
 
@@ -138,13 +168,28 @@ def _mla_ragged_kernel(q2_ref, kv_hbm, layer_ref, table_ref, rows_ref,
             wait_chunk(slot, c)
             kv = buf[slot, :, 0]                           # [2, span, dkv]
 
-            s2 = jax.lax.dot_general(
-                q2, kv, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)        # [2, nh*SB, span]
-            s3 = (s2[0] + s2[1]).reshape(nh, SB, span)
+            if rope_dim:
+                # the rotary slot's first ``rope_dim`` columns hold all
+                # of it: two dots, the second a quarter as deep or less
+                dims = (((1,), (1,)), ((), ()))
+                s3 = (jax.lax.dot_general(
+                    q2[0], kv[0], dims, preferred_element_type=jnp.float32)
+                    + jax.lax.dot_general(
+                        q2[1][:, :rope_dim], kv[1][:, :rope_dim], dims,
+                        preferred_element_type=jnp.float32)
+                      ).reshape(nh, SB, span)
+            else:
+                s2 = jax.lax.dot_general(
+                    q2, kv, (((2,), (2,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32)    # [2, nh*SB, span]
+                s3 = (s2[0] + s2[1]).reshape(nh, SB, span)
             t_pos = c * span + jax.lax.broadcasted_iota(
                 jnp.int32, (1, 1, span), 2)
-            mask = in_row & (t_pos <= qpos)                # [1, SB, span]
+            if biased:
+                s3 = s3 + bias_ref[c][None]
+                mask = in_row & (t_pos < ctx)
+            else:
+                mask = in_row & (t_pos <= qpos)            # [1, SB, span]
             s = jnp.where(mask, s3, NEG_INF).reshape(nh * SB, span)
 
             # slots of other rows see nothing here: their max stays, their
@@ -174,16 +219,28 @@ def _mla_ragged_kernel(q2_ref, kv_hbm, layer_ref, table_ref, rows_ref,
         .astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "interpret", "name",
+                                    "rope_dim"))
 def _mla_ragged(q2, kv_pages, layer_idx, page_table, q_starts, q_lens,
-                kv_lens, sm_scale: float, interpret: bool = False):
+                kv_lens, sm_scale: float, interpret: bool = False,
+                bias=None, name: str = "mla_ragged", rope_dim: int = 0):
     _two, T, nh, dkv = q2.shape
     _L, _N, _2, _one, page_size, _ = kv_pages.shape
     P = page_table.shape[1]
-    chunk = min(PAGES_PER_CHUNK, P)
+    chunk = min(PAGES_PER_CHUNK if bias is None else BIASED_PAGES_PER_CHUNK,
+                P)
     span = chunk * page_size
     slab_bytes = 2 * 2 * span * dkv * kv_pages.dtype.itemsize
-    SB = _query_block(T, nh, dkv, span, slab_bytes)
+    n_chunks = -(-P // chunk)
+    if bias is None:
+        SB = _query_block(T, nh, dkv, span, slab_bytes)
+    else:
+        # a slot's bias block, double-buffered, beside the rest
+        SB = max(1, min(T, max(8, BIASED_M_ROWS // nh)))
+        per_row = 22 * span + 32 * dkv + 8 * n_chunks * span // nh
+        while SB > 8 and nh * SB * per_row + slab_bytes > BIASED_VMEM_STACK:
+            SB = max(8, SB // 2)
     n_blocks = -(-T // SB)
     # sm_scale rides the packed queries (the kernel's matmuls see it once)
     qs = (q2 * sm_scale).astype(kv_pages.dtype)
@@ -198,8 +255,20 @@ def _mla_ragged(q2, kv_pages, layer_idx, page_table, q_starts, q_lens,
         jnp.sum((q_starts[None, :] < t0 + SB), axis=1)]).astype(jnp.int32)
 
     kernel = functools.partial(_mla_ragged_kernel, page_size=page_size,
-                               chunk=chunk, q_block=SB)
+                               chunk=chunk, q_block=SB,
+                               biased=bias is not None, rope_dim=rope_dim)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    extra, extra_specs = (), []
+    if bias is not None:
+        # [T, S] -> [chunks, T, span]: keys past the table's read NEG_INF
+        S = P * page_size
+        b = jnp.pad(bias.astype(jnp.float32),
+                    ((0, n_blocks * SB - T), (0, n_chunks * span - S)),
+                    constant_values=NEG_INF)
+        extra = (b.reshape(n_blocks * SB, n_chunks, span)
+                 .transpose(1, 0, 2),)
+        extra_specs = [pl.BlockSpec((n_chunks, SB, span),
+                                    lambda i: (0, i, 0))]
     out = pl.pallas_call(
         kernel,
         grid=(n_blocks,),
@@ -207,7 +276,7 @@ def _mla_ragged(q2, kv_pages, layer_idx, page_table, q_starts, q_lens,
             pl.BlockSpec((2, SB, nh, dkv), lambda i: (0, i, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             smem, smem, smem, smem, smem, smem,
-        ],
+        ] + extra_specs,
         out_specs=pl.BlockSpec((SB, nh, dkv), lambda i: (i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, 2, 1, chunk * page_size, dkv), kv_pages.dtype),
@@ -219,8 +288,12 @@ def _mla_ragged(q2, kv_pages, layer_idx, page_table, q_starts, q_lens,
         out_shape=jax.ShapeDtypeStruct((n_blocks * SB, nh, dkv),
                                        jnp.float32),
         interpret=interpret,
-        name="mla_ragged",
-    )(qs, kv_pages, layer_idx, page_table, rows, q_starts, q_lens, kv_lens)
+        name=name,
+        **({} if bias is None else {
+            "compiler_params": pltpu.CompilerParams(
+                vmem_limit_bytes=BIASED_VMEM_LIMIT)}),
+    )(qs, kv_pages, layer_idx, page_table, rows, q_starts, q_lens, kv_lens,
+      *extra)
     return out[:T]
 
 
@@ -229,8 +302,9 @@ def mla_ragged_attention_packed(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
                                 page_table: jnp.ndarray,
                                 q_starts: jnp.ndarray, q_lens: jnp.ndarray,
                                 kv_lens: jnp.ndarray, sm_scale: float,
-                                interpret: bool | None = None
-                                ) -> jnp.ndarray:
+                                interpret: bool | None = None,
+                                bias: jnp.ndarray | None = None,
+                                name: str = "mla_ragged") -> jnp.ndarray:
     """Latent paged attention of a token-packed step over the stacked MLA
     cache (drop-in for ``models.deepseek.mla_ragged_attention``).
 
@@ -246,6 +320,11 @@ def mla_ragged_attention_packed(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
     q_lens:     [R] real query tokens per row (a decode row is 1, a pad
                 row 0)
     kv_lens:    [R] context per row including its new tokens
+    bias:       [T, P * ps] float32 or None: added to every head's scores
+                of a slot against its row's keys; with it every key below
+                ``kv_lens`` is attended and the bias alone masks (module
+                docstring)
+    name:       the kernel's name in a device trace
 
     Returns the latent attention output [T, nh, dkv] in f32 — feed to
     ``models.deepseek._expand_and_project``.
@@ -257,7 +336,12 @@ def mla_ragged_attention_packed(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
     return _mla_ragged(q2, pages, layer, page_table.astype(jnp.int32),
                        q_starts.astype(jnp.int32), q_lens.astype(jnp.int32),
                        kv_lens.astype(jnp.int32), sm_scale,
-                       interpret=_resolve_interpret(interpret))
+                       interpret=_resolve_interpret(interpret), bias=bias,
+                       name=name,
+                       # (the masked form alone: every other family's
+                       # kernel is the one it was)
+                       rope_dim=(-(-dr // 128) * 128
+                                 if bias is not None and dkv > 128 else 0))
 
 
 __all__ = ["mla_ragged_attention_packed", "supports"]

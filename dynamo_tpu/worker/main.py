@@ -56,8 +56,9 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--page-size", type=int, default=16)
     p.add_argument("--max-num-seqs", type=int, default=64)
     p.add_argument("--state-slots", type=int, default=None,
-                   help="slots of the recurrent-state pool, for a model "
-                        "with linear-attention layers: one a request while "
+                   help="slots of the recurrent-state pool (a model with "
+                        "linear-attention layers) or of the window rings "
+                        "(a model with window layers): one a request while "
                         "it is admitted (default: --max-num-seqs); a model "
                         "without such layers keeps no pool")
     p.add_argument("--max-prefill-chunk", type=int, default=1024)
@@ -237,7 +238,7 @@ def build_engine(args: argparse.Namespace, startup=None) -> JaxEngine:
         # the kinds of cache the engine keeps: the paged pool, and the
         # recurrent-state pool of a family with linear-attention layers
         attrs["cache.kinds"] = engine.cache_kinds
-        if engine.state_slots:
+        if cfg.state_layers:
             from dynamo_tpu.ops.gdn import CHUNK
             attrs["linear_attention"] = f"gdn[chunk={CHUNK}]"
         # the form of the prefill-carrying steps: what

@@ -330,18 +330,21 @@ class EngineDispatchCollector:
         for reason, value in sorted(why.items()):
             refused.add_metric([str(reason)], float(value))
         yield refused
-        # the recurrent-state pool of a family with linear-attention
-        # layers (both 0 for every other family), and the prefix lookups
-        # such a family does not make
+        # the pool of slots of a family that keeps one a sequence beside
+        # its pages - the recurrent state of linear-attention layers, the
+        # rings of window layers - (both 0 for every other family), and
+        # the prefix lookups such a family does not make
         yield GaugeMetricFamily(
             "dynamo_worker_state_slots_total",
-            "Slots of the recurrent-state pool (--state-slots): requests "
-            "of a model with linear-attention layers that can be admitted "
-            "at once; 0 for a model that keeps no such state",
+            "Slots of the recurrent-state pool or of the window rings "
+            "(--state-slots): requests of a model with linear-attention "
+            "or window layers that can be admitted at once; 0 for a model "
+            "whose cache is its pages",
             value=float(stats.get("state_slots_total", 0)))
         yield GaugeMetricFamily(
             "dynamo_worker_state_slots_in_use",
-            "Slots of the recurrent-state pool held by admitted requests "
+            "Slots of the recurrent-state pool or the window rings held "
+            "by admitted requests "
             "(given at admission, taken back at finish and at preemption)",
             value=float(stats.get("state_slots_in_use", 0)))
         pr = CounterMetricFamily(
@@ -350,13 +353,42 @@ class EngineDispatchCollector:
             "could not be used, by reason: 'recurrent_state' (the model "
             "keeps a state beside the paged cache; a prefix's pages "
             "without the state that matches them are a wrong answer, so "
-            "the whole prompt is computed)",
+            "the whole prompt is computed), 'window_cache' (the model's "
+            "window layers keep a ring a sequence, which a prefix's pages "
+            "do not hold)",
             labels=["reason"])
-        why = {"recurrent_state": 0.0}
+        why = {"recurrent_state": 0.0, "window_cache": 0.0}
         why.update(stats.get("prefix_reuse_refused") or {})
         for reason, value in sorted(why.items()):
             pr.add_metric([str(reason)], float(value))
         yield pr
+        # what the full-attention layers' queries could see and what they
+        # attended (the same but where a layer attends a learned
+        # selection), and the device bytes of each kind of cache
+        yield CounterMetricFamily(
+            "dynamo_worker_attn_visible_keys",
+            "Keys the queries of ONE full-attention layer could see (a "
+            "token at position p sees p + 1), counted for a model that "
+            "keeps a slot a sequence beside its pages; an indexer scores "
+            "every one of them",
+            value=float(stats.get("attn_visible_keys", 0)))
+        yield CounterMetricFamily(
+            "dynamo_worker_attn_selected_keys",
+            "Keys the queries of ONE full-attention layer attended where "
+            "the layer attends a learned selection (min(index_topk, p + "
+            "1) for a token at position p); 0 for every other model",
+            value=float(stats.get("attn_selected_keys", 0)))
+        cb = GaugeMetricFamily(
+            "dynamo_worker_cache_bytes",
+            "Device bytes of the engine's cache by kind: 'paged' (the "
+            "page pool of the attention layers), 'state' (recurrent "
+            "state and convolution inputs, a slot a sequence), 'index' "
+            "(an indexer's key pages, addressed by the page table), "
+            "'window' (window layers' rings, a slot a sequence)",
+            labels=["kind"])
+        for kind, value in sorted((stats.get("cache_bytes") or {}).items()):
+            cb.add_metric([str(kind)], float(value))
+        yield cb
         # prefill-carrying steps by the form they ran in, so a model that
         # silently serves padded shows on the scrape
         pf = CounterMetricFamily(
@@ -559,6 +591,10 @@ def engine_dispatch_stats(engine) -> Dict[str, object]:
             - len(getattr(sched, "_free_slots", ()))),
         "prefix_reuse_refused": dict(
             getattr(sched, "prefix_reuse_refused", None) or {}),
+        "attn_visible_keys": float(getattr(engine, "attn_visible_keys", 0)),
+        "attn_selected_keys": float(
+            getattr(engine, "attn_selected_keys", 0)),
+        "cache_bytes": dict(getattr(engine, "cache_bytes", None) or {}),
         "guided_parity_mismatches": float(
             getattr(engine, "guided_parity_mismatches", 0)),
         "multistep_fallbacks": dict(

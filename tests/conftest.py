@@ -61,6 +61,7 @@ LONGEST_FIRST = (
     "test_sdar.py",
     "test_pallas_tpu_lowering.py",
     "test_deepseek.py",
+    "test_dots3.py",
     "test_mesh_sharded.py",
     "test_bench.py",
     "test_multistep.py",

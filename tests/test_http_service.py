@@ -6,6 +6,7 @@ counting engines, SSE assertions, metrics) and the discovery e2e.
 
 import asyncio
 import json
+import time
 
 import aiohttp
 import pytest
@@ -339,5 +340,48 @@ async def test_annotations_sse_events(card):
         named = {e.event: json.loads(e.data) for e in events if e.event}
         assert named["formatted_prompt"] == "<|user|>q<|end|><|assistant|>"
         assert isinstance(named["token_ids"], list) and named["token_ids"]
+    finally:
+        await service.stop()
+
+
+@pytest.mark.parametrize("path,body", [
+    ("/v1/completions", {"prompt": [1, 2, 3, 4]}),
+    ("/v1/chat/completions",
+     {"messages": [{"role": "user", "content": "hello"}]}),
+])
+@pytest.mark.parametrize("stream", [True, False])
+async def test_a_caller_that_hangs_up_before_the_first_token_ends_its_request(
+        card, path, body, stream):
+    """The engine's generator is closed when the caller goes, not when the
+    first token (a whole prompt's prefill later) cannot be written."""
+    ended = []
+
+    class SlowToStart(EchoEngine):
+        async def generate(self, request, ctx=None):
+            try:
+                async for out in super().generate(request, ctx):
+                    yield out
+            finally:
+                ended.append(time.monotonic())
+
+    manager = ModelManager()
+    manager.add(card.name, LocalEnginePipeline(card, SlowToStart(delay_s=5.0)))
+    service = await HttpService(manager, host="127.0.0.1", port=0).start()
+    try:
+        async with aiohttp.ClientSession() as s:
+            async def call():
+                async with s.post(
+                        f"http://127.0.0.1:{service.port}{path}",
+                        json={"model": "echo-model", "max_tokens": 4,
+                              "stream": stream, **body}) as r:
+                    await r.read()
+            t0 = time.monotonic()
+            task = asyncio.ensure_future(call())
+            await asyncio.sleep(0.3)
+            task.cancel()
+            await asyncio.gather(task, return_exceptions=True)
+        while not ended and time.monotonic() - t0 < 4.0:
+            await asyncio.sleep(0.05)
+        assert ended and ended[0] - t0 < 2.0
     finally:
         await service.stop()

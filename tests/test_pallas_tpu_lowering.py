@@ -963,3 +963,67 @@ def test_qwen3_next_step_programs_compile_for_a_v5e():
                     if " copy(" in ln and state in ln.split(" copy(")[0]]
         mem = compiled.memory_analysis()
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
+
+
+def test_dots3_step_programs_compile_for_a_v5e():
+    """The long-context cell's two step programs - the token-packed step
+    of 640 slots over 24 rows and the fused block of two decode steps - at
+    the published widths (abstract weights: 3,093,416,192 parameters) and
+    the cell's three pools compile for a v5e: the packed step with the
+    masked form of the ragged latent kernel for both attention kinds
+    (``mla_selected``, ``mla_window``) and ``moe_grouped`` in it, the fused
+    block (one-token rows: the gathered forms, plain XLA) with
+    ``moe_grouped``; neither copies the latent pages, the index pages or
+    the rings, and both fit the chip's memory."""
+    import json
+    import os
+
+    from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
+    from dynamo_tpu.engine.program_check import pool_copies, step_programs
+    from dynamo_tpu.models import dots3
+    from dynamo_tpu.models.config import ModelConfig
+
+    one_chip = _v5e_chip()
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "configs",
+        "dots3-note-prev.json")
+    with open(path) as f:
+        hf = json.load(f)
+    args = hf.pop("benchmark")["worker_args"]
+    args = {args[i]: int(args[i + 1]) for i in range(0, len(args), 2)
+            if args[i + 1].isdigit()}
+    cfg = ModelConfig.from_hf(hf)
+    abs_params = jax.eval_shape(
+        lambda: dots3.init_params(cfg, jax.random.PRNGKey(0)))
+    rows, chunk = args["--max-num-seqs"], args["--max-prefill-chunk"]
+    eng = JaxEngine(cfg, abs_params, JaxEngineConfig(
+        num_pages=16, page_size=16, max_num_seqs=rows,
+        max_context=args["--max-context"], max_prefill_chunk=chunk,
+        attn_impl="pallas", decode_multistep=args["--decode-multistep"],
+        state_slots=args["--state-slots"]))
+    assert eng.padded_reason is None
+    assert eng._packed_cap == args["--min-prefill-bucket"]
+    assert eng.cache_kinds == (
+        "paged[L=3,Hkv=1,Dh=512]+index[L=3,D=128]"
+        f"+window[L=6,S={rows},R=1024,D=1024]")
+    programs = step_programs(
+        eng, rows, chunk, width=args["--decode-multistep"],
+        sharding=one_chip, num_pages=args["--num-pages"],
+        tokens=eng._packed_cap)
+    pool = (3, args["--num-pages"]) + tuple(eng.kv_pool.shape[2:])
+    other = [f"bf16[3,{args['--num-pages']},16,128]",
+             f"bf16[6,{rows + 1},64,2,1,16,1024]"]
+    want = {"packed": {"mla_selected", "mla_window", "moe_grouped"},
+            "fused": {"moe_grouped"}}
+    for name, kernels in want.items():
+        fn, fn_args = programs[name]
+        compiled = fn.lower(*fn_args).compile()
+        hlo = compiled.as_text()
+        calls = {ln.split("=")[0].strip().lstrip("%").split(".")[0]
+                 for ln in hlo.splitlines() if "tpu_custom_call" in ln}
+        assert calls == kernels, name
+        assert pool_copies(hlo, pool, eng.kv_pool.dtype) == []
+        assert not [ln for ln in hlo.splitlines() if " copy(" in ln
+                    and any(s in ln.split(" copy(")[0] for s in other)]
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
