@@ -537,6 +537,18 @@ class JaxEngine(ScheduledEngineBase):
         # ``decode_kernel_rows``, summed:
         # dynamo_worker_packed_decode_kernel_rows_total)
         self.packed_decode_kernel_rows = 0
+        # the form in which the one-token rows of a model whose full
+        # layers attend a learned selection attend it: "masked" on the
+        # latent kernels, "gathered" on the XLA path (None: no layer
+        # selects), and the rows dispatched in each
+        # (dynamo_worker_attn_one_token_rows_total{form})
+        self.one_token_form: Optional[str] = None
+        if model_cfg.index_topk:
+            self.one_token_form = (
+                "masked" if family.on_kernels(
+                    model_cfg, self._attn_decode, self.cfg.page_size)
+                else "gathered")
+        self.attn_one_token_rows: Dict[str, int] = {}
         # prefill-carrying dispatches by form: "packed", "padded:<reason>"
         # (dynamo_worker_prefill_steps_total; the collector pre-seeds the
         # labels, worker/metrics.py PREFILL_FORMS)
@@ -733,11 +745,22 @@ class JaxEngine(ScheduledEngineBase):
         visibility has a block (``ops/pallas/ragged.py``)."""
         if self.padded_reason is not None:
             return None
+        if self.one_token_form is not None:
+            return ("chunks:mla_selected,one_token:mla_selected_rows"
+                    if self.one_token_form == "masked" else "gathered")
         if self.model_cfg.kv_lora_rank:
             return "mla_ragged"
         if self._packed_splits:
             return "chunks:ragged_mixed,one_token:paged_decode"
         return "ragged_mixed"
+
+    def _count_one_token_rows(self, n: int) -> None:
+        """``n`` more one-token rows dispatched, under this engine's form
+        of a learned selection (nothing where no layer selects)."""
+        form = self.one_token_form
+        if form is not None:
+            self.attn_one_token_rows[form] = (
+                self.attn_one_token_rows.get(form, 0) + n)
 
     def _decode_kernel_rows(self, new: np.ndarray, slots: int) -> int:
         """Rows of one packed step that the decode kernel attends: the
@@ -2369,6 +2392,7 @@ class JaxEngine(ScheduledEngineBase):
         self._step_counter += w
         self.decode_dispatches += 1
         self.multistep_blocks += 1
+        self._count_one_token_rows(len(seqs) * w)
         self.last_padded = (B, w)
         self.last_program = f"multistep{w}[{B}]"
         if _fresh:
@@ -2783,6 +2807,8 @@ class JaxEngine(ScheduledEngineBase):
                 self.params, self.pages, feed, pos, table, total, new,
                 self._rng, np.int32(step), temp, top_k, top_p, extra)
             self._queue_moe_aux(aux)
+        if self.one_token_form is not None:
+            self._count_one_token_rows(int(np.count_nonzero(a["new"] == 1)))
         # the slots the device computed and the step program with its
         # bucket, as the ring names them: a packed step pays for its T
         # slots whatever its rows

@@ -248,9 +248,10 @@ def init_params(cfg: ModelConfig, rng: jax.Array,
 class Step:
     """What every layer of one step shares: the rows on the flat axis
     (``ops/gdn.Rows``), each token's position, the step's form, and
-    whether the rows of several tokens run the masked form in the ragged
-    latent kernel (``kernel``: the chip) or everything the gathered form
-    (the CPU, the ``scan`` path, the oracle)."""
+    whether the step runs on the latent kernels (``kernel``: the chip -
+    the masked form of a full layer's selection for every row, of a
+    window for the rows of several tokens) or everything the gathered
+    form (the CPU, the ``scan`` path, the oracle)."""
 
     def __init__(self, tokens, positions, page_table, total_lens, new_lens,
                  slots, starts, kernel: bool):
@@ -271,6 +272,8 @@ class Step:
         # a [B, S > 1] step every row
         least = 1 if self.packed else 0
         self.q_lens = jnp.where(new_lens > least, new_lens, 0)
+        # ... and the rows the one-token kernel takes, by their contexts
+        self.one_lens = jnp.where(new_lens == 1, total_lens, 0)
         self.first = jnp.clip(self.rows.start, 0, B * S - 1)
 
     @property
@@ -383,6 +386,9 @@ def full_block(cfg: ModelConfig, lp, h, cache, lidx, st: Step):
         scale, scopes = _mla_scale(cfg), ("index/score", "index/topk")
         q_i = q_i.astype(index.dtype)
         if st.kernel:
+            from dynamo_tpu.ops.pallas.mla_decode_masked import (
+                mla_masked_decode_stacked)
+
             one, bias = sl.select_split(
                 q_i, w_i, index, lidx, st.page_table, st.rows,
                 st.total_lens, cfg.index_topk, scopes=scopes, **st.walk)
@@ -392,10 +398,11 @@ def full_block(cfg: ModelConfig, lp, h, cache, lidx, st: Step):
                     lat = _masked(st, q_lat, q_pe, kv, lidx, st.page_table,
                                   st.total_lens, bias, scale, "mla_selected")
                 if one is not None:
-                    (sel, live), to = one
-                    lat = sl.lay(lat, sl.sparse_attend(
+                    rows_bias, to = one
+                    lat = sl.lay(lat, mla_masked_decode_stacked(
                         q_lat[st.first], q_pe[st.first], kv, lidx,
-                        st.page_table, sel, live, scale), to)
+                        st.page_table, st.one_lens, rows_bias, scale,
+                        name="mla_selected_rows"), to)
         else:
             sel, live = sl.select(
                 q_i, w_i, index, lidx, st.page_table, st.rows,
@@ -482,6 +489,18 @@ def _ffn(cfg, lp, h, moe_kw):
 
 # ----------------------------------------------------------------- forward
 
+def on_kernels(cfg: ModelConfig, attn_impl: Optional[Callable],
+               page_size: int) -> bool:
+    """Whether a step handed ``attn_impl`` runs the family's latent
+    kernels (``Step.kernel``): the engine's Pallas marker, and both
+    attention kinds' latents and the pages at widths the kernels tile."""
+    from dynamo_tpu.ops.pallas.mla_decode import supports
+
+    return bool(getattr(attn_impl, "pallas_paged_kernel", False)
+                and supports(cfg.kv_lora_rank, page_size)
+                and supports(cfg.swa_kv_lora_rank, page_size))
+
+
 def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             positions: jnp.ndarray, pages: Dict[str, jnp.ndarray],
             page_table: jnp.ndarray, total_lens: jnp.ndarray,
@@ -495,7 +514,9 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     never called: its ``pallas_paged_kernel`` marker opts the family into
     the masked form of both attention kinds for the rows of several
     tokens (``ops/pallas/mla_ragged.py`` with a bias: ``mla_selected``,
-    ``mla_window`` in a device trace) and into ``moe_grouped``. No ``logits_window``: a verify window or a scoring
+    ``mla_window`` in a device trace), of the selection for the rows of
+    one (``ops/pallas/mla_decode_masked.py``: ``mla_selected_rows``) and
+    into ``moe_grouped``. No ``logits_window``: a verify window or a scoring
     pass would have to take back what a rejected token wrote to a ring,
     so the engine offers neither."""
     from dynamo_tpu.models.moe import (grouped_on_chip, split_experts,
@@ -505,18 +526,13 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         raise NotImplementedError(
             f"moe_backend {cfg.moe_backend!r}: this family's sparse block "
             "(held range, shared expert) runs the grouped layer only")
-    from dynamo_tpu.ops.pallas.mla_decode import supports as mla_supports
-
     # (a slot past the pool's is held to its last: a write to no slot would
     # be dropped in silence)
     slots = jnp.minimum(page_table[:, -1], pages["win"].shape[1] - 1)
     page_table = page_table[:, :-1]
-    ps = pages["kv"].shape[-2]
-    kernel = (getattr(attn_impl, "pallas_paged_kernel", False)
-              and mla_supports(cfg.kv_lora_rank, ps)
-              and mla_supports(cfg.swa_kv_lora_rank, ps))
     st = Step(tokens, positions, page_table, total_lens, new_lens, slots,
-              packed_rows(packed, new_lens), kernel)
+              packed_rows(packed, new_lens),
+              on_kernels(cfg, attn_impl, pages["kv"].shape[-2]))
     wcfg = cfg.window_cfg()
     K = cfg.first_k_dense_replace
     G, P, tail = cfg.layer_pattern()
@@ -583,6 +599,6 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 forward.supports_packed = True
 
 
-__all__ = ["init_params", "forward", "make_pages", "sparse_block",
-           "index_inputs", "full_block", "window_block",
+__all__ = ["init_params", "forward", "on_kernels", "make_pages",
+           "sparse_block", "index_inputs", "full_block", "window_block",
            "window_bytes_per_sequence"]

@@ -14,7 +14,9 @@ over layers), so a step program compiles one layer body:
 - ``mla_decode.mla_paged_decode_stacked`` /
   ``mla_prefill.mla_paged_prefill_stacked`` /
   ``mla_ragged.mla_ragged_attention_packed`` — the same three step forms
-  over DeepSeek's latent cache.
+  over DeepSeek's latent cache;
+  ``mla_decode_masked.mla_masked_decode_stacked`` — the decode step of a
+  model that attends a learned selection: a bias over the streamed context.
 
 The XLA implementations in ``dynamo_tpu.ops.attention`` remain the portable
 reference (CPU tests).

@@ -23,14 +23,19 @@ selected tokens alone::
     out[t, h]  = sum_{s in S_t} softmax_{s in S_t}(a[t, h, s]) c[s]
 
 in one of two forms. **Gathered** (``sparse_attend``): the selected rows
-are fetched by ``(page, offset)`` - what a row of ONE token runs, and the
-oracle. **Masked** (``bias`` for ``ops/pallas/mla_ragged.py``): the row's
-whole context streams through the ragged latent kernel and a bias ``[T,
-S]``, 0 on the selection and ``NEG_INF`` off it, keeps the softmax to the
-selection - what a row of SEVERAL tokens runs on the chip, where fetching
-2,048 rows of 1 KB for each of 512 queries takes 38 ms a layer (XLA's
-gather, 29 ns a row) and streaming 16 k rows once for all of them a third
-of that. The two are the same sum.
+are fetched by ``(page, offset)`` from the selection as a sorted list
+(``select``) - the path without kernels (the CPU, the oracle). **Masked**:
+the row's whole context streams through a latent kernel and a bias, 0 on
+the selection and ``NEG_INF`` off it, keeps the softmax to the selection -
+what every row runs on the chip (``select_split``): the rows of SEVERAL
+tokens through ``ops/pallas/mla_ragged.py`` (``bias [T, S]``), where
+fetching 2,048 rows of 1 KB for each of 512 queries takes 38 ms a layer
+(XLA's gather, 29 ns a row) and streaming 16 k rows once for all of them a
+third of that; the rows of ONE token through
+``ops/pallas/mla_decode_masked.py`` (``bias [R, S]``), where a row's 2,048
+gathered rows cost what streaming 25 k tokens does and the list they are
+gathered by costs a sort of the table's width besides (3.7 ms a layer for
+24 rows of 25,600). The two are the same sum.
 
 **The window.** A window layer keeps, a sequence, a ring of ``R``
 positions in pages of the latent layout ``[L, slots * R / ps, 2, 1, ps,
@@ -324,40 +329,40 @@ def select_split(q: jnp.ndarray, w: jnp.ndarray, pool: jnp.ndarray, layer,
                  page_table: jnp.ndarray, rows: Rows,
                  total_lens: jnp.ndarray, topk: int, *, width: int,
                  packed: bool, scopes: Tuple[str, str] = ("score", "topk")):
-    """The selection in the forms the chip runs (module docstring): ``(one,
-    bias)``. ``one = ((sel [R, K], live [R, K]), to [R])`` the lists of the
-    rows of ONE token, on the rows' axis (``one_token_rows``; None where
-    the step holds none: a ``[B, S > 1]`` step); ``bias [N, S]`` float32
-    the masked form of the rows of several, ``NEG_INF`` everywhere else
-    (None for ``[B, 1]``)."""
+    """The selection in the form the chip runs (module docstring), a bias
+    of 0 on a query's selection and ``NEG_INF`` off it: ``(one, bias)``.
+    ``one = (bias [R, S], to [R])`` the rows of ONE token, on the rows'
+    axis with the slot each belongs at (``one_token_rows``; None where the
+    step holds none: a ``[B, S > 1]`` step); ``bias [N, S]`` float32 the
+    rows of several, ``NEG_INF`` everywhere else (None for ``[B, 1]``).
+    Both are ``topk_mask`` as it stands: no list, so no sort of the
+    table's width."""
     N = q.shape[0]
     S = page_table.shape[1] * pool.shape[2]
     K = min(topk, S)
     one = bias = None
     whole = S <= topk          # every visible key is selected: no scores
+    row = _select_row(pool, layer, page_table, K, scopes, True)
+    s = jnp.arange(S, dtype=jnp.int32)[None, :]
     if width == 1 or packed:
         if whole:
-            sel = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32),
-                                   (rows.start.shape[0], S))
-            one = ((sel, (sel < total_lens[:, None])
-                    & (rows.new == 1)[:, None]),
-                   jnp.where(rows.new == 1, rows.start, N))
+            single = rows.new == 1
+            one = (jnp.where((s < total_lens[:, None]) & single[:, None],
+                             0.0, NEG_INF),
+                   jnp.where(single, rows.start, N))
         else:
-            one = one_token_rows(
-                _select_row(pool, layer, page_table, K, scopes, False),
-                rows, total_lens, (q, w))
+            one = one_token_rows(row, rows, total_lens, (q, w))
     if width > 1:
         least = 1 if packed else 0
         if whole:
             pos = token_positions(rows, total_lens)
-            seen = ((jnp.arange(S, dtype=jnp.int32)[None, :] <= pos[:, None])
+            seen = ((s <= pos[:, None])
                     & (rows.valid & (rows.new[rows.row] > least))[:, None])
             bias = jnp.where(seen, 0.0, NEG_INF)
         else:
             bias = several_token_rows(
-                _select_row(pool, layer, page_table, K, scopes, True), rows,
-                total_lens, (q, w), jnp.full((N, S), NEG_INF, jnp.float32),
-                width, least)
+                row, rows, total_lens, (q, w),
+                jnp.full((N, S), NEG_INF, jnp.float32), width, least)
     return one, bias
 
 

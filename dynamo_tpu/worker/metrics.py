@@ -231,6 +231,9 @@ class EngineDispatchCollector:
     PREFILL_FORMS = ("packed", "padded:forward", "padded:family",
                      "padded:dp", "padded:spec", "padded:attn_impl",
                      "padded:ring")
+    # the forms a one-token row attends a learned selection in
+    # (engine/jax_engine.py ``one_token_form``)
+    ONE_TOKEN_FORMS = ("masked", "gathered")
 
     def __init__(self, registry: CollectorRegistry):
         self._source: Optional[Callable[[], Dict[str, float]]] = None
@@ -378,6 +381,22 @@ class EngineDispatchCollector:
             "the layer attends a learned selection (min(index_topk, p + "
             "1) for a token at position p); 0 for every other model",
             value=float(stats.get("attn_selected_keys", 0)))
+        one = CounterMetricFamily(
+            "dynamo_worker_attn_one_token_rows",
+            "Rows of ONE token that the dispatches of a model whose "
+            "full-attention layers attend a learned selection carried (a "
+            "decode row a step; a fused block: its rows times its width), "
+            "by the form they attended the selection in: 'masked' (the "
+            "row's context streamed through the latent decode kernel with "
+            "the selection as a bias: the Pallas kernels), 'gathered' (the "
+            "selected rows fetched by a sorted list: the XLA path); both 0 "
+            "for every other model",
+            labels=["form"])
+        forms = dict.fromkeys(self.ONE_TOKEN_FORMS, 0.0)
+        forms.update(stats.get("attn_one_token_rows") or {})
+        for form, value in sorted(forms.items()):
+            one.add_metric([str(form)], float(value))
+        yield one
         cb = GaugeMetricFamily(
             "dynamo_worker_cache_bytes",
             "Device bytes of the engine's cache by kind: 'paged' (the "
@@ -567,7 +586,7 @@ def engine_dispatch_stats(engine) -> Dict[str, object]:
     ``ScheduledEngineBase`` engine (JaxEngine and the mocker both carry
     the counters). Values are floats, except ``multistep_fallbacks``,
     ``admission_runs``, ``chained_blocks``, ``chained_steps``,
-    ``chain_refusals`` and
+    ``chain_refusals``, ``attn_one_token_rows`` and
     ``prefill_steps``: per-label count dicts the collector renders as
     labeled families."""
     sched = getattr(engine, "scheduler", None)
@@ -594,6 +613,8 @@ def engine_dispatch_stats(engine) -> Dict[str, object]:
         "attn_visible_keys": float(getattr(engine, "attn_visible_keys", 0)),
         "attn_selected_keys": float(
             getattr(engine, "attn_selected_keys", 0)),
+        "attn_one_token_rows": dict(
+            getattr(engine, "attn_one_token_rows", None) or {}),
         "cache_bytes": dict(getattr(engine, "cache_bytes", None) or {}),
         "guided_parity_mismatches": float(
             getattr(engine, "guided_parity_mismatches", 0)),

@@ -47,7 +47,8 @@ ASYNC_TEST_TIMEOUT = float(os.environ.get("DYN_TEST_TIMEOUT", "60"))
 
 
 # The files that take longest, longest first (seconds of a whole run on
-# six workers, junit, PR 42: from 706 down to 40). ``--dist loadfile``
+# six workers, junit, PR 42: from 706 down to 40; the first seven again at
+# PR 48: 1,411 / 687 / 588 / 519 / 313 / 311 / 263). ``--dist loadfile``
 # hands a worker one file at a time and by default starts with the files
 # that hold the most tests, which left the few long end-to-end files
 # (17 tests in 706 s, 2 in 190 s) to the end of the run, each alone on
@@ -56,12 +57,12 @@ ASYNC_TEST_TIMEOUT = float(os.environ.get("DYN_TEST_TIMEOUT", "60"))
 # behind them. Add a file here when it grows past the last one's time.
 LONGEST_FIRST = (
     "benchmarks/test_benchmarks_e2e.py",
-    "test_packed_step.py",
+    "test_pallas_tpu_lowering.py",
+    "test_dots3.py",
     "test_qwen3_next.py",
     "test_sdar.py",
-    "test_pallas_tpu_lowering.py",
     "test_deepseek.py",
-    "test_dots3.py",
+    "test_packed_step.py",
     "test_mesh_sharded.py",
     "test_bench.py",
     "test_multistep.py",

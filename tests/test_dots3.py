@@ -267,7 +267,8 @@ def test_the_selection_is_the_references_top_k_index_for_index(tiny):
     """One full layer's indexer on a packed step of a chunk row and
     one-token rows against index pages filled by earlier steps: every
     token's list (sorted) is the reference's ``lax.top_k`` of its dense
-    score row, and the masked form's bias is that list as a mask."""
+    score row, and the masked form's bias - of the chunk row's slots and
+    of the one-token row alike - is that list as a mask."""
     hf, cfg, params = tiny
     rng = np.random.default_rng(3)
     T = 90
@@ -310,10 +311,16 @@ def test_the_selection_is_the_references_top_k_index_for_index(tiny):
         if slot < 40:
             assert sorted(np.flatnonzero(np.asarray(bias[slot]) == 0)) \
                 == sorted(want)
-    (sel1, live1), to = one
+    rows_bias, to = one
     assert np.asarray(to).tolist() == [N, 40]
-    assert sorted(np.asarray(sel1[1])[np.asarray(live1[1])]) == sorted(
+    # the one-token row's bias opens the list it was sorted from on the
+    # parent, key for key; the chunk row's is shut
+    assert np.flatnonzero(np.asarray(rows_bias[1]) == 0).tolist() == sorted(
+        np.asarray(sel[40])[np.asarray(live[40])]) == sorted(
         np.asarray(idx[59])[np.asarray(vals[59]) > -np.inf])
+    assert set(np.unique(np.asarray(rows_bias))) == {
+        np.float32(0.0), np.float32(sl.NEG_INF)}
+    assert (np.asarray(rows_bias[0]) < sl.NEG_INF / 2).all()
     assert (np.asarray(bias[40:]) < sl.NEG_INF / 2).all()
 
 
@@ -385,13 +392,12 @@ def test_chunked_prefill_equals_whole_prefill_across_a_ring_wrap(tiny):
     np.testing.assert_allclose(parts[159], whole[159], atol=2e-5)
 
 
-@pytest.mark.parametrize("packed", [False, True])
-def test_the_masked_kernel_form_equals_the_gathered_form(packed):
-    """A step of two chunk rows and two one-token rows against pools that
-    earlier steps filled: the masked form (``mla_ragged`` with a bias,
-    interpret mode: ``mla_selected``, ``mla_window``) for the rows of
-    several tokens beside the gathered form for the others gives the
-    logits and the pools of the gathered form for every row."""
+@pytest.fixture(scope="module")
+def filled():
+    """Four rows' pools at the kernels' tiny widths, filled by whole
+    prompts a row a step (the gathered form): ``(cfg, pages, table, have,
+    fwd, rng)``, ``have`` the tokens each row holds, ``fwd(t, pos, pages,
+    table, total, new, impl, packed)`` the jitted forward."""
     _hf, cfg, params = _family(**KERNEL)
     rng = np.random.default_rng(2)
     ps, P, chunk = 8, 24, 24
@@ -408,7 +414,6 @@ def test_the_masked_kernel_form_equals_the_gathered_form(packed):
             params, cfg, t, pos, pg, tb, tot, new, attn_impl=impl,
             packed=packed), static_argnums=(6, 7))
     with jax.default_matmul_precision("highest"):
-        # fill: whole prompts, a row a step (the gathered form)
         for r, n in enumerate(have):
             s = 0
             while s < n:
@@ -422,7 +427,29 @@ def test_the_masked_kernel_form_equals_the_gathered_form(packed):
                                     jnp.asarray([s + m]), jnp.asarray([m]),
                                     None, False)
                 s += m
-        new = [24, 17, 1, 1]
+    return cfg, pages, table, have, fwd, rng
+
+
+# a step's rows by the tokens each brings: two chunk rows beside two
+# one-token rows back to back (a packed step: the chunk rows' slots come
+# back zero from the one-token kernel and are filled by ``mla_selected``),
+# the same as ``[B, S]`` rows, and a decode step ``[B, 1]`` (the fused
+# block's: every row of one token, one of them dead)
+STEPS = {"padded": ([24, 17, 9, 1], 24), "packed": ([24, 17, 1, 1], None),
+         "decode": ([1, 1, 0, 1], 1)}
+
+
+@pytest.mark.parametrize("step", sorted(STEPS))
+def test_the_masked_kernel_form_equals_the_gathered_form(filled, step):
+    """A step against pools that earlier steps filled: the masked forms
+    (interpret mode: ``mla_ragged`` with a bias for the rows of several
+    tokens, ``mla_selected`` and ``mla_window``; the latent decode kernel
+    with a bias for a full layer's rows of one, ``mla_selected_rows``)
+    give the logits and the pools of the gathered form for every row."""
+    cfg, pages, table, have, fwd, rng = filled
+    new, S = STEPS[step]
+    R, packed = len(new), S is None
+    with jax.default_matmul_precision("highest"):
         if packed:
             T = 48
             t, pos = np.zeros((1, T), np.int32), np.zeros((1, T), np.int32)
@@ -432,21 +459,134 @@ def test_the_masked_kernel_form_equals_the_gathered_form(packed):
                 pos[0, s:s + n] = have[r] + np.arange(n)
                 s += n
         else:
-            new = [24, 17, 9, 1]
-            t = rng.integers(0, 512, (R, chunk)).astype(np.int32)
-            pos = np.asarray(have)[:, None] + np.arange(chunk)[None]
+            t = rng.integers(0, 512, (R, S)).astype(np.int32)
+            pos = np.asarray(have)[:, None] + np.arange(S)[None]
         args = (jnp.asarray(t), jnp.asarray(pos), None, jnp.asarray(table),
                 jnp.asarray(have) + jnp.asarray(new), jnp.asarray(new))
-        copy = jax.tree_util.tree_map(jnp.copy, pages)
-        want, pg_want, _ = fwd(*args[:2], copy, *args[3:], None, packed)
+        want, pg_want, _ = fwd(*args[:2], pages, *args[3:], None, packed)
         got, pg_got, _ = fwd(*args[:2], pages, *args[3:], _Kernels(), packed)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5)
+    live = np.asarray(new) > 0
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=3e-5)
     for name in pg_want:
         # (a later layer's keys are computed from an earlier layer's
         # attention: equal to rounding, not to the bit)
         np.testing.assert_allclose(np.asarray(pg_got[name]),
                                    np.asarray(pg_want[name]), atol=3e-5,
                                    err_msg=name)
+
+
+# (topk 24 over a table of 16 pages of 8: a context and whether every index
+# key but ten is the same key)
+@pytest.mark.parametrize("ctx,tied", [
+    (90, True), (24, False), (25, False), (17, False), (128, False)], ids=[
+    "a_tie_at_the_cut", "exactly_topk", "topk_plus_one",
+    "shorter_than_topk", "the_tables_last_position"])
+def test_the_masked_one_token_form_is_the_gather_over_the_sorted_list(ctx,
+                                                                      tied):
+    """A packed step of a chunk row and two one-token rows over the same
+    pools: the one-token rows' bias (``select_split``) opens exactly the
+    positions of the parent's sorted list (``select``) where ``live``, row
+    for row, and the latent decode kernel over that bias (interpret mode)
+    gives what ``sparse_attend`` gives over the list; the chunk row enters
+    the kernel with length 0 and comes back zero. The case's row: a tie of
+    scores that straddles the ``topk``-th place (the lowest positions of
+    the tied win), a context of exactly ``topk``, of one more, of fewer
+    (the selection is the whole context), and a row whose query sits at the
+    table's last position."""
+    from dynamo_tpu.ops.pallas.mla_decode_masked import (
+        mla_masked_decode_stacked)
+
+    rng = np.random.default_rng(11)
+    K, J, D, nh, dkv, dr, ps, P = 24, 4, 32, 4, 128, 64, 8, 16
+    S = P * ps
+    new, total = [17, 1, 1], [60, ctx, 77]
+    R = len(new)
+    keys = rng.standard_normal((R, S, D)).astype(np.float32)
+    rows, N = _step(None, new, total)
+    q = rng.standard_normal((N, J, D)).astype(np.float32)
+    w = np.abs(rng.standard_normal((N, J))).astype(np.float32)
+    if tied:
+        # small whole numbers, so that equal keys score EQUAL whatever the
+        # order of the sums: ten keys that score higher, each by its own
+        # factor, every other key the same - K - 10 of those are taken,
+        # the lowest positions
+        q[17] = rng.integers(-3, 4, (J, D))
+        w[17] = rng.integers(1, 4, J)
+        keys[1, :] = q[17, 0]
+        high = rng.choice(ctx, 10, replace=False)
+        keys[1, high] *= (2 + np.arange(10))[:, None]
+    index = np.zeros((2, R * P + 1, ps, D), np.float32)
+    index[1, 1:] = keys.reshape(R * P, ps, D)
+    latent = jnp.asarray(rng.standard_normal((2, R * P + 1, 2, 1, ps, dkv)),
+                         jnp.float32)
+    table = jnp.asarray(1 + np.arange(R * P).reshape(R, P), jnp.int32)
+    q, w = jnp.asarray(q), jnp.asarray(w)
+    q_lat = jnp.asarray(rng.standard_normal((N, nh, dkv)), jnp.float32)
+    q_pe = jnp.asarray(rng.standard_normal((N, nh, dr)), jnp.float32)
+    total = jnp.asarray(total, jnp.int32)
+    kw = dict(width=N, packed=True)
+    with jax.default_matmul_precision("highest"):
+        sel, live = sl.select(q, w, jnp.asarray(index), 1, table, rows,
+                              total, K, **kw)
+        one, _bias = sl.select_split(q, w, jnp.asarray(index), 1, table,
+                                     rows, total, K, **kw)
+        rows_bias, to = one
+        first = jnp.clip(rows.start, 0, N - 1)
+        got = mla_masked_decode_stacked(
+            q_lat[first], q_pe[first], latent, 1, table,
+            jnp.where(rows.new == 1, total, 0), rows_bias, 0.13,
+            interpret=True, name="mla_selected_rows")
+        want = sl.sparse_attend(q_lat[first], q_pe[first], latent, 1, table,
+                                sel[first], live[first], 0.13)
+    assert np.asarray(to).tolist() == [N, 17, 18]
+    assert not np.asarray(got[0]).any()
+    for r in (1, 2):
+        slot = int(rows.start[r])
+        listed = sorted(np.asarray(sel[slot])[np.asarray(live[slot])])
+        assert np.flatnonzero(np.asarray(rows_bias[r]) == 0).tolist() \
+            == listed
+        assert len(listed) == min(K, int(total[r]))
+        np.testing.assert_allclose(np.asarray(got[r]), np.asarray(want[r]),
+                                   atol=2e-5, rtol=2e-5)
+    if tied:
+        listed = set(np.flatnonzero(np.asarray(rows_bias[1]) == 0).tolist())
+        rest = sorted(set(range(ctx)) - set(high.tolist()))
+        assert listed == set(high.tolist()) | set(rest[:K - 10])
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("attn_impl", ["pallas", "scan"])
+def test_no_step_program_on_the_kernels_sorts_the_tables_width(attn_impl):
+    """The tiny configuration's step programs as the engine jits them -
+    the prefill-carrying step (token-packed on the kernels) and the fused
+    block: on the kernel path no ``sort`` has an operand as wide as the
+    page table's tokens (the selection stays a mask; the expert layer's
+    sorts of a step's picks are shorter), on the XLA path the one-token
+    rows' list is sorted out of exactly that width."""
+    from dynamo_tpu.engine.program_check import step_programs
+
+    _hf, cfg, params = _family(**(KERNEL if attn_impl == "pallas" else {}))
+    # (a table of 384 tokens: no other axis of the tiny model is as long)
+    eng = _engine(cfg, params, attn_impl=attn_impl, max_context=384)
+    assert eng.one_token_form == {"pallas": "masked",
+                                  "scan": "gathered"}[attn_impl]
+    S = eng.cfg.max_context
+    programs = step_programs(eng, 4, 64, width=4)
+    mixed = "packed" if attn_impl == "pallas" else "mixed"
+    for name in (mixed, "fused"):
+        fn, args = programs[name]
+        wide = [e for e in _eqns(fn.trace(*args).jaxpr)
+                if e.primitive.name == "sort"
+                and S in e.invars[0].aval.shape]
+        assert bool(wide) == (attn_impl == "scan"), (name, wide)
 
 
 @pytest.mark.parametrize("nh,dkv,ctx", [(4, 128, (1100, 700)),
@@ -562,14 +702,16 @@ def _is_the_references_greedy(hf, params, prompt, served) -> bool:
     return np.asarray(want).tolist() == list(served)
 
 
+@pytest.mark.async_timeout(240)
 @pytest.mark.parametrize("attn_impl", ["scan", "pallas"])
 async def test_the_served_path_gives_the_references_greedy_tokens(attn_impl):
     """Five requests on four rows and four window slots - prompts of 5 to
     150 tokens computed in chunks of at most 70 beside the rows that
     decode, fused blocks and blocks chained behind a mixed step - stream
     the reference's greedy continuation, token for token: padded steps on
-    the XLA path, and the token-packed step with the masked form of the
-    latent kernel in interpret mode (latents of 128 for its tiles)."""
+    the XLA path, and the token-packed step with the masked forms of the
+    latent kernels in interpret mode (latents of 128 for their tiles);
+    the one-token rows are counted under the form they ran in."""
     from dynamo_tpu.worker.metrics import engine_dispatch_stats
 
     hf, cfg, params = _family(**(KERNEL if attn_impl == "pallas" else {}))
@@ -610,6 +752,16 @@ async def test_the_served_path_gives_the_references_greedy_tokens(attn_impl):
             r["score_pairs"] for r in ring)
         assert stats["state_slots_in_use"] == 0.0
         assert set(stats["cache_bytes"]) == {"paged", "index", "window"}
+        # every one-token row (a decode row a step, a block's rows times
+        # its width) attended its selection in the path's one form: masked
+        # on the kernels, gathered - and none masked - on the reference path
+        form = {"pallas": "masked", "scan": "gathered"}[attn_impl]
+        assert eng.one_token_form == form
+        assert set(stats["attn_one_token_rows"]) == {form}
+        assert stats["attn_one_token_rows"][form] > eng.multistep_blocks > 0
+        assert eng.packed_attention == (
+            "chunks:mla_selected,one_token:mla_selected_rows"
+            if attn_impl == "pallas" else None)
     finally:
         await eng.stop()
 
@@ -645,6 +797,32 @@ async def test_a_slot_is_reused_and_a_preempted_row_recomputes(tiny):
         assert sched.prefix_reuse_refused == {"window_cache": 4}
     finally:
         await eng.stop()
+
+
+def test_the_one_token_rows_are_counted_by_the_engines_form(tiny):
+    """``dynamo_worker_attn_one_token_rows_total{form}`` renders both
+    forms pre-seeded; an engine counts a step's one-token rows and a
+    block's rows times its width under its own form - ``gathered`` off the
+    kernels - and an engine of a model without a selection counts
+    nothing."""
+    from prometheus_client import CollectorRegistry, generate_latest
+
+    from dynamo_tpu.worker.metrics import (EngineDispatchCollector,
+                                           engine_dispatch_stats)
+    _hf, cfg, params = tiny
+    eng = _engine(cfg, params)
+    assert eng.one_token_form == "gathered"
+    eng._count_one_token_rows(3)
+    eng._count_one_token_rows(4 * 2)
+    reg = CollectorRegistry()
+    EngineDispatchCollector(reg).attach(lambda: engine_dispatch_stats(eng))
+    text = generate_latest(reg).decode()
+    name = "dynamo_worker_attn_one_token_rows_total"
+    assert f'{name}{{form="gathered"}} 11.0' in text
+    assert f'{name}{{form="masked"}} 0.0' in text
+    eng.one_token_form = None             # what every other family's reads
+    eng._count_one_token_rows(5)
+    assert eng.attn_one_token_rows == {"gathered": 11}
 
 
 def test_the_worker_refuses_at_its_arguments_and_names_the_caches(tmp_path):

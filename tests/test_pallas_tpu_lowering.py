@@ -23,12 +23,13 @@ def _native_kernels(monkeypatch):
     """Pin interpret OFF during export: ``_resolve_interpret(None)`` keys
     off ``jax.default_backend()`` (cpu here), but these tests lower for
     the TPU platform — the kernels must take their native path."""
-    from dynamo_tpu.ops.pallas import (decode, gdn, mla_decode, mla_prefill,
+    from dynamo_tpu.ops.pallas import (decode, gdn, mla_decode,
+                                       mla_decode_masked, mla_prefill,
                                        mla_ragged, moe_grouped, prefill,
                                        ragged)
 
-    for mod in (decode, prefill, mla_decode, mla_prefill, mla_ragged,
-                ragged, moe_grouped, gdn):
+    for mod in (decode, prefill, mla_decode, mla_decode_masked, mla_prefill,
+                mla_ragged, ragged, moe_grouped, gdn):
         monkeypatch.setattr(mod, "_resolve_interpret",
                             lambda interpret: False)
 
@@ -971,10 +972,13 @@ def test_dots3_step_programs_compile_for_a_v5e():
     the published widths (abstract weights: 3,093,416,192 parameters) and
     the cell's three pools compile for a v5e: the packed step with the
     masked form of the ragged latent kernel for both attention kinds
-    (``mla_selected``, ``mla_window``) and ``moe_grouped`` in it, the fused
-    block (one-token rows: the gathered forms, plain XLA) with
-    ``moe_grouped``; neither copies the latent pages, the index pages or
-    the rings, and both fit the chip's memory."""
+    (``mla_selected``, ``mla_window``), the latent decode kernel over the
+    one-token rows' selection as a bias (``mla_selected_rows``) and
+    ``moe_grouped`` in it, the fused block (one-token rows: the selection
+    through ``mla_selected_rows``, the window out of the ring in plain
+    XLA) with ``moe_grouped``; neither sorts an axis as long as the page
+    table's tokens (the selection stays a mask), neither copies the latent
+    pages, the index pages or the rings, and both fit the chip's memory."""
     import json
     import os
 
@@ -1013,8 +1017,10 @@ def test_dots3_step_programs_compile_for_a_v5e():
     pool = (3, args["--num-pages"]) + tuple(eng.kv_pool.shape[2:])
     other = [f"bf16[3,{args['--num-pages']},16,128]",
              f"bf16[6,{rows + 1},64,2,1,16,1024]"]
-    want = {"packed": {"mla_selected", "mla_window", "moe_grouped"},
-            "fused": {"moe_grouped"}}
+    want = {"packed": {"mla_selected", "mla_selected_rows", "mla_window",
+                       "moe_grouped"},
+            "fused": {"mla_selected_rows", "moe_grouped"}}
+    table_tokens = f"{args['--max-context']}]"
     for name, kernels in want.items():
         fn, fn_args = programs[name]
         compiled = fn.lower(*fn_args).compile()
@@ -1022,6 +1028,8 @@ def test_dots3_step_programs_compile_for_a_v5e():
         calls = {ln.split("=")[0].strip().lstrip("%").split(".")[0]
                  for ln in hlo.splitlines() if "tpu_custom_call" in ln}
         assert calls == kernels, name
+        assert not [ln for ln in hlo.splitlines() if " sort(" in ln
+                    and table_tokens in ln.split(" sort(")[0]], name
         assert pool_copies(hlo, pool, eng.kv_pool.dtype) == []
         assert not [ln for ln in hlo.splitlines() if " copy(" in ln
                     and any(s in ln.split(" copy(")[0] for s in other)]
