@@ -25,6 +25,16 @@ FFN(RMSNorm(h))``.
 - **Window layer** (``cfg.window_cfg()``: other heads, head sizes, ranks,
   theta, rescales): the same latent attention over ``{s : t - swa_window <
   s <= t}``, no indexer, from a ring a sequence.
+- **On the kernels** (``Step.kernel``) each row kind of each layer kind
+  goes to a latent kernel with a bias - the rows of several tokens to
+  ``ops/pallas/mla_ragged.py`` (``mla_selected``, ``mla_window``), the
+  rows of one to ``ops/pallas/mla_decode_masked.py``
+  (``mla_selected_rows``, ``mla_window_rows``: the ring as pages) - and
+  XLA moves nothing of a step's ``T x nh x dkv`` elements around them: the
+  queries enter heads-major, scaled and cast by ``W_UK``'s own matmul
+  (``_kernel_queries``), the output leaves heads-major into ``W_UV``'s
+  (``_finish``), the one-token rows are fetched and laid in place as flat
+  rows (``_masked_rows``).
 - **Gate**, both kinds: ``gamma = sigmoid(x W_g)`` a head, multiplied onto
   the head's attention output before the out-projection.
 - **FFN**: the first ``first_k_dense_replace`` layers a SwiGLU; every
@@ -249,9 +259,9 @@ class Step:
     """What every layer of one step shares: the rows on the flat axis
     (``ops/gdn.Rows``), each token's position, the step's form, and
     whether the step runs on the latent kernels (``kernel``: the chip -
-    the masked form of a full layer's selection for every row, of a
-    window for the rows of several tokens) or everything the gathered
-    form (the CPU, the ``scan`` path, the oracle)."""
+    the masked form of a full layer's selection and of a window layer's
+    ring for every row) or everything the gathered form (the CPU, the
+    ``scan`` path, the oracle)."""
 
     def __init__(self, tokens, positions, page_table, total_lens, new_lens,
                  slots, starts, kernel: bool):
@@ -316,10 +326,11 @@ def _rope_head(cfg: ModelConfig, x: jnp.ndarray,
 
 
 def _finish(cfg: ModelConfig, lp, h, x, lat, w_uv) -> jnp.ndarray:
-    """``lat [N, nh, dkv]`` latent attention output -> ``W_UV`` expand ->
-    the headwise gate -> the out-projection residual."""
+    """``lat [nh, N, dkv]`` latent attention output, heads-major as the
+    masked kernels write it -> ``W_UV`` expand (a batched matmul over
+    heads) -> the headwise gate -> the out-projection residual."""
     B, S, H = h.shape
-    out = jnp.einsum("nhk,hkd->nhd", lat, w_uv.astype(jnp.float32))
+    out = jnp.einsum("hnk,hkd->nhd", lat, w_uv.astype(jnp.float32))
     if cfg.attn_gate:
         with jax.named_scope("gate"):
             gamma = jax.nn.sigmoid(jnp.dot(
@@ -328,6 +339,18 @@ def _finish(cfg: ModelConfig, lp, h, x, lat, w_uv) -> jnp.ndarray:
             out = out * gamma[:, :, None]
     out = out.reshape(B, S, cfg.num_heads * cfg.v_head_dim).astype(h.dtype)
     return h + out @ lp["wo"]
+
+
+def _kernel_queries(q_lat, q_pe, scale: float, dtype):
+    """``_mla_qkv``'s queries ``[B, S, nh, d]`` as the masked kernels take
+    them: heads-major ``[nh, B * S, d]`` - the layout ``W_UK``'s batched
+    matmul over heads writes, so the absorbed queries are never moved -
+    times the softmax scale and in the cache's ``dtype``, in the pass that
+    writes them (the kernels are then called with a scale of 1)."""
+    B, S, nh, _ = q_lat.shape
+    return tuple(
+        (jnp.moveaxis(q.reshape(B * S, nh, -1), 1, 0).astype(jnp.float32)
+         * scale).astype(dtype) for q in (q_lat, q_pe))
 
 
 def index_inputs(cfg: ModelConfig, lp, x: jnp.ndarray,
@@ -346,16 +369,41 @@ def index_inputs(cfg: ModelConfig, lp, x: jnp.ndarray,
             w.reshape(B * S, J))
 
 
-def _masked(st: Step, q_lat, q_pe, pool, layer, table, kv_lens, bias,
-            scale: float, name: str):
+def _masked(st: Step, q, pool, layer, table, kv_lens, bias, name: str):
     """The masked form (``ops/pallas/mla_ragged.py`` with a bias) for the
-    step's rows of several tokens: ``[N, nh, dkv]`` float32, zero in every
+    step's rows of several tokens, ``q`` the ``_kernel_queries``: ``[nh,
+    N, dkv]`` float32, heads-major as ``_finish`` reads it, zero in every
     other slot."""
-    from dynamo_tpu.ops.pallas.mla_ragged import mla_ragged_attention_packed
+    from dynamo_tpu.ops.pallas.mla_ragged import mla_masked_attention_packed
 
-    return mla_ragged_attention_packed(
-        q_lat, q_pe, pool, layer, table, st.rows.start, st.q_lens, kv_lens,
-        scale, bias=bias, name=name)
+    return mla_masked_attention_packed(
+        *q, pool, layer, table, st.rows.start, st.q_lens, kv_lens, bias,
+        1.0, name=name)
+
+
+def _masked_rows(st: Step, lat, q, pool, layer, table, kv_lens, bias,
+                 name: str):
+    """The step's rows of ONE token through the latent decode kernel with
+    a bias (``ops/pallas/mla_decode_masked.py``; ``bias [R, S]``; a row of
+    ``kv_lens`` 0 streams nothing), laid over the masked form's ``lat
+    [nh, N, dkv]`` (None: the step holds no row of several tokens) at each
+    row's slot. On the flat axis ``[nh * N]`` a row's heads lie ``N``
+    apart: its queries are fetched, and its result laid in place, as
+    ``nh`` rows of ``dkv`` - no pass over ``q`` or ``lat``."""
+    from dynamo_tpu.ops.pallas.mla_decode_masked import (
+        mla_masked_decode_stacked)
+
+    nh, N, dkv = q[0].shape
+    heads = jnp.arange(nh, dtype=jnp.int32)[None, :] * N
+    at = heads + st.first[:, None]                             # [R, nh]
+    res = mla_masked_decode_stacked(
+        *(x.reshape(nh * N, -1)[at] for x in q), pool, layer, table,
+        kv_lens, bias, 1.0, name=name)
+    if lat is None:
+        lat = jnp.zeros((nh, N, dkv), jnp.float32)
+    to = jnp.where((st.new_lens == 1)[:, None], at, nh * N)   # or nowhere
+    return lat.reshape(nh * N, dkv).at[to].set(res, mode="drop").reshape(
+        nh, N, dkv)
 
 
 def full_block(cfg: ModelConfig, lp, h, cache, lidx, st: Step):
@@ -369,7 +417,6 @@ def full_block(cfg: ModelConfig, lp, h, cache, lidx, st: Step):
         k_new, v_new = _cache_rows(cfg, c_kv, k_pe)
         x = _rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
         q_i, k_i, w_i = index_inputs(cfg, lp, x, st.positions)
-        q_lat, q_pe = q_lat.reshape(N, nh, -1), q_pe.reshape(N, nh, -1)
     with jax.named_scope("layer.kv_write"):
         kv = st.write(kv, lidx, k_new, v_new, st.page_table)
         # the index pages as a pool of one array a token
@@ -386,31 +433,29 @@ def full_block(cfg: ModelConfig, lp, h, cache, lidx, st: Step):
         scale, scopes = _mla_scale(cfg), ("index/score", "index/topk")
         q_i = q_i.astype(index.dtype)
         if st.kernel:
-            from dynamo_tpu.ops.pallas.mla_decode_masked import (
-                mla_masked_decode_stacked)
-
             one, bias = sl.select_split(
                 q_i, w_i, index, lidx, st.page_table, st.rows,
                 st.total_lens, cfg.index_topk, scopes=scopes, **st.walk)
             with jax.named_scope("sparse"):
-                lat = jnp.zeros((N, nh, q_lat.shape[-1]), jnp.float32)
+                q = _kernel_queries(q_lat, q_pe, scale, kv.dtype)
+                lat = None
                 if bias is not None:
-                    lat = _masked(st, q_lat, q_pe, kv, lidx, st.page_table,
-                                  st.total_lens, bias, scale, "mla_selected")
+                    lat = _masked(st, q, kv, lidx, st.page_table,
+                                  st.total_lens, bias, "mla_selected")
                 if one is not None:
-                    rows_bias, to = one
-                    lat = sl.lay(lat, mla_masked_decode_stacked(
-                        q_lat[st.first], q_pe[st.first], kv, lidx,
-                        st.page_table, st.one_lens, rows_bias, scale,
-                        name="mla_selected_rows"), to)
+                    rows_bias, _to = one
+                    lat = _masked_rows(st, lat, q, kv, lidx, st.page_table,
+                                       st.one_lens, rows_bias,
+                                       "mla_selected_rows")
         else:
             sel, live = sl.select(
                 q_i, w_i, index, lidx, st.page_table, st.rows,
                 st.total_lens, cfg.index_topk, scopes=scopes, **st.walk)
             with jax.named_scope("sparse"):
                 lat = sl.sparse_attend(
-                    q_lat, q_pe, kv, lidx, st.page_table[st.rows.row], sel,
-                    live & st.rows.valid[:, None], scale)
+                    q_lat.reshape(N, nh, -1), q_pe.reshape(N, nh, -1), kv,
+                    lidx, st.page_table[st.rows.row], sel,
+                    live & st.rows.valid[:, None], scale).swapaxes(0, 1)
         h = _finish(cfg, lp, h, x, lat, w_uv)
     return h, {**cache, "kv": kv, "index": index}
 
@@ -428,7 +473,6 @@ def window_block(wcfg: ModelConfig, lp, h, cache, widx, st: Step):
         q_lat, q_pe, c_kv, k_pe, w_uv = _mla_qkv(wcfg, lp, h, st.positions)
         k_new, v_new = _cache_rows(wcfg, c_kv, k_pe)
         x = _rms_norm(h, lp["attn_norm"], wcfg.rms_norm_eps)
-        q_lat, q_pe = q_lat.reshape(N, nh, -1), q_pe.reshape(N, nh, -1)
     with jax.named_scope("layer.kv_write"):
         pool = st.write(win.reshape(Lw, n_slots * Rp, 2, 1, ps, dkv), widx,
                         k_new, v_new,
@@ -437,20 +481,28 @@ def window_block(wcfg: ModelConfig, lp, h, cache, widx, st: Step):
     with jax.named_scope("layer.attn"):
         with jax.named_scope("window"):
             scale = _mla_scale(wcfg)
-            walk = dict(st.walk, only_one_token=st.kernel)
-            lat = jnp.zeros((N, nh, dkv), jnp.float32)
-            if st.one_token or not st.kernel:
-                lat = sl.window_attend(q_lat, q_pe, win, widx, st.rows,
-                                       st.total_lens, wcfg.swa_window,
-                                       scale, **walk)
-            if st.kernel and st.width > 1:
-                seen = sl.ring_seen(st.rows, st.pos, st.total_lens, ring,
-                                    wcfg.swa_window)
-                lat = lat + _masked(
-                    st, q_lat, q_pe, pool, widx,
-                    sl.ring_table(st.rows.slot, Rp),
-                    jnp.minimum(st.total_lens, ring),
-                    jnp.where(seen, 0.0, sl.NEG_INF), scale, "mla_window")
+            if st.kernel:
+                # the rings as pages: each row's ring streamed with its
+                # entries' true positions as the bias, both row kinds
+                table = sl.ring_table(st.rows.slot, Rp)
+                bias = jnp.where(
+                    sl.ring_seen(st.rows, st.pos, st.total_lens, ring,
+                                 wcfg.swa_window), 0.0, sl.NEG_INF)
+                q = _kernel_queries(q_lat, q_pe, scale, pool.dtype)
+                lat = None
+                if st.width > 1:
+                    lat = _masked(st, q, pool, widx, table,
+                                  jnp.minimum(st.total_lens, ring), bias,
+                                  "mla_window")
+                if st.one_token:
+                    lat = _masked_rows(st, lat, q, pool, widx, table,
+                                       jnp.minimum(st.one_lens, ring),
+                                       bias[st.first], "mla_window_rows")
+            else:
+                lat = sl.window_attend(
+                    q_lat.reshape(N, nh, -1), q_pe.reshape(N, nh, -1), win,
+                    widx, st.rows, st.total_lens, wcfg.swa_window, scale,
+                    **st.walk).swapaxes(0, 1)
         h = _finish(wcfg, lp, h, x, lat, w_uv)
     return h, {**cache, "win": win}
 
@@ -514,11 +566,11 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     never called: its ``pallas_paged_kernel`` marker opts the family into
     the masked form of both attention kinds for the rows of several
     tokens (``ops/pallas/mla_ragged.py`` with a bias: ``mla_selected``,
-    ``mla_window`` in a device trace), of the selection for the rows of
-    one (``ops/pallas/mla_decode_masked.py``: ``mla_selected_rows``) and
-    into ``moe_grouped``. No ``logits_window``: a verify window or a scoring
-    pass would have to take back what a rejected token wrote to a ring,
-    so the engine offers neither."""
+    ``mla_window`` in a device trace) and for the rows of one
+    (``ops/pallas/mla_decode_masked.py``: ``mla_selected_rows``,
+    ``mla_window_rows``) and into ``moe_grouped``. No ``logits_window``:
+    a verify window or a scoring pass would have to take back what a
+    rejected token wrote to a ring, so the engine offers neither."""
     from dynamo_tpu.models.moe import (grouped_on_chip, split_experts,
                                        sum_aux, token_slots)
 

@@ -15,8 +15,10 @@ over layers), so a step program compiles one layer body:
   ``mla_prefill.mla_paged_prefill_stacked`` /
   ``mla_ragged.mla_ragged_attention_packed`` — the same three step forms
   over DeepSeek's latent cache;
-  ``mla_decode_masked.mla_masked_decode_stacked`` — the decode step of a
-  model that attends a learned selection: a bias over the streamed context.
+  ``mla_ragged.mla_masked_attention_packed`` /
+  ``mla_decode_masked.mla_masked_decode_stacked`` — the rows of several
+  tokens and of one of a model that attends a learned selection or a
+  window ring: a bias over the streamed context.
 
 The XLA implementations in ``dynamo_tpu.ops.attention`` remain the portable
 reference (CPU tests).

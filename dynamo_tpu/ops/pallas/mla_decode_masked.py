@@ -1,6 +1,9 @@
 """Latent (MLA) paged attention of ONE-TOKEN rows over a MASK of their
 context on TPU — the decode-step kernel of a model whose queries attend a
-learned selection of their keys (``models/dots3.py``).
+learned selection of their keys, or the entries of a window ring by their
+true positions (``models/dots3.py``: both layer kinds, ``mla_selected_rows``
+over a row's pages and ``mla_window_rows`` over its ring handed over as
+pages).
 
 The shape is ``ops/pallas/mla_decode.py``'s: one grid program a row, the
 row's pages of the 2-slot latent cache ``[L, N, 2, 1, ps, dkv]`` streamed
@@ -23,7 +26,12 @@ and values in latent space, an online softmax in f32. What differs is what
   columns that are not padding.
 - **A row of length 0 streams nothing** and comes back zero: the rows of a
   token-packed step that bring a prompt chunk (the ragged kernel's) enter
-  so.
+  so, and a step pays for the one-token rows it carries, not for its
+  table's.
+- **Two query operands**, the absorbed latent query ``[R, nh, dkv]`` and
+  the rotary query ``[R, nh, rope]`` over the rotary slot's columns that
+  are not padding (whole lanes): nothing is stacked and nothing padded to
+  the latent's width on the way in.
 
 The kernel's name in a device trace is the caller's (``name``).
 """
@@ -39,29 +47,30 @@ from jax.experimental.pallas import tpu as pltpu
 
 from dynamo_tpu.ops.pallas.decode import NEG_INF, _resolve_interpret
 from dynamo_tpu.ops.pallas.mla_decode import supports  # noqa: F401
-from dynamo_tpu.ops.pallas.mla_ragged import BIASED_PAGES_PER_CHUNK
+from dynamo_tpu.ops.pallas.mla_ragged import (BIASED_PAGES_PER_CHUNK,
+                                              pad_rope)
 
 
-def _kernel(q2_ref, kv_hbm, layer_ref, table_ref, lens_ref, bias_ref,
-            out_ref, buf, sem, *, page_size: int, chunk: int,
-            rope_dim: int):
+def _kernel(ql_ref, qp_ref, kv_hbm, layer_ref, table_ref, lens_ref,
+            bias_ref, out_ref, buf, sem, *, page_size: int, chunk: int):
     """One program a row.
 
-    q2_ref:   [1, 2, nh, dkv] — slot 0 the absorbed latent query, slot 1
-              the rotary query zero-padded to dkv; pre-scaled.
+    ql_ref:   [1, nh, dkv] the absorbed latent query; qp_ref: [1, nh,
+              rope] the rotary query over the rotary slot's columns that
+              are not padding; both pre-scaled.
     kv_hbm:   [L, N, 2, 1, ps, dkv] stacked latent cache (ANY).
     bias_ref: [1, chunks, span] float32, the row's bias a chunk a line.
     buf:      [2, 2, 1, span, dkv] double-buffered slabs; sem [2, chunk].
-    ``rope_dim``: the columns of the rotary slot that are not padding.
     """
     b = pl.program_id(0)
     layer = layer_ref[0]
     ctx = lens_ref[b]
     span = chunk * page_size
     num_chunks = jax.lax.div(ctx + span - 1, span)
-    nh, dkv = q2_ref.shape[2], q2_ref.shape[3]
-    q_lat = q2_ref[0, 0]                                   # [nh, dkv]
-    q_pe = q2_ref[0, 1][:, :rope_dim]
+    _one, nh, dkv = ql_ref.shape
+    rope_dim = qp_ref.shape[2]
+    q_lat = ql_ref[0]                                      # [nh, dkv]
+    q_pe = qp_ref[0]
     P = table_ref.shape[1]
 
     def page_dma(slot, i, j):
@@ -129,12 +138,10 @@ def _kernel(q2_ref, kv_hbm, layer_ref, table_ref, lens_ref, bias_ref,
     out_ref[0] = (acc / jnp.maximum(l, 1e-20)).astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret", "name",
-                                             "rope_dim"))
-def _mla_decode_masked(q2, kv_pages, layer_idx, page_table, lens, bias,
-                       sm_scale: float, interpret: bool, name: str,
-                       rope_dim: int):
-    R, _two, nh, dkv = q2.shape
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret", "name"))
+def _mla_decode_masked(q_lat, q_pe, kv_pages, layer_idx, page_table, lens,
+                       bias, sm_scale: float, interpret: bool, name: str):
+    R, nh, dkv = q_lat.shape
     _L, _N, _2, _one, page_size, _ = kv_pages.shape
     P = page_table.shape[1]
     chunk = min(BIASED_PAGES_PER_CHUNK, P)
@@ -146,11 +153,11 @@ def _mla_decode_masked(q2, kv_pages, layer_idx, page_table, lens, bias,
                 constant_values=NEG_INF).reshape(R, n_chunks, span)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
-        functools.partial(_kernel, page_size=page_size, chunk=chunk,
-                          rope_dim=rope_dim),
+        functools.partial(_kernel, page_size=page_size, chunk=chunk),
         grid=(R,),
         in_specs=[
-            pl.BlockSpec((1, 2, nh, dkv), lambda r: (r, 0, 0, 0)),
+            pl.BlockSpec((1, nh, dkv), lambda r: (r, 0, 0)),
+            pl.BlockSpec((1, nh, q_pe.shape[2]), lambda r: (r, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             smem, smem, smem,
             pl.BlockSpec((1, n_chunks, span), lambda r: (r, 0, 0)),
@@ -163,8 +170,8 @@ def _mla_decode_masked(q2, kv_pages, layer_idx, page_table, lens, bias,
         out_shape=jax.ShapeDtypeStruct((R, nh, dkv), jnp.float32),
         interpret=interpret,
         name=name,
-    )((q2 * sm_scale).astype(kv_pages.dtype), kv_pages, layer_idx,
-      page_table, lens, b)
+    )(*((x.astype(jnp.float32) * sm_scale).astype(kv_pages.dtype)
+        for x in (q_lat, q_pe)), kv_pages, layer_idx, page_table, lens, b)
 
 
 def mla_masked_decode_stacked(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
@@ -191,15 +198,11 @@ def mla_masked_decode_stacked(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
     Returns the latent attention output [R, nh, dkv] in f32, zero for a
     row that attends no key.
     """
-    dkv, dr = q_lat.shape[-1], q_pe.shape[-1]
-    q_pe_pad = jnp.pad(q_pe, ((0, 0), (0, 0), (0, dkv - dr)))
-    q2 = jnp.stack([q_lat, q_pe_pad.astype(q_lat.dtype)], axis=1)
     layer = jnp.asarray(layer_idx, jnp.int32).reshape(1)
     return _mla_decode_masked(
-        q2, pages, layer, page_table.astype(jnp.int32),
-        lens.astype(jnp.int32), bias, sm_scale,
-        interpret=_resolve_interpret(interpret), name=name,
-        rope_dim=min(dkv, -(-dr // 128) * 128))
+        q_lat, pad_rope(q_pe, q_lat.shape[-1]), pages, layer,
+        page_table.astype(jnp.int32), lens.astype(jnp.int32), bias, sm_scale,
+        interpret=_resolve_interpret(interpret), name=name)
 
 
 __all__ = ["mla_masked_decode_stacked", "supports"]

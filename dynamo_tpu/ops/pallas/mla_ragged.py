@@ -34,7 +34,14 @@ The shape is ``ops/pallas/ragged.py``'s, the mathematics
   ``[chunks, T, span]`` so that a chunk's block is one leading index, and
   a chunk is 32 pages where the unbiased kernel's is 8
   (``BIASED_PAGES_PER_CHUNK``: 21.3 -> 10.8 ms a layer for 512 queries of
-  128 heads over 8,192 keys on a v5e).
+  128 heads over 8,192 keys on a v5e). Queries and output are
+  HEADS-MAJOR there, the layout their neighbours hold: the absorbed
+  queries ``[nh, T, dkv]`` as ``W_UK``'s batched matmul writes them and
+  the rotary queries ``[nh, T, rope]`` as two operands (no stack, the
+  rotary half padded to whole lanes and not to the latent's width), the
+  output ``[nh, T, dkv]`` as the accumulator lies and as ``W_UV``'s
+  batched matmul reads it - no pass over ``T * nh * dkv`` elements in XLA
+  on either side of the call.
 
 The pure-JAX reference over the same layout, and the CPU-test oracle, is
 ``models.deepseek.mla_ragged_attention``; CPU tests of this kernel run in
@@ -69,20 +76,19 @@ BIASED_VMEM_STACK = 48 * 2**20
 BIASED_VMEM_LIMIT = 96 * 2**20
 
 
-def _mla_ragged_kernel(q2_ref, kv_hbm, layer_ref, table_ref, rows_ref,
-                       qstart_ref, qlen_ref, lens_ref, *rest,
-                       page_size: int, chunk: int, q_block: int,
-                       biased: bool = False, rope_dim: int = 0):
+def _mla_ragged_kernel(*refs, page_size: int, chunk: int, q_block: int,
+                       biased: bool = False):
     """One program per block of ``SB`` packed slots.
 
-    ``rest``: ``[bias_ref]`` (``biased``: ``[chunks, SB, span]`` float32,
-    added to every head's scores; the causal mask is then the bias's),
-    then the output and the scratch buffers below. ``rope_dim`` (a
-    multiple of 128, or 0: the whole width): the columns of the rotary
-    slot that are not padding.
+    ``refs``: the queries, the cache and the rows' scalars, ``bias_ref``
+    (``biased``: ``[chunks, SB, span]`` float32, added to every head's
+    scores; the causal mask is then the bias's), the output and the
+    scratch buffers.
 
-    q2_ref:  [2, SB, nh, dkv] — slot 0 = absorbed latent queries, slot 1 =
-             roped queries zero-padded to dkv; pre-scaled.
+    q2_ref:  [2, SB, nh, dkv] - slot 0 = absorbed latent queries, slot 1 =
+             roped queries zero-padded to dkv; pre-scaled. ``biased``: two
+             refs, heads-major: ``ql_ref [nh, SB, dkv]`` and ``qp_ref [nh,
+             SB, rope]``, the rotary slot's columns that are not padding.
     kv_hbm:  [L, N, 2, 1, ps, dkv] stacked latent cache (ANY).
     rows_ref [2, n_blocks]: the first row with a slot in the block and one
              past the last; qstart/qlen/lens [R]: a row's first slot, its
@@ -90,24 +96,37 @@ def _mla_ragged_kernel(q2_ref, kv_hbm, layer_ref, table_ref, rows_ref,
     buf:     [2, 2, 1, chunk*ps, dkv] double-buffered slabs.
     m/l [nh*SB, 1], acc [nh*SB, dkv]: the running softmax state of the
              block's slots, heads-major.
-    out_ref: [SB, nh, dkv] latent attention output in f32.
+    out_ref: [SB, nh, dkv] latent attention output in f32; ``biased``:
+             ``[nh, SB, dkv]``, as the accumulator lies.
 
     A row without slots in the block runs its chunk loop zero times, so no
     page DMA is armed and no matmul runs (the skip rides the loop bounds:
     Mosaic cannot lower the layout transposes inside a ``pl.when``)."""
-    bias_ref = rest[0] if biased else None
-    out_ref, buf, sem, m_ref, l_ref, acc_ref = rest[biased:]
+    q_refs, refs = refs[:1 + biased], refs[1 + biased:]
+    (kv_hbm, layer_ref, table_ref, rows_ref, qstart_ref, qlen_ref,
+     lens_ref) = refs[:7]
+    bias_ref = refs[7] if biased else None
+    out_ref, buf, sem, m_ref, l_ref, acc_ref = refs[7 + biased:]
     i = pl.program_id(0)
     layer = layer_ref[0]
     SB = q_block
-    nh, dkv = q2_ref.shape[2], q2_ref.shape[3]
     span = chunk * page_size
     P = table_ref.shape[1]
     t0 = i * SB
 
-    # [2, nh*SB, dkv]: heads-major rows so the slot-batched dot has one
-    # contracting dim (Mosaic) and M = nh*SB fills the MXU
-    q2 = q2_ref[...].transpose(0, 2, 1, 3).reshape(2, nh * SB, dkv)
+    # heads-major rows so the slot-batched dot has one contracting dim
+    # (Mosaic) and M = nh*SB fills the MXU
+    if biased:
+        ql_ref, qp_ref = q_refs
+        nh, _sb, dkv = ql_ref.shape
+        rope_dim = qp_ref.shape[2]
+        q_lat = ql_ref[...].reshape(nh * SB, dkv)
+        q_pe = qp_ref[...].reshape(nh * SB, rope_dim)
+    else:
+        q2_ref, = q_refs
+        nh, dkv = q2_ref.shape[2], q2_ref.shape[3]
+        # [2, nh*SB, dkv]
+        q2 = q2_ref[...].transpose(0, 2, 1, 3).reshape(2, nh * SB, dkv)
     # the packed slot of each query of the block
     slot_t = t0 + jax.lax.broadcasted_iota(jnp.int32, (1, SB, 1), 1)
     m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
@@ -168,14 +187,14 @@ def _mla_ragged_kernel(q2_ref, kv_hbm, layer_ref, table_ref, rows_ref,
             wait_chunk(slot, c)
             kv = buf[slot, :, 0]                           # [2, span, dkv]
 
-            if rope_dim:
+            if biased:
                 # the rotary slot's first ``rope_dim`` columns hold all
                 # of it: two dots, the second a quarter as deep or less
                 dims = (((1,), (1,)), ((), ()))
                 s3 = (jax.lax.dot_general(
-                    q2[0], kv[0], dims, preferred_element_type=jnp.float32)
+                    q_lat, kv[0], dims, preferred_element_type=jnp.float32)
                     + jax.lax.dot_general(
-                        q2[1][:, :rope_dim], kv[1][:, :rope_dim], dims,
+                        q_pe, kv[1][:, :rope_dim], dims,
                         preferred_element_type=jnp.float32)
                       ).reshape(nh, SB, span)
             else:
@@ -214,26 +233,33 @@ def _mla_ragged_kernel(q2_ref, kv_hbm, layer_ref, table_ref, rows_ref,
     jax.lax.fori_loop(rows_ref[0, i], rows_ref[1, i], one_row, 0)
     # slots of no row kept acc == 0, l == 0: zeros, a deterministic output
     # for the parity oracle
-    out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)    # [nh*SB, dkv]
-    out_ref[...] = out.reshape(nh, SB, dkv).transpose(1, 0, 2) \
-        .astype(out_ref.dtype)
+    out = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)   # [nh*SB, dkv]
+           ).reshape(nh, SB, dkv)
+    if not biased:
+        out = out.transpose(1, 0, 2)
+    out_ref[...] = out.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("sm_scale", "interpret", "name",
-                                    "rope_dim"))
-def _mla_ragged(q2, kv_pages, layer_idx, page_table, q_starts, q_lens,
+                   static_argnames=("sm_scale", "interpret", "name"))
+def _mla_ragged(q, kv_pages, layer_idx, page_table, q_starts, q_lens,
                 kv_lens, sm_scale: float, interpret: bool = False,
-                bias=None, name: str = "mla_ragged", rope_dim: int = 0):
-    _two, T, nh, dkv = q2.shape
+                bias=None, name: str = "mla_ragged"):
+    """``q``: the stacked queries ``[2, T, nh, dkv]``; with a ``bias`` the
+    pair ``(q_lat [nh, T, dkv], q_pe [nh, T, rope])``, and the output is
+    ``[nh, T, dkv]``."""
+    biased = bias is not None
+    if biased:
+        nh, T, dkv = q[0].shape
+    else:
+        _two, T, nh, dkv = q.shape
     _L, _N, _2, _one, page_size, _ = kv_pages.shape
     P = page_table.shape[1]
-    chunk = min(PAGES_PER_CHUNK if bias is None else BIASED_PAGES_PER_CHUNK,
-                P)
+    chunk = min(BIASED_PAGES_PER_CHUNK if biased else PAGES_PER_CHUNK, P)
     span = chunk * page_size
     slab_bytes = 2 * 2 * span * dkv * kv_pages.dtype.itemsize
     n_chunks = -(-P // chunk)
-    if bias is None:
+    if not biased:
         SB = _query_block(T, nh, dkv, span, slab_bytes)
     else:
         # a slot's bias block, double-buffered, beside the rest
@@ -242,10 +268,23 @@ def _mla_ragged(q2, kv_pages, layer_idx, page_table, q_starts, q_lens,
         while SB > 8 and nh * SB * per_row + slab_bytes > BIASED_VMEM_STACK:
             SB = max(8, SB // 2)
     n_blocks = -(-T // SB)
+    pad = n_blocks * SB - T
     # sm_scale rides the packed queries (the kernel's matmuls see it once)
-    qs = (q2 * sm_scale).astype(kv_pages.dtype)
-    if n_blocks * SB != T:
-        qs = jnp.pad(qs, ((0, 0), (0, n_blocks * SB - T), (0, 0), (0, 0)))
+    if biased:
+        # ... in the pass that writes them: each operand scaled and cast
+        # where it is produced, nothing stacked, nothing moved
+        qs = tuple((x.astype(jnp.float32) * sm_scale).astype(kv_pages.dtype)
+                   for x in q)
+        if pad:
+            qs = tuple(jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in qs)
+        q_specs = [pl.BlockSpec((nh, SB, x.shape[2]), lambda i: (0, i, 0))
+                   for x in qs]
+    else:
+        qs = (q * sm_scale).astype(kv_pages.dtype)
+        if pad:
+            qs = jnp.pad(qs, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        qs = (qs,)
+        q_specs = [pl.BlockSpec((2, SB, nh, dkv), lambda i: (0, i, 0, 0))]
     # the rows with slots in each block: rows are packed in order, so
     # those whose end lies past the block's start and whose start lies
     # before its end
@@ -255,29 +294,32 @@ def _mla_ragged(q2, kv_pages, layer_idx, page_table, q_starts, q_lens,
         jnp.sum((q_starts[None, :] < t0 + SB), axis=1)]).astype(jnp.int32)
 
     kernel = functools.partial(_mla_ragged_kernel, page_size=page_size,
-                               chunk=chunk, q_block=SB,
-                               biased=bias is not None, rope_dim=rope_dim)
+                               chunk=chunk, q_block=SB, biased=biased)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     extra, extra_specs = (), []
-    if bias is not None:
+    if biased:
         # [T, S] -> [chunks, T, span]: keys past the table's read NEG_INF
         S = P * page_size
         b = jnp.pad(bias.astype(jnp.float32),
-                    ((0, n_blocks * SB - T), (0, n_chunks * span - S)),
+                    ((0, pad), (0, n_chunks * span - S)),
                     constant_values=NEG_INF)
         extra = (b.reshape(n_blocks * SB, n_chunks, span)
                  .transpose(1, 0, 2),)
         extra_specs = [pl.BlockSpec((n_chunks, SB, span),
                                     lambda i: (0, i, 0))]
+        out_spec = pl.BlockSpec((nh, SB, dkv), lambda i: (0, i, 0))
+        out_shape = (nh, n_blocks * SB, dkv)
+    else:
+        out_spec = pl.BlockSpec((SB, nh, dkv), lambda i: (i, 0, 0))
+        out_shape = (n_blocks * SB, nh, dkv)
     out = pl.pallas_call(
         kernel,
         grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((2, SB, nh, dkv), lambda i: (0, i, 0, 0)),
+        in_specs=q_specs + [
             pl.BlockSpec(memory_space=pl.ANY),
             smem, smem, smem, smem, smem, smem,
         ] + extra_specs,
-        out_specs=pl.BlockSpec((SB, nh, dkv), lambda i: (i, 0, 0)),
+        out_specs=out_spec,
         scratch_shapes=[
             pltpu.VMEM((2, 2, 1, chunk * page_size, dkv), kv_pages.dtype),
             pltpu.SemaphoreType.DMA((2, chunk)),
@@ -285,16 +327,15 @@ def _mla_ragged(q2, kv_pages, layer_idx, page_table, q_starts, q_lens,
             pltpu.VMEM((nh * SB, 1), jnp.float32),
             pltpu.VMEM((nh * SB, dkv), jnp.float32),
         ],
-        out_shape=jax.ShapeDtypeStruct((n_blocks * SB, nh, dkv),
-                                       jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
         interpret=interpret,
         name=name,
-        **({} if bias is None else {
+        **({} if not biased else {
             "compiler_params": pltpu.CompilerParams(
                 vmem_limit_bytes=BIASED_VMEM_LIMIT)}),
-    )(qs, kv_pages, layer_idx, page_table, rows, q_starts, q_lens, kv_lens,
+    )(*qs, kv_pages, layer_idx, page_table, rows, q_starts, q_lens, kv_lens,
       *extra)
-    return out[:T]
+    return out[:, :T] if biased else out[:T]
 
 
 def mla_ragged_attention_packed(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
@@ -303,7 +344,6 @@ def mla_ragged_attention_packed(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
                                 q_starts: jnp.ndarray, q_lens: jnp.ndarray,
                                 kv_lens: jnp.ndarray, sm_scale: float,
                                 interpret: bool | None = None,
-                                bias: jnp.ndarray | None = None,
                                 name: str = "mla_ragged") -> jnp.ndarray:
     """Latent paged attention of a token-packed step over the stacked MLA
     cache (drop-in for ``models.deepseek.mla_ragged_attention``).
@@ -320,10 +360,6 @@ def mla_ragged_attention_packed(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
     q_lens:     [R] real query tokens per row (a decode row is 1, a pad
                 row 0)
     kv_lens:    [R] context per row including its new tokens
-    bias:       [T, P * ps] float32 or None: added to every head's scores
-                of a slot against its row's keys; with it every key below
-                ``kv_lens`` is attended and the bias alone masks (module
-                docstring)
     name:       the kernel's name in a device trace
 
     Returns the latent attention output [T, nh, dkv] in f32 — feed to
@@ -336,12 +372,48 @@ def mla_ragged_attention_packed(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
     return _mla_ragged(q2, pages, layer, page_table.astype(jnp.int32),
                        q_starts.astype(jnp.int32), q_lens.astype(jnp.int32),
                        kv_lens.astype(jnp.int32), sm_scale,
+                       interpret=_resolve_interpret(interpret), name=name)
+
+
+def pad_rope(q_pe: jnp.ndarray, dkv: int) -> jnp.ndarray:
+    """``q_pe [..., dr]`` zero-padded to the rotary slot's columns the
+    masked kernels read: whole lanes (128), never past the latent's
+    width."""
+    dr = q_pe.shape[-1]
+    rope = min(dkv, -(-dr // 128) * 128)
+    return jnp.pad(q_pe, ((0, 0),) * (q_pe.ndim - 1) + ((0, rope - dr),))
+
+
+def mla_masked_attention_packed(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
+                                pages: jnp.ndarray, layer_idx,
+                                page_table: jnp.ndarray,
+                                q_starts: jnp.ndarray, q_lens: jnp.ndarray,
+                                kv_lens: jnp.ndarray, bias: jnp.ndarray,
+                                sm_scale: float,
+                                interpret: bool | None = None,
+                                name: str = "mla_masked") -> jnp.ndarray:
+    """The masked form (module docstring): ``mla_ragged_attention_packed``
+    with a bias and HEADS-MAJOR queries and output.
+
+    q_lat:      [nh, T, dkv] absorbed latent queries (f32 ok; scaled and
+                cast in, in the pass that produces them)
+    q_pe:       [nh, T, dr] roped queries
+    bias:       [T, P * ps] float32, added to every head's scores of a
+                slot against its row's keys: every key below ``kv_lens``
+                is attended and the bias alone masks
+    (the rest as ``mla_ragged_attention_packed``)
+
+    Returns the latent attention output [nh, T, dkv] in f32, zero for the
+    slots of no row: ``W_UV``'s expand is a batched matmul over heads.
+    """
+    layer = jnp.asarray(layer_idx, jnp.int32).reshape(1)
+    return _mla_ragged((q_lat, pad_rope(q_pe, q_lat.shape[-1])), pages,
+                       layer, page_table.astype(jnp.int32),
+                       q_starts.astype(jnp.int32), q_lens.astype(jnp.int32),
+                       kv_lens.astype(jnp.int32), sm_scale,
                        interpret=_resolve_interpret(interpret), bias=bias,
-                       name=name,
-                       # (the masked form alone: every other family's
-                       # kernel is the one it was)
-                       rope_dim=(-(-dr // 128) * 128
-                                 if bias is not None and dkv > 128 else 0))
+                       name=name)
 
 
-__all__ = ["mla_ragged_attention_packed", "supports"]
+__all__ = ["mla_ragged_attention_packed", "mla_masked_attention_packed",
+           "supports"]

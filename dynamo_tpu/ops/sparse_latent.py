@@ -1,7 +1,10 @@
 """Latent attention over a LEARNED SELECTION of the context, and over a
 WINDOW kept in a ring: the two cache-reading mechanisms of
-``models/dots3.py``, in plain XLA. The equations, then how a step's rows
-are walked.
+``models/dots3.py``: the selection, the ring's arithmetic, and both
+attentions in plain XLA (the GATHERED forms: the path without kernels and
+the tests' oracle; on the chip every row of either kind is attended by a
+latent kernel with a bias that this module makes). The equations, then how
+a step's rows are walked.
 
 **The indexer** (a full-attention layer). Every token caches one index key
 ``k_s`` (``D`` wide) in index pages ``[L, N, ps, D]`` addressed by the
@@ -43,7 +46,11 @@ dkv]`` (slot ``s`` owns pages ``s * R / ps ..``): token ``p`` lives at ``p
 mod R`` and is overwritten by token ``p + R``. A query at ``p`` attends
 ``{s : p - window < s <= p}``; ring entry ``j`` holds token ``last -
 ((last - j) mod R)`` (``last`` the newest position written), which is
-masked by its TRUE position (``ring_seen``). A step writes a row's new
+masked by its TRUE position (``ring_seen``: as a bias it is what the
+masked kernels take with the ring's pages as a page table of its own,
+``ring_table`` - ``mla_window`` for a row of several tokens,
+``mla_window_rows`` for the rows of one; ``window_attend`` is the same sum
+read out of the ring in XLA). A step writes a row's new
 tokens before it attends, so ``R >= window - 1 + (the most tokens a row
 brings in one step)`` keeps every key a query of the same step still needs
 (``ring_size``). The ring's pages are written by the page-granular write
@@ -435,14 +442,11 @@ def sparse_attend(q_lat: jnp.ndarray, q_pe: jnp.ndarray, pool: jnp.ndarray,
 
 def window_attend(q_lat: jnp.ndarray, q_pe: jnp.ndarray, ring: jnp.ndarray,
                   layer, rows: Rows, total_lens: jnp.ndarray, window: int,
-                  scale: float, *, width: int, packed: bool,
-                  only_one_token: bool = False) -> jnp.ndarray:
+                  scale: float, *, width: int, packed: bool) -> jnp.ndarray:
     """The gathered form of the window: latent attention of every token
     over the last ``window`` tokens of its row (its own among them), read
     from the row's ring pages ``[L, slots, R / ps, 2, 1, ps, dkv]``, this
-    step's tokens already written. Returns ``[N, nh, dkv]`` float32;
-    ``only_one_token``: the rows of several tokens are left zero (the
-    masked form has them)."""
+    step's tokens already written. Returns ``[N, nh, dkv]`` float32."""
     N, nh, dkv = q_lat.shape
     dr = q_pe.shape[-1]
     _L, n_slots, Rp, _two, _one, ps, _d = ring.shape
@@ -463,12 +467,8 @@ def window_attend(q_lat: jnp.ndarray, q_pe: jnp.ndarray, ring: jnp.ndarray,
         return _softmax_latent(ql.astype(dt), qp.astype(dt), c, kr, seen,
                                scale)
 
-    out = jnp.zeros((N, nh, dkv), jnp.float32)
-    if only_one_token:
-        return lay(out, *one_token_rows(row, rows, total_lens,
-                                        (q_lat, q_pe)))
-    return _by_rows(row, rows, total_lens, (q_lat, q_pe), out, width,
-                    packed)
+    return _by_rows(row, rows, total_lens, (q_lat, q_pe),
+                    jnp.zeros((N, nh, dkv), jnp.float32), width, packed)
 
 
 __all__ = ["ring_size", "ring_table", "ring_seen", "token_positions",

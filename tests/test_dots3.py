@@ -382,14 +382,24 @@ def test_chunked_prefill_then_decode_agrees_with_the_reference(tiny):
         np.testing.assert_allclose(lg, want[p], atol=2e-5, err_msg=str(p))
 
 
-def test_chunked_prefill_equals_whole_prefill_across_a_ring_wrap(tiny):
+@pytest.mark.parametrize("form", ["gathered", "masked"])
+def test_chunked_prefill_equals_whole_prefill_across_a_ring_wrap(tiny, form):
     """The same 160 tokens in one step of 160 (a ring of 256) and in
-    chunks of 48 (a ring of 128, wrapped twice): the last logits agree."""
-    _hf, cfg, params = tiny
-    tokens = np.random.default_rng(1).integers(0, 512, 160).tolist()
-    whole = _serve(cfg, params, tokens, [160])
-    parts = _serve(cfg, params, tokens, [48, 48, 48, 16])
-    np.testing.assert_allclose(parts[159], whole[159], atol=2e-5)
+    chunks of 48 (a ring of 128, wrapped twice: the second chunk is laid
+    across the ring's end), then four tokens one at a time, padded and
+    packed in turn: the logits agree. ``masked``: the chunks on the
+    kernels (interpret mode, at the widths they tile) - ``mla_window``
+    over a chunk that wraps, ``mla_window_rows`` for the tokens after it -
+    against the whole prompt in the gathered form."""
+    _hf, cfg, params = tiny if form == "gathered" else _family(**KERNEL)
+    impl, ps = (None, 4) if form == "gathered" else (_Kernels(), 8)
+    tokens = np.random.default_rng(1).integers(0, 512, 164).tolist()
+    whole = _serve(cfg, params, tokens, [160], ps=ps)
+    parts = _serve(cfg, params, tokens, [48, 48, 48, 16], ps=ps, impl=impl)
+    assert sorted(parts)[-5:] == [159, 160, 161, 162, 163]
+    for p in range(159, 164):
+        np.testing.assert_allclose(parts[p], whole[p], atol=3e-5,
+                                   err_msg=str(p))
 
 
 @pytest.fixture(scope="module")
@@ -408,7 +418,10 @@ def filled():
     for r in range(R):
         table[r, :P] = 1 + r * P + np.arange(P)
         table[r, -1] = r + 1
-    have = [100, 131, 60, 150]           # tokens each row holds already
+    # tokens each row holds already, against rings of 128: one young, one
+    # a chunk short of the ring's end, one past its first wrap, one whose
+    # next token is the first to wrap
+    have = [60, 112, 131, 128]
     fwd = jax.jit(
         lambda t, pos, pg, tb, tot, new, impl, packed: dots3.forward(
             params, cfg, t, pos, pg, tb, tot, new, attn_impl=impl,
@@ -427,16 +440,22 @@ def filled():
                                     jnp.asarray([s + m]), jnp.asarray([m]),
                                     None, False)
                 s += m
-    return cfg, pages, table, have, fwd, rng
+    return cfg, pages, table, have, fwd, rng, params
 
 
 # a step's rows by the tokens each brings: two chunk rows beside two
 # one-token rows back to back (a packed step: the chunk rows' slots come
-# back zero from the one-token kernel and are filled by ``mla_selected``),
-# the same as ``[B, S]`` rows, and a decode step ``[B, 1]`` (the fused
-# block's: every row of one token, one of them dead)
+# back zero from the one-token kernels and are filled by ``mla_selected``
+# and ``mla_window``; the first chunk is laid across its ring's end), the
+# same as ``[B, S]`` rows, a decode step ``[B, 1]`` (the fused block's:
+# every row of one token - one in a young ring, one after its ring's wrap,
+# one AT it - and one of them dead), a packed step of one-token rows alone,
+# and one whose one-token rows sit on both sides of a chunk that wraps,
+# beside a row of no token
 STEPS = {"padded": ([24, 17, 9, 1], 24), "packed": ([24, 17, 1, 1], None),
-         "decode": ([1, 1, 0, 1], 1)}
+         "decode": ([1, 0, 1, 1], 1),
+         "packed_rows_of_one_token": ([1, 1, 1, 1], None),
+         "packed_rows_around_a_wrapping_chunk": ([1, 24, 0, 1], None)}
 
 
 @pytest.mark.parametrize("step", sorted(STEPS))
@@ -444,9 +463,10 @@ def test_the_masked_kernel_form_equals_the_gathered_form(filled, step):
     """A step against pools that earlier steps filled: the masked forms
     (interpret mode: ``mla_ragged`` with a bias for the rows of several
     tokens, ``mla_selected`` and ``mla_window``; the latent decode kernel
-    with a bias for a full layer's rows of one, ``mla_selected_rows``)
+    with a bias for the rows of one, ``mla_selected_rows`` over a full
+    layer's pages and ``mla_window_rows`` over a window layer's ring)
     give the logits and the pools of the gathered form for every row."""
-    cfg, pages, table, have, fwd, rng = filled
+    cfg, pages, table, have, fwd, rng, _params = filled
     new, S = STEPS[step]
     R, packed = len(new), S is None
     with jax.default_matmul_precision("highest"):
@@ -474,6 +494,102 @@ def test_the_masked_kernel_form_equals_the_gathered_form(filled, step):
         np.testing.assert_allclose(np.asarray(pg_got[name]),
                                    np.asarray(pg_want[name]), atol=3e-5,
                                    err_msg=name)
+
+
+@pytest.mark.parametrize("step", ["decode", "packed_rows_of_one_token",
+                                  "packed_rows_around_a_wrapping_chunk"])
+def test_a_window_layer_on_the_kernels_is_the_gathered_window_slot_for_slot(
+        filled, step):
+    """ONE window layer (``window_block``) over rings that earlier steps
+    filled, on the kernels (interpret mode: ``mla_window`` for the chunk,
+    ``mla_window_rows`` for the rows of one token, laid in place) and in
+    the gathered form: the same stream in EVERY slot and the same ring.
+    The slots of no token - a pad slot, the slot of a row of length 0 -
+    get no attention output from either form: their stream comes back as
+    it went in, bit for bit."""
+    from dynamo_tpu.models.llama import packed_rows
+
+    cfg, pages, table, have, _fwd, _rng, params = filled
+    wcfg = cfg.window_cfg()
+    new, S = STEPS[step]
+    packed = S is None
+    B, S = (1, 48) if packed else (len(new), S)
+    rng = np.random.default_rng(5)
+    h = jnp.asarray(rng.standard_normal((B, S, cfg.hidden_size)) * 0.5,
+                    jnp.float32)
+    pos = np.zeros((B, S), np.int32)
+    token = np.zeros(B * S, bool)
+    s = 0
+    for r, n in enumerate(new):
+        at = slice(s, s + n) if packed else slice(r * S, r * S + n)
+        pos.reshape(-1)[at] = have[r] + np.arange(n)
+        token[at] = True
+        s += n
+    new = jnp.asarray(new, jnp.int32)
+    total = jnp.asarray(have, jnp.int32) + new
+    lp = jax.tree_util.tree_map(lambda v: v[0, 1], params["layers"]["win"])
+
+    def block(kernel):
+        st = dots3.Step(jnp.zeros((B, S), jnp.int32), jnp.asarray(pos),
+                        jnp.asarray(table[:, :-1]), total, new,
+                        jnp.asarray(table[:, -1]), packed_rows(packed, new),
+                        kernel)
+        with jax.default_matmul_precision("highest"):
+            out, cache = dots3.window_block(wcfg, lp, h, pages, 1, st)
+        return np.asarray(out).reshape(B * S, -1), np.asarray(cache["win"])
+
+    (want, ring_want), (got, ring_got) = block(False), block(True)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    np.testing.assert_array_equal(ring_got, ring_want)
+    assert np.abs(want[token] - np.asarray(h).reshape(B * S, -1)[token]
+                  ).max() > 1e-3
+    assert (~token).any()
+    for out in (got, want):
+        np.testing.assert_array_equal(
+            out[~token], np.asarray(h).reshape(B * S, -1)[~token])
+
+
+@pytest.mark.parametrize("nh,T", [(4, 48), (64, 44)],
+                         ids=["one_block", "three_blocks_and_a_pad"])
+def test_the_masked_kernel_under_a_causal_bias_is_the_ragged_oracle(nh, T):
+    """``mla_ragged`` with a bias takes its queries as TWO operands,
+    heads-major (``[nh, T, dkv]`` and ``[nh, T, dr]``: no stack, the
+    rotary half padded to lanes inside), and gives its output heads-major:
+    with the causal mask written out as the bias it is
+    ``models.deepseek.mla_ragged_attention`` transposed - chunk rows, a
+    one-token row and a row of no token, over one query block and over
+    three with a padded tail."""
+    from dynamo_tpu.models.deepseek import _mla_scale, mla_ragged_attention
+    from dynamo_tpu.ops.pallas.mla_ragged import mla_masked_attention_packed
+
+    _hf, cfg, _params = _family(**KERNEL)
+    wcfg = cfg.window_cfg()
+    dkv, dr, ps, P = wcfg.kv_lora_rank, wcfg.qk_rope_head_dim, 8, 40
+    assert dkv == 256 and dr < 128
+    rng = np.random.default_rng(13)
+    new = np.asarray([20, 0, 1, 17])
+    total = np.asarray([300, 0, 150, 17])
+    starts = np.cumsum(new) - new
+    pool = jnp.asarray(rng.standard_normal((2, 4 * P + 1, 2, 1, ps, dkv)),
+                       jnp.float32)
+    table = jnp.asarray(1 + np.arange(4 * P).reshape(4, P), jnp.int32)
+    q_lat = jnp.asarray(rng.standard_normal((T, nh, dkv)), jnp.float32)
+    q_pe = jnp.asarray(rng.standard_normal((T, nh, dr)), jnp.float32)
+    bias = np.full((T, P * ps), sl.NEG_INF, np.float32)
+    for r in range(4):
+        for i in range(new[r]):
+            bias[starts[r] + i, :total[r] - new[r] + i + 1] = 0.0
+    rows = (table, jnp.asarray(starts), jnp.asarray(new), jnp.asarray(total))
+    with jax.default_matmul_precision("highest"):
+        want = mla_ragged_attention(wcfg, q_lat, q_pe, pool, 1, *rows)
+        got = mla_masked_attention_packed(
+            q_lat.swapaxes(0, 1), q_pe.swapaxes(0, 1), pool, 1, *rows,
+            jnp.asarray(bias), _mla_scale(wcfg), interpret=True,
+            name="mla_window")
+    assert got.shape == (nh, T, dkv) and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got).swapaxes(0, 1),
+                               np.asarray(want), atol=2e-5, rtol=2e-5)
+    assert not np.asarray(got)[:, int(new.sum()):].any()
 
 
 # (topk 24 over a table of 16 pages of 8: a context and whether every index
@@ -597,7 +713,7 @@ def test_the_masked_kernel_walks_chunks_of_32_pages(nh, dkv, ctx):
     contexts and a one-token row the kernel is told nothing of - against
     the softmax over the bias's open keys written out."""
     from dynamo_tpu.ops.pallas.mla_ragged import (
-        BIASED_PAGES_PER_CHUNK, mla_ragged_attention_packed)
+        BIASED_PAGES_PER_CHUNK, mla_masked_attention_packed)
 
     rng = np.random.default_rng(7)
     ps, P, dr, T = 16, 80, 64, 48
@@ -622,11 +738,13 @@ def test_the_masked_kernel_walks_chunks_of_32_pages(nh, dkv, ctx):
             bias[t, :pos + 1][seen] = 0.0
             row_of[t] = r
     with jax.default_matmul_precision("highest"):
-        got = mla_ragged_attention_packed(
-            q_lat, q_pe, pool, 1, table, jnp.asarray(starts),
-            jnp.asarray(np.where(new > 1, new, 0)), jnp.asarray(total),
-            0.11, interpret=True, bias=jnp.asarray(bias), name="mla_selected")
-    got = np.asarray(got)
+        got = mla_masked_attention_packed(
+            q_lat.swapaxes(0, 1), q_pe.swapaxes(0, 1), pool, 1, table,
+            jnp.asarray(starts), jnp.asarray(np.where(new > 1, new, 0)),
+            jnp.asarray(total), jnp.asarray(bias), 0.11, interpret=True,
+            name="mla_selected")
+    assert got.shape == (nh, T, dkv)
+    got = np.asarray(got).swapaxes(0, 1)
     flat = np.asarray(pool)[1]
     for t in range(T):
         if row_of[t] < 0:

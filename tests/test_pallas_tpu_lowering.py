@@ -13,6 +13,9 @@ Geometries are the real targets: Llama-3-class GQA (Hq=24/Hkv=8/Dh=128)
 and DeepSeek-V3 MLA (nh=128, dkv=512).
 """
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -788,6 +791,41 @@ def test_mla_ragged_kernel_compiles_in_the_tpu_compiler(nh, T):
     assert len(calls) == 1 and "mla_ragged" in calls[0]
 
 
+@pytest.mark.parametrize("nh,dkv,P,name", [
+    (128, 512, 1600, "mla_selected_rows"), (64, 1024, 64, "mla_window_rows")],
+    ids=["full_layer_pages", "window_layer_rings"])
+def test_mla_masked_decode_kernel_compiles_in_the_tpu_compiler(nh, dkv, P,
+                                                               name):
+    """``mla_masked_decode_stacked`` at the long-context cell's two
+    geometries (dots3-note-prev, 24 rows, pages of 16, rope 64): a full
+    layer's 128 heads over a latent of 512 and a table of 1,600 pages, and
+    a window layer's 64 heads over a latent of 1,024 with the rings as
+    pages - 64 a row, two chunks of 32 (a slab of 4 MB). The TPU compiler
+    takes both under the default scoped-VMEM limit, under the caller's
+    name."""
+    from dynamo_tpu.ops.pallas.mla_decode_masked import (
+        mla_masked_decode_stacked)
+
+    one_chip = _v5e_chip()
+    R, dr = 24, 64
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q_lat, q_pe, pages, table, lens, bias):
+        return mla_masked_decode_stacked(
+            q_lat, q_pe, pages, 2, table, lens, bias, 0.072, name=name)
+
+    text = jax.jit(fn).lower(
+        sds((R, nh, dkv), jnp.bfloat16), sds((R, nh, dr), jnp.bfloat16),
+        sds((6, (R + 1) * P, 2, 1, PS, dkv), jnp.bfloat16),
+        sds((R, P), jnp.int32), sds((R,), jnp.int32),
+        sds((R, P * PS), jnp.float32)).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and name in calls[0]
+    assert f"f32[{R},{nh},{dkv}]" in calls[0]
+
+
 def test_mla_engine_packs_without_a_pool_copy():
     """An MLA engine on the kernels declares no reason to pad, and its
     token-packed step at the MLA cell's widths (JoyAI-LLM-Flash's dense
@@ -970,20 +1008,28 @@ def test_dots3_step_programs_compile_for_a_v5e():
     """The long-context cell's two step programs - the token-packed step
     of 640 slots over 24 rows and the fused block of two decode steps - at
     the published widths (abstract weights: 3,093,416,192 parameters) and
-    the cell's three pools compile for a v5e: the packed step with the
-    masked form of the ragged latent kernel for both attention kinds
-    (``mla_selected``, ``mla_window``), the latent decode kernel over the
-    one-token rows' selection as a bias (``mla_selected_rows``) and
-    ``moe_grouped`` in it, the fused block (one-token rows: the selection
-    through ``mla_selected_rows``, the window out of the ring in plain
-    XLA) with ``moe_grouped``; neither sorts an axis as long as the page
-    table's tokens (the selection stays a mask), neither copies the latent
-    pages, the index pages or the rings, and both fit the chip's memory."""
+    the cell's three pools compile for a v5e. Each row kind of each
+    attention kind goes to a kernel: in the packed step the masked form of
+    the ragged latent kernel for the chunk (``mla_selected``,
+    ``mla_window``) and the latent decode kernel with a bias for the rows
+    of one token (``mla_selected_rows`` over their pages, ``mla_window_rows``
+    over their rings as pages), in the fused block the two ``_rows``
+    kernels alone, ``moe_grouped`` in both. Around them XLA makes NO pass
+    over a step's ``[T, heads, latent]`` elements: the queries enter
+    heads-major as ``W_UK``'s matmul writes them, the output leaves
+    heads-major as ``W_UV``'s reads it, the one-token rows' results are
+    laid in place - no copy and no zero broadcast of that size, no add of
+    two of them, and nowhere a temporary of the table's rings
+    (``[24,64,2,16,1024]``: the gathered form's read a row at a time).
+    Neither sorts an axis as long as the page table's tokens (the
+    selection stays a mask), neither copies the latent pages, the index
+    pages or the rings, and both fit the chip's memory."""
     import json
     import os
 
     from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
-    from dynamo_tpu.engine.program_check import pool_copies, step_programs
+    from dynamo_tpu.engine.program_check import (_INSTR, pool_copies,
+                                                 step_programs)
     from dynamo_tpu.models import dots3
     from dynamo_tpu.models.config import ModelConfig
 
@@ -1018,9 +1064,17 @@ def test_dots3_step_programs_compile_for_a_v5e():
     other = [f"bf16[3,{args['--num-pages']},16,128]",
              f"bf16[6,{rows + 1},64,2,1,16,1024]"]
     want = {"packed": {"mla_selected", "mla_selected_rows", "mla_window",
-                       "moe_grouped"},
-            "fused": {"mla_selected_rows", "moe_grouped"}}
+                       "mla_window_rows", "moe_grouped"},
+            "fused": {"mla_selected_rows", "mla_window_rows", "moe_grouped"}}
     table_tokens = f"{args['--max-context']}]"
+    # a step's latent queries or outputs, either attention kind, whatever
+    # the order of the axes: T x heads x latent elements
+    T, wcfg = eng._packed_cap, cfg.window_cfg()
+    assert (T, wcfg.num_heads, wcfg.kv_lora_rank) == (640, 64, 1024)
+    assert (cfg.num_heads, cfg.kv_lora_rank) == (128, 512)
+    whole = T * wcfg.num_heads * wcfg.kv_lora_rank
+    assert whole == T * cfg.num_heads * cfg.kv_lora_rank
+    rings = f"[{rows},64,2,16,1024]"
     for name, kernels in want.items():
         fn, fn_args = programs[name]
         compiled = fn.lower(*fn_args).compile()
@@ -1033,5 +1087,22 @@ def test_dots3_step_programs_compile_for_a_v5e():
         assert pool_copies(hlo, pool, eng.kv_pool.dtype) == []
         assert not [ln for ln in hlo.splitlines() if " copy(" in ln
                     and any(s in ln.split(" copy(")[0] for s in other)]
+        assert rings not in hlo, name
+        if name == "packed":
+            # (a fusion's own instructions are read too: a transposing or
+            # stacking fusion holds its copy or concatenate inside)
+            zeros = set(re.findall(
+                r"(%constant[\w.]*) = [a-z0-9]+\[\]\S* constant\(0\)", hlo))
+            glue = []
+            for m in filter(None, map(_INSTR.match, hlo.splitlines())):
+                _root, _name, _dt, dims, opcode, rest = m.groups()
+                if dims and math.prod(map(int, dims.split(","))) in (
+                        whole, 2 * whole) and (
+                        opcode in ("copy", "concatenate")
+                        or opcode == "broadcast"
+                        and rest.split(")")[0] in zeros):
+                    glue.append(m.group(0)[:160])
+            assert glue == [], glue
         mem = compiled.memory_analysis()
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
+
