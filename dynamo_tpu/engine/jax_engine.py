@@ -471,6 +471,13 @@ class JaxEngine(ScheduledEngineBase):
                 model_cfg, self.cfg.num_pages, self.cfg.page_size,
                 state_slots=self.state_slots,
                 max_chunk=self.cfg.max_prefill_chunk)
+            if "state" in self.pages:
+                # what one row's step moves of the recurrent state: its
+                # slot of every linear layer, once in and once out
+                pool = self.pages["state"]
+                self.state_row_bytes = (
+                    pool.shape[0] * 2 * int(np.prod(pool.shape[2:]))
+                    * pool.dtype.itemsize)
         else:
             self.pages = llama.make_pages(model_cfg, self.cfg.num_pages,
                                           self.cfg.page_size)

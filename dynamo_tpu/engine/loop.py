@@ -141,6 +141,12 @@ class ScheduledEngineBase(EngineBase):
         # layer's; dynamo_worker_attn_{visible,selected}_keys_total)
         self.attn_visible_keys = 0
         self.attn_selected_keys = 0
+        # bytes of recurrent state the dispatches read and wrote
+        # (dynamo_worker_state_bytes_total), and what one row's step moves
+        # of it: every linear layer's slot once in and once out (the engine
+        # of a family with such layers sets it)
+        self.state_bytes_moved = 0
+        self.state_row_bytes = 0
         self._queues: Dict[str, asyncio.Queue] = {}
         self._work = asyncio.Event()
         self._loop_task: Optional[asyncio.Task] = None
@@ -322,7 +328,7 @@ class ScheduledEngineBase(EngineBase):
             tokens_real = rows * (k + 1)
         else:
             tokens_real = rows
-        state = (0, 0, 0, 0, 0)
+        state = (0, 0, 0, 0, 0, 0)
         if self.scheduler.cfg.state_slots:
             # (rows whose slot the dispatch read, tokens through the
             # chunk form of the rule, row-steps through the one-token
@@ -342,11 +348,16 @@ class ScheduledEngineBase(EngineBase):
                 w = max(1, width)
                 spans = [(len(s) - 1, w) for s in seqs]
                 gdn = (0, rows * w)
+            # a row's state goes through every linear layer once a step:
+            # a prompt chunk's row once, a fused block's rows once a step
+            moved = (rows if kind in ("prefill", "mixed")
+                     else gdn[1]) * self.state_row_bytes
             state = (rows,) + (gdn if linear else (0, 0)) + (
                 sum(n * (2 * p + n + 1) // 2 for p, n in spans),
-                sum(selected_keys(p, n, topk) for p, n in spans))
+                sum(selected_keys(p, n, topk) for p, n in spans), moved)
             self.attn_visible_keys += state[3]
             self.attn_selected_keys += state[4]
+            self.state_bytes_moved += moved
         padded = self.last_padded
         if padded is not None:
             batch = padded[0]

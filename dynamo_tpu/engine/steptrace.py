@@ -140,7 +140,7 @@ class StepRecord:
               "assemble_ms", "upload_ms",
               "enqueue_ms", "resume_ms", "fetch_resume_ms",
               "state_rows", "gdn_tokens", "gdn_step_rows", "score_pairs",
-              "selected_keys")
+              "selected_keys", "state_bytes")
     # _enqueue: perf_counter at the start of the enqueue, kept until the
     # result arrives and device_ms can be taken; _experts: the dispatch's
     # expert-layer counts (MOE_COUNTS) while they are still device
@@ -215,6 +215,11 @@ class StepRecord:
         # p + 1 a token at position p can see (``score_pairs``: what its
         # indexer scored); ``state_rows`` are then the window slots read
         self.selected_keys = 0
+        # bytes of recurrent state the dispatch read and wrote: every
+        # linear layer's slot once in and once out for each row-step (a
+        # prompt chunk's row once, a fused block's rows once a step), so a
+        # reader needs no model arithmetic for it (0 elsewhere)
+        self.state_bytes = 0
         # the dispatch phase by stage (``dispatch_ms`` less these five is
         # the ``wait`` for the result, of a synchronous kind), and how
         # long the fetched result waited for the loop's thread
@@ -464,7 +469,7 @@ class StepRecorder:
                chained_behind: str = "", enqueue: float = 0.0,
                experts: Any = None,
                decode_kernel_rows: int = 0,
-               state: tuple = (0, 0, 0, 0, 0),
+               state: tuple = (0, 0, 0, 0, 0, 0),
                phase: Optional[Phase] = None) -> StepRecord:
         """Stamp one dispatch; returns the live ring slot (later patched
         by note_ready/note_unpack/note_compile).
@@ -507,7 +512,7 @@ class StepRecorder:
             rec.moe_held_assignments = rec.moe_zero_assignments = 0
             rec.passes = rec.row_passes = rec.revealed = rec.commits = 0
             (rec.state_rows, rec.gdn_tokens, rec.gdn_step_rows,
-             rec.score_pairs, rec.selected_keys) = state
+             rec.score_pairs, rec.selected_keys, rec.state_bytes) = state
             rec._enqueue = enqueue
             rec._experts = experts
             rec.fetch_resume_ms = 0.0

@@ -5,8 +5,9 @@ paged KV cache ``[L, N, 2, Hkv, page_size, Dh]``) and ONE ``forward``: a
 ``lax.scan`` over the layers against that cache, whose attention op is an
 argument (``attn_impl``). ``get_family(cfg)`` maps a config to its
 implementation: configs with linear-attention layers
-(``full_attention_interval > 0``: Qwen3-Next) use ``models.qwen3_next``,
-whose ``make_pages`` returns the paged pool AND the recurrent-state pools;
+(``full_attention_interval > 0``) use ``models.qwen3_next`` (with experts:
+Qwen3-Next) or ``models.olmo_hybrid`` (a dense FFN: Olmo-Hybrid), whose
+``make_pages`` returns the paged pool AND the recurrent-state pools;
 configs with window layers beside latent attention (``layer_types`` holding
 ``sliding_attention``: dots3-note) use ``models.dots3``, whose
 ``make_pages`` returns latent pages, index pages and window rings;
@@ -25,11 +26,14 @@ from dynamo_tpu.models.llama import forward, init_params, make_pages
 def get_family(cfg: ModelConfig):
     """Return the module implementing this config's model family."""
     if cfg.full_attention_interval:
-        # Qwen3-Next: Gated DeltaNet linear-attention layers with a
-        # recurrent state beside the paged cache, gated full attention
-        # every ``full_attention_interval``-th layer
-        from dynamo_tpu.models import qwen3_next
-        return qwen3_next
+        # Gated DeltaNet linear-attention layers with a recurrent state
+        # beside the paged cache and full attention every
+        # ``full_attention_interval``-th layer: the sparse family
+        # (Qwen3-Next: gated attention, an expert layer) or the dense one
+        # (Olmo-Hybrid: branches normed on their way out, attention
+        # without positions, a dense FFN)
+        from dynamo_tpu.models import olmo_hybrid, qwen3_next
+        return qwen3_next if cfg.num_experts else olmo_hybrid
     if cfg.window_layers:
         # latent attention of two geometries: full layers that attend a
         # learned selection through an indexer, window layers over a ring

@@ -52,6 +52,8 @@ class ModelConfig:
     num_heads: int
     num_kv_heads: int
     head_dim: int
+    # 0.0: the family applies no rotary embedding at all (the dense hybrid
+    # of ``models/olmo_hybrid.py``, whose linear layers carry the order)
     rope_theta: float = 10000.0
     rms_norm_eps: float = 1e-5
     max_position_embeddings: int = 8192
@@ -138,7 +140,9 @@ class ModelConfig:
     # sqrt(hidden / rank) (1.0 = the DeepSeek form)
     mla_q_scale: float = 1.0
     mla_kv_scale: float = 1.0
-    # Qwen3-Next family (models/qwen3_next.py): layer ``i`` is gated full
+    # Families with linear-attention layers (sparse: models/qwen3_next.py;
+    # dense: models/olmo_hybrid.py, whose loader derives the interval from
+    # ``layer_types``): layer ``i`` is full
     # attention where ``(i + 1) % full_attention_interval == 0`` and a Gated
     # DeltaNet linear-attention layer otherwise (0 = every layer attends:
     # every other family). A linear layer keeps no pages: a request carries
@@ -152,6 +156,11 @@ class ModelConfig:
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 0
+    # ``linear_allow_neg_eigval``: the rule's write strength is ``beta = 2
+    # sigmoid(b)`` in (0, 2) - the transition ``I - beta k k^T`` then has
+    # the eigenvalue ``1 - beta`` in (-1, 1) and may reflect - where it is
+    # ``sigmoid(b)`` in (0, 1) otherwise
+    linear_allow_neg_eigval: bool = False
     # rotary position embedding turns the first ``partial_rotary_factor``
     # of a head's dimensions only
     partial_rotary_factor: float = 1.0
@@ -377,7 +386,14 @@ class ModelConfig:
         if ("full_attention_interval" in hf
                 or "linear_attention" in kinds
                 or any(k.startswith("linear_") for k in hf)):
-            return cls._from_qwen3_next(hf, dtype)
+            # two families keep linear-attention layers: the sparse one
+            # states an interval (and experts), the dense one lists its
+            # layers and has no expert key at all
+            dense = ("full_attention_interval" not in hf and not any(
+                k in hf for k in ("num_experts", "num_local_experts",
+                                  "n_routed_experts")))
+            return (cls._from_olmo_hybrid if dense
+                    else cls._from_qwen3_next)(hf, dtype)
         if ("sliding_attention" in kinds and hf.get("kv_lora_rank")) or any(
                 k.startswith(("swa_", "index_")) for k in hf):
             return cls._from_dots3(hf, dtype)
@@ -626,6 +642,93 @@ class ModelConfig:
                 hf.get("partial_rotary_factor") or 1.0),
             shared_expert_intermediate_size=int(
                 hf.get("shared_expert_intermediate_size") or 0),
+            linear_allow_neg_eigval=bool(
+                hf.get("linear_allow_neg_eigval", False)),
+        )
+
+    @classmethod
+    def _from_olmo_hybrid(cls, hf: Dict[str, Any], dtype: str) -> "ModelConfig":
+        """The dense hybrid family (Olmo-Hybrid: Gated DeltaNet layers and
+        full attention without positions, a dense FFN in every layer),
+        read off its own keys - ``layer_types`` holding ``linear_attention``,
+        the ``linear_*`` sizes, ``linear_allow_neg_eigval``, no
+        ``full_attention_interval`` and no expert key - never off a
+        model_type. ``layer_types`` has to be whole periods of some linear
+        layers and one full-attention layer: the period's length is kept as
+        ``full_attention_interval``, which every property of the layer
+        pattern reads. A key the family cannot place is an error that
+        names it."""
+        def no(key, why):
+            raise NotImplementedError(
+                f"{key} {hf.get(key)!r}: {why} (models/olmo_hybrid.py)")
+        wanted = ("layer_types", "linear_num_key_heads",
+                  "linear_num_value_heads", "linear_key_head_dim",
+                  "linear_value_head_dim", "linear_conv_kernel_dim",
+                  "intermediate_size")
+        for key in wanted:
+            if not hf.get(key):
+                no(key, "the dense linear-attention family needs every one "
+                        "of " + ", ".join(wanted))
+        for key in sorted(hf):
+            if key.startswith("linear_") and key not in wanted + (
+                    "linear_allow_neg_eigval",):
+                no(key, "a linear-attention key this family does not "
+                        "implement")
+        kinds, L = list(hf["layer_types"]), int(hf["num_hidden_layers"])
+        n = kinds.index("full_attention") + 1 if "full_attention" in kinds \
+            else 0
+        if n < 2 or len(kinds) != L or L % n or kinds != (
+                ["linear_attention"] * (n - 1) + ["full_attention"]) * (L // n):
+            no("layer_types", f"{L} layers in whole periods of some "
+                              "linear_attention layers and one "
+                              "full_attention layer are implemented")
+        theta = (hf.get("rope_parameters") or {}).get("rope_theta",
+                                                      hf.get("rope_theta"))
+        if theta is not None:
+            no("rope_theta", "the family's full attention has no positions "
+                             "(rope_theta null): a base to rotate by "
+                             "describes another model")
+        if (hf.get("rope_parameters") or {}).get("rope_type") not in (
+                None, "default") or hf.get("rope_scaling"):
+            no("rope_scaling" if hf.get("rope_scaling")
+               else "rope_parameters", "no rotary embedding, so none to "
+                                       "scale")
+        if hf.get("attention_bias"):
+            no("attention_bias", "projections without bias")
+        if (hf.get("hidden_act") or "silu") != "silu":
+            no("hidden_act", "a SwiGLU FFN")
+        if hf.get("sliding_window") or hf.get("use_sliding_window"):
+            no("sliding_window", "the full-attention layers see their "
+                                 "whole context")
+        if int(hf["linear_num_value_heads"]) % int(
+                hf["linear_num_key_heads"]):
+            no("linear_num_value_heads", "a key head serves a whole number "
+                                         "of value heads")
+        heads = int(hf["num_attention_heads"])
+        return cls(
+            vocab_size=hf["vocab_size"],
+            hidden_size=int(hf["hidden_size"]),
+            intermediate_size=int(hf["intermediate_size"]),
+            num_layers=L,
+            num_heads=heads,
+            num_kv_heads=int(hf.get("num_key_value_heads") or heads),
+            head_dim=int(hf.get("head_dim")
+                         or hf["hidden_size"] // heads),
+            rope_theta=0.0,
+            rms_norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+            max_position_embeddings=hf.get("max_position_embeddings", 8192),
+            tie_word_embeddings=bool(hf.get("tie_word_embeddings", False)),
+            qk_norm=True,               # over the whole width, not a head
+            model_type=hf.get("model_type", "olmo_hybrid"),
+            dtype=dtype,
+            full_attention_interval=n,
+            linear_num_key_heads=int(hf["linear_num_key_heads"]),
+            linear_num_value_heads=int(hf["linear_num_value_heads"]),
+            linear_key_head_dim=int(hf["linear_key_head_dim"]),
+            linear_value_head_dim=int(hf["linear_value_head_dim"]),
+            linear_conv_kernel_dim=int(hf["linear_conv_kernel_dim"]),
+            linear_allow_neg_eigval=bool(
+                hf.get("linear_allow_neg_eigval", False)),
         )
 
     @classmethod

@@ -6,7 +6,8 @@ expert in every layer. Pure jax.
 With ``norm(x; w) = x / rms(x) * (1 + w)`` (zero-centred weights) a layer is
 ``h <- h + mixer(norm(h))``, ``h <- h + moe(norm(h))``.
 
-- **Gated DeltaNet mixer** (``Hk`` key heads, ``Hv`` value heads, each key
+- **Gated DeltaNet mixer** (``gated_delta_net``, the one mixer of both
+  families with linear layers; ``Hk`` key heads, ``Hv`` value heads, each key
   head serving ``Hv / Hk`` value heads): ``[q | k | v | z] = x W_qkvz``,
   ``[b | a] = x W_ba``; ``(q, k, v) <- SiLU(conv(q | k | v))``, a causal
   depthwise convolution of width ``linear_conv_kernel_dim`` without bias
@@ -245,6 +246,51 @@ def _l2norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
+def gated_delta_net(cfg: ModelConfig, lp, x, cache, gidx, rows: gdn.Rows,
+                    *, use_pallas: bool, several: bool):
+    """The Gated DeltaNet mixer of every family that has one (this one and
+    ``models/olmo_hybrid.py``): ``x [N, H]``, the mixer's input on the
+    step's flat axis of ``N`` slots, against the state pools' layer
+    ``gidx`` -> ``(out [N, H], cache)``. What the residual stream is
+    normed with, before or behind the mixer, is the caller's. ``lp`` holds
+    ``w_qkvz`` (columns ``q | k | v | z``), ``w_ba`` (``b | a``),
+    ``conv_w``, ``A_log``, ``dt_bias``, ``o_norm`` and ``w_out``; ``beta =
+    sigmoid(b)``, doubled where ``cfg.linear_allow_neg_eigval``;
+    ``several`` (static) is off where every row carries one slot."""
+    N = x.shape[0]
+    Hk, Dk = cfg.linear_num_key_heads, cfg.linear_key_head_dim
+    Hv, Dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
+    f32 = jnp.float32
+    with jax.named_scope("layer.gdn_in"):
+        qkvz = x @ lp["w_qkvz"]
+        ba = jnp.dot(x, lp["w_ba"], preferred_element_type=f32)
+        n_conv = cfg.linear_conv_dim
+        mixed, conv = gdn.causal_conv(qkvz[:, :n_conv], lp["conv_w"],
+                                      cache["conv"], gidx, rows)
+        mixed = jax.nn.silu(mixed)
+        z = qkvz[:, n_conv:].reshape(N, Hv, Dv)
+        q = _l2norm(mixed[:, :Hk * Dk].reshape(-1, Hk, Dk)) * Dk ** -0.5
+        k = _l2norm(mixed[:, Hk * Dk:2 * Hk * Dk].reshape(-1, Hk, Dk))
+        v = mixed[:, 2 * Hk * Dk:].reshape(-1, Hv, Dv)
+        beta = jax.nn.sigmoid(ba[:, :Hv])
+        if cfg.linear_allow_neg_eigval:
+            beta = 2.0 * beta
+        g = -jnp.exp(lp["A_log"].astype(f32)) * jax.nn.softplus(
+            ba[:, Hv:] + lp["dt_bias"].astype(f32))
+    with jax.named_scope("layer.gdn"):
+        dt = x.dtype
+        o, state = gdn.gated_delta_rule(
+            q.astype(dt), k.astype(dt), v.astype(dt), g, beta,
+            cache["state"], gidx, rows, use_pallas=use_pallas,
+            several=several)
+    with jax.named_scope("layer.gdn_out"):
+        var = jnp.mean(o * o, axis=-1, keepdims=True)
+        y = (o * jax.lax.rsqrt(var + cfg.rms_norm_eps)
+             * lp["o_norm"].astype(f32) * jax.nn.silu(z.astype(f32)))
+        out = y.astype(dt).reshape(N, Hv * Dv) @ lp["w_out"]
+    return out, {**cache, "state": state, "conv": conv}
+
+
 def gdn_mixer(cfg: ModelConfig, lp, h, cache, gidx, rows: gdn.Rows, *,
               use_pallas: bool):
     """``h + GatedDeltaNet(norm(h))`` against the state pools' layer
@@ -252,36 +298,11 @@ def gdn_mixer(cfg: ModelConfig, lp, h, cache, gidx, rows: gdn.Rows, *,
     flat axis of ``B * S`` slots (``S == 1``: a decode step, a slot a
     row). Returns ``(h, cache)``."""
     B, S, H = h.shape
-    Hk, Dk = cfg.linear_num_key_heads, cfg.linear_key_head_dim
-    Hv, Dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
-    f32 = jnp.float32
     with jax.named_scope("layer.gdn_in"):
         x = zc_norm(h, lp["attn_norm"], cfg.rms_norm_eps).reshape(B * S, H)
-        qkvz = x @ lp["w_qkvz"]
-        ba = jnp.dot(x, lp["w_ba"], preferred_element_type=f32)
-        n_conv = cfg.linear_conv_dim
-        mixed, conv = gdn.causal_conv(qkvz[:, :n_conv], lp["conv_w"],
-                                      cache["conv"], gidx, rows)
-        mixed = jax.nn.silu(mixed)
-        z = qkvz[:, n_conv:].reshape(B * S, Hv, Dv)
-        q = _l2norm(mixed[:, :Hk * Dk].reshape(-1, Hk, Dk)) * Dk ** -0.5
-        k = _l2norm(mixed[:, Hk * Dk:2 * Hk * Dk].reshape(-1, Hk, Dk))
-        v = mixed[:, 2 * Hk * Dk:].reshape(-1, Hv, Dv)
-        beta = jax.nn.sigmoid(ba[:, :Hv])
-        g = -jnp.exp(lp["A_log"].astype(f32)) * jax.nn.softplus(
-            ba[:, Hv:] + lp["dt_bias"].astype(f32))
-    with jax.named_scope("layer.gdn"):
-        dt = h.dtype
-        o, state = gdn.gated_delta_rule(
-            q.astype(dt), k.astype(dt), v.astype(dt), g, beta,
-            cache["state"], gidx, rows, use_pallas=use_pallas,
-            several=S > 1)
-    with jax.named_scope("layer.gdn_out"):
-        var = jnp.mean(o * o, axis=-1, keepdims=True)
-        y = (o * jax.lax.rsqrt(var + cfg.rms_norm_eps)
-             * lp["o_norm"].astype(f32) * jax.nn.silu(z.astype(f32)))
-        out = y.astype(dt).reshape(B, S, Hv * Dv) @ lp["w_out"]
-    return h + out, {**cache, "state": state, "conv": conv}
+    out, cache = gated_delta_net(cfg, lp, x, cache, gidx, rows,
+                                 use_pallas=use_pallas, several=S > 1)
+    return h + out.reshape(B, S, H), cache
 
 
 def full_mixer(cfg: ModelConfig, lp, h, positions, total_lens, new_lens,
@@ -405,4 +426,4 @@ forward.supports_packed = True
 
 
 __all__ = ["init_params", "forward", "make_pages", "sparse_block",
-           "decay_init", "zc_norm"]
+           "decay_init", "zc_norm", "gated_delta_net", "conv_init_std"]

@@ -4,7 +4,8 @@ A Gated DeltaNet layer (``models/qwen3_next.py``) keeps, for each request
 and value head, one state ``S [Dk, Dv]`` in float32, and for each token
 ``t`` with key ``k``, query ``q`` (both L2-normalised, ``q`` scaled by
 ``Dk ** -0.5``), value ``v``, log-decay ``g <= 0`` and write strength
-``beta`` in (0, 1)::
+``beta`` in (0, 1), or in (0, 2) where the model allows the transition
+``I - beta k k^T`` a negative eigenvalue::
 
     S <- exp(g_t) S
     u  = beta_t (v_t - S^T k_t)
@@ -104,11 +105,13 @@ def causal_conv(x: jnp.ndarray, w: jnp.ndarray, pool: jnp.ndarray, layer,
             * wf[K - 1 - j]
     # a row's first K - 1 tokens reach into the carried inputs: token s
     # (s < K - 1) takes carried input K - 1 + s - j for every j > s
-    head = jnp.zeros((rows.start.shape[0], K - 1, Ch), f32)
-    for s in range(K - 1):
-        for j in range(s + 1, K):
-            head = head.at[:, s].add(
-                carry[:, K - 1 + s - j].astype(f32) * wf[K - 1 - j])
+    # (built as one value: as K (K - 1) / 2 updates of a zero array each
+    # rewrote the whole [R, K - 1, Ch] - six passes of 6.6 MB a layer at 48
+    # rows of 11,520 channels, a fifth of a decode step: PERF.md, PR 51)
+    cf = carry.astype(f32)
+    head = jnp.stack(
+        [sum(cf[:, K - 1 + s - j] * wf[K - 1 - j] for j in range(s + 1, K))
+         for s in range(K - 1)], axis=1)
     s_idx = jnp.arange(K - 1, dtype=jnp.int32)[None, :]
     at = jnp.where(s_idx < rows.new[:, None],
                    rows.start[:, None] + s_idx, N)          # N: dropped
@@ -253,13 +256,19 @@ def gated_delta_rule(q, k, v, g, beta, pool, layer, rows: Rows,
     Dk]`` (normalised, ``q`` scaled), ``v [N, Hv, Dv]``, ``g``/``beta [N,
     Hv]`` float32; ``several`` (static) is off where the step's form gives
     every row one slot (a decode step: the chunk form is not in the
-    program). Returns ``(o [N, Hv, Dv] float32, pool)``; slots that hold no
-    token come back zero."""
+    program); ``use_pallas`` asks for the Mosaic kernels, which serve where
+    they lower at this geometry (``ops/pallas/gdn.supports``). Returns ``(o
+    [N, Hv, Dv] float32, pool)``; slots that hold no token come back
+    zero."""
     N = q.shape[0]
     one = rows.new == 1                                      # [R]
     if use_pallas:
-        from dynamo_tpu.ops.pallas.gdn import gdn_chunk, gdn_step
-    else:
+        from dynamo_tpu.ops.pallas.gdn import gdn_chunk, gdn_step, supports
+        # a geometry the kernels cannot lower serves on the plain forms
+        # (the worker's ``startup.engine`` span says which, and why)
+        use_pallas = supports(q.shape[1], v.shape[1], q.shape[2],
+                              v.shape[2])
+    if not use_pallas:
         gdn_chunk, gdn_step = gdn_chunk_xla, gdn_step_xla
     # the step form, over every row: its one token, or nothing
     at = jnp.minimum(rows.start, N - 1)

@@ -16,12 +16,19 @@ after every chunk. The number of live chunks is dynamic under a static
 grid: a chunk past the last repeats the last one's block indices (nothing
 is fetched), computes nothing, and copies slot 0 of the pool onto itself.
 Inside a chunk everything is a matrix product: ``(I + A)^-1`` of the
-64 x 64 strictly lower-triangular ``A`` comes from its 16 x 16 diagonal
-blocks ``D`` (nilpotent: ``(I + D)^-1 = (I - D)(I + D^2)(I + D^4)(I +
-D^8)``) and the rest ``L`` (``(I + D + L)^-1 = (I - N)(I + N^2)(I + D)^-1``
-with ``N = (I + D)^-1 L``, whose fourth power is zero), in float32 at the
-highest precision: the alternating sums stay small because no block is
-wider than 16. Keys, queries and values enter the products in the dtype
+64 x 64 strictly lower-triangular ``A`` is built by doubling. The inverse
+of its 2 x 2 diagonal blocks is ``I - A`` there; two inverted neighbours
+``T_a``, ``T_b`` of width ``b`` and the block ``J`` of ``A`` that joins
+them invert together as ``[[T_a, 0], [-T_b J T_a, T_b]]``, which over the
+whole matrix is ``T <- T - T J T`` with ``J`` the joining blocks of that
+width - five such steps, ten products, in float32 at the highest
+precision. Every intermediate is a block of the true inverse, whose
+entries the rule bounds (the transitions ``I - beta k k^T`` do not expand
+for ``beta`` in [0, 2]); a sum of powers of ``A`` is not: at ``beta = 2``
+over parallel keys (``linear_allow_neg_eigval``) the powers of a 16-wide
+block reach 2e6 before they cancel to +-2, and float32 keeps a tenth of
+that answer (tests/test_olmo_hybrid.py). Keys, queries and values enter
+the products in the dtype
 they come in (bfloat16 on the chip, float32 in the CPU tests), the state as
 well, and every product accumulates in float32, which is what the state is
 kept and updated in.
@@ -46,9 +53,38 @@ from jax.experimental.pallas import tpu as pltpu
 from dynamo_tpu.ops.gdn import CHUNK, Chunks
 from dynamo_tpu.ops.pallas.decode import _resolve_interpret
 
-_BLOCK = 16          # the diagonal blocks of the chunk's triangular solve
 _HIGHEST = jax.lax.Precision.HIGHEST
 _F32 = jnp.float32
+
+
+# what the state blocks in flight may take of the 16 MiB a kernel's VMEM is
+# held to: ``gdn_step`` keeps two blocks in and two out
+_STATE_VMEM = 12 << 20
+
+
+def why_not(Hk: int, Hv: int, Dk: int, Dv: int):
+    """None where ``gdn_chunk`` and ``gdn_step`` lower for this geometry,
+    else the reason they do not (the rule then runs on ``ops/gdn.py``'s
+    ``gdn_chunk_xla`` / ``gdn_step_xla``). Head counts and ``Dv`` are
+    free: a block is the whole of an array's last two axes, which Mosaic
+    pads in VMEM (30 heads of 96 x 192 lower as they are)."""
+    if Hk < 1 or Hv % Hk:
+        return f"{Hk} key heads do not serve {Hv} value heads in whole groups"
+    if Dk % 8:
+        return (f"a key head of {Dk} is no multiple of 8: the state's "
+                f"[{Dk}, {Dv}] float32 tiles would be padded in the pool in "
+                "HBM and the pool copied around every call")
+    hb = _head_block(Hv, Hv // Hk, 8)
+    need = 4 * hb * Dk * -(-Dv // 128) * 128 * 4
+    if need > _STATE_VMEM:
+        return (f"{hb} states of [{Dk}, {Dv}] float32, two in and two out, "
+                f"take {need} bytes of VMEM (over {_STATE_VMEM})")
+    return None
+
+
+def supports(Hk: int, Hv: int, Dk: int, Dv: int) -> bool:
+    """Geometries these kernels can lower for (else use the XLA forms)."""
+    return why_not(Hk, Hv, Dk, Dv) is None
 
 
 def _head_block(Hv: int, rep: int, cap: int) -> int:
@@ -68,15 +104,16 @@ def _mm32(a, b):
 def _unit_lower_inverse(A, t, s):
     """``(I + A)^-1`` of a strictly lower-triangular ``A [C, C]`` float32
     (``t``/``s``: its row and column numbers), module docstring."""
-    eye = (t == s).astype(_F32)
-    D = jnp.where(t // _BLOCK == s // _BLOCK, A, 0.0)
-    L = A - D
-    D2 = _mm32(D, D)
-    D4 = _mm32(D2, D2)
-    D8 = _mm32(D4, D4)
-    Td = _mm32(_mm32(eye - D, eye + D2), _mm32(eye + D4, eye + D8))
-    N = _mm32(Td, L)
-    return _mm32(_mm32(eye - N, eye + _mm32(N, N)), Td)
+    C = A.shape[0]
+    T = (t == s).astype(_F32) - jnp.where(t // 2 == s // 2, A, 0.0)
+    b = 2
+    while b < C:
+        # the part of A that joins two inverted blocks of b into one of 2b
+        join = jnp.where((t // (2 * b) == s // (2 * b))
+                         & (t // b != s // b), A, 0.0)
+        T = T - _mm32(_mm32(T, join), T)
+        b *= 2
+    return T
 
 
 def _chunk_kernel(slot_ref, flags_ref, live_ref, layer_ref, q_ref, k_ref,
@@ -146,7 +183,7 @@ def gdn_chunk(q, k, v, g, beta, pool, layer, ck: Chunks, *, interpret=None):
     NC, C, Hk, Dk = q.shape
     Hv, Dv = v.shape[2:]
     rep = Hv // Hk
-    if C != CHUNK or C != 4 * _BLOCK:
+    if C != CHUNK or C & (C - 1):
         raise ValueError(f"gdn_chunk is built for chunks of {CHUNK} tokens")
     hb = _head_block(Hv, rep, 4)
     kb = hb // rep
@@ -202,11 +239,11 @@ def _step_kernel(slot_ref, keep_ref, layer_ref, q_ref, k_ref, v_ref, eg_ref,
         kh = i // rep
         kc = k_ref[0, 0][:, kh:kh + 1]                       # [Dk, 1]
         qc = q_ref[0, 0][:, kh:kh + 1]
-        S = sin_ref[0, 0, i] * keep * eg_ref[0, i:i + 1, :]  # [Dk, Dv]
-        u = b_ref[0, i:i + 1, :] * (
-            v_ref[0, i:i + 1, :] - jnp.sum(kc * S, axis=0, keepdims=True))
+        S = sin_ref[0, 0, i] * keep * eg_ref[0, 0, i:i + 1, :]  # [Dk, Dv]
+        u = b_ref[0, 0, i:i + 1, :] * (
+            v_ref[0, 0, i:i + 1, :] - jnp.sum(kc * S, axis=0, keepdims=True))
         S = S + kc * u
-        o_ref[0, i:i + 1, :] = jnp.sum(qc * S, axis=0, keepdims=True)
+        o_ref[0, 0, i:i + 1, :] = jnp.sum(qc * S, axis=0, keepdims=True)
         sout_ref[0, 0, i] = S
 
 
@@ -225,14 +262,18 @@ def gdn_step(q, k, v, g, beta, pool, layer, slot, fresh, *, interpret=None):
     def columns(a):             # [R, Hk, Dk] -> [R, nb, Dk, kb] float32
         return jnp.swapaxes(a.astype(_F32).reshape(R, nb, kb, Dk), 2, 3)
 
-    def wide(a):                # [R, Hv] -> [R, Hv, Dv]
-        return jnp.broadcast_to(a.astype(_F32)[..., None], (R, Hv, Dv))
+    # a block is the WHOLE of an array's last two axes, whatever the head
+    # count and the head's size (30 heads cut into blocks of 6, a head of
+    # 96 x 192): Mosaic pads such a tile in VMEM and the pool stays as it is
+    def blocks(a):              # [R, Hv, Dv] -> [R, nb, hb, Dv] float32
+        return a.astype(_F32).reshape(R, nb, hb, Dv)
 
-    def key_map(r, b, slot, keep, ly):
+    def wide(a):                # [R, Hv] -> [R, nb, hb, Dv]
+        return blocks(jnp.broadcast_to(a.astype(_F32)[..., None],
+                                       (R, Hv, Dv)))
+
+    def block_map(r, b, slot, keep, ly):
         return (r, b, 0, 0)
-
-    def row_map(r, b, slot, keep, ly):
-        return (r, b, 0)
 
     def pool_map(r, b, slot, keep, ly):
         return (ly[0], slot[r], b, 0, 0)
@@ -243,18 +284,18 @@ def gdn_step(q, k, v, g, beta, pool, layer, slot, fresh, *, interpret=None):
             num_scalar_prefetch=3,
             grid=(R, nb),
             in_specs=[
-                pl.BlockSpec((1, 1, Dk, kb), key_map),
-                pl.BlockSpec((1, 1, Dk, kb), key_map),
-                pl.BlockSpec((1, hb, Dv), row_map),
-                pl.BlockSpec((1, hb, Dv), row_map),
-                pl.BlockSpec((1, hb, Dv), row_map),
+                pl.BlockSpec((1, 1, Dk, kb), block_map),
+                pl.BlockSpec((1, 1, Dk, kb), block_map),
+                pl.BlockSpec((1, 1, hb, Dv), block_map),
+                pl.BlockSpec((1, 1, hb, Dv), block_map),
+                pl.BlockSpec((1, 1, hb, Dv), block_map),
                 pl.BlockSpec((1, 1, hb, Dk, Dv), pool_map),
             ],
             out_specs=[
-                pl.BlockSpec((1, hb, Dv), row_map),
+                pl.BlockSpec((1, 1, hb, Dv), block_map),
                 pl.BlockSpec((1, 1, hb, Dk, Dv), pool_map),
             ]),
-        out_shape=[jax.ShapeDtypeStruct((R, Hv, Dv), _F32),
+        out_shape=[jax.ShapeDtypeStruct((R, nb, hb, Dv), _F32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
         input_output_aliases={8: 1},
         compiler_params=pltpu.CompilerParams(
@@ -263,8 +304,8 @@ def gdn_step(q, k, v, g, beta, pool, layer, slot, fresh, *, interpret=None):
         name="gdn_step",
     )(slot.astype(i32), (~fresh).astype(i32),
       jnp.asarray(layer, i32).reshape(1), columns(q), columns(k),
-      v.astype(_F32), wide(jnp.exp(g)), wide(beta), pool)
-    return o, pool
+      blocks(v), wide(jnp.exp(g)), wide(beta), pool)
+    return o.reshape(R, Hv, Dv), pool
 
 
-__all__ = ["gdn_chunk", "gdn_step"]
+__all__ = ["gdn_chunk", "gdn_step", "supports", "why_not"]

@@ -84,9 +84,19 @@ def _fit_query_block(S: int, Hq: int, Dh: int, span: int,
     plus bf16 q/out copies — ``Hq*SB*(14*span + 24*Dh)`` bytes total.
     Calibrated on v5e: predicts 15.9 MiB where the chip measured 16.79 MiB
     (Hq=24, SB=128, span=128, Dh=128), hence the conservative budget.
+
+    Beside the double-buffered slab the stack holds the chunk in flight
+    read OUT of it, keys and values once more each and in float32: twice
+    the slab again. At the calibration's 8 key/value heads that is 2 MiB,
+    which the fitted per-row cost already carries; at 30 heads without
+    grouping (Olmo-Hybrid: ``[2, 2, 30, 128, 128]`` bf16, 3.75 MiB) the TPU
+    compiler measured a constant 11.1 MiB beside 0.10 MiB a query row
+    (17.63 MiB at SB=64, 24.18 at 128: PERF.md, PR 51), so what the
+    copies take over those 2 MiB is counted (SB=32 there: 14.4 MiB).
     """
+    copies = max(0, 2 * slab_bytes - 2 * 2**20)
     return shrink_query_block(min(QUERY_BLOCK, S), 8, Hq,
-                              14 * span + 24 * Dh, slab_bytes)
+                              14 * span + 24 * Dh, slab_bytes + copies)
 
 
 def _horizon(qpos, block: int):

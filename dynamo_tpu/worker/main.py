@@ -239,8 +239,19 @@ def build_engine(args: argparse.Namespace, startup=None) -> JaxEngine:
         # recurrent-state pool of a family with linear-attention layers
         attrs["cache.kinds"] = engine.cache_kinds
         if cfg.state_layers:
+            # the rule's geometry and the range of its write strength;
+            # and, where the kernels were asked for and cannot lower at
+            # that geometry, that the plain forms serve and why
             from dynamo_tpu.ops.gdn import CHUNK
-            attrs["linear_attention"] = f"gdn[chunk={CHUNK}]"
+            from dynamo_tpu.ops.pallas.gdn import why_not
+            geometry = (cfg.linear_num_key_heads, cfg.linear_num_value_heads,
+                        cfg.linear_key_head_dim, cfg.linear_value_head_dim)
+            attrs["linear_attention"] = (
+                "gdn[chunk={},Hk={},Hv={},Dk={},Dv={},beta<{}]".format(
+                    CHUNK, *geometry, 2 if cfg.linear_allow_neg_eigval else 1))
+            refused = why_not(*geometry)
+            if engine.attn_impl == "pallas" and refused:
+                attrs["linear_attention"] += f"[xla: {refused}]"
         # the form of the prefill-carrying steps: what
         # dynamo_worker_prefill_steps_total{form} will count
         attrs["prefill.form"] = (

@@ -381,6 +381,13 @@ class EngineDispatchCollector:
             "the layer attends a learned selection (min(index_topk, p + "
             "1) for a token at position p); 0 for every other model",
             value=float(stats.get("attn_selected_keys", 0)))
+        yield CounterMetricFamily(
+            "dynamo_worker_state_bytes",
+            "Bytes of recurrent state the dispatches of a model with "
+            "linear-attention layers read and wrote: every linear layer's "
+            "float32 slot once in and once out for each row-step (the "
+            "ring's state_bytes); 0 for every other model",
+            value=float(stats.get("state_bytes", 0)))
         one = CounterMetricFamily(
             "dynamo_worker_attn_one_token_rows",
             "Rows of ONE token that the dispatches of a model whose "
@@ -613,6 +620,7 @@ def engine_dispatch_stats(engine) -> Dict[str, object]:
         "attn_visible_keys": float(getattr(engine, "attn_visible_keys", 0)),
         "attn_selected_keys": float(
             getattr(engine, "attn_selected_keys", 0)),
+        "state_bytes": float(getattr(engine, "state_bytes_moved", 0)),
         "attn_one_token_rows": dict(
             getattr(engine, "attn_one_token_rows", None) or {}),
         "cache_bytes": dict(getattr(engine, "cache_bytes", None) or {}),

@@ -75,6 +75,7 @@ LONGEST_FIRST = (
     "test_model.py",
     "test_chip_smoke.py",
     "test_longcat.py",
+    "test_olmo_hybrid.py",
     "test_moe_grouped.py",
     "benchmarks/test_benchmarks.py",
     "test_kv_write.py",

@@ -1106,3 +1106,157 @@ def test_dots3_step_programs_compile_for_a_v5e():
         mem = compiled.memory_analysis()
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
 
+
+
+# -- Olmo-Hybrid: a rule of 30 heads of 96 x 192, attention at a group of
+# -- one (ISSUE 51) ---------------------------------------------------------
+
+@pytest.mark.parametrize("N,R", [(640, 48), (48, 48)])
+def test_the_gated_delta_rule_compiles_where_nothing_tiles(N, R):
+    """``gdn_chunk`` and ``gdn_step`` at Olmo-Hybrid's widths - 30 key heads
+    serving 30 value heads (blocks of 3 and of 6 heads), a state of 96 x 192
+    float32 (neither a multiple of 128), twelve linear layers' states of 49
+    slots - over the crowd cell's packed step (640 slots, 48 rows) and over
+    a decode step: the TPU compiler takes both as they are, the tiles pad
+    in VMEM, and the state pool is aliased and never copied or padded in
+    HBM."""
+    from dynamo_tpu.ops import gdn
+    from dynamo_tpu.ops.pallas.gdn import supports, why_not
+
+    one_chip = _v5e_chip()
+    H, Dk, Dv = 30, 96, 192
+    assert supports(H, H, Dk, Dv) and why_not(H, H, Dk, Dv) is None
+    assert "multiple of 8" in why_not(3, 3, 12, 24)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def rule(q, k, v, g, b, pool, layer, start, new, total, slots):
+        rows = gdn.token_rows(N, start, new, total, slots)
+        return gdn.gated_delta_rule(q, k, v, g, b, pool, layer, rows,
+                                    use_pallas=True, several=N > R)
+
+    bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    compiled = jax.jit(rule, donate_argnums=(5,)).lower(
+        sds((N, H, Dk), bf16), sds((N, H, Dk), bf16), sds((N, H, Dv), bf16),
+        sds((N, H), f32), sds((N, H), f32), sds((12, 49, H, Dk, Dv), f32),
+        sds((), i32), *[sds((R,), i32)] * 4).compile()
+    hlo = compiled.as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    names = sorted(ln.split("=")[0].strip().split(".")[0] for ln in calls)
+    assert names == (["%gdn_chunk", "%gdn_step"] if N > R else ["%gdn_step"])
+    pool_bytes = 12 * 49 * H * Dk * Dv * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes / 2
+    assert not [ln for ln in hlo.splitlines()
+                if " copy(" in ln and "f32[12,49,30,96,192]"
+                in ln.split(" copy(")[0]]
+
+
+def test_the_paged_kernels_compile_at_a_group_of_one():
+    """``paged_decode`` and ``ragged_mixed`` at Olmo-Hybrid's full
+    attention: 30 query heads over 30 key/value heads of 128, ONE query a
+    key head, the crowd cell's pool and table, 48 rows and 640 slots. The
+    double-buffered slab of 30 heads is 3.75 MiB and the chunk read out of
+    it as much again twice over: the ragged kernel's query block is 32
+    there (at 64 the TPU compiler counted 17.63 MiB of its 16), and the
+    blocks of the GQA cells stay what they were."""
+    from dynamo_tpu.ops.pallas.decode import paged_decode_attention_stacked
+    from dynamo_tpu.ops.pallas.prefill import _fit_query_block
+    from dynamo_tpu.ops.pallas.ragged import ragged_mixed_attention_packed
+
+    def block(Hq, Hkv, Dh, T=1152, span=128):
+        return _fit_query_block(T, Hq, Dh, span, 2 * 2 * Hkv * span * Dh * 2)
+    assert block(30, 30, 128, 640) == 32
+    # qwen3-4b, llama-3.2-3b, qwen3-next, sdar: as before this PR
+    assert [block(32, 8, 128), block(24, 8, 128), block(16, 2, 256),
+            block(32, 4, 128)] == [64, 64, 64, 64]
+
+    one_chip = _v5e_chip()
+    Hq, Hkv, Dh, R, T, P = 30, 30, 128, 48, 640, 80
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pages = sds((4, 4096, 2, Hkv, PS, Dh), jnp.bfloat16)
+    i32 = jnp.int32
+
+    def decode(q, pages, table, positions, total):
+        return paged_decode_attention_stacked(q, pages, 1, table, positions,
+                                              total, Dh ** -0.5)
+
+    def packed(q, pages, table, starts, q_lens, kv_lens):
+        return ragged_mixed_attention_packed(q, pages, 1, table, starts,
+                                             q_lens, kv_lens, Dh ** -0.5)
+
+    text = jax.jit(decode).lower(
+        sds((R, 1, Hq, Dh), jnp.bfloat16), pages, sds((R, P), i32),
+        sds((R, 1), i32), sds((R,), i32)).compile().as_text()
+    assert "paged_decode" in text
+    text = jax.jit(packed).lower(
+        sds((T, Hq, Dh), jnp.bfloat16), pages, sds((R, P), i32),
+        sds((R,), i32), sds((R,), i32), sds((R,), i32)).compile().as_text()
+    assert "ragged_mixed" in text and "paged_decode" in text
+
+
+def test_olmo_hybrid_step_programs_compile_for_a_v5e():
+    """The crowd cell's two step programs - the token-packed step of 640
+    slots over its rows and the fused block of decode steps - at the
+    published widths (abstract weights: 4,100,788,944 parameters) and the
+    cell's pools compile for a v5e with the rule's two kernels and the
+    paged kernels in them, with neither the paged pool nor the state pool
+    copied, inside the chip's memory."""
+    import json
+    import os
+
+    from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
+    from dynamo_tpu.engine.program_check import pool_copies, step_programs
+    from dynamo_tpu.models import olmo_hybrid
+    from dynamo_tpu.models.config import ModelConfig
+
+    one_chip = _v5e_chip()
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "configs",
+        "olmo-hybrid-7b.json")
+    with open(path) as f:
+        hf = json.load(f)
+    args = hf.pop("benchmark")["worker_args"]
+    args = {args[i]: int(args[i + 1]) for i in range(0, len(args), 2)
+            if args[i + 1].isdigit()}
+    cfg = ModelConfig.from_hf(hf)
+    abs_params = jax.eval_shape(
+        lambda: olmo_hybrid.init_params(cfg, jax.random.PRNGKey(0)))
+    rows, chunk = args["--max-num-seqs"], args["--max-prefill-chunk"]
+    eng = JaxEngine(cfg, abs_params, JaxEngineConfig(
+        num_pages=16, page_size=16, max_num_seqs=rows,
+        max_context=args["--max-context"], max_prefill_chunk=chunk,
+        attn_impl="pallas", decode_multistep=args["--decode-multistep"],
+        state_slots=args["--state-slots"]))
+    assert eng.padded_reason is None
+    assert eng._packed_cap == args["--min-prefill-bucket"]
+    assert eng.packed_attention == (
+        "chunks:ragged_mixed,one_token:paged_decode")
+    assert eng.cache_kinds == (
+        f"paged[L=4,Hkv=30,Dh=128]+state[L=12,S={rows},f32]")
+    programs = step_programs(
+        eng, rows, chunk, width=args["--decode-multistep"],
+        sharding=one_chip, num_pages=args["--num-pages"],
+        tokens=eng._packed_cap)
+    pool = (4, args["--num-pages"]) + tuple(eng.kv_pool.shape[2:])
+    state = f"f32[12,{rows + 1},30,96,192]"
+    want = {"packed": {"gdn_chunk", "gdn_step", "paged_decode",
+                       "ragged_mixed"},
+            "fused": {"gdn_step", "paged_decode"}}
+    for name, kernels in want.items():
+        fn, fn_args = programs[name]
+        compiled = fn.lower(*fn_args).compile()
+        hlo = compiled.as_text()
+        calls = {ln.split("=")[0].strip().lstrip("%").split(".")[0]
+                 for ln in hlo.splitlines() if "tpu_custom_call" in ln}
+        assert calls == kernels, name
+        assert pool_copies(hlo, pool, eng.kv_pool.dtype) == []
+        assert not [ln for ln in hlo.splitlines()
+                    if " copy(" in ln and state in ln.split(" copy(")[0]]
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9

@@ -628,6 +628,7 @@ def test_the_worker_refuses_at_its_arguments_and_names_the_caches(tmp_path):
     attrs = [st[3] for st in startup.stages
              if st[0] == "startup.engine"][0]
     assert attrs["cache.kinds"] == "paged[L=2,Hkv=2,Dh=16]+state[L=6,S=3,f32]"
-    assert attrs["linear_attention"] == "gdn[chunk=64]"
+    assert attrs["linear_attention"] == (
+        "gdn[chunk=64,Hk=2,Hv=4,Dk=16,Dv=16,beta<1]")
     assert attrs["moe.experts"] == "grouped[E=16,k=3][held=0+4]"
     assert attrs["prefill.form"] == "padded:attn_impl"
