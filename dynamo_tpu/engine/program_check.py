@@ -24,10 +24,28 @@ The third is the expert layer's: ``expert_temporaries`` lists the
 instructions that write a ``[tokens, experts, expert width]`` array, which
 the mask form of a mixture layer does and the grouped one must not
 (``tests/test_moe_grouped.py``).
+
+The fourth is the weights': a forward that scans over PERIODS of layers and
+hands the inner loop its period's ``[G, ...]`` slice of a ``[P, G, ...]``
+stack has that slice written first - a loop's operand has to be a buffer -
+so every non-expert matrix of a period is read and written once a period a
+step before any matmul reads it: 1.00 s of an 8 s slice of
+``dots3-note-prev.longctx``, 6.5 ms of an 88 ms packed step and 4.9 of a
+19 ms decode step, with no scope to its name (PERF.md section 5, PR 49);
+1.3 GB of temporaries and a near-doubled decode step at Olmo-Hybrid's
+widths, found in the compiled program before any chip call (section 6,
+PR 51). The families index one flat stack inside the inner body instead
+(``models/moe.flat_layers``, PR 52) and ``weight_copies`` lists what a
+compiled program still writes of a weight outside a matmul, by where it
+runs: inside a loop's body (to be empty), in the entry computation (the
+layout a consumer wants of a whole stack, once a dispatch: listed), or
+into the chip's fast memory (a fetch ahead of the matmul, no traffic of
+its own).
 Used by
-``tests/test_kv_write.py`` and ``tests/test_sampling_topk.py`` (toy
-models, CPU),
-``tests/test_pallas_tpu_lowering.py`` (a tp=2 mesh, the TPU compiler) and
+``tests/test_kv_write.py``, ``tests/test_sampling_topk.py`` and
+``tests/test_weight_copies.py`` (toy models, CPU),
+``tests/test_pallas_tpu_lowering.py`` (a tp=2 mesh and the cells' step
+programs at the published widths, the TPU compiler) and
 the kernel child of ``chip_smoke.py`` (serving geometry, on the chip).
 """
 
@@ -50,6 +68,8 @@ _IN_PLACE = frozenset({
     "dynamic-update-slice", "optimization-barrier"})
 _INSTR = re.compile(
     r"^\s*(ROOT )?(%[\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\((.*)$")
+# a computation's first line: ``[ENTRY ]%name (parameters) -> type {``
+_HEAD = re.compile(r"^(ENTRY )?(%[\w.\-]+) \(.*\{\s*$")
 
 
 def pool_copies(hlo_text: str, pool_shape, dtype) -> List[str]:
@@ -62,7 +82,7 @@ def pool_copies(hlo_text: str, pool_shape, dtype) -> List[str]:
     roots: Dict[str, str] = {}      # computation name -> its root's opcode
     found, comp = [], None
     for line in hlo_text.splitlines():
-        head = re.match(r"^(ENTRY )?(%[\w.\-]+) \(.*\{\s*$", line)
+        head = _HEAD.match(line)
         if head:
             comp = head.group(2)
             continue
@@ -125,6 +145,143 @@ def expert_temporaries(hlo_text: str, tokens: int, experts: int,
         if m and m.group(4) and sorted(
                 int(d) for d in m.group(4).split(",")) == want:
             found.append(line.strip())
+    return found
+
+
+# opcodes that move an array's elements and compute nothing: a value that
+# reaches a weight through these alone is that weight, written again
+_MOVES = frozenset({"copy", "transpose", "reshape", "slice", "dynamic-slice",
+                     "copy-done"})
+# ... and those that write nothing: the same buffer under another name
+_VIEWS = frozenset({"bitcast", "get-tuple-element", "tuple", "while",
+                    "optimization-barrier", "parameter", "copy-start"})
+_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+             "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+             "u64": 8}
+_ARRAY = re.compile(r"(\w+)\[([\d,]*)\]")
+_CALLED = re.compile(r"(body|calls|to_apply)=(%[\w.\-]+)")
+
+
+def _closing(text: str, start: int) -> int:
+    """Index of the parenthesis that closes the one at ``start``."""
+    depth = 0
+    for i in range(start, len(text)):
+        depth += (text[i] == "(") - (text[i] == ")")
+        if depth == 0:
+            return i
+    return len(text) - 1
+
+
+def _computations(hlo_text: str):
+    """``(name -> instructions, entry's name)`` of an HLO module's text. An
+    instruction is ``(is_root, name, type, opcode, operands, attributes,
+    line)``; ``type`` is the printed result type, a tuple's included."""
+    comps, entry, body = {}, None, None
+    for line in hlo_text.splitlines():
+        head = _HEAD.match(line)
+        if head:
+            body = comps[head.group(2)] = []
+            entry = head.group(2) if head.group(1) else entry
+            continue
+        m = re.match(r"^\s*(ROOT )?(%[\w.\-]+) = (.*)$", line)
+        if not m or body is None:
+            continue
+        rest = m.group(3)
+        cut = (_closing(rest, 0) + 1 if rest.startswith("(")
+               else rest.index(" "))
+        typ, rest = rest[:cut], rest[cut:].lstrip()
+        op = re.match(r"([\w\-]+)\(", rest)
+        if not op:
+            continue
+        end = _closing(rest, op.end() - 1)
+        body.append((bool(m.group(1)), m.group(2), typ, op.group(1),
+                     re.findall(r"%[\w.\-]+", rest[op.end():end]),
+                     rest[end + 1:], line.strip()))
+    return comps, entry
+
+
+def _array_bytes(typ: str) -> List[int]:
+    """Bytes of each array a printed result type holds."""
+    return [_ITEMSIZE.get(dt, 4)
+            * math.prod(int(d) for d in dims.split(",") if d)
+            for dt, dims in _ARRAY.findall(typ)]
+
+
+def weight_copies(hlo_text: str, params, min_bytes: int = 4 << 20
+                  ) -> Dict[str, List[dict]]:
+    """Instructions of an optimised HLO module that write a weight again:
+    at least ``min_bytes`` whose operand chain leads back to an entry
+    parameter with the dtype and shape of a leaf of ``params`` through
+    nothing but moves (slices, copies, transposes, a fusion of those) -
+    never a ``dot``, a ``convolution`` or a kernel, which READ a weight,
+    nor a bitcast, which writes nothing. An array that merely has a
+    weight's element count is not one: ``bf16[38400,16,128]``, a step's
+    gathered index keys, counts what five layers of ``wq_a`` count.
+
+    Told apart by where they run and where they write: ``"loop"``, inside
+    a ``while`` body to the device's main memory (once a period or a layer
+    of every step - a loop handed a slice of a stack,
+    ``models/moe.flat_layers``: to be empty but for what a test names);
+    ``"entry"``, outside every loop (once a dispatch: the layout a consumer
+    wants of a whole stack, hoisted; listed, not failed); ``"on_chip"``,
+    a result the compiler placed in the chip's fast memory (``S(1)`` in
+    its layout: a layer's matrix fetched ahead of the matmul that reads it
+    there, in place of that matmul's own read). Each ``{"bytes", "leaf",
+    "line"}``, the leaf by its parameter's name."""
+    leaves = {(_HLO_DTYPE.get(jnp.dtype(l.dtype).name), tuple(l.shape))
+              for l in jax.tree_util.tree_leaves(params)}
+    comps, entry = _computations(hlo_text)
+    found: Dict[str, List[dict]] = {"loop": [], "entry": [], "on_chip": []}
+
+    def walk(comp: str, args: list, where: Optional[str]):
+        """The root's weight (a leaf's name, a dict of them by index for a
+        tuple, or None) of a computation called with ``args``; ``where``
+        is None inside a fusion, whose caller is the one that writes."""
+        held: Dict[str, object] = {}
+        root = None
+        for is_root, name, typ, opcode, operands, attrs, line in comps[comp]:
+            ins = [held.get(o) for o in operands]
+            first = ins[0] if ins else None
+            out = None
+            if opcode == "parameter":
+                index = int(re.search(r"parameter\((\d+)\)", line).group(1))
+                if comp == entry:
+                    m = _ARRAY.match(typ)
+                    shape = tuple(int(d) for d in m.group(2).split(",") if d)
+                    out = name if (m.group(1), shape) in leaves else None
+                elif index < len(args):
+                    out = args[index]
+            elif opcode == "get-tuple-element":
+                index = int(re.search(r"index=(\d+)", attrs).group(1))
+                out = first.get(index) if isinstance(first, dict) else None
+            elif opcode == "tuple":
+                out = dict(enumerate(ins))
+            elif opcode == "while":
+                # a weight is loop-invariant: what goes in at an index
+                # comes out at it
+                body = dict(_CALLED.findall(attrs)).get("body")
+                walk(body, [first], "loop")
+                out = first
+            elif opcode in ("fusion", "call"):
+                called = dict(_CALLED.findall(attrs))
+                out = walk(called.get("calls") or called.get("to_apply"),
+                           ins, None if opcode == "fusion" else where)
+            elif opcode in _MOVES or opcode in _VIEWS:
+                out = first
+            held[name] = out
+            if is_root:
+                root = out
+            if where is None or opcode in _VIEWS or opcode == "call":
+                continue
+            outs = out.values() if isinstance(out, dict) else [out]
+            for leaf, size, layout in zip(outs, _array_bytes(typ),
+                                          re.findall(r"\]\{([^}]*)\}", typ)):
+                if isinstance(leaf, str) and size >= min_bytes:
+                    found["on_chip" if "S(1)" in layout else where].append(
+                        {"bytes": size, "leaf": leaf, "line": line[:200]})
+        return root
+
+    walk(entry, [], "entry")
     return found
 
 
@@ -245,4 +402,5 @@ def check_step_programs(engine, batch: int, chunk: int, width: int = 8,
 
 
 __all__ = ["pool_copies", "vocab_sorts", "expert_temporaries",
+           "weight_copies",
            "step_programs", "check_step_programs"]

@@ -571,8 +571,9 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     ``mla_window_rows``) and into ``moe_grouped``. No ``logits_window``:
     a verify window or a scoring pass would have to take back what a
     rejected token wrote to a ring, so the engine offers neither."""
-    from dynamo_tpu.models.moe import (grouped_on_chip, split_experts,
-                                       sum_aux, token_slots)
+    from dynamo_tpu.models.moe import (flat_layers, grouped_on_chip,
+                                       layer_at, split_experts, sum_aux,
+                                       token_slots)
 
     if cfg.moe_backend != "grouped":
         raise NotImplementedError(
@@ -594,11 +595,10 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                   use_pallas=grouped_on_chip(attn_impl))
     lf, lw = params["layers"]["full"], params["layers"]["win"]
     full_scanned, full_experts = split_experts(cfg, lf)
-    win_scanned, win_experts = split_experts(cfg, lw)
-    # the window layers' experts as ONE stack over periods and places, so
-    # the grouped layer indexes it by the layer and no slice is made
-    win_experts = {k: v.reshape((-1,) + v.shape[2:])
-                   for k, v in win_experts.items()}
+    # the window layers as ONE stack over periods and places: the loops
+    # below carry indices alone, each layer's leaves are read where they
+    # lie (``moe.flat_layers``) and the grouped layer indexes the experts
+    win_scanned, win_experts = split_experts(cfg, flat_layers(lw))
 
     def dense(carry, xs):
         h, cache = carry
@@ -609,39 +609,35 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                                              cfg.rms_norm_eps))
         return (h, cache), None
 
-    def full(h, cache, fp, p):
+    def full(carry, p):
+        h, cache = carry
+        fp = layer_at(full_scanned, p)
         h, cache = full_block(cfg, fp, h, cache, K + p, st)
         h, aux = _ffn(cfg, {**fp, **full_experts}, h, dict(moe_kw, layer=p))
         return (h, cache), aux
 
-    def period(carry, xs):
-        h, cache = carry
-        fp, wp, p = xs
-        (h, cache), aux_f = full(h, cache, fp, p)
+    def period(carry, p):
+        carry, aux_f = full(carry, p)
 
-        def window(carry, xs):
+        def window(carry, j):
             h, cache = carry
-            lp, j = xs
             widx = p * G + j
+            lp = layer_at(win_scanned, widx)
             h, cache = window_block(wcfg, lp, h, cache, widx, st)
             h, aux = _ffn(cfg, {**lp, **win_experts}, h,
                           dict(moe_kw, layer=widx))
             return (h, cache), aux
 
-        (h, cache), aux_w = jax.lax.scan(window, (h, cache),
-                                         (wp, jnp.arange(G)))
-        return (h, cache), {k: aux_f[k] + jnp.sum(aux_w[k]) for k in aux_f}
+        carry, aux_w = jax.lax.scan(window, carry, jnp.arange(G))
+        return carry, {k: aux_f[k] + jnp.sum(aux_w[k]) for k in aux_f}
 
     if K:
         (h, pages), _ = jax.lax.scan(dense, (h, pages),
                                      (params["dense_layers"], jnp.arange(K)))
-    head = jax.tree_util.tree_map(lambda v: v[:P], full_scanned)
-    (h, pages), aux = jax.lax.scan(period, (h, pages),
-                                   (head, win_scanned, jnp.arange(P)))
+    (h, pages), aux = jax.lax.scan(period, (h, pages), jnp.arange(P))
     aux = sum_aux(aux)
     for t in range(tail):
-        last = jax.tree_util.tree_map(lambda v: v[P + t], full_scanned)
-        (h, pages), aux_t = full(h, pages, last, P + t)
+        (h, pages), aux_t = full((h, pages), P + t)
         aux = {k: aux[k] + aux_t[k].astype(jnp.int32) for k in aux}
     with jax.named_scope("logits"):
         logits = _logits(cfg, params, h, new_lens, starts=st.starts)

@@ -453,6 +453,27 @@ def split_experts(cfg: ModelConfig, layers: Dict[str, jnp.ndarray]):
             {k: layers[k] for k in EXPERT_LEAVES})
 
 
+def flat_layers(stack):
+    """A tree of layers stacked over periods and places ``[P, G, ...]`` as
+    ONE stack ``[P * G, ...]`` (a bitcast, made once outside every loop).
+    A forward that scans over periods takes a layer's leaves from it with
+    ``layer_at`` inside the inner body and never hands a loop a slice of
+    a stack as an operand or as ``xs``: a loop's operand has to be a
+    buffer, so XLA materialised each period's ``[G, ...]`` slice - every
+    non-expert matrix read and written once a period a step before any
+    matmul read it (1.00 s of an 8 s slice of ``dots3-note-prev.longctx``,
+    PERF.md section 6, PR 49 and PR 52; 1.3 GB of temporaries at
+    Olmo-Hybrid's widths, PR 51)."""
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), stack)
+
+
+def layer_at(stack, i):
+    """Layer ``i`` (traced or static) of a tree of stacked layers: each
+    leaf read where it lies, by the matmul that consumes it."""
+    return jax.tree_util.tree_map(lambda a: a[i], stack)
+
+
 def token_slots(tokens: jnp.ndarray, new_lens: jnp.ndarray,
                 packed: bool) -> jnp.ndarray:
     """``[B * S]`` bool: the slots of a step that hold a token. The others

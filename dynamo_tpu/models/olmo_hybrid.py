@@ -56,7 +56,7 @@ from dynamo_tpu.models.llama import (
     randn_stack,
     write_rows,
 )
-from dynamo_tpu.models.moe import grouped_on_chip
+from dynamo_tpu.models.moe import flat_layers, grouped_on_chip, layer_at
 from dynamo_tpu.models.qwen3_next import (
     conv_init_std,
     decay_init,
@@ -244,13 +244,11 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         h = params["embed"][tokens]
     G = cfg.full_attention_interval - 1
     eps = cfg.rms_norm_eps
-    # the linear layers' weights as ONE stack over periods and places,
-    # indexed by the layer inside the loop: as the periods' scanned slices
-    # they were copied a period at a time (1.3 GB of temporaries and as
-    # much again to read and write every period of a decode step at the
+    # the linear layers as ONE stack over periods and places, each layer's
+    # leaves read where they lie inside the loop (``moe.flat_layers``; as
+    # the periods' scanned slices they were 1.3 GB of temporaries at the
     # published widths: the sandbox's compile for the v5e, PERF.md PR 51)
-    lg = jax.tree_util.tree_map(
-        lambda a: a.reshape((-1,) + a.shape[2:]), params["layers"]["gdn"])
+    lg = flat_layers(params["layers"]["gdn"])
 
     def period(carry, xs):
         fp, p = xs
@@ -258,7 +256,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         def linear(j, carry):
             h, cache = carry
             gidx = p * G + j
-            lp = jax.tree_util.tree_map(lambda a: a[gidx], lg)
+            lp = layer_at(lg, gidx)
             out, cache = gated_delta_net(
                 cfg, lp, h.reshape(B * S, -1), cache, gidx, rows,
                 use_pallas=on_chip, several=S > 1)

@@ -357,8 +357,9 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     family into ``gdn_chunk`` / ``gdn_step`` and ``moe_grouped``. No
     ``logits_window``: a verify window or a scoring pass over a recurrent
     state would have to roll it back, so the engine offers neither."""
-    from dynamo_tpu.models.moe import (grouped_on_chip, split_experts,
-                                       sum_aux, token_slots)
+    from dynamo_tpu.models.moe import (flat_layers, grouped_on_chip,
+                                       layer_at, split_experts, sum_aux,
+                                       token_slots)
 
     if cfg.moe_backend != "grouped":
         raise NotImplementedError(
@@ -378,29 +379,25 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                   use_pallas=on_chip)
     lg, lf = params["layers"]["gdn"], params["layers"]["full"]
 
-    gdn_scanned, gdn_experts = split_experts(cfg, lg)
+    # the linear layers as ONE stack over periods and places: the loops
+    # below carry indices alone, each layer's leaves are read where they
+    # lie (``moe.flat_layers``) and the grouped layer indexes the experts
+    gdn_scanned, gdn_experts = split_experts(cfg, flat_layers(lg))
     full_scanned, full_experts = split_experts(cfg, lf)
-    # the linear layers' experts as ONE stack over periods and places, so
-    # the grouped layer indexes it by the layer and no slice is made
-    gdn_experts = {k: v.reshape((-1,) + v.shape[2:])
-                   for k, v in gdn_experts.items()}
 
-    def period(carry, xs):
-        h, cache = carry
-        gp, fp, p = xs
-
-        def linear(carry, xs):
+    def period(carry, p):
+        def linear(carry, j):
             h, cache = carry
-            lp, j = xs
             gidx = p * G + j
+            lp = layer_at(gdn_scanned, gidx)
             h, cache = gdn_mixer(cfg, lp, h, cache, gidx, rows,
                                  use_pallas=on_chip)
             h, aux = _ffn(cfg, {**lp, **gdn_experts}, h,
                           dict(moe_kw, layer=gidx))
             return (h, cache), aux
 
-        (h, cache), aux_g = jax.lax.scan(
-            linear, (h, cache), (gp, jnp.arange(G)))
+        (h, cache), aux_g = jax.lax.scan(linear, carry, jnp.arange(G))
+        fp = layer_at(full_scanned, p)
         h, cache = full_mixer(cfg, fp, h, positions, total_lens, new_lens,
                               page_table, cache, p, attn_impl=attn_impl,
                               starts=starts)
@@ -408,9 +405,8 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                         dict(moe_kw, layer=p))
         return (h, cache), {k: aux_f[k] + jnp.sum(aux_g[k]) for k in aux_f}
 
-    (h, pages), aux = jax.lax.scan(
-        period, (h, pages),
-        (gdn_scanned, full_scanned, jnp.arange(cfg.num_periods)))
+    (h, pages), aux = jax.lax.scan(period, (h, pages),
+                                   jnp.arange(cfg.num_periods))
     with jax.named_scope("logits"):
         # the final norm's weight is zero-centred like the stream's;
         # ``_logits`` multiplies by the weight it is handed

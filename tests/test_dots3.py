@@ -382,6 +382,39 @@ def test_chunked_prefill_then_decode_agrees_with_the_reference(tiny):
         np.testing.assert_allclose(lg, want[p], atol=2e-5, err_msg=str(p))
 
 
+F, W_ = "full_attention", "sliding_attention"
+
+
+@pytest.mark.parametrize("kinds,pattern", [
+    # two periods of two window layers and one more full layer: every
+    # indexed read of the forward at an index past 0 - the full stack's
+    # ``p`` and its tail's ``P + t``, the flat window stack's ``p * G + j``
+    ([F, F, W_, W_, F, W_, W_, F], (2, 2, 1)),
+    # one period (the outer loop runs once) of one window layer, and a tail
+    ([F, F, W_, F], (1, 1, 1)),
+    # the cell's own pattern at the tiny widths: two periods of three
+    ([F, F, W_, W_, W_, F, W_, W_, W_], (3, 2, 0)),
+], ids=["two_periods_and_a_tail", "one_period_and_a_tail", "the_cells"])
+def test_every_layer_is_read_at_its_own_index_of_its_stack(kinds, pattern):
+    """The forward's loops carry indices alone and take a layer's leaves
+    out of the stacks (``moe.flat_layers`` / ``layer_at``, ISSUE 52): at
+    other counts of periods, places and tail layers than the tiny
+    configuration's one period of three, a prompt in two chunks and four
+    tokens after it still read the reference's logits, which walks the
+    layers one at a time in the published order."""
+    hf, cfg, params = _family(num_hidden_layers=len(kinds), layer_types=kinds)
+    assert cfg.layer_pattern() == pattern
+    G, P, tail = pattern
+    assert params["layers"]["win"]["wo"].shape[:2] == (P, G)
+    assert params["layers"]["full"]["wo"].shape[0] == P + tail
+    tokens = np.random.default_rng(2).integers(0, 512, 40).tolist()
+    want = _reference_logits(hf, params, tokens)
+    got = _serve(cfg, params, tokens, [23, 13])
+    assert sorted(got) == [22, 35, 36, 37, 38, 39]
+    for p, lg in got.items():
+        np.testing.assert_allclose(lg, want[p], atol=2e-5, err_msg=str(p))
+
+
 @pytest.mark.parametrize("form", ["gathered", "masked"])
 def test_chunked_prefill_equals_whole_prefill_across_a_ring_wrap(tiny, form):
     """The same 160 tokens in one step of 160 (a ring of 256) and in

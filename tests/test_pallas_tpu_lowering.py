@@ -762,6 +762,25 @@ def _v5e_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _loops_that_write_a_weight(hlo: str, params, evicted: str = None):
+    """``(what a loop's body writes of a weight, the bytes the entry
+    computation does)`` of a compiled step program, by
+    ``engine/program_check.weight_copies`` (ISSUE 52). ``evicted`` names,
+    by a pattern over the leaf, the one kind of write a test allows a
+    loop: a ``copy-done`` that lays ONE layer's matrix back in the
+    device's main memory from the fast memory the compiler had fetched it
+    into (its memory-space assignment's, in the layout the consumer wants:
+    the per-layer face of a stored layout against a consumer's, PERF.md
+    section 7 - no loop's operand and no slice of several layers)."""
+    from dynamo_tpu.engine.program_check import weight_copies
+
+    found = weight_copies(hlo, params)
+    loop = [d for d in found["loop"] if not (
+        evicted and " copy-done(" in d["line"]
+        and re.search(evicted, d["leaf"]) and d["bytes"] <= 51e6)]
+    return loop, sum(d["bytes"] for d in found["entry"])
+
+
 @pytest.mark.parametrize("T", [256, 512, 1152])
 @pytest.mark.parametrize("nh", [32, 16])
 def test_mla_ragged_kernel_compiles_in_the_tpu_compiler(nh, T):
@@ -990,6 +1009,7 @@ def test_qwen3_next_step_programs_compile_for_a_v5e():
     want = {"packed": {"gdn_chunk", "gdn_step", "moe_grouped",
                        "paged_decode", "ragged_mixed"},
             "fused": {"gdn_step", "moe_grouped", "paged_decode"}}
+    temp = {"packed": 0.45e9, "fused": 0.13e9}
     for name, kernels in want.items():
         fn, fn_args = programs[name]
         compiled = fn.lower(*fn_args).compile()
@@ -1000,7 +1020,16 @@ def test_qwen3_next_step_programs_compile_for_a_v5e():
         assert pool_copies(hlo, pool, eng.kv_pool.dtype) == []
         assert not [ln for ln in hlo.splitlines()
                     if " copy(" in ln and state in ln.split(" copy(")[0]]
+        # no loop writes a layer's weights again (a period's three linear
+        # layers' were, 176 / 227 MB a period, as the outer scan's slices:
+        # 0.688 / 0.400 GB of temporaries); the one whole-stack copy left
+        # is the fused block's 67 MB of the full layers' ``wq``, once a
+        # dispatch
+        loop, entry_bytes = _loops_that_write_a_weight(hlo, abs_params)
+        assert loop == [], (name, loop)
+        assert entry_bytes < 70e6, (name, entry_bytes)
         mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < temp[name], name
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
 
 
@@ -1066,6 +1095,7 @@ def test_dots3_step_programs_compile_for_a_v5e():
     want = {"packed": {"mla_selected", "mla_selected_rows", "mla_window",
                        "mla_window_rows", "moe_grouped"},
             "fused": {"mla_selected_rows", "mla_window_rows", "moe_grouped"}}
+    temp = {"packed": 1.15e9, "fused": 1.06e9}
     table_tokens = f"{args['--max-context']}]"
     # a step's latent queries or outputs, either attention kind, whatever
     # the order of the axes: T x heads x latent elements
@@ -1103,7 +1133,19 @@ def test_dots3_step_programs_compile_for_a_v5e():
                         and rest.split(")")[0] in zeros):
                     glue.append(m.group(0)[:160])
             assert glue == [], glue
+        # no loop is handed a slice of a weight stack (ISSUE 52: a period's
+        # three window layers' were, 886 / 651 MB written a period, with
+        # 1.279 / 1.715 GB of temporaries); what a loop still writes is an
+        # eviction of one layer's ``wkv_b`` / ``wq_b``, and the entry
+        # computation the whole-stack layouts of those, once a dispatch
+        assert not re.search(r"bf16\[3,(8192,5120|1024,20480|1024,16384)\]",
+                             hlo), name
+        loop, entry_bytes = _loops_that_write_a_weight(
+            hlo, abs_params, evicted=r"w(kv|q)_b__")
+        assert loop == [], (name, loop)
+        assert entry_bytes < 0.8e9, (name, entry_bytes)
         mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < temp[name], name
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
 
 
@@ -1248,6 +1290,7 @@ def test_olmo_hybrid_step_programs_compile_for_a_v5e():
     want = {"packed": {"gdn_chunk", "gdn_step", "paged_decode",
                        "ragged_mixed"},
             "fused": {"gdn_step", "paged_decode"}}
+    temp = {"packed": 0.32e9, "fused": 0.19e9}
     for name, kernels in want.items():
         fn, fn_args = programs[name]
         compiled = fn.lower(*fn_args).compile()
@@ -1258,5 +1301,61 @@ def test_olmo_hybrid_step_programs_compile_for_a_v5e():
         assert pool_copies(hlo, pool, eng.kv_pool.dtype) == []
         assert not [ln for ln in hlo.splitlines()
                     if " copy(" in ln and state in ln.split(" copy(")[0]]
+        # PR 51's cure, held: the linear layers' weights are indexed in the
+        # loop and no period's slice is written (1.40 - 1.59 GB of
+        # temporaries in the first form); the fused block relays the full
+        # layers' ``wv`` whole, 118 MB once a dispatch
+        loop, entry_bytes = _loops_that_write_a_weight(hlo, abs_params)
+        assert loop == [], (name, loop)
+        assert entry_bytes < 120e6, (name, entry_bytes)
         mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < temp[name], name
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+# -- a layer's weights are read where they lie (ISSUE 52) -------------------
+
+@pytest.mark.parametrize("form", ["slices", "indices"])
+def test_a_nested_scan_over_indices_writes_no_weight_on_a_v5e(form):
+    """The toy of ``tests/test_weight_copies.py`` in the TPU compiler, two
+    periods of three layers of 2 MB: handed a period's slice the inner
+    loop has its ``[3, 1024, 1024]`` written first, by the outer loop's
+    body; over indices alone each matmul reads its layer out of
+    the stack and NOTHING of a weight is written, in a loop or outside."""
+    from dynamo_tpu.engine.program_check import weight_copies
+    from dynamo_tpu.models.moe import flat_layers, layer_at
+
+    one_chip = _v5e_chip()
+    P, G, W = 2, 3, 1024
+
+    def slices(params, x):
+        def period(h, wp):
+            def layer(h, w):
+                return jnp.tanh(h @ w), None
+            return jax.lax.scan(layer, h, wp)[0], None
+        return jax.lax.scan(period, x, params["w"])[0]
+
+    def indices(params, x):
+        flat = flat_layers(params["w"])
+
+        def period(h, p):
+            def layer(h, j):
+                return jnp.tanh(h @ layer_at(flat, p * G + j)), None
+            return jax.lax.scan(layer, h, jnp.arange(G))[0], None
+        return jax.lax.scan(period, x, jnp.arange(P))[0]
+
+    params = {"w": jax.ShapeDtypeStruct((P, G, W, W), jnp.bfloat16,
+                                        sharding=one_chip)}
+    x = jax.ShapeDtypeStruct((64, W), jnp.bfloat16, sharding=one_chip)
+    hlo = jax.jit({"slices": slices, "indices": indices}[form]).lower(
+        params, x).compile().as_text()
+    found = weight_copies(hlo, params, min_bytes=1 << 20)
+    if form == "indices":
+        assert found == {"loop": [], "entry": [], "on_chip": []}
+    else:
+        # (at 6 MB the compiler has room for the slice in its fast memory;
+        # the cells' 650 - 890 MB a period went to the main one)
+        written = found["loop"] + found["on_chip"]
+        assert [d["bytes"] for d in written] == [G * W * W * 2]
+        assert "bf16[3,1024,1024]" in written[0]["line"]
+        assert found["entry"] == []
