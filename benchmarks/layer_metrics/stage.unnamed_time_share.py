@@ -1,0 +1,19 @@
+"""Share of the device's busy time in the traced slice under no stage of the
+program: what the compiler added (parameter relayouts in the entry
+computation, ``copy-start`` / ``-done``, layout copies), what jax's lowering
+of a loop added (a ``lax.scan``'s slices of the stack it scans) and the
+holes between a loop body's operations. The one share of the seven with a
+direction worth holding: lower. One of the seven ``stage.*_time_share`` that
+partition the busy time (``scopespans.py``: the leaf operations of the ``XLA
+Ops`` line by the ``tf_op`` of their event metadata against the program's
+table of stages, ``dynamo_tpu/engine/stages.py``, shipped on the worker's
+``startup.engine`` span); a share rises when anything else falls, so the
+seven are read together. The whole table goes to
+``stage_times.worker<i>.json``. Nothing where the program ships no table,
+the trace names no scope or nothing on the machine reads event metadata."""
+
+import scopespans
+
+
+def compute(run):
+    return scopespans.share(run, "unnamed")

@@ -304,6 +304,7 @@ class Smoke:
                 + ("selection direct (a toy vocabulary), "
                    if r["selection"] == "direct"
                    else "no sort over the vocabulary, ") +
+                "nothing under no stage, "
                 f"temporaries {r['temp_bytes'] / 1e9:.3f} GB "
                 f"(compiled in {r['seconds']:.0f}s)")
         need = max(self.args.replicas, self.args.tensor_parallel_size)
@@ -761,10 +762,13 @@ def check_programs(dry: bool) -> list:
             raise Failed(
                 f"step program {r['program']}: {len(r['pool_copies'])} "
                 f"pool-sized copies, {len(r['vocab_sorts'])} sorts over "
-                f"the vocabulary, temporaries {r['temp_bytes']} B beside "
+                f"the vocabulary, {len(r['unstaged'])} equations under no "
+                f"stage, temporaries {r['temp_bytes']} B beside "
                 f"a pool of {r['pool_bytes']} B:\n"
                 + "\n".join(c[:300] for c in
-                            r["pool_copies"] + r["vocab_sorts"]))
+                            r["pool_copies"] + r["vocab_sorts"]
+                            + [f"{d['primitive']} at {d['source']}"
+                               for d in r["unstaged"]]))
     return reports
 
 

@@ -46,6 +46,7 @@ import numpy as np
 
 from dynamo_tpu.engine.loop import BlockState, ScheduledEngineBase
 from dynamo_tpu.engine.scheduler import PrefillBatch, StepPlan
+from dynamo_tpu.engine import stages
 from dynamo_tpu.engine.steptrace import MOE_COUNTS, stage
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models import llama
@@ -1085,9 +1086,10 @@ class JaxEngine(ScheduledEngineBase):
         row = NamedSharding(self.cfg.mesh, PartitionSpec("dp"))
         mat = NamedSharding(self.cfg.mesh, PartitionSpec("dp", None))
         c = jax.lax.with_sharding_constraint
-        return (c(tokens, mat), c(positions, mat), c(page_table, mat),
-                c(total_lens, row), c(new_lens, row), c(temperature, row),
-                c(top_k, row), c(top_p, row))
+        with stages.stage("step.inputs"):
+            return (c(tokens, mat), c(positions, mat), c(page_table, mat),
+                    c(total_lens, row), c(new_lens, row),
+                    c(temperature, row), c(top_k, row), c(top_p, row))
 
     def _run_forward(self, attn, params, tokens, positions, pages,
                      page_table, total_lens, new_lens, **kw):
@@ -1173,7 +1175,8 @@ class JaxEngine(ScheduledEngineBase):
         """Decode step whose input token is the previous step's on-device
         sampled token (packed column 0), row-aligned with the previous
         plan."""
-        tokens = prev_packed[:, :1]                        # [B, 1] int32
+        with stages.stage("step.chain"):
+            tokens = prev_packed[:, :1]                    # [B, 1] int32
         return self._step_impl(params, pages, tokens, positions, page_table,
                                total_lens, new_lens, rng, step, temperature,
                                top_k, top_p, pen)
@@ -1237,11 +1240,12 @@ class JaxEngine(ScheduledEngineBase):
             row = NamedSharding(self.cfg.mesh, PartitionSpec("dp"))
             mat = NamedSharding(self.cfg.mesh, PartitionSpec("dp", None))
             c = jax.lax.with_sharding_constraint
-            stop_ids = c(stop_ids, mat)
-            budget, min_gate = c(budget, row), c(min_gate, row)
-            if pcarry is not None:
-                pcarry = {k: c(v, mat if v.ndim == 2 else row)
-                          for k, v in pcarry.items()}
+            with stages.stage("step.inputs"):
+                stop_ids = c(stop_ids, mat)
+                budget, min_gate = c(budget, row), c(min_gate, row)
+                if pcarry is not None:
+                    pcarry = {k: c(v, mat if v.ndim == 2 else row)
+                              for k, v in pcarry.items()}
         B = tok.shape[0]
         pw = pen.get("pw") if pen is not None else None
         gt = pen.get("gt") if pen is not None else None
@@ -1255,20 +1259,22 @@ class JaxEngine(ScheduledEngineBase):
             # width's carry output keeps ONE fixed pytree structure (and
             # one set of out_shardings)
             W = self.cfg.penalty_window
-            pids0 = jnp.zeros((B, W), jnp.int32)
-            pcnt0 = jnp.zeros((B, W), jnp.float32)
-            pctx0 = jnp.zeros((B, W), jnp.float32)
-            pbias0 = jnp.zeros((B, W), jnp.float32)
-            pn0 = jnp.zeros(B, jnp.int32)
-            gstate0 = jnp.zeros(B, jnp.int32)
+            with stages.stage("step.inputs"):
+                pids0 = jnp.zeros((B, W), jnp.int32)
+                pcnt0 = jnp.zeros((B, W), jnp.float32)
+                pctx0 = jnp.zeros((B, W), jnp.float32)
+                pbias0 = jnp.zeros((B, W), jnp.float32)
+                pn0 = jnp.zeros(B, jnp.int32)
+                gstate0 = jnp.zeros(B, jnp.int32)
 
         def body(carry, j):
             (pages, tok, pos, total, alive,
              pids, pcnt, pctx, pbias, pn, gstate) = carry
-            new = alive.astype(jnp.int32)
+            with stages.stage("step.inputs"):
+                new = alive.astype(jnp.int32)
             logits, pages, aux = self._decode_forward(
                 params, pages, tok, pos, table, total, new)
-            with jax.named_scope("sample"):
+            with stages.stage("sample"):
                 logits = logits.astype(jnp.float32)
                 key = jax.random.fold_in(rng, step0 + j)
                 if pw is not None:
@@ -1310,41 +1316,48 @@ class JaxEngine(ScheduledEngineBase):
                     cols.append(ids)
                     cols.append(lp_bits)
                 packed = jnp.concatenate(cols, axis=1)
-            hit = jnp.any(stop_ids == sampled[:, None], axis=1)
-            min_ok = (j + 1) >= min_gate
-            stopped = (hit & min_ok) | ((j + 1) >= budget)
-            new_alive = alive & ~stopped
-            tok = jnp.where(alive[:, None], sampled[:, None], tok)
-            pos = pos + new[:, None]
-            total = total + new
-            if pw is not None:
-                # the sampled token joins the row's penalized set for the
-                # NEXT step (the per-step path recounts generated tokens
-                # including it next dispatch)
-                from dynamo_tpu.ops.sampling import update_penalty_window
-                pids, pcnt, pctx, pn = update_penalty_window(
-                    pids, pcnt, pctx, pn, sampled,
-                    alive & pw["active"])
-            if gt is not None:
-                # EOS rows self-loop in the table (the host advance
-                # no-ops EOS); dead rows freeze
-                gstate = jnp.where(alive, gt["trans"][gstate, sampled],
-                                   gstate)
+            # stop checks, budgets and the rows carried to the next step
+            with stages.stage("step.stop"):
+                hit = jnp.any(stop_ids == sampled[:, None], axis=1)
+                min_ok = (j + 1) >= min_gate
+                stopped = (hit & min_ok) | ((j + 1) >= budget)
+                new_alive = alive & ~stopped
+                tok = jnp.where(alive[:, None], sampled[:, None], tok)
+                pos = pos + new[:, None]
+                total = total + new
+                if pw is not None:
+                    # the sampled token joins the row's penalized set for
+                    # the NEXT step (the per-step path recounts generated
+                    # tokens including it next dispatch)
+                    from dynamo_tpu.ops.sampling import (
+                        update_penalty_window)
+                    pids, pcnt, pctx, pn = update_penalty_window(
+                        pids, pcnt, pctx, pn, sampled,
+                        alive & pw["active"])
+                if gt is not None:
+                    # EOS rows self-loop in the table (the host advance
+                    # no-ops EOS); dead rows freeze
+                    gstate = jnp.where(alive, gt["trans"][gstate, sampled],
+                                       gstate)
             return ((pages, tok, pos, total, new_alive,
                      pids, pcnt, pctx, pbias, pn, gstate), (packed, aux))
 
+        with stages.stage("step.inputs"):
+            step_ids = jnp.arange(n_steps, dtype=jnp.int32)
         (pages, tok, pos, total, alive, pids, pcnt, pctx, pbias, pn,
          gstate), (steps, aux) = jax.lax.scan(
             body, (pages, tok, pos, total, alive,
-                   pids0, pcnt0, pctx0, pbias0, pn0, gstate0),
-            jnp.arange(n_steps, dtype=jnp.int32))
-        carry = {"tok": tok, "pos": pos, "total": total, "alive": alive,
-                 "budget": budget - n_steps,
-                 "min_gate": min_gate - n_steps,
-                 "pids": pids, "pcnt": pcnt, "pctx": pctx, "pbias": pbias,
-                 "pn": pn, "gstate": gstate}
-        return (pages, jnp.moveaxis(steps, 0, 1), carry,
-                {k: jnp.sum(v.astype(jnp.int32)) for k, v in aux.items()})
+                   pids0, pcnt0, pctx0, pbias0, pn0, gstate0), step_ids)
+        with stages.stage("step.stop"):
+            carry = {"tok": tok, "pos": pos, "total": total, "alive": alive,
+                     "budget": budget - n_steps,
+                     "min_gate": min_gate - n_steps,
+                     "pids": pids, "pcnt": pcnt, "pctx": pctx,
+                     "pbias": pbias, "pn": pn, "gstate": gstate}
+            packed = jnp.moveaxis(steps, 0, 1)
+        with stages.stage("step.counts"):
+            aux = {k: jnp.sum(v.astype(jnp.int32)) for k, v in aux.items()}
+        return pages, packed, carry, aux
 
     def _handover_impl(self, prev_packed, rows, stop_ids):
         """From a mixed step's packed output to the carry a fused block
@@ -1360,12 +1373,13 @@ class JaxEngine(ScheduledEngineBase):
         applies on the host when the step's result arrives. Pad rows
         carry budget 0. Returns the keys of ``_multistep_impl``'s carry
         that a chained block reads."""
-        src, pos, total, budget, min_gate = rows
-        tok = prev_packed[src, :1]                          # [B, 1] int32
-        hit = jnp.any(stop_ids == tok, axis=1)
-        alive = (budget > 0) & ~(hit & (min_gate <= 0))
-        return {"tok": tok, "pos": pos[:, None], "total": total,
-                "alive": alive, "budget": budget, "min_gate": min_gate}
+        with stages.stage("step.chain"):
+            src, pos, total, budget, min_gate = rows
+            tok = prev_packed[src, :1]                      # [B, 1] int32
+            hit = jnp.any(stop_ids == tok, axis=1)
+            alive = (budget > 0) & ~(hit & (min_gate <= 0))
+            return {"tok": tok, "pos": pos[:, None], "total": total,
+                    "alive": alive, "budget": budget, "min_gate": min_gate}
 
     def _fill_impl(self, toks, prev_packed, fill):
         """The token array of a mixed step chained behind a mixed step,
@@ -1377,9 +1391,10 @@ class JaxEngine(ScheduledEngineBase):
         entries point past the end and are dropped. No row is masked
         here: one that this token ends rides the step with it and the
         host drops what it samples."""
-        slot, src = fill
-        return toks.reshape(-1).at[slot].set(
-            prev_packed[src, 0], mode="drop").reshape(toks.shape)
+        with stages.stage("step.chain"):
+            slot, src = fill
+            return toks.reshape(-1).at[slot].set(
+                prev_packed[src, 0], mode="drop").reshape(toks.shape)
 
     def _get_jit_fill(self):
         fn = self._jit_fill
@@ -1469,35 +1484,36 @@ class JaxEngine(ScheduledEngineBase):
         logits, pages, aux = self._run_forward(
             self._attn_prefill, params, tokens, positions, pages,
             page_table, total_lens, new_lens, logits_window=tokens.shape[1])
-        if gmask is not None:
-            # mask ONCE here so the packed top alternatives below see the
-            # same constrained distribution the verifier samples from —
-            # the plain path masks before its top-K too
-            from dynamo_tpu.ops.sampling import apply_vocab_mask
-            Bm, Sm, Vm = logits.shape
-            logits = apply_vocab_mask(
-                logits.astype(jnp.float32).reshape(Bm * Sm, Vm),
-                gmask.reshape(Bm * Sm, -1)).reshape(Bm, Sm, Vm)
-        key = jax.random.fold_in(rng, step)
-        n_acc, final_tok, final_lp, draft_lps = spec_verify(
-            logits, tokens, key, temperature, top_k, top_p)
-        bits = jax.lax.bitcast_convert_type
-        cols = [final_tok[:, None], bits(final_lp, jnp.int32)[:, None],
-                n_acc[:, None], bits(draft_lps, jnp.int32)]
-        if self.cfg.num_top_logprobs > 0:
-            # per-POSITION top alternatives (the OpenAI logprobs surface;
-            # the same columns the plain step packs, one set per chunk
-            # slot): [B, S*kt] ids then [B, S*kt] logprob bits
-            B = logits.shape[0]
-            ids, lp_bits = self._topk_cols(logits.astype(jnp.float32))
-            cols.append(ids.reshape(B, -1))
-            cols.append(lp_bits.reshape(B, -1))
-        packed = jnp.concatenate(cols, axis=1)
-        if self._dp > 1:
-            from jax.sharding import NamedSharding, PartitionSpec
-            packed = jax.lax.with_sharding_constraint(
-                packed, NamedSharding(self.cfg.mesh, PartitionSpec()))
-        return pages, packed, aux
+        with stages.stage("sample"):
+            if gmask is not None:
+                # mask ONCE here so the packed top alternatives below see the
+                # same constrained distribution the verifier samples from —
+                # the plain path masks before its top-K too
+                from dynamo_tpu.ops.sampling import apply_vocab_mask
+                Bm, Sm, Vm = logits.shape
+                logits = apply_vocab_mask(
+                    logits.astype(jnp.float32).reshape(Bm * Sm, Vm),
+                    gmask.reshape(Bm * Sm, -1)).reshape(Bm, Sm, Vm)
+            key = jax.random.fold_in(rng, step)
+            n_acc, final_tok, final_lp, draft_lps = spec_verify(
+                logits, tokens, key, temperature, top_k, top_p)
+            bits = jax.lax.bitcast_convert_type
+            cols = [final_tok[:, None], bits(final_lp, jnp.int32)[:, None],
+                    n_acc[:, None], bits(draft_lps, jnp.int32)]
+            if self.cfg.num_top_logprobs > 0:
+                # per-POSITION top alternatives (the OpenAI logprobs surface;
+                # the same columns the plain step packs, one set per chunk
+                # slot): [B, S*kt] ids then [B, S*kt] logprob bits
+                B = logits.shape[0]
+                ids, lp_bits = self._topk_cols(logits.astype(jnp.float32))
+                cols.append(ids.reshape(B, -1))
+                cols.append(lp_bits.reshape(B, -1))
+            packed = jnp.concatenate(cols, axis=1)
+            if self._dp > 1:
+                from jax.sharding import NamedSharding, PartitionSpec
+                packed = jax.lax.with_sharding_constraint(
+                    packed, NamedSharding(self.cfg.mesh, PartitionSpec()))
+            return pages, packed, aux
 
     def _ring_step_impl(self, params, pages, tokens, positions, page_table,
                         total_lens, new_lens, rng, step, temperature, top_k,
@@ -1515,52 +1531,52 @@ class JaxEngine(ScheduledEngineBase):
                                           total_lens)
         return pages, packed, {}
 
-    @functools.partial(jax.named_call, name="sample")
     def _sample_tail(self, logits, pages, rng, step, temperature, top_k,
                      top_p, pen=None, total_lens=None):
         """Shared sampling epilogue of every step family (chunked + ring),
-        traced under the ``sample`` scope.
+        traced under the ``sample`` stage.
 
         Everything the host needs is PACKED into one int32 buffer
         ``[B, 2 + 2K]`` (token id, logprob bits, K alternative ids, K
         alternative logprob bits): the host does exactly ONE device fetch
         per step (cost of a fetch on the chip: not measured)."""
-        key = jax.random.fold_in(rng, step)
-        seeds = None
-        if pen is not None:
-            # penalties rewrite the logits BEFORE sampling and the top-K
-            # alternatives, so reported logprobs reflect the distribution
-            # actually sampled from
-            from dynamo_tpu.ops.sampling import apply_penalties
-            logits = apply_penalties(logits, pen["ids"], pen["cnt"],
-                                     pen["ctx"], pen["fp"], pen["pp"],
-                                     pen["rp"], pen_bias=pen["bias"])
-            if "mask" in pen:
-                # guided allow-mask LAST: a penalty/bias can reweight
-                # inside the grammar but never resurrect an illegal token
-                from dynamo_tpu.ops.sampling import apply_vocab_mask
-                logits = apply_vocab_mask(logits, pen["mask"])
-            seeds = pen["seeds"]
-        sampled, logprobs = sample_tokens(
-            logits, key, temperature, top_k, top_p, seeds=seeds,
-            # seeded rows key on (base rng, seed, token position): replays
-            # are deterministic under any batching/step interleaving
-            seed_rng=rng, seed_pos=total_lens,
-            min_p=pen["min_p"] if pen is not None else None)
-        cols = [sampled[:, None],
-                jax.lax.bitcast_convert_type(logprobs, jnp.int32)[:, None]]
-        if self.cfg.num_top_logprobs > 0:
-            ids, lp_bits = self._topk_cols(logits.astype(jnp.float32))
-            cols.append(ids)
-            cols.append(lp_bits)
-        packed = jnp.concatenate(cols, axis=1)
-        if self._dp > 1:
-            # gather the dp-sharded rows back to every rank (rank 0 reads
-            # the whole batch locally; [B, 2+2K] int32 — a few KB)
-            from jax.sharding import NamedSharding, PartitionSpec
-            packed = jax.lax.with_sharding_constraint(
-                packed, NamedSharding(self.cfg.mesh, PartitionSpec()))
-        return pages, packed
+        with stages.stage("sample"):
+            key = jax.random.fold_in(rng, step)
+            seeds = None
+            if pen is not None:
+                # penalties rewrite the logits BEFORE sampling and the top-K
+                # alternatives, so reported logprobs reflect the distribution
+                # actually sampled from
+                from dynamo_tpu.ops.sampling import apply_penalties
+                logits = apply_penalties(logits, pen["ids"], pen["cnt"],
+                                         pen["ctx"], pen["fp"], pen["pp"],
+                                         pen["rp"], pen_bias=pen["bias"])
+                if "mask" in pen:
+                    # guided allow-mask LAST: a penalty/bias can reweight
+                    # inside the grammar but never resurrect an illegal token
+                    from dynamo_tpu.ops.sampling import apply_vocab_mask
+                    logits = apply_vocab_mask(logits, pen["mask"])
+                seeds = pen["seeds"]
+            sampled, logprobs = sample_tokens(
+                logits, key, temperature, top_k, top_p, seeds=seeds,
+                # seeded rows key on (base rng, seed, token position): replays
+                # are deterministic under any batching/step interleaving
+                seed_rng=rng, seed_pos=total_lens,
+                min_p=pen["min_p"] if pen is not None else None)
+            cols = [sampled[:, None],
+                    jax.lax.bitcast_convert_type(logprobs, jnp.int32)[:, None]]
+            if self.cfg.num_top_logprobs > 0:
+                ids, lp_bits = self._topk_cols(logits.astype(jnp.float32))
+                cols.append(ids)
+                cols.append(lp_bits)
+            packed = jnp.concatenate(cols, axis=1)
+            if self._dp > 1:
+                # gather the dp-sharded rows back to every rank (rank 0 reads
+                # the whole batch locally; [B, 2+2K] int32 — a few KB)
+                from jax.sharding import NamedSharding, PartitionSpec
+                packed = jax.lax.with_sharding_constraint(
+                    packed, NamedSharding(self.cfg.mesh, PartitionSpec()))
+            return pages, packed
 
     # -- plan -> device arrays --------------------------------------------
 
@@ -2487,29 +2503,33 @@ class JaxEngine(ScheduledEngineBase):
         top ids, K top log-probability bits - the state for a chained
         dispatch, and the summed MoE counts)."""
         R, B = state["tok"].shape
-        mask_id = jnp.int32(self.model_cfg.mask_token_id)
-        offs = jnp.arange(B, dtype=jnp.int32)[None, :]
         rep = functools.partial(jnp.repeat, repeats=B, axis=0)
-        temp, top_k, top_p = (rep(samp["temp"]), rep(samp["top_k"]),
-                              rep(samp["top_p"]))
-        seeds, min_p = rep(samp["seeds"]), rep(samp["min_p"])
+        with stages.stage("step.inputs"):
+            mask_id = jnp.int32(self.model_cfg.mask_token_id)
+            offs = jnp.arange(B, dtype=jnp.int32)[None, :]
+            temp, top_k, top_p = (rep(samp["temp"]), rep(samp["top_k"]),
+                                  rep(samp["top_p"]))
+            seeds, min_p = rep(samp["seeds"]), rep(samp["min_p"])
         bits = functools.partial(jax.lax.bitcast_convert_type,
                                  new_dtype=jnp.int32)
 
         def body(carry, j):
             pages, tok, rev, pidx, start, tail, budget, alive = carry
-            pos = start[:, None] + offs
-            new = alive.astype(jnp.int32) * B
-            total = jnp.where(alive, start + B, 1)
+            with stages.stage("step.inputs"):
+                pos = start[:, None] + offs
+                new = alive.astype(jnp.int32) * B
+                total = jnp.where(alive, start + B, 1)
+                shown = jnp.where(rev, tok, mask_id)
             logits, pages, aux = self._run_forward(
-                self._attn_prefill, params, jnp.where(rev, tok, mask_id),
-                pos, pages, table, total, new, logits_window=B)
-            # masks the budget pays for: the positions past it are never
-            # revealed (the block's tail is not emitted, and what a served
-            # token was conditioned on is a function of served tokens)
-            masked = ~rev & (offs < (tail + budget)[:, None])
+                self._attn_prefill, params, shown, pos, pages, table, total,
+                new, logits_window=B)
             served = alive          # the rows this pass serves
-            with jax.named_scope("pass/confidence"):
+            with stages.stage("pass/confidence"):
+                # masks the budget pays for: the positions past it are
+                # never revealed (the block's tail is not emitted, and what
+                # a served token was conditioned on is a function of served
+                # tokens)
+                masked = ~rev & (offs < (tail + budget)[:, None])
                 lf = logits.astype(jnp.float32).reshape(R * B, -1)
                 sampled, lps = sample_tokens(
                     lf, jax.random.fold_in(rng, step0 + j), temp, top_k,
@@ -2522,12 +2542,12 @@ class JaxEngine(ScheduledEngineBase):
                 if self.cfg.num_top_logprobs > 0:
                     ids, lp_bits = self._topk_cols(lf)
                     tops = [ids.reshape(R, -1), lp_bits.reshape(R, -1)]
-            with jax.named_scope("pass/reveal"):
+            with stages.stage("pass/reveal"):
                 now = alive[:, None] & reveal(
                     jnp.exp(lps), masked, pidx, samp["steps"], samp["tau"])
                 tok = jnp.where(now, sampled, tok)
                 rev = rev | now
-            with jax.named_scope("pass/commit"):
+            with stages.stage("pass/commit"):
                 commit = alive & ~jnp.any(masked, axis=1)
                 budget = jnp.where(commit, budget - (B - tail), budget)
                 start = jnp.where(commit, start + B, start)
@@ -2535,19 +2555,24 @@ class JaxEngine(ScheduledEngineBase):
                 rev = rev & ~commit[:, None]
                 pidx = jnp.where(commit, 0, pidx + alive.astype(jnp.int32))
                 alive = alive & ~(commit & (budget <= 0))
-            packed = jnp.concatenate(
-                [served[:, None].astype(jnp.int32),
-                 commit[:, None].astype(jnp.int32), now.astype(jnp.int32),
-                 sampled, bits(lps)] + tops, axis=1)
+                packed = jnp.concatenate(
+                    [served[:, None].astype(jnp.int32),
+                     commit[:, None].astype(jnp.int32),
+                     now.astype(jnp.int32), sampled, bits(lps)] + tops,
+                    axis=1)
             return ((pages, tok, rev, pidx, start, tail, budget, alive),
                     (packed, aux))
 
         keys = ("tok", "rev", "pidx", "start", "tail", "budget", "alive")
+        with stages.stage("step.inputs"):
+            pass_ids = jnp.arange(n_passes, dtype=jnp.int32)
         (pages, *out), (passes, aux) = jax.lax.scan(
-            body, (pages, *(state[k] for k in keys)),
-            jnp.arange(n_passes, dtype=jnp.int32))
-        return (pages, jnp.moveaxis(passes, 0, 1), dict(zip(keys, out)),
-                {k: jnp.sum(v.astype(jnp.int32)) for k, v in aux.items()})
+            body, (pages, *(state[k] for k in keys)), pass_ids)
+        with stages.stage("pass/commit"):
+            packed = jnp.moveaxis(passes, 0, 1)
+        with stages.stage("step.counts"):
+            aux = {k: jnp.sum(v.astype(jnp.int32)) for k, v in aux.items()}
+        return pages, packed, dict(zip(keys, out)), aux
 
     def _get_jit_passes(self, w: int):
         fn = self._jit_passes.get(w)

@@ -41,6 +41,14 @@ runs: inside a loop's body (to be empty), in the entry computation (the
 layout a consumer wants of a whole stack, once a dispatch: listed), or
 into the chip's fast memory (a fetch ahead of the matmul, no traffic of
 its own).
+
+The fifth is the trace's: a chip's profile names an operation by the stage
+it was traced under (``engine/stages.py``), and an equation traced under
+none is device time no reader can place (13.5 % of a cell's busy time hid so
+until PR 52). ``unstaged`` lists those of a step program from its jaxpr - no
+compile - and ``check_step_programs`` reports them: to be empty, so that
+what a trace still shows without a stage is the compiler's own
+(``tests/test_stages.py``).
 Used by
 ``tests/test_kv_write.py``, ``tests/test_sampling_topk.py`` and
 ``tests/test_weight_copies.py`` (toy models, CPU),
@@ -285,6 +293,72 @@ def weight_copies(hlo_text: str, params, min_bytes: int = 4 << 20
     return found
 
 
+def _equations(fn, args):
+    """``(equation, scopes)`` of every leaf equation of the jitted ``fn``
+    traced at ``args`` (shapes will do; nothing is lowered or compiled):
+    loops, branches and calls are walked into with the scopes that were
+    open around them, a Pallas kernel is one equation, and a
+    ``fori_loop``'s own counter - jax's equation, traced by no line of the
+    program - is left out."""
+    from jax._src import source_info_util
+
+    def loop_counter(eqn) -> bool:
+        for frame in eqn.source_info.traceback.frames:
+            if source_info_util.is_user_filename(frame.file_name):
+                return False
+            if "_fori_" in frame.function_name:
+                return True
+        return False
+
+    def walk(jaxpr, outer: tuple):
+        for eqn in jaxpr.eqns:
+            scopes = outer + tuple(
+                s.name for s in eqn.source_info.name_stack.stack
+                if type(s).__name__ == "Scope")
+            inner = [] if eqn.primitive.name == "pallas_call" else [
+                getattr(v, "jaxpr", v) for p in eqn.params.values()
+                for v in (p if isinstance(p, (tuple, list)) else (p,))
+                if hasattr(getattr(v, "jaxpr", v), "eqns")]
+            for sub in inner:
+                yield from walk(sub, scopes)
+            if not inner and not loop_counter(eqn):
+                yield eqn, "/".join(scopes)
+
+    yield from walk(fn.trace(*args).jaxpr.jaxpr, ())
+
+
+def unstaged(fn, args) -> List[dict]:
+    """The equations of a step program that no registered stage covers
+    (``engine/stages.py``), each as ``{"primitive", "scopes", "bytes",
+    "source"}``: what it writes and the line that traced it."""
+    from jax._src import source_info_util
+
+    from dynamo_tpu.engine.stages import stage_of
+
+    return [{
+        "primitive": eqn.primitive.name,
+        "scopes": scopes,
+        "bytes": sum(
+            math.prod(v.aval.shape) * jnp.dtype(v.aval.dtype).itemsize
+            for v in eqn.outvars if hasattr(v.aval, "shape")),
+        "source": source_info_util.summarize(eqn.source_info)}
+        for eqn, scopes in _equations(fn, args)
+        if stage_of(scopes) is None]
+
+
+def stages_opened(fn, args) -> Dict[str, int]:
+    """``stage -> equations traced under it`` of a step program: the
+    stages a chip's trace of it can show."""
+    from dynamo_tpu.engine.stages import stage_of
+
+    found: Dict[str, int] = {}
+    for _eqn, scopes in _equations(fn, args):
+        stage = stage_of(scopes)
+        if stage is not None:
+            found[stage] = found.get(stage, 0) + 1
+    return found
+
+
 def _pool_shape(engine, num_pages: Optional[int]):
     """The paged pool's shape (of a family with a recurrent state,
     ``engine.pages`` holds the state pools beside it)."""
@@ -373,9 +447,10 @@ def check_step_programs(engine, batch: int, chunk: int, width: int = 8,
                         sharding=None, num_pages: Optional[int] = None,
                         tokens: Optional[int] = None) -> List[dict]:
     """Compile the programs and report, for each, the pool-sized
-    copies in its HLO, its temporary bytes beside the pool's bytes and
-    the sorts over the vocabulary. A program is ``ok`` with no such copy,
-    temporaries under one pool and, where the sampler's selection is the
+    copies in its HLO, its temporary bytes beside the pool's bytes, the
+    sorts over the vocabulary and the equations it traces under no stage
+    (``unstaged``). A program is ``ok`` with no such copy, temporaries under
+    one pool, no such equation and, where the sampler's selection is the
     grouped one (``ops/sampling.candidate_form``; a toy vocabulary takes
     ``lax.top_k`` by design), no such sort; ``selection`` is that form."""
     from dynamo_tpu.ops.sampling import candidate_form
@@ -393,14 +468,16 @@ def check_step_programs(engine, batch: int, chunk: int, width: int = 8,
         copies = pool_copies(text, shape, dtype)
         sorts = vocab_sorts(text, vocab) if selection != "direct" else []
         temp = int(compiled.memory_analysis().temp_size_in_bytes)
+        no_stage = unstaged(fn, args)
         out.append({"program": name, "pool_shape": list(shape),
                     "pool_bytes": pool_bytes, "temp_bytes": temp,
                     "pool_copies": copies, "selection": selection,
-                    "vocab_sorts": sorts,
-                    "ok": not copies and not sorts and temp < pool_bytes})
+                    "vocab_sorts": sorts, "unstaged": no_stage,
+                    "ok": not copies and not sorts and not no_stage
+                    and temp < pool_bytes})
     return out
 
 
 __all__ = ["pool_copies", "vocab_sorts", "expert_temporaries",
-           "weight_copies",
+           "weight_copies", "unstaged", "stages_opened",
            "step_programs", "check_step_programs"]

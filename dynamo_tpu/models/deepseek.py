@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dynamo_tpu.engine.stages import stage
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.llama import (
     MOE_INIT_GAIN as INIT_GAIN,
@@ -248,7 +249,7 @@ def _mla_qkv(cfg: ModelConfig, lp: Dict[str, jnp.ndarray], h: jnp.ndarray,
     eps = cfg.rms_norm_eps
     x = _rms_norm(h, lp["attn_norm"], eps)
     if cfg.q_lora_rank:
-        with jax.named_scope("q_compress"):
+        with stage("q_compress"):
             q = _rms_norm(x @ lp["wq_a"], lp["q_a_norm"], eps) @ lp["wq_b"]
     else:
         q = x @ lp["wq"]
@@ -320,14 +321,13 @@ def _expand_and_project(cfg: ModelConfig, lp, h, lat, w_uv) -> jnp.ndarray:
     return h + out @ lp["wo"]
 
 
-def _mla_attend(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
-                h: jnp.ndarray, q_lat, q_pe, w_uv,
-                ckv_ctx: jnp.ndarray, kpe_ctx: jnp.ndarray,
-                positions: jnp.ndarray, total_lens: jnp.ndarray
-                ) -> jnp.ndarray:
-    """Latent-space attention + output projection residual (direct path:
-    decode steps / small tables — the full [B,nh,S,T] scores fit).
-    ckv_ctx/kpe_ctx: [B, T, dkv] / [B, T, dr] gathered context."""
+def _mla_latent(cfg: ModelConfig, q_lat, q_pe, ckv_ctx: jnp.ndarray,
+                kpe_ctx: jnp.ndarray, positions: jnp.ndarray,
+                total_lens: jnp.ndarray) -> jnp.ndarray:
+    """Latent-space attention (direct path: decode steps / small tables —
+    the full [B,nh,S,T] scores fit). ckv_ctx/kpe_ctx: [B, T, dkv] /
+    [B, T, dr] gathered context. Returns the latent output
+    [B, S, nh, dkv]."""
     sm_scale = _mla_scale(cfg)
     T = ckv_ctx.shape[1]
     scores = (jnp.einsum("bsnk,btk->bnst", q_lat,
@@ -339,8 +339,18 @@ def _mla_attend(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
             & (t_pos < total_lens[:, None, None, None]))
     scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)                # [B,nh,S,T]
-    lat = jnp.einsum("bnst,btk->bsnk", probs,
-                     ckv_ctx.astype(jnp.float32))          # [B,S,nh,dkv]
+    return jnp.einsum("bnst,btk->bsnk", probs,
+                      ckv_ctx.astype(jnp.float32))         # [B,S,nh,dkv]
+
+
+def _mla_attend(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
+                h: jnp.ndarray, q_lat, q_pe, w_uv,
+                ckv_ctx: jnp.ndarray, kpe_ctx: jnp.ndarray,
+                positions: jnp.ndarray, total_lens: jnp.ndarray
+                ) -> jnp.ndarray:
+    """``_mla_latent`` + output projection residual."""
+    lat = _mla_latent(cfg, q_lat, q_pe, ckv_ctx, kpe_ctx, positions,
+                      total_lens)
     return _expand_and_project(cfg, lp, h, lat, w_uv)
 
 
@@ -515,7 +525,7 @@ def _moe_mlp(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
 
     B, S, H = x.shape
     xt = x.reshape(B * S, H)
-    with jax.named_scope("route"):
+    with stage("route"):
         top_w, top_i = _gate(cfg, lp, x)
         top_w, top_i = top_w.reshape(B * S, -1), top_i.reshape(B * S, -1)
     if cfg.moe_backend == "dispatch":
@@ -528,7 +538,7 @@ def _moe_mlp(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
             xt, top_w, top_i, lp["w_gate"], lp["w_up"], lp["w_down"], **kw)
     routed = routed.reshape(B, S, H).astype(x.dtype)
     if cfg.n_shared_experts:
-        with jax.named_scope("shared"):
+        with stage("shared"):
             routed = routed + (jax.nn.silu(x @ lp["ws_gate"])
                                * (x @ lp["ws_up"])) @ lp["ws_down"]
     return routed, aux
@@ -540,14 +550,13 @@ def _dense_mlp(lp: Dict[str, jnp.ndarray], x: jnp.ndarray) -> jnp.ndarray:
 
 # ----------------------------------------------------------------- forward
 
-def _attend(cfg: ModelConfig, lp, h, q_lat, q_pe, w_uv, positions,
-            total_lens, new_lens, page_table, pages, lidx, *,
-            use_pallas: bool, starts=None):
-    """The attention stage of ``_layer_step`` (latent attention over the
-    paged cache plus the out-projection residual), by the path the step
-    form (``starts``: ``llama.packed_rows``) and the geometry pick.
-    Returns the new ``h``."""
-    S = h.shape[1]
+def _attend(cfg: ModelConfig, q_lat, q_pe, positions, total_lens, new_lens,
+            page_table, pages, lidx, *, use_pallas: bool, starts=None):
+    """The attention stage of ``_layer_step``: latent attention over the
+    paged cache, by the path the step form (``starts``:
+    ``llama.packed_rows``) and the geometry pick. Returns the latent
+    output ``[B, S, nh, dkv]`` that ``_expand_and_project`` takes."""
+    S = q_lat.shape[1]
     P = page_table.shape[1]
     ps = pages.shape[-2]
     if starts is not None:
@@ -560,24 +569,22 @@ def _attend(cfg: ModelConfig, lp, h, q_lat, q_pe, w_uv, positions,
                                               _mla_scale(cfg))
         else:
             lat = mla_ragged_attention(cfg, q_lat[0], q_pe[0], *rows)
-        h = _expand_and_project(cfg, lp, h, lat[None], w_uv)
-    elif use_pallas and S == 1:
+        return lat[None]
+    if use_pallas and S == 1:
         from dynamo_tpu.ops.pallas.mla_decode import (
             mla_paged_decode_stacked)
 
-        lat = mla_paged_decode_stacked(q_lat, q_pe, pages, lidx,
-                                       page_table, total_lens,
-                                       _mla_scale(cfg))
-        h = _expand_and_project(cfg, lp, h, lat, w_uv)
-    elif use_pallas:
+        return mla_paged_decode_stacked(q_lat, q_pe, pages, lidx,
+                                        page_table, total_lens,
+                                        _mla_scale(cfg))
+    if use_pallas:
         from dynamo_tpu.ops.pallas.mla_prefill import (
             mla_paged_prefill_stacked)
 
-        lat = mla_paged_prefill_stacked(q_lat, q_pe, pages, lidx,
-                                        page_table, positions, total_lens,
-                                        _mla_scale(cfg))
-        h = _expand_and_project(cfg, lp, h, lat, w_uv)
-    elif S > 1 and P > PAGES_PER_CHUNK:
+        return mla_paged_prefill_stacked(q_lat, q_pe, pages, lidx,
+                                         page_table, positions, total_lens,
+                                         _mla_scale(cfg))
+    if S > 1 and P > PAGES_PER_CHUNK:
         table = _pad_table(page_table, PAGES_PER_CHUNK)
 
         def gather_chunk(c):
@@ -586,14 +593,11 @@ def _attend(cfg: ModelConfig, lp, h, q_lat, q_pe, w_uv, positions,
                 (table.shape[0], PAGES_PER_CHUNK))
             return _gather_ctx(cfg, pages[lidx, tbl])
 
-        h = _mla_attend_blockwise(cfg, lp, h, q_lat, q_pe, w_uv,
-                                  gather_chunk, P, ps, positions,
-                                  total_lens)
-    else:
-        ckv_ctx, kpe_ctx = _gather_ctx(cfg, pages[lidx, page_table])
-        h = _mla_attend(cfg, lp, h, q_lat, q_pe, w_uv, ckv_ctx, kpe_ctx,
-                        positions, total_lens)
-    return h
+        return _latent_blockwise(q_lat, q_pe, gather_chunk, P, ps,
+                                 positions, total_lens, _mla_scale(cfg))
+    ckv_ctx, kpe_ctx = _gather_ctx(cfg, pages[lidx, page_table])
+    return _mla_latent(cfg, q_lat, q_pe, ckv_ctx, kpe_ctx, positions,
+                       total_lens)
 
 
 def _layer_step(cfg: ModelConfig, lp, h, positions, total_lens, new_lens,
@@ -608,25 +612,26 @@ def _layer_step(cfg: ModelConfig, lp, h, positions, total_lens, new_lens,
     the geometry supports them; ``moe_kw`` goes to the grouped
     expert layer. Returns ``(h, pages, aux)``, ``aux`` the expert layer's
     counts (empty for a dense layer)."""
-    # stage names for the device trace, as in models/llama.py
-    with jax.named_scope("layer.attn_in"):
+    with stage("layer.attn_in"):
         q_lat, q_pe, c_kv, k_pe, w_uv = _mla_qkv(cfg, lp, h, positions)
         k_new, v_new = _cache_rows(cfg, c_kv, k_pe)
-    with jax.named_scope("layer.kv_write"):
+    with stage("layer.kv_write"):
         pages = write_rows(pages, lidx, k_new, v_new, page_table,
                            positions, total_lens, new_lens, starts)
-    with jax.named_scope("layer.attn"):
-        h = _attend(cfg, lp, h, q_lat, q_pe, w_uv, positions, total_lens,
-                    new_lens, page_table, pages, lidx,
-                    use_pallas=use_pallas, starts=starts)
-    with jax.named_scope("layer.moe" if moe else "layer.ffn"):
+    with stage("layer.attn"):
+        lat = _attend(cfg, q_lat, q_pe, positions, total_lens, new_lens,
+                      page_table, pages, lidx, use_pallas=use_pallas,
+                      starts=starts)
+    with stage("layer.attn_out"):
+        h = _expand_and_project(cfg, lp, h, lat, w_uv)
+    with stage("layer.moe" if moe else "layer.ffn"):
         x = _rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
         if moe:
             mlp, aux = _moe_mlp(cfg, lp, x, ep_mesh=ep_mesh,
                                 **(moe_kw or {}))
         else:
             mlp, aux = _dense_mlp(lp, x), {}
-    h = h + mlp
+        h = h + mlp
     return h, pages, aux
 
 
@@ -659,8 +664,9 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     use_pallas = (getattr(attn_impl, "pallas_paged_kernel", False)
                   and mla_supports(cfg.kv_lora_rank, pages.shape[-2]))
     K = cfg.first_k_dense_replace
-    starts = packed_rows(packed, new_lens)
-    with jax.named_scope("embed"):
+    with stage("step.inputs"):
+        starts = packed_rows(packed, new_lens)
+    with stage("embed"):
         h = params["embed"][tokens]
     aux = {}
 
@@ -670,7 +676,9 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             lp, lidx = xs
             kw = {}
             if experts:     # stacked, indexed by the MoE layer's number
-                lp, kw = {**lp, **experts}, dict(moe_kw, layer=lidx - K)
+                with stage("layer.moe"):
+                    kw = dict(moe_kw, layer=lidx - K)
+                lp = {**lp, **experts}
             h, pages, aux = _layer_step(
                 cfg, lp, h, positions, total_lens, new_lens, page_table,
                 pages, lidx, moe=moe, use_pallas=use_pallas, ep_mesh=ep_mesh,
@@ -679,18 +687,22 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         return step
 
     if K and "dense_layers" in params:
+        with stage("step.inputs"):
+            layer_ids = jnp.arange(K)
         (h, pages), _ = jax.lax.scan(
-            body(False), (h, pages),
-            (params["dense_layers"], jnp.arange(K)))
+            body(False), (h, pages), (params["dense_layers"], layer_ids))
     if "moe_layers" in params:
         scanned, experts = split_experts(cfg, params["moe_layers"])
-        valid = token_slots(tokens, new_lens, packed)
+        with stage("step.inputs"):
+            valid = token_slots(tokens, new_lens, packed)
+            layer_ids = K + jnp.arange(cfg.num_layers - K)
         (h, pages), aux = jax.lax.scan(
             body(True, experts, valid=valid,
                  use_pallas=grouped_on_chip(attn_impl)), (h, pages),
-            (scanned, K + jnp.arange(cfg.num_layers - K)))
-        aux = sum_aux(aux)
-    with jax.named_scope("logits"):
+            (scanned, layer_ids))
+        with stage("step.counts"):
+            aux = sum_aux(aux)
+    with stage("logits"):
         logits = _logits(cfg, params, h, new_lens, window=logits_window,
                          starts=starts)
     return logits, pages, aux

@@ -70,6 +70,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from dynamo_tpu.engine.stages import stage
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.deepseek import (
     _attn_leaves,
@@ -332,7 +333,7 @@ def _finish(cfg: ModelConfig, lp, h, x, lat, w_uv) -> jnp.ndarray:
     B, S, H = h.shape
     out = jnp.einsum("hnk,hkd->nhd", lat, w_uv.astype(jnp.float32))
     if cfg.attn_gate:
-        with jax.named_scope("gate"):
+        with stage("gate"):
             gamma = jax.nn.sigmoid(jnp.dot(
                 x.reshape(B * S, H), lp["w_og"],
                 preferred_element_type=jnp.float32))
@@ -412,12 +413,12 @@ def full_block(cfg: ModelConfig, lp, h, cache, lidx, st: Step):
     B, S, H = h.shape
     N, nh = B * S, cfg.num_heads
     kv, index = cache["kv"], cache["index"]
-    with jax.named_scope("layer.attn_in"):
+    with stage("layer.attn_in"):
         q_lat, q_pe, c_kv, k_pe, w_uv = _mla_qkv(cfg, lp, h, st.positions)
         k_new, v_new = _cache_rows(cfg, c_kv, k_pe)
         x = _rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
         q_i, k_i, w_i = index_inputs(cfg, lp, x, st.positions)
-    with jax.named_scope("layer.kv_write"):
+    with stage("layer.kv_write"):
         kv = st.write(kv, lidx, k_new, v_new, st.page_table)
         # the index pages as a pool of one array a token
         Li, Np, ps, D = index.shape
@@ -429,14 +430,14 @@ def full_block(cfg: ModelConfig, lp, h, cache, lidx, st: Step):
                 index.reshape(Li, Np, 1, 1, ps, D), lidx, k_i,
                 st.page_table, st.positions, st.new_lens)).reshape(
                     index.shape)
-    with jax.named_scope("layer.attn"):
-        scale, scopes = _mla_scale(cfg), ("index/score", "index/topk")
+    with stage("layer.attn"):
+        scale = _mla_scale(cfg)
         q_i = q_i.astype(index.dtype)
         if st.kernel:
             one, bias = sl.select_split(
                 q_i, w_i, index, lidx, st.page_table, st.rows,
-                st.total_lens, cfg.index_topk, scopes=scopes, **st.walk)
-            with jax.named_scope("sparse"):
+                st.total_lens, cfg.index_topk, **st.walk)
+            with stage("sparse"):
                 q = _kernel_queries(q_lat, q_pe, scale, kv.dtype)
                 lat = None
                 if bias is not None:
@@ -450,12 +451,13 @@ def full_block(cfg: ModelConfig, lp, h, cache, lidx, st: Step):
         else:
             sel, live = sl.select(
                 q_i, w_i, index, lidx, st.page_table, st.rows,
-                st.total_lens, cfg.index_topk, scopes=scopes, **st.walk)
-            with jax.named_scope("sparse"):
+                st.total_lens, cfg.index_topk, **st.walk)
+            with stage("sparse"):
                 lat = sl.sparse_attend(
                     q_lat.reshape(N, nh, -1), q_pe.reshape(N, nh, -1), kv,
                     lidx, st.page_table[st.rows.row], sel,
                     live & st.rows.valid[:, None], scale).swapaxes(0, 1)
+    with stage("layer.attn_out"):
         h = _finish(cfg, lp, h, x, lat, w_uv)
     return h, {**cache, "kv": kv, "index": index}
 
@@ -469,17 +471,17 @@ def window_block(wcfg: ModelConfig, lp, h, cache, widx, st: Step):
     win = cache["win"]
     Lw, n_slots, Rp, _two, _one, ps, dkv = win.shape
     ring = Rp * ps
-    with jax.named_scope("layer.attn_in"):
+    with stage("layer.attn_in"):
         q_lat, q_pe, c_kv, k_pe, w_uv = _mla_qkv(wcfg, lp, h, st.positions)
         k_new, v_new = _cache_rows(wcfg, c_kv, k_pe)
         x = _rms_norm(h, lp["attn_norm"], wcfg.rms_norm_eps)
-    with jax.named_scope("layer.kv_write"):
+    with stage("layer.kv_write"):
         pool = st.write(win.reshape(Lw, n_slots * Rp, 2, 1, ps, dkv), widx,
                         k_new, v_new,
                         sl.ring_table(st.rows.slot, Rp, twice=True), ring)
         win = pool.reshape(win.shape)
-    with jax.named_scope("layer.attn"):
-        with jax.named_scope("window"):
+    with stage("layer.attn"):
+        with stage("window"):
             scale = _mla_scale(wcfg)
             if st.kernel:
                 # the rings as pages: each row's ring streamed with its
@@ -503,6 +505,7 @@ def window_block(wcfg: ModelConfig, lp, h, cache, widx, st: Step):
                     q_lat.reshape(N, nh, -1), q_pe.reshape(N, nh, -1), win,
                     widx, st.rows, st.total_lens, wcfg.swa_window, scale,
                     **st.walk).swapaxes(0, 1)
+    with stage("layer.attn_out"):
         h = _finish(wcfg, lp, h, x, lat, w_uv)
     return h, {**cache, "win": win}
 
@@ -517,14 +520,14 @@ def sparse_block(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
 
     B, S, H = x.shape
     xt = x.reshape(B * S, H)
-    with jax.named_scope("route"):
+    with stage("route"):
         top_w, top_i = _gate(cfg, lp, x)
     out, aux = grouped_experts(
         xt, top_w.reshape(B * S, -1), top_i.reshape(B * S, -1),
         lp["w_gate"], lp["w_up"], lp["w_down"],
         first_expert=cfg.expert_offset, num_routed=cfg.num_experts, **kw)
     if cfg.n_shared_experts:
-        with jax.named_scope("shared"):
+        with stage("shared"):
             out = out + jnp.dot(
                 jax.nn.silu(xt @ lp["ws_gate"]) * (xt @ lp["ws_up"]),
                 lp["ws_down"], preferred_element_type=jnp.float32)
@@ -532,11 +535,12 @@ def sparse_block(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
 
 
 def _ffn(cfg, lp, h, moe_kw):
-    with jax.named_scope("layer.moe"):
+    with stage("layer.moe"):
         out, aux = sparse_block(
             cfg, lp, _rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps),
             **moe_kw)
-    return h + out, aux
+        h = h + out
+    return h, aux
 
 
 # ----------------------------------------------------------------- forward
@@ -581,38 +585,43 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             "(held range, shared expert) runs the grouped layer only")
     # (a slot past the pool's is held to its last: a write to no slot would
     # be dropped in silence)
-    slots = jnp.minimum(page_table[:, -1], pages["win"].shape[1] - 1)
-    page_table = page_table[:, :-1]
-    st = Step(tokens, positions, page_table, total_lens, new_lens, slots,
-              packed_rows(packed, new_lens),
-              on_kernels(cfg, attn_impl, pages["kv"].shape[-2]))
+    with stage("step.inputs"):
+        slots = jnp.minimum(page_table[:, -1], pages["win"].shape[1] - 1)
+        page_table = page_table[:, :-1]
+        st = Step(tokens, positions, page_table, total_lens, new_lens,
+                  slots, packed_rows(packed, new_lens),
+                  on_kernels(cfg, attn_impl, pages["kv"].shape[-2]))
     wcfg = cfg.window_cfg()
     K = cfg.first_k_dense_replace
     G, P, tail = cfg.layer_pattern()
-    with jax.named_scope("embed"):
+    with stage("embed"):
         h = params["embed"][tokens]
-    moe_kw = dict(valid=token_slots(tokens, new_lens, packed),
-                  use_pallas=grouped_on_chip(attn_impl))
+    with stage("step.inputs"):
+        moe_kw = dict(valid=token_slots(tokens, new_lens, packed),
+                      use_pallas=grouped_on_chip(attn_impl))
     lf, lw = params["layers"]["full"], params["layers"]["win"]
     full_scanned, full_experts = split_experts(cfg, lf)
     # the window layers as ONE stack over periods and places: the loops
     # below carry indices alone, each layer's leaves are read where they
     # lie (``moe.flat_layers``) and the grouped layer indexes the experts
-    win_scanned, win_experts = split_experts(cfg, flat_layers(lw))
+    with stage("layer.weights"):
+        win_scanned, win_experts = split_experts(cfg, flat_layers(lw))
 
     def dense(carry, xs):
         h, cache = carry
         lp, lidx = xs
         h, cache = full_block(cfg, lp, h, cache, lidx, st)
-        with jax.named_scope("layer.ffn"):
+        with stage("layer.ffn"):
             h = h + _dense_mlp(lp, _rms_norm(h, lp["mlp_norm"],
                                              cfg.rms_norm_eps))
         return (h, cache), None
 
     def full(carry, p):
         h, cache = carry
-        fp = layer_at(full_scanned, p)
-        h, cache = full_block(cfg, fp, h, cache, K + p, st)
+        with stage("layer.weights"):
+            fp = layer_at(full_scanned, p)
+            lidx = K + p
+        h, cache = full_block(cfg, fp, h, cache, lidx, st)
         h, aux = _ffn(cfg, {**fp, **full_experts}, h, dict(moe_kw, layer=p))
         return (h, cache), aux
 
@@ -621,25 +630,36 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 
         def window(carry, j):
             h, cache = carry
-            widx = p * G + j
-            lp = layer_at(win_scanned, widx)
+            with stage("layer.weights"):
+                widx = p * G + j
+                lp = layer_at(win_scanned, widx)
             h, cache = window_block(wcfg, lp, h, cache, widx, st)
             h, aux = _ffn(cfg, {**lp, **win_experts}, h,
                           dict(moe_kw, layer=widx))
             return (h, cache), aux
 
-        carry, aux_w = jax.lax.scan(window, carry, jnp.arange(G))
-        return carry, {k: aux_f[k] + jnp.sum(aux_w[k]) for k in aux_f}
+        with stage("step.inputs"):
+            places = jnp.arange(G)
+        carry, aux_w = jax.lax.scan(window, carry, places)
+        with stage("step.counts"):
+            aux = {k: aux_f[k] + jnp.sum(aux_w[k]) for k in aux_f}
+        return carry, aux
 
     if K:
+        with stage("step.inputs"):
+            layer_ids = jnp.arange(K)
         (h, pages), _ = jax.lax.scan(dense, (h, pages),
-                                     (params["dense_layers"], jnp.arange(K)))
-    (h, pages), aux = jax.lax.scan(period, (h, pages), jnp.arange(P))
-    aux = sum_aux(aux)
+                                     (params["dense_layers"], layer_ids))
+    with stage("step.inputs"):
+        periods = jnp.arange(P)
+    (h, pages), aux = jax.lax.scan(period, (h, pages), periods)
+    with stage("step.counts"):
+        aux = sum_aux(aux)
     for t in range(tail):
         (h, pages), aux_t = full((h, pages), P + t)
-        aux = {k: aux[k] + aux_t[k].astype(jnp.int32) for k in aux}
-    with jax.named_scope("logits"):
+        with stage("step.counts"):
+            aux = {k: aux[k] + aux_t[k].astype(jnp.int32) for k in aux}
+    with stage("logits"):
         logits = _logits(cfg, params, h, new_lens, starts=st.starts)
     return logits, pages, aux
 

@@ -27,6 +27,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from dynamo_tpu.engine.stages import stage
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.llama import (
     _select_last,
@@ -110,17 +111,28 @@ def _project_qkv(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
     return q, k, v
 
 
-def _finish_layer(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
-                  h: jnp.ndarray, attn: jnp.ndarray) -> jnp.ndarray:
+def _finish_attn(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
+                 h: jnp.ndarray, attn: jnp.ndarray) -> jnp.ndarray:
+    """Out-projection, its norm and the residual."""
     B, S, _ = h.shape
-    eps = cfg.rms_norm_eps
     attn_out = quant.mm(lp, "wo", attn.reshape(B, S, cfg.q_size))
-    h = h + _rms_norm(attn_out, lp["post_attn_norm"], eps)
+    return h + _rms_norm(attn_out, lp["post_attn_norm"], cfg.rms_norm_eps)
+
+
+def _ffn(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
+         h: jnp.ndarray) -> jnp.ndarray:
+    """Gated MLP between its two norms, and the residual."""
+    eps = cfg.rms_norm_eps
     x = _rms_norm(h, lp["pre_ffw_norm"], eps)
     act = (jax.nn.gelu(quant.mm(lp, "w_gate", x), approximate=True)
            * quant.mm(lp, "w_up", x))
     mlp = quant.mm(lp, "w_down", act)
     return h + _rms_norm(mlp, lp["post_ffw_norm"], eps)
+
+
+def _finish_layer(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
+                  h: jnp.ndarray, attn: jnp.ndarray) -> jnp.ndarray:
+    return _ffn(cfg, lp, _finish_attn(cfg, lp, h, attn))
 
 
 def _logits(cfg: ModelConfig, params: Params, h: jnp.ndarray,
@@ -176,29 +188,41 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     math."""
     if not getattr(attn_impl, "supports_window_softcap", False):
         attn_impl = None
-    starts = packed_rows(packed, new_lens)
     sm_scale = _sm_scale(cfg)
     softcap = cfg.attn_logit_softcap or None  # static: both paths accept
-    windows = layer_windows(cfg)
-    h = _embed(cfg, params, tokens)
+    # the stages of ``llama.forward`` (engine/stages.py)
+    with stage("step.inputs"):
+        starts = packed_rows(packed, new_lens)
+        windows = layer_windows(cfg)
+    with stage("embed"):
+        h = _embed(cfg, params, tokens)
 
     def body(carry, xs):
         h, pages = carry
         lp, lidx, win = xs
-        q, k, v = _project_qkv(cfg, lp, h, positions)
-        pages = write_rows(pages, lidx, k, v, page_table, positions,
-                           total_lens, new_lens, starts)
-        attn = attend_rows(attn_impl, q, pages, lidx, page_table, positions,
-                           total_lens, new_lens, sm_scale, starts,
-                           window=win, softcap=softcap)
-        h = _finish_layer(cfg, lp, h, attn)
+        with stage("layer.attn_in"):
+            q, k, v = _project_qkv(cfg, lp, h, positions)
+        with stage("layer.kv_write"):
+            pages = write_rows(pages, lidx, k, v, page_table, positions,
+                               total_lens, new_lens, starts)
+        with stage("layer.attn"):
+            attn = attend_rows(attn_impl, q, pages, lidx, page_table,
+                               positions, total_lens, new_lens, sm_scale,
+                               starts, window=win, softcap=softcap)
+        with stage("layer.attn_out"):
+            h = _finish_attn(cfg, lp, h, attn)
+        with stage("layer.ffn"):
+            h = _ffn(cfg, lp, h)
         return (h, pages), None
 
+    with stage("step.inputs"):
+        layer_ids = jnp.arange(cfg.num_layers)
     (h, pages), _ = jax.lax.scan(
-        body, (h, pages),
-        (params["layers"], jnp.arange(cfg.num_layers), windows))
-    return _logits(cfg, params, h, new_lens, window=logits_window,
-                   starts=starts), pages
+        body, (h, pages), (params["layers"], layer_ids, windows))
+    with stage("logits"):
+        logits = _logits(cfg, params, h, new_lens, window=logits_window,
+                         starts=starts)
+    return logits, pages
 
 
 forward.supports_packed = True

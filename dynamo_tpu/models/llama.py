@@ -25,6 +25,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from dynamo_tpu.engine.stages import stage
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.ops.attention import (
     paged_attention,
@@ -165,13 +166,18 @@ def _finish_attn(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
     return h + quant.mm(lp, "wo", attn.reshape(B, S, cfg.q_size))
 
 
-def _finish_layer(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
-                  h: jnp.ndarray, attn: jnp.ndarray) -> jnp.ndarray:
-    """Shared post-attention math: out-proj residual + gated MLP residual."""
-    h = _finish_attn(cfg, lp, h, attn)
+def _ffn(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
+         h: jnp.ndarray) -> jnp.ndarray:
+    """Gated MLP residual."""
     x = _rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
     act = jax.nn.silu(quant.mm(lp, "w_gate", x)) * quant.mm(lp, "w_up", x)
     return h + quant.mm(lp, "w_down", act)
+
+
+def _finish_layer(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
+                  h: jnp.ndarray, attn: jnp.ndarray) -> jnp.ndarray:
+    """Shared post-attention math: out-proj residual + gated MLP residual."""
+    return _ffn(cfg, lp, _finish_attn(cfg, lp, h, attn))
 
 
 def _select_last(h: jnp.ndarray, new_lens: jnp.ndarray,
@@ -297,32 +303,36 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     Returns (logits [B, vocab] at each sequence's last real new token, pages).
     """
     sm_scale = cfg.head_dim ** -0.5
-    starts = packed_rows(packed, new_lens)
-    # the named scopes are the stage names a device trace shows for the
-    # operations traced under them (docs/observability.md)
-    with jax.named_scope("embed"):
+    # the stages are the names a device trace shows for the operations
+    # traced under them (engine/stages.py, docs/observability.md)
+    with stage("step.inputs"):
+        starts = packed_rows(packed, new_lens)
+    with stage("embed"):
         h = params["embed"][tokens]  # [B, S, H]
 
     def body(carry, xs):
         h, pages = carry
         lp, lidx = xs
-        with jax.named_scope("layer.attn_in"):
+        with stage("layer.attn_in"):
             q, k, v = _project_qkv(cfg, lp, h, positions)
-        with jax.named_scope("layer.kv_write"):
+        with stage("layer.kv_write"):
             pages = write_rows(pages, lidx, k, v, page_table, positions,
                                total_lens, new_lens, starts)
-        with jax.named_scope("layer.attn"):
+        with stage("layer.attn"):
             attn = attend_rows(attn_impl, q, pages, lidx, page_table,
                                positions, total_lens, new_lens, sm_scale,
                                starts, **visibility(cfg))
-        with jax.named_scope("layer.ffn"):
-            h = _finish_layer(cfg, lp, h, attn)
+        with stage("layer.attn_out"):
+            h = _finish_attn(cfg, lp, h, attn)
+        with stage("layer.ffn"):
+            h = _ffn(cfg, lp, h)
         return (h, pages), None
 
+    with stage("step.inputs"):
+        layer_ids = jnp.arange(cfg.num_layers)
     (h, pages), _ = jax.lax.scan(
-        body, (h, pages),
-        (params["layers"], jnp.arange(cfg.num_layers)))
-    with jax.named_scope("logits"):
+        body, (h, pages), (params["layers"], layer_ids))
+    with stage("logits"):
         logits = _logits(cfg, params, h, new_lens, window=logits_window,
                          starts=starts)
     return logits, pages

@@ -55,12 +55,14 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from dynamo_tpu.engine.stages import stage
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.deepseek import (
     _attend,
     _attn_leaves,
     _cache_rows,
     _dense_mlp,
+    _expand_and_project,
     _mla_qkv,
 )
 from dynamo_tpu.models.llama import (
@@ -179,7 +181,7 @@ def expert_branch(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
 
     B, S, H = x.shape
     xt = x.reshape(B * S, H)
-    with jax.named_scope("route"):
+    with stage("route"):
         top_w, top_i = _gate(cfg, lp, xt)
     out, aux = grouped_experts(
         xt, top_w, top_i, lp["w_gate"], lp["w_up"], lp["w_down"],
@@ -190,17 +192,29 @@ def expert_branch(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
 # ----------------------------------------------------------------- forward
 
 def _attention_block(cfg: ModelConfig, lp, h, positions, total_lens,
-                     new_lens, page_table, pages, cache_layer, *,
+                     new_lens, page_table, pages, lidx, which: int, *,
                      use_pallas: bool, starts):
-    """``h + MLA(norm(h))`` against cache layer ``cache_layer``. Returns
+    """``h + MLA(norm(h))`` of attention block ``which`` (0 or 1) of double
+    layer ``lidx``, against cache layer ``2 lidx + which``, under the
+    stages ``layer.attn<which>/{in,kv_write,attn,out}``: where every
+    family cuts a token mixer (``engine/stages.py``). Returns
     ``(h, pages)``."""
-    q_lat, q_pe, c_kv, k_pe, w_uv = _mla_qkv(cfg, lp, h, positions)
-    k_new, v_new = _cache_rows(cfg, c_kv, k_pe)
-    pages = write_rows(pages, cache_layer, k_new, v_new, page_table,
-                       positions, total_lens, new_lens, starts)
-    h = _attend(cfg, lp, h, q_lat, q_pe, w_uv, positions, total_lens,
-                new_lens, page_table, pages, cache_layer,
-                use_pallas=use_pallas, starts=starts)
+    name = f"layer.attn{which}"
+    with stage(f"{name}/in"):
+        # (no ``+ 0`` traced for the first block: the programs are the
+        # parent's, operation for operation)
+        cache_layer = 2 * lidx + 1 if which else 2 * lidx
+        q_lat, q_pe, c_kv, k_pe, w_uv = _mla_qkv(cfg, lp, h, positions)
+        k_new, v_new = _cache_rows(cfg, c_kv, k_pe)
+    with stage(f"{name}/kv_write"):
+        pages = write_rows(pages, cache_layer, k_new, v_new, page_table,
+                           positions, total_lens, new_lens, starts)
+    with stage(f"{name}/attn"):
+        lat = _attend(cfg, q_lat, q_pe, positions, total_lens, new_lens,
+                      page_table, pages, cache_layer, use_pallas=use_pallas,
+                      starts=starts)
+    with stage(f"{name}/out"):
+        h = _expand_and_project(cfg, lp, h, lat, w_uv)
     return h, pages
 
 
@@ -212,22 +226,20 @@ def _layer_step(cfg: ModelConfig, lp, h, positions, total_lens, new_lens,
     Returns ``(h, pages, aux)``, ``aux`` the expert branch's counts."""
     eps = cfg.rms_norm_eps
     rows = dict(use_pallas=use_pallas, starts=starts)
-    # stage names for the device trace (docs/observability.md)
-    with jax.named_scope("layer.attn0"):
-        a0, pages = _attention_block(
-            cfg, lp["attn0"], h, positions, total_lens, new_lens,
-            page_table, pages, 2 * lidx, **rows)
+    a0, pages = _attention_block(
+        cfg, lp["attn0"], h, positions, total_lens, new_lens,
+        page_table, pages, lidx, 0, **rows)
+    with stage("layer.moe"):
+        # the one norm both branches of the first half read
         x0 = _rms_norm(a0, lp["attn0"]["mlp_norm"], eps)
-    with jax.named_scope("layer.moe"):
         s, aux = expert_branch(cfg, lp, x0, **(moe_kw or {}))
-    with jax.named_scope("layer.ffn0"):
+    with stage("layer.ffn0"):
         b0 = a0 + _dense_mlp(lp["ffn0"], x0)
-    with jax.named_scope("layer.attn1"):
-        a1, pages = _attention_block(
-            cfg, lp["attn1"], b0, positions, total_lens, new_lens,
-            page_table, pages, 2 * lidx + 1, **rows)
+    a1, pages = _attention_block(
+        cfg, lp["attn1"], b0, positions, total_lens, new_lens,
+        page_table, pages, lidx, 1, **rows)
+    with stage("layer.ffn1"):
         x1 = _rms_norm(a1, lp["attn1"]["mlp_norm"], eps)
-    with jax.named_scope("layer.ffn1"):
         h = a1 + _dense_mlp(lp["ffn1"], x1) + s
     return h, pages, aux
 
@@ -255,13 +267,15 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             "(held range, zero-compute experts) runs the grouped layer only")
     use_pallas = (getattr(attn_impl, "pallas_paged_kernel", False)
                   and mla_supports(cfg.kv_lora_rank, pages.shape[-2]))
-    starts = packed_rows(packed, new_lens)
-    with jax.named_scope("embed"):
+    with stage("step.inputs"):
+        starts = packed_rows(packed, new_lens)
+    with stage("embed"):
         h = params["embed"][tokens]
     scanned, experts = split_experts(cfg, params["layers"])
     # slots that hold no token route to no expert, identity ones included
-    moe_kw = dict(valid=token_slots(tokens, new_lens, packed),
-                  use_pallas=grouped_on_chip(attn_impl))
+    with stage("step.inputs"):
+        moe_kw = dict(valid=token_slots(tokens, new_lens, packed),
+                      use_pallas=grouped_on_chip(attn_impl))
 
     def step(carry, xs):
         h, pages = carry
@@ -272,12 +286,15 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             moe_kw=dict(moe_kw, layer=lidx), starts=starts)
         return (h, pages), aux
 
-    (h, pages), aux = jax.lax.scan(
-        step, (h, pages), (scanned, jnp.arange(cfg.num_layers)))
-    with jax.named_scope("logits"):
+    with stage("step.inputs"):
+        layer_ids = jnp.arange(cfg.num_layers)
+    (h, pages), aux = jax.lax.scan(step, (h, pages), (scanned, layer_ids))
+    with stage("logits"):
         logits = _logits(cfg, params, h, new_lens, window=logits_window,
                          starts=starts)
-    return logits, pages, sum_aux(aux)
+    with stage("step.counts"):
+        aux = sum_aux(aux)
+    return logits, pages, aux
 
 
 forward.supports_packed = True

@@ -26,6 +26,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from dynamo_tpu.engine.stages import stage
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.llama import (
     Params,
@@ -224,7 +225,7 @@ def grouped_experts(xt: jnp.ndarray, top_w: jnp.ndarray,
     if live is not None:
         # an unrouted assignment sorts behind every expert's
         flat_e = jnp.where(live, flat_e, E)
-    with jax.named_scope("sort"):
+    with stage("sort"):
         sorted_t, order, first = _sorted_picks(flat_e, E, k)
         counts = first[1:] - first[:-1]                     # [E]
     if whole:               # every pick of a valid token is computed here
@@ -252,26 +253,26 @@ def grouped_experts(xt: jnp.ndarray, top_w: jnp.ndarray,
         tm = 16 if A <= 2048 else 128
         held = T * min(k, E)                    # bound on sum c
         n_tiles = -(-held // tm) + min(E, held)  # ... on sum ceil(c / tm)
-        with jax.named_scope("sort"):
+        with stage("sort"):
             row_tok, _, pos, tile_expert, num_tiles = _tile_plan(
                 sorted_t, order, first, tm=tm, n_tiles=n_tiles)
-        with jax.named_scope("experts"):
+        with stage("experts"):
             ys = moe_grouped(
                 xt[row_tok], tile_expert, num_tiles.reshape(1),
                 jnp.asarray(layer, i32).reshape(1), w_gate, w_up, w_down,
                 tm=tm)
     else:
         w_gate, w_up, w_down = w_gate[layer], w_up[layer], w_down[layer]
-        with jax.named_scope("experts"):
+        with stage("experts"):
             xs = xt[sorted_t]                              # [A, H]
             act = (jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, counts))
                    * jax.lax.ragged_dot(xs, w_up, counts))
             ys = jax.lax.ragged_dot(
                 act.astype(xt.dtype), w_down, counts,
                 preferred_element_type=jnp.float32)        # [A, H]
-        with jax.named_scope("sort"):
+        with stage("sort"):
             pos = _unsort(order, jnp.arange(A, dtype=i32))
-    with jax.named_scope("combine"):
+    with stage("combine"):
         routed = (flat_e < E).reshape(T, k, 1)
         # rows no group owns are never written: select, do not multiply
         y = jnp.where(routed, ys[jnp.minimum(pos, ys.shape[0] - 1)]
@@ -299,7 +300,7 @@ def moe_mlp_counted(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
     ``kw`` goes to ``grouped_experts``."""
     B, S, H = x.shape
     xt = x.reshape(B * S, H)
-    with jax.named_scope("route"):
+    with stage("route"):
         top_w, top_i = _router_topk(cfg, lp, xt)           # [T, k]
     out, aux = grouped_experts(
         xt, top_w, top_i, lp["w_gate"], lp["w_up"], lp["w_down"], **kw)
@@ -427,16 +428,17 @@ def _moe_layer_tail(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
     """Returns ``(h, aux)``: the grouped layer's counts (experts touched,
     assignments), or the dispatch backend's dropped assignments. ``kw``
     goes to ``grouped_experts``."""
-    h = _finish_attn(cfg, lp, h, attn)
-    # stage names for the device trace, as in models/deepseek.py
-    with jax.named_scope("layer.moe"):
+    with stage("layer.attn_out"):
+        h = _finish_attn(cfg, lp, h, attn)
+    with stage("layer.moe"):
         x = _rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
         if cfg.moe_backend == "dispatch":
             mlp, dropped = moe_mlp_dispatch(cfg, lp, x, ep_mesh=ep_mesh)
             aux = {"moe_dropped_assignments": dropped}
         else:
             mlp, aux = moe_mlp_counted(cfg, lp, x, **kw)
-    return h + mlp, aux
+        h = h + mlp
+    return h, aux
 
 
 EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
@@ -548,10 +550,12 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     ``moe_dropped_assignments`` — which the engine forwards to worker
     stats)."""
     sm_scale = cfg.head_dim ** -0.5
-    starts = packed_rows(packed, new_lens)
-    with jax.named_scope("embed"):
+    with stage("step.inputs"):
+        starts = packed_rows(packed, new_lens)
+    with stage("embed"):
         h = params["embed"][tokens]
-    valid = token_slots(tokens, new_lens, packed)
+    with stage("step.inputs"):
+        valid = token_slots(tokens, new_lens, packed)
     scanned, experts = split_experts(cfg, params["layers"])
     kw = (dict(valid=valid, use_pallas=grouped_on_chip(attn_impl))
           if experts else {})
@@ -559,13 +563,12 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     def body(carry, xs):
         h, pages = carry
         lp, lidx = xs
-        # the stage names of ``llama.forward`` (docs/observability.md)
-        with jax.named_scope("layer.attn_in"):
+        with stage("layer.attn_in"):
             q, k, v = _project_qkv(cfg, lp, h, positions)
-        with jax.named_scope("layer.kv_write"):
+        with stage("layer.kv_write"):
             pages = write_rows(pages, lidx, k, v, page_table, positions,
                                total_lens, new_lens, starts)
-        with jax.named_scope("layer.attn"):
+        with stage("layer.attn"):
             attn = attend_rows(attn_impl, q, pages, lidx, page_table,
                                positions, total_lens, new_lens, sm_scale,
                                starts, **visibility(cfg))
@@ -574,12 +577,15 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                                  ep_mesh=ep_mesh, **grouped)
         return (h, pages), aux
 
-    (h, pages), aux = jax.lax.scan(
-        body, (h, pages), (scanned, jnp.arange(cfg.num_layers)))
-    with jax.named_scope("logits"):
+    with stage("step.inputs"):
+        layer_ids = jnp.arange(cfg.num_layers)
+    (h, pages), aux = jax.lax.scan(body, (h, pages), (scanned, layer_ids))
+    with stage("logits"):
         logits = _logits(cfg, params, h, new_lens, window=logits_window,
                          starts=starts)
-    return logits, pages, sum_aux(aux)
+    with stage("step.counts"):
+        aux = sum_aux(aux)
+    return logits, pages, aux
 
 
 forward.supports_packed = True

@@ -46,6 +46,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from dynamo_tpu.engine.stages import stage
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.llama import (
     MOE_INIT_GAIN,
@@ -185,7 +186,7 @@ def init_params(cfg: ModelConfig, rng: jax.Array,
 
 def _ffn(cfg: ModelConfig, lp, h):
     """``h + rms(SwiGLU(h); w_2)``."""
-    with jax.named_scope("layer.ffn"):
+    with stage("layer.ffn"):
         act = jax.nn.silu(h @ lp["w_gate"]) * (h @ lp["w_up"])
         return h + _rms_norm(act @ lp["w_down"], lp["ffn_norm"],
                              cfg.rms_norm_eps)
@@ -199,22 +200,22 @@ def full_mixer(cfg: ModelConfig, lp, h, positions, total_lens, new_lens,
     B, S, _ = h.shape
     Hq, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     eps = cfg.rms_norm_eps
-    with jax.named_scope("layer.attn_in"):
+    with stage("layer.attn_in"):
         q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
-        with jax.named_scope("qk_norm"):
+        with stage("qk_norm"):
             q = _rms_norm(q, lp["q_norm"], eps).reshape(B, S, Hq, Dh)
             k = _rms_norm(k, lp["k_norm"], eps).reshape(B, S, Hkv, Dh)
         v = v.reshape(B, S, Hkv, Dh)
-    with jax.named_scope("layer.kv_write"):
+    with stage("layer.kv_write"):
         kv = write_rows(cache["kv"], lidx, k, v, page_table, positions,
                         total_lens, new_lens, starts)
-    with jax.named_scope("layer.attn"):
+    with stage("layer.attn"):
         attn = attend_rows(attn_impl, q, kv, lidx, page_table, positions,
                            total_lens, new_lens, Dh ** -0.5, starts)
-    with jax.named_scope("layer.attn_out"):
-        out = _rms_norm(attn.reshape(B, S, Hq * Dh) @ lp["wo"],
-                        lp["mixer_norm"], eps)
-    return h + out, {**cache, "kv": kv}
+    with stage("layer.attn_out"):
+        h = h + _rms_norm(attn.reshape(B, S, Hq * Dh) @ lp["wo"],
+                          lp["mixer_norm"], eps)
+    return h, {**cache, "kv": kv}
 
 
 # ----------------------------------------------------------------- forward
@@ -234,13 +235,14 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     keys and values in their pages and nothing else: no layer rotates by
     them. No ``logits_window``, as in ``qwen3_next.forward``."""
     on_chip = grouped_on_chip(attn_impl)
-    slots, page_table = page_table[:, -1], page_table[:, :-1]
     B, S = tokens.shape
-    starts = packed_rows(packed, new_lens)
-    rows = gdn.token_rows(
-        B * S, starts if packed else jnp.arange(B, dtype=jnp.int32) * S,
-        new_lens, total_lens, slots)
-    with jax.named_scope("embed"):
+    with stage("step.inputs"):
+        slots, page_table = page_table[:, -1], page_table[:, :-1]
+        starts = packed_rows(packed, new_lens)
+        rows = gdn.token_rows(
+            B * S, starts if packed else jnp.arange(B, dtype=jnp.int32) * S,
+            new_lens, total_lens, slots)
+    with stage("embed"):
         h = params["embed"][tokens]
     G = cfg.full_attention_interval - 1
     eps = cfg.rms_norm_eps
@@ -248,19 +250,23 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     # leaves read where they lie inside the loop (``moe.flat_layers``; as
     # the periods' scanned slices they were 1.3 GB of temporaries at the
     # published widths: the sandbox's compile for the v5e, PERF.md PR 51)
-    lg = flat_layers(params["layers"]["gdn"])
+    with stage("layer.weights"):
+        lg = flat_layers(params["layers"]["gdn"])
 
     def period(carry, xs):
         fp, p = xs
 
         def linear(j, carry):
             h, cache = carry
-            gidx = p * G + j
-            lp = layer_at(lg, gidx)
+            with stage("layer.weights"):
+                gidx = p * G + j
+                lp = layer_at(lg, gidx)
+            with stage("layer.gdn_in"):
+                x = h.reshape(B * S, -1)
             out, cache = gated_delta_net(
-                cfg, lp, h.reshape(B * S, -1), cache, gidx, rows,
-                use_pallas=on_chip, several=S > 1)
-            with jax.named_scope("layer.gdn_out"):
+                cfg, lp, x, cache, gidx, rows, use_pallas=on_chip,
+                several=S > 1)
+            with stage("layer.gdn_out"):
                 h = h + _rms_norm(out.reshape(h.shape), lp["mixer_norm"],
                                   eps)
             return _ffn(cfg, lp, h), cache
@@ -271,10 +277,11 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                               starts=starts)
         return (_ffn(cfg, fp, h), cache), None
 
+    with stage("step.inputs"):
+        periods = jnp.arange(cfg.num_periods)
     (h, pages), _ = jax.lax.scan(
-        period, (h, pages),
-        (params["layers"]["full"], jnp.arange(cfg.num_periods)))
-    with jax.named_scope("logits"):
+        period, (h, pages), (params["layers"]["full"], periods))
+    with stage("logits"):
         logits = _logits(cfg, params, h, new_lens, starts=starts)
     return logits, pages
 

@@ -57,6 +57,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from dynamo_tpu.engine.stages import stage
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.llama import (
     MOE_INIT_GAIN,
@@ -221,12 +222,12 @@ def sparse_block(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
 
     B, S, H = x.shape
     xt = x.reshape(B * S, H)
-    with jax.named_scope("route"):
+    with stage("route"):
         top_w, top_i = _router_topk(cfg, lp, xt)
     out, aux = grouped_experts(
         xt, top_w, top_i, lp["w_gate"], lp["w_up"], lp["w_down"],
         first_expert=cfg.expert_offset, num_routed=cfg.num_experts, **kw)
-    with jax.named_scope("shared"):
+    with stage("shared"):
         act = jax.nn.silu(xt @ lp["ws_gate"]) * (xt @ lp["ws_up"])
         gate = jax.nn.sigmoid(jnp.dot(
             xt, lp["w_sg"], preferred_element_type=jnp.float32))
@@ -236,10 +237,11 @@ def sparse_block(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
 
 
 def _ffn(cfg, lp, h, moe_kw):
-    with jax.named_scope("layer.moe"):
+    with stage("layer.moe"):
         out, aux = sparse_block(
             cfg, lp, zc_norm(h, lp["mlp_norm"], cfg.rms_norm_eps), **moe_kw)
-    return h + out, aux
+        h = h + out
+    return h, aux
 
 
 def _l2norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
@@ -261,7 +263,7 @@ def gated_delta_net(cfg: ModelConfig, lp, x, cache, gidx, rows: gdn.Rows,
     Hk, Dk = cfg.linear_num_key_heads, cfg.linear_key_head_dim
     Hv, Dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
     f32 = jnp.float32
-    with jax.named_scope("layer.gdn_in"):
+    with stage("layer.gdn_in"):
         qkvz = x @ lp["w_qkvz"]
         ba = jnp.dot(x, lp["w_ba"], preferred_element_type=f32)
         n_conv = cfg.linear_conv_dim
@@ -277,13 +279,13 @@ def gated_delta_net(cfg: ModelConfig, lp, x, cache, gidx, rows: gdn.Rows,
             beta = 2.0 * beta
         g = -jnp.exp(lp["A_log"].astype(f32)) * jax.nn.softplus(
             ba[:, Hv:] + lp["dt_bias"].astype(f32))
-    with jax.named_scope("layer.gdn"):
+    with stage("layer.gdn"):
         dt = x.dtype
         o, state = gdn.gated_delta_rule(
             q.astype(dt), k.astype(dt), v.astype(dt), g, beta,
             cache["state"], gidx, rows, use_pallas=use_pallas,
             several=several)
-    with jax.named_scope("layer.gdn_out"):
+    with stage("layer.gdn_out"):
         var = jnp.mean(o * o, axis=-1, keepdims=True)
         y = (o * jax.lax.rsqrt(var + cfg.rms_norm_eps)
              * lp["o_norm"].astype(f32) * jax.nn.silu(z.astype(f32)))
@@ -298,11 +300,13 @@ def gdn_mixer(cfg: ModelConfig, lp, h, cache, gidx, rows: gdn.Rows, *,
     flat axis of ``B * S`` slots (``S == 1``: a decode step, a slot a
     row). Returns ``(h, cache)``."""
     B, S, H = h.shape
-    with jax.named_scope("layer.gdn_in"):
+    with stage("layer.gdn_in"):
         x = zc_norm(h, lp["attn_norm"], cfg.rms_norm_eps).reshape(B * S, H)
     out, cache = gated_delta_net(cfg, lp, x, cache, gidx, rows,
                                  use_pallas=use_pallas, several=S > 1)
-    return h + out.reshape(B, S, H), cache
+    with stage("layer.gdn_out"):
+        h = h + out.reshape(B, S, H)
+    return h, cache
 
 
 def full_mixer(cfg: ModelConfig, lp, h, positions, total_lens, new_lens,
@@ -312,7 +316,7 @@ def full_mixer(cfg: ModelConfig, lp, h, positions, total_lens, new_lens,
     B, S, _ = h.shape
     Hq, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     eps, rd = cfg.rms_norm_eps, cfg.rotary_dim
-    with jax.named_scope("layer.attn_in"):
+    with stage("layer.attn_in"):
         x = zc_norm(h, lp["attn_norm"], eps)
         qg = (x @ lp["wq"]).reshape(B, S, Hq, 2 * Dh)
         q, gate = qg[..., :Dh], qg[..., Dh:]
@@ -328,17 +332,17 @@ def full_mixer(cfg: ModelConfig, lp, h, positions, total_lens, new_lens,
         else:
             q = apply_rope(q, positions, cfg.rope_theta)
             k = apply_rope(k, positions, cfg.rope_theta)
-    with jax.named_scope("layer.kv_write"):
+    with stage("layer.kv_write"):
         kv = write_rows(cache["kv"], lidx, k, v, page_table, positions,
                         total_lens, new_lens, starts)
-    with jax.named_scope("layer.attn"):
+    with stage("layer.attn"):
         attn = attend_rows(attn_impl, q, kv, lidx, page_table, positions,
                            total_lens, new_lens, Dh ** -0.5, starts)
-    with jax.named_scope("layer.attn_out"):
+    with stage("layer.attn_out"):
         attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
             attn.dtype)
-        out = attn.reshape(B, S, Hq * Dh) @ lp["wo"]
-    return h + out, {**cache, "kv": kv}
+        h = h + attn.reshape(B, S, Hq * Dh) @ lp["wo"]
+    return h, {**cache, "kv": kv}
 
 
 # ----------------------------------------------------------------- forward
@@ -366,48 +370,58 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             f"moe_backend {cfg.moe_backend!r}: this family's sparse block "
             "(held range, shared expert) runs the grouped layer only")
     on_chip = grouped_on_chip(attn_impl)
-    slots, page_table = page_table[:, -1], page_table[:, :-1]
     B, S = tokens.shape
-    starts = packed_rows(packed, new_lens)
-    rows = gdn.token_rows(
-        B * S, starts if packed else jnp.arange(B, dtype=jnp.int32) * S,
-        new_lens, total_lens, slots)
-    with jax.named_scope("embed"):
+    with stage("step.inputs"):
+        slots, page_table = page_table[:, -1], page_table[:, :-1]
+        starts = packed_rows(packed, new_lens)
+        rows = gdn.token_rows(
+            B * S, starts if packed else jnp.arange(B, dtype=jnp.int32) * S,
+            new_lens, total_lens, slots)
+    with stage("embed"):
         h = params["embed"][tokens]
     G = cfg.full_attention_interval - 1
-    moe_kw = dict(valid=token_slots(tokens, new_lens, packed),
-                  use_pallas=on_chip)
+    with stage("step.inputs"):
+        moe_kw = dict(valid=token_slots(tokens, new_lens, packed),
+                      use_pallas=on_chip)
     lg, lf = params["layers"]["gdn"], params["layers"]["full"]
 
     # the linear layers as ONE stack over periods and places: the loops
     # below carry indices alone, each layer's leaves are read where they
     # lie (``moe.flat_layers``) and the grouped layer indexes the experts
-    gdn_scanned, gdn_experts = split_experts(cfg, flat_layers(lg))
+    with stage("layer.weights"):
+        gdn_scanned, gdn_experts = split_experts(cfg, flat_layers(lg))
     full_scanned, full_experts = split_experts(cfg, lf)
 
     def period(carry, p):
         def linear(carry, j):
             h, cache = carry
-            gidx = p * G + j
-            lp = layer_at(gdn_scanned, gidx)
+            with stage("layer.weights"):
+                gidx = p * G + j
+                lp = layer_at(gdn_scanned, gidx)
             h, cache = gdn_mixer(cfg, lp, h, cache, gidx, rows,
                                  use_pallas=on_chip)
             h, aux = _ffn(cfg, {**lp, **gdn_experts}, h,
                           dict(moe_kw, layer=gidx))
             return (h, cache), aux
 
-        (h, cache), aux_g = jax.lax.scan(linear, carry, jnp.arange(G))
-        fp = layer_at(full_scanned, p)
+        with stage("step.inputs"):
+            places = jnp.arange(G)
+        (h, cache), aux_g = jax.lax.scan(linear, carry, places)
+        with stage("layer.weights"):
+            fp = layer_at(full_scanned, p)
         h, cache = full_mixer(cfg, fp, h, positions, total_lens, new_lens,
                               page_table, cache, p, attn_impl=attn_impl,
                               starts=starts)
         h, aux_f = _ffn(cfg, {**fp, **full_experts}, h,
                         dict(moe_kw, layer=p))
-        return (h, cache), {k: aux_f[k] + jnp.sum(aux_g[k]) for k in aux_f}
+        with stage("step.counts"):
+            aux = {k: aux_f[k] + jnp.sum(aux_g[k]) for k in aux_f}
+        return (h, cache), aux
 
-    (h, pages), aux = jax.lax.scan(period, (h, pages),
-                                   jnp.arange(cfg.num_periods))
-    with jax.named_scope("logits"):
+    with stage("step.inputs"):
+        periods = jnp.arange(cfg.num_periods)
+    (h, pages), aux = jax.lax.scan(period, (h, pages), periods)
+    with stage("logits"):
         # the final norm's weight is zero-centred like the stream's;
         # ``_logits`` multiplies by the weight it is handed
         w = params["final_norm"]
@@ -415,7 +429,9 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             cfg, {**params, "final_norm": (
                 1.0 + w.astype(jnp.float32)).astype(w.dtype)},
             h, new_lens, starts=starts)
-    return logits, pages, sum_aux(aux)
+    with stage("step.counts"):
+        aux = sum_aux(aux)
+    return logits, pages, aux
 
 
 forward.supports_packed = True

@@ -48,6 +48,8 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from dynamo_tpu.engine.stages import stage
+
 CHUNK = 64
 
 
@@ -117,13 +119,17 @@ def causal_conv(x: jnp.ndarray, w: jnp.ndarray, pool: jnp.ndarray, layer,
                    rows.start[:, None] + s_idx, N)          # N: dropped
     y = y.at[at.reshape(-1)].add(head.reshape(-1, Ch), mode="drop")
     # the carried inputs after this step: entries n .. n + K - 2 of
-    # (carried ++ the row's new inputs)
-    e = rows.new[:, None] + s_idx                            # [R, K-1]
-    taken = x[jnp.clip(rows.start[:, None] + e - (K - 1), 0, N - 1)]
-    old = jnp.take_along_axis(carry, jnp.minimum(e, K - 2)[..., None],
-                              axis=1)
-    carry = jnp.where((e < K - 1)[..., None], old, taken.astype(pool.dtype))
-    return y, pool.at[layer, rows.slot].set(carry)
+    # (carried ++ the row's new inputs); a cache write, and named as one
+    # (``engine/stages.py``)
+    with stage("conv_write"):
+        e = rows.new[:, None] + s_idx                        # [R, K-1]
+        taken = x[jnp.clip(rows.start[:, None] + e - (K - 1), 0, N - 1)]
+        old = jnp.take_along_axis(carry, jnp.minimum(e, K - 2)[..., None],
+                                  axis=1)
+        carry = jnp.where((e < K - 1)[..., None], old,
+                          taken.astype(pool.dtype))
+        pool = pool.at[layer, rows.slot].set(carry)
+    return y, pool
 
 
 # ------------------------------------------------------------- chunk plan

@@ -35,6 +35,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dynamo_tpu.engine.stages import stage
+
 TOPK_MAX = 64
 
 
@@ -223,7 +225,7 @@ def top_candidates(logits: jnp.ndarray, k: int):
     g = _group_width(V, k)
     if not g:
         return jax.lax.top_k(logits, k)
-    with jax.named_scope("top_candidates"):
+    with stage("top_candidates"):
         G = -(-V // g)
         x = logits.reshape(-1, V)
         if G * g != V:

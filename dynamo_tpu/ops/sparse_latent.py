@@ -73,6 +73,7 @@ from typing import Callable, Tuple
 import jax
 import jax.numpy as jnp
 
+from dynamo_tpu.engine.stages import stage
 from dynamo_tpu.ops.gdn import Rows
 
 NEG_INF = -1e30
@@ -285,8 +286,7 @@ def _row_keys(pool: jnp.ndarray, layer, page_table: jnp.ndarray):
 
 def select(q: jnp.ndarray, w: jnp.ndarray, pool: jnp.ndarray, layer,
            page_table: jnp.ndarray, rows: Rows, total_lens: jnp.ndarray,
-           topk: int, *, width: int, packed: bool,
-           scopes: Tuple[str, str] = ("score", "topk")
+           topk: int, *, width: int, packed: bool
            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Every token's selection as a list: ``(sel [N, K] int32, live [N, K]
     bool)``, ``K = min(topk, the table's tokens)``. ``sel`` holds
@@ -294,7 +294,8 @@ def select(q: jnp.ndarray, w: jnp.ndarray, pool: jnp.ndarray, layer,
     visible tokens where ``live``; a context no longer than ``topk``
     selects itself whole and scores nothing. ``q [N, J, D]``, ``w [N,
     J]``; ``pool [L, N, ps, D]`` the index pages, this step's keys already
-    written; ``scopes`` name the two stages for the device trace."""
+    written. Traced under the stages ``index/score`` and ``index/topk``
+    (``engine/stages.py``)."""
     N = q.shape[0]
     S = page_table.shape[1] * pool.shape[2]
     K = min(topk, S)
@@ -303,23 +304,23 @@ def select(q: jnp.ndarray, w: jnp.ndarray, pool: jnp.ndarray, layer,
         sel = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (N, S))
         return sel, (sel <= pos[:, None]) & rows.valid[:, None]
     return _by_rows(
-        _select_row(pool, layer, page_table, K, scopes, False), rows,
+        _select_row(pool, layer, page_table, K, False), rows,
         total_lens, (q, w), (jnp.zeros((N, K), jnp.int32),
                              jnp.zeros((N, K), jnp.bool_)), width, packed)
 
 
-def _select_row(pool, layer, page_table, K, scopes, as_bias: bool):
+def _select_row(pool, layer, page_table, K, as_bias: bool):
     """The indexer on one row's queries: ``(sel, live)`` lists, or the
     bias ``[C, S]`` of the masked form."""
     keys_of = _row_keys(pool, layer, page_table)
     S = page_table.shape[1] * pool.shape[2]
 
     def row(r, qpos, qb, wb):
-        with jax.named_scope(scopes[0]):
+        with stage("index/score"):
             scores = index_scores(qb, wb, keys_of(r), jnp.max(qpos) + 1)
             seen = jnp.arange(S, dtype=jnp.int32)[None, :] <= qpos[:, None]
             scores = jnp.where(seen, scores, NEG_INF)
-        with jax.named_scope(scopes[1]):
+        with stage("index/topk"):
             mask = topk_mask(scores, K)
             if as_bias:
                 return jnp.where(mask, 0.0, NEG_INF)
@@ -335,7 +336,7 @@ def _select_row(pool, layer, page_table, K, scopes, as_bias: bool):
 def select_split(q: jnp.ndarray, w: jnp.ndarray, pool: jnp.ndarray, layer,
                  page_table: jnp.ndarray, rows: Rows,
                  total_lens: jnp.ndarray, topk: int, *, width: int,
-                 packed: bool, scopes: Tuple[str, str] = ("score", "topk")):
+                 packed: bool):
     """The selection in the form the chip runs (module docstring), a bias
     of 0 on a query's selection and ``NEG_INF`` off it: ``(one, bias)``.
     ``one = (bias [R, S], to [R])`` the rows of ONE token, on the rows'
@@ -349,7 +350,7 @@ def select_split(q: jnp.ndarray, w: jnp.ndarray, pool: jnp.ndarray, layer,
     K = min(topk, S)
     one = bias = None
     whole = S <= topk          # every visible key is selected: no scores
-    row = _select_row(pool, layer, page_table, K, scopes, True)
+    row = _select_row(pool, layer, page_table, K, True)
     s = jnp.arange(S, dtype=jnp.int32)[None, :]
     if width == 1 or packed:
         if whole:

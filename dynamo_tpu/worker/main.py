@@ -25,6 +25,7 @@ import os
 
 import jax
 
+from dynamo_tpu.engine import stages
 from dynamo_tpu.engine.jax_engine import (ATTN_IMPLS, JaxEngine,
                                           JaxEngineConfig)
 from dynamo_tpu.llm.register import register_llm, serve_engine
@@ -262,6 +263,9 @@ def build_engine(args: argparse.Namespace, startup=None) -> JaxEngine:
             attrs["prefill.attention"] = engine.packed_attention
         if engine.gen_block > 1:
             attrs["generation"] = engine.generation
+        # the names the step programs are traced under, each with its
+        # group: what a reader of this process's device trace sums by
+        attrs["stages"] = stages.as_attribute()
         return engine
 
 
