@@ -34,6 +34,7 @@ from dynamo_tpu.models.llama import (
     attend_rows,
     make_pages,
     packed_rows,
+    qkv_products,
     write_rows,
 )
 from dynamo_tpu.ops.rope import apply_rope
@@ -103,9 +104,10 @@ def _project_qkv(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
                  h: jnp.ndarray, positions: jnp.ndarray):
     B, S, _ = h.shape
     x = _rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
-    q = quant.mm(lp, "wq", x).reshape(B, S, cfg.num_heads, cfg.head_dim)
-    k = quant.mm(lp, "wk", x).reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
-    v = quant.mm(lp, "wv", x).reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
+    q, k, v = qkv_products(cfg, lp, x)
+    q = q.reshape(B, S, cfg.num_heads, cfg.head_dim)
+    k = k.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
+    v = v.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
@@ -226,6 +228,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 
 
 forward.supports_packed = True
+forward.reads_wqkv = True
 
 
 __all__ = ["init_params", "forward", "make_pages", "layer_windows"]

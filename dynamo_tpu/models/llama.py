@@ -134,6 +134,58 @@ def init_params(cfg: ModelConfig, rng: jax.Array, scale: float = 0.02) -> Params
     return params
 
 
+QKV = ("wq", "wk", "wv")
+
+
+def fuse_qkv(layers: Dict[str, Any]) -> Dict[str, Any]:
+    """The layer tree with ``wq | wk | wv`` laid side by side as ONE stack
+    ``wqkv [L, D, q_size + 2 kv_size]`` (``bqkv`` of the biases likewise),
+    or the tree as it is where it does not hold the three.
+
+    Three products that share their left operand are what the TPU compiler
+    re-lays a layer at a time: each layer's three matrices fetched and
+    transposed ahead of three small products, and in a fused block the
+    whole stacks transposed once a dispatch (1.13 GB at Qwen3-4B; PERF.md
+    section 6, PR 54). One product reads the one stack where it lies, as
+    ``wo`` and the FFN's do. A concatenation of the same values, made once
+    at load (``jax_engine.serving_weights``) - ``init_params`` and
+    the loaders keep making the three, which a mesh and the pipeline
+    stages place by name. The tree given keeps its leaves: the three live
+    as long as their owner holds it (the worker lays its tree out first
+    and keeps the result alone, ``jax_engine.serving_weights``). Abstract
+    leaves give abstract leaves."""
+    def side_by_side(*parts):
+        if isinstance(parts[0], jax.ShapeDtypeStruct):
+            return jax.eval_shape(side_by_side, *parts)
+        return jnp.concatenate(parts, axis=-1)
+
+    out = dict(layers)
+    for fused, names in (("wqkv", QKV), ("bqkv", ("bq", "bk", "bv"))):
+        if all(name in out for name in names):
+            out[fused] = side_by_side(*(out.pop(name) for name in names))
+    return out
+
+
+def qkv_products(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
+                 x: jnp.ndarray
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """``x @ wq, x @ wk, x @ wv`` with their biases, ``[B, S, *]`` each:
+    ONE product split by columns where the tree holds ``wqkv``
+    (``fuse_qkv``), the three where it holds the three - a mesh with
+    ``tp``, the pipeline stages, any caller with the tree of
+    ``init_params``. What the tree holds decides, nothing else."""
+    if quant.holds(lp, "wqkv"):
+        qkv = quant.mm(lp, "wqkv", x)
+        if "bqkv" in lp:
+            qkv = qkv + lp["bqkv"]
+        return tuple(jnp.split(
+            qkv, (cfg.q_size, cfg.q_size + cfg.kv_size), axis=-1))
+    q, k, v = (quant.mm(lp, name, x) for name in QKV)
+    if "bq" in lp:
+        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+    return q, k, v
+
+
 def _project_qkv(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
                  h: jnp.ndarray, positions: jnp.ndarray
                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -141,13 +193,7 @@ def _project_qkv(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
     B, S, _ = h.shape
     eps = cfg.rms_norm_eps
     x = _rms_norm(h, lp["attn_norm"], eps)
-    q = quant.mm(lp, "wq", x)
-    k = quant.mm(lp, "wk", x)
-    v = quant.mm(lp, "wv", x)
-    if cfg.attention_bias:
-        q = q + lp["bq"]
-        k = k + lp["bk"]
-        v = v + lp["bv"]
+    q, k, v = qkv_products(cfg, lp, x)
     q = q.reshape(B, S, cfg.num_heads, cfg.head_dim)
     k = k.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
     v = v.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
@@ -339,6 +385,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 
 
 forward.supports_packed = True
+forward.reads_wqkv = True
 
 
 def _dense_hidden(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,

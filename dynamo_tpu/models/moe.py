@@ -589,6 +589,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 
 
 forward.supports_packed = True
+forward.reads_wqkv = True
 
 
 __all__ = ["forward", "init_params", "make_pages", "moe_mlp",

@@ -37,8 +37,11 @@ Params = Dict[str, Any]
 
 # stacked [L, in, out] layer weights that quantize (llama family tree —
 # llama 2/3, mistral, qwen2/3 — which shares these exact names; the MoE
-# and MLA families keep bf16 until their expert/latent paths opt in)
-LAYER_WEIGHTS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+# and MLA families keep bf16 until their expert/latent paths opt in);
+# ``wqkv`` is the engine's ``wq | wk | wv`` side by side
+# (``llama.fuse_qkv``, before this transform: a concatenation's per-column
+# scales are the three's, concatenated)
+LAYER_WEIGHTS = ("wq", "wk", "wv", "wqkv", "wo", "w_gate", "w_up", "w_down")
 
 _EPS = 1e-30
 
@@ -105,6 +108,12 @@ def quantize_params(params: Params) -> Params:
     return out
 
 
+def holds(lp: Dict[str, jnp.ndarray], name: str) -> bool:
+    """Whether the layer tree holds the weight ``name``, as it was loaded
+    or as its int8 pair."""
+    return name in lp or name + "_q" in lp
+
+
 def mm(lp: Dict[str, jnp.ndarray], name: str, x: jnp.ndarray
        ) -> jnp.ndarray:
     """``x @ lp[name]``, transparently using the int8 pair when the tree
@@ -116,5 +125,5 @@ def mm(lp: Dict[str, jnp.ndarray], name: str, x: jnp.ndarray
     return x @ lp[name]
 
 
-__all__ = ["LAYER_WEIGHTS", "mm", "qdot", "quantize_params",
+__all__ = ["LAYER_WEIGHTS", "holds", "mm", "qdot", "quantize_params",
            "quantize_weight"]

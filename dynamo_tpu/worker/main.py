@@ -27,7 +27,7 @@ import jax
 
 from dynamo_tpu.engine import stages
 from dynamo_tpu.engine.jax_engine import (ATTN_IMPLS, JaxEngine,
-                                          JaxEngineConfig)
+                                          JaxEngineConfig, serving_weights)
 from dynamo_tpu.llm.register import register_llm, serve_engine
 from dynamo_tpu.model_card import ModelDeploymentCard
 from dynamo_tpu.models import llama
@@ -217,7 +217,9 @@ def build_engine(args: argparse.Namespace, startup=None) -> JaxEngine:
     ``sample.top_candidates`` says which form the sampler's selection
     takes at this vocabulary, ``prefill.form`` which form the
     prefill-carrying steps take: ``packed`` or ``padded:<reason>``,
-    ``prefill.attention`` which kernels attend a packed step's rows).
+    ``prefill.attention`` which kernels attend a packed step's rows,
+    ``qkv`` whether one stored ``wqkv`` serves: ``fused`` or
+    ``split:<reason>``).
     Callers that keep no startup trace (run.py,
     step followers) pass none."""
     from dynamo_tpu.ops.sampling import candidate_form
@@ -235,7 +237,13 @@ def build_engine(args: argparse.Namespace, startup=None) -> JaxEngine:
                    if cfg.ep_size > 1 else "")
                 + (f"[zero={cfg.zero_expert_num}]"
                    if cfg.zero_expert_num else ""))
+        # the worker owns the tree: laid out here as the engine will hold
+        # it, so that what the layout lets go of (``wq``, ``wk``, ``wv``
+        # once side by side) is gone before the engine makes its pools
+        params = serving_weights(cfg, params, engine_cfg, forward_fn)
         engine = JaxEngine(cfg, params, engine_cfg, forward_fn=forward_fn)
+        # q, k and v from one stored matrix, or why the three serve
+        attrs["qkv"] = engine.qkv
         # the kinds of cache the engine keeps: the paged pool, and the
         # recurrent-state pool of a family with linear-attention layers
         attrs["cache.kinds"] = engine.cache_kinds

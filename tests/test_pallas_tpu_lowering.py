@@ -1359,3 +1359,141 @@ def test_a_nested_scan_over_indices_writes_no_weight_on_a_v5e(form):
         assert [d["bytes"] for d in written] == [G * W * W * 2]
         assert "bf16[3,1024,1024]" in written[0]["line"]
         assert found["entry"] == []
+
+
+# -- q, k and v from one stored matrix (ISSUE 54) ---------------------------
+
+@pytest.mark.parametrize("form", ["three", "one"])
+def test_products_that_share_their_operand_write_their_weights_on_a_v5e(form):
+    """The toy of ISSUE 54, one ``lax.scan`` over 36 layers of Qwen3-4B's
+    projection widths around a plain grouped attention: three products of
+    one left operand have each layer's ``wq`` / ``wk`` / ``wv`` written
+    ahead of them - fetched into the chip's fast memory in the layout
+    their consumer wants, not the stack's - and indexing the stack inside
+    the body changes nothing of it (tried for the issue); ONE stored
+    ``[36, 2560, 6144]`` and one product, split after, and nothing of a
+    weight is written, in the loop or outside."""
+    from dynamo_tpu.engine.program_check import weight_copies
+
+    one_chip = _v5e_chip()
+    L, D, T, H, Hkv, Dh = 36, 2560, 64, 32, 8, 128
+    Q, KV = H * Dh, Hkv * Dh
+
+    def layers(params, x):
+        def layer(h, lp):
+            if form == "one":
+                q, k, v = jnp.split(h @ lp["wqkv"], (Q, Q + KV), axis=-1)
+            else:
+                q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
+            q = q.reshape(T, Hkv, H // Hkv, Dh)
+            k, v = k.reshape(T, Hkv, Dh), v.reshape(T, Hkv, Dh)
+            s = jax.nn.softmax(jnp.einsum("tkgd,ukd->kgtu", q, k), -1)
+            o = jnp.einsum("kgtu,ukd->tkgd", s, v).reshape(T, Q)
+            return h + o @ lp["wo"], None
+        return jax.lax.scan(layer, x, params)[0]
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    qkv = ({"wqkv": sds(L, D, Q + 2 * KV)} if form == "one" else
+           {"wq": sds(L, D, Q), "wk": sds(L, D, KV), "wv": sds(L, D, KV)})
+    params = {**qkv, "wo": sds(L, Q, D)}
+    hlo = jax.jit(layers).lower(params, sds(T, D)).compile().as_text()
+    found = weight_copies(hlo, params, min_bytes=1 << 20)
+    if form == "one":
+        assert found == {"loop": [], "entry": [], "on_chip": []}
+    else:
+        # each of the three a layer at a time (21 / 5 / 5 MB), never ``wo``
+        written = [d for where in found.values() for d in where]
+        assert {re.search(r"__(\w+?)__", d["leaf"]).group(1)
+                for d in written} == {"wq", "wk", "wv"}
+        assert {d["bytes"] for d in written} == {D * Q * 2, D * KV * 2}
+
+
+def _cell_engine(name: str, family):
+    """An engine over abstract weights of a shipped configuration at the
+    cell's worker arguments, and those arguments."""
+    import json
+    import os
+
+    from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
+    from dynamo_tpu.models.config import ModelConfig
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "configs", name + ".json")
+    with open(path) as f:
+        hf = json.load(f)
+    args = hf.pop("benchmark")["worker_args"]
+    args = dict(zip(args[::2], args[1::2]))
+    cfg = ModelConfig.from_hf(hf)
+    abs_params = jax.eval_shape(
+        lambda: family.init_params(cfg, jax.random.PRNGKey(0)))
+    more = {field: kind(args[flag]) for flag, field, kind in (
+        ("--denoising-steps", "denoising_steps", int),
+        ("--confidence-threshold", "confidence_threshold", float),
+        ("--min-prefill-bucket", "min_prefill_bucket", int),
+        ("--min-decode-bucket", "min_decode_bucket", int),
+        ("--min-prefill-seqs-bucket", "min_prefill_seqs_bucket", int),
+    ) if flag in args}
+    eng = JaxEngine(cfg, abs_params, JaxEngineConfig(
+        num_pages=16, page_size=16,
+        max_num_seqs=int(args.get("--max-num-seqs", 64)),
+        max_context=4096, max_prefill_chunk=1024, attn_impl="pallas",
+        decode_multistep=int(args.get("--decode-multistep", 8)), **more))
+    return eng, args
+
+
+# (configuration, program, rows, token slots, the most temporaries, GB):
+# the programs the two cells' rings name - ``multistep8[64]`` and
+# ``packed[1152,64]`` of ``qwen3-4b.batch``, ``passes3[32,4]`` and
+# ``packed[1024,8]`` of ``sdar-30b-a3b-chat.blockgen``
+_GQA_PROGRAMS = [
+    ("qwen3-4b", "fused", 64, 1152, 0.05),
+    ("qwen3-4b", "packed", 64, 1152, 0.05),
+    ("sdar-30b-a3b-chat", "passes", 32, 1024, 0.30),
+    ("sdar-30b-a3b-chat", "packed", 8, 1024, 0.40),
+]
+
+
+@pytest.mark.parametrize("name,program,rows,tokens,temp_gb", _GQA_PROGRAMS,
+                         ids=[f"{c[0]}-{c[1]}" for c in _GQA_PROGRAMS])
+def test_gqa_step_programs_write_no_projection_weight_on_a_v5e(
+        name, program, rows, tokens, temp_gb):
+    """The dense and the MoE GQA cells' real step programs (abstract
+    weights at the published widths, the cells' pools) compiled for a v5e:
+    the engine serves ``wqkv`` and the compiled program writes nothing of
+    it - no loop writes any weight, and neither the entry computation nor
+    the chip's fast memory holds a re-laid ``wq`` / ``wk`` / ``wv`` /
+    ``wqkv``. On the three the fused block of ``qwen3-4b`` transposed the
+    whole stacks once a dispatch (``copy.103`` ``bf16[36,2560,4096]`` and
+    two of a quarter its size: 1.13 GB of temporaries, 1.136 GB in all)
+    and every program fetched-and-re-laid each layer's three ahead of
+    three small products (PERF.md section 6, PR 54)."""
+    from dynamo_tpu.engine.program_check import (pool_copies, step_programs,
+                                                 weight_copies)
+    from dynamo_tpu.models import llama, moe
+
+    one_chip = _v5e_chip()
+    family = {"qwen3-4b": llama, "sdar-30b-a3b-chat": moe}[name]
+    eng, args = _cell_engine(name, family)
+    assert eng.qkv == "fused" and eng.padded_reason is None
+    assert "wqkv" in eng.params["layers"]
+    num_pages = int(args["--num-pages"])
+    fn, fn_args = step_programs(
+        eng, rows, 1024, width=eng.multistep, sharding=one_chip,
+        num_pages=num_pages, tokens=tokens)[program]
+    compiled = fn.lower(*fn_args).compile()
+    hlo = compiled.as_text()
+    # the one product, under its stage, over every row or slot
+    width = eng.model_cfg.q_size + 2 * eng.model_cfg.kv_size
+    assert re.search(rf"convolution[.\d]* = bf16\[(\d+,)+{width}\]", hlo)
+    pool = (eng.kv_pool.shape[0], num_pages) + tuple(eng.kv_pool.shape[2:])
+    assert pool_copies(hlo, pool, eng.kv_pool.dtype) == []
+    found = weight_copies(hlo, eng.params, min_bytes=1 << 20)
+    assert found["loop"] == [], found["loop"]
+    projection = [d for where in ("entry", "on_chip") for d in found[where]
+                  if re.search(r"__(wq|wk|wv|wqkv)__", d["leaf"])]
+    assert projection == [], projection
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < temp_gb * 1e9, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9

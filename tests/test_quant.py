@@ -124,7 +124,8 @@ class TestEngine:
                                max_prefill_chunk=32, max_context=128,
                                attn_impl="scan", quantize="int8")
         eng = JaxEngine.random_init(cfg, ecfg)
-        assert "wq_q" in eng.params["layers"]
+        # the engine lays wq | wk | wv side by side before it quantises
+        assert "wqkv_q" in eng.params["layers"]
 
         req = PreprocessedRequest(
             token_ids=list(range(1, 20)),
@@ -194,7 +195,8 @@ class TestEngine:
             num_pages=32, page_size=16, max_num_seqs=2,
             max_prefill_chunk=32, max_context=128,
             attn_impl="scan", quantize="int8"))
-        assert "wq_q" in eng.params["layers"]
+        # the engine lays wq | wk | wv side by side before it quantises
+        assert "wqkv_q" in eng.params["layers"]
         req = PreprocessedRequest(
             token_ids=list(range(1, 20)),
             sampling_options=SamplingOptions(temperature=0.0),

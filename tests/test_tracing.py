@@ -629,12 +629,14 @@ async def test_worker_writes_its_startup_trace_when_ready(tmp_path):
     # which form the sampler's selection takes at this vocabulary (512),
     # and which the prefill-carrying steps take (the CPU's XLA path)
     # ... and the kinds of cache the engine keeps (one paged pool: no
-    # linear-attention layer, no state pool), and the table of stages the
+    # linear-attention layer, no state pool), that q, k and v come from
+    # one stored matrix (one device, a GQA family), and the table of stages the
     # step programs are traced under, for a reader of the device trace
     from dynamo_tpu.engine import stages
     assert kids[2]["attrs"] == {"sample.top_candidates": "direct",
                                 "cache.kinds": "paged[L=2,Hkv=2,Dh=16]",
                                 "prefill.form": "padded:attn_impl",
+                                "qkv": "fused",
                                 "stages": stages.as_attribute()}
     # the stages cover the start-up: imports and the engine build dominate
     assert sum(s["duration_s"] for s in kids) >= 0.8 * rec["duration_s"]
