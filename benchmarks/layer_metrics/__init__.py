@@ -1,6 +1,8 @@
 """One file per per-layer metric, found by the metric's name: each has one
 ``compute(run)`` that returns a number, or None where there is nothing to
-read."""
+read. A quantity has one name in every cell that reports it; the cell is in
+the entry's ``workloads``, not in the name (the names that end in a cell's
+traffic mix are that cell's own cost function or kernel)."""
 
 import importlib.util
 import os
@@ -15,6 +17,14 @@ def reader(name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def listed(bench: dict, group: str, cell: str) -> dict:
+    """The metrics of ``bench[group]`` that ``cell`` reports, by name: the
+    entries that list it, and the entries with no list, which are every
+    cell's - a cell added later reports those without an edit to them."""
+    return {m["name"]: m for m in bench[group]
+            if cell in m.get("workloads", [cell])}
 
 
 def percentile(values: list, q: float):

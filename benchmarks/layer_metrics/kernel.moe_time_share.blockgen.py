@@ -1,6 +1,10 @@
-"""``kernel.moe_time_share.reason`` in the block-generation cells, which are judged on ``out_tok_per_s`` (a per-layer
-metric names one end-to-end metric and lists its cells, so the quantity is split)."""
+"""Share of the device's busy time in the traced slice that the grouped
+expert matmul took in the block-generation cells: the ``moe_grouped``
+Mosaic calls over busy time (a pass sends every row's block through all
+128 experts). Nothing where the trace has no such call."""
 
-from layer_metrics import reader
+from layer_metrics._kernels import time_share
 
-compute = reader("kernel.moe_time_share.reason").compute
+
+def compute(run):
+    return time_share(run, ("moe_grouped",))

@@ -51,7 +51,7 @@ import loadgen  # noqa: E402
 import modeldir  # noqa: E402
 import served  # noqa: E402
 import traffic  # noqa: E402
-from layer_metrics import reader  # noqa: E402
+from layer_metrics import listed, reader  # noqa: E402
 from served import Failed  # noqa: E402
 
 TRACE_SLICE_S = 8.0      # profiler slice in the middle of the window
@@ -509,10 +509,9 @@ class Run:
                 f"{sum(w for _d, w in got[half:]) / len(got[half:]):.2f}s; "
                 f"{self.window_tokens / self.seconds:.1f} tokens/s streamed "
                 "in the window")
-        wanted = {m["name"]: m for m in
-                  self.bench["per_layer" if self.traced else "end_to_end"]
-                  if self.args.workload in m.get(
-                      "workloads", [self.args.workload])}
+        wanted = listed(self.bench,
+                        "per_layer" if self.traced else "end_to_end",
+                        self.args.workload)
         metrics = {}
         if self.traced:
             for name, m in wanted.items():

@@ -21,6 +21,7 @@ import modeldir  # noqa: E402
 import peaks  # noqa: E402
 import traffic  # noqa: E402
 import xplane  # noqa: E402
+from layer_metrics import listed, reader  # noqa: E402
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
     BENCHMARK = json.load(_f)
@@ -41,6 +42,12 @@ def _has_own_rule(config: str) -> bool:
 
 # the configurations the next-token rule scores (``reference/score.py``)
 DEFAULT_RULE_CONFIGS = [c for c in BUILT_CONFIGS if not _has_own_rule(c)]
+# what ``reduced`` may never name: a hidden, intermediate, latent, state or
+# projection size, a head size, an expansion factor, the experts a token
+# picks. A vocabulary or a number of experts that one chip holds a slice of
+# is no width (the model-configs guide, section 4)
+WIDTH = re.compile(r"(_dim|_rank)$|hidden_size|intermediate_size|head_size"
+                   r"|state_size|expand|experts_per_tok|top_?k")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
@@ -106,8 +113,7 @@ def test_configuration_file_is_the_published_config(config):
     assert bench["source"] == entry["source"]
     assert sorted(bench["reduced"]) == sorted(entry["reduced"])
     for key in entry["reduced"]:
-        assert not (key.endswith("_dim") or key.endswith("_rank")
-                    or "size" in key), f"{key} is a width"
+        assert not WIDTH.search(key), f"{key} is a width"
     catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
     if os.path.exists(catalog):
         with open(catalog) as f:
@@ -122,8 +128,104 @@ def test_configuration_file_is_the_published_config(config):
     f[:-3] for f in os.listdir(os.path.join(BENCH, "layer_metrics"))
     if f.endswith(".py") and not f.startswith("_")))
 def test_every_per_layer_metric_has_a_reader(metric):
-    from layer_metrics import reader
     assert callable(reader(metric).compute)
+
+
+# every (entry, cell) pair a traced run prints, as ``benchmarks/run.py``
+# selects them (``layer_metrics.listed``)
+PAIRS = [(name, cell) for cell in CELLS
+         for name in listed(BENCHMARK, "per_layer", cell)]
+
+
+@pytest.mark.parametrize("metric, cell", PAIRS)
+def test_every_pair_of_metric_and_cell_has_a_reader(metric, cell):
+    """The reader file is there under the entry's own name, and the cell
+    reports the end-to-end metric the entry says it moves."""
+    assert os.path.exists(os.path.join(BENCH, "layer_metrics",
+                                       f"{metric}.py"))
+    assert callable(reader(metric).compute)
+    entry = listed(BENCHMARK, "per_layer", cell)[metric]
+    assert entry["moves"] in listed(BENCHMARK, "end_to_end", cell)
+
+
+# The pairs at PR 55, written out: the 128 of PR 54's list under the names
+# they have since (a quantity every cell reports has one name, the cell is
+# in ``workloads``), less ``kernel.topk_time_share`` on ``longctx`` (no
+# step program holds the sort it read), plus the seven stages in the seven
+# cells. 16 x 7 + 3 x 3 + 55 = 176.
+EVERY_CELL_PR55 = [
+    "loop.host_gap_share", "loop.idle_behind_host_share",
+    "sched.queue_wait_share", "setup.worker_ready_s", "setup.first_calls_s",
+    "step.decode_device_ms", "step.mixed_device_ms",
+    "step.prefill_occupancy", "step.compiles_in_window",
+    "stage.mixer_in_time_share", "stage.cache_write_time_share",
+    "stage.mixer_time_share", "stage.mixer_out_time_share",
+    "stage.ffn_time_share", "stage.around_layers_time_share",
+    "stage.unnamed_time_share"]
+IDLE_IN_PR55 = ["loop.idle_in_assemble_share", "loop.idle_in_enqueue_share",
+                "loop.idle_in_handover_share"]
+OWN_PR55 = {
+    "qwen3-4b.batch": IDLE_IN_PR55 + [
+        "step.decode_hbm_share.batch", "step.mfu.batch",
+        "kernel.attn_time_share.batch"],
+    "joyai-llm-flash.reason": IDLE_IN_PR55 + [
+        "moe.experts_touched_share.reason", "kernel.moe_time_share.reason",
+        "kernel.moe_roofline_share.reason", "kernel.mla_time_share.reason",
+        "step.decode_hbm_share.reason", "step.mfu.reason"],
+    "sdar-30b-a3b-chat.blockgen": IDLE_IN_PR55 + [
+        "gen.tokens_per_pass.blockgen", "gen.commit_pass_share.blockgen",
+        "step.decode_hbm_share.blockgen", "step.pass_mfu.blockgen",
+        "moe.experts_touched_share.blockgen",
+        "kernel.moe_time_share.blockgen",
+        "kernel.moe_roofline_share.blockgen",
+        "kernel.attn_time_share.blockgen"],
+    "longcat-flash-omni.turns": [
+        "step.rank_mfu.turns", "step.decode_hbm_share.turns",
+        "kernel.moe_roofline_share.turns", "kernel.moe_time_share.turns",
+        "kernel.mla_time_share.turns", "moe.experts_touched_share.turns",
+        "moe.zero_pick_share.turns", "moe.held_pick_share.turns"],
+    "qwen3-next-80b-a3b-instruct.longdoc": [
+        "kernel.gdn_time_share.longdoc",
+        "kernel.gdn_roofline_share.longdoc",
+        "kernel.gdn_step_roofline_share.longdoc", "step.rank_mfu.longdoc",
+        "step.decode_hbm_share.longdoc", "kernel.attn_time_share.longdoc",
+        "kernel.moe_time_share.longdoc",
+        "kernel.moe_roofline_share.longdoc",
+        "moe.experts_touched_share.longdoc", "moe.held_pick_share.longdoc"],
+    "dots3-note-prev.longctx": [
+        "attn.selected_share.longctx", "cache.bytes_per_live_token.longctx",
+        "step.rank_mfu.longctx", "step.decode_hbm_share.longctx",
+        "kernel.moe_time_share.longctx",
+        "kernel.moe_roofline_share.longctx",
+        "moe.experts_touched_share.longctx", "moe.held_pick_share.longctx",
+        "kernel.sparse_attn_time_share.longctx",
+        "kernel.sparse_attn_roofline_share.longctx",
+        "kernel.window_attn_time_share.longctx",
+        "kernel.window_attn_roofline_share.longctx"],
+    "olmo-hybrid-7b.crowd": [
+        "step.rank_mfu.crowd", "step.decode_hbm_share.crowd",
+        "kernel.gdn_time_share.crowd",
+        "kernel.gdn_step_roofline_share.crowd",
+        "kernel.gdn_roofline_share.crowd", "kernel.attn_time_share.crowd",
+        "kernel.attn_decode_roofline_share.crowd",
+        "cache.state_share.crowd"]}
+
+
+def test_no_cell_has_lost_a_metric_it_printed_at_pr_55():
+    """A later collapse of the list may not drop a cell's metric unseen:
+    every pair of PR 55 is still a pair (a later PR adds cells and
+    entries, so more is fine), a name is listed once, and the metric whose
+    sort no step program holds stays out."""
+    held = {(name, cell) for cell, own in OWN_PR55.items()
+            for name in EVERY_CELL_PR55 + own}
+    assert len(held) == 176 and set(OWN_PR55) <= set(CELLS)
+    assert held <= set(PAIRS), sorted(held - set(PAIRS))
+    assert len(PAIRS) == len(set(PAIRS))
+    # what every cell reports has no list to edit when a cell is added
+    by_name = {m["name"]: m for m in BENCHMARK["per_layer"]}
+    assert not any("workloads" in by_name[n] for n in EVERY_CELL_PR55)
+    assert not any(n.startswith("kernel.topk_time_share")
+                   for n, _cell in PAIRS)
 
 
 # ----------------------------------------------------------------- traffic
@@ -369,17 +471,22 @@ def _load(name, path):
 
 
 def test_the_shipped_configurations_are_scored_by_the_next_token_rule():
-    """The seam's default: none of the three shipped reference modules
-    exports a ``score``, none of their configurations has a ``probe`` block
-    (so the cache keys and the request bodies are the parent's), and the
-    agreement test below still holds its three cases."""
-    assert DEFAULT_RULE_CONFIGS == ["dsv2lite", "joyai-llm-flash",
-                                    "qwen3-4b"]
+    """The seam's default: the configurations without a ``probe`` block (so
+    the cache keys and the request bodies are the parent's) are scored by
+    the next-token rule - the seven of PR 55 at the least, a later one's
+    too - and none of the three reference modules the seam shipped with
+    exports a ``score``; ``sdar-30b-a3b-chat`` alone has a rule of its
+    own. The agreement test below holds a case for each."""
+    assert set(DEFAULT_RULE_CONFIGS) >= {
+        "dots3-note-prev", "dsv2lite", "joyai-llm-flash",
+        "longcat-flash-omni", "olmo-hybrid-7b", "qwen3-4b",
+        "qwen3-next-80b-a3b-instruct"}
+    assert "sdar-30b-a3b-chat" not in DEFAULT_RULE_CONFIGS
     for family in ("llama", "deepseek", "joyai"):
         with open(os.path.join(BENCH, "reference", family + ".py")) as f:
             assert not re.search(r"^(def score\b|score\s*=)", f.read(), re.M)
     assert {modeldir.load_config(c)["bench"]["reference"]
-            for c in DEFAULT_RULE_CONFIGS} == {"llama", "deepseek", "joyai"}
+            for c in DEFAULT_RULE_CONFIGS} >= {"llama", "deepseek", "joyai"}
 
 
 @pytest.mark.parametrize("config", DEFAULT_RULE_CONFIGS)

@@ -20,6 +20,7 @@ BENCH = os.path.join(REPO, "benchmarks")
 sys.path.insert(0, BENCH)
 
 import traffic  # noqa: E402
+from layer_metrics import listed  # noqa: E402
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
     BENCHMARK = json.load(_f)
@@ -44,9 +45,8 @@ def _check_line(out, traced, cell):
     assert line["device"]["platform"] == "cpu"
     for key in ("kind", "count", "memory_peak_bytes"):
         assert key in line["device"]
-    group = BENCHMARK["per_layer" if traced else "end_to_end"]
-    declared = {m["name"]: m for m in group
-                if cell in m.get("workloads", [cell])}
+    declared = listed(BENCHMARK, "per_layer" if traced else "end_to_end",
+                      cell)
     assert set(line["metrics"]) <= set(declared)
     for name, m in line["metrics"].items():
         assert m["unit"] == declared[name]["unit"]

@@ -23,7 +23,7 @@ sys.path.insert(0, BENCH)
 import dots3_cost  # noqa: E402
 import modeldir  # noqa: E402
 import traffic  # noqa: E402
-from layer_metrics import reader  # noqa: E402
+from layer_metrics import listed, reader  # noqa: E402
 
 CONFIG = "dots3-note-prev"
 CELL = CONFIG + ".longctx"
@@ -36,7 +36,7 @@ REDUCED = ["num_hidden_layers", "layer_types", "n_routed_experts",
 NEW = ["attn.selected_share", "cache.bytes_per_live_token",
        "kernel.sparse_attn_time_share", "kernel.sparse_attn_roofline_share",
        "kernel.window_attn_time_share", "kernel.window_attn_roofline_share",
-       "kernel.topk_time_share", "step.rank_mfu", "step.decode_hbm_share",
+       "step.rank_mfu", "step.decode_hbm_share",
        "step.decode_device_ms", "step.mixed_device_ms",
        "step.prefill_occupancy", "step.compiles_in_window",
        "kernel.moe_time_share", "kernel.moe_roofline_share",
@@ -44,6 +44,7 @@ NEW = ["attn.selected_share", "cache.bytes_per_live_token",
        "loop.host_gap_share", "loop.idle_behind_host_share",
        "sched.queue_wait_share", "setup.worker_ready_s",
        "setup.first_calls_s"]
+
 
 
 def _args(bench):
@@ -138,10 +139,11 @@ def test_the_cells_files_carry_the_parameters_it_was_defined_with():
 
 
 def test_the_benchmark_lists_the_cells_metrics_each_with_its_reader():
-    by_name = {m["name"]: m for m in BENCHMARK["per_layer"]}
+    # as the harness selects them; a quantity every cell reports is under
+    # the stem's name, the cell's own under the cell's
+    mine = listed(BENCHMARK, "per_layer", CELL)
     for stem in NEW:
-        m = by_name[f"{stem}.longctx"]
-        assert m["workloads"] == [CELL] or CELL in m["workloads"]
+        m = mine.get(f"{stem}.longctx") or mine[stem]
         assert m["moves"] == ("setup_s" if stem.startswith("setup.")
                               else "out_tok_per_s")
         assert callable(reader(m["name"]).compute)
@@ -230,9 +232,8 @@ TRACE = {"mark": {"start_unix": 105.0, "stop_unix": 125.0}, "busy_s": 0.40,
                   0.020, 6],
                  ["%moe_grouped.12 custom-call f32[6144,5120]{1,0} [mosaic]",
                   0.020, 24],
-                 ["%sort.42 s32[24,1,25600]{2,1,0}", 0.016, 9],
-                 # the expert layer's sort of a step's picks: not the
-                 # selection's
+                 # sorts, which no kernel reader counts: the expert layer's
+                 # of a step's picks
                  ["%sort.7 s32[5120]{0}", 0.010, 24],
                  ["%fusion.9 fusion bf16[640,5120]", 0.05, 900]]}
 
@@ -248,8 +249,6 @@ def test_readers_read_the_ring_and_the_trace():
         pytest.approx(5.0)
     assert reader("kernel.moe_time_share.longctx").compute(run) == \
         pytest.approx(5.0)
-    assert reader("kernel.topk_time_share.longctx").compute(run) == \
-        pytest.approx(4.0)
     # the chunk's 512 tokens' selected rows in three layers against 80 ms
     chunk = 512 / 532
     flops, nbytes = dots3_cost.sparse_attn_cost(hf, "bfloat16",
@@ -292,9 +291,9 @@ def test_readers_read_the_ring_and_the_trace():
                  532 * 513))
     assert mfu == pytest.approx(100 * flops / 197e12 / 0.310)
     assert 0 < mfu <= 100
-    assert reader("step.decode_device_ms.longctx").compute(run) == 40.0
-    assert reader("step.mixed_device_ms.longctx").compute(run) == 150.0
-    assert reader("step.prefill_occupancy.longctx").compute(run) == \
+    assert reader("step.decode_device_ms").compute(run) == 40.0
+    assert reader("step.mixed_device_ms").compute(run) == 150.0
+    assert reader("step.prefill_occupancy").compute(run) == \
         pytest.approx(100 * 532 / 640)
     for name in ("step.decode_hbm_share.longctx", "step.rank_mfu.longctx"):
         assert reader(name).compute(_run_stub(ring, platform="cpu")) is None
@@ -305,7 +304,7 @@ def test_readers_read_the_ring_and_the_trace():
     "kernel.sparse_attn_roofline_share.longctx",
     "kernel.window_attn_time_share.longctx",
     "kernel.window_attn_roofline_share.longctx",
-    "kernel.topk_time_share.longctx", "kernel.moe_time_share.longctx",
+    "kernel.moe_time_share.longctx",
     "kernel.moe_roofline_share.longctx", "attn.selected_share.longctx",
     "cache.bytes_per_live_token.longctx",
     "moe.experts_touched_share.longctx", "moe.held_pick_share.longctx",

@@ -136,10 +136,10 @@ def test_device_time_per_step_from_the_ring(tmp_path):
     assert reader("step.decode_device_ms").compute(run) == \
         pytest.approx(320.0)
     # mixed 1500, 1600 and the prefill 900
-    assert reader("step.mixed_device_ms.batch").compute(run) == \
+    assert reader("step.mixed_device_ms").compute(run) == \
         pytest.approx(1500.0)
     old = _run(tmp_path, OLD_RING)
-    assert reader("step.decode_device_ms.batch").compute(old) is None
+    assert reader("step.decode_device_ms").compute(old) is None
     assert reader("step.mixed_device_ms").compute(old) is None
     assert reader("step.mixed_device_ms").compute(_run(tmp_path, [])) is None
 
@@ -148,7 +148,7 @@ def test_first_calls_before_the_window(tmp_path):
     assert reader("setup.first_calls_s").compute(_run(tmp_path, RING)) == \
         pytest.approx(10.5)
     # the field is older than this PR: an older program's ring reads too
-    assert reader("setup.first_calls_s.batch").compute(
+    assert reader("setup.first_calls_s").compute(
         _run(tmp_path, OLD_RING)) == pytest.approx(10.5)
     empty = _run(tmp_path, [])
     empty.ring = []                    # an untraced run pages no ring
@@ -186,12 +186,12 @@ def test_queue_wait_share_and_worker_ready(tmp_path):
     run = _run(tmp_path, RING, TRACES)
     assert reader("sched.queue_wait_share").compute(run) == \
         pytest.approx(100.0 * 49.0 / 70.0)
-    assert reader("setup.worker_ready_s.batch").compute(run) == \
+    assert reader("setup.worker_ready_s").compute(run) == \
         pytest.approx(17.5)
     # an older program: request traces, no startup trace; or no export
     old = _run(tmp_path, RING, TRACES[1:])
     assert reader("setup.worker_ready_s").compute(old) is None
-    assert reader("sched.queue_wait_share.batch").compute(old) == \
+    assert reader("sched.queue_wait_share").compute(old) == \
         pytest.approx(70.0)
     os.remove(tmp_path / "worker0.traces.jsonl")
     assert reader("sched.queue_wait_share").compute(old) is None
