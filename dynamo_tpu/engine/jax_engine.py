@@ -361,7 +361,11 @@ class JaxEngine(ScheduledEngineBase):
         # a family that keeps a slot a sequence beside its pages: what
         # only moves block chains is refused by name here, at start-up
         self.state_slots = 0
-        if model_cfg.slot_kind:
+        # ... and a family that keeps index pages beside its keys and
+        # values (one block chain, no slot): what places or moves the
+        # pages of one pool is refused the same way
+        selects = bool(model_cfg.index_topk)
+        if model_cfg.slot_kind or selects:
             for what, on in (
                     ("a device mesh (--tensor-parallel-size, "
                      "--data-parallel-size, --sequence-parallel-size)",
@@ -372,9 +376,16 @@ class JaxEngine(ScheduledEngineBase):
                     ("--quantize", bool(self.cfg.quantize)),
                     ("--speculative-num-tokens (a rejected draft would "
                      "have to roll the slot back)",
-                     bool(self.cfg.spec_tokens))):
+                     bool(self.cfg.spec_tokens)
+                     and bool(model_cfg.slot_kind))):
                 if on:
                     model_cfg.paged_only(what)
+            if self.cfg.spec_tokens and not model_cfg.slot_kind:
+                raise NotImplementedError(
+                    "--speculative-num-tokens: a verify window over a "
+                    "learned selection (sa_config, models/moe.py) is not "
+                    "implemented")
+        if model_cfg.slot_kind:
             self.state_slots = int(self.cfg.state_slots
                                    or self.cfg.max_num_seqs)
             if self.state_slots < 1:
@@ -604,10 +615,12 @@ class JaxEngine(ScheduledEngineBase):
         # (dynamo_worker_attn_one_token_rows_total{form})
         self.one_token_form: Optional[str] = None
         if model_cfg.index_topk:
-            self.one_token_form = (
-                "masked" if family.on_kernels(
-                    model_cfg, self._attn_decode, self.cfg.page_size)
-                else "gathered")
+            on_kernels = (
+                family.on_kernels(model_cfg, self._attn_decode,
+                                  self.cfg.page_size)
+                if model_cfg.slot_kind
+                else llama.selects_on_kernels(self._attn_decode, self.pages))
+            self.one_token_form = "masked" if on_kernels else "gathered"
         self.attn_one_token_rows: Dict[str, int] = {}
         # prefill-carrying dispatches by form: "packed", "padded:<reason>"
         # (dynamo_worker_prefill_steps_total; the collector pre-seeds the
@@ -754,14 +767,16 @@ class JaxEngine(ScheduledEngineBase):
     def kv_pool(self):
         """The paged pool alone (of a family with a slot a sequence,
         ``pages`` holds the other pools too)."""
-        return self.pages["kv"] if self.state_slots else self.pages
+        return self.pages["kv"] if isinstance(self.pages, dict) \
+            else self.pages
 
     @property
     def page_pools(self) -> Tuple[str, ...]:
         """The pools of ``pages`` that the page table addresses (axis 1:
-        the pages), of a family that keeps several."""
+        the pages), of a family that keeps several: one block chain
+        holds a page of each."""
         return tuple(k for k in ("kv", "index")
-                     if self.state_slots and k in self.pages)
+                     if isinstance(self.pages, dict) and k in self.pages)
 
     @property
     def cache_kinds(self) -> str:
@@ -771,10 +786,12 @@ class JaxEngine(ScheduledEngineBase):
         if self.model_cfg.state_layers:
             kinds += (f"+state[L={self.model_cfg.state_layers},"
                       f"S={self.state_slots},f32]")
+        if "index" in self.page_pools:
+            kinds += (f"+index[L={self.pages['index'].shape[0]},"
+                      f"D={self.model_cfg.index_head_dim}]")
         if self.model_cfg.window_layers:
-            Li, _n, _ps, Di = self.pages["index"].shape
             Lw, _s, Rp, _two, _one, ps, Dw = self.pages["win"].shape
-            kinds += (f"+index[L={Li},D={Di}]+window[L={Lw},"
+            kinds += (f"+window[L={Lw},"
                       f"S={self.state_slots},R={Rp * ps},D={Dw}]")
         return kinds
 
@@ -784,7 +801,8 @@ class JaxEngine(ScheduledEngineBase):
         (``dynamo_worker_cache_bytes{kind}``)."""
         kind = {"kv": "paged", "index": "index", "win": "window"}
         out: Dict[str, int] = {}
-        pools = self.pages if self.state_slots else {"kv": self.pages}
+        pools = (self.pages if isinstance(self.pages, dict)
+                 else {"kv": self.pages})
         for name, pool in pools.items():
             k = kind.get(name, "state")
             out[k] = out.get(k, 0) + int(pool.size) * pool.dtype.itemsize
@@ -806,8 +824,11 @@ class JaxEngine(ScheduledEngineBase):
         if self.padded_reason is not None:
             return None
         if self.one_token_form is not None:
+            if self.one_token_form != "masked":
+                return "gathered"
             return ("chunks:mla_selected,one_token:mla_selected_rows"
-                    if self.one_token_form == "masked" else "gathered")
+                    if self.model_cfg.kv_lora_rank
+                    else "chunks:selected_chunks,one_token:selected_rows")
         if self.model_cfg.kv_lora_rank:
             return "mla_ragged"
         if self._packed_splits:

@@ -329,14 +329,17 @@ class ScheduledEngineBase(EngineBase):
         else:
             tokens_real = rows
         state = (0, 0, 0, 0, 0, 0)
-        if self.scheduler.cfg.state_slots:
+        slots = self.scheduler.cfg.state_slots
+        topk = getattr(getattr(self, "model_cfg", None), "index_topk", 0)
+        if slots or topk:
             # (rows whose slot the dispatch read, tokens through the
             # chunk form of the rule, row-steps through the one-token
             # form, query-key pairs a full-attention layer scored, and
-            # the keys it attended where it attends a selection)
-            topk = getattr(getattr(self, "model_cfg", None),
-                           "index_topk", 0)
-            linear = self.scheduler.cfg.slot_kind == "recurrent_state"
+            # the keys it attended where it attends a selection - with a
+            # slot a sequence or, the grouped-query family that selects,
+            # without one)
+            linear = slots and self.scheduler.cfg.slot_kind == \
+                "recurrent_state"
             if kind in ("prefill", "mixed"):
                 several = [c.length for c in chunks if c.length > 1]
                 # (a chained step's decode rows: the token in flight too)
@@ -352,7 +355,7 @@ class ScheduledEngineBase(EngineBase):
             # a prompt chunk's row once, a fused block's rows once a step
             moved = (rows if kind in ("prefill", "mixed")
                      else gdn[1]) * self.state_row_bytes
-            state = (rows,) + (gdn if linear else (0, 0)) + (
+            state = (rows if slots else 0,) + (gdn if linear else (0, 0)) + (
                 sum(n * (2 * p + n + 1) // 2 for p, n in spans),
                 sum(selected_keys(p, n, topk) for p, n in spans), moved)
             self.attn_visible_keys += state[3]

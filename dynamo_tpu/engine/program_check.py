@@ -388,7 +388,7 @@ def step_programs(engine, batch: int, chunk: int, width: int = 8,
     params = jax.tree_util.tree_map(lambda l: sds(l.shape, l.dtype),
                                     engine.params)
     pages = sds(_pool_shape(engine, num_pages), engine.kv_pool.dtype)
-    if engine.state_slots:
+    if isinstance(engine.pages, dict):
         # the slot pools as the engine holds them, beside the paged pool
         # (and what other pool the page table addresses, at its pages)
         pages = {**{k: sds(a.shape[:1] + (_pool_shape(engine, num_pages)[1],)

@@ -14,7 +14,9 @@ configs with window layers beside latent attention (``layer_types`` holding
 double-layer configs (``attn_blocks_per_layer == 2``:
 LongCat-Flash) use ``models.longcat``, other MLA configs (``kv_lora_rank >
 0``) ``models.deepseek``,
-other MoE configs (``num_experts > 0``: mixtral / qwen3_moe routing)
+other MoE configs (``num_experts > 0``: mixtral / qwen3_moe routing; with
+``index_topk`` - ``sa_config``, Keye-VL-2.0 - behind a learned selection,
+their cache a tree of key/value and index pages on one block chain)
 ``models.moe``, gemma-2 ``models.gemma``; everything else in the Llama tree
 (llama 2/3, mistral, qwen2/qwen3) uses ``models.llama``.
 """

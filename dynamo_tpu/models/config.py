@@ -19,6 +19,82 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, Optional
 
 
+SA_CONFIG_KEYS = ("indexer_head_dim", "indexer_num_heads",
+                  "indexer_num_kv_heads", "topk")
+
+
+def _selection(hf: Dict[str, Any], mla: bool, num_experts: int
+               ) -> Dict[str, Any]:
+    """``sa_config`` of a file of the llama tree as config fields: an
+    indexer of ``indexer_num_heads`` heads of ``indexer_head_dim`` over ONE
+    index key a token that keeps the best ``topk`` visible tokens, in every
+    layer, in front of the grouped-query cache (``models/moe.py``).
+    ``q_chunk_size`` / ``kv_chunk_size`` are the tiling in which the
+    published code evaluates the scores and change no selection: read and
+    dropped. Anything else the loader cannot honour is an error that names
+    the key."""
+    def no(key, why):
+        raise NotImplementedError(
+            f"sa_config {key} {(hf.get('sa_config') or {}).get(key)!r}: "
+            f"{why} (models/moe.py)")
+    sa = hf["sa_config"]
+    if not isinstance(sa, dict):
+        raise NotImplementedError(
+            f"sa_config {sa!r}: a mapping with "
+            + ", ".join(SA_CONFIG_KEYS) + " is implemented (models/moe.py)")
+    for key in SA_CONFIG_KEYS:
+        if not sa.get(key):
+            no(key, "the selection needs every one of "
+                    + ", ".join(SA_CONFIG_KEYS))
+    for key in sorted(sa):
+        if key not in SA_CONFIG_KEYS + ("q_chunk_size", "kv_chunk_size"):
+            no(key, "a key of the selection this loader does not implement")
+    if int(sa["indexer_num_kv_heads"]) != 1:
+        no("indexer_num_kv_heads", "one index key a token, shared by "
+                                   "every index head, is implemented")
+    if int(sa["indexer_head_dim"]) % 2:
+        no("indexer_head_dim", "rotary turns the index heads in halves")
+    if mla or not num_experts:
+        raise NotImplementedError(
+            "sa_config: the learned selection is implemented in front of "
+            "the grouped-query cache of the expert family (models/moe.py), "
+            "not beside " + ("kv_lora_rank (latent attention selects "
+                             "through the index_* keys, models/dots3.py)"
+                             if mla else "a dense FFN"))
+    if hf.get("use_sliding_window") or hf.get("sliding_window"):
+        raise NotImplementedError(
+            "sa_config beside a sliding window: every layer attends its "
+            "selection of the whole context (models/moe.py)")
+    return dict(index_n_heads=int(sa["indexer_num_heads"]),
+                index_head_dim=int(sa["indexer_head_dim"]),
+                index_topk=int(sa["topk"]))
+
+
+def _plain_rotary(hf: Dict[str, Any]) -> None:
+    """Raise where a file of the llama tree asks for rotary positions this
+    loader would silently not give it. Implemented: no ``rope_scaling``,
+    or type ``default`` - with or without ``mrope_section``, the split of a
+    head's rotary pairs over three position streams (time, height, width)
+    of a multimodal model: text alone feeds the three the SAME position,
+    and the rotation is then plain rotary whatever the split
+    (``tests/test_keye.py`` holds it)."""
+    rs = hf.get("rope_scaling")
+    if not rs:
+        return
+    rtype = rs.get("rope_type", rs.get("type"))
+    if rtype not in (None, "default"):
+        raise NotImplementedError(
+            f"rope_scaling type {rtype!r}: outside the latent-attention "
+            "family (yarn) only plain rotary positions are implemented "
+            "(type 'default' or none)")
+    for key in sorted(rs):
+        if key not in ("rope_type", "type", "mrope_section",
+                       "mrope_interleaved"):
+            raise NotImplementedError(
+                f"rope_scaling {key} {rs[key]!r}: plain rotary positions "
+                "take no parameter")
+
+
 def _topk_method(hf: Dict[str, Any], model_type: str) -> str:
     """The MLA family's gate, from the config's own keys first:
     ``topk_method``, else ``scoring_func`` (sigmoid scores are the
@@ -176,6 +252,11 @@ class ModelConfig:
     # ``swa_*`` geometry over the last ``swa_window`` tokens, the query's
     # own among them, kept in a ring a sequence whose size does not grow
     # with its context). () = every layer the same: every other family
+    # Without window layers or a latent (``sa_config`` of a file of the
+    # llama tree, ``_selection``): EVERY layer of an expert model selects,
+    # in front of its grouped-query pages (``models/moe.py``); the index
+    # pages share the block chain of the keys and values, there is no
+    # slot, and the prefix cache stays on
     layer_types: tuple = ()
     index_n_heads: int = 0
     index_head_dim: int = 0
@@ -246,15 +327,23 @@ class ModelConfig:
     def paged_only(self, what: str) -> None:
         """Raise, by the family's name, where ``what`` moves block chains
         of the paged cache only: a request of a family with linear or
-        window layers is its pages AND its slot (``slot_kind``), and a
-        chain without the matching slot is a wrong answer, not a slow
-        one."""
+        window layers is its pages AND its slot (``slot_kind``), a block
+        of a family that selects is its keys and values AND its index
+        keys, and a chain without the matching slot or index keys is a
+        wrong answer, not a slow one."""
         if self.state_layers:
             raise NotImplementedError(
                 f"model_type {self.model_type!r} keeps a recurrent state "
                 f"beside the paged cache ({self.state_layers} "
                 f"linear-attention layers): {what} moves block chains only "
                 "and cannot move a state yet")
+        if self.index_topk and not self.window_layers:
+            raise NotImplementedError(
+                f"model_type {self.model_type!r} keeps index pages beside "
+                "the pages of its keys and values (a learned selection of "
+                f"{self.index_topk} tokens, one block chain for both "
+                f"pools): {what} moves the pages of one pool only, and a "
+                "chain without its index keys selects by zeros")
         if self.window_layers:
             raise NotImplementedError(
                 f"model_type {self.model_type!r} keeps a window cache "
@@ -454,6 +543,13 @@ class ModelConfig:
             extra["rope_interleave"] = bool(
                 hf.get("rope_interleave", True))
         mla = bool(extra.get("kv_lora_rank"))
+        if not mla:
+            _plain_rotary(hf)
+        # a learned selection in front of the grouped-query cache, read off
+        # the key that makes it one (``sa_config``), never off a model_type
+        selects = "sa_config" in hf
+        if selects:
+            extra.update(_selection(hf, mla, num_experts))
         if mt in ("sdar", "sdar_moe"):
             # generation by diffusion over blocks (the Qwen3 / Qwen3-MoE
             # block under a block-wise visibility). The published config
@@ -484,7 +580,12 @@ class ModelConfig:
             # gemma ties embeddings by default and serializes nothing
             tie_word_embeddings=bool(hf.get("tie_word_embeddings",
                                             mt.startswith("gemma"))),
-            qk_norm=mt in ("qwen3", "qwen3_moe", "sdar", "sdar_moe"),
+            # per-head q/k norm: the Qwen3 block's, by the names that are
+            # it - and by what the file carries where it carries
+            # ``sa_config`` (a Qwen3-MoE block under another name, whose
+            # ``qk_norm`` key, where present, has the last word)
+            qk_norm=(bool(hf.get("qk_norm", True)) if selects else
+                     mt in ("qwen3", "qwen3_moe", "sdar", "sdar_moe")),
             attention_bias=bool(hf.get("attention_bias", mt == "qwen2")),
             model_type=mt,
             dtype=dtype,

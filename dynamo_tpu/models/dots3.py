@@ -19,7 +19,8 @@ FFN(RMSNorm(h))``.
   ``w = x W_Iw``; ``I[t, s] = sum_j w[t, j] relu(q_I[t, j] . k_I[s])`` and
   the ``min(index_topk, t + 1)`` best-scored tokens ``s <= t`` are the
   ONLY keys the latent attention of token ``t`` reads, all heads alike
-  (``ops/sparse_latent.py``: the exact selection, the gather). The
+  (``ops/indexer.py``: the exact selection; ``ops/sparse_latent.py``: the
+  gather). The
   indexer's constant factors (``index_n_heads ** -0.5 * index_head_dim **
   -0.5``) change no selection and are left out.
 - **Window layer** (``cfg.window_cfg()``: other heads, head sizes, ranks,
@@ -89,9 +90,10 @@ from dynamo_tpu.models.llama import (
     randn_stack,
     write_rows,
 )
+from dynamo_tpu.ops import indexer
 from dynamo_tpu.ops import sparse_latent as sl
-from dynamo_tpu.ops.attention import write_slabs, write_slabs_packed
 from dynamo_tpu.ops.gdn import token_rows
+from dynamo_tpu.ops.indexer import layer_norm as _layer_norm
 
 Params = Dict[str, Any]
 
@@ -118,7 +120,6 @@ QUERY_GAIN = 4.0
 # has a standard deviation of 2.7 and the score I one of 8.4 (same
 # measurement), so a selection is decided by a score's leading digits.
 INDEX_GAIN = 1.0
-LAYER_NORM_EPS = 1e-6
 
 
 def make_pages(cfg: ModelConfig, num_pages: int, page_size: int,
@@ -278,7 +279,7 @@ class Step:
             B * S, starts if self.packed
             else jnp.arange(B, dtype=jnp.int32) * S,
             new_lens, total_lens, slots)
-        self.pos = sl.token_positions(self.rows, total_lens)
+        self.pos = indexer.token_positions(self.rows, total_lens)
         # the rows the masked form takes: those of several tokens, and in
         # a [B, S > 1] step every row
         least = 1 if self.packed else 0
@@ -306,14 +307,6 @@ class Step:
             positions, total = positions % ring, begin + self.new_lens
         return write_rows(pool, layer, k, v, table, positions, total,
                           self.new_lens, self.starts)
-
-
-def _layer_norm(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.mean((xf - mu) ** 2, axis=-1, keepdims=True)
-    return ((xf - mu) * jax.lax.rsqrt(var + LAYER_NORM_EPS)).astype(x.dtype) \
-        * w + b
 
 
 def _rope_head(cfg: ModelConfig, x: jnp.ndarray,
@@ -420,21 +413,14 @@ def full_block(cfg: ModelConfig, lp, h, cache, lidx, st: Step):
         q_i, k_i, w_i = index_inputs(cfg, lp, x, st.positions)
     with stage("layer.kv_write"):
         kv = st.write(kv, lidx, k_new, v_new, st.page_table)
-        # the index pages as a pool of one array a token
-        Li, Np, ps, D = index.shape
-        k_i = k_i.reshape(B, S, 1, 1, D)
-        index = (write_slabs_packed(
-            index.reshape(Li, Np, 1, 1, ps, D), lidx, k_i[0], st.page_table,
-            st.starts, st.new_lens, st.total_lens) if st.packed
-            else write_slabs(
-                index.reshape(Li, Np, 1, 1, ps, D), lidx, k_i,
-                st.page_table, st.positions, st.new_lens)).reshape(
-                    index.shape)
+        index = indexer.write_index_keys(
+            index, lidx, k_i.reshape(B, S, -1), st.page_table,
+            st.positions, st.total_lens, st.new_lens, st.starts)
     with stage("layer.attn"):
         scale = _mla_scale(cfg)
         q_i = q_i.astype(index.dtype)
         if st.kernel:
-            one, bias = sl.select_split(
+            one, bias = indexer.select_split(
                 q_i, w_i, index, lidx, st.page_table, st.rows,
                 st.total_lens, cfg.index_topk, **st.walk)
             with stage("sparse"):
@@ -449,7 +435,7 @@ def full_block(cfg: ModelConfig, lp, h, cache, lidx, st: Step):
                                        st.one_lens, rows_bias,
                                        "mla_selected_rows")
         else:
-            sel, live = sl.select(
+            sel, live = indexer.select(
                 q_i, w_i, index, lidx, st.page_table, st.rows,
                 st.total_lens, cfg.index_topk, **st.walk)
             with stage("sparse"):
@@ -489,7 +475,7 @@ def window_block(wcfg: ModelConfig, lp, h, cache, widx, st: Step):
                 table = sl.ring_table(st.rows.slot, Rp)
                 bias = jnp.where(
                     sl.ring_seen(st.rows, st.pos, st.total_lens, ring,
-                                 wcfg.swa_window), 0.0, sl.NEG_INF)
+                                 wcfg.swa_window), 0.0, indexer.NEG_INF)
                 q = _kernel_queries(q_lat, q_pe, scale, pool.dtype)
                 lat = None
                 if st.width > 1:

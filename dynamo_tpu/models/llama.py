@@ -30,11 +30,12 @@ from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.ops.attention import (
     paged_attention,
     ragged_paged_attention,
+    selected_attention,
     write_kv,
     write_kv_packed,
 )
 from dynamo_tpu.ops.rope import apply_rope
-from dynamo_tpu.ops import quant
+from dynamo_tpu.ops import indexer, quant
 from dynamo_tpu.ops.sampling import top_candidates
 
 Params = Dict[str, Any]
@@ -67,8 +68,18 @@ def make_pages(cfg: ModelConfig, num_pages: int, page_size: int,
     hand out pages starting at index 1.
     """
     dtype = dtype or jnp.dtype(cfg.dtype)
-    return jnp.zeros((cfg.num_cache_layers, num_pages, 2, cfg.num_kv_heads,
-                      page_size, cfg.head_dim), dtype=dtype)
+    kv = jnp.zeros((cfg.num_cache_layers, num_pages, 2, cfg.num_kv_heads,
+                    page_size, cfg.head_dim), dtype=dtype)
+    if not cfg.index_topk:
+        return kv
+    # a model whose layers attend a learned selection: one index key a
+    # token a layer beside its keys and values, under the SAME page id -
+    # two arrays, one block chain (``write_rows`` writes both, the page
+    # table addresses both, ``engine/pages.py`` owns the ids)
+    return {"kv": kv,
+            "index": indexer.index_pages(cfg.num_cache_layers, num_pages,
+                                         page_size, cfg.index_head_dim,
+                                         dtype)}
 
 
 def randn_stack(key, n: int, shape: tuple, scale: float,
@@ -289,8 +300,18 @@ def packed_rows(packed: bool, new_lens: jnp.ndarray
 
 
 def write_rows(pages, lidx, k, v, page_table, positions, total_lens,
-               new_lens, starts):
-    """The cache write of either step form (``starts``: ``packed_rows``)."""
+               new_lens, starts, k_i=None):
+    """The cache write of either step form (``starts``: ``packed_rows``).
+    ``pages`` a tree of key/value pages and index pages (``make_pages``
+    of a model that selects): ``k_i [B, S, D]``, the tokens' index keys,
+    go into the index pages through the same page table in the same
+    stage - a block never holds the one without the other."""
+    if isinstance(pages, dict):
+        return {"kv": write_rows(pages["kv"], lidx, k, v, page_table,
+                                 positions, total_lens, new_lens, starts),
+                "index": indexer.write_index_keys(
+                    pages["index"], lidx, k_i, page_table, positions,
+                    total_lens, new_lens, starts)}
     if starts is None:
         return write_kv(pages, lidx, k, v, page_table, positions, new_lens)
     return write_kv_packed(pages, lidx, k[0], v[0], page_table, starts,
@@ -320,6 +341,87 @@ def attend_rows(attn_impl, q, pages, lidx, page_table, positions,
     return (attn_impl or ragged_paged_attention)(
         q[0], pages, lidx, page_table, starts, new_lens, total_lens,
         sm_scale, **kw)[None]
+
+
+def index_inputs(cfg: ModelConfig, lp: Dict[str, jnp.ndarray],
+                 h: jnp.ndarray, positions: jnp.ndarray):
+    """The indexer's three projections of the normed stream (``h [B, S,
+    H]``; the norm is ``_project_qkv``'s own, computed once by the
+    compiler): ``(q_I [B * S, J, D], k_I [B, S, D], w [B * S, J]
+    float32)`` - ``q_I = x W_qI`` and the ONE key a token ``k_I =
+    LayerNorm(x W_kI)`` both turned by the token's position over their
+    whole ``D`` (``rope_theta``, halves: the family's convention), ``w = x
+    W_w``."""
+    B, S, _ = h.shape
+    J, D = cfg.index_n_heads, cfg.index_head_dim
+    x = _rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
+    q = apply_rope((x @ lp["wi_q"]).reshape(B, S, J, D), positions,
+                   cfg.rope_theta)
+    k = apply_rope(indexer.layer_norm(x @ lp["wi_k"], lp["i_norm_w"],
+                                      lp["i_norm_b"])[:, :, None, :],
+                   positions, cfg.rope_theta)[:, :, 0]
+    w = jnp.dot(x, lp["wi_w"], preferred_element_type=jnp.float32)
+    return q.reshape(B * S, J, D), k, w.reshape(B * S, J)
+
+
+def selects_on_kernels(attn_impl, pages) -> bool:
+    """Whether a step of a model that selects runs the masked kernels
+    (``ops/pallas/ragged.selected_attention_rows``): the engine's Pallas
+    marker, unwrapped (a mesh wraps the kernels a shard), at a head size
+    and pages they tile. Otherwise the gathered form, in XLA."""
+    from dynamo_tpu.ops.pallas.decode import supports
+
+    kv = pages["kv"]
+    return bool(getattr(attn_impl, "pallas_paged_kernel", False)
+                and not getattr(attn_impl, "per_shard", False)
+                and supports(kv.shape[-1], kv.shape[-2]))
+
+
+def attend_selected(cfg: ModelConfig, attn_impl, q, q_i, w_i, pages, lidx,
+                    page_table, total_lens, new_lens, sm_scale, starts):
+    """Attention of either step form over a LEARNED SELECTION: each token
+    keeps the ``min(index_topk, pos + 1)`` best-scored tokens it can see
+    (``ops/indexer.py``, against the index pages this step has written)
+    and the grouped-query softmax runs over those alone, one selection
+    for every head. ``q [B, S, Hq, Dh]``; returns the same.
+
+    On the kernels the MASKED form - the selection as a bias, a row's
+    whole context streamed (``selected_rows`` for the rows of one token,
+    ``selected_chunks`` for the rows of several, in a device trace) -
+    elsewhere the GATHERED form. Where the table holds no more than
+    ``index_topk`` tokens every visible token is selected, nothing is
+    scored, and the result is dense attention's."""
+    from dynamo_tpu.ops.gdn import token_rows
+
+    B, S, Hq, Dh = q.shape
+    N = B * S
+    packed = starts is not None
+    rows = token_rows(
+        N, starts if packed else jnp.arange(B, dtype=jnp.int32) * S,
+        new_lens, total_lens, jnp.zeros_like(new_lens))
+    walk = dict(width=S, packed=packed)
+    kv, index = pages["kv"], pages["index"]
+    qf = q.reshape(N, Hq, Dh)
+    q_i = q_i.astype(index.dtype)
+    if selects_on_kernels(attn_impl, pages):
+        from dynamo_tpu.ops.pallas.ragged import selected_attention_rows
+
+        one, bias = indexer.select_split(
+            q_i, w_i, index, lidx, page_table, rows, total_lens,
+            cfg.index_topk, **walk)
+        with stage("sparse"):
+            out = selected_attention_rows(
+                qf, kv, lidx, page_table, rows.start, new_lens, total_lens,
+                one, bias, sm_scale)
+    else:
+        sel, live = indexer.select(
+            q_i, w_i, index, lidx, page_table, rows, total_lens,
+            cfg.index_topk, **walk)
+        with stage("sparse"):
+            out = selected_attention(
+                qf, kv, lidx, page_table[rows.row], sel,
+                live & rows.valid[:, None], sm_scale)
+    return out.reshape(B, S, Hq, Dh).astype(q.dtype)
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,

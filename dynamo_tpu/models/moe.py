@@ -15,8 +15,28 @@ swapping the dense MLP for routed experts:
   pins fixed ``[E, C, H]`` buffers to the ``ep`` axis and drops past
   capacity.
 
+- **a learned selection in front of the attention** where the config has
+  one (``cfg.index_topk``: ``sa_config`` of a file of the llama tree,
+  Keye-VL-2.0's language model): in EVERY layer an indexer - ``q_I = x
+  W_qI`` (``index_n_heads`` heads of ``index_head_dim``), one cached key a
+  token ``k_I = LayerNorm(x W_kI)``, both rotated over their whole width,
+  head weights ``w = x W_w``; ``I[t, s] = sum_j w[t, j] relu(q_I[t, j] .
+  k_I[s])`` - keeps the ``min(index_topk, t + 1)`` best-scored tokens a
+  query can see, EXACTLY, and the grouped-query softmax runs over those
+  alone, one selection for every head (``llama.index_inputs``,
+  ``attend_selected``; the indexer's arithmetic is ``ops/indexer.py``'s,
+  shared with ``models/dots3.py``). The cache is then a tree
+  (``llama.make_pages``): the key/value pages and index pages under the
+  SAME page id, written in one stage (``llama.write_rows``) - one block
+  chain, no slot, so the prefix cache stays on and a hit, an eviction and
+  a preemption move both pools. The same ``forward``: every branch is
+  static on the config, and a model without ``index_topk`` traces the
+  program it always did.
+
 Weight layout (stacked for scan): ``w_router [L, H, E]``,
-``w_gate/w_up [L, E, H, I]``, ``w_down [L, E, I, H]``.
+``w_gate/w_up [L, E, H, I]``, ``w_down [L, E, I, H]``; with a selection
+``wi_q [L, H, J * D]``, ``wi_k [L, H, D]``, ``wi_w [L, H, J]`` and the
+index key's LayerNorm ``i_norm_w`` / ``i_norm_b [L, D]``.
 """
 
 from __future__ import annotations
@@ -35,6 +55,8 @@ from dynamo_tpu.models.llama import (
     _project_qkv,
     _rms_norm,
     attend_rows,
+    attend_selected,
+    index_inputs,
     make_pages,
     packed_rows,
     randn_stack,
@@ -533,6 +555,20 @@ def init_params(cfg: ModelConfig, rng: jax.Array,
     layers["w_gate"] = randn_stack(next(keys), L, (E, H, I), scale, dtype)
     layers["w_up"] = randn_stack(next(keys), L, (E, H, I), scale, dtype)
     layers["w_down"] = randn_stack(next(keys), L, (E, I, H), scale, dtype)
+    if cfg.index_topk:
+        # the indexer (``llama.index_inputs``), at the common scale: the
+        # key is LayerNormed and the attention's q and k are normed a
+        # head, so relu's argument has a standard deviation of 4 and a
+        # head's attention scores one of 1 - a selection is decided by a
+        # score's leading digits and leaving it out moves the output
+        # (benchmarks/configs/keye-vl-2.0-30b-a3b.json ``assumed``)
+        J, D = cfg.index_n_heads, cfg.index_head_dim
+        ki = iter(jax.random.split(jax.random.fold_in(rng, 11), 3))
+        layers["wi_q"] = randn_stack(next(ki), L, (H, J * D), scale, dtype)
+        layers["wi_k"] = randn_stack(next(ki), L, (H, D), scale, dtype)
+        layers["wi_w"] = randn_stack(next(ki), L, (H, J), scale, dtype)
+        layers["i_norm_w"] = jnp.ones((L, D), dtype)
+        layers["i_norm_b"] = jnp.zeros((L, D), dtype)
     return params
 
 
@@ -548,8 +584,13 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     layer's counts summed over layers — ``moe_experts_touched`` and
     ``moe_assignments``, or the dispatch backend's
     ``moe_dropped_assignments`` — which the engine forwards to worker
-    stats)."""
+    stats). ``pages`` is ``llama.make_pages``'s: the pool, or the tree of
+    key/value and index pages of a model that selects (module
+    docstring)."""
     sm_scale = cfg.head_dim ** -0.5
+    # every layer attends a learned selection (``cfg.index_topk``,
+    # ``sa_config``): ``pages`` is then the tree of ``make_pages``
+    selects = bool(cfg.index_topk)
     with stage("step.inputs"):
         starts = packed_rows(packed, new_lens)
     with stage("embed"):
@@ -565,13 +606,21 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
         lp, lidx = xs
         with stage("layer.attn_in"):
             q, k, v = _project_qkv(cfg, lp, h, positions)
+            if selects:
+                q_i, k_i, w_i = index_inputs(cfg, lp, h, positions)
         with stage("layer.kv_write"):
             pages = write_rows(pages, lidx, k, v, page_table, positions,
-                               total_lens, new_lens, starts)
+                               total_lens, new_lens, starts,
+                               **({"k_i": k_i} if selects else {}))
         with stage("layer.attn"):
-            attn = attend_rows(attn_impl, q, pages, lidx, page_table,
-                               positions, total_lens, new_lens, sm_scale,
-                               starts, **visibility(cfg))
+            if selects:
+                attn = attend_selected(cfg, attn_impl, q, q_i, w_i, pages,
+                                       lidx, page_table, total_lens,
+                                       new_lens, sm_scale, starts)
+            else:
+                attn = attend_rows(attn_impl, q, pages, lidx, page_table,
+                                   positions, total_lens, new_lens,
+                                   sm_scale, starts, **visibility(cfg))
         grouped = dict(kw, layer=lidx) if experts else {}
         h, aux = _moe_layer_tail(cfg, {**lp, **experts}, h, attn,
                                  ep_mesh=ep_mesh, **grouped)

@@ -74,7 +74,7 @@ def write_slabs(pool: jnp.ndarray, layer_idx, new: jnp.ndarray,
     in at most ``J`` pages in table order. Why pages and no smaller
     window: the module docstring.
     """
-    A, Hkv, page_size, Dh = pool.shape[-4:]
+    A, Hkv, page_size, Dh = _page_geometry(pool, new)
     B, S = positions.shape
     J = (S + page_size - 2) // page_size + 1
     start = positions[:, 0]
@@ -117,16 +117,32 @@ def write_slabs(pool: jnp.ndarray, layer_idx, new: jnp.ndarray,
                          real.reshape(B, J, page_size))
 
 
+def _page_geometry(pool: jnp.ndarray, new: jnp.ndarray) -> tuple:
+    """``(A, Hkv, page_size, Dh)`` of a pool ``[L, N, A, Hkv, ps, Dh]``, or
+    of a FLAT pool ``[L, N, ps * A * Hkv * Dh]`` whose page is one row (a
+    pool of narrow arrays: a minor axis under the chip's 128 lanes is
+    padded to them, and the compiler re-lays such a pool around every
+    dispatch), read off the new tokens ``[..., A, Hkv, Dh]``."""
+    if pool.ndim != 3:
+        return pool.shape[-4:]
+    A, Hkv, Dh = new.shape[-3:]
+    return A, Hkv, pool.shape[-1] // (A * Hkv * Dh), Dh
+
+
 def _commit_pages(at, laid: jnp.ndarray, real: jnp.ndarray) -> jnp.ndarray:
     """The page gather / select / scatter both forms of the write end in.
     ``at`` indexes the pool by the physical page of every slab ``[..., ]``,
     ``laid [..., ps, 2, Hkv, Dh]`` holds the new tokens at their slots,
     token-major, and ``real [..., ps]`` says which slots take one; every
-    other slot keeps what the page held."""
+    other slot keeps what the page held. A flat pool's pages (``[...,
+    ps * A * Hkv * Dh]``, ``_page_geometry``) are seen as slabs for the
+    select and scattered back as rows."""
     old = at.get(mode="clip")                        # [..., 2, Hkv, ps, Dh]
     laid = jnp.moveaxis(laid, -4, -2)
-    return at.set(jnp.where(real[..., None, None, :, None], laid, old),
-                  mode="drop")
+    flat = old.shape
+    new = jnp.where(real[..., None, None, :, None], laid,
+                    old.reshape(laid.shape))
+    return at.set(new.reshape(flat), mode="drop")
 
 
 def write_kv_packed(pool: jnp.ndarray, layer_idx, k_new: jnp.ndarray,
@@ -153,7 +169,7 @@ def write_slabs_packed(pool: jnp.ndarray, layer_idx, new: jnp.ndarray,
     of ``n`` tokens at any offset spans at most ``(n - 1) // ps + 2``), and
     slab ``m`` takes the ``ps`` packed tokens from ``starts[r] + j * ps -
     off[r]`` on: one slice a page, no per-row padded buffer."""
-    page_size = pool.shape[-2]
+    page_size = _page_geometry(pool, new)[2]
     T = new.shape[0]
     R = page_table.shape[0]
     M = T // page_size + 2 * R
@@ -435,6 +451,64 @@ def ragged_paged_attention(q: jnp.ndarray, pages: jnp.ndarray, layer_idx,
     return jnp.where(valid[:, None, None], out, 0.0).astype(q.dtype)
 
 
+def selected_attention(q: jnp.ndarray, pages: jnp.ndarray, layer_idx,
+                       tables: jnp.ndarray, sel: jnp.ndarray,
+                       live: jnp.ndarray, sm_scale: float) -> jnp.ndarray:
+    """Grouped-query attention of ``T`` queries, each over a SELECTION of
+    its own context, in the GATHERED form: the selected tokens' keys and
+    values are fetched by ``(page, offset)`` and the softmax runs over
+    them alone - the path without kernels (the CPU) and the oracle of the
+    masked kernels (``ops/pallas/ragged.selected_attention_rows``), which
+    stream a row's whole context under a bias instead: a token is ``2
+    Hkv`` rows of ``Dh`` in the page layout, and a fetch of rows costs
+    the TPU more than the stream it saves (PERF.md section 6, PR 56).
+
+    q:      [T, Hq, Dh]
+    pages:  [L, N, 2, Hkv, page_size, Dh]
+    tables: [T, P] each query's own page-table row
+    sel:    [T, K] int32 positions in the query's context; ``live [T, K]``
+            which of them count (``ops/indexer.select``)
+    returns [T, Hq, Dh] float32, zero where a query has no live key.
+
+    A block of queries at a time: the gathered rows ``[block, K, 2, Hkv,
+    Dh]`` are the large temporary."""
+    T, Hq, Dh = q.shape
+    L, N, _two, Hkv, ps, _ = pages.shape
+    K = sel.shape[1]
+    G = Hq // Hkv
+    tb = T
+    while tb > 8 and tb * K * 2 * Hkv * Dh > (1 << 27):
+        tb = -(-tb // 2)
+    nb = -(-T // tb)
+    pad = nb * tb - T
+
+    def cut(x):
+        x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+        return x.reshape((nb, tb) + x.shape[1:])
+
+    # every layer's pages on one axis: no slice of the layer is made
+    flat = pages.reshape((L * N, 2, Hkv, ps, Dh))
+    base = layer_idx * N
+
+    def block(xs):
+        qb, table, s, lv = xs
+        page = base + jnp.take_along_axis(table, s // ps, axis=1)
+        kv = flat[page, :, :, s % ps]                  # [tb, K, 2, Hkv, Dh]
+        qg = qb.reshape(tb, Hkv, G, Dh).astype(pages.dtype)
+        a = jnp.einsum("tngd,tknd->tngk", qg, kv[:, :, 0],
+                       preferred_element_type=jnp.float32) * sm_scale
+        a = jnp.where(lv[:, None, None, :], a, NEG_INF)
+        m = jnp.max(a, axis=-1, keepdims=True)
+        p = jnp.where(lv[:, None, None, :], jnp.exp(a - m), 0.0)
+        den = jnp.sum(p, axis=-1, keepdims=True)
+        out = jnp.einsum("tngk,tknd->tngd", p.astype(pages.dtype),
+                         kv[:, :, 1], preferred_element_type=jnp.float32)
+        return (out / jnp.maximum(den, 1e-20)).reshape(tb, Hq, Dh)
+
+    out = jax.lax.map(block, (cut(q), cut(tables), cut(sel), cut(live)))
+    return out.reshape(nb * tb, Hq, Dh)[:T]
+
+
 def paged_attention(q: jnp.ndarray, pages: jnp.ndarray, layer_idx,
                     page_table: jnp.ndarray, positions: jnp.ndarray,
                     total_lens: jnp.ndarray, sm_scale: float,
@@ -482,6 +556,7 @@ def paged_attention(q: jnp.ndarray, pages: jnp.ndarray, layer_idx,
 
 
 __all__ = ["write_kv", "write_kv_packed", "paged_attention", "horizon",
-           "ragged_paged_attention", "packed_token_rows",
+           "ragged_paged_attention", "selected_attention",
+           "packed_token_rows",
            "merge_softmax_partials", "normalize_softmax_partials",
            "NEG_INF"]

@@ -51,6 +51,10 @@ NEG_INF = -1e30
 # pages per streamed chunk: with 16-token pages this is 128 positions per
 # burst — one chunk's matmul fills the MXU's 128 lanes
 PAGES_PER_CHUNK = 8
+# ... and of the masked form (a ``bias``): the rescale of the accumulator
+# and the loop's own cost run once for four times the keys
+# (``mla_ragged.BIASED_PAGES_PER_CHUNK``, where it was measured)
+BIASED_PAGES_PER_CHUNK = 32
 
 
 def supports(head_dim: int, page_size: int) -> bool:
@@ -67,9 +71,15 @@ def _resolve_interpret(interpret) -> bool:
 
 
 def _decode_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
-                   lens_ref, out_ref, buf, sem, *, page_size: int,
-                   n_kv: int, chunk: int, softcap: float):
+                   lens_ref, *rest, page_size: int, n_kv: int, chunk: int,
+                   softcap: float, biased: bool = False):
     """One program per sequence: stream page chunks, online-softmax attend.
+
+    ``rest``: ``bias_ref`` where ``biased`` (``[1, chunks, span]`` float32,
+    the row's bias a chunk a line, added to every head's scores: 0 on the
+    keys the row attends, ``NEG_INF`` on the others - a learned selection
+    of the context, ``models/moe.py`` with ``cfg.index_topk``), then
+    ``out_ref, buf, sem``.
 
     kv_hbm is the STACKED cache ``[L, N, 2, Hkv, ps, Dh]`` and ``layer_ref``
     an SMEM scalar selecting the layer — the dynamic layer index rides the
@@ -96,6 +106,8 @@ def _decode_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
     token-packed step runs this kernel over all of its rows with a length
     for the one-token rows only (``ops/pallas/ragged.py``).
     """
+    bias_ref = rest[0] if biased else None
+    out_ref, buf, sem = rest[biased:]
     b = pl.program_id(0)
     layer = layer_ref[0]
     win = window_ref[0]
@@ -165,6 +177,8 @@ def _decode_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
             preferred_element_type=jnp.float32)
         if softcap:
             s = jnp.tanh(s / softcap) * softcap
+        if biased:
+            s = s + bias_ref[0, pl.ds(c, 1), :][None]
         pos = c * span + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where((pos < ctx) & (pos >= first_pos), s, NEG_INF)
 
@@ -172,7 +186,7 @@ def _decode_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
         p = jnp.exp(s - m_new[..., None])
         # a fully-masked first chunk would leave m at -inf and leak
         # exp(0)=1 weights — zero those rows (cannot happen without a
-        # window, where chunk c0=0 always holds position 0)
+        # window or a bias, where chunk c0=0 always holds position 0)
         p = jnp.where((m_new > NEG_INF / 2)[..., None], p, 0.0)
         scale = jnp.where(m > NEG_INF / 2, jnp.exp(m - m_new), 0.0)
         l = l * scale + jnp.sum(p, axis=-1)
@@ -192,17 +206,33 @@ def _decode_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("sm_scale", "softcap", "interpret"))
+                   static_argnames=("sm_scale", "softcap", "interpret",
+                                    "name"))
 def _paged_decode(q, kv_pages, layer_idx, window, page_table, total_lens,
                   sm_scale: float, softcap: float = 0.0,
-                  interpret: bool = False):
+                  interpret: bool = False, bias=None,
+                  name: str = "paged_decode"):
+    """``bias [B, P * ps]`` float32 (optional): the masked form, under the
+    caller's ``name`` in a device trace."""
     B, Hq, Dh = q.shape
     _L, _N, _two, Hkv, page_size, _ = kv_pages.shape
     P = page_table.shape[1]
-    chunk = min(PAGES_PER_CHUNK, P)
+    biased = bias is not None
+    chunk = min(BIASED_PAGES_PER_CHUNK if biased else PAGES_PER_CHUNK, P)
+    extra, extra_specs = (), []
+    if biased:
+        # [B, S] -> [B, chunks, span]: keys past the table's read NEG_INF
+        span = chunk * page_size
+        n_chunks = -(-P // chunk)
+        extra = (jnp.pad(bias.astype(jnp.float32),
+                         ((0, 0), (0, n_chunks * span - P * page_size)),
+                         constant_values=NEG_INF).reshape(B, n_chunks, span),)
+        extra_specs = [pl.BlockSpec((1, n_chunks, span),
+                                    lambda b: (b, 0, 0))]
 
     kernel = functools.partial(_decode_kernel, page_size=page_size,
-                               n_kv=Hkv, chunk=chunk, softcap=softcap)
+                               n_kv=Hkv, chunk=chunk, softcap=softcap,
+                               biased=biased)
     return pl.pallas_call(
         kernel,
         grid=(B,),
@@ -213,7 +243,7 @@ def _paged_decode(q, kv_pages, layer_idx, window, page_table, total_lens,
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
+        ] + extra_specs,
         out_specs=pl.BlockSpec((1, Hq, Dh), lambda b: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, 2, Hkv, chunk * page_size, Dh), kv_pages.dtype),
@@ -221,9 +251,9 @@ def _paged_decode(q, kv_pages, layer_idx, window, page_table, total_lens,
         ],
         out_shape=jax.ShapeDtypeStruct((B, Hq, Dh), q.dtype),
         interpret=interpret,
-        name="paged_decode",
+        name=name,
     )((q * sm_scale).astype(q.dtype), kv_pages, layer_idx, window,
-      page_table, total_lens)
+      page_table, total_lens, *extra)
 
 
 def paged_decode_attention_stacked(q: jnp.ndarray, pages: jnp.ndarray,
@@ -231,7 +261,8 @@ def paged_decode_attention_stacked(q: jnp.ndarray, pages: jnp.ndarray,
                                    positions: jnp.ndarray,
                                    total_lens: jnp.ndarray, sm_scale: float,
                                    window=None, softcap=None,
-                                   interpret: bool | None = None
+                                   interpret: bool | None = None,
+                                   bias=None, name: str = "paged_decode"
                                    ) -> jnp.ndarray:
     """Drop-in for ``ops.attention.paged_attention`` when S == 1: the whole
     stacked cache enters the kernel and the (possibly TRACED) ``layer_idx``
@@ -246,6 +277,11 @@ def paged_decode_attention_stacked(q: jnp.ndarray, pages: jnp.ndarray,
     window:     optional scalar (python int or traced, 0 = unlimited) —
                 gemma-2 alternating sliding-window layers
     softcap:    optional STATIC float (gemma logit soft-capping)
+    bias:       optional [B, P * ps] float32 added to every head's scores
+                (0 on the keys a row attends, ``NEG_INF`` on the others:
+                the masked form of a learned selection); a row of
+                ``total_lens`` 0 streams nothing and comes back zero
+    name:       the kernel's name in a device trace
     """
     B, S, Hq, Dh = q.shape
     if S != 1:
@@ -257,7 +293,8 @@ def paged_decode_attention_stacked(q: jnp.ndarray, pages: jnp.ndarray,
                         page_table.astype(jnp.int32),
                         total_lens.astype(jnp.int32), sm_scale,
                         softcap=float(softcap or 0.0),
-                        interpret=_resolve_interpret(interpret))
+                        interpret=_resolve_interpret(interpret), bias=bias,
+                        name=name)
     return out[:, None]                                    # [B, 1, Hq, Dh]
 
 

@@ -42,8 +42,23 @@ The ragged kernel reads the queries where they lie:
   ``lax.scan`` forward, the causal online softmax in f32 and window /
   softcap are the prefill kernel's (``ops/pallas/prefill.py``).
 
+**With a bias** (``selected_attention_rows``: a model whose layers attend a
+LEARNED SELECTION of their context, ``models/moe.py`` with
+``cfg.index_topk``) both kernels add ``ops/indexer.select_split``'s bias -
+0 on the keys a query attends, ``NEG_INF`` on the others - to every head's
+scores while the row's whole context streams through: the decode kernel a
+line a row (``selected_rows`` in a device trace), the ragged kernel a line a
+slot, ``[chunks, SB, span]`` a block (``selected_chunks``), both at chunks
+of 32 pages. The routing is not ``ragged_mixed_attention_packed``'s run of
+trailing rows: every row of one token goes to the decode kernel, fetched by
+its first slot and laid back in place. Fetching a query's 2,048 selected
+tokens instead - 8 rows of 256 B a token in the page layout - costs XLA's
+gather 14.0 ms a layer for 48 rows of 24.7 k where the stream takes 3.4
+(PERF.md section 6, PR 56): the masked form is the one form.
+
 The pure-JAX reference over the same layout, and the CPU-test oracle, is
-``ops.attention.ragged_paged_attention``; CPU tests of this kernel run in
+``ops.attention.ragged_paged_attention`` (with a selection:
+``ops.attention.selected_attention``); CPU tests of this kernel run in
 interpreter mode.
 """
 
@@ -57,6 +72,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dynamo_tpu.ops.pallas.decode import (  # noqa: F401
+    BIASED_PAGES_PER_CHUNK,
     _paged_decode,
     _resolve_interpret,
     supports,
@@ -69,13 +85,26 @@ from dynamo_tpu.ops.pallas.prefill import (
 
 NEG_INF = -1e30
 
+# the masked form (``bias``): query slots a block, and the scoped-VMEM
+# limit the call asks for - a block's bias ``[chunks, SB, span]`` float32
+# (``SB`` lines of the table's whole width, double-buffered) lies beside
+# the slabs and the scores of a chunk of 32 pages, past the 16 MiB the
+# compiler scopes by default (``mla_ragged``'s masked form, the same way)
+BIASED_Q_BLOCK = 32
+BIASED_VMEM_LIMIT = 96 * 2**20
+
 
 def _ragged_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
-                   rows_ref, qstart_ref, qlen_ref, lens_ref, out_ref,
-                   buf, sem, m_ref, l_ref, acc_ref, *,
+                   rows_ref, qstart_ref, qlen_ref, lens_ref, *rest,
                    page_size: int, n_kv: int, chunk: int, q_block: int,
-                   softcap: float, block: int = 1):
+                   softcap: float, block: int = 1, biased: bool = False):
     """One program per block of ``SB`` packed slots.
+
+    ``rest``: ``bias_ref`` where ``biased`` (``[chunks, SB, span]``
+    float32, a slot's bias against ITS row's keys, added to every head's
+    scores beside the causal mask: 0 on the keys the slot attends,
+    ``NEG_INF`` on the others), then ``out_ref, buf, sem, m_ref, l_ref,
+    acc_ref``.
 
     q_ref/out_ref: [SB, Hq, Dh]; rows_ref [2, n_blocks]: the first row
     with a slot in the block and one past the last; qstart/qlen/lens [R]:
@@ -88,6 +117,8 @@ def _ragged_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
     chunk loop zero times, so no page DMA is armed and no matmul runs.
     Mosaic cannot lower the layout transposes inside a ``pl.when`` branch,
     so that skip is expressed through the loop bounds."""
+    bias_ref = rest[0] if biased else None
+    out_ref, buf, sem, m_ref, l_ref, acc_ref = rest[biased:]
     i = pl.program_id(0)
     layer = layer_ref[0]
     win = window_ref[0]
@@ -175,6 +206,8 @@ def _ragged_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
             if softcap:
                 s = jnp.tanh(s / softcap) * softcap
             s4 = s.reshape(n_kv, G, SB, span)
+            if biased:
+                s4 = s4 + bias_ref[c][None, None]
             t_pos = c * span + jax.lax.broadcasted_iota(
                 jnp.int32, (1, 1, 1, span), 3)
             mask = in_row & (t_pos <= _horizon(qpos, block))  # [1,G,SB,span]
@@ -212,19 +245,25 @@ def _ragged_kernel(q_ref, kv_hbm, layer_ref, window_ref, table_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("sm_scale", "softcap", "interpret",
-                                    "block"))
+                                    "block", "name"))
 def _ragged_mixed(q, kv_pages, layer_idx, window, page_table, q_starts,
                   q_lens, kv_lens, sm_scale: float, softcap: float = 0.0,
-                  interpret: bool = False, block: int = 1, n_rows=None):
+                  interpret: bool = False, block: int = 1, n_rows=None,
+                  bias=None, name: str = "ragged_mixed"):
     """``n_rows`` (optional traced scalar): only the first ``n_rows`` rows
-    have slots for this kernel; a block's row bounds end there."""
+    have slots for this kernel; a block's row bounds end there. ``bias [T,
+    P * ps]`` float32 (optional): the masked form, under the caller's
+    ``name`` in a device trace; the rows may then lie apart on the flat
+    axis (a ``[B, S]`` step's, ``S`` apart)."""
     T, Hq, Dh = q.shape
     _L, _N, _two, Hkv, page_size, _ = kv_pages.shape
     P = page_table.shape[1]
-    chunk = min(PAGES_PER_CHUNK, P)
+    biased = bias is not None
+    chunk = min(BIASED_PAGES_PER_CHUNK if biased else PAGES_PER_CHUNK, P)
     span = chunk * page_size
     slab_bytes = 2 * 2 * Hkv * span * Dh * kv_pages.dtype.itemsize
-    SB = _fit_query_block(T, Hq, Dh, span, slab_bytes)
+    SB = (max(1, min(T, BIASED_Q_BLOCK)) if biased
+          else _fit_query_block(T, Hq, Dh, span, slab_bytes))
     n_blocks = -(-T // SB)
     # sm_scale rides the packed q (the kernel's matmuls see it once)
     qs = (q * sm_scale).astype(q.dtype)
@@ -242,9 +281,24 @@ def _ragged_mixed(q, kv_pages, layer_idx, window, page_table, q_starts,
 
     kernel = functools.partial(_ragged_kernel, page_size=page_size,
                                n_kv=Hkv, chunk=chunk, q_block=SB,
-                               softcap=softcap, block=block)
+                               softcap=softcap, block=block, biased=biased)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     G = Hq // Hkv
+    extra, extra_specs, params = (), [], {}
+    if biased:
+        # [T, S] -> [chunks, T, span], a chunk's block one leading index:
+        # keys past the table's and slots past the step's read NEG_INF
+        n_chunks = -(-P // chunk)
+        b = jnp.pad(bias.astype(jnp.float32),
+                    ((0, n_blocks * SB - T),
+                     (0, n_chunks * span - P * page_size)),
+                    constant_values=NEG_INF)
+        extra = (b.reshape(n_blocks * SB, n_chunks, span)
+                 .transpose(1, 0, 2),)
+        extra_specs = [pl.BlockSpec((n_chunks, SB, span),
+                                    lambda i: (0, i, 0))]
+        params = {"compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=BIASED_VMEM_LIMIT)}
     out = pl.pallas_call(
         kernel,
         grid=(n_blocks,),
@@ -252,7 +306,7 @@ def _ragged_mixed(q, kv_pages, layer_idx, window, page_table, q_starts,
             pl.BlockSpec((SB, Hq, Dh), lambda i: (i, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             smem, smem, smem, smem, smem, smem, smem,
-        ],
+        ] + extra_specs,
         out_specs=pl.BlockSpec((SB, Hq, Dh), lambda i: (i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, 2, Hkv, chunk * page_size, Dh), kv_pages.dtype),
@@ -263,10 +317,61 @@ def _ragged_mixed(q, kv_pages, layer_idx, window, page_table, q_starts,
         ],
         out_shape=jax.ShapeDtypeStruct((n_blocks * SB, Hq, Dh), q.dtype),
         interpret=interpret,
-        name="ragged_mixed",
+        name=name,
+        **params,
     )(qs, kv_pages, layer_idx, window, page_table, rows, q_starts, q_lens,
-      kv_lens)
+      kv_lens, *extra)
     return out[:T]
+
+
+def selected_attention_rows(q: jnp.ndarray, pages: jnp.ndarray, layer_idx,
+                            page_table: jnp.ndarray, q_starts: jnp.ndarray,
+                            new_lens: jnp.ndarray, kv_lens: jnp.ndarray,
+                            one, bias, sm_scale: float,
+                            interpret: bool | None = None) -> jnp.ndarray:
+    """Grouped-query attention of a step's rows over a LEARNED SELECTION of
+    their contexts, in the masked form: the bias of
+    ``ops/indexer.select_split`` added to the scores while a row's whole
+    context streams through the kernel that serves its kind.
+
+    q:        [N, Hq, Dh] the step's queries on the flat axis (packed back
+              to back, or a ``[B, S]`` step's rows ``S`` apart)
+    q_starts: [R] a row's first slot; new_lens [R] its new tokens; kv_lens
+              [R] its context, those included
+    one:      ``(bias [R, S], to [R])`` the rows of ONE token, or None: the
+              decode kernel with a bias (``selected_rows`` in a device
+              trace), a grid program a row, results laid at slots ``to``
+    bias:     ``[N, S]`` the rows of several tokens, or None: the ragged
+              kernel with a bias (``selected_chunks``)
+
+    Returns ``[N, Hq, Dh]``, zero in the slots of no row."""
+    N = q.shape[0]
+    layer = jnp.asarray(layer_idx, jnp.int32).reshape(1)
+    win = jnp.zeros((1,), jnp.int32)
+    table = page_table.astype(jnp.int32)
+    q_starts = q_starts.astype(jnp.int32)
+    new_lens = new_lens.astype(jnp.int32)
+    kv_lens = kv_lens.astype(jnp.int32)
+    interpret = _resolve_interpret(interpret)
+    out = None
+    if bias is not None:
+        several = new_lens > (1 if one is not None else 0)
+        out = _ragged_mixed(
+            q, pages, layer, win, table, q_starts,
+            jnp.where(several, new_lens, 0), kv_lens, sm_scale,
+            interpret=interpret, bias=bias, name="selected_chunks")
+    if one is not None:
+        rows_bias, to = one
+        # a row that brings another number of tokens streams nothing
+        # there and its result is laid nowhere
+        res = _paged_decode(
+            q[jnp.clip(q_starts, 0, N - 1)], pages, layer, win, table,
+            jnp.where(new_lens == 1, kv_lens, 0), sm_scale,
+            interpret=interpret, bias=rows_bias, name="selected_rows")
+        if out is None:
+            out = jnp.zeros_like(q)
+        out = out.at[to].set(res.astype(out.dtype), mode="drop")
+    return out
 
 
 def takes_decode_kernel(block: int = 1) -> bool:
@@ -365,5 +470,5 @@ ragged_mixed_attention_packed.supports_window_softcap = True
 ragged_mixed_attention_packed.pallas_paged_kernel = True
 
 
-__all__ = ["ragged_mixed_attention_packed", "supports",
-           "takes_decode_kernel"]
+__all__ = ["ragged_mixed_attention_packed", "selected_attention_rows",
+           "supports", "takes_decode_kernel"]

@@ -196,6 +196,23 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
+def settle_heap() -> None:
+    """Collect what start-up left behind and freeze what is alive, once,
+    before the first request: the interpreter's full collections then walk
+    what requests allocate, not the millions of objects that jax, the
+    weights' trees and the imported modules hold. A full collection of a
+    worker's whole heap stops every thread for 2-4 s, and a request of a
+    long prompt allocates enough containers (a block a page:
+    ``tokens.TokenBlockSequence``) to set one off inside a benchmark's
+    window in one run of five (PERF.md section 6, PR 56). The price: a
+    cycle that forms later among the objects frozen here is never
+    collected - they are start-up's (modules, parsers, the weights' trees),
+    which a worker keeps until it exits anyway."""
+    import gc
+    gc.collect()
+    gc.freeze()
+
+
 def arm_guided(engine, card) -> None:
     """Give the engine the tokenizer's byte vocabulary so response_format
     guided decoding works; a failure disables the feature, never the
@@ -452,6 +469,7 @@ async def amain(args: argparse.Namespace) -> None:
     # tokenizer's byte view to walk grammar masks) and no step program
     with startup.stage("startup.prime"):
         arm_guided(engine, card)
+        settle_heap()
     # endpoints, model registration, system server: until the ready line
     startup.stage_until_ready("startup.register")
 

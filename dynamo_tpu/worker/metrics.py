@@ -372,8 +372,9 @@ class EngineDispatchCollector:
             "dynamo_worker_attn_visible_keys",
             "Keys the queries of ONE full-attention layer could see (a "
             "token at position p sees p + 1), counted for a model that "
-            "keeps a slot a sequence beside its pages; an indexer scores "
-            "every one of them",
+            "keeps a slot a sequence beside its pages or whose layers "
+            "attend a learned selection (with or without a slot); an "
+            "indexer scores every one of them",
             value=float(stats.get("attn_visible_keys", 0)))
         yield CounterMetricFamily(
             "dynamo_worker_attn_selected_keys",
@@ -394,8 +395,9 @@ class EngineDispatchCollector:
             "full-attention layers attend a learned selection carried (a "
             "decode row a step; a fused block: its rows times its width), "
             "by the form they attended the selection in: 'masked' (the "
-            "row's context streamed through the latent decode kernel with "
-            "the selection as a bias: the Pallas kernels), 'gathered' (the "
+            "row's context streamed through the decode kernel of its cache "
+            "- latent or grouped-query - with the selection as a bias: the "
+            "Pallas kernels), 'gathered' (the "
             "selected rows fetched by a sorted list: the XLA path); both 0 "
             "for every other model",
             labels=["form"])
@@ -409,7 +411,8 @@ class EngineDispatchCollector:
             "Device bytes of the engine's cache by kind: 'paged' (the "
             "page pool of the attention layers), 'state' (recurrent "
             "state and convolution inputs, a slot a sequence), 'index' "
-            "(an indexer's key pages, addressed by the page table), "
+            "(an indexer's key pages, addressed by the page table of the "
+            "keys and values: one block chain holds both), "
             "'window' (window layers' rings, a slot a sequence)",
             labels=["kind"])
         for kind, value in sorted((stats.get("cache_bytes") or {}).items()):

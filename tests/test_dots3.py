@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
 from dynamo_tpu.models import dots3, get_family
 from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.ops import indexer
 from dynamo_tpu.ops import sparse_latent as sl
 from dynamo_tpu.ops.gdn import token_rows
 from dynamo_tpu.protocols.common import (PreprocessedRequest,
@@ -236,16 +237,16 @@ def test_topk_mask_is_lax_top_k_without_the_sort():
     rng = np.random.default_rng(0)
     x = rng.standard_normal((6, 300)).astype(np.float32)
     x[1, :] = np.round(x[1, :], 1)              # many ties, some at the cut
-    x[2, 40:] = sl.NEG_INF                      # fewer visible than k
+    x[2, 40:] = indexer.NEG_INF                      # fewer visible than k
     x[3, :] = 0.0                               # every key tied
     x[4, ::2] = -0.5
-    x[5, :] = np.where(rng.random(300) < 0.5, 1.0, sl.NEG_INF)
+    x[5, :] = np.where(rng.random(300) < 0.5, 1.0, indexer.NEG_INF)
     for k in (1, 17, 64, 300):
         vals, idx = jax.lax.top_k(jnp.asarray(x), k)
         want = np.zeros(x.shape, bool)
         np.put_along_axis(want, np.asarray(idx), np.asarray(vals)
-                          > sl.NEG_INF / 2, axis=1)
-        got = np.asarray(jax.jit(sl.topk_mask, static_argnums=1)(
+                          > indexer.NEG_INF / 2, axis=1)
+        got = np.asarray(jax.jit(indexer.topk_mask, static_argnums=1)(
             jnp.asarray(x), k))
         assert (got == want).all(), k
 
@@ -299,9 +300,9 @@ def test_the_selection_is_the_references_top_k_index_for_index(tiny):
                                 jnp.zeros(N - 41, jnp.int32)])
         kw = dict(width=N, packed=True)
         total = jnp.asarray([T, 60])
-        sel, live = sl.select(q[take], w[take], pool, 1, table, rows, total,
+        sel, live = indexer.select(q[take], w[take], pool, 1, table, rows, total,
                               K, **kw)
-        one, bias = sl.select_split(q[take], w[take], pool, 1, table, rows,
+        one, bias = indexer.select_split(q[take], w[take], pool, 1, table, rows,
                                     total, K, **kw)
     for slot, t in enumerate(np.asarray(take[:41])):
         want = np.asarray(idx[t])[np.asarray(vals[t]) > -np.inf]
@@ -319,9 +320,9 @@ def test_the_selection_is_the_references_top_k_index_for_index(tiny):
         np.asarray(sel[40])[np.asarray(live[40])]) == sorted(
         np.asarray(idx[59])[np.asarray(vals[59]) > -np.inf])
     assert set(np.unique(np.asarray(rows_bias))) == {
-        np.float32(0.0), np.float32(sl.NEG_INF)}
-    assert (np.asarray(rows_bias[0]) < sl.NEG_INF / 2).all()
-    assert (np.asarray(bias[40:]) < sl.NEG_INF / 2).all()
+        np.float32(0.0), np.float32(indexer.NEG_INF)}
+    assert (np.asarray(rows_bias[0]) < indexer.NEG_INF / 2).all()
+    assert (np.asarray(bias[40:]) < indexer.NEG_INF / 2).all()
 
 
 # ------------------------------------------------ the model, by its pools
@@ -608,7 +609,7 @@ def test_the_masked_kernel_under_a_causal_bias_is_the_ragged_oracle(nh, T):
     table = jnp.asarray(1 + np.arange(4 * P).reshape(4, P), jnp.int32)
     q_lat = jnp.asarray(rng.standard_normal((T, nh, dkv)), jnp.float32)
     q_pe = jnp.asarray(rng.standard_normal((T, nh, dr)), jnp.float32)
-    bias = np.full((T, P * ps), sl.NEG_INF, np.float32)
+    bias = np.full((T, P * ps), indexer.NEG_INF, np.float32)
     for r in range(4):
         for i in range(new[r]):
             bias[starts[r] + i, :total[r] - new[r] + i + 1] = 0.0
@@ -676,9 +677,9 @@ def test_the_masked_one_token_form_is_the_gather_over_the_sorted_list(ctx,
     total = jnp.asarray(total, jnp.int32)
     kw = dict(width=N, packed=True)
     with jax.default_matmul_precision("highest"):
-        sel, live = sl.select(q, w, jnp.asarray(index), 1, table, rows,
+        sel, live = indexer.select(q, w, jnp.asarray(index), 1, table, rows,
                               total, K, **kw)
-        one, _bias = sl.select_split(q, w, jnp.asarray(index), 1, table,
+        one, _bias = indexer.select_split(q, w, jnp.asarray(index), 1, table,
                                      rows, total, K, **kw)
         rows_bias, to = one
         first = jnp.clip(rows.start, 0, N - 1)
@@ -761,7 +762,7 @@ def test_the_masked_kernel_walks_chunks_of_32_pages(nh, dkv, ctx):
     q_lat = jnp.asarray(rng.standard_normal((T, nh, dkv)), jnp.float32)
     q_pe = jnp.asarray(rng.standard_normal((T, nh, dr)), jnp.float32)
     # each slot of a chunk row sees a random third of what lies before it
-    bias = np.full((T, S), sl.NEG_INF, np.float32)
+    bias = np.full((T, S), indexer.NEG_INF, np.float32)
     row_of = np.full(T, -1)
     for r in range(2):
         for i in range(new[r]):

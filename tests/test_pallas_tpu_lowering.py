@@ -1497,3 +1497,109 @@ def test_gqa_step_programs_write_no_projection_weight_on_a_v5e(
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < temp_gb * 1e9, mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+# -- Keye-VL-2.0: a learned selection in front of grouped-query pages
+# -- (ISSUE 56) --------------------------------------------------------------
+
+@pytest.mark.parametrize("form,N", [("rows", 48), ("chunks", 640)])
+def test_the_masked_gqa_kernels_compile_in_the_tpu_compiler(form, N):
+    """``selected_attention_rows`` at the ask-many cell's geometry (32
+    query heads over 4 key/value heads of 128, 48 rows, pages of 16, a
+    table of 1,600 pages): the decode kernel with a bias a row
+    (``selected_rows``, a chunk of 32 pages: a slab of 2 MB under the
+    default scoped-VMEM limit) and the ragged kernel with a bias a slot
+    (``selected_chunks``: a block of 32 slots' bias lines beside the
+    slabs, under the limit the call asks for). The TPU compiler takes
+    both, each under its own name."""
+    from dynamo_tpu.ops.pallas.ragged import selected_attention_rows
+
+    one_chip = _v5e_chip()
+    R, Hq, Hkv, Dh, P = 48, 32, 4, 128, 1600
+    S = P * PS
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q, pages, table, starts, new, total, rows_bias, to, bias):
+        one = (rows_bias, to) if form == "rows" else None
+        return selected_attention_rows(
+            q, pages, 3, table, starts, new, total, one,
+            bias if form == "chunks" else None, 0.088)
+
+    text = jax.jit(fn).lower(
+        sds((N, Hq, Dh), jnp.bfloat16),
+        sds((6, 16384, 2, Hkv, PS, Dh), jnp.bfloat16),
+        sds((R, P), jnp.int32), sds((R,), jnp.int32), sds((R,), jnp.int32),
+        sds((R,), jnp.int32), sds((R, S), jnp.float32),
+        sds((R,), jnp.int32), sds((N, S), jnp.float32)).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and f"selected_{form}" in calls[0]
+
+
+def test_keye_step_programs_compile_for_a_v5e():
+    """The ask-many cell's two step programs - the token-packed step of
+    640 slots over 48 rows and the fused block of two decode steps - at
+    the published widths (abstract weights: 4.375 B parameters) and the
+    cell's two pools compile for a v5e. Each row kind goes to a masked
+    kernel: in the packed step ``selected_chunks`` for the rows of several
+    tokens and ``selected_rows`` for the rows of one, in the fused block
+    ``selected_rows`` alone, ``moe_grouped`` in both. Neither sorts an
+    axis as long as the page table's tokens (the selection stays a mask),
+    neither copies the key/value pages or the index pages, no loop is
+    handed a slice of a weight stack, and both fit the chip's memory
+    beside the weights and the pools."""
+    import json
+    import os
+
+    from dynamo_tpu.engine.jax_engine import JaxEngine, JaxEngineConfig
+    from dynamo_tpu.engine.program_check import pool_copies, step_programs
+    from dynamo_tpu.models import moe
+    from dynamo_tpu.models.config import ModelConfig
+
+    one_chip = _v5e_chip()
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "configs",
+        "keye-vl-2.0-30b-a3b.json")
+    with open(path) as f:
+        hf = json.load(f)
+    args = hf.pop("benchmark")["worker_args"]
+    args = {args[i]: int(args[i + 1]) for i in range(0, len(args), 2)
+            if args[i + 1].isdigit()}
+    cfg = ModelConfig.from_hf(hf)
+    abs_params = jax.eval_shape(
+        lambda: moe.init_params(cfg, jax.random.PRNGKey(0)))
+    rows, chunk = args["--max-num-seqs"], args["--max-prefill-chunk"]
+    eng = JaxEngine(cfg, abs_params, JaxEngineConfig(
+        num_pages=16, page_size=16, max_num_seqs=rows,
+        max_context=args["--max-context"], max_prefill_chunk=chunk,
+        attn_impl="pallas", decode_multistep=args["--decode-multistep"]))
+    assert eng.padded_reason is None and eng.one_token_form == "masked"
+    assert eng._packed_cap == args["--min-prefill-bucket"]
+    assert eng.cache_kinds == "paged[L=6,Hkv=4,Dh=128]+index[L=6,D=64]"
+    programs = step_programs(
+        eng, rows, chunk, width=args["--decode-multistep"],
+        sharding=one_chip, num_pages=args["--num-pages"],
+        tokens=eng._packed_cap)
+    pool = (6, args["--num-pages"]) + tuple(eng.kv_pool.shape[2:])
+    index = f"bf16[6,{args['--num-pages']},1024]"
+    want = {"packed": {"selected_chunks", "selected_rows", "moe_grouped"},
+            "fused": {"selected_rows", "moe_grouped"}}
+    table_tokens = f"{args['--max-context']}]"
+    for name, kernels in want.items():
+        fn, fn_args = programs[name]
+        compiled = fn.lower(*fn_args).compile()
+        hlo = compiled.as_text()
+        calls = {ln.split("=")[0].strip().lstrip("%").split(".")[0]
+                 for ln in hlo.splitlines() if "tpu_custom_call" in ln}
+        assert calls == kernels, name
+        assert not [ln for ln in hlo.splitlines() if " sort(" in ln
+                    and table_tokens in ln.split(" sort(")[0]], name
+        assert pool_copies(hlo, pool, eng.kv_pool.dtype) == []
+        assert not [ln for ln in hlo.splitlines() if " copy(" in ln
+                    and index in ln.split(" copy(")[0]], name
+        loop, _entry = _loops_that_write_a_weight(hlo, abs_params)
+        assert loop == [], (name, loop)
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < 0.8e9, name
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9

@@ -22,12 +22,14 @@ from dynamo_tpu.engine.program_check import (
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROWS, CHUNK = 8, 64
 
-# the seven cells' configurations: one family each
+# the cells' configurations: one family each (the expert family twice:
+# under block diffusion, and behind a learned selection)
 FAMILIES = {
     "qwen3-4b": "llama", "joyai-llm-flash": "deepseek",
     "sdar-30b-a3b-chat": "moe", "longcat-flash-omni": "longcat",
     "qwen3-next-80b-a3b-instruct": "qwen3_next",
-    "dots3-note-prev": "dots3", "olmo-hybrid-7b": "olmo_hybrid"}
+    "dots3-note-prev": "dots3", "olmo-hybrid-7b": "olmo_hybrid",
+    "keye-vl-2.0-30b-a3b": "moe"}
 LAYER_GROUPS = {"mixer_in", "cache_write", "mixer", "mixer_out", "ffn"}
 
 
